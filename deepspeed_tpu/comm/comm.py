@@ -436,10 +436,9 @@ def _eager_collective(key, make_fn, tensor, group, out_spec=None):
     cache_key = (key, axes, in_spec, out_spec, ctx.epoch)
     fn = _EAGER_JIT_CACHE.get(cache_key)
     if fn is None:
-        from jax.experimental.shard_map import shard_map
         fn = jax.jit(
-            shard_map(make_fn(axes), mesh=ctx.mesh, in_specs=(in_spec, ), out_specs=out_spec,
-                      check_rep=False))
+            jax.shard_map(make_fn(axes), mesh=ctx.mesh, in_specs=(in_spec, ),
+                          out_specs=out_spec, check_vma=False))
         _EAGER_JIT_CACHE[cache_key] = fn
     return fn(tensor)
 

@@ -347,11 +347,9 @@ class CheckpointConfig(ConfigModel):
 class CompileConfig(ConfigModel):
     """Reference ``runtime/compiler.py`` surface; on TPU everything is always
     compiled — these knobs control jit options (donation, persistent cache).
+    The cache directory is not a knob: ``runtime/compiler.py`` takes
+    ``$JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``.
 
-    - ``cache_dir``: persistent XLA compilation-cache directory (the
-      autotuner's ``_enable_compile_cache`` promoted into engine init).
-      Multi-restart runs skip recompiles; a pre-existing
-      ``JAX_COMPILATION_CACHE_DIR`` env/config always wins.
     - ``cache_min_compile_secs``: only programs whose compile took at least
       this long are persisted (JAX's
       ``jax_persistent_cache_min_compile_time_secs``).
@@ -359,7 +357,6 @@ class CompileConfig(ConfigModel):
     enabled: bool = True
     backend: str = "xla"
     kwargs: Dict[str, Any] = {}
-    cache_dir: Optional[str] = None
     cache_min_compile_secs: Optional[float] = Field(None, ge=0)
 
 

@@ -65,9 +65,7 @@ def debug_report():
                      f"{v if v else NO}")
     lines.append(f"python version {'.' * 34} {sys.version.split()[0]}")
     try:
-        # RESOLVED variant (env override OR silicon-A/B sentinel promotion),
-        # not the raw env var: a FOLDED_PROVEN run with the env unset still
-        # executes the folded kernels and must report as such
+        # the variant as the dispatcher resolves it, not the raw env var
         from .ops.attention import resolved_attention_variant
         lines.append(f"flash-attention variant {'.' * 25} "
                      f"{resolved_attention_variant()}")

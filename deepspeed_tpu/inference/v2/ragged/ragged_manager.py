@@ -36,22 +36,22 @@ class DSStateManager:
         """Reference memory_config sizing (manager_configs.py): 'allocate' =
         memory_config_size IS the block count; 'reserve' = that fraction of
         free HBM becomes KV blocks. Reserve engages only on a real TPU
-        (PJRT memory stats); elsewhere the deterministic default keeps CPU
-        tests from sizing a cache off host RAM."""
+        (PJRT memory stats), where a device that reports no free memory is
+        an error; elsewhere the deterministic default keeps CPU tests from
+        sizing a cache off host RAM."""
         if config.memory_config_mode == "allocate":
             return max(1, int(config.memory_config_size))
         from ....ops.registry import on_tpu
-        if on_tpu():
-            try:
-                from ....accelerator import get_accelerator
-                free = get_accelerator().available_memory()
-            except Exception:  # noqa: BLE001 — stats are best-effort
-                free = None
-            if free and free > 0:
-                from .kv_cache import estimate_kv_blocks
-                return max(64, estimate_kv_blocks(
-                    kv_config, free, config.memory_config_size))
-        return max(64, config.max_tracked_sequences)
+        if not on_tpu():
+            return max(64, config.max_tracked_sequences)
+        from ....accelerator import get_accelerator
+        from .kv_cache import estimate_kv_blocks
+        free = get_accelerator().available_memory()
+        if free <= 0:
+            raise RuntimeError(
+                f"cannot size the KV pool: the device reports {free} free "
+                f"bytes (memory stats missing, or the weights fill it)")
+        return estimate_kv_blocks(kv_config, free, config.memory_config_size)
 
     # ---- sequence tracking (reference ragged_manager.py:96-160) ----
 

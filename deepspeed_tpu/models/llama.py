@@ -21,7 +21,7 @@ import numpy as np
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
-from ..ops.registry import on_tpu
+from ..ops.registry import interpret_kernels, on_tpu
 
 # logical axis names; mapped onto mesh axes by parallel/tp.py rules
 EMBED = "embed"
@@ -244,7 +244,7 @@ def _use_cast(w, dtype):
     bf16 copy of the model. XLA undoes a naive in-body ``astype`` —
     ``convert(slice(W))`` commutes to ``slice(convert(W))`` and LICM hoists
     the now loop-invariant whole-tree convert right back out of the scan
-    loop (the round-4 OOM pattern, ``.perf/bench_fast_r4_0731T1228.out``).
+    loop (the round-4 OOM pattern).
     The ``optimization_barrier`` between the slice and the cast makes that
     reorder illegal, pinning the convert to chunk granularity. When params
     already arrive at compute dtype (engine-side casting), this is a no-op.
@@ -346,7 +346,7 @@ class LlamaAttention(nn.Module):
         mesh_shape = (dict(get_mesh_context().mesh.shape)
                       if mesh_is_initialized() else {})
         sp_sz = mesh_shape.get("seq", 1)
-        mp_sz = mesh_shape.get("model", 1)
+        one_device = all(n == 1 for n in mesh_shape.values())
 
         # shared flash eligibility (shape/mask/positions); the sharded and
         # unsharded dispatch conditions below both build on it
@@ -354,18 +354,17 @@ class LlamaAttention(nn.Module):
                           and cfg.pos_embedding != "alibi"
                           and (s <= 128 or s % 128 == 0))
         on_flash_backend = cfg.attn_impl == "flash" or on_tpu()
-        # a raw pallas_call doesn't auto-partition under GSPMD: with a
-        # nontrivial seq/model mesh the sharded dispatch below owns the
-        # kernel path
-        use_flash = (flash_shape_ok and on_flash_backend
-                     and sp_sz == 1 and mp_sz == 1)
+        # a raw pallas_call doesn't auto-partition under GSPMD: on any mesh
+        # of more than one device (data parallel and ZeRO included) the
+        # sharded dispatch below owns the kernel path
+        use_flash = flash_shape_ok and on_flash_backend and one_device
         if use_flash:
             # the Pallas kernel handles local (sliding-window) attention
             # natively, skipping out-of-window blocks
             attn = flash_attention(q, k, v, causal=True, scale=cfg.attn_scale,
                                    window=window,
                                    softcap=cfg.attn_logit_softcapping,
-                                   interpret=not on_tpu())
+                                   interpret=interpret_kernels())
         else:
             mask = None
             if attn_mask is not None:
@@ -416,15 +415,15 @@ class LlamaAttention(nn.Module):
                                                     scale=cfg.attn_scale)
 
             attn = None
-            if (sp_sz > 1 or mp_sz > 1) and flash_shape_ok and on_flash_backend:
-                # flash-inside-shard_map: seq axis = Ulysses all-to-alls
-                # (the 32k-seq memory-safe path), model axis = per-head-block
-                # kernel (a raw pallas_call can't auto-partition under GSPMD)
+            if not one_device and flash_shape_ok and on_flash_backend:
+                # flash-inside-shard_map: data axes = each device's own
+                # rows, seq axis = Ulysses all-to-alls (the 32k-seq
+                # memory-safe path), model axis = per-head-block kernel
                 from ..sequence.layer import ulysses_flash
                 attn = ulysses_flash(
                     q, k, v, window=window, scale=cfg.attn_scale,
                     softcap=cfg.attn_logit_softcapping,
-                    interpret=not on_tpu())
+                    interpret=interpret_kernels())
             if attn is None and sp_sz > 1:
                 # GSPMD Ulysses: sharding constraints make XLA emit the
                 # all-to-all pair around full-sequence attention
@@ -759,8 +758,27 @@ def logical_axis_tree(params):
         is_leaf=lambda x: hasattr(x, "unbox"))
 
 
-def init_llama(config: LlamaConfig, seed: int = 0, seq_len: int = 8):
+def init_llama(config: LlamaConfig, seed: int = 0, seq_len: int = 8,
+               dtype=None):
+    """Module + seeded parameters, fp32 on the default device. With
+    ``dtype`` the init and the cast are one jitted program, so the tree is
+    born at ``dtype``: the eager flax ``init`` leaves the whole fp32 tree on
+    the device first — 15 GB at 16 Mistral-7B layers before a server casts
+    it to bf16. (Eager stays the default: a fresh whole-init compile per
+    call costs seconds, which small-model callers and the tests would pay
+    hundreds of times.) A caller whose state is to be sharded runs the
+    jitted form under ``jax.default_device(jax.devices("cpu")[0])`` and lets
+    the engine place the shards, so that no chip ever holds the whole tree;
+    jitted, the forward pass flax traces to shape the parameters is dead
+    code, so it needs no kernel the host backend lacks."""
     model = LlamaForCausalLM(config)
     ids = jnp.ones((1, seq_len), dtype=jnp.int32)
-    variables = model.init(jax.random.PRNGKey(seed), ids)
-    return model, unbox_params(variables["params"])
+
+    def _init(key):
+        return unbox_params(model.init(key, ids)["params"])
+
+    key = jax.random.PRNGKey(seed)
+    if dtype is None:
+        return model, _init(key)
+    return model, jax.jit(lambda k: jax.tree_util.tree_map(
+        lambda x: x.astype(dtype), _init(k)))(key)

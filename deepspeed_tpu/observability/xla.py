@@ -38,8 +38,6 @@ _COMPILE_HIST = dict(lo=1e-3, hi=1e4, buckets_per_decade=5)
 # step times: µs-scale fused CPU steps to minutes-long K-step waves
 _STEP_HIST = dict(lo=1e-6, hi=1e3, buckets_per_decade=10)
 
-_FALLBACK_PEAK_FLOPS = 197e12  # accelerator ABC default (v5e-class)
-
 
 def cost_analysis_flops(stage) -> float:
     """FLOPs from ``cost_analysis()`` of a ``jax.stages.Lowered`` OR
@@ -286,12 +284,10 @@ def install_backend_compile_listener(
 
 def peak_device_flops() -> float:
     """Per-device peak bf16 FLOP/s from the accelerator abstraction (the
-    MFU denominator); falls back to the v5e-class default."""
-    try:
-        from ..accelerator import get_accelerator
-        return max(1.0, float(get_accelerator().peak_bf16_flops()))
-    except Exception:
-        return _FALLBACK_PEAK_FLOPS
+    MFU denominator). A TPU whose kind has no published peak raises; a
+    CPU gets the accelerator ABC's default."""
+    from ..accelerator import get_accelerator
+    return max(1.0, float(get_accelerator().peak_bf16_flops()))
 
 
 class TrainInstruments:

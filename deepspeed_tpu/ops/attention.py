@@ -30,11 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import registry, use_pallas
 
@@ -197,8 +193,8 @@ def _regroup(q, k, v):
 
 def _use_folded() -> bool:
     """Legacy probe (kept for bench.py's journal tagging): whether the
-    folded-variant *preference* is active — ``DS_TPU_FLASH_FOLDED`` env, or
-    the deprecated ``.perf/FOLDED_PROVEN`` sentinel. Per-shape dispatch
+    folded-variant *preference* is active (``DS_TPU_FLASH_FOLDED`` env).
+    Per-shape dispatch
     (ops/kernel_dispatch.py) now owns the actual folded-vs-per-head choice;
     this only reports the variant a Pallas leg falls back to when no
     measurement decides it."""
@@ -208,10 +204,8 @@ def _use_folded() -> bool:
 
 def resolved_attention_variant() -> str:
     """The flash-attention variant that will ACTUALLY run on a Pallas leg —
-    env override OR sentinel promotion resolved, not just the env var.
-    Reporting surfaces (env_report, bench run tags) must use this: a
-    sentinel-promoted run with the env unset is still a folded run, and
-    labeling it per-head poisons any A/B that keys off the tag. For the
+    the env override resolved as the dispatcher resolves it. Reporting
+    surfaces (env_report, bench run tags) must use this. For the
     full per-leg (fwd/bwd × impl × blocks) resolution use
     ``kernel_dispatch.resolved_note``."""
     return "folded" if _use_folded() else "per-head"
@@ -696,4 +690,4 @@ def flash_attention(q,
                                  interpret, fwd_dec, bwd_dec)
 
 
-registry.register("flash_attention", "pallas" if _HAS_PLTPU else "xla", True)
+registry.register("flash_attention", "pallas", True)

@@ -30,12 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LSE_MASKED = -1e30  # matches attention.py's fully-masked-row marker
@@ -393,6 +388,6 @@ def flash_bwd_folded(q, k, v, lse, o, g_out, scale, causal, block_q, block_k,
 
 from .registry import registry  # noqa: E402
 
-registry.register("flash_attention_folded", "pallas" if _HAS_PLTPU else "xla",
+registry.register("flash_attention_folded", "pallas",
                   True, "head-folded flash variant (DS_TPU_FLASH_FOLDED=1): "
                   "all KV heads per grid step, natural [B,S,H,D] layouts")

@@ -20,10 +20,11 @@ rename): a writer killed mid-commit leaves either the old table or the new
 one, never truncated JSON.  A corrupt/unreadable table degrades to "no
 measurements" — dispatch still works off the heuristics.
 
-Location precedence (env wins, mirroring ``$DS_TPU_COMPILE_CACHE_DIR``):
-``$DS_TPU_ATTN_CACHE_DIR``/attn_dispatch.json if the env is set, else
-``$XDG_CACHE_HOME|~/.cache``/deepspeed_tpu/attn_dispatch.json.  Never a
-repo-relative dotfile (tier-1 CI points the env at a hermetic temp dir).
+Location: the tracked table beside this module
+(``deepspeed_tpu/ops/attn_dispatch.json``, empty until a chip sweep commits
+to it), so which kernel compiles follows from the checkout and from nothing
+in a user's home.  ``$DS_TPU_ATTN_CACHE_DIR``/attn_dispatch.json replaces it
+for the sweep tool and for tests (tier-1 points it at a hermetic temp dir).
 """
 
 import json
@@ -37,13 +38,9 @@ CACHE_FILENAME = "attn_dispatch.json"
 
 def cache_dir() -> str:
     """Directory holding the dispatch table — ``$DS_TPU_ATTN_CACHE_DIR`` if
-    set, else the per-user XDG cache tree (outside any repo checkout)."""
-    env = os.environ.get("DS_TPU_ATTN_CACHE_DIR")
-    if env:
-        return env
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "deepspeed_tpu")
+    set, else this module's own directory (the tracked table)."""
+    return (os.environ.get("DS_TPU_ATTN_CACHE_DIR")
+            or os.path.dirname(os.path.abspath(__file__)))
 
 
 def cache_path() -> str:

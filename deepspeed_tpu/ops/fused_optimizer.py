@@ -20,11 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import registry, use_pallas
 
@@ -51,8 +47,7 @@ def _launch_flat(kernel, tensors, scalars, out_dtypes, interpret):
 
     blk = lambda i: (i, 0)
     tile_spec = pl.BlockSpec((tile, lanes), blk)
-    scalar_spec = (pl.BlockSpec(memory_space=pltpu.SMEM) if _HAS_PLTPU
-                   else pl.BlockSpec((1, )))
+    scalar_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     outs = pl.pallas_call(
         kernel,
         grid=(rows // tile, ),
@@ -219,6 +214,6 @@ def fused_lamb_step(params, grads, m, v, lr, step,
     return (pf - lr_arr[0] * trust * u).astype(params.dtype), m_n, v_n
 
 
-registry.register("fused_adam", "pallas" if _HAS_PLTPU else "xla", True)
-registry.register("fused_lion", "pallas" if _HAS_PLTPU else "xla", True)
-registry.register("fused_lamb", "pallas" if _HAS_PLTPU else "xla", True)
+registry.register("fused_adam", "pallas", True)
+registry.register("fused_lion", "pallas", True)
+registry.register("fused_lamb", "pallas", True)

@@ -22,17 +22,13 @@ per leg, strongest first:
    (``autotune_cache.py``, written by ``bin/ds_kernel_tune``);
 5. the built-in heuristic table below (which encodes the measured
    42.7 < 62.9 ms fwd result: XLA fused forward at hd64 / seq >= 1024,
-   Pallas backward always);
-6. the deprecated ``.perf/FOLDED_PROVEN`` sentinel — still honored as a
-   folded-variant preference so an existing promotion isn't silently
-   dropped, but it logs a deprecation warning pointing at the cache.
+   Pallas backward always).
 
 Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
 measured cache blocks > per-head_dim defaults (the round-5 sweep result
 (256, 512) at hd64).
 """
 
-import functools
 import os
 from typing import NamedTuple, Optional
 
@@ -45,8 +41,7 @@ IMPL_FOLDED = "folded"  # head-folded kernels (ops/attention_folded.py)
 _IMPLS = (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED)
 
 # head_dim -> default (block_q, block_k).  hd64 = (256, 512) measured on
-# v5e 8/1: +20% over (256, 256) on the identical bench program
-# (.perf/flash_256x512_r5_0801T1906.out).
+# v5e 2026-08-01: +20% over (256, 256) on the identical bench program.
 BLOCK_TABLE = {64: (256, 512), 128: (128, 128)}
 DEFAULT_BLOCKS = (128, 128)
 
@@ -103,30 +98,9 @@ def device_kind() -> str:
     """Device kind string for cache keys ("TPU v5e", "cpu", ...).  Interpret
     mode keys as "interpret" so CPU sweep results never masquerade as chip
     measurements."""
-    try:
-        import jax
-        d = jax.devices()[0]
-        return getattr(d, "device_kind", None) or d.platform
-    except Exception:  # noqa: BLE001 — no backend yet
-        return "unknown"
-
-
-@functools.cache
-def _sentinel_folded() -> bool:
-    """Deprecated ``.perf/FOLDED_PROVEN`` sentinel (pre-dispatch silicon A/B
-    promotion).  Still read as a variant preference so an earned promotion
-    survives the transition, but the tracked autotune cache is the
-    replacement — warn once."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..", ".perf", "FOLDED_PROVEN")
-    if os.path.exists(path):
-        logger.warning(
-            ".perf/FOLDED_PROVEN is deprecated: commit a measured entry to "
-            "the attention autotune cache instead (bin/ds_kernel_tune); the "
-            "sentinel is honored only as a folded-variant preference when "
-            "no measurement exists")
-        return True
-    return False
+    import jax
+    d = jax.devices()[0]
+    return getattr(d, "device_kind", None) or d.platform
 
 
 def _env_impl(name: str) -> Optional[str]:
@@ -141,13 +115,10 @@ def _env_impl(name: str) -> Optional[str]:
 
 def _variant_preference() -> Optional[str]:
     """Which Pallas variant (per-head vs folded) a Pallas leg should use
-    when nothing shape-specific decided it: legacy env wins, then the
-    deprecated sentinel."""
+    when nothing shape-specific decided it: the legacy env, else none."""
     env = os.environ.get("DS_TPU_FLASH_FOLDED")
     if env is not None:
         return IMPL_FOLDED if env not in ("", "0") else IMPL_PALLAS
-    if _sentinel_folded():
-        return IMPL_FOLDED
     return None
 
 
@@ -171,7 +142,7 @@ def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
     """Built-in table when no measurement exists.
 
     Forward: XLA's fused softmax-attention beat the Pallas flash forward at
-    the bench shape (42.7 vs 62.9 ms, hd64/seq1024 — docs/PERF_NOTES.md);
+    the bench shape (42.7 vs 62.9 ms, hd64/seq1024, v5e 2026-08-01);
     the regime is "scores fit comfortably and XLA fuses the whole chain",
     which holds for hd64 at seq >= 1024 on sequences that are not
     window-limited.  Windowed shapes keep the Pallas forward: it skips

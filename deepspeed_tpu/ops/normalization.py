@@ -14,11 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import registry, use_pallas
 
@@ -87,5 +83,5 @@ def layer_norm(x, weight, bias, eps: float = 1e-5, force_pallas: Optional[bool] 
     return (y * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-registry.register("rms_norm", "pallas" if _HAS_PLTPU else "xla", True)
-registry.register("layer_norm", "pallas" if _HAS_PLTPU else "xla", True)
+registry.register("rms_norm", "pallas", True)
+registry.register("layer_norm", "pallas", True)

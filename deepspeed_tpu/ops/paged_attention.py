@@ -4,13 +4,15 @@ Reference capability: ``deepspeed/inference/v2/kernels/ragged_ops/
 blocked_flash/`` (attention_atom.h — per-atom block-table flash over a paged
 KV cache). TPU design, rather than a port of the CUDA atom machinery:
 
-- Grid ``(seqs, pages)``: ONE grid step streams one whole KV page — ALL
-  heads — against every query head (static in-kernel head unroll). The
-  page loop is innermost so an online softmax (running max / sum /
-  accumulator in VMEM scratch) streams the sequence's history one page at
-  a time; no [S, L, ...] gather is ever materialized. The earlier design
-  put kv_heads in the grid: 16x the grid steps, 16x smaller DMAs, and the
-  8/1 xprof trace showed per-step overheads dominating exactly that shape.
+- Grid ``(seqs, query tiles, pages)``: ONE grid step streams one whole KV
+  page — ALL heads — against every query head of one tile of new tokens
+  (static in-kernel head unroll). The page loop is innermost so an online
+  softmax (running max / sum / accumulator in VMEM scratch) streams the
+  sequence's history one page at a time; no [S, L, ...] gather is ever
+  materialized. The query tile (``_query_tile``) bounds what is resident:
+  with the whole ``[N, H, D]`` run in one block the v5e compiler refuses
+  N >= 256 at 32 heads of 128 (24 MB of scoped VMEM against a 16 MB limit).
+  A tile also skips the pages that lie wholly after its last query.
 - The *block table is scalar-prefetched*: the BlockSpec index map reads
   ``block_table[s, page]`` to DMA exactly the pages the sequence owns,
   straight from the full cache in HBM — the layer index is prefetched too,
@@ -47,12 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover - pallas-less jax installs
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -68,10 +65,10 @@ def _paged_attn_kernel(layer_ref, bt_ref, seen_ref, lens_ref,  # scalar prefetch
     slopes_ref = rest.pop(0) if has_alibi else None
     o_ref, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
-    b = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    b = pl.program_id(2)
+    n_pages = pl.num_programs(2)
     D = q_ref.shape[-1]
-    N = q_ref.shape[1]
+    N = q_ref.shape[1]  # the query TILE, not the whole new-token run
     ng = N * groups
 
     @pl.when(b == 0)
@@ -80,8 +77,10 @@ def _paged_attn_kernel(layer_ref, bt_ref, seen_ref, lens_ref,  # scalar prefetch
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    hist_len = lens_ref[s]   # seen + new: valid key region
-    seen = seen_ref[s]
+    # absolute position of this tile's first query; keys after the tile's
+    # last query are causally dead for every row of it
+    seen = seen_ref[s] + pl.program_id(1) * N
+    hist_len = jnp.minimum(lens_ref[s], seen + N)
 
     live = b * page_size < hist_len
     if window is not None:
@@ -160,6 +159,24 @@ def _paged_attn_kernel(layer_ref, bt_ref, seen_ref, lens_ref,  # scalar prefetch
                 out.reshape(N, groups, D).astype(o_ref.dtype)
 
 
+# Scoped VMEM the resident blocks may take. The v5e compiler's limit is
+# 16 MB; the page blocks and in-kernel temporaries need the rest.
+_RESIDENT_VMEM_BYTES = 11 << 20
+
+
+def _query_tile(n: int, heads: int, head_dim: int, itemsize: int) -> int:
+    """New tokens per grid step: the largest divisor of ``n`` by a power of
+    two whose resident blocks fit ``_RESIDENT_VMEM_BYTES``. Per query row
+    (one token, one head): the fp32 accumulator, the running max and sum
+    (one value each, padded to a 128-lane fp32 row), and the q and out
+    blocks, double-buffered."""
+    per_row = head_dim * 4 + 2 * 128 * 4 + 2 * 2 * head_dim * itemsize
+    tile = n
+    while tile * heads * per_row > _RESIDENT_VMEM_BYTES and tile % 2 == 0:
+        tile //= 2
+    return tile
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret", "window",
                                              "attn_scale", "use_alibi",
                                              "softcap"))
@@ -200,25 +217,24 @@ def paged_attention(q, cache, layer, block_table, seq_seen, seq_lens,
     G = H // KV
     scale = attn_scale if attn_scale is not None else 1.0 / (D ** 0.5)
     n_pages = slots // page_size
+    TN = _query_tile(N, H, D, q.dtype.itemsize)
     # free reshape (middle-dim split): one (layer, page) DMA block is
     # [2, page_size, KV*D] — k and v pages for every head arrive together
     kv_pages = cache.reshape(L2, n_pages, page_size, KVD)
 
-    def q_map(s, b, layer_r, bt_r, seen_r, lens_r):
-        return (s, 0, 0, 0)
+    def q_map(s, t, b, layer_r, bt_r, seen_r, lens_r):
+        return (s, t, 0, 0)
 
-    def kv_map(s, b, layer_r, bt_r, seen_r, lens_r):
-        # clamp trailing pages to the last needed page: identical consecutive
-        # block indices skip the DMA re-fetch
-        needed = jax.lax.max((lens_r[s] + page_size - 1) // page_size, 1)
+    def kv_map(s, t, b, layer_r, bt_r, seen_r, lens_r):
+        # clamp trailing pages to the last page this tile can see: identical
+        # consecutive block indices skip the DMA re-fetch
+        visible = jax.lax.min(lens_r[s], seen_r[s] + (t + 1) * TN)
+        needed = jax.lax.max((visible + page_size - 1) // page_size, 1)
         page = bt_r[s, jax.lax.min(b, needed - 1)]
         return (layer_r[0], page, 0, 0)
 
-    def o_map(s, b, layer_r, bt_r, seen_r, lens_r):
-        return (s, 0, 0, 0)
-
     in_specs = [
-        pl.BlockSpec((1, N, H, D), q_map),
+        pl.BlockSpec((1, TN, H, D), q_map),
         pl.BlockSpec((2, 1, page_size, KVD), kv_map),
     ]
     inputs = [q, kv_pages]
@@ -237,19 +253,19 @@ def paged_attention(q, cache, layer, block_table, seq_seen, seq_lens,
             slopes = jnp.asarray(alibi_slopes(H)).reshape(KV, G)
         # [1, KV, G] with block (1, KV, G): the last two block dims equal
         # the array dims, which Mosaic lowers for any KV/G
-        in_specs.append(pl.BlockSpec((1, KV, G), lambda s, b, *_: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, KV, G), lambda s, t, b, *_: (0, 0, 0)))
         inputs.append(slopes.astype(jnp.float32).reshape(1, KV, G))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(S, B),
+        grid=(S, N // TN, B),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, N, H, D), o_map),
+        out_specs=pl.BlockSpec((1, TN, H, D), q_map),
         scratch_shapes=[
             # rows grouped kv-head-major: head h owns [h*NG, (h+1)*NG)
-            pltpu.VMEM((N * H, 1), jnp.float32),  # running max
-            pltpu.VMEM((N * H, 1), jnp.float32),  # running sum
-            pltpu.VMEM((N * H, D), jnp.float32),  # accumulator
+            pltpu.VMEM((TN * H, 1), jnp.float32),  # running max
+            pltpu.VMEM((TN * H, 1), jnp.float32),  # running sum
+            pltpu.VMEM((TN * H, D), jnp.float32),  # accumulator
         ],
     )
 
@@ -318,6 +334,6 @@ def paged_attention_reference(q, cache, layer, block_table, seq_seen, seq_lens,
 
 from .registry import registry  # noqa: E402
 
-registry.register("paged_attention", "pallas" if _HAS_PLTPU else "xla", True,
+registry.register("paged_attention", "pallas", True,
                   "ragged blocked-flash decode over paged KV (block tables, "
                   "window/ALiBi/scale in-kernel; reference ragged_ops)")

@@ -17,11 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import registry, use_pallas
 
@@ -91,7 +87,7 @@ def dequantize_int8_blockwise(values, scales, shape, block_size: int = 2048,
     return x.reshape(-1)[:n].reshape(shape).astype(dtype)
 
 
-registry.register("quantizer_int8", "pallas" if _HAS_PLTPU else "xla", True)
+registry.register("quantizer_int8", "pallas", True)
 
 
 # ---------------------------------------------------------------- FP8/FP quant

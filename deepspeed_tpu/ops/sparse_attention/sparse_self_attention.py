@@ -94,12 +94,7 @@ class SparseSelfAttention:
                                 key_padding_mask_mode=self.key_padding_mask_mode)
 
 
-try:
-    from jax.experimental.pallas import tpu as _pltpu  # noqa: F401
-    _SPARSE_BACKEND = "pallas"
-except ImportError:  # pragma: no cover
-    _SPARSE_BACKEND = "xla"
-registry.register("sparse_attention", _SPARSE_BACKEND, True,
+registry.register("sparse_attention", "pallas", True,
                   "splash block-sparse kernel, sparse fwd AND bwd (dq via "
                   "forward block table, dk/dv via transposed table); "
                   "masked-dense XLA fallback via use_kernel=False")

@@ -32,11 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -116,9 +112,6 @@ def _splash_kernel(table_ref, count_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
 def _splash_fwd(q, k, v, table, counts, block, scale, interpret,
                 with_lse=False):
-    if not _HAS_PLTPU:
-        raise RuntimeError("splash attention needs jax.experimental.pallas.tpu; "
-                           "use sparse_attention(..., use_kernel=False)")
     B, H, S, D = q.shape
     nb = S // block
     A = table.shape[-1]

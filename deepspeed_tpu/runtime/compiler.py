@@ -24,110 +24,47 @@ def _reset_cache_latch() -> None:
     updates are silently ignored — entries log "cache is disabled/not
     initialized". reset_cache() clears the latch so the NEXT compile
     re-initializes against the directory just configured."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — internals moved; cache is best-effort
-        pass
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
-# the path THIS function last applied (as opposed to the user/supervisor
-# exporting JAX_COMPILATION_CACHE_DIR before launch): a later explicit
-# ``compile.cache_dir`` may override a self-applied setting, but never a
-# genuinely user-chosen cache — even one exported after a self-apply
-_SELF_APPLIED_PATH = None
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def default_cache_dir() -> str:
-    """Default persistent-cache location, OUTSIDE any repo/working tree:
-    ``$DS_TPU_COMPILE_CACHE_DIR`` if set, else
-    ``$XDG_CACHE_HOME|~/.cache``/deepspeed_tpu/xla_cache. A cwd-relative
-    default would litter project checkouts with compiled-program blobs (and
-    tempt them into version control)."""
-    env = os.environ.get("DS_TPU_COMPILE_CACHE_DIR")
-    if env:
-        return env
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "deepspeed_tpu", "xla_cache")
+def compile_cache_dir() -> str:
+    """Where compiled programs persist: ``$JAX_COMPILATION_CACHE_DIR`` when
+    the launcher set it, else ``<checkout>/.jax_cache`` (git-ignored). The
+    path is part of the cache's key, so it is never a home, temporary, pid-
+    or time-derived directory: a cache that moves never hits."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
 
 
-def configure_compile_cache(compile_config) -> Callable[[], None]:
-    """Point JAX's persistent compilation cache at ``compile.cache_dir``
-    (the autotuner's ``_enable_compile_cache`` promoted into engine init):
-    multi-restart runs skip recompiles of the engine's step programs. An
-    unset ``cache_dir`` falls back to :func:`default_cache_dir` (per-user,
-    outside the repo tree).
-
-    A pre-existing ``JAX_COMPILATION_CACHE_DIR`` env var or jax.config
-    setting always wins — the engine never redirects a cache the user (or a
-    supervisor process) already chose. (A cache this module itself applied
-    earlier does not count as user-chosen: an explicit config may replace
-    it.) The env var is also SET here so spawned child processes inherit the
-    cache. Returns an undo() restoring prior state (no-op when nothing was
-    applied).
+def configure_compile_cache(compile_config=None) -> str:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
+    — the one decision for trainer, server and ``chip_smoke.py`` alike.
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it and
+    nothing here sets another directory. Returns the directory in use.
 
     Also installs the process-wide XLA backend-compile listener
-    (``ds_xla_backend_compile_seconds``): the compile-cache entry point is
-    the one place every engine passes through before its first compile, so
-    compiles that bypass the per-key ``CompileWatch`` wrappers (model init,
-    eager ops, persistent-cache deserialization misses) are still visible.
-    Idempotent; never blocks cache configuration."""
-    global _SELF_APPLIED_PATH
-    try:
-        from ..observability.xla import install_backend_compile_listener
-        install_backend_compile_listener()
-    except Exception:  # pragma: no cover — telemetry must not break startup
-        pass
-    path = getattr(compile_config, "cache_dir", None)
-    explicit = bool(path)
-    if not path:
-        path = default_cache_dir()
+    (``ds_xla_backend_compile_seconds``): this is the one place every
+    engine passes through before its first compile, so compiles that bypass
+    the per-key ``CompileWatch`` wrappers (model init, eager ops) are still
+    visible. Idempotent."""
     import jax
-    preset = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-              or getattr(jax.config, "jax_compilation_cache_dir", None))
-    if preset and (preset != _SELF_APPLIED_PATH or not explicit):
-        return lambda: None  # user's cache wins / default already in effect
-    path = str(path)
-    prev = getattr(jax.config, "jax_compilation_cache_dir", None)
-    prev_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    prev_self = _SELF_APPLIED_PATH
+    from ..observability.xla import install_backend_compile_listener
+    install_backend_compile_listener()
+    path = compile_cache_dir()
     min_secs = getattr(compile_config, "cache_min_compile_secs", None)
-    prev_min = getattr(jax.config,
-                       "jax_persistent_cache_min_compile_time_secs", None)
-    applied = False
-    try:
+    if min_secs is not None:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(min_secs))
+    if jax.config.jax_compilation_cache_dir != path:
         os.makedirs(path, exist_ok=True)
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = path
         jax.config.update("jax_compilation_cache_dir", path)
-        if min_secs is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_secs))
         _reset_cache_latch()
-        applied = True
-        _SELF_APPLIED_PATH = path
-    except Exception as e:  # pragma: no cover — the cache is an optimization
-        logger.warning(f"persistent compile cache unavailable: {e}")
-
-    def undo() -> None:
-        global _SELF_APPLIED_PATH
-        if not applied:
-            return
-        if prev_env is None:
-            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-        else:
-            os.environ["JAX_COMPILATION_CACHE_DIR"] = prev_env
-        _SELF_APPLIED_PATH = prev_self
-        try:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            if min_secs is not None:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", prev_min)
-            _reset_cache_latch()
-        except Exception:  # pragma: no cover
-            pass
-
-    return undo
+    return path
 
 
 def disable(fn: Callable) -> Callable:

@@ -44,19 +44,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..comm.compressed import compressed_allreduce_tree
 from ..utils.logging import log_dist
 
-try:
-    from jax import shard_map as _shard_map
 
-    def _smap(f, mesh, in_specs, out_specs, axes):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          axis_names=set(axes), check_vma=False)
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _old
-
-    def _smap(f, mesh, in_specs, out_specs, axes):
-        auto = {"pipe", "data", "fsdp", "seq", "expert", "model"} - set(axes)
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False, auto=frozenset(auto))
+def _smap(f, mesh, in_specs, out_specs, axes):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(axes), check_vma=False)
 
 
 def wire_supported(engine) -> bool:

@@ -27,19 +27,10 @@ from ..zero_sharding import ZeroShardingPlan, composed_tp_zero_spec, leaf_spec
 from ...parallel.tp import path_str
 from .spmd import spmd_pipeline_1f1b, spmd_pipeline_eval
 
-try:
-    from jax import shard_map as _shard_map
 
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          axis_names={"pipe"}, check_vma=False)
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=False, auto=frozenset(
-                                  {"data", "fsdp", "seq", "expert", "model"}))
+def _smap(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names={"pipe"}, check_vma=False)
 
 
 class PipeZeroPlan(ZeroShardingPlan):

@@ -56,10 +56,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover — older jax
-    from jax.core import Literal
+from jax.extend.core import Literal
 
 from ..comm.bucketing import flatten_buckets, param_gather_bucket, plan_buckets
 from ..utils.logging import log_dist, logger

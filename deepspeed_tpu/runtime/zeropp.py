@@ -31,18 +31,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.quantizer import quantize_int8_blockwise, dequantize_int8_blockwise
 
-try:
-    from jax import shard_map as _shard_map_new
 
-    def _smap(f, mesh, in_specs, out_specs, manual):
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              axis_names=set(manual), check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _smap(f, mesh, in_specs, out_specs, manual):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=False)
+def _smap(f, mesh, in_specs, out_specs, manual):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(manual), check_vma=False)
 
 
 def _axis_size(axis_name):

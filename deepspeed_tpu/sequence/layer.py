@@ -127,35 +127,38 @@ def ulysses_flash(q, k, v, *, window: Optional[int] = None,
                   softcap: Optional[float] = None,
                   sequence_axis: str = "seq", model_axis: str = "model",
                   mesh_ctx=None, interpret: bool = False):
-    """Ulysses/TP with the Pallas flash kernel per device (module doc §3).
+    """The Pallas flash kernel per device under any mesh (module doc §3).
 
-    [b, S/sp, h/mp, d] inputs under the global mesh → all-to-all over the
-    seq axis to [b, S, h/(sp·mp), d] → causal flash over the full sequence
-    on the local head block → all-to-all back. Both axes are optional:
-    seq-only is classic Ulysses; model-only needs NO collectives (attention
-    is embarrassingly parallel over heads) but still gets the kernel, which
-    a pallas_call under plain GSPMD cannot (no auto-partitioning). Requires
-    heads divisible by sp·mp so the GQA group mapping survives the split
-    (any misaligned layout provably reduces to empty per-device KV slices,
-    so there is no third layout to fall back to). Returns ``None`` when
-    ineligible — the caller falls back to the GSPMD formulation.
+    A Mosaic kernel cannot be partitioned by GSPMD: its lowering refuses
+    ("Mosaic kernels cannot be automatically partitioned") unless EVERY
+    axis name of the mesh is manual, axes of size one included. So on every
+    mesh of more than one device — data parallel and ZeRO included — the
+    kernel runs inside a shard_map over all the mesh's axes (less those an
+    enclosing shard_map, the pipeline's, already made manual). The batch
+    dim rides the data-parallel axes (``data``, ``fsdp``: each device
+    attends its own rows, no collective); the seq axis is Ulysses:
+    [b, S/sp, h/mp, d] → all-to-all to [b, S, h/(sp·mp), d] → causal flash
+    over the full sequence on the local head block → all-to-all back; the
+    model axis needs NO collectives (attention is embarrassingly parallel
+    over heads).
+    Requires heads divisible by sp·mp so the GQA group mapping survives the
+    split (any misaligned layout provably reduces to empty per-device KV
+    slices, so there is no third layout to fall back to). Returns ``None``
+    when ineligible — the caller falls back to the GSPMD formulation.
     """
     ctx = mesh_ctx or get_mesh_context()
     sp = ctx.axis_size(sequence_axis)
     mp = ctx.axis_size(model_axis)
-    if sp == 1 and mp == 1:
+    if ctx.mesh.size == 1:
         return None
     nq, nkv = q.shape[2], k.shape[2]
     if nq % (sp * mp) or nkv % (sp * mp) or q.shape[1] % sp:
         return None  # heads/sequence must divide the manual axes
+    dp = tuple(a for a in ("data", "fsdp") if ctx.axis_size(a) > 1)
+    if dp and q.shape[0] % ctx.axis_size(dp):
+        dp = ()  # rows do not divide: every device attends the whole batch
 
     from ..ops.attention import flash_attention
-
-    manual = set()
-    if sp > 1:
-        manual.add(sequence_axis)
-    if mp > 1:
-        manual.add(model_axis)
 
     def body(q_l, k_l, v_l):
         if sp > 1:
@@ -169,14 +172,13 @@ def ulysses_flash(q, k, v, *, window: Optional[int] = None,
             out = seq_all_to_all(out, sequence_axis, 1, 2)  # [b,S/sp,h/mp,d]
         return out
 
-    spec = P(None, sequence_axis if sp > 1 else None,
-             model_axis if mp > 1 else None, None)
-    if not hasattr(jax, "shard_map"):
-        # partial-manual shard_map (axis_names=) needs the stable jax API;
-        # the older experimental ``auto=`` spelling aborts under the Pallas
-        # interpret body — signal ineligible and let the caller take the
-        # GSPMD Ulysses formulation instead
-        return None
+    outer = frozenset(jax.sharding.get_abstract_mesh().manual_axes)
+    names = frozenset(ctx.mesh.axis_names) - outer
+    if not names:
+        return body(q, k, v)  # already manual over the whole mesh
+    spec = P(tuple(a for a in dp if a in names) or None,
+             sequence_axis if sp > 1 and sequence_axis in names else None,
+             model_axis if mp > 1 and model_axis in names else None, None)
     return jax.shard_map(body, mesh=ctx.mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, axis_names=frozenset(manual),
+                         out_specs=spec, axis_names=names,
                          check_vma=False)(q, k, v)

@@ -1,7 +1,7 @@
 """Seeded property sweep of the Pallas flash kernel (interpret mode) vs the
 XLA oracle — randomized GQA ratios x window x softcap x ragged-ish shapes.
-The fixed-shape tests missed a real Mosaic GQA-bwd bug on chip (PERF_NOTES
-round 4); this sweep at least pins the MATH for every dispatchable combo so
+The fixed-shape tests missed a real Mosaic GQA-bwd bug on chip (round 4);
+this sweep at least pins the MATH for every dispatchable combo so
 silicon runs only have lowering left to prove."""
 
 import numpy as np

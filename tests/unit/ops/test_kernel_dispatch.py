@@ -277,10 +277,16 @@ def test_cache_bad_impl_entry_falls_back(monkeypatch, tmp_path):
 def test_env_dir_precedence(monkeypatch, tmp_path):
     monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path / "a"))
     assert cache_path() == str(tmp_path / "a" / "attn_dispatch.json")
+    # unset: the tracked (empty) table in the checkout — never a home
     monkeypatch.delenv("DS_TPU_ATTN_CACHE_DIR")
     monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
-    assert cache_path() == str(tmp_path / "xdg" / "deepspeed_tpu"
-                               / "attn_dispatch.json")
+    import json
+    import deepspeed_tpu.ops.autotune_cache as ac
+    tracked = os.path.join(os.path.dirname(os.path.abspath(ac.__file__)),
+                           "attn_dispatch.json")
+    assert cache_path() == tracked
+    with open(tracked) as f:
+        assert json.load(f) == {"version": 1, "entries": {}}
 
 
 def test_cache_hit_changes_dispatched_kernels(monkeypatch, tmp_path):

@@ -165,3 +165,34 @@ def test_softcap_matches_reference():
     # the cap must actually bite (differs from uncapped)
     out_u = paged_attention_reference(q, cache, 0, bt, seen, lens, page_size=ps)
     assert not np.allclose(np.asarray(out_r), np.asarray(out_u))
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_query_tiles_match_dense_reference(monkeypatch, window):
+    """A new-token run longer than one query tile: the grid's tile axis,
+    the per-tile causal page skip and the window skip must give what the
+    whole-run block gave. The VMEM budget is shrunk so that these small
+    shapes split into four tiles of 8."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    S, N, KV, G, D, ps, n_pages, B = 3, 32, 2, 2, 32, 16, 32, 8
+    per_row = D * 4 + 2 * 128 * 4 + 4 * D * 4
+    monkeypatch.setattr(pa, "_RESIDENT_VMEM_BYTES", 8 * KV * G * per_row)
+    assert pa._query_tile(N, KV * G, D, 4) == 8
+    rng = np.random.default_rng(11)
+    q, cache, bt, seen, lens = _setup(rng, S, N, KV, G, D, ps, n_pages, B,
+                                      seen=[40, 0, 70], n_new=[32, 19, 1])
+    out_k = paged_attention(q, cache, 1, bt, seen, lens, page_size=ps,
+                            window=window, interpret=INTERP)
+    out_r = paged_attention_reference(q, cache, 1, bt, seen, lens,
+                                      page_size=ps, window=window)
+    n_new = np.asarray(lens - seen)
+    for s in range(S):  # rows past n_new are padding: unspecified
+        np.testing.assert_allclose(np.asarray(out_k)[s, :n_new[s]],
+                                   np.asarray(out_r)[s, :n_new[s]], atol=2e-5)
+
+
+def test_query_tile_bounds_resident_vmem_at_7b_widths():
+    from deepspeed_tpu.ops.paged_attention import _query_tile
+    assert _query_tile(512, 32, 128, 2) == 128  # what the v5e compiler takes
+    assert _query_tile(128, 32, 128, 2) == 128
+    assert _query_tile(1, 32, 128, 2) == 1

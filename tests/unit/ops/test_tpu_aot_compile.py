@@ -1,0 +1,111 @@
+"""The main path's Pallas kernels, compiled for a described (not attached)
+TPU v5e at Mistral-7B widths.
+
+The TPU's compiler is installed with jax and compiles for a topology that
+is only described, so these tests guard what interpret mode cannot see — a
+kernel that asks for more VMEM than the chip has, or a block the tiling
+refuses — at no chip time. Nothing runs; numerics are ``chip_smoke.py``'s.
+
+This is the only file that describes a topology, and it does so inside a
+fixture: one process at a time may load the TPU's library, so the call must
+not happen while any module is imported (every xdist worker imports every
+test file), and the compile stays in this process.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.attention import flash_attention
+from deepspeed_tpu.ops.normalization import rms_norm
+from deepspeed_tpu.ops.paged_attention import paged_attention
+
+# Mistral-7B-v0.1: 32 query / 8 KV heads of 128, hidden 4096, window 4096
+H, KV, D, HIDDEN, WINDOW, PAGE = 32, 8, 128, 4096, 4096, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A program compiled for a described chip is written to the persistent
+    cache but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n_new,window,int8", [
+    (1, None, False),
+    (128, None, False),
+    (512, None, False),      # 24 MB of scoped VMEM before the query tile
+    (512, WINDOW, False),
+    (512, None, True),
+])
+def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
+                                  int8):
+    sds = functools.partial(_sds, sharding=one_chip)
+    seqs, pages, blocks = 2, 1024, 128
+    args = [sds((seqs, n_new, H, D), jnp.bfloat16),
+            sds((4, pages * PAGE, KV * D), jnp.int8 if int8 else jnp.bfloat16),
+            sds((), jnp.int32), sds((seqs, blocks), jnp.int32),
+            sds((seqs, ), jnp.int32), sds((seqs, ), jnp.int32)]
+    kern = functools.partial(paged_attention, page_size=PAGE, window=window)
+    if int8:
+        args.append(sds((4, pages * PAGE, KV), jnp.bfloat16))
+        _compile(lambda *a: kern(*a[:-1], cache_scales=a[-1]), *args)
+    else:
+        _compile(kern, *args)
+
+
+@pytest.mark.parametrize("seq,window", [(2048, None), (4096, WINDOW)])
+def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
+                                          window):
+    def sds(heads):
+        return _sds((1, seq, heads, D), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              force_pallas=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(H), sds(KV), sds(KV))
+    # forward, dq, and dk+dv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_rms_norm_compiles(one_chip, no_compile_cache):
+    x = _sds((1024, HIDDEN), jnp.bfloat16, one_chip)
+    w = _sds((HIDDEN, ), jnp.bfloat16, one_chip)
+    _compile(functools.partial(rms_norm, force_pallas=True), x, w)

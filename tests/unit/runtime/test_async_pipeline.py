@@ -381,34 +381,42 @@ def test_fused_partition_context_room_and_degenerate_cases():
 # persistent compile cache
 # ---------------------------------------------------------------------------
 
-def test_configure_compile_cache_sets_and_undoes(tmp_path, monkeypatch):
-    from deepspeed_tpu.runtime.compiler import configure_compile_cache
+@pytest.fixture
+def _restore_jax_cache_dir():
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_env_set_is_used_and_unchanged(tmp_path, monkeypatch,
+                                                     _restore_jax_cache_dir):
+    """The launcher's ``JAX_COMPILATION_CACHE_DIR`` is the cache, for the
+    trainer and the server alike; nothing in code sets another."""
+    from deepspeed_tpu.runtime import compiler
+    chosen = str(tmp_path / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", chosen)
+    jax.config.update("jax_compilation_cache_dir", chosen)  # as jax reads it
+    assert compiler.configure_compile_cache() == chosen
+    assert compiler.configure_compile_cache(
+        types.SimpleNamespace(cache_min_compile_secs=None)) == chosen
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == chosen
+    assert jax.config.jax_compilation_cache_dir == chosen
+    assert not os.path.exists(compiler.compile_cache_dir() + "_other")
+
+
+def test_compile_cache_env_unset_is_the_checkout(monkeypatch,
+                                                 _restore_jax_cache_dir):
+    """Unset: ``<checkout>/.jax_cache`` — a fixed path (it is part of the
+    cache key), never a home, temporary, pid- or time-derived one — and the
+    environment stays unset."""
+    import deepspeed_tpu
+    from deepspeed_tpu.runtime import compiler
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    cache = tmp_path / "xla_cache"
-    cfg = types.SimpleNamespace(cache_dir=str(cache),
-                                cache_min_compile_secs=None)
-    undo = configure_compile_cache(cfg)
-    try:
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(cache)
-        assert jax.config.jax_compilation_cache_dir == str(cache)
-        assert cache.is_dir()
-    finally:
-        undo()
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(deepspeed_tpu.__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert compiler.compile_cache_dir() == want
+    assert compiler.configure_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
     assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
-    assert jax.config.jax_compilation_cache_dir != str(cache)
-
-
-def test_configure_compile_cache_respects_existing(tmp_path, monkeypatch):
-    from deepspeed_tpu.runtime.compiler import configure_compile_cache
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/user/chose/this")
-    cfg = types.SimpleNamespace(cache_dir=str(tmp_path / "mine"),
-                                cache_min_compile_secs=None)
-    undo = configure_compile_cache(cfg)
-    undo()
-    # the user's setting was never touched and the engine's dir not created
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/user/chose/this"
-    assert not (tmp_path / "mine").exists()
-    # unset cache_dir: a clean no-op
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    assert configure_compile_cache(
-        types.SimpleNamespace(cache_dir=None))() is None

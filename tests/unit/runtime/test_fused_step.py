@@ -152,7 +152,7 @@ def test_gas_fused_respects_zero_and_scaling():
 
 def test_steps_compile_once_across_run():
     """Per-step recompilation is the classic silent 10x step-time killer
-    (every jit signature change costs a fresh XLA compile over the relay).
+    (every jit signature change costs a fresh XLA compile).
     Both training paths must hit their jit caches on every step after the
     first: loop-carried state (params/opt_state/scale) keeps ONE sharding
     + aval signature, fresh same-shape batches keep one input aval."""

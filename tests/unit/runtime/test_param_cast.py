@@ -72,8 +72,8 @@ def test_param_cast_model_matches_engine_cast():
 def test_param_cast_model_no_stacked_convert():
     """Under remat (the realistic bench config) the compiled fused step must
     contain NO whole-stacked bf16 parameter buffer at all — no
-    `bf16[n_scan, ...]` convert temp (the round-4 OOM pattern,
-    .perf/bench_fast_r4_0731T1228.out) and no bf16 stacked residual.
+    `bf16[n_scan, ...]` convert temp (the round-4 OOM pattern) and no
+    bf16 stacked residual.
 
     Three pieces make this structural: use-site casts (param_cast="model"),
     the optimization_barrier in _use_cast (stops XLA's
