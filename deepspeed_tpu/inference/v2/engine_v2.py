@@ -18,7 +18,7 @@ import numpy as np
 import jax
 
 from ...models.llama import LlamaConfig, init_llama
-from ...observability import get_registry
+from ...observability import get_registry, get_tracer
 from ...utils.fault_injection import InjectedFault, get_fault_injector
 from .config_v2 import RaggedInferenceEngineConfig
 from .model import RaggedLlamaModel
@@ -101,6 +101,7 @@ class _InFlightWave:
     new_keys: object       # lazy [S, 2] advanced PRNG keys (sampled waves)
     n_steps: int
     sampled: bool
+    ctx_tokens: int = 0    # sum of the rows' context lengths at dispatch
 
 
 @dataclass
@@ -116,6 +117,7 @@ class _InFlightSpecWave:
     dlen: object           # lazy [n_steps, S] per-window draft lengths
     new_keys: object       # lazy [S, 2] advanced keys (None when greedy)
     n_steps: int
+    ctx_tokens: int = 0    # sum of the rows' context lengths at dispatch
 
 
 _FF_KEY = None
@@ -186,6 +188,10 @@ class InferenceEngineV2:
         # adapter-free engine). Slot assignment is per-uid and lives in the
         # registry's pin table; KV and scheduling accounting never see it.
         self._adapters = getattr(model, "_adapters", None)
+        # the span API's tracer (observability/tracing.py) for this engine's
+        # halves of a tick: the process-wide one until a ServingScheduler
+        # hands over its own (NO_TRACER with its observability off)
+        self.tracer = get_tracer()
 
     # ---- multi-LoRA (inference/v2/adapters) ----
 
@@ -259,6 +265,39 @@ class InferenceEngineV2:
         place)."""
         batch_uids = list(batch_uids)
         _fire_request_poison(batch_uids)
+        with self.tracer.scope("ds.tick.assemble", rows=len(batch_uids)):
+            batch = self._assemble_put(batch_uids, batch_tokens, do_checks,
+                                       adopt_prefix)
+        t0 = time.monotonic()
+        with self.tracer.scope("ds.tick.dispatch", rows=len(batch_uids)):
+            logits = self._model.forward(
+                batch, window_logits=window_logits,
+                adapter_slots=self._adapter_slot_rows(
+                    batch_uids, batch.q_tok_idx.shape[0]))
+        _put_seconds.record(time.monotonic() - t0)
+
+        pc = self._state_manager.prefix_cache
+        for uid in batch_uids:
+            seq = self._state_manager.get_sequence(uid)
+            seq.post_forward()
+            # sequences whose feed carried draft tokens defer registration:
+            # the caller rolls back rejections (history AND pending) and
+            # then calls _register_pending itself
+            if pc is not None and uid not in defer_register:
+                self._register_pending(seq)
+            if not window_logits:
+                # draft steps also defer the trailing-window KV free: seen
+                # is inflated by unverified drafts here, and a block freed
+                # against the inflated window could still be needed after
+                # rollback (free is irreversible — the caller frees once
+                # seen is truthful)
+                self._model.maybe_free_kv(seq)
+        return logits
+
+    def _assemble_put(self, batch_uids, batch_tokens, do_checks: bool,
+                      adopt_prefix: bool):
+        """The host half of :meth:`put` before the dispatch: feasibility,
+        prefix adoption, KV allocation and the finalized ``RaggedBatch``."""
         batch_tokens = [np.asarray(t, dtype=np.int32).reshape(-1) for t in batch_tokens]
 
         if do_checks:
@@ -331,32 +370,9 @@ class InferenceEngineV2:
             host_seq_desc.pre_forward(tokens.size)
             self._batch.insert_sequence(host_seq_desc, tokens, do_checks=do_checks)
 
-        batch = self._batch.finalize(
+        return self._batch.finalize(
             total_slots=self._state_manager.kv_cache.num_blocks *
             self._state_manager.kv_cache.block_size)
-        t0 = time.monotonic()
-        logits = self._model.forward(
-            batch, window_logits=window_logits,
-            adapter_slots=self._adapter_slot_rows(
-                batch_uids, batch.q_tok_idx.shape[0]))
-        _put_seconds.record(time.monotonic() - t0)
-
-        for uid in batch_uids:
-            seq = self._state_manager.get_sequence(uid)
-            seq.post_forward()
-            # sequences whose feed carried draft tokens defer registration:
-            # the caller rolls back rejections (history AND pending) and
-            # then calls _register_pending itself
-            if pc is not None and uid not in defer_register:
-                self._register_pending(seq)
-            if not window_logits:
-                # draft steps also defer the trailing-window KV free: seen
-                # is inflated by unverified drafts here, and a block freed
-                # against the inflated window could still be needed after
-                # rollback (free is irreversible — the caller frees once
-                # seen is truthful)
-                self._model.maybe_free_kv(seq)
-        return logits
 
     def score(self, batch_uids: Iterable[int], batch_tokens: Iterable,
               flush: bool = True):
@@ -977,65 +993,67 @@ class InferenceEngineV2:
         t0 = time.monotonic()
         batch_uids = list(batch_uids)
         _fire_request_poison(batch_uids)
-        seqs = []
-        for uid in batch_uids:
-            seq = self._state_manager.get_sequence(uid)
-            if seq is None or seq.seen_tokens == 0:
-                raise ValueError(f"fused_decode_steps: uid {uid} is not a "
-                                 "live prefilled sequence")
-            seqs.append(seq)
-        if len(seqs) > self._config.state_manager.max_ragged_sequence_count:
-            raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-        sm = self._config.state_manager
-        # feasibility before ANY allocation: the whole wave must fit —
-        # get_kv_requirements is the allocator's own arithmetic
-        free = self._state_manager.free_blocks
-        for seq in seqs:
-            if seq.seen_tokens + n_steps > sm.max_context:
-                raise SchedulingError(SchedulingResult.SequenceTokenLimitExceeded)
-            n_fit, req = self._model.get_kv_requirements(seq, n_steps, free)
-            if n_fit != n_steps:
-                raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-            free -= req
-        for seq in seqs:
-            self._model.maybe_allocate_kv(seq, n_steps)
+        with self.tracer.scope("ds.tick.assemble", rows=len(batch_uids)):
+            seqs = []
+            for uid in batch_uids:
+                seq = self._state_manager.get_sequence(uid)
+                if seq is None or seq.seen_tokens == 0:
+                    raise ValueError(f"fused_decode_steps: uid {uid} is not a "
+                                     "live prefilled sequence")
+                seqs.append(seq)
+            if len(seqs) > self._config.state_manager.max_ragged_sequence_count:
+                raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+            sm = self._config.state_manager
+            # feasibility before ANY allocation: the whole wave must fit —
+            # get_kv_requirements is the allocator's own arithmetic
+            free = self._state_manager.free_blocks
+            for seq in seqs:
+                if seq.seen_tokens + n_steps > sm.max_context:
+                    raise SchedulingError(SchedulingResult.SequenceTokenLimitExceeded)
+                n_fit, req = self._model.get_kv_requirements(seq, n_steps, free)
+                if n_fit != n_steps:
+                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                free -= req
+            for seq in seqs:
+                self._model.maybe_allocate_kv(seq, n_steps)
 
-        from .ragged.ragged_wrapper import _bucket
-        S = _bucket(len(seqs), floor=1)
-        B = _bucket(max(s.cur_allocated_blocks for s in seqs), floor=1)
-        tokens = np.zeros(S, np.int32)
-        seq_lens = np.zeros(S, np.int32)
-        liv = np.zeros(S, np.int32)
-        block_table = np.zeros((S, B), np.int32)
-        for i, (seq, t) in enumerate(zip(seqs, last_tokens)):
-            tokens[i] = int(t)
-            seq_lens[i] = seq.seen_tokens
-            liv[i] = 1
-            block_table[i] = seq.block_table(B)
-        aslots = self._adapter_slot_rows(batch_uids, S)
+            from .ragged.ragged_wrapper import _bucket
+            S = _bucket(len(seqs), floor=1)
+            B = _bucket(max(s.cur_allocated_blocks for s in seqs), floor=1)
+            tokens = np.zeros(S, np.int32)
+            seq_lens = np.zeros(S, np.int32)
+            liv = np.zeros(S, np.int32)
+            block_table = np.zeros((S, B), np.int32)
+            for i, (seq, t) in enumerate(zip(seqs, last_tokens)):
+                tokens[i] = int(t)
+                seq_lens[i] = seq.seen_tokens
+                liv[i] = 1
+                block_table[i] = seq.block_table(B)
+            aslots = self._adapter_slot_rows(batch_uids, S)
         lps = new_keys = None
-        if specs is None:
-            out = self._model.fused_decode(tokens, seq_lens, liv, block_table,
-                                           n_steps, fetch=False,
-                                           adapter_slots=aslots)  # [K, S]
-        else:
-            V = int(self._model.config.vocab_size)
-            use_pen, use_eos, want_lp = self._spec_statics(specs)
-            temps, top_ks, top_ps, pens, eos, keys, mask = self._spec_arrays(
-                batch_uids, specs, S, V, use_pen)
-            n_out = np.zeros(S, np.int32)
-            min_new = np.zeros(S, np.int32)
-            for i, s in enumerate(specs):
-                n_out[i] = s.n_out
-                min_new[i] = s.min_new
-            out, lps, new_keys = self._model.fused_decode(
-                tokens, seq_lens, liv, block_table, n_steps,
-                sampling=dict(keys=keys, temps=temps, top_ks=top_ks,
-                              top_ps=top_ps, penalties=pens, eos_ids=eos,
-                              n_out=n_out, min_new=min_new, seen_mask=mask,
-                              want_logprobs=want_lp, use_penalty=use_pen,
-                              use_eos_mask=use_eos),
-                fetch=False, adapter_slots=aslots)
+        with self.tracer.scope("ds.tick.dispatch", rows=len(batch_uids)):
+            if specs is None:
+                out = self._model.fused_decode(tokens, seq_lens, liv, block_table,
+                                               n_steps, fetch=False,
+                                               adapter_slots=aslots)  # [K, S]
+            else:
+                V = int(self._model.config.vocab_size)
+                use_pen, use_eos, want_lp = self._spec_statics(specs)
+                temps, top_ks, top_ps, pens, eos, keys, mask = self._spec_arrays(
+                    batch_uids, specs, S, V, use_pen)
+                n_out = np.zeros(S, np.int32)
+                min_new = np.zeros(S, np.int32)
+                for i, s in enumerate(specs):
+                    n_out[i] = s.n_out
+                    min_new[i] = s.min_new
+                out, lps, new_keys = self._model.fused_decode(
+                    tokens, seq_lens, liv, block_table, n_steps,
+                    sampling=dict(keys=keys, temps=temps, top_ks=top_ks,
+                                  top_ps=top_ps, penalties=pens, eos_ids=eos,
+                                  n_out=n_out, min_new=min_new, seen_mask=mask,
+                                  want_logprobs=want_lp, use_penalty=use_pen,
+                                  use_eos_mask=use_eos),
+                    fetch=False, adapter_slots=aslots)
         for seq in seqs:
             seq.pre_forward(n_steps)
             seq.post_forward()
@@ -1043,7 +1061,8 @@ class InferenceEngineV2:
         _dispatches_total.inc()
         return _InFlightWave(uids=batch_uids, seqs=seqs, tokens=tokens,
                              out=out, lps=lps, new_keys=new_keys,
-                             n_steps=n_steps, sampled=specs is not None)
+                             n_steps=n_steps, sampled=specs is not None,
+                             ctx_tokens=int(seq_lens.sum()))
 
     def fused_decode_harvest(self, wave: "_InFlightWave"):
         """FETCH half of :meth:`fused_decode_steps`: block on the wave's
@@ -1054,15 +1073,16 @@ class InferenceEngineV2:
         t0 = time.monotonic()
         n, n_steps = len(wave.seqs), wave.n_steps
         lps = None
-        if wave.sampled:
-            out, lps, new_keys = jax.device_get(
-                (wave.out, wave.lps, wave.new_keys))
-            for i, u in enumerate(wave.uids):
-                self._sample_keys[u] = np.asarray(new_keys[i], np.uint32)
-            lps = np.asarray(lps)[:, :n].T  # [n_seqs, K]
-        else:
-            out = jax.device_get(wave.out)
-        out = np.asarray(out)[:, :n].T  # [n_seqs, K]
+        with self.tracer.scope("ds.tick.harvest", rows=n):
+            if wave.sampled:
+                out, lps, new_keys = jax.device_get(
+                    (wave.out, wave.lps, wave.new_keys))
+                for i, u in enumerate(wave.uids):
+                    self._sample_keys[u] = np.asarray(new_keys[i], np.uint32)
+                lps = np.asarray(lps)[:, :n].T  # [n_seqs, K]
+            else:
+                out = jax.device_get(wave.out)
+            out = np.asarray(out)[:, :n].T  # [n_seqs, K]
 
         pc = self._state_manager.prefix_cache
         if pc is not None:
@@ -1130,81 +1150,84 @@ class InferenceEngineV2:
         t0 = time.monotonic()
         batch_uids = list(batch_uids)
         _fire_request_poison(batch_uids)
-        d = max(1, int(num_draft_tokens))
-        scfg = getattr(self._config, "sampling", None)
-        max_ngram = int(scfg.spec_max_ngram) if scfg is not None else 8
-        if draft_ngram > max_ngram:
-            raise ValueError(f"draft_ngram {draft_ngram} exceeds "
-                             f"spec_max_ngram {max_ngram}")
-        W = self.spec_ring_window(d)
-        seqs = []
-        for uid in batch_uids:
-            seq = self._state_manager.get_sequence(uid)
-            if seq is None or seq.seen_tokens == 0:
-                raise ValueError(f"fused_spec_decode_steps: uid {uid} is "
-                                 "not a live prefilled sequence")
-            seqs.append(seq)
-        if len(seqs) > self._config.state_manager.max_ragged_sequence_count:
-            raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-        sm = self._config.state_manager
-        worst = n_steps * (1 + d)
-        free = self._state_manager.free_blocks
-        for seq in seqs:
-            if seq.seen_tokens + worst > sm.max_context:
-                raise SchedulingError(
-                    SchedulingResult.SequenceTokenLimitExceeded)
-            n_fit, req = self._model.get_kv_requirements(seq, worst, free)
-            if n_fit != worst:
-                raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-            free -= req
-        for seq in seqs:
-            self._model.maybe_allocate_kv(seq, worst)
+        with self.tracer.scope("ds.tick.assemble", rows=len(batch_uids)):
+            d = max(1, int(num_draft_tokens))
+            scfg = getattr(self._config, "sampling", None)
+            max_ngram = int(scfg.spec_max_ngram) if scfg is not None else 8
+            if draft_ngram > max_ngram:
+                raise ValueError(f"draft_ngram {draft_ngram} exceeds "
+                                 f"spec_max_ngram {max_ngram}")
+            W = self.spec_ring_window(d)
+            seqs = []
+            for uid in batch_uids:
+                seq = self._state_manager.get_sequence(uid)
+                if seq is None or seq.seen_tokens == 0:
+                    raise ValueError(f"fused_spec_decode_steps: uid {uid} is "
+                                     "not a live prefilled sequence")
+                seqs.append(seq)
+            if len(seqs) > self._config.state_manager.max_ragged_sequence_count:
+                raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+            sm = self._config.state_manager
+            worst = n_steps * (1 + d)
+            free = self._state_manager.free_blocks
+            for seq in seqs:
+                if seq.seen_tokens + worst > sm.max_context:
+                    raise SchedulingError(
+                        SchedulingResult.SequenceTokenLimitExceeded)
+                n_fit, req = self._model.get_kv_requirements(seq, worst, free)
+                if n_fit != worst:
+                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                free -= req
+            for seq in seqs:
+                self._model.maybe_allocate_kv(seq, worst)
 
-        from .ragged.ragged_wrapper import _bucket
-        S = _bucket(len(seqs), floor=1)
-        B = _bucket(max(s.cur_allocated_blocks for s in seqs), floor=1)
-        tokens = np.zeros(S, np.int32)
-        seq_lens = np.zeros(S, np.int32)
-        liv = np.zeros(S, np.int32)
-        block_table = np.zeros((S, B), np.int32)
-        hist = np.zeros((S, W), np.int32)
-        hist_len = np.zeros(S, np.int32)
-        ngrams = np.zeros(S, np.int32)
-        max_d = np.zeros(S, np.int32)
-        for i, (seq, h) in enumerate(zip(seqs, histories)):
-            tokens[i] = int(h[-1])
-            seq_lens[i] = seq.seen_tokens
-            liv[i] = 1
-            block_table[i] = seq.block_table(B)
-            L = len(h)
-            tail = np.asarray(h[max(0, L - W):], np.int32)
-            p = np.arange(L - tail.size, L)
-            hist[i, p % W] = tail  # logical position p lives in slot p % W
-            hist_len[i] = L
-            ngrams[i] = int(draft_ngram)
-            max_d[i] = d
-        sampling = None
-        if specs is not None:
-            temps = np.zeros(S, np.float32)
-            top_ks = np.zeros(S, np.int32)
-            top_ps = np.ones(S, np.float32)
-            keys = np.zeros((S, 2), np.uint32)
-            for i, (u, s) in enumerate(zip(batch_uids, specs)):
-                temps[i] = s.temperature
-                top_ks[i] = s.top_k
-                top_ps[i] = s.top_p
-                keys[i] = self._sampler_key(u, s.seed)
-            sampling = dict(keys=keys, temps=temps, top_ks=top_ks,
-                            top_ps=top_ps)
-        out, n_emit, dlen, new_keys = self._model.fused_spec_decode(
-            tokens, seq_lens, liv, block_table, hist, hist_len, ngrams,
-            max_d, n_steps, d, max_ngram, sampling=sampling, fetch=False,
-            adapter_slots=self._adapter_slot_rows(batch_uids, S))
+            from .ragged.ragged_wrapper import _bucket
+            S = _bucket(len(seqs), floor=1)
+            B = _bucket(max(s.cur_allocated_blocks for s in seqs), floor=1)
+            tokens = np.zeros(S, np.int32)
+            seq_lens = np.zeros(S, np.int32)
+            liv = np.zeros(S, np.int32)
+            block_table = np.zeros((S, B), np.int32)
+            hist = np.zeros((S, W), np.int32)
+            hist_len = np.zeros(S, np.int32)
+            ngrams = np.zeros(S, np.int32)
+            max_d = np.zeros(S, np.int32)
+            for i, (seq, h) in enumerate(zip(seqs, histories)):
+                tokens[i] = int(h[-1])
+                seq_lens[i] = seq.seen_tokens
+                liv[i] = 1
+                block_table[i] = seq.block_table(B)
+                L = len(h)
+                tail = np.asarray(h[max(0, L - W):], np.int32)
+                p = np.arange(L - tail.size, L)
+                hist[i, p % W] = tail  # logical position p lives in slot p % W
+                hist_len[i] = L
+                ngrams[i] = int(draft_ngram)
+                max_d[i] = d
+            sampling = None
+            if specs is not None:
+                temps = np.zeros(S, np.float32)
+                top_ks = np.zeros(S, np.int32)
+                top_ps = np.ones(S, np.float32)
+                keys = np.zeros((S, 2), np.uint32)
+                for i, (u, s) in enumerate(zip(batch_uids, specs)):
+                    temps[i] = s.temperature
+                    top_ks[i] = s.top_k
+                    top_ps[i] = s.top_p
+                    keys[i] = self._sampler_key(u, s.seed)
+                sampling = dict(keys=keys, temps=temps, top_ks=top_ks,
+                                top_ps=top_ps)
+        with self.tracer.scope("ds.tick.dispatch", rows=len(batch_uids)):
+            out, n_emit, dlen, new_keys = self._model.fused_spec_decode(
+                tokens, seq_lens, liv, block_table, hist, hist_len, ngrams,
+                max_d, n_steps, d, max_ngram, sampling=sampling, fetch=False,
+                adapter_slots=self._adapter_slot_rows(batch_uids, S))
         _dispatch_seconds.record(time.monotonic() - t0)
         _dispatches_total.inc()
         return _InFlightSpecWave(uids=batch_uids, seqs=seqs, tokens=tokens,
                                  out=out, n_emit=n_emit, dlen=dlen,
-                                 new_keys=new_keys, n_steps=n_steps)
+                                 new_keys=new_keys, n_steps=n_steps,
+                                 ctx_tokens=int(seq_lens.sum()))
 
     def fused_spec_decode_harvest(self, wave: "_InFlightSpecWave"):
         """FETCH half of :meth:`fused_spec_decode_steps`: block on the
@@ -1213,14 +1236,15 @@ class InferenceEngineV2:
         ``(tokens, drafted, accepted)``."""
         t0 = time.monotonic()
         n_steps, tokens, seqs = wave.n_steps, wave.tokens, wave.seqs
-        if wave.new_keys is not None:
-            out, n_emit, dlen, new_keys = jax.device_get(
-                (wave.out, wave.n_emit, wave.dlen, wave.new_keys))
-            for i, u in enumerate(wave.uids):
-                self._sample_keys[u] = np.asarray(new_keys[i], np.uint32)
-        else:
-            out, n_emit, dlen = jax.device_get(
-                (wave.out, wave.n_emit, wave.dlen))
+        with self.tracer.scope("ds.tick.harvest", rows=len(seqs)):
+            if wave.new_keys is not None:
+                out, n_emit, dlen, new_keys = jax.device_get(
+                    (wave.out, wave.n_emit, wave.dlen, wave.new_keys))
+                for i, u in enumerate(wave.uids):
+                    self._sample_keys[u] = np.asarray(new_keys[i], np.uint32)
+            else:
+                out, n_emit, dlen = jax.device_get(
+                    (wave.out, wave.n_emit, wave.dlen))
 
         pc = self._state_manager.prefix_cache
         toks_lists, drafted, accepted = [], [], []

@@ -19,7 +19,6 @@ logits_gather). TPU design:
 
 import functools
 import re
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -64,6 +63,16 @@ def _serving_compile_watch():
         from ...observability.xla import CompileWatch
         _SERVE_COMPILE_WATCH = CompileWatch(registry=_obs)
     return _SERVE_COMPILE_WATCH
+
+
+def _named_program(name: str, fn, **statics):
+    """``fn`` with ``statics`` bound, under a ``__name__``: ``jax.jit`` names
+    a program's module after it (``jit_<name>`` on the device trace's "XLA
+    Modules" line), and a ``functools.partial`` has none (``jit__unknown``)."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs, **statics)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _compile_key_str(key) -> str:
@@ -637,16 +646,17 @@ class RaggedLlamaModel:
             kw = ({"out_shardings": (None, jax.tree_util.tree_map(
                        lambda a: a.sharding, kv.cache))}
                   if self._mesh_ctx is not None else {})
-            fn = jax.jit(partial(_ragged_forward, config=self.config,
-                                 block_size=self.kv_block_size,
-                                 attn_backend=self.attn_backend,
-                                 tp_size=self.tp_size,
-                                 kv_pad=self._kv_pad,
-                                 tp_wire=self._wire_static,
-                                 wire_block=self._wire_block,
-                                 window_logits=window_logits,
-                                 mesh=(self._mesh_ctx.mesh
-                                       if self._mesh_ctx is not None else None)),
+            fn = jax.jit(_named_program(
+                "ds_ragged_forward", _ragged_forward, config=self.config,
+                block_size=self.kv_block_size,
+                attn_backend=self.attn_backend,
+                tp_size=self.tp_size,
+                kv_pad=self._kv_pad,
+                tp_wire=self._wire_static,
+                wire_block=self._wire_block,
+                window_logits=window_logits,
+                mesh=(self._mesh_ctx.mesh
+                      if self._mesh_ctx is not None else None)),
                          donate_argnums=(1, ), **kw)
             fn = _serving_compile_watch().wrap(fn, _compile_key_str(key))
             self._fwd_cache[key] = fn
@@ -686,7 +696,8 @@ class RaggedLlamaModel:
             kw = ({"out_shardings": jax.tree_util.tree_map(
                        lambda a: a.sharding, kv.cache)}
                   if self._mesh_ctx is not None else {})
-            fn = jax.jit(partial(_cow, block_size=self.kv_block_size),
+            fn = jax.jit(_named_program("ds_kv_cow", _cow,
+                                        block_size=self.kv_block_size),
                          donate_argnums=(0, ), **kw)
             fn = _serving_compile_watch().wrap(fn, "cow_copy_block")
             self._fwd_cache["cow_copy"] = fn
@@ -751,19 +762,20 @@ class RaggedLlamaModel:
                 kw = {"out_shardings": out_sh}
             else:
                 kw = {}
-            fn = jax.jit(partial(_fused_decode_loop, config=self.config,
-                                 block_size=self.kv_block_size,
-                                 attn_backend=self.attn_backend,
-                                 tp_size=self.tp_size,
-                                 kv_pad=self._kv_pad,
-                                 tp_wire=self._wire_static,
-                                 wire_block=self._wire_block,
-                                 total_slots=total_slots,
-                                 n_steps=n_steps,
-                                 sample=sampling is not None,
-                                 **statics,
-                                 mesh=(self._mesh_ctx.mesh
-                                       if self._mesh_ctx is not None else None)),
+            fn = jax.jit(_named_program(
+                "ds_fused_decode", _fused_decode_loop, config=self.config,
+                block_size=self.kv_block_size,
+                attn_backend=self.attn_backend,
+                tp_size=self.tp_size,
+                kv_pad=self._kv_pad,
+                tp_wire=self._wire_static,
+                wire_block=self._wire_block,
+                total_slots=total_slots,
+                n_steps=n_steps,
+                sample=sampling is not None,
+                **statics,
+                mesh=(self._mesh_ctx.mesh
+                      if self._mesh_ctx is not None else None)),
                          donate_argnums=(1, ), **kw)
             fn = _serving_compile_watch().wrap(fn, _compile_key_str(key))
             self._fwd_cache[key] = fn
@@ -842,20 +854,22 @@ class RaggedLlamaModel:
                 kw = {"out_shardings": out_sh}
             else:
                 kw = {}
-            fn = jax.jit(partial(_fused_spec_decode_loop, config=self.config,
-                                 block_size=self.kv_block_size,
-                                 attn_backend=self.attn_backend,
-                                 tp_size=self.tp_size,
-                                 kv_pad=self._kv_pad,
-                                 tp_wire=self._wire_static,
-                                 wire_block=self._wire_block,
-                                 total_slots=total_slots,
-                                 n_steps=n_steps,
-                                 d=draft_width,
-                                 max_ngram=max_ngram,
-                                 sample=sampling is not None,
-                                 mesh=(self._mesh_ctx.mesh
-                                       if self._mesh_ctx is not None else None)),
+            fn = jax.jit(_named_program(
+                "ds_fused_spec_decode", _fused_spec_decode_loop,
+                config=self.config,
+                block_size=self.kv_block_size,
+                attn_backend=self.attn_backend,
+                tp_size=self.tp_size,
+                kv_pad=self._kv_pad,
+                tp_wire=self._wire_static,
+                wire_block=self._wire_block,
+                total_slots=total_slots,
+                n_steps=n_steps,
+                d=draft_width,
+                max_ngram=max_ngram,
+                sample=sampling is not None,
+                mesh=(self._mesh_ctx.mesh
+                      if self._mesh_ctx is not None else None)),
                          donate_argnums=(1, ), **kw)
             fn = _serving_compile_watch().wrap(fn, _compile_key_str(key))
             self._fwd_cache[key] = fn

@@ -70,6 +70,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from ...observability import ProfilerBusy, ServingInstruments
+from ...observability.tracing import NO_TRACER
 from ...utils.fault_injection import InjectedFault, get_fault_injector
 from ...utils.logging import logger
 from ...utils.retry import RetriesExhausted, retry_with_backoff
@@ -444,6 +445,14 @@ class ServingScheduler:
                 profile_max_seconds=self._ocfg.profile_max_seconds)
         else:
             self._obs = None
+        # the span API (observability/tracing.py): the scheduler's ticks and
+        # the engine's halves of them record into the same tracer, the one
+        # GET /debug/trace renders; off with the observability block
+        self._tracer = (self._obs.tracer if self._obs is not None
+                        else NO_TRACER)
+        engine.tracer = self._tracer
+        if disagg is not None:
+            disagg.prefill_engine.tracer = self._tracer
         sm = engine._config.state_manager
         self._max_batch_tokens = sm.max_ragged_batch_size
         self._token_budget = min(token_budget or self._max_batch_tokens,
@@ -1187,7 +1196,8 @@ class ServingScheduler:
                         self._engine.free_blocks,
                         tr["fused_tokens"], tr["decode_tokens"])
                 if not progressed:
-                    self._wake.wait(self._idle_wait)
+                    with self._tracer.scope("ds.tick.idle_wait"):
+                        self._wake.wait(self._idle_wait)
                     self._wake.clear()
         except BaseException as e:  # noqa: BLE001 — loop death must not
             crash = e               # silently hang every blocked caller
@@ -1234,21 +1244,23 @@ class ServingScheduler:
                 # the tick retry AND the quarantine bisect) — in-process
                 # tests then replay the journal over the same engine
                 raise ServingCrash("injected daemon crash")
-        with self._lock:
-            if self._inbox:
-                self._waiting.extend(self._inbox)
-                self._inbox = []
+        with self._tracer.scope("ds.tick.admit"):
+            with self._lock:
+                if self._inbox:
+                    self._waiting.extend(self._inbox)
+                    self._inbox = []
 
-        # cancelled LIVE rows free their engine state HERE, before this
-        # tick's admission — a cancel storm's blocks are available to
-        # _admit in the same step instead of one tick later
-        self._sweep_cancelled()
-        self._expire_deadlines()
+            # cancelled LIVE rows free their engine state HERE, before this
+            # tick's admission — a cancel storm's blocks are available to
+            # _admit in the same step instead of one tick later
+            self._sweep_cancelled()
+            self._expire_deadlines()
 
-        admitted = self._admit()
+            admitted = self._admit()
         advanced = self._advance_tick()
         if self._journal is not None:
-            self._journal_progress()
+            with self._tracer.scope("ds.tick.emit"):
+                self._journal_progress()
         return bool(admitted or advanced)
 
     def _journal_progress(self) -> None:
@@ -1697,6 +1709,15 @@ class ServingScheduler:
         # cadence instead of sleeping idle_wait on top of the transfer
         return advanced or bool(self._on_prefill)
 
+    def _ctx_tokens(self, reqs) -> int:
+        """Sum of the rows' context lengths (tokens the engine already holds
+        in the KV cache) now, before a put: the ``ctx_tokens`` of the span
+        that put records. 0 with observability off."""
+        if self._obs is None:
+            return 0
+        seqs = (self._engine._state_manager.get_sequence(r.uid) for r in reqs)
+        return sum(seq.seen_tokens for seq in seqs if seq is not None)
+
     def _prefilled(self, r: _Request) -> bool:
         seq = self._engine._state_manager.get_sequence(r.uid)
         return seq is not None and seq.seen_tokens > 0
@@ -1788,7 +1809,9 @@ class ServingScheduler:
         self._in_flight = frozenset(protected)
         n_steps = 0
         try:
-            fed = self._overlap_fill(budget)
+            # a wrapper of other scopes: ring only (tracing.py's leaf rule)
+            with self._tracer.scope("ds.tick.overlap_fill", annotate=False):
+                fed = self._overlap_fill(budget)
             if fed:
                 self._trace["prefill_overlap_tokens"] += fed
                 if self._obs is not None:
@@ -1857,13 +1880,15 @@ class ServingScheduler:
             spent += take
         if not p_reqs:
             return overlap_fed
+        ctx = self._ctx_tokens(p_reqs)
         t0 = time.monotonic()
         if self._tick_put(p_reqs, p_chunks, {}) is None:
             # eviction fence refused / eviction ended the fill
             return overlap_fed
         if self._obs is not None:
             self._obs.prefill_span([r.uid for r in p_reqs], t0,
-                                   time.monotonic(), spent, overlap=True)
+                                   time.monotonic(), spent, overlap=True,
+                                   ctx_tokens=ctx)
         return overlap_fed + spent
 
     # ---- disaggregated prefill/decode (disagg.py) ----
@@ -2028,42 +2053,45 @@ class ServingScheduler:
                            if r.uid not in self._on_prefill]
                 prefills = [r for r in prefills
                             if r.uid not in self._on_prefill]
-        # decode SLA: every decoding sequence's 1 token is RESERVED before
-        # drafts or prefill chunks may spend anything (generate() reserves
-        # identically: draft_budget = max_batch - len(live))
-        if len(decodes) > budget:
-            # only an oversubscribed tick rations decode slots — and then
-            # by weighted-fair queueing order, not arrival order
-            decodes = self._fair_decode_order(decodes)
-        reserve = min(len(decodes), budget)
-        spare = budget - reserve
-        d_reqs, d_chunks, drafted = [], [], {}
-        for req in decodes[:reserve]:
-            chunk = req.feed_slice(1)
-            if req.speculative and spare > 0 and req.outputs:
-                seq = self._engine._state_manager.get_sequence(req.uid)
-                room = min(req.num_draft_tokens, spare,
-                           self._max_context - seq.seen_tokens - 2,
-                           req.max_new_tokens - len(req.outputs) - 1)
-                d = InferenceEngineV2.prompt_lookup_draft(
-                    req.prompt + req.outputs,
-                    draft_ngram=req.draft_ngram, max_tokens=room,
-                    match_window=self._engine.spec_ring_window(
-                        req.num_draft_tokens),
-                    match_cache=req.match_cache)
-                if d:
-                    drafted[req.uid] = d
-                    chunk = chunk + d
-                    spare -= len(d)
-            d_reqs.append(req)
-            d_chunks.append(chunk)
-        p_reqs, p_chunks = [], []
-        for req, take in self._fair_takes(prefills, max(0, spare)):
-            p_reqs.append(req)
-            p_chunks.append(req.feed_slice(take))
-            spare -= take
+        with self._tracer.scope("ds.tick.assemble",
+                                rows=len(decodes) + len(prefills)):
+            # decode SLA: every decoding sequence's 1 token is RESERVED before
+            # drafts or prefill chunks may spend anything (generate() reserves
+            # identically: draft_budget = max_batch - len(live))
+            if len(decodes) > budget:
+                # only an oversubscribed tick rations decode slots — and then
+                # by weighted-fair queueing order, not arrival order
+                decodes = self._fair_decode_order(decodes)
+            reserve = min(len(decodes), budget)
+            spare = budget - reserve
+            d_reqs, d_chunks, drafted = [], [], {}
+            for req in decodes[:reserve]:
+                chunk = req.feed_slice(1)
+                if req.speculative and spare > 0 and req.outputs:
+                    seq = self._engine._state_manager.get_sequence(req.uid)
+                    room = min(req.num_draft_tokens, spare,
+                               self._max_context - seq.seen_tokens - 2,
+                               req.max_new_tokens - len(req.outputs) - 1)
+                    d = InferenceEngineV2.prompt_lookup_draft(
+                        req.prompt + req.outputs,
+                        draft_ngram=req.draft_ngram, max_tokens=room,
+                        match_window=self._engine.spec_ring_window(
+                            req.num_draft_tokens),
+                        match_cache=req.match_cache)
+                    if d:
+                        drafted[req.uid] = d
+                        chunk = chunk + d
+                        spare -= len(d)
+                d_reqs.append(req)
+                d_chunks.append(chunk)
+            p_reqs, p_chunks = [], []
+            for req, take in self._fair_takes(prefills, max(0, spare)):
+                p_reqs.append(req)
+                p_chunks.append(req.feed_slice(take))
+                spare -= take
         if not d_reqs and not p_reqs:
             return False
+        ctx = self._ctx_tokens(p_reqs)
         t_put = time.monotonic()
         if drafted and p_reqs:
             # a prefill chunk inside a window-logits put would materialize
@@ -2080,7 +2108,7 @@ class ServingScheduler:
         if self._obs is not None and p_reqs:
             self._obs.prefill_span(
                 [r.uid for r in p_reqs], t_put, time.monotonic(),
-                sum(len(c) for c in p_chunks))
+                sum(len(c) for c in p_chunks), ctx_tokens=ctx)
         self._retire_finished()
         return True
 
@@ -2139,9 +2167,10 @@ class ServingScheduler:
         Returns ``(fused_reqs, engine_handle, K, all_greedy, t_dispatch)``,
         or None when no subset reaches a 2-step window or KV pressure
         refuses the wave (the caller's per-token tick owns eviction)."""
-        fusable_uids, K, _solo = self._engine.fused_partition(
-            [r.uid for r in decodes],
-            [r.max_new_tokens - len(r.outputs) for r in decodes], cap)
+        with self._tracer.scope("ds.tick.assemble", rows=len(decodes)):
+            fusable_uids, K, _solo = self._engine.fused_partition(
+                [r.uid for r in decodes],
+                [r.max_new_tokens - len(r.outputs) for r in decodes], cap)
         if K < 2:
             return None
         fusable_set = set(fusable_uids)
@@ -2175,30 +2204,32 @@ class ServingScheduler:
         self._trace["fused_dispatches"] += 1
         self._trace["fused_k_sum"] += K
         wave_tokens = 0
-        for i, (req, row) in enumerate(zip(fused, toks)):
-            req.fed += K
-            emitted = self._emit_many(req, [int(t) for t in row],
-                                      lps=[float(l) for l in lps[i]]
-                                      if lps is not None else None)
-            self._trace["fused_tokens"] += emitted
-            self._trace["decode_tokens"] += emitted
-            wave_tokens += emitted
-            if not self._engine.decode_finished(
-                    req.uid, req.outputs, req.max_new_tokens,
-                    req.eos_token_id, req.stop):
-                # deferred bookkeeping for requests that decode on
-                # (fused_decode_steps defers like the speculative path);
-                # retiring ones flush in _retire_finished
-                seq = self._engine._state_manager.get_sequence(req.uid)
-                self._engine._register_pending(seq)
-                self._engine._model.maybe_free_kv(seq)
+        with self._tracer.scope("ds.tick.emit", rows=len(fused)):
+            for i, (req, row) in enumerate(zip(fused, toks)):
+                req.fed += K
+                emitted = self._emit_many(req, [int(t) for t in row],
+                                          lps=[float(l) for l in lps[i]]
+                                          if lps is not None else None)
+                self._trace["fused_tokens"] += emitted
+                self._trace["decode_tokens"] += emitted
+                wave_tokens += emitted
+                if not self._engine.decode_finished(
+                        req.uid, req.outputs, req.max_new_tokens,
+                        req.eos_token_id, req.stop):
+                    # deferred bookkeeping for requests that decode on
+                    # (fused_decode_steps defers like the speculative path);
+                    # retiring ones flush in _retire_finished
+                    seq = self._engine._state_manager.get_sequence(req.uid)
+                    self._engine._register_pending(seq)
+                    self._engine._model.maybe_free_kv(seq)
         if self._obs is not None:
             self._obs.fused_dispatches.inc()
             self._obs.fused_tokens.inc(wave_tokens)
             self._obs.wave_span([r.uid for r in fused], t0,
                                 time.monotonic(), K, len(fused),
                                 "greedy" if all_greedy else "sampled",
-                                flops=self._engine._model.last_wave_flops())
+                                flops=self._engine._model.last_wave_flops(),
+                                ctx_tokens=h.ctx_tokens)
         return fused
 
     def _spec_fusable(self, r: _Request) -> bool:
@@ -2241,10 +2272,11 @@ class ServingScheduler:
                               []).append(r)
         waves = []
         for (d, ng), rows in groups.items():
-            fusable_uids, K, _solo = self._engine.fused_spec_partition(
-                [r.uid for r in rows],
-                [r.max_new_tokens - len(r.outputs) for r in rows],
-                d, cap)
+            with self._tracer.scope("ds.tick.assemble", rows=len(rows)):
+                fusable_uids, K, _solo = self._engine.fused_spec_partition(
+                    [r.uid for r in rows],
+                    [r.max_new_tokens - len(r.outputs) for r in rows],
+                    d, cap)
             if K < 2:
                 continue
             fusable_set = set(fusable_uids)
@@ -2272,27 +2304,28 @@ class ServingScheduler:
         self._trace["fused_dispatches"] += 1
         self._trace["fused_k_sum"] += K
         wave_tokens = wave_dr = wave_ac = 0
-        for req, row, dr, ac in zip(fused, toks_lists, drafted,
-                                    accepted):
-            req.fed += len(row)
-            req.drafted += dr
-            req.accepted += ac
-            self._trace["spec_drafted"] += dr
-            self._trace["spec_accepted"] += ac
-            wave_dr += dr
-            wave_ac += ac
-            emitted = self._emit_many(req, row)
-            self._trace["fused_tokens"] += emitted
-            self._trace["decode_tokens"] += emitted
-            wave_tokens += emitted
-            if not self._engine.decode_finished(
-                    req.uid, req.outputs, req.max_new_tokens,
-                    req.eos_token_id, req.stop):
-                # deferred bookkeeping exactly like _fused_tick:
-                # retiring rows flush in _retire_finished instead
-                seq = self._engine._state_manager.get_sequence(req.uid)
-                self._engine._register_pending(seq)
-                self._engine._model.maybe_free_kv(seq)
+        with self._tracer.scope("ds.tick.emit", rows=len(fused)):
+            for req, row, dr, ac in zip(fused, toks_lists, drafted,
+                                        accepted):
+                req.fed += len(row)
+                req.drafted += dr
+                req.accepted += ac
+                self._trace["spec_drafted"] += dr
+                self._trace["spec_accepted"] += ac
+                wave_dr += dr
+                wave_ac += ac
+                emitted = self._emit_many(req, row)
+                self._trace["fused_tokens"] += emitted
+                self._trace["decode_tokens"] += emitted
+                wave_tokens += emitted
+                if not self._engine.decode_finished(
+                        req.uid, req.outputs, req.max_new_tokens,
+                        req.eos_token_id, req.stop):
+                    # deferred bookkeeping exactly like _fused_tick:
+                    # retiring rows flush in _retire_finished instead
+                    seq = self._engine._state_manager.get_sequence(req.uid)
+                    self._engine._register_pending(seq)
+                    self._engine._model.maybe_free_kv(seq)
         if self._obs is not None:
             self._obs.fused_dispatches.inc()
             self._obs.fused_tokens.inc(wave_tokens)
@@ -2301,7 +2334,8 @@ class ServingScheduler:
             self._obs.wave_span([r.uid for r in fused], t0,
                                 time.monotonic(), K, len(fused), "spec",
                                 drafted=wave_dr, accepted=wave_ac,
-                                flops=self._engine._model.last_wave_flops())
+                                flops=self._engine._model.last_wave_flops(),
+                                ctx_tokens=h.ctx_tokens)
         return fused
 
     def _tick_put(self, reqs, chunks, drafted) -> Optional[bool]:
@@ -2314,11 +2348,13 @@ class ServingScheduler:
                 # do_checks stays ON: chunks always fit the ragged limits
                 # under the SplitFuse budget, and the feasibility check is
                 # what turns KV exhaustion into a catchable SchedulingError
-                logits = np.asarray(self._engine.put(
+                logits = self._engine.put(
                     [r.uid for r in reqs], chunks,
                     window_logits=use_window,
                     defer_register=(frozenset(drafted)
-                                    if use_window else frozenset())))
+                                    if use_window else frozenset()))
+                with self._tracer.scope("ds.tick.harvest", rows=len(reqs)):
+                    logits = np.asarray(logits)  # the host waits here
                 break
             except SchedulingError:
                 if use_window:
@@ -2362,52 +2398,56 @@ class ServingScheduler:
                     self._finish(victim, flush=False)
                 return None
         device_wave = []  # (req, logits_row) — one batched sample dispatch
-        for req, chunk, row in zip(reqs, chunks, logits):
-            spec_sampled = (req.speculative is not None
-                            and req.temperature != 0.0)
-            d = drafted.get(req.uid, [])
-            if d:
-                if spec_sampled:
-                    new_toks, m = self._engine.accept_drafts_sampled(
-                        req.uid, d, row, self._spec_for(req),
-                        req.num_draft_tokens)
-                    req.key_burns += 1  # one split per verified window
-                else:
-                    new_toks, m = self._engine.accept_drafts(req.uid, d, row)
-                req.fed += 1 + m
-                req.drafted += len(d)
-                req.accepted += m
-                self._trace["spec_drafted"] += len(d)
-                self._trace["spec_accepted"] += m
-                self._trace["decode_tokens"] += self._emit_many(req,
-                                                                new_toks)
-            else:
-                req.fed += len(chunk)
-                if req.pending == 0:  # feed complete: row is the next token
-                    last = row[len(chunk) - 1] if use_window else row
+        # host-side sampling and the per-row emission it feeds, one span a put
+        with self._tracer.scope("ds.tick.sample", rows=len(reqs)):
+            for req, chunk, row in zip(reqs, chunks, logits):
+                spec_sampled = (req.speculative is not None
+                                and req.temperature != 0.0)
+                d = drafted.get(req.uid, [])
+                if d:
                     if spec_sampled:
-                        # a draft-free step of a sampled speculative request
-                        # still burns its per-WINDOW key (accept with an
-                        # empty draft) so the key chain advances once per
-                        # step on every path, fused or not
-                        new_toks, _ = self._engine.accept_drafts_sampled(
-                            req.uid, [], last, self._spec_for(req),
+                        new_toks, m = self._engine.accept_drafts_sampled(
+                            req.uid, d, row, self._spec_for(req),
                             req.num_draft_tokens)
-                        req.key_burns += 1  # draft-free window still burns
-                        self._trace["decode_tokens"] += self._emit_many(
-                            req, new_toks)
-                    elif self._device_eligible(req):
-                        device_wave.append((req, last))
+                        req.key_burns += 1  # one split per verified window
                     else:
-                        self._emit(req, last)
-            if use_window:
-                # window puts defer the trailing-window KV free for EVERY
-                # sequence in the batch — resume it here
-                seq = self._engine._state_manager.get_sequence(req.uid)
-                if seq is not None:
-                    self._engine._model.maybe_free_kv(seq)
+                        new_toks, m = self._engine.accept_drafts(req.uid, d, row)
+                    req.fed += 1 + m
+                    req.drafted += len(d)
+                    req.accepted += m
+                    self._trace["spec_drafted"] += len(d)
+                    self._trace["spec_accepted"] += m
+                    self._trace["decode_tokens"] += self._emit_many(req,
+                                                                    new_toks)
+                else:
+                    req.fed += len(chunk)
+                    if req.pending == 0:  # feed complete: row is the next token
+                        last = row[len(chunk) - 1] if use_window else row
+                        if spec_sampled:
+                            # a draft-free step of a sampled speculative request
+                            # still burns its per-WINDOW key (accept with an
+                            # empty draft) so the key chain advances once per
+                            # step on every path, fused or not
+                            new_toks, _ = self._engine.accept_drafts_sampled(
+                                req.uid, [], last, self._spec_for(req),
+                                req.num_draft_tokens)
+                            req.key_burns += 1  # draft-free window still burns
+                            self._trace["decode_tokens"] += self._emit_many(
+                                req, new_toks)
+                        elif self._device_eligible(req):
+                            device_wave.append((req, last))
+                        else:
+                            self._emit(req, last)
+                if use_window:
+                    # window puts defer the trailing-window KV free for EVERY
+                    # sequence in the batch — resume it here
+                    seq = self._engine._state_manager.get_sequence(req.uid)
+                    if seq is not None:
+                        self._engine._model.maybe_free_kv(seq)
         if device_wave:
-            self._emit_device(device_wave)
+            with self._tracer.scope("ds.tick.sample", rows=len(device_wave),
+                             device=True):
+                self._emit_device(device_wave)
         return True
 
     def _stream_put(self, req: _Request, tok: int) -> None:
@@ -2519,22 +2559,23 @@ class ServingScheduler:
         return emitted
 
     def _retire_finished(self) -> None:
-        for req in list(self._live):
-            if req.uid in self._in_flight:
-                continue  # fused wave in flight: judge/flush after harvest
-            if req.uid in self._on_prefill:
-                # prefill-group resident: no decode-side descriptor yet —
-                # an eos-on-first-token finish lands at takeover instead
-                continue
-            if not req.outputs or req.pending > 1:
-                continue  # still (re)prefilling — nothing sampled to judge
-            if self._engine._state_manager.get_sequence(req.uid) is None:
-                continue  # admitted this tick, nothing fed yet
-            if self._engine.decode_finished(req.uid, req.outputs,
-                                            req.max_new_tokens,
-                                            req.eos_token_id, req.stop):
-                self._live.remove(req)
-                self._finish(req)
+        with self._tracer.scope("ds.tick.emit"):
+            for req in list(self._live):
+                if req.uid in self._in_flight:
+                    continue  # fused wave in flight: judge/flush after harvest
+                if req.uid in self._on_prefill:
+                    # prefill-group resident: no decode-side descriptor yet —
+                    # an eos-on-first-token finish lands at takeover instead
+                    continue
+                if not req.outputs or req.pending > 1:
+                    continue  # still (re)prefilling — nothing sampled to judge
+                if self._engine._state_manager.get_sequence(req.uid) is None:
+                    continue  # admitted this tick, nothing fed yet
+                if self._engine.decode_finished(req.uid, req.outputs,
+                                                req.max_new_tokens,
+                                                req.eos_token_id, req.stop):
+                    self._live.remove(req)
+                    self._finish(req)
 
     def _finish(self, req: _Request, flush: bool = True) -> None:
         if self._disagg is not None and req.uid in self._on_prefill:

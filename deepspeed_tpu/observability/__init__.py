@@ -1,18 +1,26 @@
 """Unified serving/training observability.
 
-Three pillars, all host-side and allocation-light (nothing here ever
-touches the device — timestamps are ``time.monotonic()`` around already
-existing host boundaries, honoring the async-dispatch design):
+All recording is host-side and allocation-light, around host boundaries
+that already exist, honoring the async-dispatch design: nothing here forces
+a device sync. Two clocks are in play, and one API joins them: a span taken
+with :meth:`RequestTracer.scope` goes to the tracer's ring stamped with
+``time.monotonic()`` (always on) **and** to ``jax.profiler.TraceAnnotation``,
+so that whenever a profiler session is on it sits in the xplane on the
+device trace's clock, next to the device lines. Everything else (metrics,
+request timelines, the goodput ledger) is ``time.monotonic()`` /
+``perf_counter`` alone.
 
 - :mod:`metrics` — a process-wide registry of counters, gauges, and
   log-bucketed histograms (fixed-size numpy bucket arrays; p50/p90/p99
   derivable at read time). Rendered as Prometheus text by the serving
   daemon's ``GET /metrics`` and bridgeable into the ``monitor/`` fan-out
   (one ``(name, value, step)`` event schema shared with training).
-- :mod:`tracing` — per-request span timelines (submit → queue → admit →
-  prefill chunks → fused K-waves → journal → finish) in a bounded ring,
-  exportable per-uid as JSON and in bulk as Chrome ``trace_event`` JSON
-  (loadable in Perfetto / chrome://tracing).
+- :mod:`tracing` — the span API (``scope``: the program's own phases,
+  ``ds.train.*`` / ``ds.tick.*`` / ``ds.init.*``, with parent and self
+  time) and per-request span timelines (submit → queue → admit → prefill
+  chunks → fused K-waves → journal → finish) in bounded rings, exportable
+  per-uid as JSON and in bulk as Chrome ``trace_event`` JSON (loadable in
+  Perfetto / chrome://tracing).
 - :mod:`profiler` — guarded on-demand ``jax.profiler`` captures (one at
   a time, duration-bounded) behind ``POST /debug/profile``.
 - :mod:`xla` — compile observability: per-compile-key compile/retrace/hit

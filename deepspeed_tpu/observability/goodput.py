@@ -16,8 +16,10 @@ Attribution model — two complementary mechanisms:
   (dispatch + device wait + dataloader) lands in ``useful_step``.
 - ``span(category)``: a context manager for excursions with clear
   boundaries (checkpoint save/load, anomaly rollback, the async-window
-  host fetch). A span records its own duration directly AND banks it as
-  *foreign* time, which the next ``mark`` subtracts from the cursor
+  host fetch). A span is also a ``ds.train.<category>`` scope of the
+  process-wide tracer (observability/tracing.py). A span records its own
+  duration directly AND banks it as *foreign* time, which the next
+  ``mark`` subtracts from the cursor
   interval — the same second is never counted twice. Nested spans fold
   into the outermost category (a rollback that internally loads a
   checkpoint is all "anomaly_rollback").
@@ -39,6 +41,7 @@ from contextlib import contextmanager
 from typing import Dict, Optional
 
 from .metrics import MetricsRegistry, get_registry
+from .tracing import get_tracer
 
 CATEGORIES = (
     "useful_step",      # optimizer-step wall (dispatch + device + data wait)
@@ -105,7 +108,10 @@ class GoodputLedger:
             nested = self._span_depth > 1
         t0 = self._clock()
         try:
-            yield
+            # the same excursion on the span API's two sinks (the tracer's
+            # ring and, in a profiler session, the device trace's clock)
+            with get_tracer().scope("ds.train." + category):
+                yield
         finally:
             dt = self._clock() - t0
             with self._lock:

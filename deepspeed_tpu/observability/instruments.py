@@ -208,23 +208,33 @@ class ServingInstruments:
 
     def wave_span(self, uids: Iterable, t0: float, t1: float, K: int,
                   size: int, kind: str, drafted: int = 0,
-                  accepted: int = 0, flops: float = 0.0) -> None:
+                  accepted: int = 0, flops: float = 0.0,
+                  ctx_tokens: int = 0) -> None:
+        """``size`` (also ``rows``) is the wave's live rows and
+        ``ctx_tokens`` the sum of their context lengths at dispatch: what
+        the paged attention calls of the wave read."""
         self.wave.record(t1 - t0)
         if flops > 0 and t1 > t0:
             self.wave_mfu.set(min(1.0, flops / ((t1 - t0) * self.peak_flops)))
-        args = {"K": K, "size": size, "kind": kind}
+        args = {"K": K, "size": size, "kind": kind, "rows": size,
+                "ctx_tokens": ctx_tokens}
         if drafted:
             args["drafted"], args["accepted"] = drafted, accepted
         self.tracer.global_span(f"fused_wave[{kind}]", t0, t1, args,
                                 uids=[str(u) for u in uids])
 
     def prefill_span(self, uids: Iterable, t0: float, t1: float,
-                     tokens: int, overlap: bool = False) -> None:
+                     tokens: int, overlap: bool = False,
+                     ctx_tokens: int = 0) -> None:
+        """One span per tick in the global ring, copied onto each request
+        it fed. ``rows`` is the prefilling rows and ``ctx_tokens`` the sum
+        of their context lengths (tokens already cached) at dispatch."""
         self.prefill.record(t1 - t0)
         name = "prefill_overlap" if overlap else "prefill"
-        args = {"tokens": tokens}
-        for u in uids:
-            self.tracer.span(str(u), name, t0, t1, args)
+        uids = [str(u) for u in uids]
+        args = {"tokens": tokens, "rows": len(uids),
+                "ctx_tokens": ctx_tokens}
+        self.tracer.global_span(name, t0, t1, args, uids=uids)
 
     def request_finished(self, uid, t_submit: float, t_done: float,
                          outcome: str, n_tokens: int,

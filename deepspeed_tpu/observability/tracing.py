@@ -1,18 +1,39 @@
-"""Per-request span timelines in bounded ring buffers.
+"""Span timelines in bounded ring buffers, and the one span API.
 
-A *span* is one closed interval of a request's life on the host clock:
-queue wait, a prefill chunk, one fused K-wave, a journal append, the
-finish. Spans carry a small ``args`` dict (wave K, wave size, spec
-accept counts, chunk tokens...) and are recorded with plain
-``time.monotonic()`` timestamps — recording never touches the device.
+A *span* is one closed interval on the host clock: a request's queue
+wait, a prefill chunk, one fused K-wave, a journal append, the finish —
+or one phase of the program's own host work (a scheduler tick's
+admission, a training step's dispatch, a section of engine construction).
+Spans carry a small ``args`` dict (wave K, wave size, spec accept counts,
+chunk tokens...).
 
-Storage is bounded three ways so a long-lived daemon cannot grow:
+:meth:`RequestTracer.scope` is how the program times its own phases. It
+writes to two sinks:
+
+- the tracer's bounded ring, stamped with ``time.monotonic()``, always on:
+  ``(name, t0, t1, parent, uid, args, sid)`` where ``parent`` is the
+  ``sid`` of the enclosing open scope on that thread, so a layer's self
+  time is its duration less its children's (:meth:`RequestTracer.scopes`);
+- ``jax.profiler.TraceAnnotation(name)``, so that whenever a profiler
+  session is on (``POST /debug/profile``, the benchmark's traced run) the
+  span is in the xplane on the **device trace's clock**, next to the
+  device lines; with no session on, entering one is a flag check.
+
+Rule for the profiler sink: leaf phases only. A tool that names an idle
+gap by the host event covering most of it cannot tell a wrapper from the
+child that fills it, so a scope that encloses other scopes passes
+``annotate=False`` and goes to the ring alone. Names start with ``ds.``
+and are literal strings; a shape, uid or step number goes in ``args``.
+
+Storage is bounded so a long-lived daemon cannot grow:
 
 - at most ``max_requests`` live timelines (oldest evicted first),
 - at most ``max_spans_per_request`` spans per timeline (a deque ring —
   a pathological million-token request keeps its most recent spans),
-- a global ``max_waves`` ring of wave/global spans for the bulk
-  ``GET /debug/trace`` Chrome export.
+- a global ``max_waves`` ring of wave/global/scope spans for the bulk
+  ``GET /debug/trace`` Chrome export,
+- a small ring of its own for set-up spans (``ds.init*``, ``ds.compile.*``:
+  once per engine or program), which the window's traffic cannot evict.
 
 Export formats:
 
@@ -24,10 +45,18 @@ Export formats:
   Perfetto / chrome://tracing, one ``tid`` lane per request.
 """
 
+import itertools
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional
+from contextlib import nullcontext
+from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# scopes recorded once per engine or per compiled program: kept in a ring
+# of their own so that steady traffic cannot evict them
+KEPT_PREFIXES = ("ds.init", "ds.compile.")
 
 
 class _Timeline:
@@ -41,17 +70,109 @@ class _Timeline:
         self.done = False
 
 
+class _Scope:
+    """One open :meth:`RequestTracer.scope`. ``args`` may be filled in
+    while the scope is open (a count known only after the work)."""
+
+    __slots__ = ("_tracer", "name", "uid", "args", "_annotation", "_t0",
+                 "_sid", "_parent", "_stack")
+
+    def __init__(self, tracer, name, uid, annotate, args):
+        self._tracer = tracer
+        self.name = name
+        self.uid = uid
+        self.args = args
+        self._annotation = TraceAnnotation(name) if annotate else None
+
+    def __enter__(self):
+        tracer = self._tracer
+        stack = self._stack = tracer._open_scopes()
+        self._parent = stack[-1] if stack else None
+        self._sid = next(tracer._sids)
+        stack.append(self._sid)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self._stack.pop()
+        self._tracer._record_scope(
+            (self.name, self._t0, t1, self._parent,
+             None if self.uid is None else str(self.uid),
+             self.args or None, self._sid))
+        return False
+
+
 class RequestTracer:
-    """Bounded recorder of request lifecycles and global daemon spans."""
+    """Bounded recorder of request lifecycles, daemon spans and the
+    program's own phases (:meth:`scope`)."""
 
     def __init__(self, max_requests: int = 512,
                  max_spans_per_request: int = 512,
-                 max_waves: int = 2048):
+                 max_waves: int = 2048, max_kept: int = 512):
         self._lock = threading.Lock()
         self._max_requests = int(max_requests)
         self._max_spans = int(max_spans_per_request)
         self._timelines: "OrderedDict[str, _Timeline]" = OrderedDict()
+        # ring records: (name, t0, t1, parent sid, uid, args, sid)
         self._waves = deque(maxlen=int(max_waves))
+        self._kept = deque(maxlen=int(max_kept))
+        self._sids = itertools.count(1)
+        self._local = threading.local()
+
+    # ---- the span API (hot path: two clock reads, one lock, one append) ----
+
+    def scope(self, name: str, uid=None, *, annotate: bool = True, **args):
+        """Context manager timing one phase of host work into both sinks
+        (module docstring). ``uid`` ties the span to one request: it is
+        then also on that request's timeline, so pass it only for phases
+        that happen a few times per request. ``annotate=False`` keeps a
+        scope that encloses other scopes off the profiler's sink."""
+        return _Scope(self, name, uid, annotate, args)
+
+    def _open_scopes(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _record_scope(self, rec: tuple) -> None:
+        name, t0, t1, _, uid, args, _ = rec
+        with self._lock:
+            (self._kept if name.startswith(KEPT_PREFIXES)
+             else self._waves).append(rec)
+            if uid is not None:
+                tl = self._timelines.get(uid)
+                if tl is not None:
+                    tl.spans.append((name, t0, t1, args))
+
+    def scopes(self, prefix: str = "ds.", since: float = 0.0) -> List[dict]:
+        """The recorded scopes whose name starts with ``prefix`` and that
+        ended at or after ``since`` (monotonic), oldest first, each with
+        its ``self_s``: its duration less that of the recorded scopes it
+        directly encloses."""
+        with self._lock:
+            recs = [r for ring in (self._kept, self._waves) for r in ring
+                    if r[6] is not None]
+        child_s = {}
+        for _, t0, t1, parent, _, _, _ in recs:
+            if parent is not None:
+                child_s[parent] = child_s.get(parent, 0.0) + (t1 - t0)
+        out = []
+        for name, t0, t1, parent, uid, args, sid in recs:
+            if not name.startswith(prefix) or t1 < since:
+                continue
+            out.append({"name": name, "t0_monotonic": t0, "t1_monotonic": t1,
+                        "dur_s": t1 - t0,
+                        "self_s": (t1 - t0) - child_s.get(sid, 0.0),
+                        "sid": sid, "parent": parent, "uid": uid,
+                        "args": dict(args) if args else {}})
+        out.sort(key=lambda d: d["t0_monotonic"])
+        return out
 
     # ---- recording (hot path: one lock, one deque append) ----
 
@@ -105,7 +226,7 @@ class RequestTracer:
         """Record a daemon-level interval (a fused wave, a restart) into
         the global ring, optionally mirrored onto member timelines."""
         with self._lock:
-            self._waves.append((name, t0, t1, args))
+            self._waves.append((name, t0, t1, None, None, args, None))
             if uids:
                 for uid in uids:
                     tl = self._timelines.get(uid)
@@ -150,12 +271,13 @@ class RequestTracer:
         live timeline, one ``tid`` lane per request (pid 1 = daemon)."""
         with self._lock:
             waves = list(self._waves)
+            kept = list(self._kept)
             tls = [(tl.uid, tl.t_submit, list(tl.spans), list(tl.events))
                    for tl in self._timelines.values()]
         if last is not None and last >= 0:
             waves = waves[-last:]
         events = []
-        for name, t0, t1, args in waves:
+        for name, t0, t1, _, _, args, _ in kept + waves:
             ev = {"name": name, "ph": "X", "pid": 1, "tid": 0,
                   "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6}
             if args:
@@ -182,8 +304,20 @@ class RequestTracer:
         with self._lock:
             self._timelines.clear()
             self._waves.clear()
+            self._kept.clear()
 
 
+class _NoTracer:
+    """What an engine or scheduler holds in a tracer's place with its
+    observability block off: ``scope`` times nothing."""
+
+    _nothing = nullcontext()
+
+    def scope(self, name: str, uid=None, *, annotate: bool = True, **args):
+        return self._nothing
+
+
+NO_TRACER = _NoTracer()
 _TRACER = RequestTracer()
 
 

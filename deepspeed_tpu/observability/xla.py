@@ -32,6 +32,7 @@ import time
 from typing import Any, Optional, Tuple
 
 from .metrics import Histogram, MetricsRegistry, get_registry
+from .tracing import get_tracer
 
 # compile times span ~ms (tiny CPU programs) to ~1h (giant TPU programs)
 _COMPILE_HIST = dict(lo=1e-3, hi=1e4, buckets_per_decade=5)
@@ -142,10 +143,13 @@ class WatchedJit:
         if self._flops_spec is None:
             return 0.0
         a, k = self._flops_spec
-        try:
-            self._flops = cost_analysis_flops(self._fn.lower(*a, **k))
-        except Exception:
-            self._flops = 0.0
+        # the re-lowering is a whole-program trace: timed, so that what the
+        # MFU gauges cost inside a compile stall can be read off the tracer
+        with get_tracer().scope("ds.compile.cost_analysis", key=self.key):
+            try:
+                self._flops = cost_analysis_flops(self._fn.lower(*a, **k))
+            except Exception:
+                self._flops = 0.0
         return self._flops
 
 

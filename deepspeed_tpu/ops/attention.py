@@ -253,6 +253,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
             pltpu.VMEM((G * block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qg, kt, vt)
     o = (out.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
          .reshape(B, Sq, H, D))
@@ -458,6 +459,7 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((G * block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qg, kt, vt, dog, lse, delta)
 
     # kv-major grid for dk/dv: q sweep innermost
@@ -483,6 +485,7 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkdv",
     )(qg, kt, vt, dog, lse, delta)
 
     dq = (dq.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
@@ -644,6 +647,15 @@ def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
 
 _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
 
+# The device trace names a Pallas call by the innermost name scope of the
+# frame that holds it, and a transformation wraps the scopes of its own
+# frame (``jvp(flash_fwd)`` reads ``%jvp_flash_fwd_``). A jit boundary
+# outside the custom_vjp starts a new frame, so the kernels read
+# ``%flash_fwd.N`` / ``%flash_dq.N`` / ``%flash_dkdv.N`` under ``jax.grad``
+# on one device as they do inside a ``shard_map``. XLA inlines the call.
+_flash_attention_call = jax.jit(_dispatched_attention,
+                                static_argnums=(3, 4, 5, 6, 7, 8, 9))
+
 
 def flash_attention(q,
                     k,
@@ -686,8 +698,8 @@ def flash_attention(q,
         and impl_bwd is None)
     fwd_dec = _fit_blocks(fwd_dec, q.shape[1], k.shape[1])
     bwd_dec = _fit_blocks(bwd_dec, q.shape[1], k.shape[1])
-    return _dispatched_attention(q, k, v, scale, causal, window, softcap,
-                                 interpret, fwd_dec, bwd_dec)
+    return _flash_attention_call(q, k, v, scale, causal, window,
+                                 softcap, interpret, fwd_dec, bwd_dec)
 
 
 registry.register("flash_attention", "pallas", True)

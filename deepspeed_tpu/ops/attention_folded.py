@@ -333,6 +333,7 @@ def flash_fwd_folded(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((H * block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="folded_flash_fwd",
     )(q, kf, vf)
     return out, lse
 
@@ -360,6 +361,7 @@ def flash_bwd_folded(q, k, v, lse, o, g_out, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((H * block_q, D), jnp.float32)],
         interpret=interpret,
+        name="folded_flash_dq",
     )(q, kf, vf, g_out, lse, delta)
 
     q_spec2 = pl.BlockSpec((1, block_q, H, D), lambda b, j, i: (b, i, 0, 0))
@@ -382,6 +384,7 @@ def flash_bwd_folded(q, k, v, lse, o, g_out, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, KV * D), jnp.float32),
         ],
         interpret=interpret,
+        name="folded_flash_dkdv",
     )(q, kf, vf, g_out, lse, delta)
     return dq, dk.reshape(B, Sk, KV, D), dv.reshape(B, Sk, KV, D)
 

@@ -148,6 +148,7 @@ def _splash_fwd(q, k, v, table, counts, block, scale, interpret,
         grid_spec=grid_spec,
         out_shape=out_shape if with_lse else out_shape[0],
         interpret=interpret,
+        name="splash_fwd",
     )(jnp.asarray(table), jnp.asarray(counts), qf, kf, vf)
     if with_lse:
         o, lse = out
@@ -275,6 +276,7 @@ def _splash_bwd(q, k, v, o, lse, g, table, counts, tableT, countsT,
         ),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         interpret=interpret,
+        name="splash_dq",
     )(jnp.asarray(table), jnp.asarray(counts), qf, kf, vf, dof, lse, delta)
 
     # ---- dk/dv: grid (BH, k_block, active-q), transposed table ----
@@ -301,6 +303,7 @@ def _splash_bwd(q, k, v, o, lse, g, table, counts, tableT, countsT,
         out_shape=[jax.ShapeDtypeStruct((BH, S, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
         interpret=interpret,
+        name="splash_dkdv",
     )(jnp.asarray(tableT), jnp.asarray(countsT), qf, kf, vf, dof, lse, delta)
 
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, S, D),

@@ -42,6 +42,7 @@ from ..checkpoint.engine import (OrbaxCheckpointEngine, CheckpointCorruptionErro
 from ..utils.fault_injection import get_fault_injector
 from ..comm.mesh import get_mesh_context, mesh_is_initialized
 from ..config import DeepSpeedTpuConfig
+from ..observability.tracing import NO_TRACER, get_tracer
 from ..utils.logging import logger, log_dist
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER, FORWARD_GLOBAL_TIMER,
                            FORWARD_MICRO_TIMER, STEP_GLOBAL_TIMER, STEP_MICRO_TIMER,
@@ -208,6 +209,19 @@ class DeepSpeedTpuEngine:
         else:
             raw = config if config is not None else {}
             self._config = DeepSpeedTpuConfig(raw, world_size=self._dp_world_from(raw))
+        # the span API (observability/tracing.py): the process-wide tracer,
+        # off with the observability block like every other recording
+        self._tracer = (get_tracer()
+                        if self._config.observability_config.enabled
+                        else NO_TRACER)
+        with self._tracer.scope("ds.init", annotate=False):
+            self._construct(model, optimizer, model_parameters, training_data,
+                            lr_scheduler, mpu, collate_fn, mesh_param,
+                            loss_fn, kwargs)
+
+    def _construct(self, model, optimizer, model_parameters, training_data,
+                   lr_scheduler, mpu, collate_fn, mesh_param, loss_fn,
+                   kwargs):
         self.module = model
         # multi-output models (reference test_multi_output_model.py): the
         # torch pattern combines the returned losses BETWEEN forward and
@@ -229,41 +243,42 @@ class DeepSpeedTpuEngine:
         self.last_fwd_spec = None  # abstract fwd arg spec (flops profiler)
 
         # ---- mesh ----
-        if not mesh_is_initialized():
-            mc = self._config.mesh_config
-            axes = {a: getattr(mc, a) for a in mc.axis_order}
-            tp_sz = self._config.tensor_parallel_config.tp_size
-            if tp_sz and tp_sz > 1 and axes.get("model", 1) == 1:
-                # tensor_parallel.tp_size creates the model axis when the
-                # mesh config doesn't name one (inference-config spelling)
-                axes["model"] = tp_sz
-            elif (tp_sz and tp_sz > 1
-                  and axes.get("model", 1) not in (tp_sz, -1)):
-                # -1 means the user delegated the size to absorption — only
-                # an EXPLICIT different size is a real conflict
-                from ..utils.logging import logger as _logger
-                _logger.warning(
-                    f"tensor_parallel.tp_size={tp_sz} conflicts with mesh "
-                    f"model={axes.get('model')} — the mesh axis wins; TP "
-                    f"runs at {axes.get('model')}")
-            hpz = self._config.zero_config.zero_hpz_partition_size
-            if hpz > 1 and axes.get("fsdp", 1) == 1:
-                # hpZ (ZeRO++ secondary partition): shard params over the
-                # innermost ICI-local axis only; replicate across nodes
-                from .zeropp import hpz_mesh_axes
-                axes.update(hpz_mesh_axes(jax.device_count(), hpz))
-            mics = self._config.zero_config.mics_shard_size
-            if mics > 1 and axes.get("fsdp", 1) == 1:
-                # MiCS: ZeRO-3 within shard groups, replicate across
-                from .mics import mics_mesh_axes
-                axes.update(mics_mesh_axes(jax.device_count(), mics))
-            if mesh_param is not None:  # reference mesh_param=(dp, sp)
-                axes = {"data": mesh_param[0], "seq": mesh_param[1]}
-            dist.init_distributed(mesh_axes=axes)
-        self.mesh_ctx = get_mesh_context()
-        self.dp_world_size = self.mesh_ctx.dp_size
-        # pre-initialized mesh may differ from the config's pre-mesh guess
-        self._config.reresolve(self.dp_world_size)
+        with self._tracer.scope("ds.init.mesh"):
+            if not mesh_is_initialized():
+                mc = self._config.mesh_config
+                axes = {a: getattr(mc, a) for a in mc.axis_order}
+                tp_sz = self._config.tensor_parallel_config.tp_size
+                if tp_sz and tp_sz > 1 and axes.get("model", 1) == 1:
+                    # tensor_parallel.tp_size creates the model axis when the
+                    # mesh config doesn't name one (inference-config spelling)
+                    axes["model"] = tp_sz
+                elif (tp_sz and tp_sz > 1
+                      and axes.get("model", 1) not in (tp_sz, -1)):
+                    # -1 means the user delegated the size to absorption — only
+                    # an EXPLICIT different size is a real conflict
+                    from ..utils.logging import logger as _logger
+                    _logger.warning(
+                        f"tensor_parallel.tp_size={tp_sz} conflicts with mesh "
+                        f"model={axes.get('model')} — the mesh axis wins; TP "
+                        f"runs at {axes.get('model')}")
+                hpz = self._config.zero_config.zero_hpz_partition_size
+                if hpz > 1 and axes.get("fsdp", 1) == 1:
+                    # hpZ (ZeRO++ secondary partition): shard params over the
+                    # innermost ICI-local axis only; replicate across nodes
+                    from .zeropp import hpz_mesh_axes
+                    axes.update(hpz_mesh_axes(jax.device_count(), hpz))
+                mics = self._config.zero_config.mics_shard_size
+                if mics > 1 and axes.get("fsdp", 1) == 1:
+                    # MiCS: ZeRO-3 within shard groups, replicate across
+                    from .mics import mics_mesh_axes
+                    axes.update(mics_mesh_axes(jax.device_count(), mics))
+                if mesh_param is not None:  # reference mesh_param=(dp, sp)
+                    axes = {"data": mesh_param[0], "seq": mesh_param[1]}
+                dist.init_distributed(mesh_axes=axes)
+            self.mesh_ctx = get_mesh_context()
+            self.dp_world_size = self.mesh_ctx.dp_size
+            # pre-initialized mesh may differ from the config's pre-mesh guess
+            self._config.reresolve(self.dp_world_size)
 
         # ---- precision policy ----
         if self._config.bf16_enabled:
@@ -325,38 +340,39 @@ class DeepSpeedTpuEngine:
         self.optimizer = self  # engine exposes optimizer-ish API (reference returns the wrapper)
 
         # ---- ZeRO sharding plan (optionally composed with native TP) ----
-        zc = self._config.zero_config
-        tpc = self._config.tensor_parallel_config
-        tp_requested = tpc.enabled or (tpc.tp_size or 0) > 1
-        self._tp_training = tp_requested and self.mesh_ctx.axis_size("model") > 1
-        if tp_requested and not self._tp_training:
-            from ..utils.logging import logger as _logger
-            _logger.warning(
-                "tensor_parallel requested but the mesh has no model axis "
-                "> 1 — TP sharding disabled (add model to the mesh config "
-                "or set tensor_parallel.tp_size)")
-        self.zero_plan = ZeroShardingPlan(self.mesh_ctx, zc.stage,
-                                          param_persistence_threshold=zc.param_persistence_threshold,
-                                          tp=self._tp_training,
-                                          logical_axes=kwargs.get("logical_axes"))
-        if zc.stage >= 3 and model_parameters is not None:
-            # max_live_parameters governor advisory (zero_governor.py): the
-            # structural ceiling is scan chunking — warn when the model's
-            # unrolled params exceed the configured budget AND the model isn't
-            # already scan-governed (embeddings/head stay live regardless)
-            scan_governed = bool(getattr(getattr(model, "config", None),
-                                         "scan_layers", False))
-            n_el = sum(int(np.prod(getattr(p, "shape", ())))
-                       for p in jax.tree_util.tree_leaves(model_parameters))
-            if n_el > zc.max_live_parameters and not scan_governed:
+        with self._tracer.scope("ds.init.zero_plan"):
+            zc = self._config.zero_config
+            tpc = self._config.tensor_parallel_config
+            tp_requested = tpc.enabled or (tpc.tp_size or 0) > 1
+            self._tp_training = tp_requested and self.mesh_ctx.axis_size("model") > 1
+            if tp_requested and not self._tp_training:
                 from ..utils.logging import logger as _logger
                 _logger.warning(
-                    f"ZeRO-3: model has {n_el:.3g} elements > "
-                    f"stage3_max_live_parameters={zc.max_live_parameters:.3g}. "
-                    f"XLA may gather beyond the budget on an unrolled model — "
-                    f"use scan_layers (LlamaConfig.with_live_param_budget) or "
-                    f"runtime.zero_governor.governed_layer_scan to make the "
-                    f"ceiling structural.")
+                    "tensor_parallel requested but the mesh has no model axis "
+                    "> 1 — TP sharding disabled (add model to the mesh config "
+                    "or set tensor_parallel.tp_size)")
+            self.zero_plan = ZeroShardingPlan(self.mesh_ctx, zc.stage,
+                                              param_persistence_threshold=zc.param_persistence_threshold,
+                                              tp=self._tp_training,
+                                              logical_axes=kwargs.get("logical_axes"))
+            if zc.stage >= 3 and model_parameters is not None:
+                # max_live_parameters governor advisory (zero_governor.py): the
+                # structural ceiling is scan chunking — warn when the model's
+                # unrolled params exceed the configured budget AND the model isn't
+                # already scan-governed (embeddings/head stay live regardless)
+                scan_governed = bool(getattr(getattr(model, "config", None),
+                                             "scan_layers", False))
+                n_el = sum(int(np.prod(getattr(p, "shape", ())))
+                           for p in jax.tree_util.tree_leaves(model_parameters))
+                if n_el > zc.max_live_parameters and not scan_governed:
+                    from ..utils.logging import logger as _logger
+                    _logger.warning(
+                        f"ZeRO-3: model has {n_el:.3g} elements > "
+                        f"stage3_max_live_parameters={zc.max_live_parameters:.3g}. "
+                        f"XLA may gather beyond the budget on an unrolled model — "
+                        f"use scan_layers (LlamaConfig.with_live_param_budget) or "
+                        f"runtime.zero_governor.governed_layer_scan to make the "
+                        f"ceiling structural.")
 
         # ZeRO-Offload: optimizer states on host DRAM or NVMe (reference
         # stage_1_and_2.py cpu-offload path + cpu_adam); frees HBM of the
@@ -405,8 +421,9 @@ class DeepSpeedTpuEngine:
         self._init_state(model_parameters)
 
         # ---- compiled steps ----
-        self._build_compiled_fns()
-        self._watch_compiled_fns()
+        with self._tracer.scope("ds.init.build_step"):
+            self._build_compiled_fns()
+            self._watch_compiled_fns()
 
         # ---- compile() / is_compiled surface (reference engine.py:3665) ----
         from .compiler import attach_compile_api
@@ -464,7 +481,9 @@ class DeepSpeedTpuEngine:
             except (TypeError, ValueError):
                 pass
 
-        self.checkpoint_engine = OrbaxCheckpointEngine()
+        with self._tracer.scope("ds.init.checkpoint_engine"):
+            # the first engine of a process imports orbax here (seconds)
+            self.checkpoint_engine = OrbaxCheckpointEngine()
         dist.configure(deepspeed_config=self._config)
 
         # training data loader (reference deepspeed_io, engine.py:1743)
@@ -489,7 +508,8 @@ class DeepSpeedTpuEngine:
 
         # ---- resilience: preemption autosave, anomaly sentry, auto-resume
         # (after the dataloader so auto-resume can restore sampler state) ----
-        self._init_resilience()
+        with self._tracer.scope("ds.init.resume"):
+            self._init_resilience()
 
         if self._train_obs is not None:
             # everything up to here — construction, compile-cache setup,
@@ -512,90 +532,92 @@ class DeepSpeedTpuEngine:
         """Master params fp32 (BF16/FP16 optimizer semantics: reference
         bf16_optimizer.py:34 keeps fp32 master weights), sharded per plan."""
         ctx = self.mesh_ctx
-        # host (numpy) leaves stay on the host until device_put places each
-        # shard: jnp.asarray would stage the whole tree on device 0 first
-        params = jax.tree_util.tree_map(
-            lambda x: (jnp.asarray(x, dtype=jnp.float32)
-                       if isinstance(x, jax.Array)
-                       else np.asarray(x, dtype=np.float32)), params)
-        # Compiler-scheduled ZeRO-3 (runtime/zero3_schedule.py): when the
-        # bucketed wire is on and the mesh qualifies, the fp32 masters live
-        # as 1/dp-sharded flat buckets (+ replicated persistent leaves)
-        # instead of a leaf tree — the optimizer state below is then built
-        # OVER the store, so moments shard identically (params+opt ~dp×
-        # smaller per chip). Grads are store-shaped too.
-        from .zero3_schedule import init_param_store, zero3_store_supported
-        self._zero3_store = None
-        self._zero3_schedule = None
-        if zero3_store_supported(self):
-            init_param_store(self, params)  # sets params/param_shardings/_zero3_store
-        else:
-            self.param_shardings = self.zero_plan.param_shardings(params)
-            self.params = jax.device_put(params, self.param_shardings)
-
-        self.grad_shardings = (self.param_shardings if self._zero3_store is not None
-                               else self.zero_plan.grad_shardings(params))
-        acc_dtype = self.grad_accum_dtype
-        zeros_fn = jax.jit(
-            lambda p: jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, acc_dtype), p),
-            out_shardings=self.grad_shardings)
-        self.grad_acc = zeros_fn(self.params)
-
-        if self._offload_device in ("cpu", "nvme") and self._offload_ratio >= 1.0:
-            # no device opt state at all — that's the HBM saving
-            self.opt_state = None
-            self.opt_state_shardings = None
-            self._build_host_optimizer(params)
-        elif self._offload_device in ("cpu", "nvme"):
-            # Twin-Flow partial offload: split leaves at the `ratio` element
-            # boundary (leaf-greedy ≙ reference sub-group split). Host subset:
-            # numpy Adam; device subset: the fused optax path. set_to_zero on
-            # the host subset keeps those params untouched by the device
-            # program — the host step merges its masters back afterwards.
-            from .host_offload import flatten_tree, unflatten_like
-            # sizes come from array metadata — no device->host transfer here
-            flat = flatten_tree(params)
-            total = sum(v.size for v in flat.values())
-            budget = self._offload_ratio * total
-            cum, labels = 0, {}
-            for k, v in flat.items():
-                if cum < budget:
-                    labels[k] = "host"
-                    self._host_param_names.add(k)
-                    cum += v.size
-                else:
-                    labels[k] = "device"
-            label_tree = unflatten_like(labels, params)
-            self._device_tx = optax.multi_transform(
-                {"device": self.base_tx, "host": optax.set_to_zero()}, label_tree)
-            opt_state_shape = jax.eval_shape(self._device_tx.init, self.params)
-            self.opt_state_shardings = self.zero_plan.opt_state_shardings(opt_state_shape)
-            self.opt_state = jax.jit(self._device_tx.init,
-                                     out_shardings=self.opt_state_shardings)(self.params)
-            self._build_host_optimizer(params, subset=self._host_param_names)
-            log_dist(f"Twin-Flow partial offload: {cum}/{total} elements "
-                     f"({cum/total:.2f}) on host, rest on device", ranks=[0])
-        else:
-            opt_state_shape = jax.eval_shape(self.base_tx.init, self.params)
-            if self._zero3_store is not None:
-                from .zero3_schedule import store_opt_state_shardings
-                self.opt_state_shardings = store_opt_state_shardings(
-                    opt_state_shape, self.param_shardings, self.mesh_ctx)
+        with self._tracer.scope("ds.init.place_params"):
+            # host (numpy) leaves stay on the host until device_put places each
+            # shard: jnp.asarray would stage the whole tree on device 0 first
+            params = jax.tree_util.tree_map(
+                lambda x: (jnp.asarray(x, dtype=jnp.float32)
+                           if isinstance(x, jax.Array)
+                           else np.asarray(x, dtype=np.float32)), params)
+            # Compiler-scheduled ZeRO-3 (runtime/zero3_schedule.py): when the
+            # bucketed wire is on and the mesh qualifies, the fp32 masters live
+            # as 1/dp-sharded flat buckets (+ replicated persistent leaves)
+            # instead of a leaf tree — the optimizer state below is then built
+            # OVER the store, so moments shard identically (params+opt ~dp×
+            # smaller per chip). Grads are store-shaped too.
+            from .zero3_schedule import init_param_store, zero3_store_supported
+            self._zero3_store = None
+            self._zero3_schedule = None
+            if zero3_store_supported(self):
+                init_param_store(self, params)  # sets params/param_shardings/_zero3_store
             else:
-                self.opt_state_shardings = self.zero_plan.opt_state_shardings(opt_state_shape)
-            self.opt_state = jax.jit(self.base_tx.init,
-                                     out_shardings=self.opt_state_shardings)(self.params)
+                self.param_shardings = self.zero_plan.param_shardings(params)
+                self.params = jax.device_put(params, self.param_shardings)
 
-        # Pin every piece of loop-carried state to an explicit NamedSharding —
-        # a leaf whose sharding differs between iterations (eager-created
-        # scalars come back SingleDeviceSharding) forces a jit recompile every
-        # step.
-        repl = self.mesh_ctx.replicated()
-        self.scale_state = jax.device_put(self.scaler_cfg.initial_state(), repl)
-        self.scale_state_shardings = jax.tree_util.tree_map(lambda _: repl,
-                                                            tuple(self.scale_state))
-        self._one = jax.device_put(jnp.float32(1.0), repl)
+        with self._tracer.scope("ds.init.opt_state"):
+            self.grad_shardings = (self.param_shardings if self._zero3_store is not None
+                                   else self.zero_plan.grad_shardings(params))
+            acc_dtype = self.grad_accum_dtype
+            zeros_fn = jax.jit(
+                lambda p: jax.tree_util.tree_map(
+                    lambda x: jnp.zeros(x.shape, acc_dtype), p),
+                out_shardings=self.grad_shardings)
+            self.grad_acc = zeros_fn(self.params)
+
+            if self._offload_device in ("cpu", "nvme") and self._offload_ratio >= 1.0:
+                # no device opt state at all — that's the HBM saving
+                self.opt_state = None
+                self.opt_state_shardings = None
+                self._build_host_optimizer(params)
+            elif self._offload_device in ("cpu", "nvme"):
+                # Twin-Flow partial offload: split leaves at the `ratio` element
+                # boundary (leaf-greedy ≙ reference sub-group split). Host subset:
+                # numpy Adam; device subset: the fused optax path. set_to_zero on
+                # the host subset keeps those params untouched by the device
+                # program — the host step merges its masters back afterwards.
+                from .host_offload import flatten_tree, unflatten_like
+                # sizes come from array metadata — no device->host transfer here
+                flat = flatten_tree(params)
+                total = sum(v.size for v in flat.values())
+                budget = self._offload_ratio * total
+                cum, labels = 0, {}
+                for k, v in flat.items():
+                    if cum < budget:
+                        labels[k] = "host"
+                        self._host_param_names.add(k)
+                        cum += v.size
+                    else:
+                        labels[k] = "device"
+                label_tree = unflatten_like(labels, params)
+                self._device_tx = optax.multi_transform(
+                    {"device": self.base_tx, "host": optax.set_to_zero()}, label_tree)
+                opt_state_shape = jax.eval_shape(self._device_tx.init, self.params)
+                self.opt_state_shardings = self.zero_plan.opt_state_shardings(opt_state_shape)
+                self.opt_state = jax.jit(self._device_tx.init,
+                                         out_shardings=self.opt_state_shardings)(self.params)
+                self._build_host_optimizer(params, subset=self._host_param_names)
+                log_dist(f"Twin-Flow partial offload: {cum}/{total} elements "
+                         f"({cum/total:.2f}) on host, rest on device", ranks=[0])
+            else:
+                opt_state_shape = jax.eval_shape(self.base_tx.init, self.params)
+                if self._zero3_store is not None:
+                    from .zero3_schedule import store_opt_state_shardings
+                    self.opt_state_shardings = store_opt_state_shardings(
+                        opt_state_shape, self.param_shardings, self.mesh_ctx)
+                else:
+                    self.opt_state_shardings = self.zero_plan.opt_state_shardings(opt_state_shape)
+                self.opt_state = jax.jit(self.base_tx.init,
+                                         out_shardings=self.opt_state_shardings)(self.params)
+
+            # Pin every piece of loop-carried state to an explicit NamedSharding —
+            # a leaf whose sharding differs between iterations (eager-created
+            # scalars come back SingleDeviceSharding) forces a jit recompile every
+            # step.
+            repl = self.mesh_ctx.replicated()
+            self.scale_state = jax.device_put(self.scaler_cfg.initial_state(), repl)
+            self.scale_state_shardings = jax.tree_util.tree_map(lambda _: repl,
+                                                                tuple(self.scale_state))
+            self._one = jax.device_put(jnp.float32(1.0), repl)
 
     def _build_host_optimizer(self, params, subset=None):
         """ZeRO-Offload host optimizer (numpy Adam ≙ cpu_adam; NVMe moments
@@ -1141,19 +1163,20 @@ class DeepSpeedTpuEngine:
             import signal
             os.kill(os.getpid(), signal.SIGTERM)
         if self._sentry is not None and self._async_window is None:
-            if losses_vec is not None:
-                lv = np.asarray(host_fetch(losses_vec)).ravel()
-                ov = (np.asarray(host_fetch(overflows_vec)).ravel()
-                      if overflows_vec is not None else np.zeros(len(lv)))
-                base = self.global_steps - len(lv)
-                obs = [(float(l), bool(o), base + i + 1)
-                       for i, (l, o) in enumerate(zip(lv, ov))]
-            else:
-                l = (None if loss is None
-                     else float(np.asarray(host_fetch(loss)).ravel()[-1]))
-                o = (bool(host_fetch(overflow))
-                     if overflow is not None and self._use_loss_scaling else False)
-                obs = [(l, o, self.global_steps)]
+            with self._tracer.scope("ds.train.loss_read"):
+                if losses_vec is not None:
+                    lv = np.asarray(host_fetch(losses_vec)).ravel()
+                    ov = (np.asarray(host_fetch(overflows_vec)).ravel()
+                          if overflows_vec is not None else np.zeros(len(lv)))
+                    base = self.global_steps - len(lv)
+                    obs = [(float(l), bool(o), base + i + 1)
+                           for i, (l, o) in enumerate(zip(lv, ov))]
+                else:
+                    l = (None if loss is None
+                         else float(np.asarray(host_fetch(loss)).ravel()[-1]))
+                    o = (bool(host_fetch(overflow))
+                         if overflow is not None and self._use_loss_scaling else False)
+                    obs = [(l, o, self.global_steps)]
             for l, o, s in obs:
                 self._sentry.observe(l, o, s)
                 if self._sentry.should_rollback:
@@ -1615,7 +1638,8 @@ class DeepSpeedTpuEngine:
         if w is None or not w.entries:
             return
         entries, duration, comm_steps = w.take()
-        with self._obs_span("host_sync_stall"):
+        with self._obs_span("host_sync_stall"), \
+                self._tracer.scope("ds.train.loss_read", steps=len(entries)):
             # the ONE deliberate device→host block of the window
             fetched = host_fetch([(loss, ovf) for (_, loss, ovf) in entries])
         total_steps, n_overflow, last_loss = 0, 0, None
@@ -1643,11 +1667,12 @@ class DeepSpeedTpuEngine:
                 gcc.quantization_block_size, duration, comm_steps,
                 op="reduce_scatter")
             self._bank_zero3_gathers(comm_steps)
-        if self.monitor is not None:
-            self.monitor.flush_events(fetch=host_fetch)
-        self._publish_registry_events(
-            window_start=self.global_steps - total_steps,
-            window_len=total_steps)
+        with self._tracer.scope("ds.train.publish"):
+            if self.monitor is not None:
+                self.monitor.flush_events(fetch=host_fetch)
+            self._publish_registry_events(
+                window_start=self.global_steps - total_steps,
+                window_len=total_steps)
         if getattr(self, "_sentry", None) is not None:
             # async-mode sentry feed: the window's values were just fetched
             # in the batched transfer above — zero additional syncs
@@ -1680,14 +1705,18 @@ class DeepSpeedTpuEngine:
         # reference, where eval mode never blocks train_batch
         self._training = True
         if self._train_step_fused is not None:
-            batch = next(data_iter)
+            with self._tracer.scope("ds.train.data_wait"):
+                batch = next(data_iter)
             if not isinstance(batch, tuple):
                 batch = (batch, )
             loss = self.fused_train_step(*batch)
             # async mode returns the LIVE device scalar — float() here would
             # reinstate the very per-step barrier the window removes; callers
             # wanting a host number use get_loss() (drains the window)
-            return loss if self._async_window is not None else float(loss)
+            if self._async_window is not None:
+                return loss
+            with self._tracer.scope("ds.train.loss_read"):
+                return float(loss)  # the host waits for the device here
         if self._train_batch_fused is not None:
             return self._run_fused_train_batch(data_iter)
         losses = []
@@ -1709,24 +1738,27 @@ class DeepSpeedTpuEngine:
         axis, run the scan-fused program (one dispatch per optimizer step)."""
         gas = self.gradient_accumulation_steps()
         micros = []
-        for _ in range(gas):
-            batch = next(data_iter)
-            if not isinstance(batch, tuple):
-                batch = (batch, )
-            batch, kw = self._apply_data_efficiency(batch, {})
-            assert not kw, "fused gas path takes positional batch arrays only"
-            micros.append(batch)
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *micros)
-        stacked = jax.device_put(
-            stacked, self.zero_plan.batch_sharding(stacked, stacked=True))
+        with self._tracer.scope("ds.train.data_wait"):
+            for _ in range(gas):
+                batch = next(data_iter)
+                if not isinstance(batch, tuple):
+                    batch = (batch, )
+                batch, kw = self._apply_data_efficiency(batch, {})
+                assert not kw, "fused gas path takes positional batch arrays only"
+                micros.append(batch)
+        with self._tracer.scope("ds.train.batch_put"):
+            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *micros)
+            stacked = jax.device_put(
+                stacked, self.zero_plan.batch_sharding(stacked, stacked=True))
         step_t0 = time.perf_counter()
         self.tput_timer.start()
         self._flops_profile_pre(self._train_batch_fused,
                                 (self.params, self.opt_state, self.scale_state,
                                  stacked, ()))
-        (loss, self.params, self.opt_state, self.scale_state, overflow,
-         gnorm) = self._train_batch_fused(self.params, self.opt_state,
-                                          self.scale_state, stacked, ())
+        with self._tracer.scope("ds.train.dispatch"):
+            (loss, self.params, self.opt_state, self.scale_state, overflow,
+             gnorm) = self._train_batch_fused(self.params, self.opt_state,
+                                              self.scale_state, stacked, ())
         self._last_grad_norm = gnorm
         self.losses = loss
         self.micro_steps += gas
@@ -1741,19 +1773,24 @@ class DeepSpeedTpuEngine:
             if self._grad_comm_layout is not None:
                 self._async_window.comm_steps += 1
             self._push_async_step(loss, overflow)
-            self._flops_profile_post()
-            self._resilience_step_boundary(loss=loss, overflow=overflow)
+            with self._tracer.scope("ds.train.publish"):
+                self._flops_profile_post()
+                self._resilience_step_boundary(loss=loss, overflow=overflow)
             return loss
         if self._use_loss_scaling and bool(overflow):
             self.skipped_steps += 1
         else:
             self._advance_schedule()
-        if self.monitor is not None:
-            self.monitor.write_events([("Train/Samples/train_loss", float(loss),
-                                        self.global_samples)])
-        self._publish_registry_events()
-        self._flops_profile_post()
-        loss_val = float(loss)  # blocks on the dispatch
+        with self._tracer.scope("ds.train.publish"):
+            if self.monitor is not None:
+                with self._tracer.scope("ds.train.loss_read"):
+                    loss_f = float(loss)
+                self.monitor.write_events([("Train/Samples/train_loss", loss_f,
+                                            self.global_samples)])
+            self._publish_registry_events()
+            self._flops_profile_post()
+        with self._tracer.scope("ds.train.loss_read"):
+            loss_val = float(loss)  # blocks on the dispatch
         if self._grad_comm_layout is not None:
             # per-step wire volume -> CommsLogger/calc_bw_log; the in-trace
             # collectives can't time themselves, so bank the host-measured
@@ -1766,7 +1803,8 @@ class DeepSpeedTpuEngine:
                 str(tier), gcc.quantization_block_size,
                 duration=time.perf_counter() - step_t0, op="reduce_scatter")
             self._bank_zero3_gathers(1)
-        self._resilience_step_boundary(loss=loss, overflow=overflow)
+        with self._tracer.scope("ds.train.publish"):
+            self._resilience_step_boundary(loss=loss, overflow=overflow)
         return loss_val
 
     def _bank_zero3_gathers(self, steps: int):
@@ -1796,10 +1834,12 @@ class DeepSpeedTpuEngine:
         assert self._train_step_fused is not None, \
             "fused_train_step requires gradient_accumulation_steps == 1"
         self.tput_timer.start()
-        args, kwargs = self._apply_data_efficiency(args, kwargs)
+        with self._tracer.scope("ds.train.data_wait"):
+            args, kwargs = self._apply_data_efficiency(args, kwargs)
         kwargs, static_kv = _split_static_kwargs(kwargs)
-        args = jax.device_put(args, self.zero_plan.batch_sharding(args))
-        kwargs = jax.device_put(kwargs, self.zero_plan.batch_sharding(kwargs))
+        with self._tracer.scope("ds.train.batch_put"):
+            args = jax.device_put(args, self.zero_plan.batch_sharding(args))
+            kwargs = jax.device_put(kwargs, self.zero_plan.batch_sharding(kwargs))
         step_fn = self._train_step_fused
         if self._wire_step is not None and self.global_steps >= self._wire_freeze_step:
             # post-warmup: packed 1-bit momentum exchange replaces the fp32
@@ -1808,9 +1848,10 @@ class DeepSpeedTpuEngine:
         self._flops_profile_pre(step_fn, (self.params, self.opt_state,
                                           self.scale_state, args, kwargs,
                                           static_kv))
-        (loss, self.params, self.opt_state, self.scale_state, overflow,
-         gnorm) = step_fn(self.params, self.opt_state, self.scale_state,
-                          args, kwargs, static_kv)
+        with self._tracer.scope("ds.train.dispatch"):
+            (loss, self.params, self.opt_state, self.scale_state, overflow,
+             gnorm) = step_fn(self.params, self.opt_state, self.scale_state,
+                              args, kwargs, static_kv)
         self._last_grad_norm = gnorm
         self.losses = loss
         self.micro_steps += 1
@@ -1827,12 +1868,16 @@ class DeepSpeedTpuEngine:
                 self.skipped_steps += 1
             else:
                 self._advance_schedule()
-            if self.monitor is not None:
-                self.monitor.write_events([("Train/Samples/train_loss", float(loss),
-                                            self.global_samples)])
-            self._publish_registry_events()
-        self._flops_profile_post()
-        self._resilience_step_boundary(loss=loss, overflow=overflow)
+        with self._tracer.scope("ds.train.publish"):
+            if self._async_window is None:
+                if self.monitor is not None:
+                    with self._tracer.scope("ds.train.loss_read"):
+                        loss_f = float(loss)
+                    self.monitor.write_events([("Train/Samples/train_loss", loss_f,
+                                                self.global_samples)])
+                self._publish_registry_events()
+            self._flops_profile_post()
+            self._resilience_step_boundary(loss=loss, overflow=overflow)
         return loss
 
     def eval_batch(self, *args, **kwargs):
@@ -1874,17 +1919,19 @@ class DeepSpeedTpuEngine:
                 "random-LTD batch routing — use fused_train_step")
         kwargs, static_kv = _split_static_kwargs(kwargs)
         K = jax.tree_util.tree_leaves(args + tuple(kwargs.values()))[0].shape[0]
-        args = jax.device_put(args, self.zero_plan.batch_sharding(args, stacked=True))
-        kwargs = jax.device_put(kwargs,
-                                self.zero_plan.batch_sharding(kwargs, stacked=True))
+        with self._tracer.scope("ds.train.batch_put"):
+            args = jax.device_put(args, self.zero_plan.batch_sharding(args, stacked=True))
+            kwargs = jax.device_put(kwargs,
+                                    self.zero_plan.batch_sharding(kwargs, stacked=True))
         self.tput_timer.start()
         self._flops_profile_pre(self._train_steps_fused,
                                 (self.params, self.opt_state, self.scale_state,
                                  args, kwargs, static_kv), steps=K)
-        (losses, self.params, self.opt_state, self.scale_state, overflows,
-         gnorms) = self._train_steps_fused(self.params, self.opt_state,
-                                           self.scale_state, args, kwargs,
-                                           static_kv)
+        with self._tracer.scope("ds.train.dispatch", steps=int(K)):
+            (losses, self.params, self.opt_state, self.scale_state, overflows,
+             gnorms) = self._train_steps_fused(self.params, self.opt_state,
+                                               self.scale_state, args, kwargs,
+                                               static_kv)
         self._last_grad_norm = gnorms[-1]
         self.losses = losses[-1]
         self.micro_steps += K
@@ -1903,16 +1950,18 @@ class DeepSpeedTpuEngine:
             self.skipped_steps += n_overflow
             for _ in range(K - n_overflow):
                 self._advance_schedule()
-            if self.monitor is not None:
-                base = self.global_samples - (K - 1) * self.train_batch_size()
-                self.monitor.write_events(
-                    [("Train/Samples/train_loss", float(l),
-                      base + i * self.train_batch_size())
-                     for i, l in enumerate(np.asarray(losses))])
-            self._publish_registry_events(
-                window_start=self.global_steps - K, window_len=K)
-        self._flops_profile_post()
-        self._resilience_step_boundary(losses_vec=losses, overflows_vec=overflows)
+        with self._tracer.scope("ds.train.publish"):
+            if self._async_window is None:
+                if self.monitor is not None:
+                    base = self.global_samples - (K - 1) * self.train_batch_size()
+                    self.monitor.write_events(
+                        [("Train/Samples/train_loss", float(l),
+                          base + i * self.train_batch_size())
+                         for i, l in enumerate(np.asarray(losses))])
+                self._publish_registry_events(
+                    window_start=self.global_steps - K, window_len=K)
+            self._flops_profile_post()
+            self._resilience_step_boundary(losses_vec=losses, overflows_vec=overflows)
         return losses
 
     def module_forward(self, *args, **kwargs):

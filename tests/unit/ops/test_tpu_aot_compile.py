@@ -109,3 +109,62 @@ def test_rms_norm_compiles(one_chip, no_compile_cache):
     x = _sds((1024, HIDDEN), jnp.bfloat16, one_chip)
     w = _sds((HIDDEN, ), jnp.bfloat16, one_chip)
     _compile(functools.partial(rms_norm, force_pallas=True), x, w)
+
+
+def _custom_call_names(compiled):
+    """Instruction names of the program's Mosaic kernels: what the device
+    trace's "XLA Ops" line calls them."""
+    return [line.split(" = ")[0].split("%")[-1]
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["one_chip", "shard_map_2x2"])
+def test_flash_kernels_keep_their_names_in_the_compiled_program(
+        topo, one_chip, no_compile_cache, monkeypatch, meshed):
+    """The training cell's attention (1 x 4096 x 32/8 heads x 128 a chip,
+    window 4096) and its gradient: forward, dq and dk+dv are named for what
+    they are on one chip and inside the shard_map that ``models/llama.py``
+    wraps them in (``sequence/layer.py:ulysses_flash``), where they used to
+    take the shard_map's name. The benchmark's flash roofline readers and
+    ``breakdown.device_ops`` key on these names."""
+    seq = 4096
+    if meshed:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from deepspeed_tpu.comm.mesh import MeshContext
+        from deepspeed_tpu.sequence.layer import ulysses_flash
+        # code that asks "is this a TPU" sees the CPU here: steer it in the
+        # test, as the kernel is what is being compiled
+        monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
+                            lambda force=None: True)
+        ctx = MeshContext.create({"fsdp": 4}, devices=topo.devices)
+        where = NamedSharding(ctx.mesh, P("fsdp", None, None, None))
+        rows = 4
+
+        def attend(q, k, v):
+            return ulysses_flash(q, k, v, window=WINDOW, mesh_ctx=ctx)
+    else:
+        where, rows = one_chip, 1
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=WINDOW,
+                                   force_pallas=True)
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    def sds(heads):
+        return _sds((rows, seq, heads, D), jnp.bfloat16, where)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(H), sds(KV), sds(KV))
+    names = _custom_call_names(compiled)
+    assert len(names) == 3, names
+    for prefix in ("flash_fwd", "flash_dq", "flash_dkdv"):
+        assert sum(n.startswith(prefix) for n in names) == 1, names
+    assert not any(n.startswith("shard_map") for n in names)
+    # each chip's call is the cell's shape: [rows * kv, group, seq, d]
+    fwd, = [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "%flash_fwd" in line.split(" = ")[0]]
+    assert f"bf16[{KV},{H // KV},{seq},{D}]" in fwd
