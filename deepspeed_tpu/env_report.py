@@ -80,6 +80,12 @@ def debug_report():
                      f"{kernel_dispatch.table_source()}")
         lines.append(f"attn dispatch @ bench shape {'.' * 21} "
                      f"{kernel_dispatch.resolved_note()}")
+        # each chip's call in the benchmark's train-zero3-seq4k cell
+        # (Mistral-7B widths): the blocks are chosen from the shape
+        lines.append(f"attn dispatch @ [1,4096,32/8,128] w4096 {'.' * 9} "
+                     + kernel_dispatch.resolved_note(
+                         batch=1, seq=4096, heads=32, kv_heads=8,
+                         head_dim=128, window=4096))
     except Exception as e:  # pragma: no cover
         lines.append(f"attn dispatch table {'.' * 29} {NO} ({e})")
     try:

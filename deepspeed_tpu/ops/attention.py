@@ -14,11 +14,17 @@ rows of a KV group against one K/V block — K/V are never expanded to query
 heads (G× HBM saving), and the folded G dimension *fattens* the MXU matmul.
 
 Forward (grid ``(B*KV, q_blocks, kv_blocks)``, kv innermost): accumulators
-(o, m, l) persist in VMEM scratch across the kv sweep; the log-sum-exp is
-written out as a residual. Backward is the standard two-pass recompute:
-a dq kernel sweeps kv blocks per q block, a dk/dv kernel sweeps q blocks
-per kv block; both rebuild p from the saved LSE (no second online softmax)
-and skip fully-masked blocks under causal.
+(o, m, l) persist in VMEM scratch across the kv sweep, m and l replicated
+over the lanes; the log-sum-exp is written out as a residual. Backward is
+the standard two-pass recompute: a dq kernel sweeps kv blocks per q block, a
+dk/dv kernel sweeps q blocks per kv block on the TRANSPOSED score tile
+(``k . q^T``, so dv and dk contract the minor dimension); both rebuild p
+from the saved LSE (no second online softmax). A block wholly above the
+causal diagonal or outside the window is skipped and fetches nothing: the
+swept operand's index map is clamped to the live range, so a dead step
+names the block already resident. Blocks come from the shape
+(``kernel_dispatch.choose_blocks``: 512 keys and up to 1024 folded query
+rows a step where the sequences allow).
 """
 
 import functools
@@ -69,11 +75,93 @@ def _xla_attention(q, k, v, scale, causal, window=None, softcap=None):
     return out.reshape(B, Sq, H, D)
 
 
-def _row_pos(shape, block_q, offset):
-    """Absolute q position of each row in a [G*BQ, BK] score tile (rows are
-    g-major: row = g * BQ + pos)."""
-    r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    return offset + r % block_q
+# Row statistics (running max and sum, the saved LSE, delta) are kept in
+# VMEM lane-replicated, [rows, STAT_LANES]: a [rows, 1] column costs as many
+# vector registers as a [rows, 128] tile with one lane of each in use, and
+# every use pays a lane broadcast. Replicated, a load is dense and widening
+# to the score tile re-uses the same registers.
+STAT_LANES = 128
+
+
+def _lanes(x, n):
+    """A lane-replicated [rows, STAT_LANES] statistic as [rows, n]."""
+    if n <= STAT_LANES:
+        return x if n == STAT_LANES else x[:, :n]
+    if n % STAT_LANES == 0:
+        return jnp.concatenate([x] * (n // STAT_LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _mask_scores(s, q_pos, k_pos, causal, window):
+    if causal:
+        s = jnp.where(k_pos > q_pos, NEG_INF, s)
+    if window is not None:  # local attention: drop keys out of window
+        s = jnp.where(q_pos - k_pos >= window, NEG_INF, s)
+    return s
+
+
+def _when_live(qi, ki, block_q, block_k, causal, window, compute):
+    """Run ``compute(masked)`` on the [q block qi] x [kv block ki] tile if it
+    is live (some (q, k) pair unmasked), with ``masked=False`` if it is
+    interior (every pair unmasked), so that it skips the iota + select mask
+    chain (splash-style full/edge specialization: at seq >> block most live
+    tiles are interior)."""
+    if not causal and window is None:
+        compute(masked=False)
+        return
+    live = interior = True
+    if causal:
+        live = ki * block_k <= qi * block_q + block_q - 1
+        interior = ki * block_k + block_k - 1 <= qi * block_q
+    if window is not None:
+        live = live & (ki * block_k + block_k - 1
+                       >= qi * block_q - (window - 1))
+        interior = interior & (
+            qi * block_q + block_q - 1 - ki * block_k <= window - 1)
+
+    @pl.when(live & interior)
+    def _():
+        compute(masked=False)
+
+    @pl.when(live & jnp.logical_not(interior))
+    def _():
+        compute(masked=True)
+
+
+def _live_kv_block(i, j, block_q, block_k, num_kv, causal, window):
+    """Index-map clamp of the swept kv block ``j`` to the live range of q
+    block ``i``: a dead step then names the block its neighbour already
+    holds and the pipeline copies nothing (``_when_live`` skips its
+    arithmetic)."""
+    if not causal and window is None:
+        return j
+    if window is not None:
+        j = jnp.maximum(j, jnp.maximum(i * block_q - (window - 1), 0) // block_k)
+    if causal:
+        j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    return jnp.minimum(j, num_kv - 1)
+
+
+def _live_q_block(j, i, block_q, block_k, num_q, causal, window):
+    """The same for the dk/dv kernel, which sweeps q blocks ``i`` per kv
+    block ``j``."""
+    if not causal and window is None:
+        return i
+    if causal:
+        i = jnp.maximum(i, (j * block_k) // block_q)
+    if window is not None:
+        i = jnp.minimum(i, (j * block_k + block_k - 1 + window - 1) // block_q)
+    return jnp.minimum(i, num_q - 1)
+
+
+def _compiler_params(vmem_bytes):
+    """A tile set whose estimate passes the compiler's scoped-VMEM default
+    asks for what it needs (the chip has several times the default); the
+    blocks the dispatcher picks stay under it and take the default."""
+    from .kernel_dispatch import vmem_limit_bytes
+    limit = vmem_limit_bytes(vmem_bytes)
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 # ---------------------------------------------------------------------------
@@ -107,76 +195,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:  # Gemma-2: cap BEFORE masking
             s = softcap_scores(s, softcap)
-        if masked and (causal or window is not None):
-            q_pos = _row_pos(s.shape, block_q, qi * block_q)
+        if masked:
+            # rows are g-major: row = g * BQ + pos
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) % block_q
             k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if causal:
-                s = jnp.where(k_pos > q_pos, NEG_INF, s)
-            if window is not None:  # local attention: drop keys out of window
-                s = jnp.where(q_pos - k_pos >= window, NEG_INF, s)
-        # Everything row-wise stays 2D [G*BQ, 1]: Mosaic cannot shape-cast a
-        # lane-dim vector into a sublane column ((1,G,BQ)->(G*BQ,1) is an
-        # "unsupported shape cast"), so no 1D intermediates are ever formed.
+            s = _mask_scores(s, q_pos, k_pos, causal, window)
+        # m, l and the rescale factor are [G*BQ, STAT_LANES], lane-replicated
+        # (no 1D intermediates: Mosaic cannot shape-cast a lane-dim vector
+        # into a sublane column)
         m_prev, l_prev = m_s[:], l_s[:]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         m_safe = jnp.where(m_cur <= NEG_INF, 0.0, m_cur)
-        p = jnp.exp(s - m_safe)
+        p = jnp.exp(s - _lanes(m_safe, block_k))
         if masked:
             # an INTERIOR block's scores are real numbers — only edge
             # blocks can carry NEG_INF rows that must zero out
             p = jnp.where(s <= NEG_INF, 0.0, p)
         corr = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - m_safe))
-        l_cur = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        l_s[:] = l_prev * corr + p.sum(axis=-1, keepdims=True)
         # p back to the input dtype for the MXU (standard flash practice —
         # GPU flash uses fp16/bf16 P too); the accumulator stays fp32
         pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc[:] = acc[:] * corr + pv
+        acc[:] = acc[:] * _lanes(corr, d) + pv
         m_s[:] = m_cur
-        l_s[:] = l_cur
 
-    cond = True
-    if causal:
-        cond = ki * block_k <= qi * block_q + block_q - 1
-    if window is not None:  # skip blocks entirely older than the window
-        cond = cond & (ki * block_k + block_k - 1 >= qi * block_q - (window - 1))
-    if not causal and window is None:
-        if cond is True:
-            _compute(masked=False)
-        else:  # pragma: no cover — cond is always True without causal/window
-            @pl.when(cond)
-            def _():
-                _compute(masked=False)
-    else:
-        # full/edge block specialization (splash-style): a block strictly
-        # inside the causal/window region skips the iota+select mask chain
-        # entirely — at seq >> block, most live blocks are interior, and
-        # the 0801T1906 trace showed this elementwise work dominating the
-        # kernel (70% of step time at ~6% of model FLOPs)
-        interior = True
-        if causal:
-            interior = ki * block_k + block_k - 1 <= qi * block_q
-        if window is not None:  # every (q, k) pair strictly inside window
-            interior = interior & (
-                qi * block_q + block_q - 1 - ki * block_k <= window - 1)
-
-        @pl.when(cond & interior)
-        def _():
-            _compute(masked=False)
-
-        @pl.when(cond & jnp.logical_not(interior))
-        def _():
-            _compute(masked=True)
+    _when_live(qi, ki, block_q, block_k, causal, window, _compute)
 
     @pl.when(ki == num_kv - 1)
     def _finalize():
-        g, bq = o_ref.shape[1], o_ref.shape[2]
-        l = l_s[:]  # [G*BQ, 1]
+        g, bq, d = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
+        l = l_s[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[:] / safe_l).reshape(g, bq, -1).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / _lanes(safe_l, d)).reshape(g, bq, d).astype(
+            o_ref.dtype)
         m_safe = jnp.where(m_s[:] <= NEG_INF, 0.0, m_s[:])
         lse = jnp.where(l == 0.0, LSE_MASKED, m_safe + jnp.log(safe_l))
-        lse_ref[0] = lse.reshape(g, bq, 1)
+        lse_ref[0] = lse[:, :1].reshape(g, bq, 1)
 
 
 def _regroup(q, k, v):
@@ -211,30 +267,40 @@ def resolved_attention_variant() -> str:
     return "folded" if _use_folded() else "per-head"
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
-               softcap=None):
-    """Per-head Pallas forward → (o, lse[B*KV, G, Sq, 1])."""
-    B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
-    assert H % KV == 0, (H, KV)
-    G = H // KV
+def _blocked(Sq, Sk, block_q, block_k):
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     assert Sq % block_q == 0 and Sk % block_k == 0, (
         f"seq lens ({Sq},{Sk}) must be divisible by blocks ({block_q},{block_k})")
-    num_q, num_kv = Sq // block_q, Sk // block_k
+    return block_q, block_k, Sq // block_q, Sk // block_k
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
+               softcap=None):
+    """Per-head Pallas forward → (o, lse[B*KV, G, Sq, 1])."""
+    from .kernel_dispatch import flash_vmem_bytes
+    B, Sq, H, D = q.shape
+    Sk, KV = k.shape[1], k.shape[2]
+    assert H % KV == 0, (H, KV)
+    G = H // KV
+    block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
 
     qg, kt, vt = _regroup(q, k, v)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, num_kv=num_kv,
                                window=window, softcap=softcap)
+
+    def kv_map(b, i, j):
+        return (b, _live_kv_block(i, j, block_q, block_k, num_kv, causal,
+                                  window), 0)
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * KV, num_q, num_kv),
         in_specs=[
             pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_k, D), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
@@ -249,9 +315,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
         ],
         scratch_shapes=[
             pltpu.VMEM((G * block_q, D), jnp.float32),
-            pltpu.VMEM((G * block_q, 1), jnp.float32),
-            pltpu.VMEM((G * block_q, 1), jnp.float32),
+            pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
+            pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
         ],
+        compiler_params=_compiler_params(flash_vmem_bytes(
+            "fwd", G, D, q.dtype.itemsize, block_q, block_k)),
         interpret=interpret,
         name="flash_fwd",
     )(qg, kt, vt)
@@ -284,7 +352,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         v = v_ref[0]
         do = do_ref[0].reshape(g * bq, d)
         # lse/delta carry a trailing unit lane dim so this reshape is a
-        # supported Mosaic cast (minormost dim preserved); no 1D intermediates
+        # supported Mosaic cast (minormost dim preserved); no 1D
+        # intermediates. Read once a step, the columns cost less here than
+        # a lane-replicated copy in scratch (measured, PERF.md §6 PR 25)
         lse = lse_ref[0].reshape(g * bq, 1)
         delta = delta_ref[0].reshape(g * bq, 1)
 
@@ -293,13 +363,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         if softcap is not None:
             t = jnp.tanh(s / softcap)
             s = softcap * t  # == softcap_scores; t reused for d/ds = 1 - t^2
-        if masked and (causal or window is not None):
-            q_pos = _row_pos(s.shape, block_q, qi * block_q)
+        if masked:
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) % block_q
             k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if causal:
-                s = jnp.where(k_pos > q_pos, NEG_INF, s)
-            if window is not None:
-                s = jnp.where(q_pos - k_pos >= window, NEG_INF, s)
+            s = _mask_scores(s, q_pos, k_pos, causal, window)
         p = jnp.exp(s - lse)
         if masked:  # interior blocks never carry NEG_INF scores
             p = jnp.where(s <= NEG_INF, 0.0, p)
@@ -312,29 +380,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
                                          (((1, ), (0, )), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    cond = True
-    if causal:
-        cond = ki * block_k <= qi * block_q + block_q - 1
-    if window is not None:
-        cond = cond & (ki * block_k + block_k - 1 >= qi * block_q - (window - 1))
-    if not causal and window is None:
-        _compute(masked=False)
-    else:
-        # full/edge specialization — see _fwd_kernel
-        interior = True
-        if causal:
-            interior = ki * block_k + block_k - 1 <= qi * block_q
-        if window is not None:
-            interior = interior & (
-                qi * block_q + block_q - 1 - ki * block_k <= window - 1)
-
-        @pl.when(cond & interior)
-        def _():
-            _compute(masked=False)
-
-        @pl.when(cond & jnp.logical_not(interior))
-        def _():
-            _compute(masked=True)
+    _when_live(qi, ki, block_q, block_k, causal, window, _compute)
 
     @pl.when(ki == num_kv - 1)
     def _finalize():
@@ -362,64 +408,41 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].reshape(g * bq, d)
-        lse = lse_ref[0].reshape(g * bq, 1)
-        delta = delta_ref[0].reshape(g * bq, 1)
+        lse = lse_ref[0, 0]      # [1, G*BQ]: rows, g-major like q's
+        delta = delta_ref[0, 0]
 
-        s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
+        # the scores TRANSPOSED, [BK, G*BQ] = k . q^T: dv and dk below are
+        # then plain [BK, G*BQ] x [G*BQ, D] products. Contracting p and ds
+        # over their first dimension instead makes Mosaic transpose both
+        # tiles on every step (that was half of this kernel's time).
+        s = jax.lax.dot_general(k, q, (((1, ), (1, )), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             t = jnp.tanh(s / softcap)
             s = softcap * t  # == softcap_scores; t reused for d/ds = 1 - t^2
-        if masked and (causal or window is not None):
-            q_pos = _row_pos(s.shape, block_q, qi * block_q)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if causal:
-                s = jnp.where(k_pos > q_pos, NEG_INF, s)
-            if window is not None:
-                s = jnp.where(q_pos - k_pos >= window, NEG_INF, s)
+        if masked:
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) % block_q
+            s = _mask_scores(s, q_pos, k_pos, causal, window)
         p = jnp.exp(s - lse)
         if masked:  # interior blocks never carry NEG_INF scores
             p = jnp.where(s <= NEG_INF, 0.0, p)
-        # dv += pᵀ @ do ; dk += dsᵀ @ q — over the folded G*BQ rows, which
+        # dv += p^T @ do ; dk += ds^T @ q — over the folded G*BQ rows, which
         # also sums the G query heads sharing this KV head (GQA reduce)
         dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0, ), (0, )), ((), ())),
+                                         (((1, ), (0, )), ((), ())),
                                          preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1, ), (1, )), ((), ())),
+        dp = jax.lax.dot_general(v, do, (((1, ), (1, )), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         if softcap is not None:
             ds = ds * (1.0 - t * t)
         dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
-                                         (((0, ), (0, )), ((), ())),
+                                         (((1, ), (0, )), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    cond = True
-    if causal:
-        # a q block contributes iff its last row can see this kv block
-        cond = qi * block_q + block_q - 1 >= ki * block_k
-    if window is not None:  # ...and its first row is not past the window
-        cond = cond & (qi * block_q <= ki * block_k + block_k - 1 + (window - 1))
-    if not causal and window is None:
-        _compute(masked=False)
-    else:
-        # full/edge specialization — see _fwd_kernel. Interior here means
-        # every (q, k) pair in the tile is unmasked: the whole q block is
-        # at-or-after the kv block (causal) and inside the window
-        interior = True
-        if causal:
-            interior = ki * block_k + block_k - 1 <= qi * block_q
-        if window is not None:
-            interior = interior & (
-                qi * block_q + block_q - 1 - ki * block_k <= window - 1)
-
-        @pl.when(cond & interior)
-        def _():
-            _compute(masked=False)
-
-        @pl.when(cond & jnp.logical_not(interior))
-        def _():
-            _compute(masked=True)
+    _when_live(qi, ki, block_q, block_k, causal, window, _compute)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -431,13 +454,16 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
                softcap=None):
     """Per-head Pallas backward; ``res`` carries lse in the per-head
     [B*KV, G, Sq, 1] layout."""
+    from .kernel_dispatch import flash_vmem_bytes
     q, k, v, o, lse = res
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    num_q, num_kv = Sq // block_q, Sk // block_k
+    block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
+    params = _compiler_params(flash_vmem_bytes(
+        "bwd", G, D, q.dtype.itemsize, block_q, block_k))
+    static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                  window=window, softcap=softcap)
 
     qg, kt, vt = _regroup(q, k, v)
     dog, _, _ = _regroup(g_out, k, v)
@@ -445,31 +471,43 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [B*KV, G, Sq, 1] — unit lane dim, see lse
 
+    def kv_map(b, i, j):
+        return (b, _live_kv_block(i, j, block_q, block_k, num_kv, causal,
+                                  window), 0)
+
     q_spec = pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
+    k_spec = pl.BlockSpec((1, block_k, D), kv_map)
     r_spec = pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0))
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_kv=num_kv,
-                          window=window, softcap=softcap),
+        functools.partial(_dq_kernel, num_kv=num_kv, **static),
         grid=(B * KV, num_q, num_kv),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((G * block_q, D), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_dq",
     )(qg, kt, vt, dog, lse, delta)
 
-    # kv-major grid for dk/dv: q sweep innermost
-    q_spec2 = pl.BlockSpec((1, G, block_q, D), lambda b, j, i: (b, 0, i, 0))
+    # kv-major grid for dk/dv: q sweep innermost. lse and delta enter as
+    # rows of the transposed score tile: [B*KV, q blocks, 1, G*BQ], g-major
+    # inside a block like the folded q rows
+    def rows(x):
+        return (x.reshape(B * KV, G, num_q, block_q).transpose(0, 2, 1, 3)
+                .reshape(B * KV, num_q, 1, G * block_q))
+
+    def q_blk(j, i):
+        return _live_q_block(j, i, block_q, block_k, num_q, causal, window)
+
+    q_spec2 = pl.BlockSpec((1, G, block_q, D),
+                           lambda b, j, i: (b, 0, q_blk(j, i), 0))
     k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    r_spec2 = pl.BlockSpec((1, G, block_q, 1), lambda b, j, i: (b, 0, i, 0))
+    r_spec2 = pl.BlockSpec((1, 1, 1, G * block_q),
+                           lambda b, j, i: (b, q_blk(j, i), 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q=num_q,
-                          window=window, softcap=softcap),
+        functools.partial(_dkdv_kernel, num_q=num_q, **static),
         grid=(B * KV, num_kv, num_q),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
         out_specs=[
@@ -484,9 +522,10 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
         name="flash_dkdv",
-    )(qg, kt, vt, dog, lse, delta)
+    )(qg, kt, vt, dog, rows(lse), rows(delta))
 
     dq = (dq.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
           .reshape(B, Sq, H, D))
@@ -678,7 +717,8 @@ def flash_attention(q,
     the built-in heuristic table (XLA fused fwd + Pallas flash bwd at
     hd64/seq>=1024 — the round-5 chip measurement). ``impl_fwd``/
     ``impl_bwd`` ("xla" | "pallas" | "folded") pin a leg explicitly (tests,
-    the sweep tool); ``block_q``/``block_k`` pin the Pallas tile sizes.
+    the sweep tool); ``block_q``/``block_k`` pin the Pallas tile sizes,
+    which otherwise follow from the shape (``kernel_dispatch.choose_blocks``).
     Off-TPU without interpret, the pure-XLA fused path runs both legs.
     """
     from . import kernel_dispatch as kd

@@ -25,8 +25,10 @@ per leg, strongest first:
    Pallas backward always).
 
 Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
-measured cache blocks > per-head_dim defaults (the round-5 sweep result
-(256, 512) at hd64).
+measured cache blocks > ``choose_blocks``, a pure function of the shape
+signature and a VMEM estimate of the leg's tiles (512 keys and a 256-query
+block folded to at most 1024 rows a step; head size 64 at group 1 keeps the
+(256, 512) its round-5 sweep measured).
 """
 
 import os
@@ -39,11 +41,6 @@ IMPL_XLA = "xla"
 IMPL_PALLAS = "pallas"  # per-head kernels (ops/attention.py)
 IMPL_FOLDED = "folded"  # head-folded kernels (ops/attention_folded.py)
 _IMPLS = (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED)
-
-# head_dim -> default (block_q, block_k).  hd64 = (256, 512) measured on
-# v5e 2026-08-01: +20% over (256, 256) on the identical bench program.
-BLOCK_TABLE = {64: (256, 512), 128: (128, 128)}
-DEFAULT_BLOCKS = (128, 128)
 
 # candidate (block_q, block_k) grid the offline sweep times, beyond the
 # defaults — the round-5 sweep died at the window edge before reaching them
@@ -134,8 +131,98 @@ def _env_blocks() -> Optional[tuple]:
         return None
 
 
-def default_blocks(head_dim: int) -> tuple:
-    return BLOCK_TABLE.get(head_dim, DEFAULT_BLOCKS)
+# What the block choice aims at (the PR 25 sweep on a v5e at head size 128,
+# PERF.md §6): 512 keys a grid step, so the step's fixed cost and the
+# per-row softmax statistics are paid once per 512 keys; a 256-row query
+# block, folded with its group to at most 1024 rows, so K/V are re-read at
+# most once per 1024 query rows. Head size 64 at group 1 comes out at the
+# (256, 512) its own sweep measured (2026-08-01: +20% over (256, 256)).
+KEY_BLOCK = 512
+QUERY_BLOCK = 256
+MAX_ROWS = 1024
+# Mosaic's default scoped-VMEM limit on a v5e (the core has 128 MiB behind
+# it). The blocks chosen here stay under it by the estimate below; explicit
+# or measured blocks past it get their own limit on the call.
+VMEM_SCOPED_DEFAULT_BYTES = 16 * 2**20
+
+
+def flash_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
+                     block_q: int, block_k: int) -> int:
+    """Upper estimate of the VMEM one grid step of the per-head flash
+    kernels holds (``ops/attention.py``), for ``leg`` "fwd" or "bwd" (the
+    larger of the dq and dk/dv kernels). Counted: every pipelined operand
+    and result block twice (double buffering), the scratch accumulators and
+    the forward's lane-replicated statistics, and the score-tile
+    temporaries: two fp32 tiles and p's cast in the forward, three and both
+    casts in the backward. A ``[rows, 1]`` block occupies a full 128-lane
+    row. Checked against the v5e compiler (described-chip compiles, PR 25):
+    every tile set it put under the default compiled, the first refusals
+    stand at 22 MiB (forward) and 25 MiB (backward) of this estimate."""
+    rows = group * block_q
+    lanes = max(head_dim, 128)
+    q_blk = rows * lanes * itemsize
+    kv_blk = block_k * lanes * itemsize
+    stat = rows * 128 * 4
+    tile = rows * block_k
+    if leg == "fwd":
+        blocks = 2 * q_blk + 2 * kv_blk + stat        # q, o; k, v; lse
+        scratch = rows * lanes * 4 + 2 * stat         # acc; m, l
+        temps = tile * (2 * 4 + itemsize)
+    else:
+        # dq: q, do, dq; k, v; lse, delta | dk/dv: q, do; k, v, dk, dv; rows
+        blocks = max(3 * q_blk + 2 * kv_blk + 2 * stat,
+                     2 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4)
+        scratch = max(rows * lanes * 4, 2 * block_k * lanes * 4)
+        temps = tile * (3 * 4 + 2 * itemsize)
+    return 2 * blocks + scratch + temps
+
+
+def vmem_limit_bytes(estimate: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel whose tiles are estimated at
+    ``estimate``: None (the compiler's default) while it fits, else the
+    estimate and a quarter more."""
+    if estimate <= VMEM_SCOPED_DEFAULT_BYTES:
+        return None
+    return estimate * 5 // 4
+
+
+def _largest_block(seq: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``seq`` and is at most
+    ``cap``; for a sequence with none (shorter than 128, or not a multiple
+    of it), the whole sequence if it fits, else its largest power-of-two
+    divisor under the cap."""
+    for b in range(min(cap, seq) // 128 * 128, 0, -128):
+        if seq % b == 0:
+            return b
+    if seq <= cap:
+        return seq
+    b = 1
+    while b * 2 <= cap and seq % (b * 2) == 0:
+        b *= 2
+    return b
+
+
+def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
+    """(block_q, block_k) of a Pallas leg from the shape alone: KEY_BLOCK
+    keys and QUERY_BLOCK queries a step where the sequences allow, the
+    query block halved while the group folds it past MAX_ROWS rows, then
+    whichever of the two is larger halved while the leg's VMEM estimate is
+    over the compiler's default (fp32 operands in the backward, which holds
+    more score tiles at once, are what reach it)."""
+    group = max(1, sig.heads // sig.kv_heads)
+    itemsize = 4 if "32" in sig.dtype else 2
+    cap_q, cap_k = max(128, min(QUERY_BLOCK, MAX_ROWS // group)), KEY_BLOCK
+    while True:
+        bq = _largest_block(sig.seq_q, cap_q)
+        bk = _largest_block(sig.seq_k, cap_k)
+        over = flash_vmem_bytes(leg, group, sig.head_dim, itemsize, bq,
+                                bk) > VMEM_SCOPED_DEFAULT_BYTES
+        if not over or max(cap_q, cap_k) <= 128:
+            return bq, bk
+        if group * cap_q >= cap_k and cap_q > 128:
+            cap_q //= 2
+        else:
+            cap_k //= 2
 
 
 def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
@@ -198,7 +285,7 @@ def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
         impl = variant or IMPL_PALLAS
         source += "+pallas-forced"
 
-    # blocks: explicit > env > measured > head_dim default
+    # blocks: explicit > env > measured > chosen from the shape
     blocks = explicit_blocks or _env_blocks()
     if blocks is None and measured is not None:
         try:
@@ -206,7 +293,7 @@ def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
         except (KeyError, TypeError, ValueError):
             blocks = None
     if blocks is None:
-        blocks = default_blocks(sig.head_dim)
+        blocks = choose_blocks(sig, leg)
     return Decision(impl=impl, block_q=int(blocks[0]), block_k=int(blocks[1]),
                     source=source)
 
@@ -240,12 +327,12 @@ def table_source() -> str:
 
 
 def resolved_note(batch=8, seq=1024, heads=16, kv_heads=None, head_dim=64,
-                  dtype="bfloat16", causal=True,
+                  dtype="bfloat16", causal=True, window=None,
                   kind: Optional[str] = None) -> str:
     """The per-leg dispatch note at a given (default: THE bench) shape —
     reporting surfaces call this so every banked artifact records which
     kernels actually ran."""
     sig = make_sig((batch, seq, heads, head_dim),
                    kv_heads if kv_heads is not None else heads, seq, dtype,
-                   causal, None, None)
+                   causal, window, None)
     return describe(*resolve(sig, kind))

@@ -43,15 +43,15 @@ def _time(fn, iters: int, warmup: int = 1) -> float:
     return (time.perf_counter() - t0) / iters * 1e3  # ms
 
 
-def _blocks_for(impl: str, head_dim: int, quick: bool):
+def _blocks_for(impl: str, sig, leg: str, quick: bool):
     """Candidate (block_q, block_k) grid for a Pallas impl; XLA has none."""
     from deepspeed_tpu.ops import kernel_dispatch as kd
     if impl == kd.IMPL_XLA:
         return [None]
+    chosen = kd.choose_blocks(sig, leg)
     if quick:
-        return [kd.default_blocks(head_dim)]
-    cands = dict.fromkeys((kd.default_blocks(head_dim), ) + kd.SWEEP_BLOCKS)
-    return list(cands)
+        return [chosen]
+    return list(dict.fromkeys((chosen, ) + kd.SWEEP_BLOCKS))
 
 
 def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
@@ -98,7 +98,7 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
         rows = []
         for impl in impls:
             seen = set()
-            for blocks in _blocks_for(impl, head_dim, quick):
+            for blocks in _blocks_for(impl, sig, leg, quick):
                 if blocks is not None:
                     # a tile can't exceed the sequence — clamp, then dedupe
                     # (several candidates can clamp to the same point)
@@ -120,7 +120,7 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
             print(f"  {leg}: no candidate ran — leg left to heuristics")
             continue
         label, impl, blocks, ms = min(rows, key=lambda r: r[-1])
-        bq, bk = blocks or kd.default_blocks(head_dim)
+        bq, bk = blocks or kd.choose_blocks(sig, leg)
         entry = {"impl": impl, "block_q": bq, "block_k": bk,
                  "ms": round(ms, 4),
                  "note": f"ds_kernel_tune iters={iters}"}

@@ -27,27 +27,67 @@ for _ in range(14):
     ))
 
 
+def _case(s, h, kv, d, *, b=1, sk=None, causal=True, window=None, softcap=None,
+          blocks=None):
+    return dict(b=b, s=s, sk=sk, h=h, kv=kv, d=d, causal=causal, window=window,
+                softcap=softcap, blocks=blocks)
+
+
+# What the shape-chosen blocks bring (ops/kernel_dispatch.py:choose_blocks):
+# 512-key blocks with a smaller query block, groups of 1/4/8, head sizes
+# 64/128/256, and grid steps that are dead on either side of the band, whose
+# index maps are clamped to a live block.
+CASES += [
+    # block_q != block_k, 512 keys a step, each group size and head size
+    _case(1024, 4, 4, 64, blocks=(128, 512)),
+    _case(1024, 4, 1, 128, blocks=(128, 512)),
+    _case(1024, 8, 1, 128, blocks=(64, 512)),
+    _case(512, 2, 1, 256, blocks=(128, 512), softcap=50.0),
+    # the blocks the dispatcher itself picks at a Mistral-like group
+    _case(1024, 8, 2, 128, window=1024),
+    # a window whose edge falls inside a 512-key block
+    _case(1024, 4, 1, 128, window=300, blocks=(128, 512)),
+    _case(1024, 2, 2, 64, window=700, softcap=20.0, blocks=(256, 512)),
+    # rows whose whole key block is dead, before the window and after the
+    # diagonal: the clamped index maps must still read each live block
+    _case(1024, 4, 2, 64, window=128, blocks=(128, 128)),
+    _case(1024, 4, 1, 64, window=100, blocks=(128, 512)),
+    _case(1024, 2, 1, 128, window=96, blocks=(512, 128)),
+    # no mask at all, and more keys than queries
+    _case(512, 4, 1, 128, causal=False, blocks=(128, 512)),
+    _case(256, 4, 2, 64, sk=1024, causal=False, blocks=(128, 512)),
+    _case(512, 2, 1, 64, sk=256, causal=False, softcap=30.0, blocks=(256, 128)),
+    # a window with no causal mask: future keys all live, old ones dead
+    _case(512, 2, 2, 64, causal=False, window=64, blocks=(128, 128)),
+]
+
+
 @pytest.mark.parametrize("case", CASES, ids=lambda c: (
     f"b{c['b']}s{c['s']}h{c['h']}kv{c['kv']}d{c['d']}"
-    f"w{c['window']}c{c['softcap']}"))
+    f"w{c['window']}c{c['softcap']}"
+    + (f"k{c['sk']}" if c.get("sk") else "")
+    + ("" if c.get("causal", True) else "full")
+    + ("x".join(map(str, ("", ) + c["blocks"])) if c.get("blocks") else "")))
 def test_flash_matches_oracle(case):
     rng = np.random.default_rng(7)
+    causal, sk = case.get("causal", True), case.get("sk") or case["s"]
+    bq, bk = case.get("blocks") or (None, None)
     q = jnp.asarray(rng.normal(size=(case["b"], case["s"], case["h"], case["d"])),
                     jnp.float32)
-    k = jnp.asarray(rng.normal(size=(case["b"], case["s"], case["kv"], case["d"])),
+    k = jnp.asarray(rng.normal(size=(case["b"], sk, case["kv"], case["d"])),
                     jnp.float32)
-    v = jnp.asarray(rng.normal(size=(case["b"], case["s"], case["kv"], case["d"])),
+    v = jnp.asarray(rng.normal(size=(case["b"], sk, case["kv"], case["d"])),
                     jnp.float32)
     scale = 1.0 / np.sqrt(case["d"])
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=True, window=case["window"],
+        out = flash_attention(q, k, v, causal=causal, window=case["window"],
                               softcap=case["softcap"], interpret=True,
-                              force_pallas=True)
+                              force_pallas=True, block_q=bq, block_k=bk)
         return (out.astype(jnp.float32) ** 2).mean(), out
 
     def loss_ref(q, k, v):
-        out = _xla_attention(q, k, v, scale, True, case["window"],
+        out = _xla_attention(q, k, v, scale, causal, case["window"],
                              case["softcap"])
         return (out.astype(jnp.float32) ** 2).mean(), out
 

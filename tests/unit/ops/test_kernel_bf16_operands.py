@@ -30,20 +30,29 @@ def _oracle_grads(q, k, v, scale, causal):
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("kv_heads", [8, 2])  # MHA and GQA
-def test_flash_bf16_fwd_bwd_matches_fp32_oracle(kv_heads):
+@pytest.mark.parametrize("kv_heads,seq,d,blocks", [
+    (8, 256, 64, None),             # MHA
+    (2, 256, 64, None),             # GQA
+    (2, 1024, 128, None),           # group 4, head 128: the chosen (256, 512)
+    (1, 1024, 128, (64, 512)),      # group 8, 512 keys a step
+    (4, 512, 256, (128, 512)),      # head size 256
+    (8, 1024, 64, (256, 512)),      # head size 64 at its measured blocks
+])
+def test_flash_bf16_fwd_bwd_matches_fp32_oracle(kv_heads, seq, d, blocks):
     rng = np.random.default_rng(11)
-    q = jnp.asarray(rng.standard_normal((2, 256, 8, 64)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((2, 256, kv_heads, 64)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((2, 256, kv_heads, 64)), jnp.bfloat16)
+    b = 2 if seq <= 256 else 1
+    bq, bk = blocks or (None, None)
+    q = jnp.asarray(rng.standard_normal((b, seq, 8, d)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((b, seq, kv_heads, d)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((b, seq, kv_heads, d)), jnp.bfloat16)
 
     def L(q, k, v):
         o = flash_attention(q, k, v, causal=True, force_pallas=True,
-                            interpret=True)
+                            interpret=True, block_q=bq, block_k=bk)
         return (o.astype(jnp.float32) ** 2).mean()
 
     (lf, (dq, dk, dv)) = jax.value_and_grad(L, argnums=(0, 1, 2))(q, k, v)
-    lo, (dqo, dko, dvo) = _oracle_grads(q, k, v, 1.0 / 8.0, True)
+    lo, (dqo, dko, dvo) = _oracle_grads(q, k, v, 1.0 / np.sqrt(d), True)
 
     assert abs(float(lf) - float(lo)) / abs(float(lo)) < 2e-2
     for got, want, name in ((dq, dqo, "dq"), (dk, dko, "dk"), (dv, dvo, "dv")):

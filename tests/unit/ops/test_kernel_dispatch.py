@@ -148,7 +148,65 @@ def test_bench_shape_routes_xla_fwd_pallas_bwd():
     fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
     assert fwd.impl == kd.IMPL_XLA and fwd.source == "heuristic"
     assert bwd.impl == kd.IMPL_PALLAS and bwd.source == "heuristic"
-    assert (bwd.block_q, bwd.block_k) == kd.default_blocks(64)
+    assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(_bench_sig(), "bwd")
+    assert (bwd.block_q, bwd.block_k) == (256, 512)  # the 2026-08-01 sweep
+
+
+def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None):
+    return kd.make_sig((1, seq, heads, d), kv, seq_k or seq, dtype, True,
+                       window, None)
+
+
+@pytest.mark.parametrize("name,sig,fwd,bwd", [
+    # each chip's call in train-zero3-seq4k (Mistral-7B widths)
+    ("cell", _sig(4096, 32, 8, 128, window=4096), (256, 512), (256, 512)),
+    ("chip_smoke", _sig(2048, 32, 8, 128, window=4096), (256, 512), (256, 512)),
+    # the one shape with a block sweep on the chip before PR 25: unchanged
+    ("hd64_1024", _bench_sig(), (256, 512), (256, 512)),
+    ("llama3_70b", _sig(4096, 64, 8, 128), (128, 512), (128, 512)),
+    ("gemma2_hd256", _sig(4096, 16, 8, 256), (256, 512), (256, 512)),
+    ("ulysses_32k", _sig(32768, 8, 2, 128), (256, 512), (256, 512)),
+    # fp32 operands: the backward's tiles pass the default, so it halves
+    ("fp32", _sig(4096, 32, 8, 128, "float32"), (256, 512), (128, 512)),
+    ("seq128", _sig(128, 8, 8, 64), (128, 128), (128, 128)),
+    ("seq384", _sig(384, 8, 2, 64), (128, 384), (128, 384)),
+    ("seq64", _sig(64, 4, 4, 16), (64, 64), (64, 64)),
+    ("keys_ne_queries", _sig(256, 8, 2, 128, seq_k=1536), (256, 512),
+     (256, 512)),
+])
+def test_blocks_follow_from_the_shape(name, sig, fwd, bwd):
+    """``choose_blocks`` alone, no kernel: divisors of the sequences, a
+    VMEM estimate under the limit it assumes (so the call keeps the
+    compiler's default), and grid steps big enough to feed the MXU wherever
+    the sequence allows."""
+    group = sig.heads // sig.kv_heads
+    itemsize = 4 if sig.dtype == "float32" else 2
+    for leg, want in (("fwd", fwd), ("bwd", bwd)):
+        bq, bk = kd.choose_blocks(sig, leg)
+        assert (bq, bk) == want, (name, leg)
+        assert sig.seq_q % bq == 0 and sig.seq_k % bk == 0
+        est = kd.flash_vmem_bytes(leg, group, sig.head_dim, itemsize, bq, bk)
+        assert est <= kd.VMEM_SCOPED_DEFAULT_BYTES, (name, leg, est)
+        assert kd.vmem_limit_bytes(est) is None
+        if sig.seq_k >= 512 and sig.seq_q >= 512:
+            assert bk >= 512 and group * bq >= 256, (name, leg)
+    if name == "cell":
+        bq, bk = kd.choose_blocks(sig, "bwd")
+        assert bk >= 512 and 512 <= group * bq <= 1024
+    # and it is what an unpinned Pallas leg resolves to
+    _, dec = kd.resolve(sig, "TPU v5e")
+    assert (dec.block_q, dec.block_k) == bwd and dec.source == "heuristic"
+
+
+def test_blocks_past_the_default_vmem_ask_for_their_own_limit():
+    """Explicit blocks (the sweep tool's (1024, 1024)) are not shrunk: the
+    call carries a limit a quarter above the estimate instead."""
+    est = kd.flash_vmem_bytes("bwd", 4, 128, 2, 1024, 1024)
+    assert est > kd.VMEM_SCOPED_DEFAULT_BYTES
+    assert kd.vmem_limit_bytes(est) == est * 5 // 4
+    # the estimate grows with every tile dimension
+    assert est > kd.flash_vmem_bytes("bwd", 4, 128, 2, 512, 1024)
+    assert est > kd.flash_vmem_bytes("fwd", 4, 128, 2, 1024, 1024)
 
 
 def test_heuristic_boundaries():

@@ -88,11 +88,20 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
         _compile(kern, *args)
 
 
-@pytest.mark.parametrize("seq,window", [(2048, None), (4096, WINDOW)])
+@pytest.mark.parametrize("seq,window,heads,kv,d", [
+    (2048, None, H, KV, D),
+    (4096, WINDOW, H, KV, D),
+    (8192, WINDOW, H, KV, D),   # dead steps on both sides of the window
+    (4096, None, 64, 8, D),     # Llama-3-70B's group of 8
+    (4096, WINDOW, 16, 8, 256),  # Gemma-2's head size
+    (1024, None, 16, 16, 64),   # the 0.4B preset: head size 64, group 1
+])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
-                                          window):
-    def sds(heads):
-        return _sds((1, seq, heads, D), jnp.bfloat16, one_chip)
+                                          window, heads, kv, d):
+    """The blocks ``kernel_dispatch.choose_blocks`` picks for each shape fit
+    the chip's VMEM and tile; a refusal here costs no chip time."""
+    def sds(n):
+        return _sds((1, seq, n, d), jnp.bfloat16, one_chip)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, window=window,
@@ -100,7 +109,7 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
-                        sds(H), sds(KV), sds(KV))
+                        sds(heads), sds(kv), sds(kv))
     # forward, dq, and dk+dv
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
