@@ -476,17 +476,24 @@ class LlamaMoEBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.grouped_matmul import moe_grouped_mlp, moe_dense_mlp
+        from ..ops.grouped_matmul import (expert_counts, moe_grouped_mlp,
+                                          moe_dense_mlp)
         cfg = self.config
         E, k = cfg.num_local_experts, cfg.num_experts_per_tok
         H, F = cfg.hidden_size, cfg.intermediate_size
         logits = _dense(E, "gate", (EMBED, "expert"), jnp.float32)(x.astype(jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1)
         w, idx = jax.lax.top_k(probs, k)
+        # (token, choice) assignments per expert: what the engine's fused
+        # step returns beside the loss ("moe_stats", read only when mutable)
+        counts = expert_counts(idx, E)
+        self.sow("moe_stats", "expert_counts", counts,
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((E, ), jnp.int32))
         if cfg.router_aux_loss_coef > 0:
             # Switch/Mixtral load balance: E * sum_e(frac_routed_e * mean_prob_e)
             pe = probs.reshape(-1, E).mean(axis=0)
-            fe = jax.nn.one_hot(idx.reshape(-1), E).mean(axis=0)
+            fe = counts.astype(jnp.float32) / idx.size
             self.sow("aux_loss", "moe_load_balance",
                      cfg.router_aux_loss_coef * E * jnp.sum(fe * pe),
                      reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.float32(0.0))
@@ -494,11 +501,15 @@ class LlamaMoEBlock(nn.Module):
             w = w / jnp.sum(w, -1, keepdims=True)
         w = w.astype(cfg.dtype)
 
-        init = nn.with_partitioning(nn.initializers.lecun_normal(), ("expert", EMBED, HIDDEN))
+        # each expert is a matrix of its own: the expert axis is a batch
+        # axis of the initializer, not part of the fan-in (counted in, it
+        # made the seeded block's output E times too small to see)
+        lecun = nn.initializers.lecun_normal(batch_axis=(0, ))
+        init = nn.with_partitioning(lecun, ("expert", EMBED, HIDDEN))
         w1 = _use_cast(self.param("w1", init, (E, H, F), jnp.float32), cfg.dtype)
         w3 = _use_cast(self.param("w3", init, (E, H, F), jnp.float32), cfg.dtype)
         w2 = _use_cast(self.param("w2",
-                                  nn.with_partitioning(nn.initializers.lecun_normal(),
+                                  nn.with_partitioning(lecun,
                                                        ("expert", HIDDEN, EMBED)),
                                   (E, F, H), jnp.float32), cfg.dtype)
 
@@ -668,7 +679,8 @@ class LlamaModel(nn.Module):
             # aux_loss rides the scan as a stacked per-step axis (the engine
             # sums all leaves, so stacking ≡ the unscanned reduce_fn sum)
             ScanLayer = nn.scan(_ScanBody,
-                                variable_axes={"params": 0, "aux_loss": 0},
+                                variable_axes={"params": 0, "aux_loss": 0,
+                                               "moe_stats": 0},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,

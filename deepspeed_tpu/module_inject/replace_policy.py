@@ -218,6 +218,20 @@ class MixtralPolicy(HFCheckpointPolicy):
         return gate, experts
 
 
+def _mlp_experts_map(layer: int, num_experts: int):
+    """The ``mlp.gate`` / ``mlp.experts.E.{gate,up,down}_proj`` layout
+    Qwen2-MoE and OLMoE share, onto the MoE block's stacked w1/w3/w2."""
+    p = f"model.layers.{layer}.mlp."
+    f = f"layers_{layer}/block_sparse_moe/"
+    gate = {p + "gate.weight": (f + "gate/kernel", True)}
+    experts = {}
+    for hf_name, fx in (("gate_proj", "w1"), ("up_proj", "w3"),
+                        ("down_proj", "w2")):
+        experts[f + fx] = [p + f"experts.{e}.{hf_name}.weight"
+                           for e in range(num_experts)]
+    return gate, experts
+
+
 class Qwen2MoePolicy(MixtralPolicy):
     """Qwen2-MoE (reference ``inference/v2/model_implementations/qwen_v2_moe``):
     qwen2 attention (qkv biases) + sparse MoE with NON-renormalized top-k
@@ -242,21 +256,51 @@ class Qwen2MoePolicy(MixtralPolicy):
                 "shared_expert_intermediate_size"))
 
     def moe_map(self, layer: int, num_experts: int):
+        gate, experts = _mlp_experts_map(layer, num_experts)
         p = f"model.layers.{layer}.mlp."
         f = f"layers_{layer}/block_sparse_moe/"
-        gate = {
-            p + "gate.weight": (f + "gate/kernel", True),
-            p + "shared_expert_gate.weight": (f + "shared_expert_gate/kernel", True),
-        }
+        gate[p + "shared_expert_gate.weight"] = (f + "shared_expert_gate/kernel", True)
         for proj in ("gate_proj", "up_proj", "down_proj"):
             gate[p + f"shared_expert.{proj}.weight"] = (
                 f + f"shared_expert/{proj}/kernel", True)
-        experts = {}
-        for hf_name, fx in (("gate_proj", "w1"), ("up_proj", "w3"),
-                            ("down_proj", "w2")):
-            experts[f + fx] = [p + f"experts.{e}.{hf_name}.weight"
-                               for e in range(num_experts)]
         return gate, experts
+
+
+class OlmoePolicy(MixtralPolicy):
+    """OLMoE (HF ``modeling_olmoe.py``): PRE-norm llama layers whose
+    attention RMS-normalizes the flat q/k projections (OLMo2's q_norm/k_norm
+    without its post-norm residual) and whose every MLP is a sparse MoE of
+    ``num_experts`` SwiGLU experts ``intermediate_size`` wide, top-k of a
+    softmax router, NOT renormalized unless ``norm_topk_prob``; no shared
+    expert, no biases."""
+    arch = "olmoe"
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        if hf_config.get("attention_bias"):
+            raise ValueError("olmoe: attention_bias=True (biases on q/k/v AND "
+                             "o_proj) is not supported")
+        cfg = HFCheckpointPolicy.config_from_hf(self, hf_config)
+        return dataclasses.replace(
+            cfg,
+            clip_qkv=hf_config.get("clip_qkv"),
+            qk_norm=True,
+            num_local_experts=hf_config.get("num_experts", 64),
+            num_experts_per_tok=hf_config.get("num_experts_per_tok", 8),
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", False)),
+            # OlmoeConfig's class default; only training reads it
+            router_aux_loss_coef=hf_config.get("router_aux_loss_coef", 0.01))
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        out = super().weight_map(layer, attention_bias)
+        p = f"model.layers.{layer}.self_attn."
+        f = f"layers_{layer}/self_attn/"
+        out[p + "q_norm.weight"] = (f + "q_norm/weight", False)
+        out[p + "k_norm.weight"] = (f + "k_norm/weight", False)
+        return out
+
+    def moe_map(self, layer: int, num_experts: int):
+        return _mlp_experts_map(layer, num_experts)
 
 
 class GemmaPolicy(HFCheckpointPolicy):
@@ -1290,6 +1334,8 @@ _POLICIES = {
     "Qwen2ForCausalLM": Qwen2Policy,
     "mixtral": MixtralPolicy,
     "MixtralForCausalLM": MixtralPolicy,
+    "olmoe": OlmoePolicy,
+    "OlmoeForCausalLM": OlmoePolicy,
     "qwen2_moe": Qwen2MoePolicy,
     "qwen2moe": Qwen2MoePolicy,
     "Qwen2MoeForCausalLM": Qwen2MoePolicy,

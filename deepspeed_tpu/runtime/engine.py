@@ -63,25 +63,42 @@ def _tree_where(cond, a, b):
     return jax.tree_util.tree_map(lambda x, y: jnp.where(cond, x, y), a, b)
 
 
-def _as_apply_fn(model) -> Callable:
-    """Accept a flax Module, (module, method) or raw apply callable."""
+def _as_apply_fns(model):
+    """Accept a flax Module, (module, method) or raw apply callable. Returns
+    ``(apply_fn, apply_with_stats)``: the second also returns what a flax
+    model sowed for the host (``{}`` when nothing), and is ``None`` for a
+    raw callable."""
     if _HAS_FLAX and isinstance(model, nn.Module):
 
-        def apply_fn(params, *args, **kwargs):
+        def apply_with_stats(params, *args, **kwargs):
             # "aux_loss" is the contract for modules that sow auxiliary
             # training losses (MoE router load-balancing — reference
             # sharded_moe.py l_aux): sown scalars are ADDED to a scalar
-            # model loss; logits outputs pass through untouched
+            # model loss; logits outputs pass through untouched.
+            # "moe_stats" is the contract for routing counters: sown
+            # ``expert_counts`` ([..., E] per MoE block, a leading axis under
+            # a layer scan) come back summed over blocks, with the aux term
             out, mods = model.apply({"params": params}, *args, **kwargs,
-                                    mutable=["aux_loss"])
+                                    mutable=["aux_loss", "moe_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
+            aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
-                out = out + sum(jnp.sum(a) for a in aux)
-            return out
+                out = out + aux_total
+            stats = {}
+            counts = jax.tree_util.tree_leaves(mods.get("moe_stats", {}))
+            if counts:
+                stats["expert_counts"] = sum(
+                    c.reshape(-1, c.shape[-1]).sum(axis=0) for c in counts)
+                if aux:
+                    stats["aux_loss"] = aux_total.astype(jnp.float32)
+            return out, stats
 
-        return apply_fn
+        def apply_fn(params, *args, **kwargs):
+            return apply_with_stats(params, *args, **kwargs)[0]
+
+        return apply_fn, apply_with_stats
     if callable(model):
-        return model
+        return model, None
     raise TypeError(f"model must be a flax Module or callable apply_fn, got {type(model)}")
 
 
@@ -311,11 +328,14 @@ class DeepSpeedTpuEngine:
                              "fp32 accumulation otherwise")
 
         # ---- apply fn (+ activation checkpointing) ----
-        self.apply_fn = _as_apply_fn(model)
+        self.apply_fn, self._apply_with_stats = _as_apply_fns(model)
         ac = self._config.activation_checkpointing_config
         if ac.remat_policy:
             policy = getattr(jax.checkpoint_policies, ac.remat_policy, None)
             self.apply_fn = jax.checkpoint(self.apply_fn, policy=policy)
+            if self._apply_with_stats is not None:
+                self._apply_with_stats = jax.checkpoint(
+                    self._apply_with_stats, policy=policy)
 
         # ---- lr schedule ----
         self.lr_scheduler = None
@@ -395,6 +415,7 @@ class DeepSpeedTpuEngine:
         apc = self._config.async_pipeline_config
         self._async_window = (_AsyncStepWindow(apc.sync_interval)
                               if apc.enabled else None)
+        self._moe_pending = []   # device stats of fused MoE steps not yet published
 
         # ---- training/compiler observability (observability/xla.py +
         # observability/goodput.py): created before the compiled fns so the
@@ -694,8 +715,12 @@ class DeepSpeedTpuEngine:
                                                qgz=zc.zero_quantized_gradients,
                                                zero_axes=self.zero_plan.zero_axes)
 
+        apply_with_stats = self._apply_with_stats or (
+            lambda *a, **kw: (apply_fn(*a, **kw), {}))
+
         def loss_from_cparams(cparams, args, kwargs, static_kv, scale):
-            out = apply_fn(cparams, *args, **dict(kwargs, **dict(static_kv)))
+            out, stats = apply_with_stats(cparams, *args,
+                                          **dict(kwargs, **dict(static_kv)))
             if self._loss_fn is not None:
                 loss = self._loss_fn(out)
             else:
@@ -704,7 +729,7 @@ class DeepSpeedTpuEngine:
             scaled = loss.astype(jnp.float32) / gas
             if use_scaling:
                 scaled = scaled * scale
-            return scaled, loss
+            return scaled, (loss, stats)
 
         # param_cast="model": pass fp32 masters straight into apply and let
         # the model's use-site casts (flax `dtype=` convention) down-convert
@@ -730,7 +755,8 @@ class DeepSpeedTpuEngine:
             return loss_from_cparams(params, args, kwargs, static_kv, scale)
 
         def value_and_grads(params, args, kwargs, static_kv, scale):
-            """((scaled, loss), grads) for one microbatch. With engine-side
+            """((scaled, (loss, stats)), grads) for one microbatch (``stats``:
+            what the model sowed for the host, ``{}`` when nothing). With engine-side
             casting, differentiate wrt the COMPUTE-dtype cast of the params,
             not the fp32 masters, when possible: bit-identical values (the
             cast's VJP is an exact bf16->fp32 up-cast, so the fp32 cotangent
@@ -752,7 +778,7 @@ class DeepSpeedTpuEngine:
         def fwd_bwd(params, acc, scale, args, kwargs, static_kv):
             # acc dtype = grad_accum_dtype (fp32 default: full accumulation
             # precision across microbatches; bf16 opt-in halves the buffer)
-            (scaled, loss), grads = value_and_grads(
+            (scaled, (loss, _)), grads = value_and_grads(
                 params, args, kwargs, static_kv, scale)
             new_acc = jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), acc, grads)
             return loss, new_acc
@@ -855,7 +881,7 @@ class DeepSpeedTpuEngine:
         # host-driven kernel launches; under XLA the fusion is free win)
         def train_step(params, opt_state, scale_state, args, kwargs, static_kv):
             scale = scale_state.cur_scale if use_scaling else jnp.float32(1.0)
-            (_, loss), grads = value_and_grads(
+            (_, (loss, stats)), grads = value_and_grads(
                 params, args, kwargs, static_kv, scale)
             grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) / scale, grads)
             overflow = has_overflow(grads) if use_scaling else jnp.bool_(False)
@@ -869,14 +895,15 @@ class DeepSpeedTpuEngine:
                 new_params = _tree_where(overflow, params, new_params)
                 new_opt = _tree_where(overflow, opt_state, new_opt)
             new_scale_state = scaler_cfg.update(scale_state, overflow)
-            return loss, new_params, new_opt, new_scale_state, overflow, gnorm
+            return (loss, new_params, new_opt, new_scale_state, overflow, gnorm,
+                    stats)
 
         self._train_step_fused = jax.jit(
             train_step,
             donate_argnums=(0, 1),
             static_argnums=(5, ),
             out_shardings=(None, self.param_shardings, self.opt_state_shardings,
-                           scale_out, repl, repl),
+                           scale_out, repl, repl, repl),
         ) if gas == 1 and self._device_tx is None else None
         # (Twin-Flow needs the materialized grad buffer to snapshot the host
         # subset, so the one-program fused path is off under partial offload)
@@ -895,21 +922,21 @@ class DeepSpeedTpuEngine:
             def one(carry, batch):
                 p, o, s = carry
                 b_args, b_kwargs = batch
-                loss, p, o, s, overflow, gnorm = train_step(
+                loss, p, o, s, overflow, gnorm, stats = train_step(
                     p, o, s, b_args, b_kwargs, static_kv)
-                return (p, o, s), (loss, overflow, gnorm)
+                return (p, o, s), (loss, overflow, gnorm, stats)
 
-            (p, o, s), (losses, overflows, gnorms) = jax.lax.scan(
+            (p, o, s), (losses, overflows, gnorms, stats) = jax.lax.scan(
                 one, (params, opt_state, scale_state),
                 (stacked_args, stacked_kwargs))
-            return losses, p, o, s, overflows, gnorms
+            return losses, p, o, s, overflows, gnorms, stats
 
         self._train_steps_fused = jax.jit(
             train_steps,
             donate_argnums=(0, 1),
             static_argnums=(5, ),
             out_shardings=(None, self.param_shardings, self.opt_state_shardings,
-                           scale_out, repl, repl),
+                           scale_out, repl, repl, repl),
         ) if self._train_step_fused is not None else None
 
         # 1-bit compressed WIRE program (reference runtime/comm/nccl.py:16):
@@ -1562,6 +1589,40 @@ class DeepSpeedTpuEngine:
             return obs.ledger.span(category)
         return nullcontext()
 
+    def _publish_moe_stats(self):
+        """Routing counters of the fused MoE steps dispatched since the last
+        call, in one fetch. Called before the newest step's stats are held,
+        so what it reads has been computed (the step before was read, or
+        the window drained) and the device never waits for it: the gauges
+        lag the step counter by one dispatch."""
+        if not self._moe_pending:
+            return
+        from ..observability import get_registry
+        fetched, self._moe_pending = host_fetch(self._moe_pending), []
+        # [E] a step, [K, E] a K-step dispatch
+        counts = sum(np.asarray(s["expert_counts"], np.int64)
+                     .reshape(-1, s["expert_counts"].shape[-1]).sum(axis=0)
+                     for s in fetched)
+        reg = get_registry()
+        reg.counter(
+            "ds_moe_tokens_routed_total",
+            "(token, expert) assignments the router made, summed over MoE "
+            "layers and steps (top_k per token and layer: none is dropped)"
+        ).inc(float(counts.sum()))
+        reg.gauge(
+            "ds_moe_expert_load_max_over_mean",
+            "Busiest expert's assignments over the mean expert's, counts "
+            "summed over MoE layers and the steps of the last publish"
+        ).set(float(counts.max() / max(counts.mean(), 1.0)))
+        aux = [np.asarray(s["aux_loss"], np.float64).mean()
+               for s in fetched if "aux_loss" in s]
+        if aux:
+            reg.gauge(
+                "ds_moe_aux_loss",
+                "Router load-balancing term as added to the loss "
+                "(coefficient included, summed over layers), mean over the "
+                "steps of the last publish").set(float(np.mean(aux)))
+
     def _publish_registry_events(self, window_start=None, window_len=None):
         """Registry publish cadence: refresh derived observability views
         (MFU, memory, goodput fraction), fan the registry into the monitor
@@ -1670,6 +1731,7 @@ class DeepSpeedTpuEngine:
         with self._tracer.scope("ds.train.publish"):
             if self.monitor is not None:
                 self.monitor.flush_events(fetch=host_fetch)
+            self._publish_moe_stats()
             self._publish_registry_events(
                 window_start=self.global_steps - total_steps,
                 window_len=total_steps)
@@ -1850,8 +1912,8 @@ class DeepSpeedTpuEngine:
                                           static_kv))
         with self._tracer.scope("ds.train.dispatch"):
             (loss, self.params, self.opt_state, self.scale_state, overflow,
-             gnorm) = step_fn(self.params, self.opt_state, self.scale_state,
-                              args, kwargs, static_kv)
+             gnorm, stats) = step_fn(self.params, self.opt_state,
+                                     self.scale_state, args, kwargs, static_kv)
         self._last_grad_norm = gnorm
         self.losses = loss
         self.micro_steps += 1
@@ -1875,10 +1937,23 @@ class DeepSpeedTpuEngine:
                         loss_f = float(loss)
                     self.monitor.write_events([("Train/Samples/train_loss", loss_f,
                                                 self.global_samples)])
+                self._publish_moe_stats()   # of the steps before this one
                 self._publish_registry_events()
             self._flops_profile_post()
             self._resilience_step_boundary(loss=loss, overflow=overflow)
+        if stats:
+            self._moe_pending.append(stats)
         return loss
+
+    def moe_stats(self):
+        """Routing stats of the newest fused MoE step not yet published, as
+        host arrays: ``expert_counts`` ``[E]`` ((token, expert) assignments,
+        summed over the MoE layers) and, with a router loss, ``aux_loss``.
+        A device→host fetch that waits for that step; ``None`` for a model
+        that sows none."""
+        if not self._moe_pending:
+            return None
+        return host_fetch(self._moe_pending[-1])
 
     def eval_batch(self, *args, **kwargs):
         """Forward-only compiled path for evaluation.
@@ -1929,9 +2004,9 @@ class DeepSpeedTpuEngine:
                                  args, kwargs, static_kv), steps=K)
         with self._tracer.scope("ds.train.dispatch", steps=int(K)):
             (losses, self.params, self.opt_state, self.scale_state, overflows,
-             gnorms) = self._train_steps_fused(self.params, self.opt_state,
-                                               self.scale_state, args, kwargs,
-                                               static_kv)
+             gnorms, stats) = self._train_steps_fused(
+                 self.params, self.opt_state, self.scale_state, args, kwargs,
+                 static_kv)
         self._last_grad_norm = gnorms[-1]
         self.losses = losses[-1]
         self.micro_steps += K
@@ -1958,10 +2033,13 @@ class DeepSpeedTpuEngine:
                         [("Train/Samples/train_loss", float(l),
                           base + i * self.train_batch_size())
                          for i, l in enumerate(np.asarray(losses))])
+                self._publish_moe_stats()   # of the dispatches before this one
                 self._publish_registry_events(
                     window_start=self.global_steps - K, window_len=K)
             self._flops_profile_post()
             self._resilience_step_boundary(losses_vec=losses, overflows_vec=overflows)
+        if stats:
+            self._moe_pending.append(stats)
         return losses
 
     def module_forward(self, *args, **kwargs):

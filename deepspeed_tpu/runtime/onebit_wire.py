@@ -115,16 +115,16 @@ def build_wire_step(engine, name: str):
                     jax.tree_util.tree_map(lambda _: batch_spec, kwargs))
         fn = _smap(region, mesh, in_specs, (P(), P(), P(), P()), dp_axes)
         loss, new_params, new_opt, gnorm = fn(params, opt_state, args, kwargs)
-        # same output arity as the engine's fused step
+        # same output arity as the engine's fused step (no model stats)
         return (loss, new_params, new_opt, scale_state,
-                jnp.bool_(False), gnorm)
+                jnp.bool_(False), gnorm, {})
 
     from .loss_scaler import LossScaleState
     jitted = jax.jit(step, donate_argnums=(0, 1), static_argnums=(5, ),
                      out_shardings=(None, engine.param_shardings,
                                     engine.opt_state_shardings,
                                     LossScaleState(*engine.scale_state_shardings),
-                                    repl, repl))
+                                    repl, repl, repl))
     log_dist(f"1-bit wire program built: dp axes {dp_axes}, "
              f"optimizer {name} (packed uint8 sign exchange)", ranks=[0])
     return jitted

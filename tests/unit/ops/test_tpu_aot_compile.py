@@ -95,6 +95,7 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
     (4096, None, 64, 8, D),     # Llama-3-70B's group of 8
     (4096, WINDOW, 16, 8, 256),  # Gemma-2's head size
     (1024, None, 16, 16, 64),   # the 0.4B preset: head size 64, group 1
+    (4096, None, 16, 16, D),    # OLMoE: head size 128, group 1, no window
 ])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
                                           window, heads, kv, d):
@@ -118,6 +119,32 @@ def test_rms_norm_compiles(one_chip, no_compile_cache):
     x = _sds((1024, HIDDEN), jnp.bfloat16, one_chip)
     w = _sds((HIDDEN, ), jnp.bfloat16, one_chip)
     _compile(functools.partial(rms_norm, force_pallas=True), x, w)
+
+
+def test_grouped_matmul_compiles_to_the_native_kernel_at_olmoe_widths(
+        one_chip, no_compile_cache):
+    """OLMoE's MoE block a step: 16,384 tokens x top-8 = 131,072 rows over 64
+    experts of 2048 x 1024, forward and gradient. ``jax.lax.ragged_dot`` must
+    lower to the chip's grouped-matmul kernel (``%ragged-dot*`` custom calls,
+    FLOPs proportional to top-k: what ``benchmark/moe_cost.py`` matches in a
+    trace), three forward and nine with the gradient, and fit the chip."""
+    from deepspeed_tpu.ops.grouped_matmul import moe_grouped_mlp
+    T, HID, F, E, K = 16384, 2048, 1024, 64, 8
+    sds = functools.partial(_sds, sharding=one_chip)
+    args = [sds((T, HID), jnp.bfloat16), sds((E, HID, F), jnp.bfloat16),
+            sds((E, HID, F), jnp.bfloat16), sds((E, F, HID), jnp.bfloat16),
+            sds((T, K), jnp.int32), sds((T, K), jnp.bfloat16)]
+
+    def loss(*a):
+        return jnp.sum(moe_grouped_mlp(*a).astype(jnp.float32) ** 2)
+
+    for fn, calls in ((moe_grouped_mlp, 3),
+                      (jax.grad(loss, argnums=(0, 1, 2, 3)), 9)):
+        compiled = _compile(fn, *args)
+        names = [n for n in _custom_call_names(compiled)
+                 if n.startswith("ragged-dot") and "metadata" not in n]
+        assert len(names) == calls, names
+        assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
 
 
 def _custom_call_names(compiled):
