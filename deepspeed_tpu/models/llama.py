@@ -465,8 +465,9 @@ class LlamaMoEBlock(nn.Module):
     """Mixtral-style sparse MoE MLP (reference moe/sharded_moe.py gating +
     module_inject/containers mixtral): softmax router over E experts, top-k
     renormalized combine. Compute is a megablocks-style grouped GEMM
-    (``ops/grouped_matmul.py``: sort-by-expert → ragged_dot → weighted
-    scatter combine) so per-token FLOPs ∝ top-k, matching the reference's
+    (``ops/grouped_matmul.py``: sort-by-expert → gather into expert order →
+    ragged_dot → gather back by the inverse permutation and a weighted sum
+    over each token's k rows) so per-token FLOPs ∝ top-k, matching the reference's
     CUTLASS moe_gemm capability; ``moe_grouped=False`` keeps the
     dense-over-experts oracle (also the better layout when the 'expert'
     logical axis is sharded over a real mesh axis — EP uses moe/layer.py's

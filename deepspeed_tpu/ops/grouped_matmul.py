@@ -6,37 +6,47 @@ experts and each expert multiplies only its own tokens, so per-token FLOPs
 scale with top-k instead of the expert count E (the round-1 path computed
 every expert for every token and masked: E/k× wasted FLOPs).
 
-TPU design: sort the (token, choice) assignments by expert id (one XLA sort),
-run the three expert MLPs as ragged grouped GEMMs with
-``jax.lax.ragged_dot`` — on TPU/GPU this lowers to the native
+TPU design: sort the (token, choice) assignments by expert id (one XLA
+sort, giving the permutation ``order`` and its inverse ``inv``), gather the
+tokens into expert order, run the three expert MLPs as ragged grouped GEMMs
+with ``jax.lax.ragged_dot`` — on TPU/GPU this lowers to the native
 ``chlo.ragged_dot`` grouped-GEMM instruction (MXU, FLOPs ∝ top-k; the CPU
 backend decomposes to a dense-masked form, which only the test harness
 sees), the grouped-GEMM analog of the reference's CUTLASS kernel — then
-combine with a weighted scatter-add back to token order. Fully differentiable (ragged_dot carries transpose rules), static
-shapes throughout (T*k assignments regardless of routing), no capacity
-factor and no token dropping: exact token-choice semantics.
+gather the rows back by ``inv`` and sum each token's k rows, weighted, in
+float32. Dispatch and combine are permutations, so each carries its exact
+transpose as a ``jax.custom_vjp``: forward and backward move rows with
+gathers only, never a scatter-add (which XLA serialises, not knowing the
+indices cannot collide). The ``[T*k, ·]`` arrays between the matmuls cross
+HBM in ``x.dtype``; the MXU accumulates in float32 either way. Static shapes
+throughout (T*k assignments regardless of routing), no capacity factor and
+no token dropping: exact token-choice semantics.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 
-def moe_sort_tokens(top_idx):
+def moe_sort_permutation(top_idx):
     """Sort (token, choice) assignments by expert.
 
     Args:
       top_idx: ``[T, k]`` int32 expert id per (token, choice).
     Returns:
-      (tok_sorted ``[T*k]`` source token per sorted assignment,
-       order ``[T*k]`` the sort permutation over flattened assignments,
-       group_sizes ``[E?]`` — caller computes via bincount; returned here
-       as the sorted expert ids for convenience).
+      (order ``[T*k]`` int32: the flat assignment ``t*k + j`` at each sorted
+       row, a stable sort so ties keep token order;
+       inv ``[T*k]`` int32: the sorted row of each flat assignment,
+       ``inv[order] == arange(T*k)``).
+
+    The inverse is a second sort: on a v5e at 131,072 assignments 0.29 ms
+    beside the first sort's 0.25, against 0.67 ms for an iota scatter and
+    0.42 ms for a counting sort over the ``[T*k, E]`` compare.
     """
-    Tk = top_idx.size
-    flat_e = top_idx.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    tok_sorted = (jnp.arange(Tk, dtype=jnp.int32) // top_idx.shape[1])[order]
-    return tok_sorted, order, flat_e[order]
+    order = jnp.argsort(top_idx.reshape(-1), stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    return order, inv
 
 
 def expert_counts(top_idx, num_experts: int):
@@ -45,6 +55,59 @@ def expert_counts(top_idx, num_experts: int):
     against 1.34 ms for ``jnp.bincount``'s scatter-add."""
     return jnp.sum(top_idx.reshape(-1, 1) == jnp.arange(num_experts), axis=0,
                    dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, ))
+def moe_dispatch(x, order, inv, k):
+    """``x[T, H] → xs[T*k, H]``: row ``r`` of the result is token
+    ``order[r] // k``. Its transpose gathers the k rows of each token back
+    by ``inv`` and sums them in float32."""
+    return x[order // k]
+
+
+def _moe_dispatch_fwd(x, order, inv, k):
+    return moe_dispatch(x, order, inv, k), inv
+
+
+def _moe_dispatch_bwd(k, inv, dxs):
+    dx = jnp.sum(dxs[inv].reshape(inv.size // k, k, -1), axis=1,
+                 dtype=jnp.float32)
+    return dx.astype(dxs.dtype), None, None
+
+
+moe_dispatch.defvjp(_moe_dispatch_fwd, _moe_dispatch_bwd)
+
+
+@jax.custom_vjp
+def moe_combine(y, top_w, order, inv):
+    """``out[t] = Σ_j top_w[t, j] · y[inv[t*k + j]]``: ``y[T*k, H]`` in
+    expert order back to ``[T, H]`` tokens, the weighted sum over a token's
+    k rows taken in float32 and cast once to ``y.dtype``. Backward: the row
+    gradient is a gather of ``dout`` into expert order scaled by each row's
+    weight, and the weights' gradient ``Σ_h y · dout`` is taken in expert
+    order beside it (one pass over ``y``) and permuted as ``[T*k]`` scalars."""
+    T, k = top_w.shape
+    yk = y[inv].reshape(T, k, -1).astype(jnp.float32)
+    out = jnp.sum(yk * top_w.astype(jnp.float32)[:, :, None], axis=1)
+    return out.astype(y.dtype)
+
+
+def _moe_combine_fwd(y, top_w, order, inv):
+    return moe_combine(y, top_w, order, inv), (y, top_w, order, inv)
+
+
+def _moe_combine_bwd(res, dout):
+    y, top_w, order, inv = res
+    T, k = top_w.shape
+    g = dout[order // k].astype(jnp.float32)  # [T*k, H] expert order
+    w_sorted = top_w.reshape(-1)[order].astype(jnp.float32)
+    dy = (g * w_sorted[:, None]).astype(y.dtype)
+    dw_sorted = jnp.sum(y.astype(jnp.float32) * g, axis=-1)
+    dw = dw_sorted[inv].reshape(T, k).astype(top_w.dtype)
+    return dy, dw, None, None
+
+
+moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
 
 
 def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
@@ -61,30 +124,24 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
     Returns:
       ``[T, H]`` in x.dtype.
     """
-    T, H = x.shape
-    E = w1.shape[0]
     k = top_idx.shape[1]
+    order, inv = moe_sort_permutation(top_idx)
+    group_sizes = expert_counts(top_idx, w1.shape[0])
 
-    tok_sorted, order, _ = moe_sort_tokens(top_idx)
-    group_sizes = expert_counts(top_idx, E)
+    def gmm(rows, w):
+        # the kernel accumulates in float32 and rounds once on write: the
+        # same bits as a float32 result cast afterwards, half the bytes
+        return jax.lax.ragged_dot(rows, w, group_sizes,
+                                  preferred_element_type=x.dtype)
 
-    xs = x[tok_sorted]  # [T*k, H] expert-contiguous
-    h1 = jax.lax.ragged_dot(xs, w1, group_sizes,
-                            preferred_element_type=jnp.float32).astype(x.dtype)
-    h3 = jax.lax.ragged_dot(xs, w3, group_sizes,
-                            preferred_element_type=jnp.float32).astype(x.dtype)
-    act = activation(h1) * h3
-    y = jax.lax.ragged_dot(act, w2, group_sizes,
-                           preferred_element_type=jnp.float32)  # [T*k, H] fp32
-
-    w_sorted = top_w.reshape(-1)[order].astype(jnp.float32)
-    out = jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(y * w_sorted[:, None])
-    return out.astype(x.dtype)
+    xs = moe_dispatch(x, order, inv, k)  # [T*k, H] expert-contiguous
+    y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
+    return moe_combine(y, top_w, order, inv)
 
 
 def lora_sort_slots(slots, n_slots):
     """Sort per-token adapter slot ids for the grouped LoRA delta — the
-    k=1 specialization of :func:`moe_sort_tokens` (every token has exactly
+    k=1 specialization of :func:`moe_sort_permutation` (every token has exactly
     one adapter). Hoist this ONCE per forward and reuse the (order,
     group_sizes) pair across every layer/target: the sort is a function of
     the batch's slot assignment only.
