@@ -459,17 +459,22 @@ def _host_init(cfg):
         return init_llama(cfg, seed=SEED, dtype=jnp.float32)
 
 
-def _train(cfg, ds_config: dict, batch, steps: int) -> dict:
+def _train(cfg, ds_config: dict, batch, steps: int, chips=None) -> dict:
     """``deepspeed_tpu.initialize`` then ``steps`` ``train_batch`` calls on
     one repeated batch. Returns losses, step seconds, the fused step's
     program text and cache size, and every device's memory stats while the
-    state is still resident."""
+    state is still resident. ``chips``: the engine adopts a mesh over the
+    first that many devices (its default mesh takes every device the host
+    has and spreads the batch over them)."""
     import gc
     import jax
     import deepspeed_tpu
     from deepspeed_tpu.comm import reset_mesh_context
+    from deepspeed_tpu.comm.mesh import MeshContext, set_mesh_context
 
     reset_mesh_context()
+    if chips is not None:
+        set_mesh_context(MeshContext.create(devices=jax.devices()[:chips]))
     model, params = _host_init(cfg)
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=params, config=ds_config)
@@ -524,7 +529,7 @@ def training_phase(cfg, *, seq: int, batch: int = 1, steps: int = 4) -> dict:
         f"batch {batch} x {seq}")
     assert cfg.ce_chunk_size, "the trainer's chunked cross-entropy is on"
     facts = _train(cfg, _train_config(batch), _seeded_batch(cfg, batch, seq),
-                   steps)
+                   steps, chips=1)
     losses, secs = facts["losses"], facts["step_seconds"]
     log(f"  losses {[round(x, 4) for x in losses]}")
     assert np.isfinite(losses).all(), losses

@@ -349,7 +349,10 @@ class OrbaxCheckpointEngine(CheckpointEngine):
         return path
 
     def _write_host_state(self, path, host_state):
-        if host_state is not None:
+        # one sidecar per logical checkpoint, written by the process that
+        # seals it: a second process's open("wb") would truncate the file
+        # under process 0's manifest
+        if host_state is not None and jax.process_index() == 0:
             # pickle, not JSON: the reference torch.save()s arbitrary client
             # state (engine.py:3109) — numpy rng states etc. must round-trip
             import pickle
@@ -396,29 +399,46 @@ class OrbaxCheckpointEngine(CheckpointEngine):
                 self._pending_host_state = None
         path = self._pending_path
         self._pending_path = None
+        multi = jax.process_count() > 1
+        if multi:
+            # every process is done writing into the directory before
+            # process 0 reads it for the manifest ...
+            from jax.experimental import multihost_utils
+            multihost_utils.sync_global_devices(f"ds_checkpoint_written:{tag}")
+        sealed = True
         if path is not None and jax.process_index() == 0:
-            fi = get_fault_injector()
-            torn = fi.fire("checkpoint.torn_write", path=path, tag=tag)
-            if torn is not None:
-                # simulated crash mid-write: a truncated entry and no
-                # manifest/marker — the load path must detect and fall back
-                tear_checkpoint_dir(path,
-                                    truncate_to=int(torn.get("truncate_to", 16)))
-                logger.error(f"[OrbaxCheckpointEngine] commit of {tag} failed "
-                             "(torn write)")
-                return False
-            try:
-                write_manifest(path, tag)
-            except Exception as e:  # noqa: BLE001 — seal failure = no commit
-                logger.error(f"[OrbaxCheckpointEngine] could not seal {tag}: {e}")
-                return False
-            corrupt = fi.fire("checkpoint.corrupt", path=path, tag=tag)
-            if corrupt is not None:
-                # silent post-commit bit-rot: manifest verification at load
-                # time is the only thing standing between this and a bad
-                # resume — the marker stays, the data lies
-                corrupt_file_in(path, seed=fi.seed)
-        logger.info(f"[OrbaxCheckpointEngine] Checkpoint {tag} is ready now!")
+            sealed = self._seal(path, tag)
+        if multi:
+            # ... and none returns before the seal is there (or failed): a
+            # load right after a save must find the tag from any process
+            sealed = bool(multihost_utils.broadcast_one_to_all(
+                np.asarray(sealed, np.int32)))
+        if sealed:
+            logger.info(f"[OrbaxCheckpointEngine] Checkpoint {tag} is ready now!")
+        return sealed
+
+    def _seal(self, path, tag) -> bool:
+        fi = get_fault_injector()
+        torn = fi.fire("checkpoint.torn_write", path=path, tag=tag)
+        if torn is not None:
+            # simulated crash mid-write: a truncated entry and no
+            # manifest/marker — the load path must detect and fall back
+            tear_checkpoint_dir(path,
+                                truncate_to=int(torn.get("truncate_to", 16)))
+            logger.error(f"[OrbaxCheckpointEngine] commit of {tag} failed "
+                         "(torn write)")
+            return False
+        try:
+            write_manifest(path, tag)
+        except Exception as e:  # noqa: BLE001 — seal failure = no commit
+            logger.error(f"[OrbaxCheckpointEngine] could not seal {tag}: {e}")
+            return False
+        corrupt = fi.fire("checkpoint.corrupt", path=path, tag=tag)
+        if corrupt is not None:
+            # silent post-commit bit-rot: manifest verification at load
+            # time is the only thing standing between this and a bad
+            # resume — the marker stays, the data lies
+            corrupt_file_in(path, seed=fi.seed)
         return True
 
 

@@ -247,24 +247,15 @@ def _regroup(q, k, v):
     return qg, kt, vt
 
 
-def _use_folded() -> bool:
-    """Legacy probe (kept for bench.py's journal tagging): whether the
-    folded-variant *preference* is active (``DS_TPU_FLASH_FOLDED`` env).
-    Per-shape dispatch
-    (ops/kernel_dispatch.py) now owns the actual folded-vs-per-head choice;
-    this only reports the variant a Pallas leg falls back to when no
-    measurement decides it."""
-    from .kernel_dispatch import IMPL_FOLDED, _variant_preference
-    return _variant_preference() == IMPL_FOLDED
-
-
 def resolved_attention_variant() -> str:
-    """The flash-attention variant that will ACTUALLY run on a Pallas leg —
-    the env override resolved as the dispatcher resolves it. Reporting
-    surfaces (env_report, bench run tags) must use this. For the
-    full per-leg (fwd/bwd × impl × blocks) resolution use
+    """The flash-attention variant a Pallas leg falls back to when no
+    measurement decides it: the ``DS_TPU_FLASH_FOLDED`` preference resolved
+    as the dispatcher resolves it (``env_report`` prints it). Per-shape
+    dispatch (ops/kernel_dispatch.py) owns the actual folded-vs-per-head
+    choice; for the full per-leg (fwd/bwd × impl × blocks) resolution use
     ``kernel_dispatch.resolved_note``."""
-    return "folded" if _use_folded() else "per-head"
+    from .kernel_dispatch import IMPL_FOLDED, _variant_preference
+    return "folded" if _variant_preference() == IMPL_FOLDED else "per-head"
 
 
 def _blocked(Sq, Sk, block_q, block_k):

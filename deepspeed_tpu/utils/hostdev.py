@@ -1,10 +1,11 @@
-"""Forced host-device environments for multi-device tests and benches.
+"""Forced host-device environments for multi-device tests.
 
-JAX pins its backend at first import, so a process that wants N virtual
-CPU devices (``--xla_force_host_platform_device_count``) must set the
-environment BEFORE the interpreter imports jax — i.e. in a subprocess.
-Every mesh test / TP bench used to hand-roll the same env edits; this is
-the one canonical builder.
+JAX reads ``XLA_FLAGS`` when it creates its backend, so a process that
+wants N virtual CPU devices (``--xla_force_host_platform_device_count``)
+must have the flag in its environment before its first device query: a
+subprocess gets an environment built by ``force_host_devices_env``; a
+process that has not touched a device yet (``tests/conftest.py``) calls
+``ensure_host_devices``. This module owns the flag's spelling.
 """
 
 import os
@@ -33,3 +34,13 @@ def force_host_devices_env(n: int,
     if extra:
         env.update(extra)
     return env
+
+
+def ensure_host_devices(n: int) -> None:
+    """Make THIS process a CPU process of ``n`` virtual devices, unless
+    its ``XLA_FLAGS`` already force a count (a developer's, or a parent
+    test's for its subprocess: theirs wins). Only has an effect before
+    JAX creates its backend."""
+    flags = os.environ.get("XLA_FLAGS", "").split()
+    if not any(f.startswith(_FORCE_FLAG) for f in flags):
+        os.environ["XLA_FLAGS"] = " ".join(flags + [f"{_FORCE_FLAG}={int(n)}"])

@@ -2,10 +2,14 @@
 
 Replicates the reference's in-process multi-"rank" testing
 (``tests/unit/common.py:129 DistributedExec``) the TPU way: instead of forking
-N processes over torch.distributed, we expose N virtual XLA CPU devices via
-``--xla_force_host_platform_device_count`` and run SPMD over a Mesh — the same
-code path a real pod uses (single-controller SPMD), so ws=2/4/8 tests run
-without TPU hardware.
+N processes over torch.distributed, every process that imports this file (the
+pytest process, and each xdist worker) becomes an eight-device XLA CPU process
+before JAX creates its backend, and tests run SPMD over a Mesh of those
+devices — the same code path a real pod uses (single-controller SPMD). A
+``world_size(n)`` test therefore runs under the plain tier-1 command; a count
+already forced in ``XLA_FLAGS`` (a developer's, or a subprocess test's own)
+wins. A test that needs one device, or a batch that eight do not divide, pins
+its mesh (``MeshContext.create(..., devices=jax.devices()[:1])``).
 """
 
 import pytest
@@ -23,9 +27,13 @@ def pytest_configure(config):
         "markers", "faults: fault-injection resilience test (CPU-only, fast)")
 
 
-import jax  # noqa: E402
-
 import importlib  # noqa: E402
+
+from deepspeed_tpu.utils.hostdev import ensure_host_devices  # noqa: E402
+
+ensure_host_devices(8)  # before the first device query below or in a test
+
+import jax  # noqa: E402
 
 # the tests run on a CPU: Pallas kernels a test asks for run interpreted.
 # The package itself never derives this from the platform. (import_module:
@@ -78,10 +86,9 @@ def devices():
 @pytest.fixture
 def force_host_devices():
     """Env factory for SUBPROCESS tests that need their own forced
-    virtual-device count: returns ``build(n, extra=...) -> env dict`` (the
-    same scrub/pin recipe the conftest re-exec applies, shared via
-    utils/hostdev so mesh tests, TP benches and serving e2e tests stop
-    hand-rolling the four env edits)."""
+    virtual-device count: returns ``build(n, extra=...) -> env dict``
+    (``utils/hostdev``'s recipe, so mesh tests and serving e2e tests do
+    not hand-roll the env edits)."""
     from deepspeed_tpu.utils.hostdev import force_host_devices_env
 
     def _build(n: int, extra=None):

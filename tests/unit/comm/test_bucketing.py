@@ -173,12 +173,13 @@ def _count_collectives(jaxpr, names=("psum", "psum2", "all_gather", "all_to_all"
 class TestCollectiveCountTraced:
 
     def test_collective_count_bound_any_device_count(self):
-        """Acceptance bound, traced on a size-1 axis so it runs in tier-1
-        regardless of available devices: a >=8-leaf tree issues exactly one
+        """Acceptance bound, traced on a size-1 axis (a mesh pinned to one
+        device, whatever the host has): a >=8-leaf tree issues exactly one
         collective per bucket — <= ceil(total_bytes/bucket_size) per dtype —
         instead of one per leaf."""
         from deepspeed_tpu.runtime.onebit_wire import _smap
-        ctx = MeshContext.create(axis_sizes={"data": 1})
+        ctx = MeshContext.create(axis_sizes={"data": 1},
+                                 devices=jax.devices()[:1])
         set_mesh_context(ctx)
         tree = {f"l{i}": jnp.ones((64, ), jnp.float32) for i in range(8)}
         tree["h"] = jnp.ones((64, ), jnp.bfloat16)
@@ -207,8 +208,14 @@ class TestBucketedCollectives:
         return ctx
 
     def _smap(self, ctx, f, in_specs, out_specs):
+        # manual over EVERY axis of the (pure data-parallel) mesh: under a
+        # partial-manual shard_map JAX puts a sharding constraint inside a
+        # psum's reducer, and XLA's CPU backend, which promotes 16-bit
+        # all-reduces to f32 (AllReducePromotion), aborts the process
+        # cloning a reducer whose root is that copy — the bf16 bucket here
         from deepspeed_tpu.runtime.onebit_wire import _smap
-        return jax.jit(_smap(f, ctx.mesh, in_specs, out_specs, ("data", )))
+        return jax.jit(_smap(f, ctx.mesh, in_specs, out_specs,
+                             ctx.mesh.axis_names))
 
     def test_fp32_allreduce_matches_per_leaf_mean_and_collective_bound(self):
         ctx = self._ctx()

@@ -593,8 +593,11 @@ def test_autoscale_up_down_with_hysteresis(tmp_path):
     max_replicas ceiling); sustained depth below queue_low shrinks it
     back to min_replicas. A single noisy sample must NOT trigger either
     direction (hysteresis)."""
+    # the monitor thread probes from the start but does not evaluate until
+    # the test has driven the noisy samples itself: a blip timed by
+    # time.sleep() lasts as many evaluations as a loaded host makes it
     fleet = _fleet(tmp_path, n=1, min_replicas=1, max_replicas=2,
-                   autoscale=True, queue_high=5.0, queue_low=1.0,
+                   autoscale=False, queue_high=5.0, queue_low=1.0,
                    probe_interval=0.05, queue_eval_interval=0.05,
                    hysteresis=5, cooldown_s=0.2)
     try:
@@ -607,12 +610,31 @@ def test_autoscale_up_down_with_hysteresis(tmp_path):
                 conn.getresponse().read()
                 conn.close()
 
-        # a brief hot blip, then cold again: hysteresis must hold the pool
+        def probed(pred):
+            deadline = time.monotonic() + 20
+            while time.monotonic() < deadline:
+                if all(pred(r.score()) for r in fleet.healthy()):
+                    return True
+                time.sleep(0.02)
+            return False
+
+        def evaluate():
+            fleet._t_eval = float("-inf")  # this evaluation is due
+            fleet._autoscale_tick()
+
+        # hot for one evaluation short of a trend, then cold again:
+        # hysteresis must hold the pool
         set_waiting(50)
-        time.sleep(0.1)
+        assert probed(lambda s: s >= fleet.queue_high)
+        for _ in range(fleet.hysteresis - 1):
+            evaluate()
+        assert fleet._hot_streak == fleet.hysteresis - 1
         set_waiting(0)
-        time.sleep(0.6)
+        assert probed(lambda s: s <= fleet.queue_low)
+        evaluate()
+        assert fleet._hot_streak == 0
         assert len(fleet._pool) == 1, "a hot blip caused a scale"
+        fleet.autoscale = True  # from here the monitor evaluates, each 0.05 s
 
         # sustained hot -> scale up to the ceiling
         set_waiting(50)

@@ -200,10 +200,39 @@ def test_int8_wire_tolerance_parity_per_token():
                                   outs["fp"].argmax(-1))
 
 
+# An fp top-2 margin under this is a near-tie for the int8 wire. Measured on
+# these engines (tiny Llama, seed 3, int8 weights, tp 2) at the row where the
+# streams of PROMPTS[0] part: the wire moves a logit by 0.049 at most (rms
+# 0.015; the row's logits have std 1.0), so two logits close on each other
+# by up to 0.1, and the fp margin there is 0.0029 (tokens 236 and 98).
+WIRE_NEAR_TIE = 0.1
+
+
+def _assert_same_stream_but_for_a_near_tie(fp_engine, prompt, ref, got):
+    """Greedy streams under the int8 wire equal the fp-wire streams token
+    for token, until a position (if any) where the fp logits themselves
+    hold a near-tie: there int8 rounding may take the other of the tied
+    tokens, and the streams are two valid greedy continuations from then
+    on. A wire that is wrong inside a fused body parts them at a wide
+    margin, or onto a token that was not in the tie. ``fp_engine`` builds
+    the fp-wire engine, and is called only if the streams part."""
+    assert len(got) == len(ref)
+    n = next((i for i, (a, b) in enumerate(zip(ref, got)) if a != b), None)
+    if n is None:
+        return
+    logits = _logits(fp_engine(), [0], [prompt + ref[:n]])[0]
+    for tok in (ref[n], got[n]):
+        margin = float(logits.max() - logits[tok])
+        assert margin < WIRE_NEAR_TIE, (
+            f"streams part at token {n} ({ref[n]} / {got[n]}) where "
+            f"{tok} is {margin:.4f} under the fp top")
+
+
 @pytest.mark.world_size(2)
 def test_int8_wire_fused_paths_greedy_parity():
     """The wire lives INSIDE the fused scan bodies: greedy streams through
-    the fused-K and fused-speculative programs match the fp-wire streams."""
+    the fused-K and fused-speculative programs match the fp-wire streams
+    (up to a near-tie of the fp logits, see WIRE_NEAR_TIE)."""
     cfg = LlamaConfig.tiny()
 
     def mk(wire):
@@ -215,7 +244,9 @@ def test_int8_wire_fused_paths_greedy_parity():
     ref = mk("fp").generate(PROMPTS, max_new_tokens=8, fused_decode_window=4)
     got = mk("int8").generate(PROMPTS, max_new_tokens=8,
                               fused_decode_window=4)
-    assert got == ref
+    for prompt, r, g in zip(PROMPTS, ref, got):
+        _assert_same_stream_but_for_a_near_tie(lambda: mk("fp"), prompt, r, g)
+    assert got[1] == ref[1]  # no near-tie on this one: identical
 
     prompt = [1, 2, 3, 4, 1, 2, 3, 4, 1, 2]
     ref_s = mk("fp").generate([prompt], max_new_tokens=10,

@@ -273,9 +273,12 @@ def test_reference_experts_see_only_their_own_rows(capacity):
 
 def test_fused_step_returns_the_counts_and_publishes_the_gauges():
     from deepspeed_tpu.comm import reset_mesh_context
+    from deepspeed_tpu.comm.mesh import MeshContext, set_mesh_context
     from deepspeed_tpu.observability import get_registry
     cfg, model, params, ids = small()
     reset_mesh_context()
+    # one device, as the cell: eight would not divide the batch of 2
+    set_mesh_context(MeshContext.create(devices=jax.devices()[:1]))
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=params,
         config={"train_batch_size": 2, "steps_per_print": 0,
