@@ -118,10 +118,16 @@ class LlamaConfig:
     # save matmul outputs, recompute elementwise/norms — most of the memory
     # saving at a fraction of full remat's recompute). None = full recompute.
     remat_policy: "Optional[str]" = None
-    # chunked unembed+CE (ops/chunked_ce.py): vocab-chunk size for the
-    # streamed logsumexp that never materializes [tokens, vocab] logits.
-    # None/0 = dense CE. The big win is large-vocab training (32k: ~2 GB
-    # of saved activation at bs16 x 1k; Gemma 256k: ~8 GB).
+    # chunked unembed+CE (ops/chunked_ce.py): a bound on the transient
+    # logits, which hold at most tokens x ce_chunk_size elements and are
+    # never saved for the backward. The op sweeps chunks of sequence
+    # positions with the whole vocabulary in each, sc = the largest power of
+    # two <= seq * ce_chunk_size / vocab positions a chunk, and forms loss
+    # and gradient in that one sweep. None/0 = dense CE. The big win is
+    # large-vocab training (32k: ~2 GB of saved activation at bs16 x 1k;
+    # Gemma 256k: ~8 GB). Under a mesh each device sweeps its own sequences
+    # against the whole head (gathered once, dw reduced once). Not tuned for
+    # a mesh with seq > 1 (the scan slices the sequence axis).
     ce_chunk_size: "Optional[int]" = None
 
     @property

@@ -7,20 +7,45 @@ softmax; training CE in the reference stays torch — at 32k–256k vocab the
 bs16 x seq1024 x 32k fp32 is 2.1 GB saved for backward, 8+ GB at Gemma's
 256k).
 
-This op never materializes the full logits matrix in either pass:
+This op never materializes the full logits matrix, and runs the vocabulary
+matmul three times a step, which is what a dense head costs:
 
-- forward: ``lax.scan`` over vocab chunks; each step computes the chunk's
-  logits ``x @ w[:, c]`` on the MXU and folds them into a running online
-  logsumexp (m, s) plus the gold-label logit — O(T) state, O(T * chunk)
-  transient.
-- backward (``jax.custom_vjp``): re-runs the same chunk sweep, rebuilding
-  ``p_c = exp(logits_c - lse)`` and accumulating ``dx += dl_c @ w_cᵀ``,
-  ``dw_c = xᵀ @ dl_c`` per chunk — the one extra chunk-matmul sweep costs
-  ~2% of a 0.4B-model step, the 2.1 GB saved activation costs nothing.
+- It chunks over **sequence positions** with the whole vocabulary in each
+  chunk: the ``B * sc`` rows of ``x[:, s0:s0+sc]`` times ``w`` give fp32
+  logits ``[B * sc, V]`` whose rows are complete, so a row's logsumexp, its
+  loss term and its softmax gradient are all formed from that one matmul.
+- Differentiated (``jax.custom_vjp``), the forward scan therefore also forms
+  ``dl = (softmax - onehot) * mask``, writes ``dx[:, s0:s0+sc] = dl @ wᵀ``
+  and accumulates ``dw += x_cᵀ @ dl`` in fp32: the residuals are the
+  gradients of the loss's *sum*, and the backward only multiplies them by
+  ``cotangent / N``. Nothing of size ``[tokens, V]`` is stored or recomputed.
+- No scale is known when ``dl`` is rounded to ``compute_dtype``, so none is
+  in it: ``|dl| <= 1`` whatever the token count or the fp16 loss scale (held
+  2^12 up in fp16, whose five exponent bits would lose a softmax tail under
+  6e-5), every sum is fp32, and ``cotangent / N`` multiplies the fp32 result
+  (``dw``, ``dbias``) or the stored ``dx`` (``|dx| <= 2 max|w|``) in fp32.
+- The primal (evaluation) runs the loss-only scan: one matmul a chunk.
+
+``chunk`` (``LlamaConfig.ce_chunk_size``) bounds the transient logits at
+``tokens x chunk`` elements; the positions a chunk holds follow from the
+shape, ``sc`` = the largest power of two <= ``S * chunk / V`` (512 at S 4096,
+V 50304, chunk 8384; fewer at Gemma's 256k vocabulary). When ``sc`` does not
+divide S the tail is padded with weight-0 positions.
 
 Cohere ``logit_scale`` and Gemma-2 ``final_logit_softcapping`` are applied
-per chunk (elementwise), so the models that most need chunking (Gemma's
-256k vocab) keep their exact logit semantics.
+per chunk (elementwise), so the models that most need chunking keep their
+exact logit semantics.
+
+Under a mesh that shards the batch (``data``, ``fsdp``) the sweep runs in a
+``shard_map`` over those axes: the scan slices the sequence axis, never the
+batch axis, so each device sweeps its own sequences' ``[B/n * sc, V]`` logits
+against the whole head, which is gathered once before the loop as ZeRO-3
+gathers a layer's weights, and ``dw`` is summed over the devices once after
+it (a reduce-scatter where the head is sharded). Nothing crosses devices
+inside the loop. The mesh's other axes stay GSPMD's: a tensor-parallel head
+stays split along the vocabulary inside each device group; ``seq > 1``
+shards the axis the scan slices, which stays correct (GSPMD gathers) but is
+not tuned. Rows that do not divide the devices leave the whole to GSPMD.
 """
 
 import functools
@@ -28,145 +53,184 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
-def _num_chunks(V: int, chunk: int) -> int:
-    return -(-V // chunk)
+def seq_chunk(S: int, chunk: int, V: int) -> int:
+    """Positions a chunk, so that ``B * sc * V <= B * S * chunk``: the
+    largest power of two <= ``S * chunk / V`` (at least 1), or all of S when
+    ``chunk`` covers the vocabulary."""
+    if chunk >= V:
+        return S
+    return 1 << (max(1, S * chunk // V).bit_length() - 1)
 
 
-def _pad_to_chunks(w, bias, chunk):
-    """Right-pad the vocab axis to a chunk multiple: dynamic_slice CLAMPS
-    out-of-range starts (the last ragged chunk would silently re-read
-    earlier columns), so every slice must be in-bounds by construction."""
-    V = w.shape[1]
-    Vp = _num_chunks(V, chunk) * chunk
-    if Vp != V:
-        w = jnp.pad(w, ((0, 0), (0, Vp - V)))
-        if bias is not None:
-            bias = jnp.pad(bias, (0, Vp - V))
-    return w, bias
-
-
-def _chunk_logits(x, w, bias, c0, chunk, V, logit_scale, softcap,
-                  compute_dtype):
-    """fp32 logits for vocab columns [c0, c0+chunk) of the PADDED w
-    (+scale/softcap), plus the tanh(l/cap) needed for the softcap chain
-    rule; ``V`` is the true vocab size for masking the padded tail."""
-    wc = jax.lax.dynamic_slice_in_dim(w, c0, chunk, axis=1)
-    lc = jax.lax.dot_general(x.astype(compute_dtype), wc.astype(compute_dtype),
-                             (((1, ), (0, )), ((), ())),
+def _chunk_logits(xc, w, bias, logit_scale, softcap):
+    """fp32 logits ``[rows, V]`` of one chunk's rows (+scale/softcap), plus
+    the tanh(l/cap) needed for the softcap chain rule."""
+    lc = jax.lax.dot_general(xc, w, (((1, ), (0, )), ((), ())),
                              preferred_element_type=jnp.float32)
     if bias is not None:
-        lc = lc + jax.lax.dynamic_slice_in_dim(
-            bias.astype(jnp.float32), c0, chunk, axis=0)
+        lc = lc + bias
     if logit_scale is not None:
         lc = lc * jnp.float32(logit_scale)
     t = None
     if softcap is not None:
         t = jnp.tanh(lc / softcap)
         lc = softcap * t
-    # mask padded columns (V not divisible by chunk) out of the softmax
-    col = c0 + jax.lax.broadcasted_iota(jnp.int32, lc.shape, 1)
-    lc = jnp.where(col < V, lc, -jnp.inf)
-    return lc, t, col
+    return lc, t
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def chunked_unembed_ce(x, w, bias, targets, chunk: int,
-                       logit_scale: Optional[float] = None,
-                       softcap: Optional[float] = None,
-                       compute_dtype=jnp.bfloat16):
-    """Per-token NLL of ``softmax(x @ w + bias)`` without materializing the
-    logits. ``x`` [T, H], ``w`` [H, V], ``bias`` [V] or None, ``targets``
-    [T] int (callers mask ignore_index outside). Returns nll [T] fp32."""
-    nll, _ = _fwd_sweep(x, w, bias, targets, chunk, logit_scale, softcap,
-                        compute_dtype)
-    return nll
+def _dl_shift(compute_dtype) -> float:
+    """What ``dl`` (at most 1) is multiplied by before it is rounded to
+    ``compute_dtype``: fp16 flushes under 6e-5, which is a softmax tail at
+    any real vocabulary; 2^12 is exact and leaves the fp32 sums far from
+    their range."""
+    return 4096.0 if jnp.dtype(compute_dtype) == jnp.float16 else 1.0
 
 
-def _fwd_sweep(x, w, bias, targets, chunk, logit_scale, softcap, compute_dtype):
-    T = x.shape[0]
+def _local_sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
+                 compute_dtype, with_grads):
+    """One scan over chunks of ``sc`` positions. Returns ``sum(mask * nll)``
+    and, ``with_grads``, its gradients: dx in x's dtype, dwᵀ ``[V, H]`` (times
+    ``_dl_shift``) and dbias in fp32 (else None)."""
+    B, S, H = x.shape
     V = w.shape[1]
-    nc = _num_chunks(V, chunk)
-    wp, biasp = _pad_to_chunks(w, bias, chunk)
+    xc_all = x.astype(compute_dtype)
+    wc = w.astype(compute_dtype)
+    b32 = None if bias is None else bias.astype(jnp.float32)
+    grad_bias = with_grads and bias is not None
+    shift = _dl_shift(compute_dtype)
 
-    def step(carry, ci):
-        m, s, gold = carry
-        lc, _, col = _chunk_logits(x, wp, biasp, ci * chunk, chunk, V,
-                                   logit_scale, softcap, compute_dtype)
-        m_new = jnp.maximum(m, lc.max(axis=-1))
-        # exp(-inf - -inf) guards: a fully-masked chunk must not poison s
-        corr = jnp.exp(jnp.where(jnp.isneginf(m), 0.0, m - m_new))
-        s = s * corr + jnp.where(jnp.isneginf(lc), 0.0,
-                                 jnp.exp(lc - m_new[:, None])).sum(axis=-1)
-        hit = col == targets[:, None]
-        gold = gold + jnp.where(hit, jnp.where(jnp.isneginf(lc), 0.0, lc),
-                                0.0).sum(axis=-1)
-        return (m_new, s, gold), None
-
-    init = (jnp.full((T, ), -jnp.inf, jnp.float32),
-            jnp.zeros((T, ), jnp.float32),
-            jnp.zeros((T, ), jnp.float32))
-    (m, s, gold), _ = jax.lax.scan(step, init, jnp.arange(nc))
-    lse = m + jnp.log(s)
-    return lse - gold, (m, s)
-
-
-def _ce_fwd(x, w, bias, targets, chunk, logit_scale, softcap, compute_dtype):
-    nll, (m, s) = _fwd_sweep(x, w, bias, targets, chunk, logit_scale, softcap,
-                             compute_dtype)
-    lse = m + jnp.log(s)
-    return nll, (x, w, bias, targets, lse)
-
-
-def _ce_bwd(chunk, logit_scale, softcap, compute_dtype, res, g):
-    x, w, bias, targets, lse = res
-    V = w.shape[1]
-    nc = _num_chunks(V, chunk)
-    T, H = x.shape
-
-    wp, biasp = _pad_to_chunks(w, bias, chunk)
-
-    def step(carry, ci):
-        dx, dw, dbias = carry
-        c0 = ci * chunk
-        lc, t, col = _chunk_logits(x, wp, biasp, c0, chunk, V,
-                                   logit_scale, softcap, compute_dtype)
-        p = jnp.where(jnp.isneginf(lc), 0.0, jnp.exp(lc - lse[:, None]))
-        dl = (p - (col == targets[:, None]).astype(jnp.float32)) * g[:, None]
+    def step(carry, s0):
+        loss, dx, dwt, dbias = carry
+        # the chunk's B * sc rows as one matrix: on a v5e the three matmuls
+        # run a fifth faster in two dimensions than with the batch axis kept
+        # apart (head alone at the OLMoE shape: 69 ms for 83; PERF.md, PR 29)
+        xc, tg, mk = (
+            jax.lax.dynamic_slice_in_dim(a, s0, sc, axis=1).reshape(
+                B * sc, *a.shape[2:]) for a in (xc_all, targets, mask))
+        lc, t = _chunk_logits(xc, wc, b32, logit_scale, softcap)
+        lse = jax.nn.logsumexp(lc, axis=-1)
+        hit = jax.lax.broadcasted_iota(jnp.int32, lc.shape, 1) == tg[:, None]
+        gold = jnp.where(hit, lc, 0.0).sum(axis=-1)
+        loss = loss + ((lse - gold) * mk).sum()
+        if not with_grads:
+            return (loss, dx, dwt, dbias), None
+        dl = (jnp.exp(lc - lse[:, None]) - hit) * mk[:, None]
         # chain back through softcap then logit_scale (applied in that order
-        # forward: scale -> softcap), zeroing padded columns
+        # forward: scale -> softcap)
         if softcap is not None:
             dl = dl * (1.0 - t * t)
         if logit_scale is not None:
             dl = dl * jnp.float32(logit_scale)
-        dl = jnp.where(col < V, dl, 0.0)
-        wc = jax.lax.dynamic_slice_in_dim(wp, c0, chunk, axis=1)
-        dx = dx + jax.lax.dot_general(
-            dl.astype(compute_dtype), wc.astype(compute_dtype),
-            (((1, ), (1, )), ((), ())), preferred_element_type=jnp.float32)
-        dwc = jax.lax.dot_general(
-            x.astype(compute_dtype), dl.astype(compute_dtype),
-            (((0, ), (0, )), ((), ())), preferred_element_type=jnp.float32)
-        dw = jax.lax.dynamic_update_slice_in_dim(
-            dw, dwc.astype(dw.dtype), c0, axis=1)
-        if dbias is not None:
-            dbias = jax.lax.dynamic_update_slice_in_dim(
-                dbias, dl.sum(axis=0).astype(dbias.dtype), c0, axis=0)
-        return (dx, dw, dbias), None
+        if grad_bias:
+            dbias = dbias + dl.sum(axis=0)
+        if shift != 1.0:
+            dl = dl * shift
+        dl = dl.astype(compute_dtype)
+        dxc = jax.lax.dot_general(dl, wc, (((1, ), (1, )), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        if shift != 1.0:
+            dxc = dxc * (1.0 / shift)
+        dx = jax.lax.dynamic_update_slice_in_dim(
+            dx, dxc.astype(dx.dtype).reshape(B, sc, H), s0, axis=1)
+        dwt = dwt + jax.lax.dot_general(dl, xc, (((0, ), (0, )), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        return (loss, dx, dwt, dbias), None
 
-    Vp = nc * chunk
-    init = (jnp.zeros((T, H), jnp.float32),
-            jnp.zeros((H, Vp), jnp.float32),
-            None if bias is None else jnp.zeros((Vp, ), jnp.float32))
-    (dx, dw, dbias), _ = jax.lax.scan(step, init, jnp.arange(nc))
-    dx = dx.astype(x.dtype)
-    dw = dw[:, :V].astype(w.dtype)
-    dbias = None if bias is None else dbias[:V].astype(bias.dtype)
-    return dx, dw, dbias, None
+    # dw is summed as its transpose [V, H], the layout XLA gives the
+    # vocabulary matmuls on a TPU: summed as [H, V] a one-layer OLMoE step
+    # compiled for a v5e needs 1.5 GB more temporaries (PERF.md, PR 29)
+    init = (jnp.zeros((), jnp.float32),
+            jnp.zeros(x.shape, x.dtype) if with_grads else None,
+            jnp.zeros((V, H), jnp.float32) if with_grads else None,
+            jnp.zeros((V, ), jnp.float32) if grad_bias else None)
+    (loss, dx, dwt, dbias), _ = jax.lax.scan(
+        step, init, jnp.arange(0, S, sc, dtype=jnp.int32))
+    return loss, ((dx, dwt, dbias) if with_grads else None)
 
 
-chunked_unembed_ce.defvjp(_ce_fwd, _ce_bwd)
+def _batch_axes(B: int):
+    """The mesh and those of its axes that shard the batch (``data``,
+    ``fsdp``: comm/mesh.py), less any an enclosing ``shard_map`` already made
+    manual; none when no mesh is set or the rows do not divide."""
+    from ..comm.mesh import get_mesh_context, mesh_is_initialized
+    if not mesh_is_initialized():
+        return None, ()
+    ctx = get_mesh_context()
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    dp = tuple(a for a in ("data", "fsdp")
+               if ctx.axis_size(a) > 1 and a not in manual)
+    if not dp or B % ctx.axis_size(dp):
+        return None, ()
+    return ctx.mesh, dp
+
+
+def _sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
+           compute_dtype, with_grads):
+    """``_local_sweep`` of each device's own sequences: under a mesh that
+    shards the batch the scan runs inside a ``shard_map`` over those axes,
+    with the head whole on every device (gathered once, as ZeRO-3 gathers a
+    layer) and ``dw`` summed over the devices once, after the last chunk. Left
+    to GSPMD, a ``dw`` whose rows are spread over devices is all-reduced
+    inside the loop, once a chunk."""
+    static = (sc, logit_scale, softcap, compute_dtype, with_grads)
+    mesh, dp = _batch_axes(x.shape[0])
+    if not dp:
+        return _local_sweep(x, w, bias, targets, mask, *static)
+
+    def body(*local):
+        loss, grads = _local_sweep(*local, *static)
+        if grads is not None:
+            dx, *sums = grads
+            grads = (dx, *(None if d is None else jax.lax.psum(d, dp)
+                           for d in sums))
+        return jax.lax.psum(loss, dp), grads
+
+    rows, whole = P(dp), P()
+    # jitted for a caller outside any jit: a partly manual shard_map (the
+    # mesh's other axes stay GSPMD's) only lowers under one
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(rows, whole, whole, rows, rows),
+        out_specs=(whole, (rows, whole, whole) if with_grads else None),
+        axis_names=frozenset(dp), check_vma=False))(
+            x, w.astype(compute_dtype), bias, targets, mask)   # gathered as cast
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _mean_ce(x, w, bias, targets, mask, inv_n, sc, logit_scale, softcap,
+             compute_dtype):
+    """``inv_n * sum(mask * nll)`` of ``softmax(x @ w + bias)``: ``x``
+    [B, S, H] with ``sc`` dividing S, ``w`` [H, V], ``bias`` [V] or None,
+    ``targets`` [B, S] int in range, ``mask`` [B, S] fp32, ``inv_n`` a
+    scalar. A scalar, so that its cotangent is one too and ``dw`` can be
+    summed before it is known."""
+    return inv_n * _sweep(x, w, bias, targets, mask, sc, logit_scale,
+                          softcap, compute_dtype, False)[0]
+
+
+def _ce_fwd(x, w, bias, targets, mask, inv_n, sc, logit_scale, softcap,
+            compute_dtype):
+    loss, grads = _sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
+                         compute_dtype, True)
+    # the backward casts to the weights' dtypes, which it learns from these
+    like = tuple(None if a is None else jnp.zeros((0, ), a.dtype)
+                 for a in (w, bias))
+    return inv_n * loss, (grads, inv_n, like)
+
+
+def _ce_bwd(sc, logit_scale, softcap, compute_dtype, res, g):
+    (dx, dwt, dbias), inv_n, (w_like, b_like) = res
+    k = g.astype(jnp.float32) * inv_n
+    dx = (k * dx.astype(jnp.float32)).astype(dx.dtype)
+    dw = ((k / _dl_shift(compute_dtype)) * dwt).T.astype(w_like.dtype)
+    if dbias is not None:
+        dbias = (k * dbias).astype(b_like.dtype)
+    return dx, dw, dbias, None, None, None
+
+
+_mean_ce.defvjp(_ce_fwd, _ce_bwd)
 
 
 def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
@@ -176,12 +240,20 @@ def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
                                compute_dtype=jnp.bfloat16):
     """Token-mean causal-LM CE (shift-by-one, ignore_index) over a streamed
     unembed — drop-in for ``models.llama.cross_entropy_loss`` fed hidden
-    states instead of logits. ``x`` [B, S, H], ``labels`` [B, S]."""
-    B, S, H = x.shape
-    xs = x[:, :-1].reshape(B * (S - 1), H)
-    tg = labels[:, 1:].reshape(B * (S - 1))
+    states instead of logits. ``x`` [B, S, H], ``labels`` [B, S]; the
+    transient logits hold at most ``B * S * chunk`` elements."""
+    S = x.shape[1]
+    sc = seq_chunk(S, chunk, w.shape[1])
+    # the shift as shifted targets: position s predicts labels[s + 1], the
+    # last position predicts nothing (weight 0), and S stays whole; so does a
+    # tail padded up to a multiple of sc
+    pad = -S % sc
+    tg = jnp.pad(labels[:, 1:], ((0, 0), (0, 1 + pad)),
+                 constant_values=ignore_index)
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     mask = (tg != ignore_index).astype(jnp.float32)
     tg = jnp.where(tg == ignore_index, 0, tg)
-    nll = chunked_unembed_ce(xs, w, bias, tg, chunk, logit_scale, softcap,
-                             compute_dtype)
-    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    inv_n = 1.0 / jnp.maximum(mask.sum(), 1.0)
+    return _mean_ce(x, w, bias, tg, mask, inv_n, sc, logit_scale, softcap,
+                    compute_dtype)

@@ -168,6 +168,81 @@ def test_chunked_ce_and_selective_remat_under_zero3_mesh():
     np.testing.assert_allclose(losses["chunked"], losses["dense"], rtol=1e-4)
 
 
+def _loop_bodies(hlo):
+    """The lines of every computation a ``while`` body of the compiled
+    program reaches, by body: ``{body name: [lines]}``."""
+    import re
+    comps, cur = {}, None
+    for line in hlo.split("\n"):
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    out = {}
+    for body in set(re.findall(r"body=%?([\w.\-]+)", hlo)):
+        seen, todo = set(), [body]
+        while todo:
+            c = todo.pop()
+            if c not in seen and c in comps:
+                seen.add(c)
+                todo += re.findall(
+                    r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)",
+                    "\n".join(comps[c]))
+        out[body] = [line for c in seen for line in comps[c]]
+    return out
+
+
+@pytest.mark.world_size(8)
+def test_chunked_ce_sweeps_each_devices_own_rows_under_zero3():
+    """With the batch sharded over ``data x fsdp`` the head's scan runs in a
+    ``shard_map`` over those axes: each device sweeps ``[B/8 * sc, V]`` logits
+    of its own sequences, ``x`` is never gathered, nothing crosses devices
+    inside the loop (left to GSPMD, ``dw`` was all-reduced there once a
+    chunk: it is summed over the devices after the loop)."""
+    import re
+    from deepspeed_tpu.comm.mesh import reset_mesh_context
+    from deepspeed_tpu.ops.chunked_ce import seq_chunk
+    reset_mesh_context()
+    B, S = 8, 64
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, ce_chunk_size=64)
+    V, H = cfg.vocab_size, cfg.hidden_size
+    sc = seq_chunk(S, cfg.ce_chunk_size, V)
+    assert (V, H, sc) == (256, 64, 16)
+    model, params = init_llama(cfg, seed=5)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params,
+        config={"train_batch_size": B,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 3},
+                "mesh": {"data": 2, "fsdp": 4}})
+    ids = jnp.asarray(np.random.default_rng(7).integers(0, V, size=(B, S)),
+                      dtype=jnp.int32)
+    ids = jax.device_put(ids, engine.zero_plan.batch_sharding((ids, ))[0])
+    hlo = engine._train_step_fused.lower(
+        engine.params, engine.opt_state, engine.scale_state, (ids, ),
+        {"labels": ids}, ()).compile().as_text()
+
+    def shapes(op, lines):
+        return [tuple(int(d) for d in dims.split(","))
+                for dims in re.findall(
+                    r"= \(?\w+\[([\d,]+)\]\S* " + op + r"\(", "\n".join(lines))]
+
+    # the logits are the one matmul whose result has a vocabulary axis and no
+    # hidden axis (dw is [V, H], dx [rows, H]); the loop that holds it is the
+    # head's
+    head = [lines for lines in _loop_bodies(hlo).values()
+            if any(V in sh and H not in sh for sh in shapes("dot", lines))]
+    assert len(head) == 1, "the head's sweep is not one loop"
+    dots = shapes("dot", head[0])
+    assert sorted(dots) == sorted([(B // 8 * sc, V), (B // 8 * sc, H), (V, H)])
+    for op in ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute"):
+        assert not shapes(op, head[0]), f"{op} inside the head's loop"
+    for sh in shapes("all-gather", hlo.split("\n")):
+        assert int(np.prod(sh)) < B * S * H, f"all-gather of {sh}: x whole?"
+
+
 # the classes of step program a trainer's memory knobs select between, as
 # overrides of one four-layer config
 STEP_PROGRAM_CLASSES = {
