@@ -252,6 +252,13 @@ class RaggedLlamaModel:
                  tp_wire_overrides: Optional[dict] = None,
                  tp_wire_block: int = 256,
                  devices=None):
+        if config.layer_specs is not None or config.moe_experts_held is not None:
+            # this forward is attention + one global FFN a layer over all
+            # the router's experts: a convolution's state beside pages
+            # (ROADMAP R4) and a share-holding expert layer are training's
+            raise NotImplementedError(
+                "serving a model with per-layer layer_specs or a share of "
+                "the experts (moe_experts_held) is not supported")
         self.config = config
         # explicit device subset (disaggregated serving: each group's
         # engine pins params + KV to its own devices). None = process

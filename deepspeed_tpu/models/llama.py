@@ -32,6 +32,16 @@ VOCAB = "vocab"
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What ONE decoder layer is built from, for models whose layers are not
+    all alike (LFM2: gated short convolutions with an attention layer among
+    every few, a dense FFN in the leading layers and experts after)."""
+    operator: str = "attention"     # "attention" | "conv" (ops/short_conv.py)
+    ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
+    ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     """Decoder-family config. Defaults are Llama; the variant knobs below
     cover the reference's other injection containers (OPT/Falcon/Phi —
@@ -69,9 +79,11 @@ class LlamaConfig:
     attn_scale: Optional[float] = None  # None = 1/sqrt(head_dim); GPT-Neo = 1.0
     clip_qkv: Optional[float] = None  # OLMo: clamp q/k/v projections to ±clip
     logit_scale: Optional[float] = None  # Cohere: logits *= logit_scale
-    # OLMo2: RMSNorm on the FLAT q/k projections (q_norm over nq*hd, k_norm
-    # over nkv*hd) before the head reshape + rope
-    qk_norm: bool = False
+    # True / "flat" (OLMo2, OLMoE): RMSNorm on the FLAT q/k projections
+    # (q_norm over nq*hd, k_norm over nkv*hd) before the head reshape + rope.
+    # "head" (LFM2): RMSNorm over each head's hd, one weight [hd] shared by
+    # the heads, after the reshape and before rope
+    qk_norm: "bool | str" = False
     # OLMo2: post-norm residual — x + norm(attn(x)), then x + norm(mlp(x));
     # layer norms are post_attention_layernorm / post_feedforward_layernorm
     post_norm: bool = False
@@ -94,9 +106,24 @@ class LlamaConfig:
     # (x + attn(ln1(x)) + mlp(ln2(x))); 1 = Falcon/Phi shared-norm form
     parallel_residual_norms: int = 1
     lm_head_bias: bool = False        # Phi
-    num_local_experts: int = 0    # >0 = Mixtral-style MoE MLP
+    # >0 = sparse MoE MLP: the ROUTER's width, every expert of the model
+    num_local_experts: int = 0
     num_experts_per_tok: int = 2
     moe_renormalize: bool = True  # Mixtral renormalizes top-k; Qwen2-MoE not
+    # the experts whose matrices live here, when that is a share of the
+    # router's (expert parallelism as seen from one chip): share
+    # ``moe_share_index`` of ``num_local_experts / moe_experts_held``, experts
+    # ``index * held .. (index + 1) * held``. None = all of them
+    moe_experts_held: Optional[int] = None
+    moe_share_index: int = 0
+    # "softmax" (Mixtral, OLMoE, Qwen2-MoE) | "sigmoid" (LFM2, DeepSeek-V3):
+    # independent scores; with ``moe_selection_bias`` the top-k is taken of
+    # score + bias (a buffer: no gradient, its update rule is the training
+    # recipe's and not implemented) and weighted by the unbiased scores
+    moe_scoring: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_renorm_eps: float = 0.0          # p / (sum p + eps) where renormalized
+    routed_scaling_factor: float = 1.0
     # >0: sow the Switch/Mixtral load-balancing loss (reference
     # sharded_moe.py l_aux); the engine adds sown "aux_loss" scalars to the
     # training loss
@@ -105,6 +132,10 @@ class LlamaConfig:
     # a sigmoid gate (None = no shared expert)
     shared_expert_intermediate_size: Optional[int] = None
     moe_grouped: bool = True      # grouped GEMM (FLOPs ∝ top-k) vs dense-over-experts
+    # One LayerSpec a layer, for models whose layers differ; None = every
+    # layer attention + the one global FFN the fields above describe
+    layer_specs: Optional[Tuple[LayerSpec, ...]] = None
+    conv_L_cache: int = 3         # taps of the "conv" operator
     attn_impl: str = "auto"       # "auto" | "flash" (Pallas) | "xla"
     dtype: Any = jnp.bfloat16
     scan_layers: bool = False
@@ -134,19 +165,32 @@ class LlamaConfig:
     def head_dim_(self):
         return self.head_dim or self.hidden_size // self.num_attention_heads
 
+    @property
+    def experts_held_(self) -> int:
+        return self.moe_experts_held or self.num_local_experts
+
     def per_layer_elements(self) -> int:
-        """Analytic element count of one decoder layer (attention + MLP/MoE
-        + norms) — the unit of the ZeRO-3 live-parameter budget."""
+        """Analytic element count of one decoder layer (operator + MLP/MoE
+        + norms) — the unit of the ZeRO-3 live-parameter budget; with
+        ``layer_specs``, of the largest layer."""
         h, hd = self.hidden_size, self.head_dim_
         attn = h * (self.num_attention_heads * hd) * 2 \
             + h * (self.num_key_value_heads * hd) * 2
         proj = 3 if self.mlp_type in ("swiglu", "geglu_tanh") else 2
-        if self.num_local_experts > 0:
-            mlp = proj * h * self.intermediate_size * self.num_local_experts \
-                + h * self.num_local_experts
-        else:
-            mlp = proj * h * self.intermediate_size
-        return attn + mlp + 2 * h
+
+        def ffn(kind, width):
+            if kind == "moe":
+                return proj * h * width * self.experts_held_ \
+                    + h * self.num_local_experts
+            return proj * h * width
+
+        if self.layer_specs is None:
+            kind = "moe" if self.num_local_experts > 0 else "dense"
+            return attn + ffn(kind, self.intermediate_size) + 2 * h
+        conv = 4 * h * h + self.conv_L_cache * h    # in_proj, out_proj, taps
+        return max((conv if spec.operator == "conv" else attn)
+                   + ffn(spec.ffn, spec.ffn_width) + 2 * h
+                   for spec in self.layer_specs)
 
     def with_live_param_budget(self, max_live_parameters: int) -> "LlamaConfig":
         """Return a config whose layer scan chunk honors the ZeRO-3
@@ -311,6 +355,12 @@ def _layer_window(cfg, layer_idx: int):
     return cfg.sliding_window
 
 
+def _mesh_shape() -> dict:
+    from ..comm.mesh import mesh_is_initialized, get_mesh_context
+    return (dict(get_mesh_context().mesh.shape)
+            if mesh_is_initialized() else {})
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
     layer_idx: int = 0
@@ -330,13 +380,18 @@ class LlamaAttention(nn.Module):
             q = jnp.clip(q, -cfg.clip_qkv, cfg.clip_qkv)
             k = jnp.clip(k, -cfg.clip_qkv, cfg.clip_qkv)
             v = jnp.clip(v, -cfg.clip_qkv, cfg.clip_qkv)
-        if cfg.qk_norm:  # OLMo2: normalize the flat projections pre-reshape
+        per_head = cfg.qk_norm == "head"
+        if cfg.qk_norm and not per_head:
+            # OLMo2: normalize the flat projections pre-reshape
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
 
         q = q.reshape(b, s, nq, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
+        if per_head:  # LFM2: each head's hd normalized, one weight for all
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         if cfg.pos_embedding == "rope":
             q = apply_rope(q, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
             k = apply_rope(k, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
@@ -348,9 +403,7 @@ class LlamaAttention(nn.Module):
         # dot_product_attention otherwise.
         from ..ops.attention import flash_attention
 
-        from ..comm.mesh import mesh_is_initialized, get_mesh_context
-        mesh_shape = (dict(get_mesh_context().mesh.shape)
-                      if mesh_is_initialized() else {})
+        mesh_shape = _mesh_shape()
         sp_sz = mesh_shape.get("seq", 1)
         one_device = all(n == 1 for n in mesh_shape.values())
 
@@ -442,6 +495,33 @@ class LlamaAttention(nn.Module):
                       cfg.attention_out_bias)(out)
 
 
+class ShortConvOperator(nn.Module):
+    """LFM2's gated short convolution, the operator of a ``"conv"`` layer:
+    ``in_proj`` to ``B | C | u``, ``y = C * conv(B * u)`` with
+    ``conv_L_cache`` causal depthwise taps (``ops/short_conv.py``: one Pallas
+    kernel forward, one backward), ``out_proj``. No activation, no bias."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.short_conv import short_conv
+        cfg = self.config
+        H = cfg.hidden_size
+        bcx = _dense(3 * H, "in_proj", (EMBED, HIDDEN), cfg.dtype)(x)
+        taps = self.param(
+            "conv_weight",
+            nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0,
+                                                              out_axis=1),
+                                 (None, HIDDEN)),
+            (cfg.conv_L_cache, H), jnp.float32)
+        # a raw pallas_call is not partitioned under GSPMD: as for flash,
+        # the kernel runs where the mesh is one device
+        one_device = all(n == 1 for n in _mesh_shape().values())
+        y = short_conv(bcx, taps, use_kernel=on_tpu() and one_device,
+                       interpret=interpret_kernels())
+        return _dense(H, "out_proj", (HIDDEN, EMBED), cfg.dtype)(y)
+
+
 class LlamaMLP(nn.Module):
     config: LlamaConfig
 
@@ -468,35 +548,81 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaMoEBlock(nn.Module):
-    """Mixtral-style sparse MoE MLP (reference moe/sharded_moe.py gating +
-    module_inject/containers mixtral): softmax router over E experts, top-k
-    renormalized combine. Compute is a megablocks-style grouped GEMM
-    (``ops/grouped_matmul.py``: sort-by-expert → gather into expert order →
-    ragged_dot → gather back by the inverse permutation and a weighted sum
-    over each token's k rows) so per-token FLOPs ∝ top-k, matching the reference's
-    CUTLASS moe_gemm capability; ``moe_grouped=False`` keeps the
+    """Sparse MoE MLP (reference moe/sharded_moe.py gating +
+    module_inject/containers mixtral): a router ``num_local_experts`` wide,
+    top-k, SwiGLU experts ``intermediate_size`` wide. Scoring is a softmax
+    over the experts (Mixtral, renormalized; OLMoE and Qwen2-MoE, not) or
+    independent sigmoids (LFM2), there with a selection bias added for the
+    top-k only, the chosen experts weighted by their unbiased scores,
+    ``p / (sum p + moe_renorm_eps)`` and ``routed_scaling_factor``.
+
+    The experts whose matrices live in this block are all of the router's,
+    or share ``moe_share_index`` of them (``moe_experts_held``): the block
+    then routes over all, computes ``Σ p_e · expert_e(x)`` over the chosen
+    experts it holds and adds nothing for the others: the partial result of
+    one chip of an expert-parallel layer, without the exchange. No matrix
+    of an absent expert exists. ``expert_counts`` (sown, ``moe_stats``) are
+    over the router's width either way; a share also sows ``rows_held`` and
+    ``share_fallback`` (``ops/grouped_matmul.py``).
+
+    Compute is a megablocks-style grouped GEMM (``ops/grouped_matmul.py``:
+    sort-by-expert → gather into expert order → ragged_dot → gather back by
+    the inverse permutation and a weighted sum over each token's k rows) so
+    per-token FLOPs ∝ top-k; ``moe_grouped=False`` keeps the
     dense-over-experts oracle (also the better layout when the 'expert'
     logical axis is sharded over a real mesh axis — EP uses moe/layer.py's
     all-to-all dispatch instead). Expert weights carry the 'expert' logical
     axis so EP sharding is a mesh rule like everything else."""
     config: LlamaConfig
 
+    def _route(self, logits):
+        """-> (scores the balance loss reads ``[..., E]``, combine weights
+        ``[..., k]`` float32, chosen experts ``[..., k]``)."""
+        cfg = self.config
+        E, k = cfg.num_local_experts, cfg.num_experts_per_tok
+        if cfg.moe_scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            w, idx = jax.lax.top_k(scores, k)
+        elif cfg.moe_scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            if cfg.moe_selection_bias:
+                bias = self.param("expert_bias", nn.with_partitioning(
+                    nn.initializers.zeros, ("expert", )), (E, ), jnp.float32)
+                _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+                w = jnp.take_along_axis(scores, idx, axis=-1)
+            else:
+                w, idx = jax.lax.top_k(scores, k)
+        else:
+            raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
+        if cfg.moe_renormalize:  # Mixtral; Qwen2-MoE keeps raw softmax mass
+            total = jnp.sum(w, -1, keepdims=True)
+            w = w / (total + cfg.moe_renorm_eps if cfg.moe_renorm_eps else total)
+        if cfg.routed_scaling_factor != 1.0:
+            w = w * cfg.routed_scaling_factor
+        return scores, w, idx
+
     @nn.compact
     def __call__(self, x):
         from ..ops.grouped_matmul import (expert_counts, moe_grouped_mlp,
-                                          moe_dense_mlp)
+                                          moe_grouped_mlp_share, moe_dense_mlp)
         cfg = self.config
         E, k = cfg.num_local_experts, cfg.num_experts_per_tok
+        held = cfg.experts_held_
+        first = cfg.moe_share_index * held
+        if not 0 < held <= E or first + held > E:
+            raise ValueError(f"experts {first}..{first + held} held of {E}")
         H, F = cfg.hidden_size, cfg.intermediate_size
         logits = _dense(E, "gate", (EMBED, "expert"), jnp.float32)(x.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        w, idx = jax.lax.top_k(probs, k)
+        probs, w, idx = self._route(logits)
         # (token, choice) assignments per expert: what the engine's fused
         # step returns beside the loss ("moe_stats", read only when mutable)
         counts = expert_counts(idx, E)
-        self.sow("moe_stats", "expert_counts", counts,
-                 reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((E, ), jnp.int32))
+
+        def sow_stat(name, value):
+            self.sow("moe_stats", name, value, reduce_fn=lambda a, b: a + b,
+                     init_fn=lambda: jnp.zeros_like(value))
+
+        sow_stat("expert_counts", counts)
         if cfg.router_aux_loss_coef > 0:
             # Switch/Mixtral load balance: E * sum_e(frac_routed_e * mean_prob_e)
             pe = probs.reshape(-1, E).mean(axis=0)
@@ -504,8 +630,6 @@ class LlamaMoEBlock(nn.Module):
             self.sow("aux_loss", "moe_load_balance",
                      cfg.router_aux_loss_coef * E * jnp.sum(fe * pe),
                      reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.float32(0.0))
-        if cfg.moe_renormalize:  # Mixtral; Qwen2-MoE keeps raw softmax mass
-            w = w / jnp.sum(w, -1, keepdims=True)
         w = w.astype(cfg.dtype)
 
         # each expert is a matrix of its own: the expert axis is a batch
@@ -513,17 +637,26 @@ class LlamaMoEBlock(nn.Module):
         # made the seeded block's output E times too small to see)
         lecun = nn.initializers.lecun_normal(batch_axis=(0, ))
         init = nn.with_partitioning(lecun, ("expert", EMBED, HIDDEN))
-        w1 = _use_cast(self.param("w1", init, (E, H, F), jnp.float32), cfg.dtype)
-        w3 = _use_cast(self.param("w3", init, (E, H, F), jnp.float32), cfg.dtype)
+        w1 = _use_cast(self.param("w1", init, (held, H, F), jnp.float32), cfg.dtype)
+        w3 = _use_cast(self.param("w3", init, (held, H, F), jnp.float32), cfg.dtype)
         w2 = _use_cast(self.param("w2",
                                   nn.with_partitioning(lecun,
                                                        ("expert", HIDDEN, EMBED)),
-                                  (E, F, H), jnp.float32), cfg.dtype)
+                                  (held, F, H), jnp.float32), cfg.dtype)
 
         lead = x.shape[:-1]
         xt = x.reshape(-1, H)
-        fn = moe_grouped_mlp if cfg.moe_grouped else moe_dense_mlp
-        out = fn(xt, w1, w3, w2, idx.reshape(-1, k), w.reshape(-1, k))
+        idx, w = idx.reshape(-1, k), w.reshape(-1, k)
+        if held < E:
+            if not cfg.moe_grouped:
+                raise ValueError("a share of the experts needs moe_grouped")
+            out, rows_held, fell_back = moe_grouped_mlp_share(
+                xt, w1, w3, w2, idx, w, first_expert=first, num_experts=E)
+            sow_stat("rows_held", rows_held)
+            sow_stat("share_fallback", fell_back)
+        else:
+            fn = moe_grouped_mlp if cfg.moe_grouped else moe_dense_mlp
+            out = fn(xt, w1, w3, w2, idx, w)
         out = out.reshape(*lead, H)
         if cfg.shared_expert_intermediate_size:  # Qwen2-MoE
             se_cfg = dataclasses.replace(
@@ -543,6 +676,23 @@ class LlamaDecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin, positions, attn_mask=None):
         cfg = self.config
+        if cfg.layer_specs is not None:
+            # this layer's own operator and FFN (LFM2's names and residual
+            # form): r = x + op(operator_norm(x)); r + ffn(ffn_norm(r))
+            spec = cfg.layer_specs[self.layer_idx]
+            normed = _make_norm(cfg, "operator_norm")(x)
+            if spec.operator == "conv":
+                h = x + ShortConvOperator(cfg, name="conv")(normed)
+            elif spec.operator == "attention":
+                h = x + LlamaAttention(cfg, self.layer_idx, name="self_attn")(
+                    normed, cos, sin, positions, attn_mask)
+            else:
+                raise ValueError(f"unknown operator {spec.operator!r}")
+            ffn_cfg = dataclasses.replace(cfg, intermediate_size=spec.ffn_width)
+            normed2 = _make_norm(cfg, "ffn_norm")(h)
+            if spec.ffn == "moe":
+                return h + LlamaMoEBlock(ffn_cfg, name="block_sparse_moe")(normed2)
+            return h + LlamaMLP(ffn_cfg, name="mlp")(normed2)
         if cfg.sandwich_norm:
             # Gemma-2: pre AND post norms around both sublayers
             attn_out = LlamaAttention(cfg, self.layer_idx, name="self_attn")(
@@ -679,6 +829,11 @@ class LlamaModel(nn.Module):
                 raise ValueError(
                     "scan_layers requires homogeneous layers; per-layer "
                     "sliding_window_layers patterns need scan_layers=False")
+            if cfg.layer_specs is not None and len(set(cfg.layer_specs)) > 1:
+                raise ValueError(
+                    "scan_layers requires homogeneous layers; layer_specs "
+                    f"of {len(set(cfg.layer_specs))} kinds need "
+                    "scan_layers=False")
             if cfg.num_hidden_layers % cfg.scan_chunk_size != 0:
                 raise ValueError(
                     f"num_hidden_layers={cfg.num_hidden_layers} not divisible "

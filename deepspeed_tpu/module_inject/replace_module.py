@@ -95,6 +95,8 @@ def convert_hf_checkpoint(arch: str,
 def export_hf_checkpoint(arch: str, config: LlamaConfig, params: Dict) -> Dict[str, np.ndarray]:
     """Inverse conversion: flax params → HF-layout state dict (numpy)."""
     policy = policy_for(arch)
+    if hasattr(policy, "bind"):     # name maps that depend on the layer's kind
+        policy.bind(config)
     flat = {}
 
     def walk(node, prefix=""):

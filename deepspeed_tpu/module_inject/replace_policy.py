@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..models.llama import LlamaConfig
+from ..models.llama import LayerSpec, LlamaConfig
 
 
 class HFCheckpointPolicy:
@@ -301,6 +301,123 @@ class OlmoePolicy(MixtralPolicy):
 
     def moe_map(self, layer: int, num_experts: int):
         return _mlp_experts_map(layer, num_experts)
+
+
+class Lfm2MoePolicy(HFCheckpointPolicy):
+    """LFM2-MoE (HF ``modeling_lfm2_moe.py``): pre-norm layers
+    (``operator_norm``, ``ffn_norm``) whose operator is, by ``layer_types``,
+    a gated short convolution (``conv.in_proj`` to ``B | C | x``, depthwise
+    causal ``conv.conv`` of ``conv_L_cache`` taps over ``B * x``, gated by
+    ``C``, ``conv.out_proj``) or GQA attention with RMSNorm over each head's
+    q and k (``q_layernorm``, ``k_layernorm``) and ``out_proj``; whose FFN is
+    a SwiGLU ``intermediate_size`` wide in the first ``num_dense_layers``
+    layers and afterwards ``num_experts`` experts ``moe_intermediate_size``
+    wide, top-k of sigmoid scores plus ``expert_bias`` (a buffer), weighted
+    by the unbiased scores over ``sum + 1e-6`` (``norm_topk_prob``) times
+    ``routed_scaling_factor``. Final norm ``embedding_norm``, embeddings
+    tied, no biases. A chip's share of the experts is the deployment's to
+    set (``moe_experts_held``), not the checkpoint's."""
+    arch = "lfm2_moe"
+    row_parallel = ["o_proj", "down_proj", "out_proj"]
+    col_parallel = HFCheckpointPolicy.col_parallel + ["in_proj"]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        if hf_config.get("conv_bias"):
+            raise ValueError("lfm2_moe: conv_bias=True is not supported")
+        rope = hf_config.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(f"lfm2_moe: rope_type {rope['rope_type']!r} is "
+                             "not supported")
+        depth, dense = hf_config["num_hidden_layers"], hf_config.get("num_dense_layers", 0)
+        types = hf_config["layer_types"]
+        if len(types) != depth or set(types) - {"conv", "full_attention"}:
+            raise ValueError(f"lfm2_moe: layer_types {types} for {depth} layers")
+        specs = tuple(
+            LayerSpec(operator="conv" if kind == "conv" else "attention",
+                      ffn="dense" if i < dense else "moe",
+                      ffn_width=hf_config["intermediate_size"] if i < dense
+                      else hf_config["moe_intermediate_size"])
+            for i, kind in enumerate(types))
+        cfg = super().config_from_hf({
+            **hf_config,
+            "rms_norm_eps": hf_config.get("norm_eps", 1e-5),
+            "rope_theta": rope.get("rope_theta", hf_config.get("rope_theta", 1e6)),
+            "tie_word_embeddings": hf_config.get("tie_word_embeddings", True)})
+        self.bind(dataclasses.replace(
+            cfg, layer_specs=specs, qk_norm="head",
+            conv_L_cache=hf_config.get("conv_L_cache", 3),
+            num_local_experts=hf_config["num_experts"] if dense < depth else 0,
+            num_experts_per_tok=hf_config.get("num_experts_per_tok", 4),
+            moe_scoring="sigmoid",
+            moe_selection_bias=bool(hf_config.get("use_expert_bias", True)),
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_renorm_eps=1e-6,   # in the modeling code, not a config key
+            routed_scaling_factor=float(hf_config.get("routed_scaling_factor", 1.0))))
+        return self._cfg
+
+    def bind(self, cfg: LlamaConfig):
+        """The name maps depend on each layer's kind: a policy made for an
+        export is told the config (a conversion's is told by
+        ``config_from_hf``)."""
+        self._cfg = cfg
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        spec = self._cfg.layer_specs[layer]
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "operator_norm.weight": (f + "operator_norm/weight", False),
+               p + "ffn_norm.weight": (f + "ffn_norm/weight", False)}
+        if spec.operator == "conv":
+            for proj in ("in_proj", "out_proj"):
+                out[p + f"conv.{proj}.weight"] = (f + f"conv/{proj}/kernel", True)
+        else:
+            for hf_name, fx in (("q_proj", "q_proj"), ("k_proj", "k_proj"),
+                                ("v_proj", "v_proj"), ("out_proj", "o_proj")):
+                out[p + f"self_attn.{hf_name}.weight"] = (
+                    f + f"self_attn/{fx}/kernel", True)
+            out[p + "self_attn.q_layernorm.weight"] = (f + "self_attn/q_norm/weight", False)
+            out[p + "self_attn.k_layernorm.weight"] = (f + "self_attn/k_norm/weight", False)
+        if spec.ffn == "dense":
+            for hf_name, fx in (("w1", "gate_proj"), ("w3", "up_proj"),
+                                ("w2", "down_proj")):
+                out[p + f"feed_forward.{hf_name}.weight"] = (
+                    f + f"mlp/{fx}/kernel", True)
+        return out
+
+    def moe_map(self, layer: int, num_experts: int):
+        if self._cfg.layer_specs[layer].ffn != "moe":
+            return {}, {}
+        p = f"model.layers.{layer}.feed_forward."
+        f = f"layers_{layer}/block_sparse_moe/"
+        gate = {p + "gate.weight": (f + "gate/kernel", True)}
+        if self._cfg.moe_selection_bias:
+            gate[p + "expert_bias"] = (f + "expert_bias", False)
+        experts = {f + which: [p + f"experts.{e}.{which}.weight"
+                               for e in range(num_experts)]
+                   for which in ("w1", "w3", "w2")}
+        return gate, experts
+
+    def _taps(self, layer: int):
+        return (f"model.layers.{layer}.conv.conv.weight",
+                f"layers_{layer}/conv/conv_weight")
+
+    def special_hf_names(self, layer: int):
+        is_conv = self._cfg.layer_specs[layer].operator == "conv"
+        return [self._taps(layer)[0]] if is_conv else []
+
+    def convert_special(self, layer: int, cfg: LlamaConfig, get_tensor, put):
+        """torch Conv1d's depthwise weight ``[C, 1, L]`` -> taps ``[L, C]``."""
+        for hf_name in self.special_hf_names(layer):
+            put(self._taps(layer)[1], get_tensor(hf_name)[:, 0, :].T)
+
+    def export_special(self, layer: int, cfg: LlamaConfig, flat):
+        return {hf_name: flat[self._taps(layer)[1]].T[:, None, :]
+                for hf_name in self.special_hf_names(layer)}
+
+    def global_map(self, tie_embeddings: bool):
+        out = super().global_map(tie_embeddings)
+        out["model.embedding_norm.weight"] = out.pop("model.norm.weight")
+        return out
 
 
 class GemmaPolicy(HFCheckpointPolicy):
@@ -1336,6 +1453,8 @@ _POLICIES = {
     "MixtralForCausalLM": MixtralPolicy,
     "olmoe": OlmoePolicy,
     "OlmoeForCausalLM": OlmoePolicy,
+    "lfm2_moe": Lfm2MoePolicy,
+    "Lfm2MoeForCausalLM": Lfm2MoePolicy,
     "qwen2_moe": Qwen2MoePolicy,
     "qwen2moe": Qwen2MoePolicy,
     "Qwen2MoeForCausalLM": Qwen2MoePolicy,

@@ -21,6 +21,21 @@ indices cannot collide). The ``[T*k, ·]`` arrays between the matmuls cross
 HBM in ``x.dtype``; the MXU accumulates in float32 either way. Static shapes
 throughout (T*k assignments regardless of routing), no capacity factor and
 no token dropping: exact token-choice semantics.
+
+A chip that holds a share of the experts (``moe_grouped_mlp_share``: experts
+``first_expert .. first_expert + w1.shape[0]`` of a wider router) computes
+``Σ p_e · ffn_e(x)`` over the chosen experts it holds and adds nothing for
+the others: the partial sum an expert-parallel layer's exchange would bring
+home, without the exchange. The assignments are sorted with every absent
+expert's last, so the rows held come first, expert by expert, and only a
+prefix of the sorted rows is gathered, multiplied and combined. That prefix
+has a static length, ``share_rows``: twice the even share
+(``SHARE_ROWS_FACTOR``), so a router sending this chip twice its share still
+costs one pass over a quarter of the ``T*k`` rows at 8 of 64 experts held. A
+step that sends more takes the same function over all ``T*k`` rows under a
+``lax.cond``: slower, exact, counted (``share_fallback``). Rows between the
+held count and the static length are no expert's: the grouped matmul leaves
+them alone, and they are masked where rows are gathered back.
 """
 
 import functools
@@ -137,6 +152,136 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
     xs = moe_dispatch(x, order, inv, k)  # [T*k, H] expert-contiguous
     y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
     return moe_combine(y, top_w, order, inv)
+
+
+SHARE_ROWS_FACTOR = 2
+
+
+def share_rows(assignments: int, held: int, num_experts: int) -> int:
+    """Static length of the sorted-rows prefix a share works on:
+    ``SHARE_ROWS_FACTOR`` times the even share of the ``assignments``, in
+    whole tiles of 8 rows, and never more than all of them."""
+    even = -(-assignments * held // num_experts)
+    return min(assignments, -(-SHARE_ROWS_FACTOR * even // 8) * 8)
+
+
+def moe_share_permutation(top_idx, first_expert: int, held: int):
+    """Sort (token, choice) assignments so that those of the experts held
+    (``first_expert .. first_expert + held``) come first, by expert, in token
+    order; every other assignment follows. Returns ``(order, inv,
+    group_sizes [held])`` as :func:`moe_sort_permutation` and
+    :func:`expert_counts` give them for all experts."""
+    local = top_idx.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    return order, inv, expert_counts(key, held)
+
+
+def _rows_back(rows, pos, n_held):
+    """``rows[pos]`` in float32 where ``pos`` is a row held (``< n_held``),
+    zeros elsewhere: whatever lies beyond the rows held is never read as a
+    number."""
+    got = rows.at[pos].get(mode="fill", fill_value=0)
+    return jnp.where((pos < n_held)[..., None], got.astype(jnp.float32), 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, ))
+def share_dispatch(x, order_r, inv, n_held, k):
+    """``x[T, H] → xs[R, H]``: the first ``R`` sorted rows, zeros from row
+    ``n_held`` on. Transpose: each token gathers back the rows of its
+    assignments that are held, and sums them in float32."""
+    valid = jnp.arange(order_r.size) < n_held
+    return jnp.where(valid[:, None], x[order_r // k], 0)
+
+
+def _share_dispatch_fwd(x, order_r, inv, n_held, k):
+    return share_dispatch(x, order_r, inv, n_held, k), (inv, n_held)
+
+
+def _share_dispatch_bwd(k, res, dxs):
+    inv, n_held = res
+    dx = jnp.sum(_rows_back(dxs, inv.reshape(-1, k), n_held), axis=1)
+    return dx.astype(dxs.dtype), None, None, None
+
+
+share_dispatch.defvjp(_share_dispatch_fwd, _share_dispatch_bwd)
+
+
+@jax.custom_vjp
+def share_combine(y, top_w, order_r, inv, n_held):
+    """``out[t] = Σ_j top_w[t, j] · y[inv[t*k + j]]`` over the assignments
+    whose row is held; the others add nothing. As :func:`moe_combine`."""
+    T, k = top_w.shape
+    yk = _rows_back(y, inv.reshape(T, k), n_held)
+    return jnp.sum(yk * top_w.astype(jnp.float32)[:, :, None],
+                   axis=1).astype(y.dtype)
+
+
+def _share_combine_fwd(y, top_w, order_r, inv, n_held):
+    return share_combine(y, top_w, order_r, inv, n_held), \
+        (y, top_w, order_r, inv, n_held)
+
+
+def _share_combine_bwd(res, dout):
+    y, top_w, order_r, inv, n_held = res
+    T, k = top_w.shape
+    valid = jnp.arange(order_r.size) < n_held
+    g = dout[order_r // k].astype(jnp.float32)          # [R, H] expert order
+    w_sorted = top_w.reshape(-1)[order_r].astype(jnp.float32)
+    dy = jnp.where(valid[:, None], g * w_sorted[:, None], 0.0).astype(y.dtype)
+    dw_sorted = jnp.sum(y.astype(jnp.float32) * g, axis=-1, keepdims=True)
+    dw = _rows_back(dw_sorted, inv.reshape(T, k), n_held)[..., 0]
+    return dy, dw.astype(top_w.dtype), None, None, None
+
+
+share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
+
+
+def _share_rows_mlp(x, w1, w3, w2, top_w, order, inv, group_sizes, *, rows,
+                    activation):
+    """The share's MLP over the first ``rows`` sorted rows."""
+    n_held = jnp.sum(group_sizes)
+
+    def gmm(lhs, w):
+        return jax.lax.ragged_dot(lhs, w, group_sizes,
+                                  preferred_element_type=x.dtype)
+
+    xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
+    y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
+    return share_combine(y, top_w, order[:rows], inv, n_held)
+
+
+def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
+                          num_experts: int, activation=jax.nn.silu):
+    """The part of a token-choice MoE MLP that the experts held here give.
+
+    ``y[t] = Σ_j [first_expert <= top_idx[t,j] < first_expert + E_held]
+    · top_w[t,j] · ffn_{top_idx[t,j]}(x[t])``: routing is over
+    ``num_experts``, ``w1/w3 [E_held, H, F]`` and ``w2 [E_held, F, H]`` are
+    the experts held. Exact: no capacity, no row dropped (module docstring).
+
+    Returns ``(y [T, H], rows_held, fell_back)``: the (token, choice) rows
+    sent to the experts held and whether they outran ``share_rows``, both
+    int32 scalars.
+    """
+    held, assignments = w1.shape[0], top_idx.size
+    order, inv, group_sizes = moe_share_permutation(top_idx, first_expert, held)
+    rows_held = jnp.sum(group_sizes)
+    bound = share_rows(assignments, held, num_experts)
+
+    def over(rows):
+        # nothing between the matmuls is kept for the backward: the cond
+        # would keep it for both branches, the unused one's as zeros
+        return jax.checkpoint(functools.partial(
+            _share_rows_mlp, rows=rows, activation=activation))
+
+    operands = (x, w1, w3, w2, top_w, order, inv, group_sizes)
+    if bound >= assignments:
+        return over(assignments)(*operands), rows_held, jnp.int32(0)
+    fell_back = rows_held > bound
+    y = jax.lax.cond(fell_back, over(assignments), over(bound), *operands)
+    return y, rows_held, fell_back.astype(jnp.int32)
 
 
 def lora_sort_slots(slots, n_slots):

@@ -21,8 +21,8 @@ per leg, strongest first:
 4. a *measured* entry in the persistent autotune cache
    (``autotune_cache.py``, written by ``bin/ds_kernel_tune``);
 5. the built-in heuristic table below (which encodes the measured
-   42.7 < 62.9 ms fwd result: XLA fused forward at hd64 / seq >= 1024,
-   Pallas backward always).
+   42.7 < 62.9 ms fwd result: XLA fused forward at hd64 / seq >= 1024
+   while its float32 scores stay under 2 GiB, Pallas backward always).
 
 Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
 measured cache blocks > ``choose_blocks``, a pure function of the shape
@@ -144,6 +144,13 @@ MAX_ROWS = 1024
 # it). The blocks chosen here stay under it by the estimate below; explicit
 # or measured blocks past it get their own limit on the call.
 VMEM_SCOPED_DEFAULT_BYTES = 16 * 2**20
+# The most the XLA forward's materialised float32 scores may take for the
+# heuristic to choose it: four times the one shape the rule was measured at,
+# a bound and not a crossover. A forward-only sweep at head 64, group 4 (v5e,
+# PR 31, docs/kernel_dispatch.md) found no crossover to place it at: the
+# Pallas forward won at every size from 0.5 to 8 GiB, by 2.3x under the
+# bound and by up to 10x over it.
+XLA_FWD_SCORE_BYTES = 2 * 2**30
 
 
 def flash_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
@@ -240,7 +247,13 @@ def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
     (the same breakdown measured the pallas pair ahead on fwd+bwd).
     """
     if leg == "fwd":
-        if (sig.head_dim <= 64 and sig.seq_k >= 1024 and not sig.windowed):
+        # "fit comfortably": the float32 scores of the whole call, which the
+        # XLA forward writes to HBM. 0.5 GiB at the measured shape (8 x 16
+        # heads x 1024^2); 32 GiB at 4 x 32 heads x 8192^2, which no chip
+        # holds, so long sequences keep the Pallas forward too
+        scores = 4 * sig.batch * sig.heads * sig.seq_q * sig.seq_k
+        if (sig.head_dim <= 64 and sig.seq_k >= 1024 and not sig.windowed
+                and scores <= XLA_FWD_SCORE_BYTES):
             return IMPL_XLA
         return IMPL_PALLAS
     return IMPL_PALLAS

@@ -76,8 +76,10 @@ def _as_apply_fns(model):
             # sharded_moe.py l_aux): sown scalars are ADDED to a scalar
             # model loss; logits outputs pass through untouched.
             # "moe_stats" is the contract for routing counters: sown
-            # ``expert_counts`` ([..., E] per MoE block, a leading axis under
-            # a layer scan) come back summed over blocks, with the aux term
+            # ``expert_counts`` ([..., E] per MoE block, E the router's
+            # width, a leading axis under a layer scan) come back summed
+            # over blocks, with the aux term and, from blocks that hold a
+            # share of the experts, ``rows_held`` and ``share_fallback``
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
@@ -85,12 +87,19 @@ def _as_apply_fns(model):
             if aux and hasattr(out, "ndim") and out.ndim == 0:
                 out = out + aux_total
             stats = {}
-            counts = jax.tree_util.tree_leaves(mods.get("moe_stats", {}))
-            if counts:
-                stats["expert_counts"] = sum(
-                    c.reshape(-1, c.shape[-1]).sum(axis=0) for c in counts)
-                if aux:
-                    stats["aux_loss"] = aux_total.astype(jnp.float32)
+            sown = jax.tree_util.tree_flatten_with_path(
+                mods.get("moe_stats", {}))[0]
+            for path, leaf in sown:
+                # by the name it was sown under, summed over the blocks: a
+                # vector over the router's width (``expert_counts``) keeps
+                # its last axis, a scalar a block (``rows_held``,
+                # ``share_fallback``: a block that holds a share) is summed
+                name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+                total = (jnp.sum(leaf) if name != "expert_counts"
+                         else leaf.reshape(-1, leaf.shape[-1]).sum(axis=0))
+                stats[name] = stats[name] + total if name in stats else total
+            if stats and aux:
+                stats["aux_loss"] = aux_total.astype(jnp.float32)
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -235,6 +244,7 @@ class DeepSpeedTpuEngine:
             self._construct(model, optimizer, model_parameters, training_data,
                             lr_scheduler, mpu, collate_fn, mesh_param,
                             loss_fn, kwargs)
+            self._publish_layer_kinds(model)
 
     def _construct(self, model, optimizer, model_parameters, training_data,
                    lr_scheduler, mpu, collate_fn, mesh_param, loss_fn,
@@ -1589,6 +1599,20 @@ class DeepSpeedTpuEngine:
             return obs.ledger.span(category)
         return nullcontext()
 
+    def _publish_layer_kinds(self, model):
+        """``ds_model_layers{kind="<operator>+<ffn>"}``: how many decoder
+        layers of each kind the model was built with, for a model whose
+        config spells its layers out (``LlamaConfig.layer_specs``)."""
+        specs = getattr(getattr(model, "config", None), "layer_specs", None)
+        if not specs or not self._config.observability_config.enabled:
+            return
+        from collections import Counter
+        from ..observability import get_registry
+        for kind, n in Counter(f"{s.operator}+{s.ffn}" for s in specs).items():
+            get_registry().gauge(
+                "ds_model_layers", "Decoder layers by kind (operator+ffn)",
+                labels={"kind": kind}).set(float(n))
+
     def _publish_moe_stats(self):
         """Routing counters of the fused MoE steps dispatched since the last
         call, in one fetch. Called before the newest step's stats are held,
@@ -1614,6 +1638,25 @@ class DeepSpeedTpuEngine:
             "Busiest expert's assignments over the mean expert's, counts "
             "summed over MoE layers and the steps of the last publish"
         ).set(float(counts.max() / max(counts.mean(), 1.0)))
+        if "rows_held" in fetched[0]:
+            # blocks that hold a share of the router's experts
+            held = sum(float(np.sum(s["rows_held"])) for s in fetched)
+            reg.counter(
+                "ds_moe_rows_held_total",
+                "(token, expert) assignments sent to the experts held on "
+                "this chip, summed over MoE layers and steps"
+            ).inc(held)
+            reg.gauge(
+                "ds_moe_rows_held_share",
+                "Rows held over all assignments the router made, over the "
+                "steps of the last publish (experts held / router width "
+                "when the router is even)"
+            ).set(held / max(float(counts.sum()), 1.0))
+            reg.counter(
+                "ds_moe_share_fallback_total",
+                "MoE layers of a step whose rows held outran the static "
+                "rows array and took the exact pass over all assignments"
+            ).inc(sum(float(np.sum(s["share_fallback"])) for s in fetched))
         aux = [np.asarray(s["aux_loss"], np.float64).mean()
                for s in fetched if "aux_loss" in s]
         if aux:
@@ -1947,8 +1990,10 @@ class DeepSpeedTpuEngine:
 
     def moe_stats(self):
         """Routing stats of the newest fused MoE step not yet published, as
-        host arrays: ``expert_counts`` ``[E]`` ((token, expert) assignments,
-        summed over the MoE layers) and, with a router loss, ``aux_loss``.
+        host arrays: ``expert_counts`` ``[E]`` ((token, expert) assignments
+        over the router's width, summed over the MoE layers), with a router
+        loss ``aux_loss``, and where the blocks hold a share of the experts
+        ``rows_held`` and ``share_fallback`` (summed over the layers).
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
         if not self._moe_pending:

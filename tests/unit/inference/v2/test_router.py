@@ -605,10 +605,16 @@ def test_autoscale_up_down_with_hysteresis(tmp_path):
             for r in fleet.healthy():
                 conn = http.client.HTTPConnection("127.0.0.1", r.port,
                                                   timeout=10)
-                conn.request("POST", "/debug/set_waiting",
-                             json.dumps({"waiting": n}))
-                conn.getresponse().read()
-                conn.close()
+                try:
+                    conn.request("POST", "/debug/set_waiting",
+                                 json.dumps({"waiting": n}))
+                    conn.getresponse().read()
+                except (ConnectionError, http.client.HTTPException):
+                    # the fleet retired this replica between the listing and
+                    # the request: scaling down is what is under test
+                    pass
+                finally:
+                    conn.close()
 
         def probed(pred):
             deadline = time.monotonic() + 20

@@ -96,6 +96,7 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
     (4096, WINDOW, 16, 8, 256),  # Gemma-2's head size
     (1024, None, 16, 16, 64),   # the 0.4B preset: head size 64, group 1
     (4096, None, 16, 16, D),    # OLMoE: head size 128, group 1, no window
+    (8192, None, 32, 8, 64),    # LFM2: head size 64, group 4, 8,192 keys
 ])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
                                           window, heads, kv, d):
@@ -145,6 +146,53 @@ def test_grouped_matmul_compiles_to_the_native_kernel_at_olmoe_widths(
                  if n.startswith("ragged-dot") and "metadata" not in n]
         assert len(names) == calls, names
         assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+def test_short_conv_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):
+    """LFM2's gated short convolution a sequence of the cell: ``[1, 8192, 3 x
+    2048]`` in bf16 with three taps, forward and backward, as Mosaic kernels
+    named for what they are (``benchmark/conv_cost.py`` matches
+    ``%short_conv_fwd*`` and ``%short_conv_bwd*`` in a trace)."""
+    from deepspeed_tpu.ops.short_conv import short_conv
+    bcx = _sds((1, 8192, 3 * 2048), jnp.bfloat16, one_chip)
+    taps = _sds((3, 2048), jnp.float32, one_chip)
+
+    def loss(x, w):
+        return jnp.sum(short_conv(x, w, use_kernel=True).astype(jnp.float32))
+
+    names = _custom_call_names(_compile(jax.grad(loss, argnums=(0, 1)), bcx, taps))
+    assert [n.split(".")[0] for n in sorted(names)] == ["short_conv_bwd"], names
+    names = _custom_call_names(_compile(
+        lambda x, w: short_conv(x, w, use_kernel=True), bcx, taps))
+    assert [n.split(".")[0] for n in names] == ["short_conv_fwd"], names
+
+
+def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
+        one_chip, no_compile_cache):
+    """The LFM2 cell's expert layer a step: 32,768 tokens x top-4 over a
+    router of 64, 8 experts of 2048 x 1536 held. Both branches of the
+    ``cond`` (the static 32,768-row array, and all 131,072 rows) lower to the
+    chip's grouped-matmul kernel, and the program fits beside the state."""
+    from deepspeed_tpu.ops.grouped_matmul import moe_grouped_mlp_share
+    T, HID, F, E, HELD, K = 32768, 2048, 1536, 64, 8, 4
+    sds = functools.partial(_sds, sharding=one_chip)
+    args = [sds((T, HID), jnp.bfloat16), sds((HELD, HID, F), jnp.bfloat16),
+            sds((HELD, HID, F), jnp.bfloat16), sds((HELD, F, HID), jnp.bfloat16),
+            sds((T, K), jnp.int32), sds((T, K), jnp.bfloat16)]
+
+    def loss(*a):
+        y, rows, fell = moe_grouped_mlp_share(*a, first_expert=0, num_experts=E)
+        return jnp.sum(y.astype(jnp.float32) ** 2), (rows, fell)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 5), has_aux=True), *args)
+    names = [n for n in _custom_call_names(compiled)
+             if n.startswith("ragged-dot") and "metadata" not in n]
+    # in each branch three forward and, in the backward, the recomputed
+    # forward's three and the six gradients
+    assert len(names) == 2 * (3 + 3 + 6), names
+    text = compiled.as_text()
+    assert "bf16[32768,1536]" in text and "bf16[131072,1536]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
 def _custom_call_names(compiled):
