@@ -23,8 +23,8 @@ from the saved LSE (no second online softmax). A block wholly above the
 causal diagonal or outside the window is skipped and fetches nothing: the
 swept operand's index map is clamped to the live range, so a dead step
 names the block already resident. Blocks come from the shape
-(``kernel_dispatch.choose_blocks``: 512 keys and up to 1024 folded query
-rows a step where the sequences allow).
+(``kernel_dispatch.choose_blocks``: 1024 folded query rows a step at any
+group, and 512 keys or as many as the queries, where the sequences allow).
 """
 
 import functools

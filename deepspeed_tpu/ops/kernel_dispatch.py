@@ -26,9 +26,8 @@ per leg, strongest first:
 
 Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
 measured cache blocks > ``choose_blocks``, a pure function of the shape
-signature and a VMEM estimate of the leg's tiles (512 keys and a 256-query
-block folded to at most 1024 rows a step; head size 64 at group 1 keeps the
-(256, 512) its round-5 sweep measured).
+signature and a VMEM estimate of the leg's tiles (1024 folded query rows a
+step at any GQA group, and 512 keys or as many as the queries).
 """
 
 import os
@@ -131,14 +130,15 @@ def _env_blocks() -> Optional[tuple]:
         return None
 
 
-# What the block choice aims at (the PR 25 sweep on a v5e at head size 128,
-# PERF.md §6): 512 keys a grid step, so the step's fixed cost and the
-# per-row softmax statistics are paid once per 512 keys; a 256-row query
-# block, folded with its group to at most 1024 rows, so K/V are re-read at
-# most once per 1024 query rows. Head size 64 at group 1 comes out at the
-# (256, 512) its own sweep measured (2026-08-01: +20% over (256, 256)).
+# What the block choice aims at (sweeps on a v5e: PR 25 at group 4, PR 32 at
+# groups 1 and 2, docs/kernel_dispatch.md): 1024 folded rows a grid step
+# (the G query heads of a KV group times the query block), so K/V are
+# re-read once per 1024 rows at any group; 512 keys a step, so the step's
+# fixed cost and the per-row softmax statistics are paid once per 512 keys.
+# The masked work on the causal diagonal follows the larger of the two
+# blocks, so the key block rises to a query block past it (group 1) at no
+# more waste and half the steps.
 KEY_BLOCK = 512
-QUERY_BLOCK = 256
 MAX_ROWS = 1024
 # Mosaic's default scoped-VMEM limit on a v5e (the core has 128 MiB behind
 # it). The blocks chosen here stay under it by the estimate below; explicit
@@ -210,15 +210,17 @@ def _largest_block(seq: int, cap: int) -> int:
 
 
 def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
-    """(block_q, block_k) of a Pallas leg from the shape alone: KEY_BLOCK
-    keys and QUERY_BLOCK queries a step where the sequences allow, the
-    query block halved while the group folds it past MAX_ROWS rows, then
-    whichever of the two is larger halved while the leg's VMEM estimate is
-    over the compiler's default (fp32 operands in the backward, which holds
-    more score tiles at once, are what reach it)."""
+    """(block_q, block_k) of a Pallas leg from the shape alone: the query
+    block that folds with its group to MAX_ROWS rows a step (at least 128)
+    and KEY_BLOCK keys, or as many keys as queries, where the sequences
+    allow; then the larger of rows and keys halved, the keys on a tie, while
+    the leg's VMEM estimate is over the compiler's default (the backward,
+    which holds more score tiles at once, reaches it at group 1 and with
+    fp32 operands)."""
     group = max(1, sig.heads // sig.kv_heads)
     itemsize = 4 if "32" in sig.dtype else 2
-    cap_q, cap_k = max(128, min(QUERY_BLOCK, MAX_ROWS // group)), KEY_BLOCK
+    cap_q = max(128, MAX_ROWS // group)
+    cap_k = max(KEY_BLOCK, cap_q)
     while True:
         bq = _largest_block(sig.seq_q, cap_q)
         bk = _largest_block(sig.seq_k, cap_k)
@@ -226,7 +228,7 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
                                 bk) > VMEM_SCOPED_DEFAULT_BYTES
         if not over or max(cap_q, cap_k) <= 128:
             return bq, bk
-        if group * cap_q >= cap_k and cap_q > 128:
+        if group * cap_q > cap_k and cap_q > 128:
             cap_q //= 2
         else:
             cap_k //= 2

@@ -18,8 +18,11 @@ reached, plus the current defaults.
 
 Per shape the tool times:
   fwd:  xla fused, pallas per-head x blocks, folded x blocks
-  bwd:  xla (vjp recompute), pallas per-head x blocks, folded x blocks
+  bwd:  xla (vjp recompute), pallas per-head x blocks, folded x blocks;
+        the pullback alone, on residuals an untimed forward left
 and writes one cache entry per (leg, shape signature, device kind).
+``--impls`` and ``--blocks`` narrow the candidates (a block sweep of one
+impl: ``--dry-run --impls pallas --blocks 512x512,1024x512,1024x1024``).
 """
 
 import argparse
@@ -43,21 +46,24 @@ def _time(fn, iters: int, warmup: int = 1) -> float:
     return (time.perf_counter() - t0) / iters * 1e3  # ms
 
 
-def _blocks_for(impl: str, sig, leg: str, quick: bool):
-    """Candidate (block_q, block_k) grid for a Pallas impl; XLA has none."""
+def _blocks_for(impl: str, sig, leg: str, quick: bool, grid=None):
+    """Candidate (block_q, block_k) grid for a Pallas impl; XLA has none.
+    ``grid`` replaces ``SWEEP_BLOCKS`` (``--blocks``)."""
     from deepspeed_tpu.ops import kernel_dispatch as kd
     if impl == kd.IMPL_XLA:
         return [None]
     chosen = kd.choose_blocks(sig, leg)
     if quick:
         return [chosen]
-    return list(dict.fromkeys((chosen, ) + kd.SWEEP_BLOCKS))
+    return list(dict.fromkeys((chosen, ) + tuple(grid or kd.SWEEP_BLOCKS)))
 
 
 def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
-                iters, interpret, quick, impls=None, commit=True):
+                iters, interpret, quick, impls=None, commit=True,
+                grid=None):
     """Sweep one shape; returns {leg: (winner_dict, rows)} and optionally
-    commits the winners to the autotune cache."""
+    commits the winners to the autotune cache. ``grid`` replaces the
+    Pallas candidates' block grid."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kernel_dispatch as kd
@@ -84,21 +90,23 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
         return lambda: f(q, k, v)
 
     def bwd_fn(impl, blocks):
-        # time fwd+bwd with the SAME pinned fwd (xla — cheapest residual
-        # producer) so leg timings differ only by the bwd impl under test
+        # the pullback alone: one untimed forward of the same impl leaves
+        # its residuals, and only the backward's kernels are in the timing
+        # (an XLA forward's float32 scores are 4 GiB at 4 x 16 x 4096^2)
         bq, bk = blocks or (None, None)
-        g = jax.jit(jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, interpret=interpret,
-            impl_fwd=kd.IMPL_XLA, impl_bwd=impl,
-            block_q=bq, block_k=bk).sum(), argnums=(0, 1, 2)))
-        return lambda: g(q, k, v)
+        out, pull = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, interpret=interpret, impl_fwd=impl,
+            impl_bwd=impl, block_q=bq, block_k=bk), q, k, v)
+        g = jnp.ones_like(out)
+        run = jax.jit(lambda pull, g: pull(g))
+        return lambda: run(pull, g)
 
     results = {}
     for leg, make in (("fwd", fwd_fn), ("bwd", bwd_fn)):
         rows = []
         for impl in impls:
             seen = set()
-            for blocks in _blocks_for(impl, sig, leg, quick):
+            for blocks in _blocks_for(impl, sig, leg, quick, grid):
                 if blocks is not None:
                     # a tile can't exceed the sequence — clamp, then dedupe
                     # (several candidates can clamp to the same point)
@@ -152,6 +160,11 @@ def main(argv=None):
                     help="defaults-only block grid (smoke test)")
     ap.add_argument("--dry-run", action="store_true",
                     help="time everything, commit nothing")
+    ap.add_argument("--impls", default=None,
+                    help="comma list of xla,pallas,folded (default: all)")
+    ap.add_argument("--blocks", default=None,
+                    help="Pallas block grid as 'bqxbk,bqxbk,...' in place "
+                         "of kernel_dispatch.SWEEP_BLOCKS")
     args = ap.parse_args(argv)
 
     import jax
@@ -172,7 +185,10 @@ def main(argv=None):
     sweep_shape(args.batch, args.seq, args.heads, kv, args.head_dim,
                 args.dtype, args.causal, iters=args.iters,
                 interpret=args.interpret, quick=args.quick,
-                commit=not args.dry_run)
+                impls=args.impls and tuple(args.impls.split(",")),
+                commit=not args.dry_run,
+                grid=args.blocks and [tuple(int(x) for x in b.split("x"))
+                                      for b in args.blocks.split(",")])
     if not args.dry_run:
         print(f"table now: {get_cache().source_description()}")
     return 0

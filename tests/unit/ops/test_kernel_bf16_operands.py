@@ -36,7 +36,9 @@ def _oracle_grads(q, k, v, scale, causal):
     (2, 1024, 128, None),           # group 4, head 128: the chosen (256, 512)
     (1, 1024, 128, (64, 512)),      # group 8, 512 keys a step
     (4, 512, 256, (128, 512)),      # head size 256
-    (8, 1024, 64, (256, 512)),      # head size 64 at its measured blocks
+    (8, 1024, 64, (256, 512)),      # head size 64, the blocks before PR 32
+    (8, 1024, 128, None),           # group 1: (1024, 1024) and (1024, 512)
+    (4, 1024, 128, None),           # group 2: the chosen (512, 512)
 ])
 def test_flash_bf16_fwd_bwd_matches_fp32_oracle(kv_heads, seq, d, blocks):
     rng = np.random.default_rng(11)

@@ -127,6 +127,46 @@ def test_bfloat16_route_parity():
                                    atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("s,h,kv,fwd,bwd", [
+    (2048, 2, 2, (1024, 1024), (1024, 512)),   # MHA, as the OLMoE cell
+    (1024, 4, 2, (512, 512), (512, 512)),      # group 2
+    (1024, 4, 1, (256, 512), (256, 512)),      # group 4, as the dense cell
+])
+def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd):
+    """The blocks ``choose_blocks`` gives each group (1024 folded rows a
+    step), run as the dispatcher runs them: causal, bf16 operands, float32
+    accumulation, against the dense reference in float32 on the same
+    (bf16-rounded) inputs. bf16's unit roundoff is 3.9e-3 and p and ds are
+    rounded to it before their matmuls: the relative L2 error reads
+    2.0e-3 to 3.1e-3 at every block pair here, (256, 512) included."""
+    q, k, v = _qkv(b=1, s=s, h=h, kv=kv, d=128, dtype=jnp.bfloat16)
+    f_dec, b_dec = kd.resolve(kd.make_sig(q.shape, kv, s, q.dtype, True,
+                                          None, None), "interpret")
+    assert (f_dec.block_q, f_dec.block_k) == fwd
+    assert (b_dec.block_q, b_dec.block_k) == bwd
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    w = jnp.asarray(np.random.default_rng(3).normal(size=q.shape), jnp.float32)
+
+    def ref_loss(q, k, v):
+        out = _xla_attention(q, k, v, scale, True)
+        return (out * w).sum(), out
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=True,
+                              impl_fwd="pallas", impl_bwd="pallas")
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    (_, o_ref), g_ref = jax.value_and_grad(ref_loss, (0, 1, 2), has_aux=True)(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    (_, o), g = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+    for name, got, ref in zip(("o", "dq", "dk", "dv"), (o, ) + g,
+                              (o_ref, ) + g_ref):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref)
+        assert np.isfinite(got).all(), name
+        err = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+        assert err < 1e-2, (name, err)
+
+
 # ---------------------------------------------------------------------------
 # the dispatch table itself
 # ---------------------------------------------------------------------------
@@ -149,11 +189,12 @@ def test_bench_shape_routes_xla_fwd_pallas_bwd():
     assert fwd.impl == kd.IMPL_XLA and fwd.source == "heuristic"
     assert bwd.impl == kd.IMPL_PALLAS and bwd.source == "heuristic"
     assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(_bench_sig(), "bwd")
-    assert (bwd.block_q, bwd.block_k) == (256, 512)  # the 2026-08-01 sweep
+    assert (bwd.block_q, bwd.block_k) == (1024, 512)  # group 1: 1024 rows
 
 
-def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None):
-    return kd.make_sig((1, seq, heads, d), kv, seq_k or seq, dtype, True,
+def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None,
+         batch=1):
+    return kd.make_sig((batch, seq, heads, d), kv, seq_k or seq, dtype, True,
                        window, None)
 
 
@@ -161,13 +202,29 @@ def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None):
     # each chip's call in train-zero3-seq4k (Mistral-7B widths)
     ("cell", _sig(4096, 32, 8, 128, window=4096), (256, 512), (256, 512)),
     ("chip_smoke", _sig(2048, 32, 8, 128, window=4096), (256, 512), (256, 512)),
-    # the one shape with a block sweep on the chip before PR 25: unchanged
-    ("hd64_1024", _bench_sig(), (256, 512), (256, 512)),
+    # train-lfm2moe-1chip-seq8k: head size 64 is laid out in 128 lanes
+    ("lfm2_cell", _sig(8192, 32, 8, 64, batch=4), (256, 512), (256, 512)),
+    # train-olmoe-1chip-seq4k, MHA: 1024 rows a step are 1024 queries, the
+    # keys rise to them, and the backward's tiles pass the default at 1024
+    # keys, so it keeps 512 (the PR 32 sweep's winners under the default)
+    ("olmoe_cell", _sig(4096, 16, 16, 128, batch=4), (1024, 1024),
+     (1024, 512)),
+    ("group2", _sig(4096, 16, 8, 128, batch=4), (512, 512), (512, 512)),
+    # the 0.4B preset: group 1 at head size 64 follows the same rule
+    ("hd64_1024", _bench_sig(), (1024, 1024), (1024, 512)),
+    ("mha_2048", _sig(2048, 16, 16, 128), (1024, 1024), (1024, 512)),
+    ("mha_1536", _sig(1536, 16, 16, 128), (768, 768), (768, 768)),
+    ("mha_512", _sig(512, 16, 16, 128), (512, 512), (512, 512)),
     ("llama3_70b", _sig(4096, 64, 8, 128), (128, 512), (128, 512)),
-    ("gemma2_hd256", _sig(4096, 16, 8, 256), (256, 512), (256, 512)),
+    ("group16", _sig(4096, 32, 2, 128), (128, 256), (128, 128)),
+    # Gemma-2 is group 2 (16 / 8 heads of 256)
+    ("gemma2_hd256", _sig(4096, 16, 8, 256), (512, 512), (512, 512)),
     ("ulysses_32k", _sig(32768, 8, 2, 128), (256, 512), (256, 512)),
     # fp32 operands: the backward's tiles pass the default, so it halves
     ("fp32", _sig(4096, 32, 8, 128, "float32"), (256, 512), (128, 512)),
+    # at group 1 the tie of 1024 rows and 1024 keys gives up the keys first
+    ("fp32_mha", _sig(4096, 16, 16, 128, "float32"), (1024, 512),
+     (512, 512)),
     ("seq128", _sig(128, 8, 8, 64), (128, 128), (128, 128)),
     ("seq384", _sig(384, 8, 2, 64), (128, 384), (128, 384)),
     ("seq64", _sig(64, 4, 4, 16), (64, 64), (64, 64)),
@@ -188,8 +245,13 @@ def test_blocks_follow_from_the_shape(name, sig, fwd, bwd):
         est = kd.flash_vmem_bytes(leg, group, sig.head_dim, itemsize, bq, bk)
         assert est <= kd.VMEM_SCOPED_DEFAULT_BYTES, (name, leg, est)
         assert kd.vmem_limit_bytes(est) is None
-        if sig.seq_k >= 512 and sig.seq_q >= 512:
+        if sig.seq_k >= 512 and sig.seq_q >= 512 and group <= 8:
             assert bk >= 512 and group * bq >= 256, (name, leg)
+        # a step never folds more than MAX_ROWS rows unless the 128-query
+        # floor does it, and bf16 operands reach MAX_ROWS where they can
+        assert group * bq <= max(kd.MAX_ROWS, 128 * group), (name, leg)
+        if itemsize == 2 and sig.seq_q % kd.MAX_ROWS == 0:
+            assert group * bq >= kd.MAX_ROWS, (name, leg)
     if name == "cell":
         bq, bk = kd.choose_blocks(sig, "bwd")
         assert bk >= 512 and 512 <= group * bq <= 1024
@@ -434,6 +496,25 @@ def test_sweep_dry_run_commits_nothing(monkeypatch, tmp_path):
     sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True, iters=1,
                       interpret=True, quick=True, commit=False,
                       impls=(kd.IMPL_XLA, kd.IMPL_PALLAS))
+    assert not (tmp_path / "attn_dispatch.json").exists()
+
+
+def test_sweep_takes_a_block_grid_and_times_the_backward_alone(monkeypatch,
+                                                              tmp_path):
+    """``--impls`` / ``--blocks``: one impl over a grid of one's own beside
+    the chosen blocks (the PR 32 block sweep), each leg with a time of its
+    own from the same residuals."""
+    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
+    sweep = _load_sweep_module()
+    results = sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True, iters=1,
+                                interpret=True, quick=False, commit=False,
+                                impls=(kd.IMPL_PALLAS, ),
+                                grid=[(64, 64), (128, 64)])
+    for leg in ("fwd", "bwd"):
+        entry, rows = results[leg]
+        assert [r[0] for r in rows] == ["pallas@128x128", "pallas@64x64",
+                                        "pallas@128x64"]
+        assert all(r[-1] > 0 for r in rows) and entry["impl"] == "pallas"
     assert not (tmp_path / "attn_dispatch.json").exists()
 
 

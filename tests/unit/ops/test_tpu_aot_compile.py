@@ -13,6 +13,7 @@ test file), and the compile stays in this process.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,8 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
     (4096, WINDOW, 16, 8, 256),  # Gemma-2's head size
     (1024, None, 16, 16, 64),   # the 0.4B preset: head size 64, group 1
     (4096, None, 16, 16, D),    # OLMoE: head size 128, group 1, no window
+    (4096, None, 16, 8, D),     # group 2: (512, 512)
+    (1536, None, 16, 16, D),    # group 1, a sequence 1024 does not divide
     (8192, None, 32, 8, 64),    # LFM2: head size 64, group 4, 8,192 keys
 ])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
@@ -114,6 +117,44 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
                         sds(heads), sds(kv), sds(kv))
     # forward, dq, and dk+dv
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
+                                                        no_compile_cache):
+    """``train-olmoe-1chip-seq4k``'s call, ``[4, 4096, 16/16, 128]``: at
+    group 1 a step of 1024 folded rows is a 1024-query block. The forward
+    at (1024, 1024) and dq and dk/dv at (1024, 512) compile under the
+    compiler's default scoped VMEM (no limit on the call), each under its
+    own name, on the cell's own operand."""
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    shape = (4, 4096, 16, D)
+    sig = kd.make_sig(shape, 16, 4096, "bfloat16", True, None, None)
+    assert kd.choose_blocks(sig, "fwd") == (1024, 1024)
+    assert kd.choose_blocks(sig, "bwd") == (1024, 512)
+    for leg in ("fwd", "bwd"):
+        est = kd.flash_vmem_bytes(leg, 1, D, 2, *kd.choose_blocks(sig, leg))
+        assert kd.vmem_limit_bytes(est) is None, (leg, est)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, force_pallas=True)
+
+    x = _sds(shape, jnp.bfloat16, one_chip)
+    compiled = _compile(
+        jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+                 argnums=(0, 1, 2)), x, x, x)
+    calls = _custom_calls(compiled)
+    names = _custom_call_names(compiled)
+    assert sorted(n.split(".")[0] for n in names) == [
+        "flash_dkdv", "flash_dq", "flash_fwd"], names
+    # a call's backend config holds the scoped VMEM it asked for (nothing:
+    # the compiler's default) and then what the compiler gave it
+    for call in calls:
+        asked, given = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', call)
+        assert asked == "", call
+        assert (int(re.search(r'"size":"(\d+)"', given).group(1))
+                <= kd.VMEM_SCOPED_DEFAULT_BYTES)
+    fwd, = [c for c in calls if "%flash_fwd" in c.split(" = ")[0]]
+    assert f"bf16[64,1,4096,{D}]" in fwd
 
 
 def test_rms_norm_compiles(one_chip, no_compile_cache):
@@ -195,12 +236,17 @@ def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
+def _custom_calls(compiled):
+    """The compiled program's lines that call a Pallas kernel."""
+    return [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def _custom_call_names(compiled):
     """Instruction names of the program's Mosaic kernels: what the device
     trace's "XLA Ops" line calls them."""
     return [line.split(" = ")[0].split("%")[-1]
-            for line in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
+            for line in _custom_calls(compiled)]
 
 
 @pytest.mark.parametrize("meshed", [False, True], ids=["one_chip", "shard_map_2x2"])
@@ -248,7 +294,6 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
         assert sum(n.startswith(prefix) for n in names) == 1, names
     assert not any(n.startswith("shard_map") for n in names)
     # each chip's call is the cell's shape: [rows * kv, group, seq, d]
-    fwd, = [line for line in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line
-            and "%flash_fwd" in line.split(" = ")[0]]
+    fwd, = [line for line in _custom_calls(compiled)
+            if "%flash_fwd" in line.split(" = ")[0]]
     assert f"bf16[{KV},{H // KV},{seq},{D}]" in fwd
