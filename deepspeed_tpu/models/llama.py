@@ -36,7 +36,8 @@ class LayerSpec:
     """What ONE decoder layer is built from, for models whose layers are not
     all alike (LFM2: gated short convolutions with an attention layer among
     every few, a dense FFN in the leading layers and experts after)."""
-    operator: str = "attention"     # "attention" | "conv" (ops/short_conv.py)
+    # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer)
+    operator: str = "attention"
     ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
     ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
 
@@ -66,7 +67,8 @@ class LlamaConfig:
     # "rmsnorm" | "layernorm" (scale+bias) | "layernorm_nobias" (Cohere:
     # scale only) | "layernorm_np" (OLMo: non-parametric, no scale/bias)
     norm_type: str = "rmsnorm"
-    pos_embedding: str = "rope"       # "rope" | "learned" (OPT) | "alibi" (BLOOM)
+    # "rope" | "learned" (OPT) | "alibi" (BLOOM) | "none" (Granite 4.0-H: NoPE)
+    pos_embedding: str = "rope"
     embed_layernorm: bool = False     # BLOOM word_embeddings_layernorm
     pos_offset: int = 0               # OPT stores positions at index pos+2
     rotary_dim: Optional[int] = None  # Phi partial rotary; None = full head_dim
@@ -136,6 +138,19 @@ class LlamaConfig:
     # layer attention + the one global FFN the fields above describe
     layer_specs: Optional[Tuple[LayerSpec, ...]] = None
     conv_L_cache: int = 3         # taps of the "conv" operator
+    # the "mamba" operator (Mamba-2): heads of mamba_d_head values, a state
+    # of mamba_d_state a value, B and C shared by the heads of a group, the
+    # scan in chunks of mamba_chunk_size, mamba_d_conv taps before it
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    # Granite: x + residual_multiplier * sublayer(norm(x)) on both branches
+    # of a layer_specs layer (None = 1)
+    residual_multiplier: Optional[float] = None
     attn_impl: str = "auto"       # "auto" | "flash" (Pallas) | "xla"
     dtype: Any = jnp.bfloat16
     scan_layers: bool = False
@@ -188,8 +203,12 @@ class LlamaConfig:
             kind = "moe" if self.num_local_experts > 0 else "dense"
             return attn + ffn(kind, self.intermediate_size) + 2 * h
         conv = 4 * h * h + self.conv_L_cache * h    # in_proj, out_proj, taps
-        return max((conv if spec.operator == "conv" else attn)
-                   + ffn(spec.ffn, spec.ffn_width) + 2 * h
+        inner = self.mamba_n_heads * self.mamba_d_head
+        xbc = inner + 2 * self.mamba_n_groups * self.mamba_d_state
+        mamba = (h * (inner + xbc + self.mamba_n_heads) + inner * h
+                 + (self.mamba_d_conv + 1) * xbc + 3 * self.mamba_n_heads + inner)
+        operator = {"conv": conv, "attention": attn, "mamba": mamba}
+        return max(operator[spec.operator] + ffn(spec.ffn, spec.ffn_width) + 2 * h
                    for spec in self.layer_specs)
 
     def with_live_param_budget(self, max_live_parameters: int) -> "LlamaConfig":
@@ -522,6 +541,86 @@ class ShortConvOperator(nn.Module):
         return _dense(H, "out_proj", (HIDDEN, EMBED), cfg.dtype)(y)
 
 
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's: ``softplus(dt_bias)`` log-uniform in [1e-3, 1e-1]."""
+    lo, hi = np.log(1e-3), np.log(1e-1)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape) * (hi - lo) + lo), 1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2, the operator of a ``"mamba"`` layer (HF
+    ``GraniteMoeHybridMambaLayer``): ``z | xBC | dt = in_proj(u)``; ``xBC =
+    silu(conv(xBC) + bias)`` (``mamba_d_conv`` causal depthwise taps,
+    ``ops/short_conv.py::causal_conv``); ``x | B | C = xBC``, ``x`` as
+    ``mamba_n_heads`` heads of ``mamba_d_head``; ``dt = softplus(dt +
+    dt_bias)``, ``A = -exp(A_log)``; the state-space scan with a state of
+    ``mamba_d_head x mamba_d_state`` a head (``ops/ssd.py::ssd_scan``, in
+    chunks of ``mamba_chunk_size``); ``RMSNorm(y * silu(z)) * w`` over all
+    of ``y``; ``out_proj``. One group: ``B`` and ``C`` are shared by the
+    heads. Sows ``ssm_stats``: the largest ``|S|`` and the mean ``dt``."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.short_conv import causal_conv
+        from ..ops.ssd import ssd_scan
+        cfg = self.config
+        H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        if cfg.mamba_n_groups != 1:
+            raise ValueError(f"mamba_n_groups={cfg.mamba_n_groups}: one group "
+                             "(B and C shared by the heads) is what is built")
+        inner, xbc_width = H * P, H * P + 2 * N
+        b, s, _ = u.shape
+        f32 = jnp.float32
+        zxbcdt = _dense(inner + xbc_width + H, "in_proj", (EMBED, HIDDEN), cfg.dtype)(u)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + xbc_width], axis=-1)
+        taps = self.param(
+            "conv_weight",
+            nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0, out_axis=1),
+                                 (None, HIDDEN)),
+            (cfg.mamba_d_conv, xbc_width), f32)
+        conv_bias = jnp.zeros((xbc_width, ), f32)
+        if cfg.mamba_conv_bias:
+            bound = cfg.mamba_d_conv ** -0.5     # torch's Conv1d: U(+-1/sqrt(fan in))
+            conv_bias = self.param(
+                "conv_bias",
+                nn.with_partitioning(lambda key, shape, dtype=f32: jax.random.uniform(
+                    key, shape, dtype, -bound, bound), (HIDDEN, )),
+                (xbc_width, ), f32)
+        # raw pallas_calls are not partitioned under GSPMD: as for flash, the
+        # kernels run where the mesh is one device
+        kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
+        xbc = causal_conv(xbc, taps, conv_bias, use_kernel=kernels,
+                          interpret=interpret_kernels())
+        x, B, C = jnp.split(xbc, [inner, inner + N], axis=-1)
+        def per_head(name, init):
+            return self.param(name, nn.with_partitioning(init, (HEADS, )), (H, ), f32)
+
+        dt_bias = per_head("dt_bias", _dt_bias_init)
+        a_log = per_head("A_log", lambda key, shape, dtype=f32: jnp.log(
+            jnp.arange(1, shape[0] + 1, dtype=dtype)))
+        d_skip = per_head("D", nn.initializers.ones)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+        want_stats = self.is_mutable_collection("ssm_stats")
+        y = ssd_scan(x.reshape(b, s, H, P), dt, -jnp.exp(a_log), B, C, d_skip,
+                     cfg.mamba_chunk_size, use_kernel=kernels,
+                     interpret=interpret_kernels(), with_state_absmax=want_stats)
+        if want_stats:
+            y, top = y
+            self.sow("ssm_stats", "state_absmax", top, reduce_fn=jnp.maximum,
+                     init_fn=lambda: jnp.zeros((), f32))
+            self.sow("ssm_stats", "dt_mean", jax.lax.stop_gradient(jnp.mean(dt)),
+                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+        gated = y.reshape(b, s, inner).astype(f32) * jax.nn.silu(z.astype(f32))
+        weight = self.param("norm_weight",
+                            nn.with_partitioning(nn.initializers.ones, (HIDDEN, )),
+                            (inner, ), f32)
+        var = jnp.mean(gated * gated, axis=-1, keepdims=True)
+        y = (gated * jax.lax.rsqrt(var + cfg.rms_norm_eps) * weight).astype(cfg.dtype)
+        return _dense(cfg.hidden_size, "out_proj", (HIDDEN, EMBED), cfg.dtype)(y)
+
+
 class LlamaMLP(nn.Module):
     config: LlamaConfig
 
@@ -678,21 +777,33 @@ class LlamaDecoderLayer(nn.Module):
         cfg = self.config
         if cfg.layer_specs is not None:
             # this layer's own operator and FFN (LFM2's names and residual
-            # form): r = x + op(operator_norm(x)); r + ffn(ffn_norm(r))
+            # form): r = x + op(operator_norm(x)); r + ffn(ffn_norm(r)),
+            # each branch times residual_multiplier where there is one
             spec = cfg.layer_specs[self.layer_idx]
+
+            def branch(out):
+                if cfg.residual_multiplier is None:
+                    return out
+                # the product in float32, rounded once, as torch multiplies
+                # a bf16 tensor by a Python scalar
+                return (out.astype(jnp.float32)
+                        * cfg.residual_multiplier).astype(out.dtype)
+
             normed = _make_norm(cfg, "operator_norm")(x)
             if spec.operator == "conv":
-                h = x + ShortConvOperator(cfg, name="conv")(normed)
+                h = x + branch(ShortConvOperator(cfg, name="conv")(normed))
+            elif spec.operator == "mamba":
+                h = x + branch(Mamba2Mixer(cfg, name="mamba")(normed))
             elif spec.operator == "attention":
-                h = x + LlamaAttention(cfg, self.layer_idx, name="self_attn")(
-                    normed, cos, sin, positions, attn_mask)
+                h = x + branch(LlamaAttention(cfg, self.layer_idx, name="self_attn")(
+                    normed, cos, sin, positions, attn_mask))
             else:
                 raise ValueError(f"unknown operator {spec.operator!r}")
             ffn_cfg = dataclasses.replace(cfg, intermediate_size=spec.ffn_width)
             normed2 = _make_norm(cfg, "ffn_norm")(h)
             if spec.ffn == "moe":
-                return h + LlamaMoEBlock(ffn_cfg, name="block_sparse_moe")(normed2)
-            return h + LlamaMLP(ffn_cfg, name="mlp")(normed2)
+                return h + branch(LlamaMoEBlock(ffn_cfg, name="block_sparse_moe")(normed2))
+            return h + branch(LlamaMLP(ffn_cfg, name="mlp")(normed2))
         if cfg.sandwich_norm:
             # Gemma-2: pre AND post norms around both sublayers
             attn_out = LlamaAttention(cfg, self.layer_idx, name="self_attn")(
@@ -842,7 +953,7 @@ class LlamaModel(nn.Module):
             # sums all leaves, so stacking ≡ the unscanned reduce_fn sum)
             ScanLayer = nn.scan(_ScanBody,
                                 variable_axes={"params": 0, "aux_loss": 0,
-                                               "moe_stats": 0},
+                                               "moe_stats": 0, "ssm_stats": 0},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,

@@ -420,6 +420,130 @@ class Lfm2MoePolicy(HFCheckpointPolicy):
         return out
 
 
+class GraniteMoeHybridPolicy(HFCheckpointPolicy):
+    """Granite 4.0-H, the dense hybrid (HF ``modeling_granitemoehybrid.py``):
+    pre-norm layers whose mixer is, by ``layer_types``, a Mamba-2 layer
+    (``mamba.in_proj`` to ``z | xBC | dt``, ``mamba.conv1d`` with bias and
+    SiLU, the state-space scan, the gated ``mamba.norm``, ``mamba.out_proj``)
+    or GQA attention without a position embedding; a SwiGLU
+    ``shared_mlp`` (``input_linear`` holds gate and up, in that order) in
+    every layer; ``embedding_multiplier`` on the embeddings,
+    ``residual_multiplier`` on both branches, ``attention_multiplier`` as
+    the scores' scale, logits over ``logits_scaling``; embeddings tied.
+    Refused by name until a later change brings them: routed experts
+    (``num_local_experts > 0``), ``mamba_n_groups > 1``, a
+    ``position_embedding_type`` other than ``"nope"``, biases on the
+    mixer's projections, another norm or activation."""
+    arch = "granitemoehybrid"
+    row_parallel = ["o_proj", "down_proj", "out_proj"]
+    col_parallel = HFCheckpointPolicy.col_parallel + ["in_proj"]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        refused = {
+            "num_local_experts": hf_config.get("num_local_experts", 0) > 0,
+            "mamba_n_groups": hf_config.get("mamba_n_groups", 1) > 1,
+            "position_embedding_type":
+                hf_config.get("position_embedding_type", "nope") != "nope",
+            "normalization_function":
+                hf_config.get("normalization_function", "rmsnorm") != "rmsnorm",
+            "hidden_act": hf_config.get("hidden_act", "silu") != "silu",
+            "mamba_proj_bias": bool(hf_config.get("mamba_proj_bias", False))}
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(f"granitemoehybrid: {key}={hf_config[key]!r} is "
+                                 "not supported")
+        depth, types = hf_config["num_hidden_layers"], hf_config["layer_types"]
+        if len(types) != depth or set(types) - {"mamba", "attention"}:
+            raise ValueError(f"granitemoehybrid: layer_types {types} for {depth} layers")
+        heads, head = hf_config["mamba_n_heads"], hf_config["mamba_d_head"]
+        if heads * head != hf_config.get("mamba_expand", 2) * hf_config["hidden_size"]:
+            raise ValueError(f"granitemoehybrid: {heads} heads of {head} are not "
+                             "mamba_expand * hidden_size")
+        width = hf_config["shared_intermediate_size"]
+        cfg = super().config_from_hf({
+            **hf_config, "intermediate_size": width,
+            "tie_word_embeddings": hf_config.get("tie_word_embeddings", True)})
+        self.bind(dataclasses.replace(
+            cfg,
+            layer_specs=tuple(LayerSpec(operator=kind, ffn="dense", ffn_width=width)
+                              for kind in types),
+            pos_embedding="none", num_local_experts=0,
+            attention_bias=bool(hf_config.get("attention_bias", False)),
+            embed_scale=float(hf_config.get("embedding_multiplier", 1.0)),
+            residual_multiplier=float(hf_config.get("residual_multiplier", 1.0)),
+            attn_scale=float(hf_config.get("attention_multiplier", 1.0)),
+            logit_scale=1.0 / float(hf_config.get("logits_scaling", 1.0)),
+            mamba_n_heads=heads, mamba_d_head=head,
+            mamba_d_state=hf_config["mamba_d_state"], mamba_n_groups=1,
+            mamba_chunk_size=hf_config.get("mamba_chunk_size", 256),
+            mamba_d_conv=hf_config.get("mamba_d_conv", 4),
+            mamba_conv_bias=bool(hf_config.get("mamba_conv_bias", True))))
+        return self._cfg
+
+    def bind(self, cfg: LlamaConfig):
+        """As ``Lfm2MoePolicy.bind``: the name maps depend on the layer's kind."""
+        self._cfg = cfg
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "input_layernorm.weight": (f + "operator_norm/weight", False),
+               p + "post_attention_layernorm.weight": (f + "ffn_norm/weight", False),
+               p + "shared_mlp.output_linear.weight": (f + "mlp/down_proj/kernel", True)}
+        if self._cfg.layer_specs[layer].operator == "mamba":
+            m, fm = p + "mamba.", f + "mamba/"
+            out.update({m + "in_proj.weight": (fm + "in_proj/kernel", True),
+                        m + "out_proj.weight": (fm + "out_proj/kernel", True),
+                        m + "dt_bias": (fm + "dt_bias", False),
+                        m + "A_log": (fm + "A_log", False),
+                        m + "D": (fm + "D", False),
+                        m + "norm.weight": (fm + "norm_weight", False)})
+            if self._cfg.mamba_conv_bias:
+                out[m + "conv1d.bias"] = (fm + "conv_bias", False)
+        else:
+            for proj in ("q_proj", "k_proj", "v_proj", "o_proj"):
+                out[p + f"self_attn.{proj}.weight"] = (
+                    f + f"self_attn/{proj}/kernel", True)
+                if attention_bias and proj != "o_proj":
+                    out[p + f"self_attn.{proj}.bias"] = (
+                        f + f"self_attn/{proj}/bias", False)
+        return out
+
+    def _special(self, layer: int):
+        """HF name -> our path(s) of the tensors a plain map cannot express."""
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "shared_mlp.input_linear.weight":
+               (f + "mlp/gate_proj/kernel", f + "mlp/up_proj/kernel")}
+        if self._cfg.layer_specs[layer].operator == "mamba":
+            out[p + "mamba.conv1d.weight"] = (f + "mamba/conv_weight", )
+        return out
+
+    def special_hf_names(self, layer: int):
+        return list(self._special(layer))
+
+    def convert_special(self, layer: int, cfg: LlamaConfig, get_tensor, put):
+        """``input_linear`` ``[2 F, hidden]`` -> gate and up kernels
+        ``[hidden, F]``; torch Conv1d's depthwise weight ``[C, 1, L]`` -> taps
+        ``[L, C]``."""
+        for hf_name, paths in self._special(layer).items():
+            w = get_tensor(hf_name)
+            if len(paths) == 2:
+                gate, up = np.split(w, 2, axis=0)
+                put(paths[0], gate.T)
+                put(paths[1], up.T)
+            else:
+                put(paths[0], w[:, 0, :].T)
+
+    def export_special(self, layer: int, cfg: LlamaConfig, flat):
+        out = {}
+        for hf_name, paths in self._special(layer).items():
+            if len(paths) == 2:
+                out[hf_name] = np.concatenate([flat[paths[0]].T, flat[paths[1]].T])
+            else:
+                out[hf_name] = flat[paths[0]].T[:, None, :]
+        return out
+
+
 class GemmaPolicy(HFCheckpointPolicy):
     """Gemma (v1): llama graph with (1+weight) RMSNorm, sqrt(hidden) embed
     normalizer (rounded through the compute dtype, as HF does), tanh-gelu
@@ -1455,6 +1579,8 @@ _POLICIES = {
     "OlmoeForCausalLM": OlmoePolicy,
     "lfm2_moe": Lfm2MoePolicy,
     "Lfm2MoeForCausalLM": Lfm2MoePolicy,
+    "granitemoehybrid": GraniteMoeHybridPolicy,
+    "GraniteMoeHybridForCausalLM": GraniteMoeHybridPolicy,
     "qwen2_moe": Qwen2MoePolicy,
     "qwen2moe": Qwen2MoePolicy,
     "Qwen2MoeForCausalLM": Qwen2MoePolicy,

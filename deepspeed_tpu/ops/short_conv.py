@@ -1,4 +1,6 @@
-"""Gated short convolution (LFM2's ``conv`` operator between its projections).
+"""Short depthwise causal convolutions: LFM2's gated ``conv`` operator
+between its projections, and (at the end of the file) the ungated, biased,
+SiLU form Mamba-2 runs before its scan.
 
 ``in_proj`` gives three ``C``-wide parts a token, ``B``, ``C`` and ``u``
 (split in that order); the operator is
@@ -253,3 +255,201 @@ def short_conv(bcx, w, *, use_kernel: bool, interpret: bool = False):
 
 registry.register("short_conv", "pallas", True,
                   "gated depthwise causal convolution, forward and backward")
+
+
+# ---- the ungated form (Mamba-2's convolution before its scan) ----
+#
+#     y = silu(conv_L(x) + bias)          x [batch, seq, C], any C
+#
+# The same row blocks and halo views; kernels with names of their own
+# (``causal_conv_fwd``, ``causal_conv_bwd``): they move 2 and 3 values a
+# channel and token where the gated ones move 4 and 7, and a trace reader
+# counts bytes by the name. The bias travels as row ``L`` of the taps' tile,
+# its gradient as row ``L`` of the taps' gradient. The backward recomputes
+# the pre-activation of its own rows and of the HALO rows after them (the
+# filter reversed reaches ``L - 1`` rows ahead) and saves nothing.
+
+
+def causal_conv_reference(x, w, bias):
+    """``x`` ``[batch, seq, C]``, ``w`` ``[L, C]``, ``bias`` ``[C]`` ->
+    ``silu(conv(x) + bias)`` in ``x.dtype``; float32 inside, as the kernel."""
+    taps, seq = w.shape[0], x.shape[1]
+    v = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    c = bias.astype(jnp.float32) + sum(w[j].astype(jnp.float32) * v[:, j:j + seq]
+                                       for j in range(taps))
+    return (c * jax.nn.sigmoid(c)).astype(x.dtype)
+
+
+def _pre_activation(x, before, w_ref, sl, taps):
+    """``bias + sum_j w[j] * x[t - (L - 1) + j]`` of a block, and the shifted
+    views it summed (what the taps' gradient multiplies)."""
+    views = [_shifted(x, before, taps - 1 - j) for j in range(taps)]
+    return (w_ref[taps:taps + 1, sl]
+            + sum(w_ref[j:j + 1, sl] * views[j] for j in range(taps))), views
+
+
+def _conv_fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, taps, cols):
+    first = pl.program_id(1) == 0
+    for c0 in range(0, o_ref.shape[2], cols):
+        sl = slice(c0, c0 + cols)
+        before = jnp.where(first, 0.0, prev_ref[0, :, sl].astype(jnp.float32))
+        c, _ = _pre_activation(x_ref[0, :, sl].astype(jnp.float32), before, w_ref,
+                               sl, taps)
+        o_ref[0, :, sl] = (c * jax.nn.sigmoid(c)).astype(o_ref.dtype)
+
+
+def _silu_grad(c):
+    s = jax.nn.sigmoid(c)
+    return s * (1.0 + c * (1.0 - s))
+
+
+def _conv_bwd_kernel(x_ref, prev_ref, next_ref, dy_ref, dy_next_ref, w_ref,
+                     dx_ref, dw_ref, *, taps, cols):
+    rows = dy_ref.shape[1]
+    first = pl.program_id(1) == 0
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    tap_row = jax.lax.broadcasted_iota(jnp.int32, (TAP_ROWS, cols), 0)
+    for c0 in range(0, dy_ref.shape[2], cols):
+        sl = slice(c0, c0 + cols)
+        x = x_ref[0, :, sl].astype(jnp.float32)
+        before = jnp.where(first, 0.0, prev_ref[0, :, sl].astype(jnp.float32))
+        c, views = _pre_activation(x, before, w_ref, sl, taps)
+        after, _ = _pre_activation(next_ref[0, :, sl].astype(jnp.float32),
+                                   x[rows - HALO:], w_ref, sl, taps)
+        dc = dy_ref[0, :, sl].astype(jnp.float32) * _silu_grad(c)
+        ndc = jnp.where(last, 0.0, dy_next_ref[0, :, sl].astype(jnp.float32)
+                        * _silu_grad(after))
+        dx = sum(w_ref[j:j + 1, sl] * _ahead(dc, ndc, taps - 1 - j)
+                 for j in range(taps))
+        dw = jnp.where(tap_row == taps, jnp.sum(dc, axis=0, keepdims=True), 0.0)
+        for j in range(taps):
+            dw = jnp.where(tap_row == j,
+                           jnp.sum(dc * views[j], axis=0, keepdims=True), dw)
+        dx_ref[0, :, sl] = dx.astype(dx_ref.dtype)
+        dw_ref[0, :, sl] = dw
+
+
+def _conv_blocking(seq: int, width: int):
+    """(rows a block, padded sequence, channels a pass): ``_blocking`` with a
+    pass that divides any width of whole lane tiles."""
+    rows, padded, _ = _blocking(seq, width)
+    cols = next((c for c in range(BLOCK_COLS, 0, -128) if width % c == 0), width)
+    return rows, padded, cols
+
+
+def _conv_check(x, w, bias):
+    if x.ndim != 3 or x.shape[-1] != w.shape[1] or bias.shape != w.shape[1:]:
+        raise ValueError(f"causal_conv: x {x.shape} is not [batch, seq, C] for "
+                         f"taps {w.shape} and bias {bias.shape}")
+    if not 1 <= w.shape[0] < TAP_ROWS:
+        raise ValueError(f"causal_conv: {w.shape[0]} taps; the kernel takes 1 "
+                         f"to {TAP_ROWS - 1} and a bias")
+
+
+def _taps_and_bias(w, bias):
+    return _pad_taps(jnp.concatenate([w.astype(jnp.float32),
+                                      bias.astype(jnp.float32)[None]]))
+
+
+def _conv_fwd_call(x, w, bias, interpret):
+    _conv_check(x, w, bias)
+    batch, seq, width = x.shape
+    taps = w.shape[0]
+    rows, padded, cols = _conv_blocking(seq, width)
+    xp = jnp.pad(x, ((0, 0), (0, padded - seq), (0, 0)))
+    per = rows // HALO
+    out = pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, taps=taps, cols=cols),
+        grid=(batch, padded // rows),
+        in_specs=[
+            pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, HALO, width),
+                         lambda b, i: (b, jnp.maximum(i * per - 1, 0), 0)),
+            pl.BlockSpec((TAP_ROWS, width), lambda b, i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((batch, padded, width), x.dtype),
+        compiler_params=_compiler_params(rows, width, 2),
+        interpret=interpret,
+        name="causal_conv_fwd",
+    )(xp, xp, _taps_and_bias(w, bias))
+    return out[:, :seq]
+
+
+def _conv_bwd_call(x, w, bias, dy, interpret):
+    batch, seq, width = x.shape
+    taps = w.shape[0]
+    rows, padded, cols = _conv_blocking(seq, width)
+    pad = ((0, 0), (0, padded - seq), (0, 0))
+    xp, g = jnp.pad(x, pad), jnp.pad(dy.astype(x.dtype), pad)
+    per, blocks = rows // HALO, padded // rows
+    tiles = padded // HALO
+
+    def ahead(b, i):
+        return b, jnp.minimum((i + 1) * per, tiles - 1), 0
+
+    block = pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0))
+    dx, dw = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, taps=taps, cols=cols),
+        grid=(batch, blocks),
+        in_specs=[
+            block,
+            pl.BlockSpec((1, HALO, width),
+                         lambda b, i: (b, jnp.maximum(i * per - 1, 0), 0)),
+            pl.BlockSpec((1, HALO, width), ahead),
+            block,
+            pl.BlockSpec((1, HALO, width), ahead),
+            pl.BlockSpec((TAP_ROWS, width), lambda b, i: (0, 0)),
+        ],
+        out_specs=[
+            block,
+            pl.BlockSpec((1, TAP_ROWS, width),
+                         lambda b, i: (b * blocks + i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, padded, width), x.dtype),
+            jax.ShapeDtypeStruct((batch * blocks, TAP_ROWS, width), jnp.float32),
+        ],
+        compiler_params=_compiler_params(rows, width, 3),
+        interpret=interpret,
+        name="causal_conv_bwd",
+    )(xp, xp, xp, g, g, _taps_and_bias(w, bias))
+    dw = jnp.sum(dw, axis=0)
+    return dx[:, :seq], dw[:taps].astype(w.dtype), dw[taps].astype(bias.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, ))
+def _causal_conv_kernel(x, w, bias, interpret):
+    return _conv_fwd_call(x, w, bias, interpret)
+
+
+def _causal_conv_vjp_fwd(x, w, bias, interpret):
+    return _conv_fwd_call(x, w, bias, interpret), (x, w, bias)
+
+
+def _causal_conv_vjp_bwd(interpret, res, dy):
+    return _conv_bwd_call(*res, dy, interpret)
+
+
+_causal_conv_kernel.defvjp(_causal_conv_vjp_fwd, _causal_conv_vjp_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", ))
+def _causal_conv_jit(x, w, bias, interpret):
+    # a name-stack frame of its own, as ``_short_conv_jit``
+    return _causal_conv_kernel(x, w, bias, interpret)
+
+
+def causal_conv(x, w, bias, *, use_kernel: bool, interpret: bool = False):
+    """``silu(conv_L(x) + bias)``: ``x`` ``[batch, seq, C]``, depthwise causal
+    taps ``w`` ``[L, C]`` (``w[j]`` multiplies ``x[t - (L - 1) + j]``, zeros
+    left of the sequence), ``bias`` ``[C]``. ``use_kernel`` as for
+    :func:`short_conv`."""
+    if use_kernel or interpret:
+        return _causal_conv_jit(x, w, bias, interpret)
+    _conv_check(x, w, bias)
+    return causal_conv_reference(x, w, bias)
+
+
+registry.register("causal_conv", "pallas", True,
+                  "depthwise causal convolution with bias and SiLU, forward and backward")

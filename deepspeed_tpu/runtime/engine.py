@@ -80,8 +80,11 @@ def _as_apply_fns(model):
             # width, a leading axis under a layer scan) come back summed
             # over blocks, with the aux term and, from blocks that hold a
             # share of the experts, ``rows_held`` and ``share_fallback``
+            # "ssm_stats" is the same contract for a state-space mixer:
+            # ``state_absmax`` (the largest |S| a layer's scan held) comes
+            # back as the largest over the layers, ``dt_mean`` as their mean
             out, mods = model.apply({"params": params}, *args, **kwargs,
-                                    mutable=["aux_loss", "moe_stats"])
+                                    mutable=["aux_loss", "moe_stats", "ssm_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -100,6 +103,12 @@ def _as_apply_fns(model):
                 stats[name] = stats[name] + total if name in stats else total
             if stats and aux:
                 stats["aux_loss"] = aux_total.astype(jnp.float32)
+            ssm = jax.tree_util.tree_flatten_with_path(mods.get("ssm_stats", {}))[0]
+            for name, reduce in (("state_absmax", jnp.max), ("dt_mean", jnp.mean)):
+                sown = [leaf.reshape(-1) for path, leaf in ssm
+                        if path[-1].key == name]
+                if sown:
+                    stats["ssm_" + name] = reduce(jnp.concatenate(sown))
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -1623,11 +1632,25 @@ class DeepSpeedTpuEngine:
             return
         from ..observability import get_registry
         fetched, self._moe_pending = host_fetch(self._moe_pending), []
+        reg = get_registry()
+        if "ssm_state_absmax" in fetched[0]:
+            reg.gauge(
+                "ds_ssm_state_absmax",
+                "Largest |S| the state-space scans held (the chunks' states "
+                "where the kernels run), over the layers and the steps of "
+                "the last publish"
+            ).set(float(max(np.max(s["ssm_state_absmax"]) for s in fetched)))
+            reg.gauge(
+                "ds_ssm_dt_mean",
+                "Mean step size dt = softplus(dt + dt_bias) of the "
+                "state-space layers, over the steps of the last publish"
+            ).set(float(np.mean([np.mean(s["ssm_dt_mean"]) for s in fetched])))
+        if "expert_counts" not in fetched[0]:
+            return
         # [E] a step, [K, E] a K-step dispatch
         counts = sum(np.asarray(s["expert_counts"], np.int64)
                      .reshape(-1, s["expert_counts"].shape[-1]).sum(axis=0)
                      for s in fetched)
-        reg = get_registry()
         reg.counter(
             "ds_moe_tokens_routed_total",
             "(token, expert) assignments the router made, summed over MoE "
@@ -1996,9 +2019,22 @@ class DeepSpeedTpuEngine:
         ``rows_held`` and ``share_fallback`` (summed over the layers).
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
+        return self._newest_stats(lambda name: not name.startswith("ssm_"))
+
+    def ssm_stats(self):
+        """What the state-space layers sowed in the newest fused step not
+        yet published, as host scalars: ``state_absmax`` (the largest |S|
+        over the layers) and ``dt_mean``. Waits for that step, as
+        :meth:`moe_stats`; ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("ssm_"))
+        return stats and {name[len("ssm_"):]: v for name, v in stats.items()}
+
+    def _newest_stats(self, wanted):
         if not self._moe_pending:
             return None
-        return host_fetch(self._moe_pending[-1])
+        newest = {name: v for name, v in self._moe_pending[-1].items()
+                  if wanted(name)}
+        return host_fetch(newest) if newest else None
 
     def eval_batch(self, *args, **kwargs):
         """Forward-only compiled path for evaluation.

@@ -495,12 +495,13 @@ def test_the_engine_publishes_the_rows_held_and_the_layers_by_kind():
     cfg, model, params, ids = small(seed=7)
     reset_mesh_context()
     set_mesh_context(MeshContext.create(devices=jax.devices()[:1]))
+    reg = get_registry()
+    reg.reset()     # the registry is the process's: zero what other engines set
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=params,
         config={"train_batch_size": 2, "steps_per_print": 0,
                 "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
-    reg = get_registry()
-    kinds = {m.labels["kind"]: m.value for m in reg.series("ds_model_layers")}
+    kinds = {m.labels["kind"]: m.value for m in reg.series("ds_model_layers") if m.value}
     assert kinds == {"conv+dense": 1.0, "attention+moe": 1.0, "conv+moe": 3.0}
     held, routed, fallback = (reg.counter(n) for n in (
         "ds_moe_rows_held_total", "ds_moe_tokens_routed_total",

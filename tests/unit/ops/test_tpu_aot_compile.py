@@ -208,6 +208,41 @@ def test_short_conv_kernels_compile_and_keep_their_names(one_chip, no_compile_ca
     assert [n.split(".")[0] for n in names] == ["short_conv_fwd"], names
 
 
+def test_state_space_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):
+    """Granite-4.0-H-Micro's Mamba-2 mixer, the cell's sequence: the scan at
+    ``[1, 16384, 64 heads x 64]`` with a state of 128 in chunks of 256, and
+    the convolution before it at ``[1, 16384, 4352]`` with four taps and a
+    bias, bf16, forward and backward, as Mosaic kernels named for what they
+    are (``benchmark/ssd_cost.py`` matches ``%ssd_chunk_fwd*``,
+    ``%ssd_chunk_bwd*``, ``%causal_conv_fwd*``, ``%causal_conv_bwd*``)."""
+    from deepspeed_tpu.ops.short_conv import causal_conv
+    from deepspeed_tpu.ops.ssd import ssd_scan
+    scan = [_sds((1, 16384, 64, 64), jnp.bfloat16, one_chip),
+            _sds((1, 16384, 64), jnp.float32, one_chip), _sds((64, ), jnp.float32, one_chip),
+            _sds((1, 16384, 128), jnp.bfloat16, one_chip),
+            _sds((1, 16384, 128), jnp.bfloat16, one_chip), _sds((64, ), jnp.float32, one_chip)]
+
+    def scan_loss(*a):
+        return jnp.sum(ssd_scan(*a, 256, use_kernel=True).astype(jnp.float32))
+
+    names = _custom_call_names(_compile(jax.grad(scan_loss, argnums=tuple(range(6))), *scan))
+    assert sorted(n.split(".")[0] for n in names) == ["ssd_chunk_bwd", "ssd_chunk_fwd"], names
+    names = _custom_call_names(_compile(
+        lambda *a: ssd_scan(*a, 256, use_kernel=True, with_state_absmax=True), *scan))
+    assert [n.split(".")[0] for n in names] == ["ssd_chunk_fwd"], names
+    conv = [_sds((1, 16384, 4352), jnp.bfloat16, one_chip),
+            _sds((4, 4352), jnp.float32, one_chip), _sds((4352, ), jnp.float32, one_chip)]
+
+    def conv_loss(*a):
+        return jnp.sum(causal_conv(*a, use_kernel=True).astype(jnp.float32))
+
+    names = _custom_call_names(_compile(jax.grad(conv_loss, argnums=(0, 1, 2)), *conv))
+    assert [n.split(".")[0] for n in sorted(names)] == ["causal_conv_bwd"], names
+    names = _custom_call_names(_compile(
+        lambda *a: causal_conv(*a, use_kernel=True), *conv))
+    assert [n.split(".")[0] for n in names] == ["causal_conv_fwd"], names
+
+
 def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
         one_chip, no_compile_cache):
     """The LFM2 cell's expert layer a step: 32,768 tokens x top-4 over a
