@@ -15,14 +15,17 @@ heads (G× HBM saving), and the folded G dimension *fattens* the MXU matmul.
 
 Forward (grid ``(B*KV, q_blocks, kv_blocks)``, kv innermost): accumulators
 (o, m, l) persist in VMEM scratch across the kv sweep, m and l replicated
-over the lanes; the log-sum-exp is written out as a residual. Backward is
-the standard two-pass recompute: a dq kernel sweeps kv blocks per q block, a
-dk/dv kernel sweeps q blocks per kv block on the TRANSPOSED score tile
-(``k . q^T``, so dv and dk contract the minor dimension); both rebuild p
-from the saved LSE (no second online softmax). A block wholly above the
-causal diagonal or outside the window is skipped and fetches nothing: the
-swept operand's index map is clamped to the live range, so a dead step
-names the block already resident. Blocks come from the shape
+over the lanes; the log-sum-exp is written out as a residual. Backward: one
+kernel (``flash_dkdv_dq``) sweeps q blocks per kv block on the TRANSPOSED
+score tile (``k . q^T``, so dv and dk contract the minor dimension) and
+takes dq from the same tile, accumulating the dQ of a KV head's whole
+sequence in float32 in VMEM; where that does not fit
+(``kernel_dispatch`` decides from the shape) the two-pass pair runs, that
+sweep for dk/dv alone and a dq kernel that sweeps kv blocks per q block.
+All rebuild p from the saved LSE (no second online softmax). A block wholly
+above the causal diagonal or outside the window is skipped and fetches
+nothing: the swept operand's index map is clamped to the live range, so a
+dead step names the block already resident. Blocks come from the shape
 (``kernel_dispatch.choose_blocks``: 1024 folded query rows a step at any
 group, and 512 keys or as many as the queries, where the sequences allow).
 """
@@ -379,10 +382,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         dq_ref[0] = dq_acc[:].reshape(g, bq, -1).astype(dq_ref.dtype)
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_acc, dv_acc,
-                 *, scale, causal, block_q, block_k, num_q, window=None,
-                 softcap=None):
+def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                 scale, causal, block_q, block_k, num_q, num_kv, fused,
+                 window=None, softcap=None):
+    """dK and dV of one kv block over a sweep of the q blocks (innermost)
+    and, ``fused``, dQ from the same score tiles: the dQ of this KV head's
+    whole sequence accumulates in float32 in ``dq_acc`` [q blocks, G*BQ, D]
+    over the kv sweep, ascending as the dq kernel sums it, and the last kv
+    block's sweep writes it out, one q block a step. ``refs``: the results
+    dk, dv (, dq), then their float32 accumulators."""
+    if fused:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -390,6 +402,11 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if fused:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
     def _compute(masked):
         g, bq, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
@@ -429,9 +446,15 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta) * scale
         if softcap is not None:
             ds = ds * (1.0 - t * t)
-        dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
-                                         (((1, ), (0, )), ((), ())),
+        ds = ds.astype(q.dtype)
+        dk_acc[:] += jax.lax.dot_general(ds, q, (((1, ), (0, )), ((), ())),
                                          preferred_element_type=jnp.float32)
+        if fused:
+            # dq += ds @ k from the same tile: ds^T contracted over its
+            # first dimension, so Mosaic transposes this one tile a step
+            dq_acc[qi] += jax.lax.dot_general(
+                ds, k, (((0, ), (0, )), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _when_live(qi, ki, block_q, block_k, causal, window, _compute)
 
@@ -440,11 +463,19 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if fused:
+        @pl.when(ki == num_kv - 1)
+        def _finalize_dq():
+            g, bq = dq_ref.shape[1], dq_ref.shape[2]
+            dq_ref[0] = dq_acc[qi].reshape(g, bq, -1).astype(dq_ref.dtype)
+
 
 def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=None,
-               softcap=None):
+               softcap=None, fused=False):
     """Per-head Pallas backward; ``res`` carries lse in the per-head
-    [B*KV, G, Sq, 1] layout."""
+    [B*KV, G, Sq, 1] layout. ``fused``: one ``flash_dkdv_dq`` call in place
+    of ``flash_dq`` and ``flash_dkdv`` (``kernel_dispatch`` decides: the
+    float32 dQ of a KV head's whole sequence has to fit in VMEM)."""
     from .kernel_dispatch import flash_vmem_bytes
     q, k, v, o, lse = res
     B, Sq, H, D = q.shape
@@ -452,7 +483,8 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     G = H // KV
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
     params = _compiler_params(flash_vmem_bytes(
-        "bwd", G, D, q.dtype.itemsize, block_q, block_k))
+        "fused" if fused else "bwd", G, D, q.dtype.itemsize, block_q, block_k,
+        seq_q=Sq))
     static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                   window=window, softcap=softcap)
 
@@ -462,25 +494,25 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [B*KV, G, Sq, 1] — unit lane dim, see lse
 
-    def kv_map(b, i, j):
-        return (b, _live_kv_block(i, j, block_q, block_k, num_kv, causal,
-                                  window), 0)
+    if not fused:
+        def kv_map(b, i, j):
+            return (b, _live_kv_block(i, j, block_q, block_k, num_kv, causal,
+                                      window), 0)
 
-    q_spec = pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), kv_map)
-    r_spec = pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, num_kv=num_kv, **static),
-        grid=(B * KV, num_q, num_kv),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
-        out_specs=pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((G * block_q, D), jnp.float32)],
-        compiler_params=params,
-        interpret=interpret,
-        name="flash_dq",
-    )(qg, kt, vt, dog, lse, delta)
+        q_spec = pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0))
+        k_spec = pl.BlockSpec((1, block_k, D), kv_map)
+        r_spec = pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0))
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, num_kv=num_kv, **static),
+            grid=(B * KV, num_q, num_kv),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((G * block_q, D), jnp.float32)],
+            compiler_params=params,
+            interpret=interpret,
+            name="flash_dq",
+        )(qg, kt, vt, dog, lse, delta)
 
     # kv-major grid for dk/dv: q sweep innermost. lse and delta enter as
     # rows of the transposed score tile: [B*KV, q blocks, 1, G*BQ], g-major
@@ -497,26 +529,36 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
     r_spec2 = pl.BlockSpec((1, 1, 1, G * block_q),
                            lambda b, j, i: (b, q_blk(j, i), 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, num_q=num_q, **static),
+    out_specs = [k_spec2, k_spec2]
+    out_shape = [jax.ShapeDtypeStruct((B * KV, Sk, D), k.dtype),
+                 jax.ShapeDtypeStruct((B * KV, Sk, D), v.dtype)]
+    scratch_shapes = [pltpu.VMEM((block_k, D), jnp.float32),
+                      pltpu.VMEM((block_k, D), jnp.float32)]
+    if fused:
+        # a q block of dQ is complete, and written, in the last kv block's
+        # sweep; until then the map names block 0, which that sweep writes
+        # first, so nothing leaves VMEM before it holds a result
+        out_specs.append(pl.BlockSpec(
+            (1, G, block_q, D),
+            lambda b, j, i: (b, 0, jnp.where(j == num_kv - 1, i, 0), 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((num_q, G * block_q, D), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_dkdv_kernel, num_q=num_q, num_kv=num_kv,
+                          fused=fused, **static),
         grid=(B * KV, num_kv, num_q),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KV, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * KV, Sk, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
         compiler_params=params,
         interpret=interpret,
-        name="flash_dkdv",
+        name="flash_dkdv_dq" if fused else "flash_dkdv",
     )(qg, kt, vt, dog, rows(lse), rows(delta))
+    if fused:
+        dk, dv, dq = outs
+    else:
+        dk, dv = outs
 
     dq = (dq.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
           .reshape(B, Sq, H, D))
@@ -634,7 +676,8 @@ def _run_fwd(q, k, v, scale, causal, window, softcap, interpret, dec,
 
 def _bwd_lse_layout(bwd_dec):
     """Which lse layout the bwd leg consumes (None: no residual needed)."""
-    return {"xla": None, "folded": "natural", "pallas": "perhead"}[bwd_dec.impl]
+    return {"xla": None, "folded": "natural", "pallas": "perhead",
+            "fused": "perhead"}[bwd_dec.impl]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -672,7 +715,8 @@ def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
                                 bwd_dec.block_q, bwd_dec.block_k, interpret,
                                 window, softcap)
     return _flash_bwd((q, k, v, o, lse), g, scale, causal, bwd_dec.block_q,
-                      bwd_dec.block_k, interpret, window, softcap)
+                      bwd_dec.block_k, interpret, window, softcap,
+                      fused=bwd_dec.impl == "fused")
 
 
 _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -681,7 +725,8 @@ _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
 # frame that holds it, and a transformation wraps the scopes of its own
 # frame (``jvp(flash_fwd)`` reads ``%jvp_flash_fwd_``). A jit boundary
 # outside the custom_vjp starts a new frame, so the kernels read
-# ``%flash_fwd.N`` / ``%flash_dq.N`` / ``%flash_dkdv.N`` under ``jax.grad``
+# ``%flash_fwd.N`` / ``%flash_dkdv_dq.N`` (or ``%flash_dq.N`` +
+# ``%flash_dkdv.N`` where the backward is the pair) under ``jax.grad``
 # on one device as they do inside a ``shard_map``. XLA inlines the call.
 _flash_attention_call = jax.jit(_dispatched_attention,
                                 static_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -707,8 +752,9 @@ def flash_attention(q,
     ``ops/kernel_dispatch.py``: measured autotune-cache entries win, then
     the built-in heuristic table (XLA fused fwd + Pallas flash bwd at
     hd64/seq>=1024 — the round-5 chip measurement). ``impl_fwd``/
-    ``impl_bwd`` ("xla" | "pallas" | "folded") pin a leg explicitly (tests,
-    the sweep tool); ``block_q``/``block_k`` pin the Pallas tile sizes,
+    ``impl_bwd`` ("xla" | "pallas" | "folded", and for the backward "fused":
+    one kernel where "pallas" is the dq + dk/dv pair) pin a leg explicitly
+    (tests, the sweep tool); ``block_q``/``block_k`` pin the Pallas tile sizes,
     which otherwise follow from the shape (``kernel_dispatch.choose_blocks``).
     Off-TPU without interpret, the pure-XLA fused path runs both legs.
     """

@@ -14,7 +14,8 @@ per leg, strongest first:
 
 1. explicit ``impl_fwd``/``impl_bwd`` kwargs on ``flash_attention`` (tests,
    the sweep tool);
-2. ``DS_TPU_ATTN_FWD`` / ``DS_TPU_ATTN_BWD`` env (``xla|pallas|folded``);
+2. ``DS_TPU_ATTN_FWD`` / ``DS_TPU_ATTN_BWD`` env (``xla|pallas|folded``, and
+   ``fused`` for the backward);
 3. legacy ``DS_TPU_FLASH_FOLDED``: nonzero forces the folded Pallas pair on
    BOTH legs (existing A/B scripts and tests depend on that); ``0`` pins
    the per-head variant for any leg that resolves to Pallas;
@@ -22,7 +23,9 @@ per leg, strongest first:
    (``autotune_cache.py``, written by ``bin/ds_kernel_tune``);
 5. the built-in heuristic table below (which encodes the measured
    42.7 < 62.9 ms fwd result: XLA fused forward at hd64 / seq >= 1024
-   while its float32 scores stay under 2 GiB, Pallas backward always).
+   while its float32 scores stay under 2 GiB; Pallas backward always, the
+   fused kernel wherever its whole-sequence dQ accumulator fits in VMEM,
+   else the dq + dk/dv pair).
 
 Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
 measured cache blocks > ``choose_blocks``, a pure function of the shape
@@ -39,7 +42,12 @@ from ..utils.logging import logger
 IMPL_XLA = "xla"
 IMPL_PALLAS = "pallas"  # per-head kernels (ops/attention.py)
 IMPL_FOLDED = "folded"  # head-folded kernels (ops/attention_folded.py)
-_IMPLS = (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED)
+# backward only: the per-head kernels' one-pass backward (dQ, dK and dV from
+# one walk over the score tiles, ``flash_dkdv_dq``); "pallas" there is the
+# pair ``flash_dq`` + ``flash_dkdv``
+IMPL_FUSED = "fused"
+_IMPLS = {"fwd": (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED),
+          "bwd": (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED, IMPL_FUSED)}
 
 # candidate (block_q, block_k) grid the offline sweep times, beyond the
 # defaults — the round-5 sweep died at the window edge before reaching them
@@ -99,12 +107,12 @@ def device_kind() -> str:
     return getattr(d, "device_kind", None) or d.platform
 
 
-def _env_impl(name: str) -> Optional[str]:
+def _env_impl(name: str, leg: str) -> Optional[str]:
     val = os.environ.get(name, "").strip().lower()
     if not val:
         return None
-    if val not in _IMPLS:
-        logger.warning(f"{name}={val!r} ignored (want one of {_IMPLS})")
+    if val not in _IMPLS[leg]:
+        logger.warning(f"{name}={val!r} ignored (want one of {_IMPLS[leg]})")
         return None
     return val
 
@@ -140,10 +148,25 @@ def _env_blocks() -> Optional[tuple]:
 # more waste and half the steps.
 KEY_BLOCK = 512
 MAX_ROWS = 1024
+# The fused backward walks each score tile once for all three gradients and
+# carries its own VMEM limit, so its step is not held to the compiler's
+# default: 2,048 folded rows, of at most 512 queries, by 512 keys was the
+# fastest or within 2.5% of it at groups 1, 2, 4 and 8 and head sizes 64,
+# 128 and 256 (v5e sweep, PR 34, docs/kernel_dispatch.md).
+FUSED_MAX_ROWS = 2048
 # Mosaic's default scoped-VMEM limit on a v5e (the core has 128 MiB behind
 # it). The blocks chosen here stay under it by the estimate below; explicit
 # or measured blocks past it get their own limit on the call.
 VMEM_SCOPED_DEFAULT_BYTES = 16 * 2**20
+# The most the fused backward's estimate may be for the heuristic to choose
+# it: half the core's 128 MiB. The call asks for its estimate and a quarter
+# more (``vmem_limit_bytes``), 80 MiB at the cap, which leaves 48 MiB for
+# what Mosaic keeps beyond the estimate (its own stack: the state-space
+# scan's refusal at 1.25x, docs/kernel_dispatch.md) and for what XLA holds
+# in VMEM around the call. The estimate grows with ``group * seq_q`` (the
+# float32 dQ of one KV head's whole sequence): 16,384 tokens at group 4 are
+# 55 MiB, 24,576 are 71 MiB and take the dq + dk/dv pair.
+FUSED_VMEM_CAP_BYTES = 64 * 2**20
 # The most the XLA forward's materialised float32 scores may take for the
 # heuristic to choose it: four times the one shape the rule was measured at,
 # a bound and not a crossover. A forward-only sweep at head 64, group 4 (v5e,
@@ -154,17 +177,20 @@ XLA_FWD_SCORE_BYTES = 2 * 2**30
 
 
 def flash_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
-                     block_q: int, block_k: int) -> int:
+                     block_q: int, block_k: int, seq_q: int = 0) -> int:
     """Upper estimate of the VMEM one grid step of the per-head flash
-    kernels holds (``ops/attention.py``), for ``leg`` "fwd" or "bwd" (the
-    larger of the dq and dk/dv kernels). Counted: every pipelined operand
-    and result block twice (double buffering), the scratch accumulators and
-    the forward's lane-replicated statistics, and the score-tile
-    temporaries: two fp32 tiles and p's cast in the forward, three and both
-    casts in the backward. A ``[rows, 1]`` block occupies a full 128-lane
-    row. Checked against the v5e compiler (described-chip compiles, PR 25):
-    every tile set it put under the default compiled, the first refusals
-    stand at 22 MiB (forward) and 25 MiB (backward) of this estimate."""
+    kernels holds (``ops/attention.py``), for ``leg`` "fwd", "bwd" (the
+    larger of the dq and dk/dv kernels) or "fused" (the one-pass backward,
+    which also holds the float32 dQ of a KV head's ``seq_q`` queries).
+    Counted: every pipelined operand and result block twice (double
+    buffering), the scratch accumulators and the forward's lane-replicated
+    statistics, and the score-tile temporaries: two fp32 tiles and p's cast
+    in the forward, three and both casts in the backward, and ds transposed
+    in the fused one. A ``[rows, 1]`` block occupies a full 128-lane row,
+    and a head of 64 a 128-lane row of the accumulators. Checked against
+    the v5e compiler (described-chip compiles, PR 25): every tile set it
+    put under the default compiled, the first refusals stand at 22 MiB
+    (forward) and 25 MiB (backward) of this estimate."""
     rows = group * block_q
     lanes = max(head_dim, 128)
     q_blk = rows * lanes * itemsize
@@ -175,6 +201,11 @@ def flash_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
         blocks = 2 * q_blk + 2 * kv_blk + stat        # q, o; k, v; lse
         scratch = rows * lanes * 4 + 2 * stat         # acc; m, l
         temps = tile * (2 * 4 + itemsize)
+    elif leg == "fused":
+        # q, do, dq; k, v, dk, dv; lse and delta as rows
+        blocks = 3 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4
+        scratch = 2 * block_k * lanes * 4 + group * seq_q * lanes * 4
+        temps = tile * (3 * 4 + 3 * itemsize)
     else:
         # dq: q, do, dq; k, v; lse, delta | dk/dv: q, do; k, v, dk, dv; rows
         blocks = max(3 * q_blk + 2 * kv_blk + 2 * stat,
@@ -191,6 +222,15 @@ def vmem_limit_bytes(estimate: int) -> Optional[int]:
     if estimate <= VMEM_SCOPED_DEFAULT_BYTES:
         return None
     return estimate * 5 // 4
+
+
+def fused_vmem_bytes(sig: ShapeSig) -> int:
+    """``flash_vmem_bytes`` of the fused backward at ``sig``, with the blocks
+    the shape gives it: what ``_heuristic_impl`` holds against
+    FUSED_VMEM_CAP_BYTES."""
+    return flash_vmem_bytes("fused", max(1, sig.heads // sig.kv_heads),
+                            sig.head_dim, 4 if "32" in sig.dtype else 2,
+                            *choose_blocks(sig, "fused"), seq_q=sig.seq_q)
 
 
 def _largest_block(seq: int, cap: int) -> int:
@@ -216,8 +256,14 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
     allow; then the larger of rows and keys halved, the keys on a tie, while
     the leg's VMEM estimate is over the compiler's default (the backward,
     which holds more score tiles at once, reaches it at group 1 and with
-    fp32 operands)."""
+    fp32 operands). ``leg`` "fused" (the one-pass backward): FUSED_MAX_ROWS
+    rows a step, KEY_BLOCK queries at most, and KEY_BLOCK keys; whether its
+    estimate (``fused_vmem_bytes``) fits is ``_heuristic_impl``'s to ask."""
     group = max(1, sig.heads // sig.kv_heads)
+    if leg == "fused":
+        cap_q = min(KEY_BLOCK, max(128, FUSED_MAX_ROWS // group))
+        return (_largest_block(sig.seq_q, cap_q),
+                _largest_block(sig.seq_k, KEY_BLOCK))
     itemsize = 4 if "32" in sig.dtype else 2
     cap_q = max(128, MAX_ROWS // group)
     cap_k = max(KEY_BLOCK, cap_q)
@@ -244,9 +290,12 @@ def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
     window-limited.  Windowed shapes keep the Pallas forward: it skips
     out-of-window blocks entirely, XLA still materializes [S, S].
 
-    Backward: Pallas flash always — the two-pass recompute never
-    materializes scores, which is where the memory and time win lives
-    (the same breakdown measured the pallas pair ahead on fwd+bwd).
+    Backward: Pallas flash always — the recompute never materializes
+    scores, which is where the memory and time win lives (the same
+    breakdown measured the pallas pair ahead on fwd+bwd). One kernel for
+    dQ, dK and dV (``fused``: each score tile rebuilt once, not twice)
+    where its estimate, with the blocks the shape gives, fits
+    FUSED_VMEM_CAP_BYTES; else the dq + dk/dv pair.
     """
     if leg == "fwd":
         # "fit comfortably": the float32 scores of the whole call, which the
@@ -258,7 +307,8 @@ def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
                 and scores <= XLA_FWD_SCORE_BYTES):
             return IMPL_XLA
         return IMPL_PALLAS
-    return IMPL_PALLAS
+    return (IMPL_FUSED if fused_vmem_bytes(sig) <= FUSED_VMEM_CAP_BYTES
+            else IMPL_PALLAS)
 
 
 def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
@@ -274,10 +324,11 @@ def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
     impl = None
     source = None
     if explicit_impl is not None:
-        assert explicit_impl in _IMPLS, explicit_impl
+        assert explicit_impl in _IMPLS[leg], (leg, explicit_impl)
         impl, source = explicit_impl, "explicit"
     if impl is None:
-        env = _env_impl("DS_TPU_ATTN_FWD" if leg == "fwd" else "DS_TPU_ATTN_BWD")
+        env = _env_impl("DS_TPU_ATTN_FWD" if leg == "fwd" else "DS_TPU_ATTN_BWD",
+                        leg)
         if env is not None:
             impl, source = env, "env"
     if impl is None and os.environ.get("DS_TPU_FLASH_FOLDED") not in (None, "", "0"):
@@ -287,7 +338,7 @@ def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
     measured = None
     if impl is None:
         measured = get_cache().lookup(signature(leg, sig, kind))
-        if measured and measured.get("impl") in _IMPLS:
+        if measured and measured.get("impl") in _IMPLS[leg]:
             impl, source = measured["impl"], "measured"
         else:
             measured = None
@@ -308,7 +359,7 @@ def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
         except (KeyError, TypeError, ValueError):
             blocks = None
     if blocks is None:
-        blocks = choose_blocks(sig, leg)
+        blocks = choose_blocks(sig, "fused" if impl == IMPL_FUSED else leg)
     return Decision(impl=impl, block_q=int(blocks[0]), block_k=int(blocks[1]),
                     source=source)
 

@@ -18,7 +18,9 @@ reached, plus the current defaults.
 
 Per shape the tool times:
   fwd:  xla fused, pallas per-head x blocks, folded x blocks
-  bwd:  xla (vjp recompute), pallas per-head x blocks, folded x blocks;
+  bwd:  xla (vjp recompute), pallas per-head x blocks (the dq + dk/dv
+        pair), fused x blocks (the per-head one-pass backward, on the
+        per-head forward's residuals), folded x blocks;
         the pullback alone, on residuals an untimed forward left
 and writes one cache entry per (leg, shape signature, device kind).
 ``--impls`` and ``--blocks`` narrow the candidates (a block sweep of one
@@ -52,7 +54,7 @@ def _blocks_for(impl: str, sig, leg: str, quick: bool, grid=None):
     from deepspeed_tpu.ops import kernel_dispatch as kd
     if impl == kd.IMPL_XLA:
         return [None]
-    chosen = kd.choose_blocks(sig, leg)
+    chosen = kd.choose_blocks(sig, "fused" if impl == kd.IMPL_FUSED else leg)
     if quick:
         return [chosen]
     return list(dict.fromkeys((chosen, ) + tuple(grid or kd.SWEEP_BLOCKS)))
@@ -79,7 +81,8 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
 
     kind = "interpret" if interpret else kd.device_kind()
     sig = kd.make_sig(shp_q, kv_heads, seq, q.dtype, causal, None, None)
-    impls = impls or (kd.IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FOLDED)
+    impls = impls or (kd.IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FUSED,
+                      kd.IMPL_FOLDED)
 
     def fwd_fn(impl, blocks):
         bq, bk = blocks or (None, None)
@@ -95,7 +98,8 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
         # (an XLA forward's float32 scores are 4 GiB at 4 x 16 x 4096^2)
         bq, bk = blocks or (None, None)
         out, pull = jax.vjp(lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, interpret=interpret, impl_fwd=impl,
+            q, k, v, causal=causal, interpret=interpret,
+            impl_fwd=kd.IMPL_PALLAS if impl == kd.IMPL_FUSED else impl,
             impl_bwd=impl, block_q=bq, block_k=bk), q, k, v)
         g = jnp.ones_like(out)
         run = jax.jit(lambda pull, g: pull(g))
@@ -105,6 +109,8 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
     for leg, make in (("fwd", fwd_fn), ("bwd", bwd_fn)):
         rows = []
         for impl in impls:
+            if leg == "fwd" and impl == kd.IMPL_FUSED:
+                continue    # a backward: its forward is the per-head one
             seen = set()
             for blocks in _blocks_for(impl, sig, leg, quick, grid):
                 if blocks is not None:
@@ -161,7 +167,7 @@ def main(argv=None):
     ap.add_argument("--dry-run", action="store_true",
                     help="time everything, commit nothing")
     ap.add_argument("--impls", default=None,
-                    help="comma list of xla,pallas,folded (default: all)")
+                    help="comma list of xla,pallas,fused,folded (default: all)")
     ap.add_argument("--blocks", default=None,
                     help="Pallas block grid as 'bqxbk,bqxbk,...' in place "
                          "of kernel_dispatch.SWEEP_BLOCKS")

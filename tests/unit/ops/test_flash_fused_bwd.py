@@ -1,0 +1,118 @@
+"""The fused flash backward (``flash_dkdv_dq``: dQ, dK and dV from one walk
+over the score tiles) against the pair it replaces (``flash_dq`` +
+``flash_dkdv``) and against ``jax.vjp`` of the XLA reference, interpreted on
+the CPU. Both backwards run on the same per-head forward's residuals, so in
+float32 they agree to rounding: the fused kernel sums dQ over the kv blocks
+in the order the dq kernel does."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import kernel_dispatch as kd
+from deepspeed_tpu.ops.attention import _xla_attention, flash_attention
+
+
+def _case(s, h, kv, d, blocks, *, b=1, sk=None, causal=True, window=None,
+          softcap=None):
+    return dict(b=b, s=s, sk=sk or s, h=h, kv=kv, d=d, blocks=blocks,
+                causal=causal, window=window, softcap=softcap)
+
+
+CASES = {
+    # groups 1 / 4 / 8 and head sizes 64 / 128 / 256, several q blocks in
+    # the dQ accumulator, key blocks smaller and larger than the q block
+    "g1_d64": _case(512, 2, 2, 64, (128, 128), b=2),
+    "g4_d64": _case(512, 4, 1, 64, (128, 256)),
+    "g8_d128": _case(512, 8, 1, 128, (64, 256)),
+    "g2_d256_softcap": _case(512, 4, 2, 256, (128, 128), softcap=30.0),
+    "g1_d128_keys_past_queries": _case(1024, 1, 1, 128, (256, 512), softcap=50.0),
+    # a window: steps dead before it and after the diagonal, on both sides of
+    # a kv block's sweep; an edge inside a block
+    "g4_d128_window": _case(768, 4, 1, 128, (128, 128), window=200),
+    "g2_d64_window_in_block": _case(1024, 4, 2, 64, (128, 512), window=300),
+    "g1_d64_window_softcap": _case(512, 2, 2, 64, (128, 128), window=96,
+                                   softcap=20.0),
+    # a sequence 1,024 does not divide, with the blocks the dispatcher picks
+    # for the fused kernel: (512, 512) and (384, 384)
+    "g1_d64_seq1536": _case(1536, 1, 1, 64, None),
+    "g4_d64_seq384": _case(384, 4, 1, 64, None),
+    # no mask, more keys than queries, a window with no causal mask
+    "g2_d64_full_more_keys": _case(256, 4, 2, 64, (128, 512), sk=1024, causal=False),
+    "g2_d128_window_not_causal": _case(512, 2, 1, 128, (128, 128), causal=False,
+                                       window=64),
+    # one q block and one kv block: init, compute and write in one step
+    "g4_d64_one_step": _case(128, 4, 1, 64, (128, 128)),
+}
+
+
+def _grads(case, dtype, impl_bwd):
+    rng = np.random.default_rng(11)
+    shape_q = (case["b"], case["s"], case["h"], case["d"])
+    shape_kv = (case["b"], case["sk"], case["kv"], case["d"])
+    q, g = (jnp.asarray(rng.normal(size=shape_q), dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=shape_kv), dtype) for _ in range(2))
+    # no blocks given: those the dispatcher picks for the fused kernel, for
+    # the pair too (other blocks sum in another order)
+    bq, bk = case["blocks"] or kd.choose_blocks(kd.make_sig(
+        shape_q, case["kv"], case["sk"], "float32", case["causal"],
+        case["window"], case["softcap"]), "fused")
+    if impl_bwd == "reference":
+        scale = 1.0 / np.sqrt(case["d"])
+        _, pull = jax.vjp(lambda q, k, v: _xla_attention(
+            q, k, v, scale, case["causal"], case["window"], case["softcap"]),
+            q, k, v)
+    else:
+        _, pull = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=case["causal"], window=case["window"],
+            softcap=case["softcap"], interpret=True, impl_fwd="pallas",
+            impl_bwd=impl_bwd, block_q=bq, block_k=bk), q, k, v)
+    return [np.asarray(x, np.float32) for x in pull(g)]
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_fused_backward_equals_the_pair_in_float32(name):
+    fused = _grads(CASES[name], jnp.float32, "fused")
+    pair = _grads(CASES[name], jnp.float32, "pallas")
+    reference = _grads(CASES[name], jnp.float32, "reference")
+    for leaf, a, b, c in zip(("dq", "dk", "dv"), fused, pair, reference):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0, err_msg=leaf)
+        np.testing.assert_allclose(a, c, atol=5e-5, rtol=5e-4, err_msg=leaf)
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_fused_backward_in_bfloat16(name):
+    """bf16 operands, float32 accumulators and softmax, as on the training
+    path: the pair's results to a rounding of the outputs, the reference's
+    to bf16's."""
+    fused = _grads(CASES[name], jnp.bfloat16, "fused")
+    pair = _grads(CASES[name], jnp.bfloat16, "pallas")
+    reference = _grads(CASES[name], jnp.float32, "reference")
+    for leaf, a, b, c in zip(("dq", "dk", "dv"), fused, pair, reference):
+        np.testing.assert_allclose(a, b, atol=2e-2 * np.abs(b).max(), rtol=0,
+                                   err_msg=leaf)
+        assert np.abs(a - c).max() <= 4e-2 * np.abs(c).max(), leaf
+
+
+def test_the_unpinned_backward_is_the_fused_kernel():
+    """What ``flash_attention`` resolves to with nothing pinned is the fused
+    backward at a shape that fits, and its gradient is the pinned one's."""
+    case = CASES["g4_d64"]
+    sig = kd.make_sig((1, 512, 4, 64), 1, 512, "float32", True, None, None)
+    assert kd.resolve_leg("bwd", sig, "interpret").impl == kd.IMPL_FUSED
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(1, 512, 4, 64)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, 512, 1, 64)), jnp.float32)
+            for _ in range(2))
+
+    def loss(impl_bwd):
+        return lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True, force_pallas=True,
+            impl_bwd=impl_bwd, block_q=case["blocks"][0],
+            block_k=case["blocks"][1]) ** 2)
+
+    auto = jax.grad(loss(None), argnums=(0, 1, 2))(q, k, v)
+    pinned = jax.grad(loss("fused"), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(auto, pinned):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

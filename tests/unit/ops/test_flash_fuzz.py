@@ -61,13 +61,21 @@ CASES += [
     _case(512, 2, 2, 64, causal=False, window=64, blocks=(128, 128)),
 ]
 
+# the backward's implementation is drawn too: the dq + dk/dv pair or the
+# fused kernel, on the per-head forward's residuals (its own seed, so the
+# shapes above stay the ones they were)
+_bwd_rng = np.random.default_rng(34)
+for _c in CASES:
+    _c["bwd"] = str(_bwd_rng.choice(["pallas", "fused"]))
+
 
 @pytest.mark.parametrize("case", CASES, ids=lambda c: (
     f"b{c['b']}s{c['s']}h{c['h']}kv{c['kv']}d{c['d']}"
     f"w{c['window']}c{c['softcap']}"
     + (f"k{c['sk']}" if c.get("sk") else "")
     + ("" if c.get("causal", True) else "full")
-    + ("x".join(map(str, ("", ) + c["blocks"])) if c.get("blocks") else "")))
+    + ("x".join(map(str, ("", ) + c["blocks"])) if c.get("blocks") else "")
+    + c["bwd"]))
 def test_flash_matches_oracle(case):
     rng = np.random.default_rng(7)
     causal, sk = case.get("causal", True), case.get("sk") or case["s"]
@@ -83,7 +91,8 @@ def test_flash_matches_oracle(case):
     def loss_flash(q, k, v):
         out = flash_attention(q, k, v, causal=causal, window=case["window"],
                               softcap=case["softcap"], interpret=True,
-                              force_pallas=True, block_q=bq, block_k=bk)
+                              impl_fwd="pallas", impl_bwd=case["bwd"],
+                              block_q=bq, block_k=bk)
         return (out.astype(jnp.float32) ** 2).mean(), out
 
     def loss_ref(q, k, v):

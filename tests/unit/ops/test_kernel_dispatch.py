@@ -23,6 +23,7 @@ from deepspeed_tpu.ops.autotune_cache import (AutotuneCache, CACHE_VERSION,
                                               cache_path, get_cache)
 
 IMPLS = (kd.IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FOLDED)
+BWD_IMPLS = IMPLS + (kd.IMPL_FUSED, )   # the backward's one-pass kernel too
 
 
 def _qkv(b=2, s=128, h=4, kv=2, d=32, seed=7, dtype=jnp.float32):
@@ -68,9 +69,9 @@ def _route(q, k, v, fwd, bwd, causal=True, window=None, softcap=None):
 
 
 @pytest.mark.parametrize("fwd", IMPLS)
-@pytest.mark.parametrize("bwd", IMPLS)
+@pytest.mark.parametrize("bwd", BWD_IMPLS)
 def test_route_parity_causal(fwd, bwd):
-    """The custom_vjp must produce oracle values AND oracle grads for all 9
+    """The custom_vjp must produce oracle values AND oracle grads for all 12
     per-leg combinations — mixed routes cross LSE layouts (natural vs
     per-head) and residual provenance (XLA-computed lse consumed by a
     Pallas bwd), which is exactly where a wiring bug would hide."""
@@ -85,7 +86,8 @@ def test_route_parity_causal(fwd, bwd):
 
 
 @pytest.mark.parametrize("fwd,bwd", [("xla", "pallas"), ("pallas", "xla"),
-                                     ("folded", "pallas")])
+                                     ("folded", "pallas"), ("xla", "fused"),
+                                     ("folded", "fused")])
 @pytest.mark.parametrize("window,softcap", [(64, None), (None, 20.0),
                                             (64, 20.0)])
 def test_route_parity_window_softcap(fwd, bwd, window, softcap):
@@ -127,12 +129,14 @@ def test_bfloat16_route_parity():
                                    atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("impl_bwd", ["pallas", "fused"])
 @pytest.mark.parametrize("s,h,kv,fwd,bwd", [
     (2048, 2, 2, (1024, 1024), (1024, 512)),   # MHA, as the OLMoE cell
     (1024, 4, 2, (512, 512), (512, 512)),      # group 2
     (1024, 4, 1, (256, 512), (256, 512)),      # group 4, as the dense cell
 ])
-def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd):
+def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd,
+                                                       impl_bwd):
     """The blocks ``choose_blocks`` gives each group (1024 folded rows a
     step), run as the dispatcher runs them: causal, bf16 operands, float32
     accumulation, against the dense reference in float32 on the same
@@ -141,9 +145,12 @@ def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd):
     2.0e-3 to 3.1e-3 at every block pair here, (256, 512) included."""
     q, k, v = _qkv(b=1, s=s, h=h, kv=kv, d=128, dtype=jnp.bfloat16)
     f_dec, b_dec = kd.resolve(kd.make_sig(q.shape, kv, s, q.dtype, True,
-                                          None, None), "interpret")
+                                          None, None), "interpret",
+                              impl_bwd=impl_bwd)
     assert (f_dec.block_q, f_dec.block_k) == fwd
-    assert (b_dec.block_q, b_dec.block_k) == bwd
+    # the fused backward's own: (512, 512) at these groups
+    assert (b_dec.block_q, b_dec.block_k) == (
+        bwd if impl_bwd == "pallas" else (512, 512))
     scale = 1.0 / np.sqrt(q.shape[-1])
     w = jnp.asarray(np.random.default_rng(3).normal(size=q.shape), jnp.float32)
 
@@ -153,7 +160,7 @@ def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd):
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, interpret=True,
-                              impl_fwd="pallas", impl_bwd="pallas")
+                              impl_fwd="pallas", impl_bwd=impl_bwd)
         return (out.astype(jnp.float32) * w).sum(), out
 
     (_, o_ref), g_ref = jax.value_and_grad(ref_loss, (0, 1, 2), has_aux=True)(
@@ -187,9 +194,9 @@ def test_bench_shape_routes_xla_fwd_pallas_bwd():
     Pallas flash backward."""
     fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
     assert fwd.impl == kd.IMPL_XLA and fwd.source == "heuristic"
-    assert bwd.impl == kd.IMPL_PALLAS and bwd.source == "heuristic"
-    assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(_bench_sig(), "bwd")
-    assert (bwd.block_q, bwd.block_k) == (1024, 512)  # group 1: 1024 rows
+    assert bwd.impl == kd.IMPL_FUSED and bwd.source == "heuristic"
+    assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(_bench_sig(), "fused")
+    assert (bwd.block_q, bwd.block_k) == (512, 512)   # the fused kernel's
 
 
 def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None,
@@ -255,9 +262,130 @@ def test_blocks_follow_from_the_shape(name, sig, fwd, bwd):
     if name == "cell":
         bq, bk = kd.choose_blocks(sig, "bwd")
         assert bk >= 512 and 512 <= group * bq <= 1024
-    # and it is what an unpinned Pallas leg resolves to
-    _, dec = kd.resolve(sig, "TPU v5e")
-    assert (dec.block_q, dec.block_k) == bwd and dec.source == "heuristic"
+    # and it is what a Pallas leg resolves to: unpinned forward and back,
+    # but that the backward of a shape that fits is the fused kernel, with
+    # blocks of its own (below); the pair's are these
+    fwd_dec, dec = kd.resolve(sig, "TPU v5e")
+    if fwd_dec.impl == kd.IMPL_PALLAS:
+        assert (fwd_dec.block_q, fwd_dec.block_k) == fwd
+    assert dec.source == "heuristic"
+    if dec.impl == kd.IMPL_PALLAS:
+        assert (dec.block_q, dec.block_k) == bwd
+    pair = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="pallas")
+    assert (pair.block_q, pair.block_k) == bwd
+
+
+@pytest.mark.parametrize("name,sig,impl", [
+    # the benchmark's four cells
+    ("cell", _sig(4096, 32, 8, 128, window=4096), kd.IMPL_FUSED),
+    ("lfm2_cell", _sig(8192, 32, 8, 64, batch=4), kd.IMPL_FUSED),
+    ("granite_cell", _sig(16384, 32, 8, 64), kd.IMPL_FUSED),
+    ("olmoe_cell", _sig(4096, 16, 16, 128, batch=4), kd.IMPL_FUSED),
+    ("chip_smoke", _sig(2048, 32, 8, 128, window=4096), kd.IMPL_FUSED),
+    ("hd64_1024", _bench_sig(), kd.IMPL_FUSED),
+    ("llama3_70b", _sig(4096, 64, 8, 128), kd.IMPL_FUSED),
+    ("gemma2_hd256", _sig(4096, 16, 8, 256), kd.IMPL_FUSED),
+    ("fp32", _sig(4096, 32, 8, 128, "float32"), kd.IMPL_FUSED),
+    ("seq64", _sig(64, 4, 4, 16), kd.IMPL_FUSED),
+    # the float32 dQ of a KV head's sequence grows with group x seq_q:
+    # 16,384 tokens at group 4 are 55 MiB with the tiles, 24,576 are 71
+    ("group4_24k", _sig(24576, 32, 8, 128), kd.IMPL_PALLAS),
+    ("group4_32k_hd64", _sig(32768, 32, 8, 64), kd.IMPL_PALLAS),
+    ("ulysses_32k", _sig(32768, 8, 2, 128), kd.IMPL_PALLAS),
+    ("group8_16k", _sig(16384, 64, 8, 128), kd.IMPL_PALLAS),
+    ("mha_64k", _sig(65536, 16, 16, 128), kd.IMPL_FUSED),
+    ("mha_128k", _sig(131072, 16, 16, 128), kd.IMPL_PALLAS),
+    # the accumulator follows the queries, not the keys
+    ("keys_ne_queries", _sig(256, 8, 2, 128, seq_k=65536), kd.IMPL_FUSED),
+])
+def test_the_backward_is_fused_where_its_accumulator_fits(name, sig, impl):
+    """The backward's heuristic: one kernel for dQ, dK and dV where its VMEM
+    estimate, whole-sequence dQ accumulator included, is within the cap;
+    past it the dq + dk/dv pair. What a fused call asks of the chip stays
+    well inside the core's 128 MiB."""
+    dec = kd.resolve_leg("bwd", sig, "TPU v5e")
+    assert dec.impl == impl and dec.source == "heuristic", name
+    est = kd.fused_vmem_bytes(sig)
+    assert (est <= kd.FUSED_VMEM_CAP_BYTES) == (impl == kd.IMPL_FUSED), est
+    # the accumulator, at 128 lanes a row at least, is inside the estimate
+    assert est >= (sig.heads // sig.kv_heads * sig.seq_q
+                   * max(sig.head_dim, 128) * 4)
+    if impl == kd.IMPL_FUSED:
+        assert (kd.vmem_limit_bytes(est) or 0) <= 80 * 2**20
+    # the forward never resolves to it; each backward has its own blocks
+    assert kd.resolve_leg("fwd", sig, "TPU v5e").impl != kd.IMPL_FUSED
+    assert (dec.block_q, dec.block_k) == kd.choose_blocks(
+        sig, "fused" if impl == kd.IMPL_FUSED else "bwd")
+
+
+@pytest.mark.parametrize("name,sig,blocks", [
+    # 2,048 folded rows a step, 512 queries at most, by 512 keys
+    ("cell", _sig(4096, 32, 8, 128, window=4096), (512, 512)),
+    ("lfm2_cell", _sig(8192, 32, 8, 64, batch=4), (512, 512)),
+    ("granite_cell", _sig(16384, 32, 8, 64), (512, 512)),
+    ("olmoe_cell", _sig(4096, 16, 16, 128, batch=4), (512, 512)),
+    ("group2", _sig(4096, 16, 8, 128, batch=4), (512, 512)),
+    ("hd64_1024", _bench_sig(), (512, 512)),
+    ("llama3_70b", _sig(4096, 64, 8, 128), (256, 512)),
+    ("group16", _sig(4096, 32, 2, 128), (128, 512)),
+    ("gemma2_hd256", _sig(4096, 16, 8, 256), (512, 512)),
+    ("fp32", _sig(4096, 32, 8, 128, "float32"), (512, 512)),
+    ("mha_1536", _sig(1536, 16, 16, 128), (512, 512)),
+    ("seq384", _sig(384, 8, 2, 64), (384, 384)),
+    ("seq64", _sig(64, 4, 4, 16), (64, 64)),
+    ("keys_ne_queries", _sig(256, 8, 2, 128, seq_k=1536), (256, 512)),
+])
+def test_the_fused_backwards_blocks_follow_from_the_shape(name, sig, blocks):
+    """The fused kernel carries its own VMEM limit, so its step is not cut
+    to the compiler's default: the PR 34 sweep's (512, 512), the queries
+    halved past 2,048 folded rows."""
+    assert kd.choose_blocks(sig, "fused") == blocks, name
+    assert sig.seq_q % blocks[0] == 0 and sig.seq_k % blocks[1] == 0
+    dec = kd.resolve_leg("bwd", sig, "TPU v5e")
+    assert (dec.impl, dec.block_q, dec.block_k) == (kd.IMPL_FUSED, ) + blocks
+    # the pair, pinned, keeps the blocks it had
+    pair = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="pallas")
+    assert (pair.block_q, pair.block_k) == kd.choose_blocks(sig, "bwd")
+
+
+def test_fused_is_a_backward_implementation_only(monkeypatch, tmp_path):
+    """The ladder pins either backward through what exists: ``impl_bwd=``,
+    ``DS_TPU_ATTN_BWD`` and a measured entry take "fused" and "pallas" (the
+    pair); the forward's names do not include it."""
+    sig = _sig(32768, 32, 8, 64)        # heuristic: the pair
+    dec = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="fused")
+    assert (dec.impl, dec.source) == ("fused", "explicit")
+    with pytest.raises(AssertionError):
+        kd.resolve_leg("fwd", sig, "TPU v5e", explicit_impl="fused")
+    monkeypatch.setenv("DS_TPU_ATTN_BWD", "fused")
+    monkeypatch.setenv("DS_TPU_ATTN_FWD", "fused")      # ignored, with a warning
+    fwd, bwd = kd.resolve(sig, "TPU v5e")
+    assert (bwd.impl, bwd.source) == ("fused", "env")
+    assert (fwd.impl, fwd.source) == ("pallas", "heuristic")
+    monkeypatch.setenv("DS_TPU_ATTN_BWD", "pallas")
+    small = _sig(4096, 32, 8, 128)      # heuristic: fused
+    assert kd.resolve_leg("bwd", small, "TPU v5e").impl == kd.IMPL_PALLAS
+    monkeypatch.delenv("DS_TPU_ATTN_BWD")
+    monkeypatch.delenv("DS_TPU_ATTN_FWD")
+    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
+    for leg in ("fwd", "bwd"):
+        get_cache().commit(kd.signature(leg, sig, "TPU v5e"),
+                           {"impl": "fused", "block_q": 128, "block_k": 512})
+    fwd, bwd = kd.resolve(sig, "TPU v5e")
+    assert (bwd.impl, bwd.block_q, bwd.source) == ("fused", 128, "measured")
+    assert (fwd.impl, fwd.source) == ("pallas", "heuristic")
+    assert kd.describe(fwd, bwd) == (
+        "attn[fwd=pallas@256x512:heuristic,bwd=fused@128x512:measured]")
+
+
+def test_the_fused_estimate_grows_with_the_queries():
+    est = [kd.flash_vmem_bytes("fused", 4, 64, 2, 256, 512, seq_q=s)
+           for s in (4096, 8192, 16384)]
+    assert est[1] - est[0] == 4 * 4096 * 128 * 4      # head 64 in 128 lanes
+    assert est[2] - est[1] == 4 * 8192 * 128 * 4
+    # without the accumulator it is the pair's order of size
+    tiles = est[0] - 4 * 4096 * 128 * 4
+    assert 0 < tiles <= 1.2 * kd.flash_vmem_bytes("bwd", 4, 64, 2, 256, 512)
 
 
 def test_blocks_past_the_default_vmem_ask_for_their_own_limit():
@@ -328,7 +456,7 @@ def test_legacy_folded_env_forces_both_legs(monkeypatch):
     monkeypatch.setenv("DS_TPU_FLASH_FOLDED", "0")
     fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
     assert fwd.impl == kd.IMPL_XLA
-    assert bwd.impl == kd.IMPL_PALLAS
+    assert bwd.impl == kd.IMPL_FUSED    # a per-head kernel too
 
 
 def test_pallas_only_restriction(monkeypatch):
@@ -336,7 +464,7 @@ def test_pallas_only_restriction(monkeypatch):
     the XLA path back — an XLA pick degrades to the per-head kernel."""
     fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e", pallas_only=True)
     assert fwd.impl == kd.IMPL_PALLAS and "pallas-forced" in fwd.source
-    assert bwd.impl == kd.IMPL_PALLAS
+    assert bwd.impl == kd.IMPL_FUSED
     monkeypatch.setenv("DS_TPU_FLASH_FOLDED", "1")
     fwd, _ = kd.resolve(_bench_sig(), "TPU v5e", pallas_only=True)
     assert fwd.impl == kd.IMPL_FOLDED
@@ -344,10 +472,10 @@ def test_pallas_only_restriction(monkeypatch):
 
 def test_describe_and_resolved_note():
     note = kd.resolved_note(kind="TPU v5e")
-    assert note.startswith("attn[fwd=xla:heuristic,bwd=pallas@")
+    assert note.startswith("attn[fwd=xla:heuristic,bwd=fused@")
     fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
     d = kd.describe(fwd, bwd)
-    assert "fwd=xla" in d and "bwd=pallas@" in d
+    assert "fwd=xla" in d and "bwd=fused@" in d
 
 
 # ---------------------------------------------------------------------------
@@ -487,7 +615,7 @@ def test_sweep_writes_cache_consumed_by_dispatch(monkeypatch, tmp_path):
     sig = kd.make_sig((1, 128, 2, 32), 2, 128, "float32", True, None, None)
     fwd, bwd = kd.resolve(sig, "interpret")
     assert fwd.source == "measured" and bwd.source == "measured"
-    assert fwd.impl in IMPLS and bwd.impl in IMPLS
+    assert fwd.impl in IMPLS and bwd.impl in BWD_IMPLS
 
 
 def test_sweep_dry_run_commits_nothing(monkeypatch, tmp_path):

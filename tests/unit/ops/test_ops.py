@@ -85,15 +85,19 @@ def test_flash_attention_gqa_grad_pallas_bwd():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_flash_attention_bwd_is_pallas_not_recompute():
-    """Lowering the grad must contain the dq and dk/dv custom kernels (3
-    pallas calls incl. fwd) — not an XLA softmax recompute."""
+@pytest.mark.parametrize("impl_bwd,calls", [(None, 2), ("fused", 2), ("pallas", 3)])
+def test_flash_attention_bwd_is_pallas_not_recompute(impl_bwd, calls):
+    """Lowering the grad must contain the backward's custom kernels (the
+    forward and the fused dq/dk/dv kernel, which is what a small shape
+    resolves to; or the forward, dq and dk/dv when the pair is pinned) —
+    not an XLA softmax recompute."""
     q = jax.random.normal(jax.random.PRNGKey(7), (1, 16, 2, 8), jnp.float32)
     jaxpr = jax.make_jaxpr(
         jax.grad(lambda q: (flash_attention(q, q, q, causal=True, block_q=8,
-                                            block_k=8, interpret=True) ** 2).sum()))(q)
+                                            block_k=8, interpret=True,
+                                            impl_bwd=impl_bwd) ** 2).sum()))(q)
     text = str(jaxpr)
-    assert text.count("pallas_call") >= 3, text.count("pallas_call")
+    assert text.count("pallas_call") == calls, text.count("pallas_call")
     assert "softmax" not in text
 
 

@@ -100,13 +100,25 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
     (4096, None, 16, 8, D),     # group 2: (512, 512)
     (1536, None, 16, 16, D),    # group 1, a sequence 1024 does not divide
     (8192, None, 32, 8, 64),    # LFM2: head size 64, group 4, 8,192 keys
+    (16384, None, 32, 8, 64),   # Granite: 16,384 keys, a 32 MiB dQ accumulator
+    (32768, None, 32, 8, 64),   # past the fused backward's cap: the pair
+    (4096, WINDOW, H, KV, "fp32"),  # float32 operands: twice the tiles
 ])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
                                           window, heads, kv, d):
     """The blocks ``kernel_dispatch.choose_blocks`` picks for each shape fit
-    the chip's VMEM and tile; a refusal here costs no chip time."""
+    the chip's VMEM and tile, and so does the backward the shape resolves
+    to: the fused kernel with its whole-sequence dQ accumulator, or past the
+    cap the dq + dk/dv pair; a refusal here costs no chip time."""
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    dtype, d = (jnp.float32, D) if d == "fp32" else (jnp.bfloat16, d)
+    sig = kd.make_sig((1, seq, heads, d), kv, seq, jnp.dtype(dtype).name, True,
+                      window, None)
+    fused = kd.resolve_leg("bwd", sig, "TPU v5 lite").impl == kd.IMPL_FUSED
+    assert fused == (seq < 32768)
+
     def sds(n):
-        return _sds((1, seq, n, d), jnp.bfloat16, one_chip)
+        return _sds((1, seq, n, d), dtype, one_chip)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, window=window,
@@ -115,17 +127,19 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
 
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
                         sds(heads), sds(kv), sds(kv))
-    # forward, dq, and dk+dv
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    names = sorted(n.split(".")[0] for n in _custom_call_names(compiled))
+    assert names == (["flash_dkdv_dq", "flash_fwd"] if fused else
+                     ["flash_dkdv", "flash_dq", "flash_fwd"]), names
 
 
 def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
                                                         no_compile_cache):
     """``train-olmoe-1chip-seq4k``'s call, ``[4, 4096, 16/16, 128]``: at
     group 1 a step of 1024 folded rows is a 1024-query block. The forward
-    at (1024, 1024) and dq and dk/dv at (1024, 512) compile under the
-    compiler's default scoped VMEM (no limit on the call), each under its
-    own name, on the cell's own operand."""
+    at (1024, 1024) and the fused backward at its (512, 512), whose dQ
+    accumulator is 2 MiB here, compile under the compiler's default scoped
+    VMEM (no limit on the call), each under its own name, on the cell's own
+    operand."""
     from deepspeed_tpu.ops import kernel_dispatch as kd
     shape = (4, 4096, 16, D)
     sig = kd.make_sig(shape, 16, 4096, "bfloat16", True, None, None)
@@ -134,6 +148,9 @@ def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
     for leg in ("fwd", "bwd"):
         est = kd.flash_vmem_bytes(leg, 1, D, 2, *kd.choose_blocks(sig, leg))
         assert kd.vmem_limit_bytes(est) is None, (leg, est)
+    assert kd.resolve_leg("bwd", sig, "TPU v5 lite").impl == kd.IMPL_FUSED
+    assert kd.choose_blocks(sig, "fused") == (512, 512)
+    assert kd.vmem_limit_bytes(kd.fused_vmem_bytes(sig)) is None
 
     def attend(q, k, v):
         return flash_attention(q, k, v, causal=True, force_pallas=True)
@@ -145,7 +162,7 @@ def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
     calls = _custom_calls(compiled)
     names = _custom_call_names(compiled)
     assert sorted(n.split(".")[0] for n in names) == [
-        "flash_dkdv", "flash_dq", "flash_fwd"], names
+        "flash_dkdv_dq", "flash_fwd"], names
     # a call's backend config holds the scoped VMEM it asked for (nothing:
     # the compiler's default) and then what the compiler gave it
     for call in calls:
@@ -155,6 +172,58 @@ def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
                 <= kd.VMEM_SCOPED_DEFAULT_BYTES)
     fwd, = [c for c in calls if "%flash_fwd" in c.split(" = ")[0]]
     assert f"bf16[64,1,4096,{D}]" in fwd
+
+
+@pytest.mark.parametrize("cell,batch,seq,window,heads,kv,d", [
+    ("train-zero3-seq4k", 1, 4096, WINDOW, H, KV, D),
+    ("train-olmoe-1chip-seq4k", 4, 4096, None, 16, 16, D),
+    ("train-lfm2moe-1chip-seq8k", 4, 8192, None, 32, 8, 64),
+    ("train-granite4hm-1chip-longseq", 1, 16384, None, 32, 8, 64),
+])
+def test_the_fused_backward_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, cell, batch, seq, window, heads, kv, d):
+    """Each cell's attention call and its gradient: the backward is one
+    ``flash_dkdv_dq`` call that asks for what ``flash_vmem_bytes`` estimates
+    and a quarter more, within the cap's 80 MiB, and is given no more than it
+    asked for; dQ leaves it in the input dtype (no float32 dQ in HBM), and
+    nothing in the program is laid out as the pair's ``f32[.., seq, 1]``
+    delta column."""
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    sig = kd.make_sig((batch, seq, heads, d), kv, seq, "bfloat16", True,
+                      window, None)
+    dec = kd.resolve_leg("bwd", sig, "TPU v5 lite")
+    assert dec.impl == kd.IMPL_FUSED
+    est = kd.fused_vmem_bytes(sig)
+    assert est <= kd.FUSED_VMEM_CAP_BYTES
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              force_pallas=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def sds(n):
+        return _sds((batch, seq, n, d), jnp.bfloat16, one_chip)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(heads), sds(kv), sds(kv))
+    bwd, = [c for c in _custom_calls(compiled)
+            if "%flash_dkdv_dq" in c.split(" = ")[0]]
+    asked, given = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', bwd)
+
+    def end(config):    # where the region ends: XLA may keep arrays under it
+        return sum(int(x) for x in re.search(
+            r'"offset":"(\d+)","size":"(\d+)"', config).groups())
+
+    limit = kd.vmem_limit_bytes(est)
+    if limit is None:
+        assert asked == "" and end(given) <= kd.VMEM_SCOPED_DEFAULT_BYTES
+    else:
+        assert int(re.search(r'"size":"(\d+)"', asked).group(1)) == limit
+        assert limit <= 80 * 2**20 and end(given) <= end(asked) <= 128 * 2**20
+    group, bkv = heads // kv, batch * kv
+    result = bwd.split(" = ")[1].split(" custom-call(")[0]
+    assert f"bf16[{bkv},{group},{seq},{d}]" in result and "f32[" not in result
+    assert f"f32[{bkv},{group},{seq},1]" not in bwd
 
 
 def test_rms_norm_compiles(one_chip, no_compile_cache):
@@ -288,8 +357,8 @@ def _custom_call_names(compiled):
 def test_flash_kernels_keep_their_names_in_the_compiled_program(
         topo, one_chip, no_compile_cache, monkeypatch, meshed):
     """The training cell's attention (1 x 4096 x 32/8 heads x 128 a chip,
-    window 4096) and its gradient: forward, dq and dk+dv are named for what
-    they are on one chip and inside the shard_map that ``models/llama.py``
+    window 4096) and its gradient: the forward and the fused backward are
+    named for what they are on one chip and inside the shard_map that ``models/llama.py``
     wraps them in (``sequence/layer.py:ulysses_flash``), where they used to
     take the shard_map's name. The benchmark's flash roofline readers and
     ``breakdown.device_ops`` key on these names."""
@@ -324,9 +393,10 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
                         sds(H), sds(KV), sds(KV))
     names = _custom_call_names(compiled)
-    assert len(names) == 3, names
-    for prefix in ("flash_fwd", "flash_dq", "flash_dkdv"):
+    assert len(names) == 2, names
+    for prefix in ("flash_fwd", "flash_dkdv_dq"):
         assert sum(n.startswith(prefix) for n in names) == 1, names
+    assert not any(n.startswith("flash_dq") for n in names)
     assert not any(n.startswith("shard_map") for n in names)
     # each chip's call is the cell's shape: [rows * kv, group, seq, d]
     fwd, = [line for line in _custom_calls(compiled)
