@@ -412,8 +412,11 @@ class LlamaAttention(nn.Module):
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         if cfg.pos_embedding == "rope":
-            q = apply_rope(q, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
-            k = apply_rope(k, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
+            # closed before the kernel's call below: a scope that held it
+            # would rename the instruction (docs/observability.md)
+            with jax.named_scope("ds.rope"):
+                q = apply_rope(q, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
+                k = apply_rope(k, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
 
         # GQA handled natively by both paths (no materialized K/V head
         # repeat — 4x K/V bandwidth saving at 8B scale). The Pallas flash
@@ -712,10 +715,11 @@ class LlamaMoEBlock(nn.Module):
             raise ValueError(f"experts {first}..{first + held} held of {E}")
         H, F = cfg.hidden_size, cfg.intermediate_size
         logits = _dense(E, "gate", (EMBED, "expert"), jnp.float32)(x.astype(jnp.float32))
-        probs, w, idx = self._route(logits)
-        # (token, choice) assignments per expert: what the engine's fused
-        # step returns beside the loss ("moe_stats", read only when mutable)
-        counts = expert_counts(idx, E)
+        with jax.named_scope("ds.moe.route"):
+            probs, w, idx = self._route(logits)
+            # (token, choice) assignments per expert: what the engine's fused
+            # step returns beside the loss ("moe_stats", read only when mutable)
+            counts = expert_counts(idx, E)
 
         def sow_stat(name, value):
             self.sow("moe_stats", name, value, reduce_fn=lambda a, b: a + b,
@@ -1018,15 +1022,18 @@ class LlamaForCausalLM(nn.Module):
             x, w, b = LlamaModel(cfg, name="model")(input_ids, positions,
                                                     attn_mask,
                                                     return_unembed=True)
-            return chunked_cross_entropy_loss(
-                x, w, b, labels, cfg.ce_chunk_size,
-                logit_scale=cfg.logit_scale,
-                softcap=cfg.final_logit_softcapping,
-                compute_dtype=cfg.dtype)
+            # the head's matmuls run in here, not under `lm_head`
+            with jax.named_scope("ds.head.loss"):
+                return chunked_cross_entropy_loss(
+                    x, w, b, labels, cfg.ce_chunk_size,
+                    logit_scale=cfg.logit_scale,
+                    softcap=cfg.final_logit_softcapping,
+                    compute_dtype=cfg.dtype)
         logits = LlamaModel(cfg, name="model")(input_ids, positions, attn_mask)
         if labels is None:
             return logits
-        return cross_entropy_loss(logits, labels)
+        with jax.named_scope("ds.head.loss"):
+            return cross_entropy_loss(logits, labels)
 
 
 def unbox_params(params):

@@ -41,7 +41,7 @@ by ``ds_top --file``) and the ``monitor.write_registry`` bridge.
 """
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                      get_registry, histogram_delta, quantiles_from_counts)
+                      get_registry, quantiles_from_counts)
 from .tracing import RequestTracer, get_tracer
 from .profiler import ProfilerBusy, ProfilerCapture, profile_dir
 from .instruments import ServingInstruments
@@ -52,7 +52,7 @@ from .goodput import CATEGORIES as GOODPUT_CATEGORIES, GoodputLedger
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
-    "histogram_delta", "quantiles_from_counts",
+    "quantiles_from_counts",
     "RequestTracer", "get_tracer",
     "ProfilerBusy", "ProfilerCapture", "profile_dir",
     "ServingInstruments",

@@ -369,19 +369,6 @@ class MetricsRegistry:
         return events
 
 
-def histogram_delta(before: Optional[dict], after: dict) -> dict:
-    """Interval view of one histogram between two ``snapshot()`` entries
-    (``before`` may be None → the interval starts at zero)."""
-    counts = np.asarray(after["counts"]).copy()
-    count, total = int(after["count"]), float(after["sum"])
-    if before is not None:
-        counts -= np.asarray(before["counts"])
-        count -= int(before["count"])
-        total -= float(before["sum"])
-    return {"type": "histogram", "edges": after["edges"], "counts": counts,
-            "count": count, "sum": total}
-
-
 _REGISTRY = MetricsRegistry()
 
 

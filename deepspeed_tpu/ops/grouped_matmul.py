@@ -140,8 +140,6 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
       ``[T, H]`` in x.dtype.
     """
     k = top_idx.shape[1]
-    order, inv = moe_sort_permutation(top_idx)
-    group_sizes = expert_counts(top_idx, w1.shape[0])
 
     def gmm(rows, w):
         # the kernel accumulates in float32 and rounds once on write: the
@@ -149,9 +147,15 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
         return jax.lax.ragged_dot(rows, w, group_sizes,
                                   preferred_element_type=x.dtype)
 
-    xs = moe_dispatch(x, order, inv, k)  # [T*k, H] expert-contiguous
+    # the gathers have a scope each (the sort is dispatch's); the grouped
+    # matmuls between them keep the block's own, and their instruction names
+    with jax.named_scope("ds.moe.dispatch"):
+        order, inv = moe_sort_permutation(top_idx)
+        group_sizes = expert_counts(top_idx, w1.shape[0])
+        xs = moe_dispatch(x, order, inv, k)  # [T*k, H] expert-contiguous
     y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
-    return moe_combine(y, top_w, order, inv)
+    with jax.named_scope("ds.moe.combine"):
+        return moe_combine(y, top_w, order, inv)
 
 
 SHARE_ROWS_FACTOR = 2
@@ -247,9 +251,11 @@ def _share_rows_mlp(x, w1, w3, w2, top_w, order, inv, group_sizes, *, rows,
         return jax.lax.ragged_dot(lhs, w, group_sizes,
                                   preferred_element_type=x.dtype)
 
-    xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
+    with jax.named_scope("ds.moe.dispatch"):
+        xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
     y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
-    return share_combine(y, top_w, order[:rows], inv, n_held)
+    with jax.named_scope("ds.moe.combine"):
+        return share_combine(y, top_w, order[:rows], inv, n_held)
 
 
 def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
@@ -266,7 +272,9 @@ def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
     int32 scalars.
     """
     held, assignments = w1.shape[0], top_idx.size
-    order, inv, group_sizes = moe_share_permutation(top_idx, first_expert, held)
+    with jax.named_scope("ds.moe.dispatch"):
+        order, inv, group_sizes = moe_share_permutation(top_idx, first_expert,
+                                                        held)
     rows_held = jnp.sum(group_sizes)
     bound = share_rows(assignments, held, num_experts)
 

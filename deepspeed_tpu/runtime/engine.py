@@ -59,6 +59,15 @@ except ImportError:  # pragma: no cover
     _HAS_FLAX = False
 
 
+def _step_scope(region: str):
+    """The name of a region of the compiled step that no module names:
+    ``ds.step.<region>`` on the name stack of every op traced under it, which
+    is what XProf shows under a device op and what
+    ``benchmark/scope_time.py`` reads (docs/observability.md, "Device
+    scopes"). Metadata only: no op is added."""
+    return jax.named_scope("ds.step." + region)
+
+
 def _tree_where(cond, a, b):
     return jax.tree_util.tree_map(lambda x, y: jnp.where(cond, x, y), a, b)
 
@@ -763,14 +772,23 @@ class DeepSpeedTpuEngine:
         cast_in_model = (self._config.param_cast == "model"
                          and qwz_gather is None)
 
-        def loss_of(params, args, kwargs, static_kv, scale):
-            if zmeta is not None:
-                params = _materialize(params, zmeta)
-            if qwz_gather is not None:
-                params = qwz_gather(params)
-            if not cast_in_model:
-                params = jax.tree_util.tree_map(
+        def gathered(params, qwz=True):
+            with _step_scope("gather"):
+                if zmeta is not None:
+                    params = _materialize(params, zmeta)
+                if qwz and qwz_gather is not None:
+                    params = qwz_gather(params)
+            return params
+
+        def to_compute(params):
+            with _step_scope("cast"):
+                return jax.tree_util.tree_map(
                     lambda x: x.astype(compute_dtype), params)
+
+        def loss_of(params, args, kwargs, static_kv, scale):
+            params = gathered(params)
+            if not cast_in_model:
+                params = to_compute(params)
             return loss_from_cparams(params, args, kwargs, static_kv, scale)
 
         def value_and_grads(params, args, kwargs, static_kv, scale):
@@ -785,14 +803,15 @@ class DeepSpeedTpuEngine:
             consumers' up-casts fuse into each leaf's optimizer update /
             accumulate. With param_cast="model" the masters go in as-is and
             grads are fp32."""
+            fn = loss_of
             if (compute_dtype != jnp.float32 and qwz_gather is None
                     and zmeta is None and not cast_in_model):
-                cparams = jax.tree_util.tree_map(
-                    lambda x: x.astype(compute_dtype), params)
-                return jax.value_and_grad(loss_from_cparams, has_aux=True)(
-                    cparams, args, kwargs, static_kv, scale)
-            return jax.value_and_grad(loss_of, has_aux=True)(
-                params, args, kwargs, static_kv, scale)
+                fn, params = loss_from_cparams, to_compute(params)
+            # JAX's own jvp(...), transpose(...) and rematted_computation
+            # marks split this scope into forward, backward and recomputation
+            with _step_scope("loss"):
+                return jax.value_and_grad(fn, has_aux=True)(
+                    params, args, kwargs, static_kv, scale)
 
         def fwd_bwd(params, acc, scale, args, kwargs, static_kv):
             # acc dtype = grad_accum_dtype (fp32 default: full accumulation
@@ -810,33 +829,39 @@ class DeepSpeedTpuEngine:
         )
 
         def fwd_only(params, args, kwargs, static_kv):
-            if zmeta is not None:
-                params = _materialize(params, zmeta)
+            params = gathered(params, qwz=False)
             if not cast_in_model:
-                params = jax.tree_util.tree_map(
-                    lambda x: x.astype(compute_dtype), params)
+                params = to_compute(params)
             return apply_fn(params, *args, **dict(kwargs, **dict(static_kv)))
 
         self._fwd_only = jax.jit(fwd_only, static_argnums=(3, ))
 
+        def update_from(params, grads, opt_state, scale_state, scale):
+            """Everything of a step after the gradients, for the fused step,
+            the K-step scan and the split apply alike: unscale, overflow
+            test, global norm and clip, then the optimizer."""
+            with _step_scope("grad_norm"):
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32) / scale, grads)
+                overflow = has_overflow(grads) if use_scaling else jnp.bool_(False)
+                gnorm = optax.global_norm(grads)
+                if clip > 0:
+                    factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
+            with _step_scope("optimizer"):
+                updates, new_opt = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                if use_scaling:
+                    # skip the step entirely on overflow (reference fused_optimizer.py)
+                    new_params = _tree_where(overflow, params, new_params)
+                    new_opt = _tree_where(overflow, opt_state, new_opt)
+                new_scale_state = scaler_cfg.update(scale_state, overflow)
+            return new_params, new_opt, new_scale_state, overflow, gnorm
+
         def apply_step(params, acc, opt_state, scale_state):
             scale = scale_state.cur_scale if use_scaling else jnp.float32(1.0)
-            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) / scale, acc)
-            overflow = has_overflow(grads) if use_scaling else jnp.bool_(False)
-
-            gnorm = optax.global_norm(grads)
-            if clip > 0:
-                factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
-
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-
-            if use_scaling:
-                # skip the step entirely on overflow (reference fused_optimizer.py)
-                new_params = _tree_where(overflow, params, new_params)
-                new_opt = _tree_where(overflow, opt_state, new_opt)
-            new_scale_state = scaler_cfg.update(scale_state, overflow)
+            new_params, new_opt, new_scale_state, overflow, gnorm = update_from(
+                params, acc, opt_state, scale_state, scale)
             zeroed = jax.tree_util.tree_map(jnp.zeros_like, acc)
             return new_params, new_opt, zeroed, new_scale_state, overflow, gnorm
 
@@ -902,18 +927,8 @@ class DeepSpeedTpuEngine:
             scale = scale_state.cur_scale if use_scaling else jnp.float32(1.0)
             (_, (loss, stats)), grads = value_and_grads(
                 params, args, kwargs, static_kv, scale)
-            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) / scale, grads)
-            overflow = has_overflow(grads) if use_scaling else jnp.bool_(False)
-            gnorm = optax.global_norm(grads)
-            if clip > 0:
-                factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            if use_scaling:
-                new_params = _tree_where(overflow, params, new_params)
-                new_opt = _tree_where(overflow, opt_state, new_opt)
-            new_scale_state = scaler_cfg.update(scale_state, overflow)
+            new_params, new_opt, new_scale_state, overflow, gnorm = update_from(
+                params, grads, opt_state, scale_state, scale)
             return (loss, new_params, new_opt, new_scale_state, overflow, gnorm,
                     stats)
 

@@ -7,7 +7,6 @@ import pytest
 
 from deepspeed_tpu.observability import (Counter, Gauge, Histogram,
                                          MetricsRegistry, get_registry,
-                                         histogram_delta,
                                          quantiles_from_counts)
 
 
@@ -158,17 +157,3 @@ def test_to_events_shapes():
     assert not any(n.startswith("serve/h_seconds") for n in d)
 
 
-def test_histogram_delta_interval():
-    reg = MetricsRegistry()
-    h = reg.histogram("h_seconds")
-    h.record(0.1)
-    before = reg.snapshot()
-    h.record(0.2)
-    h.record(0.3)
-    d = histogram_delta(before["h_seconds"], reg.snapshot()["h_seconds"])
-    assert d["count"] == 2
-    assert d["sum"] == pytest.approx(0.5)
-    assert int(np.sum(d["counts"])) == 2
-    # None "before" = interval from zero
-    d0 = histogram_delta(None, reg.snapshot()["h_seconds"])
-    assert d0["count"] == 3

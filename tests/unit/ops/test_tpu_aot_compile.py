@@ -402,3 +402,92 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
     fwd, = [line for line in _custom_calls(compiled)
             if "%flash_fwd" in line.split(" = ")[0]]
     assert f"bf16[{KV},{H // KV},{seq},{D}]" in fwd
+
+
+# a whole layer of each training cell's kind at its widths (hidden 2048, the
+# vocabulary cut: the head has no kernel), remat as the LFM2 and Granite cells
+# run it: what each kernel's instruction must still be called when the
+# program's own scopes (docs/observability.md, "Device scopes") are around it
+_SCOPED_LAYERS = {
+    "attention_rope_dense": (
+        dict(num_attention_heads=16, num_key_value_heads=4, head_dim=128,
+             qk_norm="head", intermediate_size=8192), 4096,
+        {"flash_fwd": 2, "flash_dkdv_dq": 1}, 0),
+    "conv_moe_share": (
+        dict(num_attention_heads=32, num_key_value_heads=8, head_dim=64,
+             num_local_experts=64, moe_experts_held=8, num_experts_per_tok=4,
+             moe_scoring="sigmoid", moe_selection_bias=True,
+             moe_renorm_eps=1e-6, operator="conv", ffn="moe", ffn_width=1536),
+        # either branch of the share's cond: the forward's three, a
+        # recomputed forward's three, the six gradients
+        4096, {"short_conv_fwd": 2, "short_conv_bwd": 1}, 2 * (3 + 3 + 6)),
+    "mamba_dense": (
+        dict(num_attention_heads=32, num_key_value_heads=8, head_dim=64,
+             mamba_n_heads=64, pos_embedding="none", residual_multiplier=0.22,
+             operator="mamba", ffn="dense", ffn_width=8192), 4096,
+        {"ssd_chunk_fwd": 2, "ssd_chunk_bwd": 1, "causal_conv_fwd": 2,
+         "causal_conv_bwd": 1}, 0),
+    "attention_moe": (
+        dict(num_attention_heads=16, num_key_value_heads=16, head_dim=128,
+             qk_norm=True, num_local_experts=64, num_experts_per_tok=8,
+             moe_renormalize=False, router_aux_loss_coef=0.01,
+             intermediate_size=1024, remat=False), 4096,
+        {"flash_fwd": 1, "flash_dkdv_dq": 1}, 9),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_SCOPED_LAYERS))
+def test_kernels_keep_their_names_under_the_programs_scopes(
+        one_chip, no_compile_cache, monkeypatch, kind):
+    """Hazard (i) of the device scopes: an instruction is named by the
+    innermost name scope of the frame that holds it, and twelve admitted
+    metrics match kernels by instruction name. One layer of each cell's kind,
+    the loss and its gradient under the engine's ``ds.step.loss`` with
+    ``ds.rope``, ``ds.moe.*`` and ``ds.head.loss`` inside: every kernel is
+    still called what its reader matches, the scopes are on the ops around
+    them, and the recomputed forward's kernels are there (count 2)."""
+    import dataclasses
+    from deepspeed_tpu.models import llama
+    from deepspeed_tpu.runtime.engine import _step_scope
+    over, seq, kernels, ragged = _SCOPED_LAYERS[kind]
+    over = dict(over)
+    spec = {k: over.pop(k) for k in ("operator", "ffn", "ffn_width") if k in over}
+    cfg = llama.LlamaConfig(**{**dict(
+        vocab_size=2048, hidden_size=2048, num_hidden_layers=1,
+        max_position_embeddings=seq, ce_chunk_size=2048, remat=True,
+        layer_specs=(llama.LayerSpec(**spec), ) if spec else None), **over})
+    # code that asks "is this a TPU" sees the CPU here, and conftest turns
+    # interpret mode on: steer both in the test, the kernels are the subject
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
+    monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
+                        lambda force=None: True)
+    monkeypatch.setattr("deepspeed_tpu.ops.kernel_dispatch.device_kind",
+                        lambda: "TPU v5 lite")
+    model = llama.LlamaForCausalLM(cfg)
+    ids = _sds((1, seq), jnp.int32, one_chip)
+    shapes = jax.eval_shape(
+        lambda: {"params": llama.unbox_params(model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, seq), jnp.int32)))["params"]})
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), shapes)
+
+    def step(params, ids):
+        def loss(p):
+            out = model.apply(p, ids, ids, mutable=["aux_loss", "moe_stats"])
+            return out[0].astype(jnp.float32)
+        with _step_scope("loss"):
+            return jax.value_and_grad(loss)(params)
+
+    compiled = _compile(step, params, ids)
+    names = [n.split(".")[0] for n in _custom_call_names(compiled)
+             if not n.startswith("ragged-dot")]
+    assert {k: names.count(k) for k in set(names)} == kernels, names
+    text = compiled.as_text()
+    grouped = re.findall(r"%(ragged-dot-none[.\d]*) = ", text)
+    assert len(grouped) == ragged, grouped
+    for scope in (["ds.step.loss", "ds.head.loss"]
+                  + ["ds.rope"] * (cfg.pos_embedding == "rope" and not spec)
+                  + ["ds.moe.route", "ds.moe.dispatch", "ds.moe.combine"]
+                  * bool(ragged)):
+        assert f"/{scope}/" in text, scope
