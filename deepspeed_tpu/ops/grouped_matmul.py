@@ -35,13 +35,30 @@ costs one pass over a quarter of the ``T*k`` rows at 8 of 64 experts held. A
 step that sends more takes the same function over all ``T*k`` rows under a
 ``lax.cond``: slower, exact, counted (``share_fallback``). Rows between the
 held count and the static length are no expert's: the grouped matmul leaves
-them alone, and they are masked where rows are gathered back.
+them alone, and they are masked where rows go back to tokens.
+
+Every trip of a share back from sorted rows to tokens walks those ``R`` rows,
+not the ``T*k`` token positions of which an eighth are live
+(``_rows_to_tokens``: the combine's forward and the dispatch's transpose; the
+weights' gradient is placed at the rows' own assignments). A second sort,
+``R`` long, puts the live rows in token order, one gather of ``R`` rows
+follows it, and a token's at most k rows, now adjacent, are summed in float32
+by a kernel, a block of tokens a grid step (a scatter-add with sorted indices
+where no kernel runs). So the share takes no inverse permutation. The whole
+path above keeps its gathers: there every row is live, and walking ``T*k``
+rows costs the same gather plus the sort and the sum; so does a share whose
+prefix is more than half the assignments, and the exact pass over all of them
+(``share_walks_rows``, with the measured crossover).
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .registry import interpret_kernels, on_tpu, registry
 
 
 def moe_sort_permutation(top_idx):
@@ -172,41 +189,198 @@ def share_rows(assignments: int, held: int, num_experts: int) -> int:
 def moe_share_permutation(top_idx, first_expert: int, held: int):
     """Sort (token, choice) assignments so that those of the experts held
     (``first_expert .. first_expert + held``) come first, by expert, in token
-    order; every other assignment follows. Returns ``(order, inv,
-    group_sizes [held])`` as :func:`moe_sort_permutation` and
-    :func:`expert_counts` give them for all experts."""
+    order; every other assignment follows. Returns ``(order, group_sizes
+    [held])`` as :func:`moe_sort_permutation` and :func:`expert_counts` give
+    them for all experts. Where the inverse is needed (the exact pass over
+    all rows), the pass takes it itself."""
     local = top_idx.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inv = jnp.argsort(order).astype(jnp.int32)
-    return order, inv, expert_counts(key, held)
+    return order, expert_counts(key, held)
+
+
+# the kernel that sums token-sorted rows into their tokens: tokens a grid
+# step, and sorted rows a step's matmul takes
+TOKEN_BLOCK = 128
+ROW_CHUNK = 128
+
+
+def _sum_rows_kernel(first_ref, spans_ref, tok_ref, scale_ref, rows_ref,
+                     out_ref, acc_ref, *, weighted):
+    """Step ``(b, c)`` of ``(token blocks, chunks a block can span)`` adds to
+    block ``b``'s float32 sums the rows of its ``c``-th chunk that are its
+    tokens': ``M @ rows`` with ``M[t, i] = scale[i]`` where row ``i`` is
+    token ``t``'s, 0 elsewhere. A weighted ``M`` goes to the MXU as three
+    bfloat16 pieces that add up to its float32 value: every product is
+    exact, the sums are float32."""
+    b, c = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(c < spans_ref[b])
+    def _():
+        token = b * TOKEN_BLOCK + jax.lax.broadcasted_iota(
+            jnp.int32, (TOKEN_BLOCK, ROW_CHUNK), 0)
+        m = jnp.where(tok_ref[0] == token, scale_ref[0], 0.0)
+        rows = rows_ref[...]
+
+        def dot(lhs, **kw):
+            return jnp.dot(lhs, rows, preferred_element_type=jnp.float32, **kw)
+
+        if rows.dtype != jnp.bfloat16:
+            acc_ref[...] += dot(m, precision=jax.lax.Precision.HIGHEST)
+        elif not weighted:  # zeros and ones
+            acc_ref[...] += dot(m.astype(jnp.bfloat16))
+        else:
+            hi = m.astype(jnp.bfloat16)
+            rest = m - hi.astype(jnp.float32)
+            mid = rest.astype(jnp.bfloat16)
+            low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+            acc_ref[...] += dot(low) + dot(mid) + dot(hi)
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _sum_sorted_rows(rows, scale, tok, num_tokens, per_token, interpret):
+    """``out[t] = Σ_{i: tok[i] == t} scale[i] · rows[i]`` as ``[num_tokens,
+    H]`` in ``rows.dtype``: ``tok`` ascending, ``TOKEN_BLOCK`` times the
+    blocks or more for a row that is no token's (such rows zeros), at most
+    ``per_token`` rows a token, ``len(tok)`` whole chunks."""
+    R, H = rows.shape
+    blocks, chunks = -(-num_tokens // TOKEN_BLOCK), R // ROW_CHUNK
+    # rows edge[b] .. edge[b + 1] are block b's
+    bounds = jnp.arange(blocks + 1, dtype=jnp.int32) * TOKEN_BLOCK
+    edge = jnp.sum(tok[None, :] < bounds[:, None], axis=1, dtype=jnp.int32)
+    first = jnp.minimum(edge[:-1] // ROW_CHUNK, chunks - 1)
+    spans = jnp.where(edge[1:] > edge[:-1],
+                      -(-edge[1:] // ROW_CHUNK) - first, 0)
+
+    def chunk(b, c, first_ref, spans_ref):
+        # a step past the block's span stays on its last chunk: no new copy
+        return first_ref[b] + jnp.maximum(jnp.minimum(c, spans_ref[b] - 1), 0)
+
+    scalars = pl.BlockSpec((1, 1, ROW_CHUNK), lambda *a: (chunk(*a), 0, 0))
+    # double-buffered blocks in and out, the float32 sums and three products
+    from .kernel_dispatch import vmem_limit_bytes
+    limit = vmem_limit_bytes(TOKEN_BLOCK * H * (4 * rows.dtype.itemsize + 16))
+    out = pl.pallas_call(
+        functools.partial(_sum_rows_kernel, weighted=scale is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(blocks, -(-TOKEN_BLOCK * per_token // ROW_CHUNK) + 1),
+            in_specs=[scalars, scalars,
+                      pl.BlockSpec((ROW_CHUNK, H), lambda *a: (chunk(*a), 0))],
+            out_specs=pl.BlockSpec((TOKEN_BLOCK, H), lambda b, c, *_: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((TOKEN_BLOCK, H), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((blocks * TOKEN_BLOCK, H), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=limit),
+        interpret=interpret,
+        name="moe_rows_to_tokens",
+    )(first, spans, tok.reshape(chunks, 1, ROW_CHUNK),
+      (jnp.ones((R, ), jnp.float32) if scale is None else scale).reshape(
+          chunks, 1, ROW_CHUNK), rows)
+    return out[:num_tokens]
+
+
+def _kernel_here() -> bool:
+    """A raw ``pallas_call`` is not partitioned under GSPMD: the kernel runs
+    on a TPU where the mesh is one device (as ``models/llama.py`` decides for
+    the other kernels)."""
+    from ..comm.mesh import get_mesh_context, mesh_is_initialized
+    return on_tpu() and (not mesh_is_initialized() or all(
+        n == 1 for n in get_mesh_context().mesh.shape.values()))
+
+
+def _rows_to_tokens(rows, scale, tok, n_held, num_tokens: int, per_token: int,
+                    use_kernel=None):
+    """Sorted rows back to their tokens, by the rows held: row ``r`` of
+    ``rows[R, H]`` is token ``tok[r]``'s and live while ``r < n_held``; token
+    ``t`` gets ``Σ scale[r] · rows[r]`` over its live rows (``scale`` ``[R]``
+    float32, or None for ones), at most ``per_token`` of them, summed in
+    float32 and cast once to ``rows.dtype``: exact zeros where it has none.
+    What lies from ``n_held`` on is never read as a number.
+
+    A second sort, ``R`` long, puts the live rows in token order, the others
+    last; one gather of ``R`` rows follows it, the others filled with zeros.
+    A token's rows are then adjacent, and on one TPU device a kernel sums them
+    a block of tokens a grid step (``moe_rows_to_tokens``: a one-hot matmul,
+    so a row that is not finite spoils the tokens of its block, which a step
+    with such a row has lost anyway); elsewhere a scatter-add does, whose
+    indices XLA is told are sorted. docs/kernel_dispatch.md has the timings."""
+    R = rows.shape[0]
+    if use_kernel is None:
+        use_kernel = _kernel_here()
+    none = -(-num_tokens // TOKEN_BLOCK) * TOKEN_BLOCK
+    key = jnp.where(jnp.arange(R) < n_held, tok, none)
+    key, src = jax.lax.sort_key_val(key, jnp.arange(R, dtype=jnp.int32))
+    src = jnp.where(key < none, src, R)      # the others gather the fill
+    if use_kernel:                           # whole chunks of rows
+        key = jnp.pad(key, (0, -R % ROW_CHUNK), constant_values=none)
+        src = jnp.pad(src, (0, -R % ROW_CHUNK), constant_values=R)
+    rows = rows.at[src].get(mode="fill", fill_value=0)
+    if scale is not None:
+        scale = scale.at[src].get(mode="fill", fill_value=0)
+    if use_kernel:
+        return _sum_sorted_rows(rows, scale, key, num_tokens, per_token,
+                                interpret_kernels())
+    terms = rows.astype(jnp.float32)
+    if scale is not None:
+        terms = terms * scale[:, None]
+    out = jnp.zeros((num_tokens, rows.shape[1]), jnp.float32).at[key].add(
+        terms, mode="drop", indices_are_sorted=True)
+    return out.astype(rows.dtype)
 
 
 def _rows_back(rows, pos, n_held):
     """``rows[pos]`` in float32 where ``pos`` is a row held (``< n_held``),
     zeros elsewhere: whatever lies beyond the rows held is never read as a
-    number."""
+    number. The token-side form: a gather at every (token, choice)."""
     got = rows.at[pos].get(mode="fill", fill_value=0)
     return jnp.where((pos < n_held)[..., None], got.astype(jnp.float32), 0.0)
+
+
+def share_walks_rows(rows: int, assignments: int) -> bool:
+    """Whether a share working on ``rows`` of ``assignments`` sorted rows
+    brings them back to their tokens by the rows (:func:`_rows_to_tokens`)
+    or by the token positions (:func:`_rows_back`, which needs the inverse
+    permutation). Measured on a v5e at 32,768 tokens x top-4 x 2,048 bf16
+    (docs/kernel_dispatch.md): the combine by rows | by positions takes 1.2 |
+    4.0 ms at an eighth of the assignments, 2.9 | 7.9 at a quarter, 5.3 | 8.1
+    at half, 9.0 to 10.2 | 7.5 to 7.7 at all of them, where the sort and the
+    kernel come on top of the same 131,072-row gather. The rule stays on the
+    measured side of a crossover near four fifths."""
+    return 2 * rows <= assignments
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, ))
 def share_dispatch(x, order_r, inv, n_held, k):
     """``x[T, H] → xs[R, H]``: the first ``R`` sorted rows, zeros from row
-    ``n_held`` on. Transpose: each token gathers back the rows of its
-    assignments that are held, and sums them in float32."""
+    ``n_held`` on. Transpose: each token gets back the sum, in float32, of
+    its rows that are held: by the rows where ``inv`` is None, else gathered
+    at its k positions."""
     valid = jnp.arange(order_r.size) < n_held
     return jnp.where(valid[:, None], x[order_r // k], 0)
 
 
 def _share_dispatch_fwd(x, order_r, inv, n_held, k):
-    return share_dispatch(x, order_r, inv, n_held, k), (inv, n_held)
+    return share_dispatch(x, order_r, inv, n_held, k), \
+        (order_r, inv, n_held, x.shape[0])
 
 
 def _share_dispatch_bwd(k, res, dxs):
-    inv, n_held = res
-    dx = jnp.sum(_rows_back(dxs, inv.reshape(-1, k), n_held), axis=1)
-    return dx.astype(dxs.dtype), None, None, None
+    order_r, inv, n_held, tokens = res
+    if inv is None:
+        dx = _rows_to_tokens(dxs, None, order_r // k, n_held, tokens, k)
+    else:
+        dx = jnp.sum(_rows_back(dxs, inv.reshape(-1, k), n_held),
+                     axis=1).astype(dxs.dtype)
+    return dx, None, None, None
 
 
 share_dispatch.defvjp(_share_dispatch_fwd, _share_dispatch_bwd)
@@ -214,9 +388,14 @@ share_dispatch.defvjp(_share_dispatch_fwd, _share_dispatch_bwd)
 
 @jax.custom_vjp
 def share_combine(y, top_w, order_r, inv, n_held):
-    """``out[t] = Σ_j top_w[t, j] · y[inv[t*k + j]]`` over the assignments
-    whose row is held; the others add nothing. As :func:`moe_combine`."""
+    """``out[t] = Σ_j top_w[t, j] · y[row of (t, j)]`` over the assignments
+    whose row is held; the others add nothing. As :func:`moe_combine` where
+    ``inv`` is given; where it is None by the rows: each of the ``R`` takes
+    its weight and goes to its token."""
     T, k = top_w.shape
+    if inv is None:
+        w_sorted = top_w.reshape(-1)[order_r].astype(jnp.float32)
+        return _rows_to_tokens(y, w_sorted, order_r // k, n_held, T, k)
     yk = _rows_back(y, inv.reshape(T, k), n_held)
     return jnp.sum(yk * top_w.astype(jnp.float32)[:, :, None],
                    axis=1).astype(y.dtype)
@@ -234,15 +413,21 @@ def _share_combine_bwd(res, dout):
     g = dout[order_r // k].astype(jnp.float32)          # [R, H] expert order
     w_sorted = top_w.reshape(-1)[order_r].astype(jnp.float32)
     dy = jnp.where(valid[:, None], g * w_sorted[:, None], 0.0).astype(y.dtype)
-    dw_sorted = jnp.sum(y.astype(jnp.float32) * g, axis=-1, keepdims=True)
-    dw = _rows_back(dw_sorted, inv.reshape(T, k), n_held)[..., 0]
+    dw_sorted = jnp.sum(y.astype(jnp.float32) * g, axis=-1)
+    if inv is None:
+        # a prefix of a permutation: each live row has a place of its own
+        dw = jnp.zeros((T * k, ), jnp.float32).at[
+            jnp.where(valid, order_r, T * k)].set(
+                dw_sorted, mode="drop", unique_indices=True).reshape(T, k)
+    else:
+        dw = _rows_back(dw_sorted[:, None], inv.reshape(T, k), n_held)[..., 0]
     return dy, dw.astype(top_w.dtype), None, None, None
 
 
 share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
 
 
-def _share_rows_mlp(x, w1, w3, w2, top_w, order, inv, group_sizes, *, rows,
+def _share_rows_mlp(x, w1, w3, w2, top_w, order, group_sizes, *, rows,
                     activation):
     """The share's MLP over the first ``rows`` sorted rows."""
     n_held = jnp.sum(group_sizes)
@@ -252,6 +437,8 @@ def _share_rows_mlp(x, w1, w3, w2, top_w, order, inv, group_sizes, *, rows,
                                   preferred_element_type=x.dtype)
 
     with jax.named_scope("ds.moe.dispatch"):
+        inv = (None if share_walks_rows(rows, order.size)
+               else jnp.argsort(order).astype(jnp.int32))
         xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
     y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
     with jax.named_scope("ds.moe.combine"):
@@ -273,8 +460,8 @@ def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
     """
     held, assignments = w1.shape[0], top_idx.size
     with jax.named_scope("ds.moe.dispatch"):
-        order, inv, group_sizes = moe_share_permutation(top_idx, first_expert,
-                                                        held)
+        order, group_sizes = moe_share_permutation(top_idx, first_expert,
+                                                   held)
     rows_held = jnp.sum(group_sizes)
     bound = share_rows(assignments, held, num_experts)
 
@@ -284,7 +471,7 @@ def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
         return jax.checkpoint(functools.partial(
             _share_rows_mlp, rows=rows, activation=activation))
 
-    operands = (x, w1, w3, w2, top_w, order, inv, group_sizes)
+    operands = (x, w1, w3, w2, top_w, order, group_sizes)
     if bound >= assignments:
         return over(assignments)(*operands), rows_held, jnp.int32(0)
     fell_back = rows_held > bound
@@ -358,8 +545,8 @@ def moe_dense_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
     return jnp.einsum("te,teh->th", cw.astype(y.dtype), y).astype(x.dtype)
 
 
-from .registry import registry  # noqa: E402
-
+registry.register("moe_rows_to_tokens", "pallas", True,
+                  "a share's token-sorted rows summed into their tokens")
 registry.register("grouped_matmul", "xla", True,
                   "MoE grouped GEMM, FLOPs proportional to top-k (reference "
                   "cutlass_ops moe_gemm)")

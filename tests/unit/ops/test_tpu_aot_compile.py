@@ -340,6 +340,40 @@ def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
+def test_the_shares_rows_go_back_to_tokens_through_the_kernel_on_one_chip(
+        one_chip, no_compile_cache, monkeypatch):
+    """As above where the kernel runs (a TPU, one device): the branch over
+    the static 32,768 rows sums token-sorted rows in ``moe_rows_to_tokens``
+    (the combine's forward, also recomputed, and the dispatch's transpose);
+    the exact branch over all 131,072 keeps its gathers, and the grouped
+    matmuls are what they were."""
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_kernel_here", lambda: True)
+    monkeypatch.setattr(gm, "interpret_kernels", lambda: False)
+    T, HID, F, E, HELD, K = 32768, 2048, 1536, 64, 8, 4
+    sds = functools.partial(_sds, sharding=one_chip)
+    args = [sds((T, HID), jnp.bfloat16), sds((HELD, HID, F), jnp.bfloat16),
+            sds((HELD, HID, F), jnp.bfloat16), sds((HELD, F, HID), jnp.bfloat16),
+            sds((T, K), jnp.int32), sds((T, K), jnp.bfloat16)]
+
+    def loss(*a):
+        y, rows, fell = gm.moe_grouped_mlp_share(*a, first_expert=0, num_experts=E)
+        return jnp.sum(y.astype(jnp.float32) ** 2), (rows, fell)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 5), has_aux=True), *args)
+    names = _custom_call_names(compiled)
+    grouped = [n for n in names if n.startswith("ragged-dot") and "metadata" not in n]
+    assert len(grouped) == 2 * (3 + 3 + 6), names
+    kernels = [n for n in names if not n.startswith("ragged-dot")]
+    assert 2 <= len(kernels) <= 4 and all(
+        n.startswith("moe_rows_to_tokens") for n in kernels), names
+    # the kernel's blocks: 128 sorted rows of 2,048 in, 128 tokens out
+    call, = [line for line in _custom_calls(compiled)
+             if "%moe_rows_to_tokens" in line.split(" = ")[0]][:1]
+    assert "bf16[32768,2048]" in call
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
 def _custom_calls(compiled):
     """The compiled program's lines that call a Pallas kernel."""
     return [line for line in compiled.as_text().splitlines()
