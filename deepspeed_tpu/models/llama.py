@@ -151,6 +151,20 @@ class LlamaConfig:
     # Granite: x + residual_multiplier * sublayer(norm(x)) on both branches
     # of a layer_specs layer (None = 1)
     residual_multiplier: Optional[float] = None
+    # The training objective. "causal_lm": next-token CE under the causal
+    # mask. "block_diffusion" (BD3-LM's efficient form, SDAR's training):
+    # the model runs ONCE on ``xt ⊕ x0``, a noised copy of each document in
+    # positions [0, L) and the clean copy in [L, 2L), under the
+    # block-diffusion mask over blocks of ``diffusion_block_length`` tokens
+    # (``ops.attention.block_diffusion_mask``); only the noisy half reaches
+    # the head, whose logits at position i predict ``x0[i]`` itself, each
+    # masked token's CE weighted by 1/t of its block
+    # (``runtime/data_pipeline/block_diffusion.py`` makes the batch).
+    # ``diffusion_mask_id`` None = the vocabulary's last row
+    objective: str = "causal_lm"
+    diffusion_block_length: int = 4
+    diffusion_mask_id: Optional[int] = None
+    diffusion_t_min: float = 1e-3
     attn_impl: str = "auto"       # "auto" | "flash" (Pallas) | "xla"
     dtype: Any = jnp.bfloat16
     scan_layers: bool = False
@@ -183,6 +197,17 @@ class LlamaConfig:
     @property
     def experts_held_(self) -> int:
         return self.moe_experts_held or self.num_local_experts
+
+    @property
+    def block_diffusion_(self) -> bool:
+        if self.objective not in ("causal_lm", "block_diffusion"):
+            raise ValueError(f"unknown objective {self.objective!r}")
+        return self.objective == "block_diffusion"
+
+    @property
+    def diffusion_mask_id_(self) -> int:
+        return (self.vocab_size - 1 if self.diffusion_mask_id is None
+                else self.diffusion_mask_id)
 
     def per_layer_elements(self) -> int:
         """Analytic element count of one decoder layer (operator + MLP/MoE
@@ -419,17 +444,19 @@ class LlamaAttention(nn.Module):
                 k = apply_rope(k, cos, sin, positions, cfg.rotary_dim, cfg.rope_interleaved)
 
         # GQA handled natively by both paths (no materialized K/V head
-        # repeat — 4x K/V bandwidth saving at 8B scale). The Pallas flash
-        # kernel (fwd AND bwd, ops/attention.py) runs on TPU when the shape
-        # tiles cleanly and there's no padding mask; XLA's fused
-        # dot_product_attention otherwise.
+        # repeat — 4x K/V bandwidth saving at 8B scale). The Pallas kernels
+        # (fwd AND bwd, ops/attention.py) run on TPU when the shape tiles
+        # cleanly and the mask is one they know by its structure: causal,
+        # with or without a window, or the block-diffusion pattern. A mask
+        # given as an array (padding) takes XLA's fused
+        # dot_product_attention, as does every other case.
         from ..ops.attention import flash_attention
 
         mesh_shape = _mesh_shape()
         sp_sz = mesh_shape.get("seq", 1)
         one_device = all(n == 1 for n in mesh_shape.values())
 
-        # shared flash eligibility (shape/mask/positions); the sharded and
+        # shared kernel eligibility (shape/mask/positions); the sharded and
         # unsharded dispatch conditions below both build on it
         flash_shape_ok = (cfg.attn_impl != "xla" and attn_mask is None
                           and cfg.pos_embedding != "alibi"
@@ -439,7 +466,10 @@ class LlamaAttention(nn.Module):
         # of more than one device (data parallel and ZeRO included) the
         # sharded dispatch below owns the kernel path
         use_flash = flash_shape_ok and on_flash_backend and one_device
-        if use_flash:
+        if cfg.block_diffusion_:
+            attn = self._block_diffusion(q, k, v, attn_mask, window, sp_sz,
+                                         use_flash)
+        elif use_flash:
             # the Pallas kernel handles local (sliding-window) attention
             # natively, skipping out-of-window blocks
             attn = flash_attention(q, k, v, causal=True, scale=cfg.attn_scale,
@@ -515,6 +545,38 @@ class LlamaAttention(nn.Module):
         out = attn.reshape(b, s, nq * hd)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       cfg.attention_out_bias)(out)
+
+
+    def _block_diffusion(self, q, k, v, attn_mask, window, sp_sz, use_kernel):
+        """Attention over ``xt ⊕ x0`` under the block-diffusion mask: the
+        ``bdattn_*`` kernels on one TPU device, else XLA's attention under
+        the same mask built whole ([2L, 2L]: sizes a test or a data-parallel
+        mesh of short sequences holds)."""
+        from ..ops.attention import (block_diffusion_attention,
+                                     block_diffusion_mask)
+        cfg = self.config
+        s, blk = q.shape[1], cfg.diffusion_block_length
+        if (attn_mask is not None or window is not None
+                or cfg.pos_embedding == "alibi"
+                or cfg.attn_logit_softcapping is not None):
+            raise ValueError(
+                "the block-diffusion objective takes no padding mask, sliding "
+                "window, ALiBi or score softcapping: its mask is the pattern")
+        if s % 2 or (s // 2) % blk:
+            raise ValueError(
+                f"block diffusion runs on xt ⊕ x0, 2 * L positions with L a "
+                f"multiple of the block length {blk}: got {s}")
+        if sp_sz > 1:
+            raise ValueError(
+                "block-diffusion attention is not built for a mesh with a "
+                "'seq' axis: the Ulysses exchange splits the sequence the "
+                "pattern is defined on")
+        if use_kernel:
+            return block_diffusion_attention(q, k, v, blk, scale=cfg.attn_scale,
+                                             interpret=interpret_kernels())
+        mask = jnp.asarray(block_diffusion_mask(s // 2, blk))[None, None]
+        return jax.nn.dot_product_attention(q, k, v, mask=mask, is_causal=False,
+                                            scale=cfg.attn_scale)
 
 
 class ShortConvOperator(nn.Module):
@@ -912,7 +974,9 @@ class LlamaModel(nn.Module):
                  return_unembed=False):
         cfg = self.config
         if positions is None:
-            positions = jnp.arange(input_ids.shape[1])[None, :].astype(jnp.int32)
+            # block diffusion: both copies carry their tokens' own positions
+            n = input_ids.shape[1] // (2 if cfg.block_diffusion_ else 1)
+            positions = (jnp.arange(input_ids.shape[1]) % n)[None, :].astype(jnp.int32)
             positions = jnp.broadcast_to(positions, input_ids.shape)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          embedding_init=nn.with_partitioning(nn.initializers.normal(0.02),
@@ -968,6 +1032,10 @@ class LlamaModel(nn.Module):
             for i in range(cfg.num_hidden_layers):
                 x = layer_cls(cfg, i, name=f"layers_{i}")(x, cos, sin, positions,
                                                           attn_mask)
+        if cfg.block_diffusion_:
+            # the clean copy carries no loss: only the noisy half is normed
+            # and reaches the head
+            x = x[:, :x.shape[1] // 2]
         x = _make_norm(cfg, "norm")(x)
         if return_unembed:
             # chunked-CE path (ops/chunked_ce.py): hand back the raw unembed
@@ -998,8 +1066,16 @@ class LlamaModel(nn.Module):
         return logits
 
 
-def cross_entropy_loss(logits, labels, ignore_index: int = -100):
-    """Token-mean CE with shift-by-one (causal LM)."""
+def cross_entropy_loss(logits, labels, ignore_index: int = -100, weights=None):
+    """Token-mean CE with shift-by-one (causal LM). With ``weights`` [B, S]
+    (float) nothing is shifted: position s predicts ``labels[s]``, weighted,
+    and the sum is divided by all ``B * S`` positions (the same loss as
+    ``ops.chunked_ce.chunked_cross_entropy_loss`` with ``weights``)."""
+    if weights is not None:
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        return ((logz - gold) * weights.astype(jnp.float32)).sum() / labels.size
     logits = logits[:, :-1].astype(jnp.float32)
     targets = labels[:, 1:]
     mask = (targets != ignore_index).astype(jnp.float32)
@@ -1011,12 +1087,35 @@ def cross_entropy_loss(logits, labels, ignore_index: int = -100):
 
 
 class LlamaForCausalLM(nn.Module):
-    """Engine-contract wrapper: returns scalar loss when labels given."""
+    """Engine-contract wrapper: returns scalar loss when labels given.
+
+    Under ``objective == "block_diffusion"`` ``input_ids`` is ``xt ⊕ x0``
+    [rows, 2L], ``positions`` its position ids, ``labels`` the targets
+    ``x0`` [rows, L] and ``loss_weights`` [rows, L] the weight of each
+    (1/t of its block where ``xt`` holds the mask id, else 0): the loss is
+    ``sum(w * CE(logits_i, x0_i)) / (rows * L)``, unshifted; without labels
+    the noisy half's logits [rows, L, V] come back."""
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, labels=None, positions=None, attn_mask=None):
+    def __call__(self, input_ids, labels=None, positions=None, attn_mask=None,
+                 loss_weights=None):
         cfg = self.config
+        if cfg.block_diffusion_ and labels is not None:
+            if loss_weights is None:
+                raise ValueError("the block-diffusion loss needs loss_weights "
+                                 "(data_pipeline.block_diffusion makes them)")
+            masked = loss_weights > 0
+            for name, value in (
+                    ("tokens", jnp.float32(labels.size)),
+                    ("masked_tokens", masked.sum().astype(jnp.float32)),
+                    ("t_sum", jnp.where(masked, 1.0 / jnp.where(masked, loss_weights, 1.0),
+                                        0.0).sum().astype(jnp.float32))):
+                self.sow("diffusion_stats", name, value,
+                         reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.float32(0.0))
+        elif loss_weights is not None:
+            raise ValueError("loss_weights belong to the block-diffusion objective")
         if labels is not None and cfg.ce_chunk_size:
             from ..ops.chunked_ce import chunked_cross_entropy_loss
             x, w, b = LlamaModel(cfg, name="model")(input_ids, positions,
@@ -1028,12 +1127,12 @@ class LlamaForCausalLM(nn.Module):
                     x, w, b, labels, cfg.ce_chunk_size,
                     logit_scale=cfg.logit_scale,
                     softcap=cfg.final_logit_softcapping,
-                    compute_dtype=cfg.dtype)
+                    compute_dtype=cfg.dtype, weights=loss_weights)
         logits = LlamaModel(cfg, name="model")(input_ids, positions, attn_mask)
         if labels is None:
             return logits
         with jax.named_scope("ds.head.loss"):
-            return cross_entropy_loss(logits, labels)
+            return cross_entropy_loss(logits, labels, weights=loss_weights)
 
 
 def unbox_params(params):
@@ -1064,6 +1163,8 @@ def init_llama(config: LlamaConfig, seed: int = 0, seq_len: int = 8,
     jitted, the forward pass flax traces to shape the parameters is dead
     code, so it needs no kernel the host backend lacks."""
     model = LlamaForCausalLM(config)
+    if config.block_diffusion_:     # xt ⊕ x0 of one block
+        seq_len = 2 * config.diffusion_block_length
     ids = jnp.ones((1, seq_len), dtype=jnp.int32)
 
     def _init(key):

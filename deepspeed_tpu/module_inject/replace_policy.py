@@ -303,6 +303,54 @@ class OlmoePolicy(MixtralPolicy):
         return _mlp_experts_map(layer, num_experts)
 
 
+class SdarMoePolicy(MixtralPolicy):
+    """SDAR-MoE (JetLM ``modeling_sdar_moe.py``: Qwen3-MoE's blocks trained
+    by block diffusion): PRE-norm llama layers, GQA attention without biases
+    whose q and k are RMS-normalized over each head's ``head_dim`` (one
+    weight a projection, ``q_norm`` / ``k_norm``) before the rotary
+    embedding; every MLP a sparse MoE of ``num_experts`` SwiGLU experts
+    ``moe_intermediate_size`` wide, top-k of a softmax router, renormalized
+    where ``norm_topk_prob``; no shared expert, no router bias, untied head.
+    The objective is block diffusion over blocks of ``block_length`` tokens
+    (4, the released model's, where the config does not say). A chip's share
+    of the experts and of the vocabulary is the deployment's to set
+    (``moe_experts_held``, ``vocab_size``), not the checkpoint's."""
+    arch = "sdar_moe"
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        if hf_config.get("mlp_only_layers") or hf_config.get("decoder_sparse_step", 1) != 1:
+            raise ValueError("sdar_moe: variants mixing dense-MLP layers "
+                             "(mlp_only_layers/decoder_sparse_step) are not supported")
+        for key in ("attention_bias", "use_sliding_window", "rope_scaling"):
+            if hf_config.get(key):
+                raise ValueError(f"sdar_moe: {key}={hf_config[key]!r} is not supported")
+        cfg = HFCheckpointPolicy.config_from_hf(self, hf_config)
+        return dataclasses.replace(
+            cfg,
+            head_dim=hf_config.get("head_dim"),
+            intermediate_size=hf_config["moe_intermediate_size"],
+            qk_norm="head",
+            num_local_experts=hf_config.get("num_experts", 128),
+            num_experts_per_tok=hf_config.get("num_experts_per_tok", 8),
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            router_aux_loss_coef=hf_config.get("router_aux_loss_coef", 0.0),
+            objective="block_diffusion",
+            diffusion_block_length=int(hf_config.get("block_length", 4)),
+            diffusion_mask_id=hf_config.get("mask_token_id"))
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        out = super().weight_map(layer, attention_bias)
+        p = f"model.layers.{layer}.self_attn."
+        f = f"layers_{layer}/self_attn/"
+        out[p + "q_norm.weight"] = (f + "q_norm/weight", False)
+        out[p + "k_norm.weight"] = (f + "k_norm/weight", False)
+        return out
+
+    def moe_map(self, layer: int, num_experts: int):
+        return _mlp_experts_map(layer, num_experts)
+
+
 class Lfm2MoePolicy(HFCheckpointPolicy):
     """LFM2-MoE (HF ``modeling_lfm2_moe.py``): pre-norm layers
     (``operator_norm``, ``ffn_norm``) whose operator is, by ``layer_types``,
@@ -1577,6 +1625,8 @@ _POLICIES = {
     "MixtralForCausalLM": MixtralPolicy,
     "olmoe": OlmoePolicy,
     "OlmoeForCausalLM": OlmoePolicy,
+    "sdar_moe": SdarMoePolicy,
+    "SDARMoeForCausalLM": SdarMoePolicy,
     "lfm2_moe": Lfm2MoePolicy,
     "Lfm2MoeForCausalLM": Lfm2MoePolicy,
     "granitemoehybrid": GraniteMoeHybridPolicy,

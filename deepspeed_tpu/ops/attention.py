@@ -28,6 +28,11 @@ nothing: the swept operand's index map is clamped to the live range, so a
 dead step names the block already resident. Blocks come from the shape
 (``kernel_dispatch.choose_blocks``: 1024 folded query rows a step at any
 group, and 512 keys or as many as the queries, where the sequences allow).
+
+A third structured mask has kernels of its own, under names of their own:
+block-diffusion training's (``block_diffusion_attention``: ``bdattn_fwd``,
+``bdattn_bwd``; the section at the end of this file says how its tiles are
+found live, interior or dead).
 """
 
 import functools
@@ -55,8 +60,11 @@ def softcap_scores(s, cap):
     return cap * jnp.tanh(s / cap)
 
 
-def _xla_attention(q, k, v, scale, causal, window=None, softcap=None):
-    """Reference implementation; q [B, S, H, D], k/v [B, S, KV, D] (GQA ok)."""
+def _xla_attention(q, k, v, scale, causal, window=None, softcap=None,
+                   mask=None):
+    """Reference implementation; q [B, S, H, D], k/v [B, S, KV, D] (GQA ok).
+    ``mask`` [Sq, Sk] bool: a structured pattern given literally (True = the
+    query sees the key), beside or in place of ``causal`` / ``window``."""
     B, Sq, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -66,12 +74,14 @@ def _xla_attention(q, k, v, scale, causal, window=None, softcap=None):
         s = softcap * jnp.tanh(s / softcap)
     if causal or window is not None:
         n, m = q.shape[1], k.shape[1]
-        mask = jnp.ones((n, m), bool)
+        keep = jnp.ones((n, m), bool)
         if causal:
-            mask &= jnp.tril(mask, k=m - n)
+            keep &= jnp.tril(keep, k=m - n)
         if window is not None:
             qpos = jnp.arange(n)[:, None] + (m - n)
-            mask &= qpos - jnp.arange(m)[None, :] < window
+            keep &= qpos - jnp.arange(m)[None, :] < window
+        mask = keep if mask is None else keep & mask
+    if mask is not None:
         s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
@@ -777,6 +787,406 @@ def flash_attention(q,
     bwd_dec = _fit_blocks(bwd_dec, q.shape[1], k.shape[1])
     return _flash_attention_call(q, k, v, scale, causal, window,
                                  softcap, interpret, fwd_dec, bwd_dec)
+
+
+# ---------------------------------------------------------------------------
+# block-diffusion attention (BD3-LM / SDAR training): the noisy copy and the
+# clean copy of a document in one sequence of 2L positions
+# ---------------------------------------------------------------------------
+#
+# Positions [0, L) hold the noised copy, [L, 2L) the clean one, blocks of
+# ``block_length`` tokens. Query i sees key j iff
+#   noisy -> noisy:  blk(i) == blk(j)   (its own block, both ways)
+#   noisy -> clean:  blk(j) <  blk(i)   (strictly earlier clean blocks)
+#   clean -> clean:  blk(j) <= blk(i)   (block-causal)
+#   clean -> noisy:  never
+# so L^2 + L * block_length of the (2L)^2 pairs are live. The kernels never
+# see the noisy keys but on each query tile's own diagonal: a grid step
+# holds BOTH copies' query rows of one tile of L (noisy rows first), takes
+# the noisy rows against the tile's own noisy keys once (step 0 of its
+# sweep, a [G * BQ, BQ] product under the own-block mask), and then sweeps
+# the clean key tiles up to the diagonal for all 2 * G * BQ rows at once:
+# the two copies share every clean K/V tile they read, tiles past the
+# diagonal are skipped with their copies (the index map is clamped, as in
+# the causal kernel), tiles wholly before it run without the element mask,
+# and the diagonal tile masks noisy rows by ``<`` and clean rows by ``<=``.
+
+
+def block_diffusion_mask(seq: int, block_length: int) -> np.ndarray:
+    """[2 * seq, 2 * seq] bool, True where the query (row) sees the key: the
+    four rules above, literally."""
+    i = np.arange(2 * seq)[:, None]
+    j = np.arange(2 * seq)[None, :]
+    q_clean, k_clean = i >= seq, j >= seq
+    q_blk, k_blk = (i % seq) // block_length, (j % seq) // block_length
+    return np.where(q_clean, k_clean & (k_blk <= q_blk),
+                    np.where(k_clean, k_blk < q_blk, k_blk == q_blk))
+
+
+def block_diffusion_live_tiles(seq: int, block_q: int, block_k: int) -> tuple:
+    """(live, interior, all) grid steps of one KV head's sweep over ``seq``
+    data tokens: the own-block step of every query tile and the clean key
+    tiles at or before its diagonal are live, those wholly before it
+    interior (no element mask)."""
+    num_q, num_k = seq // block_q, seq // block_k
+    live = interior = 0
+    for i in range(num_q):
+        live += 1 + sum(j * block_k < (i + 1) * block_q for j in range(num_k))
+        interior += sum((j + 1) * block_k <= i * block_q for j in range(num_k))
+    return live, interior, num_q * (1 + num_k)
+
+
+def _bd_regroup(q, k, v, seq):
+    """q [B, 2L, H, D] -> [B*KV, 2, G, L, D] (the copy, then the head of the
+    group, then the position); k, v [B, 2L, KV, D] -> [B*KV, 2L, D]."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    qg = (q.reshape(B, 2, seq, KV, G, D).transpose(0, 3, 1, 4, 2, 5)
+          .reshape(B * KV, 2, G, seq, D))
+    kt = k.transpose(0, 2, 1, 3).reshape(B * KV, S, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * KV, S, D)
+    return qg, kt, vt
+
+
+def _bd_ungroup(x, B, KV):
+    """[B*KV, 2, G, L, D] -> [B, 2L, H, D]."""
+    _, _, G, seq, D = x.shape
+    return (x.reshape(B, KV, 2, G, seq, D).transpose(0, 2, 4, 1, 3, 5)
+            .reshape(B, 2 * seq, KV * G, D))
+
+
+def _bd_own_block(q_loc, k_loc, block_length):
+    """True where the key's tile-local position lies in the query's block."""
+    own = q_loc - q_loc % block_length
+    return (k_loc >= own) & (k_loc < own + block_length)
+
+
+def _bd_clean_limit(row, q0, block_q, half, block_length):
+    """The first clean key position a query row may NOT see: the start of
+    its own block for a noisy row (``row < half``), its end for a clean one.
+    Rows are copy-major, then head, then position."""
+    q_loc = row % block_q
+    return (q0 + q_loc - q_loc % block_length
+            + jnp.where(row >= half, block_length, 0))
+
+
+def _bd_clean_step(t, qi, block_q, block_k, compute):
+    """Step ``t >= 1`` of a query tile's sweep is clean key tile ``t - 1``:
+    run ``compute(masked)`` if it is live, without the mask if interior."""
+    k0, q0 = (t - 1) * block_k, qi * block_q
+    live = (t >= 1) & (k0 < q0 + block_q)
+    interior = k0 + block_k <= q0
+
+    @pl.when(live & interior)
+    def _():
+        compute(masked=False)
+
+    @pl.when(live & jnp.logical_not(interior))
+    def _():
+        compute(masked=True)
+
+
+def _bd_clean_tile(i, t, block_q, block_k):
+    """Index-map clamp of the swept clean key tile to the live range of
+    query tile ``i`` (dead steps, and step 0, name a tile already there)."""
+    return jnp.minimum(jnp.maximum(t - 1, 0), (i * block_q + block_q - 1) // block_k)
+
+
+def _bd_fwd_kernel(q_ref, kd_ref, vd_ref, kc_ref, vc_ref, o_ref, lse_ref, acc,
+                   m_s, l_s, *, scale, block_length, block_q, block_k, steps):
+    qi, t = pl.program_id(1), pl.program_id(2)
+    g, bq, d = q_ref.shape[2:]
+    half = g * bq
+
+    def update(rows, s, v):
+        # every row's first tile holds a key it sees (its own block, or
+        # clean block 0), so m is a real number from then on and a masked
+        # score's exp underflows to 0: no guards
+        m_prev = m_s[rows]
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_cur, s.shape[1]))
+        corr = jnp.exp(m_prev - m_cur)
+        l_s[rows] = l_s[rows] * corr + p.sum(axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc[rows] = acc[rows] * _lanes(corr, d) + pv
+        m_s[rows] = m_cur
+
+    @pl.when(t == 0)
+    def _own_block():
+        acc[:] = jnp.zeros_like(acc)
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        qn = q_ref[0, 0].reshape(half, d)
+        s = jax.lax.dot_general(qn, kd_ref[0], (((1, ), (1, )), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        q_loc = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % block_q
+        k_loc = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(_bd_own_block(q_loc, k_loc, block_length), s, NEG_INF)
+        update(slice(0, half), s, vd_ref[0])
+
+    def _clean(masked):
+        q = q_ref[0].reshape(2 * half, d)
+        s = jax.lax.dot_general(q, kc_ref[0], (((1, ), (1, )), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = (t - 1) * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos < _bd_clean_limit(row, qi * block_q, block_q,
+                                                  half, block_length),
+                          s, NEG_INF)
+        update(slice(None), s, vc_ref[0])
+
+    _bd_clean_step(t, qi, block_q, block_k, _clean)
+
+    @pl.when(t == steps - 1)
+    def _finalize():
+        l = l_s[:]
+        o_ref[0] = (acc[:] / _lanes(l, d)).reshape(2, g, bq, d).astype(o_ref.dtype)
+        lse_ref[0] = (m_s[:] + jnp.log(l))[:, :1].reshape(2, g, bq, 1)
+
+
+def _bd_specs(G, D, seq, block_q, block_k):
+    """BlockSpecs of the (KV head, query tile, step) grid: both copies' rows
+    of a query tile, the tile's own noisy keys, the swept clean key tile."""
+    q_spec = pl.BlockSpec((1, 2, G, block_q, D), lambda b, i, t: (b, 0, 0, i, 0))
+    own_spec = pl.BlockSpec((1, block_q, D), lambda b, i, t: (b, i, 0))
+    clean_spec = pl.BlockSpec(
+        (1, block_k, D),
+        lambda b, i, t: (b, seq // block_k + _bd_clean_tile(i, t, block_q, block_k), 0))
+    return q_spec, own_spec, clean_spec
+
+
+def _bd_check(q, k, block_length, block_q, block_k):
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    seq = S // 2
+    if S % 2 or seq % block_length:
+        raise ValueError(f"block-diffusion attention wants 2 * L positions, L a "
+                         f"multiple of the block length {block_length}: got {S}")
+    if (seq % block_q or seq % block_k or block_q % block_length
+            or block_k % block_length):
+        raise ValueError(
+            f"block-diffusion tiles ({block_q} queries, {block_k} keys) must "
+            f"divide L = {seq} and be multiples of the block length "
+            f"{block_length}: a tile may not straddle the copies' border or "
+            f"cut a block")
+    return B, seq, H, KV, H // KV, D
+
+
+def _bd_flash_fwd(q, k, v, scale, block_length, block_q, block_k, interpret):
+    """-> (o [B, 2L, H, D], lse [B*KV, 2, G, L, 1])."""
+    from .kernel_dispatch import bdattn_vmem_bytes
+    B, seq, H, KV, G, D = _bd_check(q, k, block_length, block_q, block_k)
+    steps = 1 + seq // block_k
+    qg, kt, vt = _bd_regroup(q, k, v, seq)
+    q_spec, own_spec, clean_spec = _bd_specs(G, D, seq, block_q, block_k)
+    rows = 2 * G * block_q
+    out, lse = pl.pallas_call(
+        functools.partial(_bd_fwd_kernel, scale=scale, block_length=block_length,
+                          block_q=block_q, block_k=block_k, steps=steps),
+        grid=(B * KV, seq // block_q, steps),
+        in_specs=[q_spec, own_spec, own_spec, clean_spec, clean_spec],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, 2, G, block_q, 1),
+                                lambda b, i, t: (b, 0, 0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct(qg.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B * KV, 2, G, seq, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                        pltpu.VMEM((rows, STAT_LANES), jnp.float32),
+                        pltpu.VMEM((rows, STAT_LANES), jnp.float32)],
+        compiler_params=_compiler_params(bdattn_vmem_bytes(
+            "fwd", G, D, q.dtype.itemsize, block_q, block_k, seq)),
+        interpret=interpret,
+        name="bdattn_fwd",
+    )(qg, kt, vt, kt, vt)
+    return _bd_ungroup(out, B, KV), lse
+
+
+def _bd_bwd_kernel(q_ref, kd_ref, vd_ref, kc_ref, vc_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dkn_ref, dvn_ref, dkc_ref, dvc_ref, dq_acc,
+                   dkc_acc, dvc_acc, *, scale, block_length, block_q, block_k,
+                   steps, num_q):
+    """dQ, dK and dV from one walk over the live score tiles, TRANSPOSED
+    (``k . q^T``, as ``_dkdv_kernel``): a query tile's dQ accumulates over
+    its sweep; its own noisy keys' dK/dV are whole after step 0 (no other
+    tile sees them) and written there; the clean keys' accumulate in float32
+    in VMEM over all query tiles, [L / BK, BK, D] each, and the last query
+    tile's sweep, in which every clean tile is live, writes them out."""
+    qi, t = pl.program_id(1), pl.program_id(2)
+    g, bq, d = q_ref.shape[2:]
+    half = g * bq
+
+    def tile_grads(k, v, q, do, lse, delta, keep):
+        """-> (dv, dk, dq) of one transposed tile: s = k q^T masked by
+        ``keep`` (None: interior)."""
+        s = jax.lax.dot_general(k, q, (((1, ), (1, )), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if keep is not None:
+            s = jnp.where(keep(s.shape), s, NEG_INF)
+        p = jnp.exp(s - lse)        # a masked score underflows to 0
+        dv = jax.lax.dot_general(p.astype(do.dtype), do, (((1, ), (0, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, (((1, ), (1, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        dk = jax.lax.dot_general(ds, q, (((1, ), (0, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dq = jax.lax.dot_general(ds, k, (((0, ), (0, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return dv, dk, dq
+
+    @pl.when((qi == 0) & (t == 0))
+    def _init_clean():
+        dkc_acc[:] = jnp.zeros_like(dkc_acc)
+        dvc_acc[:] = jnp.zeros_like(dvc_acc)
+
+    @pl.when(t == 0)
+    def _own_block():
+        def keep(shape):
+            k_loc = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            q_loc = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % block_q
+            return _bd_own_block(q_loc, k_loc, block_length)
+
+        dv, dk, dq = tile_grads(
+            kd_ref[0], vd_ref[0], q_ref[0, 0].reshape(half, d),
+            do_ref[0, 0].reshape(half, d), lse_ref[0, 0][:, :half],
+            delta_ref[0, 0][:, :half], keep)
+        dvn_ref[0] = dv.astype(dvn_ref.dtype)
+        dkn_ref[0] = dk.astype(dkn_ref.dtype)
+        dq_acc[:half] = dq
+        dq_acc[half:] = jnp.zeros((half, d), dq_acc.dtype)
+
+    def _clean(masked):
+        def keep(shape):
+            k_pos = (t - 1) * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            return k_pos < _bd_clean_limit(row, qi * block_q, block_q, half,
+                                           block_length)
+
+        dv, dk, dq = tile_grads(
+            kc_ref[0], vc_ref[0], q_ref[0].reshape(2 * half, d),
+            do_ref[0].reshape(2 * half, d), lse_ref[0, 0], delta_ref[0, 0],
+            keep if masked else None)
+        dvc_acc[t - 1] += dv
+        dkc_acc[t - 1] += dk
+        dq_acc[:] += dq
+
+    _bd_clean_step(t, qi, block_q, block_k, _clean)
+
+    @pl.when(t == steps - 1)
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[:].reshape(2, g, bq, d).astype(dq_ref.dtype)
+
+    @pl.when((qi == num_q - 1) & (t >= 1))
+    def _finalize_clean():
+        dkc_ref[0] = dkc_acc[t - 1].astype(dkc_ref.dtype)
+        dvc_ref[0] = dvc_acc[t - 1].astype(dvc_ref.dtype)
+
+
+def _bd_flash_bwd(res, g_out, scale, block_length, block_q, block_k, interpret):
+    from .kernel_dispatch import bdattn_vmem_bytes
+    q, k, v, o, lse = res
+    B, seq, H, KV, G, D = _bd_check(q, k, block_length, block_q, block_k)
+    num_q, num_k = seq // block_q, seq // block_k
+    steps = 1 + num_k
+    qg, kt, vt = _bd_regroup(q, k, v, seq)
+    dog, _, _ = _bd_regroup(g_out, k, v, seq)
+    og, _, _ = _bd_regroup(o, k, v, seq)
+    delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1,
+                    keepdims=True)                      # [B*KV, 2, G, L, 1]
+
+    def rows(x):
+        """-> [B*KV, q tiles, 1, 2 * G * BQ]: the columns of a transposed
+        score tile, in the order of the folded query rows."""
+        return (x.reshape(B * KV, 2, G, num_q, block_q).transpose(0, 3, 1, 2, 4)
+                .reshape(B * KV, num_q, 1, 2 * G * block_q))
+
+    q_spec, own_spec, clean_spec = _bd_specs(G, D, seq, block_q, block_k)
+    r_spec = pl.BlockSpec((1, 1, 1, 2 * G * block_q), lambda b, i, t: (b, i, 0, 0))
+    # a clean tile's dK/dV are whole, and written, in the last query tile's
+    # sweep; until then the map names tile 0, which that sweep writes first
+    dclean_spec = pl.BlockSpec(
+        (1, block_k, D),
+        lambda b, i, t: (b, jnp.where(i == num_q - 1, jnp.maximum(t - 1, 0), 0), 0))
+    half_kv = jax.ShapeDtypeStruct((B * KV, seq, D), k.dtype)
+    dq, dkn, dvn, dkc, dvc = pl.pallas_call(
+        functools.partial(_bd_bwd_kernel, scale=scale, block_length=block_length,
+                          block_q=block_q, block_k=block_k, steps=steps,
+                          num_q=num_q),
+        grid=(B * KV, num_q, steps),
+        in_specs=[q_spec, own_spec, own_spec, clean_spec, clean_spec, q_spec,
+                  r_spec, r_spec],
+        out_specs=[q_spec, own_spec, own_spec, dclean_spec, dclean_spec],
+        out_shape=[jax.ShapeDtypeStruct(qg.shape, q.dtype), half_kv, half_kv,
+                   half_kv, half_kv],
+        scratch_shapes=[pltpu.VMEM((2 * G * block_q, D), jnp.float32),
+                        pltpu.VMEM((num_k, block_k, D), jnp.float32),
+                        pltpu.VMEM((num_k, block_k, D), jnp.float32)],
+        compiler_params=_compiler_params(bdattn_vmem_bytes(
+            "bwd", G, D, q.dtype.itemsize, block_q, block_k, seq)),
+        interpret=interpret,
+        name="bdattn_bwd",
+    )(qg, kt, vt, kt, vt, dog, rows(lse), rows(delta))
+
+    def natural(noisy, clean):
+        return (jnp.concatenate([noisy, clean], axis=1)
+                .reshape(B, KV, 2 * seq, D).transpose(0, 2, 1, 3))
+
+    return _bd_ungroup(dq, B, KV), natural(dkn, dkc), natural(dvn, dvc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _bd_attention(q, k, v, scale, block_length, fwd_blocks, bwd_blocks, interpret):
+    return _bd_flash_fwd(q, k, v, scale, block_length, *fwd_blocks, interpret)[0]
+
+
+def _bd_fwd_rule(q, k, v, scale, block_length, fwd_blocks, bwd_blocks, interpret):
+    o, lse = _bd_flash_fwd(q, k, v, scale, block_length, *fwd_blocks, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _bd_bwd_rule(scale, block_length, fwd_blocks, bwd_blocks, interpret, res, g):
+    return _bd_flash_bwd(res, g, scale, block_length, *bwd_blocks, interpret)
+
+
+_bd_attention.defvjp(_bd_fwd_rule, _bd_bwd_rule)
+# its own jit frame, so that the calls read ``%bdattn_fwd.N`` /
+# ``%bdattn_bwd.N`` under ``jax.grad`` (see ``_flash_attention_call``)
+_bd_attention_call = jax.jit(_bd_attention, static_argnums=(3, 4, 5, 6, 7))
+
+
+def block_diffusion_attention(q, k, v, block_length: int,
+                              scale: Optional[float] = None,
+                              blocks_fwd: Optional[tuple] = None,
+                              blocks_bwd: Optional[tuple] = None,
+                              force_pallas: Optional[bool] = None,
+                              interpret: bool = False):
+    """Attention under the block-diffusion training mask; q [B, 2L, H, D],
+    k/v [B, 2L, KV, D] (GQA native), the noised copy of each sequence in
+    positions [0, L) and the clean copy in [L, 2L).
+
+    On a TPU (or with ``interpret=True`` anywhere) the kernels ``bdattn_fwd``
+    and ``bdattn_bwd`` run, with (query, key) tiles from
+    ``kernel_dispatch.choose_block_diffusion_blocks`` unless given; a shape
+    they cannot tile, or whose clean keys' float32 dK and dV pass the
+    backward's VMEM cap, is refused with the reason. Elsewhere the XLA path
+    runs both legs under ``block_diffusion_mask``."""
+    from . import kernel_dispatch as kd
+
+    scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
+    seq = q.shape[1] // 2
+    if not (use_pallas(force_pallas) or interpret):
+        return _xla_attention(q, k, v, scale, False,
+                              mask=jnp.asarray(block_diffusion_mask(seq, block_length)))
+    sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, False, None, None,
+                      pattern=f"bd{block_length}")
+    fwd = tuple(blocks_fwd or kd.choose_block_diffusion_blocks(sig, "fwd", block_length))
+    bwd = tuple(blocks_bwd or kd.choose_block_diffusion_blocks(sig, "bwd", block_length))
+    return _bd_attention_call(q, k, v, scale, int(block_length), fwd, bwd, interpret)
+
 
 
 registry.register("flash_attention", "pallas", True)

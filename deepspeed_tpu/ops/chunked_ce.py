@@ -237,23 +237,34 @@ def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
                                ignore_index: int = -100,
                                logit_scale: Optional[float] = None,
                                softcap: Optional[float] = None,
-                               compute_dtype=jnp.bfloat16):
+                               compute_dtype=jnp.bfloat16, weights=None):
     """Token-mean causal-LM CE (shift-by-one, ignore_index) over a streamed
     unembed — drop-in for ``models.llama.cross_entropy_loss`` fed hidden
     states instead of logits. ``x`` [B, S, H], ``labels`` [B, S]; the
-    transient logits hold at most ``B * S * chunk`` elements."""
+    transient logits hold at most ``B * S * chunk`` elements.
+
+    With ``weights`` [B, S] (float) nothing is shifted: position s predicts
+    ``labels[s]`` itself, its term is multiplied by ``weights[s]`` (a label
+    under a zero weight may be anything in range), and the sum is divided by
+    all ``B * S`` positions: a masked-diffusion objective's ``1/t``-weighted
+    loss. The same one sweep forms loss and gradients."""
     S = x.shape[1]
     sc = seq_chunk(S, chunk, w.shape[1])
-    # the shift as shifted targets: position s predicts labels[s + 1], the
-    # last position predicts nothing (weight 0), and S stays whole; so does a
-    # tail padded up to a multiple of sc
     pad = -S % sc
-    tg = jnp.pad(labels[:, 1:], ((0, 0), (0, 1 + pad)),
-                 constant_values=ignore_index)
+    if weights is None:
+        # the shift as shifted targets: position s predicts labels[s + 1],
+        # the last position predicts nothing (weight 0), and S stays whole;
+        # so does a tail padded up to a multiple of sc
+        tg = jnp.pad(labels[:, 1:], ((0, 0), (0, 1 + pad)),
+                     constant_values=ignore_index)
+        mask = (tg != ignore_index).astype(jnp.float32)
+        tg = jnp.where(tg == ignore_index, 0, tg)
+        inv_n = 1.0 / jnp.maximum(mask.sum(), 1.0)
+    else:
+        tg = jnp.pad(labels, ((0, 0), (0, pad)))
+        mask = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pad)))
+        inv_n = jnp.float32(1.0 / (x.shape[0] * S))
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    mask = (tg != ignore_index).astype(jnp.float32)
-    tg = jnp.where(tg == ignore_index, 0, tg)
-    inv_n = 1.0 / jnp.maximum(mask.sum(), 1.0)
     return _mean_ce(x, w, bias, tg, mask, inv_n, sc, logit_scale, softcap,
                     compute_dtype)

@@ -428,21 +428,51 @@ share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
 
 
 def _share_rows_mlp(x, w1, w3, w2, top_w, order, group_sizes, *, rows,
-                    activation):
-    """The share's MLP over the first ``rows`` sorted rows."""
+                    activation, by_rows=None):
+    """The share's MLP over the first ``rows`` sorted rows (``by_rows``:
+    whether they go back to their tokens by the rows; by default as
+    :func:`share_walks_rows` says for ``rows`` of all of ``order``)."""
     n_held = jnp.sum(group_sizes)
 
     def gmm(lhs, w):
         return jax.lax.ragged_dot(lhs, w, group_sizes,
                                   preferred_element_type=x.dtype)
 
+    if by_rows is None:
+        by_rows = share_walks_rows(rows, order.size)
     with jax.named_scope("ds.moe.dispatch"):
-        inv = (None if share_walks_rows(rows, order.size)
-               else jnp.argsort(order).astype(jnp.int32))
+        inv = None if by_rows else jnp.argsort(order).astype(jnp.int32)
         xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
     y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
     with jax.named_scope("ds.moe.combine"):
         return share_combine(y, top_w, order[:rows], inv, n_held)
+
+
+def _share_all_rows_mlp(x, w1, w3, w2, top_w, order, group_sizes, *, window,
+                        activation):
+    """The exact pass where the rows held outran the static prefix: the
+    sorted rows ``window`` at a time, each window the prefix's program on
+    its own rows and its own part of every expert's group, the windows'
+    results summed in float32. The branch is hardly ever taken, and XLA
+    reserves a ``cond``'s memory for its larger branch: walked whole, all
+    ``T * k`` rows at once, this one held 3.6 GB that no step used at 262,144
+    assignments of 2,048 values (v5e compile, PR 37); in windows it holds
+    what the prefix's branch does."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    padded = jnp.pad(order, (0, -order.size % window))  # past n_held: not read
+
+    def one_window(y, lo):
+        sizes = jnp.clip(ends, lo, lo + window) - jnp.clip(starts, lo, lo + window)
+        rows = jax.lax.dynamic_slice(padded, (lo, ), (window, ))
+        part = jax.checkpoint(functools.partial(
+            _share_rows_mlp, rows=window, activation=activation, by_rows=True))(
+                x, w1, w3, w2, top_w, rows, sizes)
+        return y + part.astype(jnp.float32), None
+
+    y, _ = jax.lax.scan(one_window, jnp.zeros(x.shape, jnp.float32),
+                        jnp.arange(0, padded.size, window, dtype=jnp.int32))
+    return y.astype(x.dtype)
 
 
 def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
@@ -475,7 +505,10 @@ def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
     if bound >= assignments:
         return over(assignments)(*operands), rows_held, jnp.int32(0)
     fell_back = rows_held > bound
-    y = jax.lax.cond(fell_back, over(assignments), over(bound), *operands)
+    y = jax.lax.cond(fell_back,
+                     functools.partial(_share_all_rows_mlp, window=bound,
+                                       activation=activation),
+                     over(bound), *operands)
     return y, rows_held, fell_back.astype(jnp.int32)
 
 

@@ -33,6 +33,7 @@ signature and a VMEM estimate of the leg's tiles (1024 folded query rows a
 step at any GQA group, and 512 keys or as many as the queries).
 """
 
+import math
 import os
 from typing import NamedTuple, Optional
 
@@ -67,6 +68,9 @@ class ShapeSig(NamedTuple):
     causal: bool
     windowed: bool
     softcapped: bool
+    # a structured mask that is neither causal nor a window: "bd<B>" =
+    # block-diffusion training over blocks of B tokens (its own kernels)
+    pattern: str = ""
 
 
 class Decision(NamedTuple):
@@ -79,13 +83,13 @@ class Decision(NamedTuple):
 
 
 def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
-             window, softcap) -> ShapeSig:
+             window, softcap, pattern: str = "") -> ShapeSig:
     b, sq, h, d = q_shape
     return ShapeSig(batch=int(b), seq_q=int(sq), seq_k=int(seq_k),
                     heads=int(h), kv_heads=int(kv_heads), head_dim=int(d),
                     dtype=str(dtype), causal=bool(causal),
                     windowed=window is not None,
-                    softcapped=softcap is not None)
+                    softcapped=softcap is not None, pattern=pattern)
 
 
 def signature(leg: str, sig: ShapeSig, device_kind: str) -> str:
@@ -95,7 +99,7 @@ def signature(leg: str, sig: ShapeSig, device_kind: str) -> str:
     return (f"{leg}|{device_kind}|b{sig.batch}|sq{sig.seq_q}|sk{sig.seq_k}"
             f"|h{sig.heads}|kv{sig.kv_heads}|d{sig.head_dim}|{sig.dtype}"
             f"|c{int(sig.causal)}|w{int(sig.windowed)}"
-            f"|sc{int(sig.softcapped)}")
+            f"|sc{int(sig.softcapped)}" + (f"|p{sig.pattern}" if sig.pattern else ""))
 
 
 def device_kind() -> str:
@@ -231,6 +235,78 @@ def fused_vmem_bytes(sig: ShapeSig) -> int:
     return flash_vmem_bytes("fused", max(1, sig.heads // sig.kv_heads),
                             sig.head_dim, 4 if "32" in sig.dtype else 2,
                             *choose_blocks(sig, "fused"), seq_q=sig.seq_q)
+
+
+# Block-diffusion attention (``ops/attention.py``, ``bdattn_fwd`` /
+# ``bdattn_bwd``): a grid step holds BOTH copies' query rows of one tile of
+# the L data tokens, 2 * group * block_q folded rows, against one tile of
+# clean keys. Both legs aim at FUSED_MAX_ROWS rows and KEY_BLOCK keys and
+# carry their own VMEM limit, as the fused causal backward does (v5e sweep at
+# 2 x 16,384 positions, 32 heads of 128 in groups of 8, PR 37: forward 19.5
+# to 20.4 ms from 1,024 rows up and any key tile, 22.5 ms at 512 rows;
+# forward + backward 55.9 to 58.8 ms over seven tile pairs, this one within
+# 1% of the best). The backward holds the float32 dK
+# and dV of one KV head's L clean keys in VMEM (no group factor: 8 MiB at
+# L 8,192 and head 128, where the causal fused kernel's dQ would be 64 MiB at
+# group 8), and its estimate is held to FUSED_VMEM_CAP_BYTES.
+
+
+def bdattn_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
+                      block_q: int, block_k: int, seq: int) -> int:
+    """Upper estimate of the VMEM one grid step of the block-diffusion
+    kernels holds, counted as ``flash_vmem_bytes`` counts: ``leg`` "fwd" or
+    "bwd" (which adds the clean keys' float32 dK and dV over ``seq``)."""
+    rows = 2 * group * block_q
+    lanes = max(head_dim, 128)
+    q_blk = rows * lanes * itemsize
+    own_blk = block_q * lanes * itemsize
+    kv_blk = block_k * lanes * itemsize
+    tile = rows * block_k
+    if leg == "fwd":
+        blocks = 2 * q_blk + 2 * own_blk + 2 * kv_blk + rows * 128 * 4
+        scratch = rows * lanes * 4 + 2 * rows * 128 * 4
+        temps = tile * (2 * 4 + itemsize)
+    else:
+        # q, do, dq; own k, v, dk, dv; clean k, v, dk, dv; lse, delta rows
+        blocks = 3 * q_blk + 4 * own_blk + 4 * kv_blk + 2 * 8 * rows * 4
+        scratch = rows * lanes * 4 + 2 * seq * lanes * 4
+        temps = tile * (3 * 4 + 3 * itemsize)
+    return 2 * blocks + scratch + temps
+
+
+def _pattern_block(seq: int, cap: int, block_length: int) -> int:
+    """The largest tile of at most ``cap`` positions that divides ``seq``
+    and holds whole blocks of ``block_length``; multiples of 128 first, then
+    of 8, then any (a short test sequence)."""
+    for lane in (128, 8, 1):
+        step = math.lcm(block_length, lane)
+        for b in range(min(cap, seq) // step * step, 0, -step):
+            if seq % b == 0:
+                return b
+    return seq
+
+
+def choose_block_diffusion_blocks(sig: ShapeSig, leg: str,
+                                  block_length: int) -> tuple:
+    """(block_q, block_k) of ``bdattn_fwd`` ("fwd") or ``bdattn_bwd``
+    ("bwd") over the L = seq_q / 2 data tokens of ``sig``. Raises where the
+    backward's estimate passes FUSED_VMEM_CAP_BYTES: there is no two-pass
+    pair for this pattern."""
+    group = max(1, sig.heads // sig.kv_heads)
+    seq = sig.seq_q // 2
+    itemsize = 4 if "32" in sig.dtype else 2
+    bq = _pattern_block(seq, max(8, FUSED_MAX_ROWS // (2 * group)), block_length)
+    bk = _pattern_block(seq, KEY_BLOCK, block_length)
+    if leg == "bwd":
+        need = bdattn_vmem_bytes("bwd", group, sig.head_dim, itemsize, bq, bk, seq)
+        if need > FUSED_VMEM_CAP_BYTES:
+            raise ValueError(
+                f"block-diffusion backward at L = {seq}, head {sig.head_dim}: "
+                f"the float32 dK and dV of the clean keys put its VMEM "
+                f"estimate at {need >> 20} MiB, over the cap of "
+                f"{FUSED_VMEM_CAP_BYTES >> 20} MiB; no two-pass backward "
+                f"exists for this pattern (shorten the sequence)")
+    return bq, bk
 
 
 def _largest_block(seq: int, cap: int) -> int:

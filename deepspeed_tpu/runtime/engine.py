@@ -92,8 +92,12 @@ def _as_apply_fns(model):
             # "ssm_stats" is the same contract for a state-space mixer:
             # ``state_absmax`` (the largest |S| a layer's scan held) comes
             # back as the largest over the layers, ``dt_mean`` as their mean
+            # "diffusion_stats" for the block-diffusion objective: the
+            # batch's data ``tokens``, its ``masked_tokens`` and the sum of
+            # their ``t``
             out, mods = model.apply({"params": params}, *args, **kwargs,
-                                    mutable=["aux_loss", "moe_stats", "ssm_stats"])
+                                    mutable=["aux_loss", "moe_stats", "ssm_stats",
+                                             "diffusion_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -118,6 +122,9 @@ def _as_apply_fns(model):
                         if path[-1].key == name]
                 if sown:
                     stats["ssm_" + name] = reduce(jnp.concatenate(sown))
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                    mods.get("diffusion_stats", {}))[0]:
+                stats["diffusion_" + path[-1].key] = jnp.sum(leaf)
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -444,6 +451,15 @@ class DeepSpeedTpuEngine:
         self._async_window = (_AsyncStepWindow(apc.sync_interval)
                               if apc.enabled else None)
         self._moe_pending = []   # device stats of fused MoE steps not yet published
+        # the block-diffusion objective's noising of raw token batches
+        # (data_pipeline/block_diffusion.py), seeded by the config's ``seed``
+        self._diffusion_noiser = None
+        cfg = getattr(model, "config", None)
+        if getattr(cfg, "block_diffusion_", False):
+            from .data_pipeline.block_diffusion import BlockDiffusionNoiser
+            self._diffusion_noiser = BlockDiffusionNoiser(
+                cfg.diffusion_block_length, cfg.diffusion_mask_id_,
+                cfg.diffusion_t_min, seed=self._config.seed)
 
         # ---- training/compiler observability (observability/xla.py +
         # observability/goodput.py): created before the compiled fns so the
@@ -1627,12 +1643,19 @@ class DeepSpeedTpuEngine:
         """``ds_model_layers{kind="<operator>+<ffn>"}``: how many decoder
         layers of each kind the model was built with, for a model whose
         config spells its layers out (``LlamaConfig.layer_specs``)."""
-        specs = getattr(getattr(model, "config", None), "layer_specs", None)
-        if not specs or not self._config.observability_config.enabled:
+        cfg = getattr(model, "config", None)
+        specs = getattr(cfg, "layer_specs", None)
+        if not self._config.observability_config.enabled:
             return
         from collections import Counter
         from ..observability import get_registry
-        for kind, n in Counter(f"{s.operator}+{s.ffn}" for s in specs).items():
+        kinds = Counter(f"{s.operator}+{s.ffn}" for s in specs or ())
+        if not specs and getattr(cfg, "block_diffusion_", False):
+            # alike layers are counted too where the objective is not the
+            # default: the kind then says what such a step runs
+            ffn = "moe" if cfg.num_local_experts > 0 else "dense"
+            kinds[f"attention+{ffn}"] = cfg.num_hidden_layers
+        for kind, n in kinds.items():
             get_registry().gauge(
                 "ds_model_layers", "Decoder layers by kind (operator+ffn)",
                 labels={"kind": kind}).set(float(n))
@@ -1660,6 +1683,19 @@ class DeepSpeedTpuEngine:
                 "Mean step size dt = softplus(dt + dt_bias) of the "
                 "state-space layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["ssm_dt_mean"]) for s in fetched])))
+        if "diffusion_masked_tokens" in fetched[0]:
+            masked, tokens = (sum(float(np.sum(s["diffusion_" + name])) for s in fetched)
+                              for name in ("masked_tokens", "tokens"))
+            reg.counter(
+                "ds_diffusion_masked_tokens_total",
+                "Data tokens the block-diffusion noising replaced by the mask "
+                "id (those that carry loss), summed over steps"
+            ).inc(masked)
+            reg.gauge(
+                "ds_diffusion_mask_rate",
+                "Masked tokens over data tokens, over the steps of the last "
+                "publish (the mean noise level t the batches drew)"
+            ).set(masked / max(tokens, 1.0))
         if "expert_counts" not in fetched[0]:
             return
         # [E] a step, [K, E] a K-step dispatch
@@ -1850,9 +1886,8 @@ class DeepSpeedTpuEngine:
         if self._train_step_fused is not None:
             with self._tracer.scope("ds.train.data_wait"):
                 batch = next(data_iter)
-            if not isinstance(batch, tuple):
-                batch = (batch, )
-            loss = self.fused_train_step(*batch)
+            args, kwargs = self._noised(batch)
+            loss = self.fused_train_step(*args, **kwargs)
             # async mode returns the LIVE device scalar — float() here would
             # reinstate the very per-step barrier the window removes; callers
             # wanting a host number use get_loss() (drains the window)
@@ -1875,6 +1910,21 @@ class DeepSpeedTpuEngine:
         if self._async_window is not None:
             return sum(losses) / self.gradient_accumulation_steps()
         return float(sum(float(l) for l in losses)) / self.gradient_accumulation_steps()
+
+    def _noised(self, batch):
+        """-> (args, kwargs) of the model's call for one batch of the data
+        iterator. Under the block-diffusion objective a batch of raw token
+        ids ([rows, L], alone or as ``(ids, ...)``) is noised here, on the
+        host, with step ``global_steps``'s draw; a ``DiffusionBatch`` made by
+        the caller passes as it is."""
+        if self._diffusion_noiser is None:
+            return (batch if isinstance(batch, tuple) else (batch, )), {}
+        from .data_pipeline.block_diffusion import DiffusionBatch
+        if not isinstance(batch, DiffusionBatch):
+            ids = batch[0] if isinstance(batch, tuple) else batch
+            with self._tracer.scope("ds.train.noise"):
+                batch = self._diffusion_noiser(np.asarray(ids), self.global_steps)
+        return batch.model_args()
 
     def _run_fused_train_batch(self, data_iter):
         """gas>1 one-program path: pull gas microbatches, stack on a leading
@@ -2034,7 +2084,22 @@ class DeepSpeedTpuEngine:
         ``rows_held`` and ``share_fallback`` (summed over the layers).
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
-        return self._newest_stats(lambda name: not name.startswith("ssm_"))
+        return self._newest_stats(
+            lambda name: not name.startswith(("ssm_", "diffusion_")))
+
+    def diffusion_stats(self):
+        """What the block-diffusion objective sowed in the newest fused step
+        not yet published, as host scalars: ``masked_tokens``, ``mask_rate``
+        (over the step's data tokens) and ``t_mean_masked`` (the mean noise
+        level of the masked tokens' blocks). Waits for that step, as
+        :meth:`moe_stats`; ``None`` under another objective."""
+        stats = self._newest_stats(lambda name: name.startswith("diffusion_"))
+        if not stats:
+            return None
+        masked = float(stats["diffusion_masked_tokens"])
+        return {"masked_tokens": int(masked),
+                "mask_rate": masked / max(float(stats["diffusion_tokens"]), 1.0),
+                "t_mean_masked": float(stats["diffusion_t_sum"]) / max(masked, 1.0)}
 
     def ssm_stats(self):
         """What the state-space layers sowed in the newest fused step not

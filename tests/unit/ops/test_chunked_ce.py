@@ -329,3 +329,82 @@ def test_loss_level_wrapper_shift_and_mask():
     dx = jax.grad(lambda x: chunked_cross_entropy_loss(
         x, w, None, labels.at[:, -1].set(3), 16, compute_dtype=jnp.float32))(x)
     assert not np.asarray(dx[:, -1]).any() and np.asarray(dx[:, 0]).any()
+
+
+# ---- given targets with float weights (the block-diffusion objective) ----
+
+
+def _dense_weighted(x, w, bias, targets, weights):
+    logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32), w.astype(jnp.float32))
+    if bias is not None:
+        logits = logits + bias.astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return ((logz - gold) * weights).sum() / targets.size
+
+
+@pytest.mark.parametrize("S,V,chunk,use_bias", [(16, 64, 16, False), (24, 50, 8, True),
+                                                (7, 33, 5, False)])
+def test_weighted_unshifted_loss_and_gradients_match_the_dense_form(S, V, chunk,
+                                                                    use_bias):
+    x, w, bias, labels = _inputs(S + V, 2, S, 12, V, use_bias)
+    rng = np.random.default_rng(S)
+    # 1/t where masked, else 0: heavy, and zero on most positions
+    t = rng.uniform(1e-3, 1.0, size=labels.shape)
+    weights = jnp.asarray(np.where(rng.random(labels.shape) < t, 1.0 / t, 0.0),
+                          jnp.float32)
+    args = (x, w) if bias is None else (x, w, bias)
+
+    def chunked(*a):
+        return chunked_cross_entropy_loss(a[0], a[1], a[2] if use_bias else None,
+                                          labels, chunk, compute_dtype=jnp.float32,
+                                          weights=weights)
+
+    def dense(*a):
+        return _dense_weighted(a[0], a[1], a[2] if use_bias else None, labels, weights)
+
+    argnums = tuple(range(len(args)))
+    want, want_g = jax.value_and_grad(dense, argnums)(*args)
+    got, got_g = jax.value_and_grad(chunked, argnums)(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    _assert_grads_close(got_g, want_g, atol=5e-5, rtol=5e-4)
+    # and the unchunked path of the model means the same loss
+    logits = jnp.einsum("bsh,hv->bsv", x, w) + (0.0 if bias is None else bias)
+    np.testing.assert_allclose(cross_entropy_loss(logits, labels, weights=weights),
+                               want, rtol=2e-6)
+
+
+def test_weights_of_zero_and_one_on_shifted_targets_are_the_shifted_form():
+    """Fed the shifted targets and a 0/1 weight, the weighted form is the
+    causal-LM loss but for its normaliser (all positions, not the live)."""
+    x, w, bias, labels = _inputs(3, 2, 16, 12, 64)
+    labels = labels.at[0, 5].set(-100).at[1, 9].set(-100)
+    shifted = jnp.pad(labels[:, 1:], ((0, 0), (0, 1)), constant_values=-100)
+    live = shifted != -100
+    targets = jnp.where(live, shifted, 0)
+    want = chunked_cross_entropy_loss(x, w, None, labels, 16,
+                                      compute_dtype=jnp.float32)
+    got = chunked_cross_entropy_loss(x, w, None, targets, 16,
+                                     compute_dtype=jnp.float32,
+                                     weights=live.astype(jnp.float32))
+    np.testing.assert_allclose(got * labels.size / live.sum(), want, rtol=2e-6)
+    g_want = jax.grad(lambda x: chunked_cross_entropy_loss(
+        x, w, None, labels, 16, compute_dtype=jnp.float32))(x)
+    g_got = jax.grad(lambda x: chunked_cross_entropy_loss(
+        x, w, None, targets, 16, compute_dtype=jnp.float32,
+        weights=live.astype(jnp.float32)))(x)
+    np.testing.assert_allclose(np.asarray(g_got) * labels.size / live.sum(),
+                               np.asarray(g_want), atol=2e-6)
+
+
+def test_the_weighted_form_is_still_one_sweep():
+    """Loss and gradients in one scan of three vocabulary matmuls a chunk."""
+    x, w, _, labels = _inputs(1, 2, 32, 12, 64)
+    weights = jnp.ones(labels.shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x, w: chunked_cross_entropy_loss(
+        x, w, None, labels, 8, weights=weights), (0, 1)))(x, w)
+    scans, dots = [], []
+    _walk(jaxpr.jaxpr, lambda e, inside: (
+        scans.append(e) if e.primitive.name == "scan" else None,
+        dots.append(e) if e.primitive.name == "dot_general" and inside else None))
+    assert len(scans) == 1 and len(dots) == 3

@@ -316,8 +316,10 @@ def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
         one_chip, no_compile_cache):
     """The LFM2 cell's expert layer a step: 32,768 tokens x top-4 over a
     router of 64, 8 experts of 2048 x 1536 held. Both branches of the
-    ``cond`` (the static 32,768-row array, and all 131,072 rows) lower to the
-    chip's grouped-matmul kernel, and the program fits beside the state."""
+    ``cond`` (the static 32,768-row array, and the exact pass over all
+    131,072 rows, which since PR 37 walks them a window of 32,768 at a time)
+    lower to the chip's grouped-matmul kernel, no array of all the rows at
+    an expert's width exists, and the program fits beside the state."""
     from deepspeed_tpu.ops.grouped_matmul import moe_grouped_mlp_share
     T, HID, F, E, HELD, K = 32768, 2048, 1536, 64, 8, 4
     sds = functools.partial(_sds, sharding=one_chip)
@@ -336,8 +338,8 @@ def test_a_share_of_the_experts_compiles_to_the_native_kernel_at_lfm2_widths(
     # forward's three and the six gradients
     assert len(names) == 2 * (3 + 3 + 6), names
     text = compiled.as_text()
-    assert "bf16[32768,1536]" in text and "bf16[131072,1536]" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    assert "bf16[32768,1536]" in text and "bf16[131072,1536]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
 def test_the_shares_rows_go_back_to_tokens_through_the_kernel_on_one_chip(
@@ -524,4 +526,89 @@ def test_kernels_keep_their_names_under_the_programs_scopes(
                   + ["ds.rope"] * (cfg.pos_embedding == "rope" and not spec)
                   + ["ds.moe.route", "ds.moe.dispatch", "ds.moe.combine"]
                   * bool(ragged)):
+        assert f"/{scope}/" in text, scope
+
+
+def test_block_diffusion_kernels_compile_at_the_sdar_cells_shape(one_chip,
+                                                                 no_compile_cache):
+    """``train-sdar-1chip-bd4-seq8k``'s call, ``[2, 16384, 32/4, 128]`` in
+    blocks of 4: the forward and the one backward kernel at the (128, 512)
+    tiles ``kernel_dispatch`` picks (2,048 folded rows a step: both copies x
+    8 heads x 128 queries), each under its own name and none under a
+    ``flash`` name, on operands laid out ``[rows * kv, 2, group, L, d]``;
+    the backward asks for the VMEM its clean keys' float32 dK and dV need."""
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    from deepspeed_tpu.ops.attention import block_diffusion_attention
+    sig = kd.make_sig((2, 16384, 32, D), 4, 16384, "bfloat16", False, None, None,
+                      pattern="bd4")
+    for leg in ("fwd", "bwd"):
+        assert kd.choose_block_diffusion_blocks(sig, leg, 4) == (128, 512)
+    need = kd.bdattn_vmem_bytes("bwd", 8, D, 2, 128, 512, 8192)
+    assert kd.VMEM_SCOPED_DEFAULT_BYTES < need < kd.FUSED_VMEM_CAP_BYTES
+
+    q = _sds((2, 16384, 32, D), jnp.bfloat16, one_chip)
+    k = _sds((2, 16384, 4, D), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        jax.grad(lambda q, k, v: jnp.sum(block_diffusion_attention(
+            q, k, v, 4, force_pallas=True).astype(jnp.float32)), argnums=(0, 1, 2)),
+        q, k, k)
+    calls, names = _custom_calls(compiled), _custom_call_names(compiled)
+    assert sorted(n.split(".")[0] for n in names) == ["bdattn_bwd", "bdattn_fwd"], names
+    assert not any("flash" in n for n in names)
+    for call in calls:
+        assert f"bf16[8,2,8,8192,{D}]" in call.split(" custom-call(")[0], call
+    bwd, = [c for c in calls if "%bdattn_bwd" in c.split(" = ")[0]]
+    asked = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', bwd)[0]
+    assert int(re.search(r'"size":"(\d+)"', asked).group(1)) == kd.vmem_limit_bytes(need)
+
+
+def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
+        one_chip, no_compile_cache, monkeypatch):
+    """One SDAR layer at its widths (16 of 128 experts held, the vocabulary
+    cut) under the block-diffusion objective, the weighted loss and its
+    gradient under the engine's ``ds.step.loss``: the attention is the
+    ``bdattn`` pair (the forward twice: recomputation) and no ``flash``
+    call, the share's grouped matmuls are XLA's own, and the program's
+    scopes are on the ops around them."""
+    from deepspeed_tpu.models import llama
+    from deepspeed_tpu.runtime.engine import _step_scope
+    seq = 4096
+    cfg = llama.LlamaConfig(
+        vocab_size=2048, hidden_size=2048, num_hidden_layers=1, intermediate_size=768,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128, qk_norm="head",
+        num_local_experts=128, moe_experts_held=16, num_experts_per_tok=8,
+        rope_theta=1e6, rms_norm_eps=1e-6, max_position_embeddings=seq,
+        ce_chunk_size=2048, remat=True, objective="block_diffusion")
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
+    monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
+                        lambda force=None: True)
+    monkeypatch.setattr("deepspeed_tpu.ops.kernel_dispatch.device_kind",
+                        lambda: "TPU v5 lite")
+    model = llama.LlamaForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda: {"params": llama.unbox_params(model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]})
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), shapes)
+    ids = _sds((1, 2 * seq), jnp.int32, one_chip)
+    targets = _sds((1, seq), jnp.int32, one_chip)
+    weights = _sds((1, seq), jnp.float32, one_chip)
+
+    def step(params, ids, targets, weights):
+        def loss(p):
+            out = model.apply(p, ids, targets, loss_weights=weights,
+                              mutable=["moe_stats", "diffusion_stats"])
+            return out[0].astype(jnp.float32)
+        with _step_scope("loss"):
+            return jax.value_and_grad(loss)(params)
+
+    compiled = _compile(step, params, ids, targets, weights)
+    names = [n.split(".")[0] for n in _custom_call_names(compiled)
+             if not n.startswith("ragged-dot")]
+    assert {k: names.count(k) for k in set(names)} == {"bdattn_fwd": 2, "bdattn_bwd": 1}, names
+    text = compiled.as_text()
+    assert len(re.findall(r"%(ragged-dot-none[.\d]*) = ", text)) == 2 * (3 + 3 + 6)
+    for scope in ("ds.step.loss", "ds.head.loss", "ds.rope", "ds.moe.route",
+                  "ds.moe.dispatch", "ds.moe.combine"):
         assert f"/{scope}/" in text, scope
