@@ -172,7 +172,11 @@ class ActivationCheckpointingConfig(ConfigModel):
     number_checkpoints: Optional[int] = None
     synchronize_checkpoint_boundary: bool = False
     profile: bool = False
-    # TPU extension: jax.checkpoint policy name
+    # TPU extension: jax.checkpoint policy name; the engine wraps the model
+    # in jax.checkpoint only when one is named. For
+    # activation_checkpointing.checkpoint() None recomputes everything but
+    # what an attention kernel gave (ops/attention.py::RESIDUAL_NAMES);
+    # "nothing_saveable" keeps nothing
     remat_policy: Optional[str] = None
 
 

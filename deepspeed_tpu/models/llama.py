@@ -176,7 +176,11 @@ class LlamaConfig:
     remat: bool = False
     # jax.checkpoint_policies name for selective remat (e.g. "dots_saveable":
     # save matmul outputs, recompute elementwise/norms — most of the memory
-    # saving at a fraction of full remat's recompute). None = full recompute.
+    # saving at a fraction of full remat's recompute). None = the whole layer
+    # is recomputed but for what its attention kernel gave (output and
+    # log-sum-exp, ops/attention.py::RESIDUAL_NAMES: tokens x hidden x 2 B a
+    # layer that has attention, and the kernel's forward runs once a step);
+    # "nothing_saveable" = nothing kept.
     remat_policy: "Optional[str]" = None
     # chunked unembed+CE (ops/chunked_ce.py): a bound on the transient
     # logits, which hold at most tokens x ce_chunk_size elements and are
@@ -940,13 +944,20 @@ class LMHead(nn.Module):
 
 def _remat_layer_cls(cfg):
     """nn.remat with the configured jax.checkpoint_policies policy (selective
-    remat — reference activation_checkpointing config's TPU analog)."""
+    remat — reference activation_checkpointing config's TPU analog). With no
+    policy named the layer is recomputed whole but for what its attention
+    kernel gave (``ops/attention.py::RESIDUAL_NAMES``): the kernel's forward
+    then runs once a step, for one more activation of the layer input's size
+    a layer that has attention; ``"nothing_saveable"`` keeps nothing."""
     if cfg.remat_policy:
         pol = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
         if pol is None:
             raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
         return nn.remat(LlamaDecoderLayer, policy=pol)
-    return nn.remat(LlamaDecoderLayer)
+    from ..ops.attention import RESIDUAL_NAMES
+    return nn.remat(LlamaDecoderLayer,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *RESIDUAL_NAMES))
 
 
 class _ScanBody(nn.Module):

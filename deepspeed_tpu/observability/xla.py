@@ -72,6 +72,42 @@ def _arg_specs(args, kwargs) -> Tuple[tuple, dict]:
             jax.tree_util.tree_map(spec, kwargs))
 
 
+def kept_residual_bytes(closed_jaxpr, names=None) -> int:
+    """Bytes of the values named ``names`` (``jax.ad_checkpoint.
+    checkpoint_name``; default ``ops/attention.py::RESIDUAL_NAMES``) that a
+    traced program's recomputations keep from the forward instead of making
+    them again: the named values computed outside a recomputation (the body
+    of a differentiated ``jax.checkpoint`` equation) less those computed inside
+    one, from the shapes in the jaxpr, a ``scan`` body counted ``length``
+    times. 0 for a program that recomputes nothing: its autodiff keeps
+    every residual, named or not."""
+    from jax._src import core
+    from jax._src.ad_checkpoint import remat_p
+    if names is None:
+        from ..ops.attention import RESIDUAL_NAMES as names
+    outside = inside = recomputations = 0
+
+    def walk(jaxpr, times, recomputed):
+        nonlocal outside, inside, recomputations
+        for eqn in jaxpr.eqns:
+            prim = eqn.primitive.name
+            if prim == "name" and eqn.params["name"] in names:
+                nbytes = times * sum(v.aval.size * v.aval.dtype.itemsize
+                                     for v in eqn.outvars)
+                if recomputed:
+                    inside += nbytes
+                else:
+                    outside += nbytes
+            again = eqn.primitive is remat_p and eqn.params["differentiated"]
+            recomputations += bool(again)
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub, times * (eqn.params["length"] if prim == "scan" else 1),
+                     recomputed or again)
+
+    walk(closed_jaxpr.jaxpr, 1, False)
+    return max(0, outside - inside) if recomputations else 0
+
+
 class WatchedJit:
     """Transparent wrapper around one jitted program. Forwards everything
     (``lower``, ``clear_cache``, ...) so callers — including the flops
@@ -85,6 +121,7 @@ class WatchedJit:
         self._calls = 0
         self.dispatches = 0       # read by TrainInstruments.publish()
         self._flops: Optional[float] = None
+        self._kept_bytes = 0
         self._flops_spec = None
 
     def _cache_entries(self) -> Optional[int]:
@@ -147,10 +184,18 @@ class WatchedJit:
         # MFU gauges cost inside a compile stall can be read off the tracer
         with get_tracer().scope("ds.compile.cost_analysis", key=self.key):
             try:
-                self._flops = cost_analysis_flops(self._fn.lower(*a, **k))
+                traced = self._fn.trace(*a, **k)
+                self._kept_bytes = kept_residual_bytes(traced.jaxpr)
+                self._flops = cost_analysis_flops(traced.lower())
             except Exception:
                 self._flops = 0.0
         return self._flops
+
+    def program_kept_bytes(self) -> int:
+        """``kept_residual_bytes`` of this program, read off the same trace
+        as ``program_flops`` (0 before the program has compiled)."""
+        self.program_flops()
+        return self._kept_bytes
 
 
 class CompileWatch:
@@ -397,6 +442,16 @@ class TrainInstruments:
                 f = prog.program_flops()
                 if f > 0:
                     flops += f * d
+                if not seen:
+                    self.registry.gauge(
+                        "ds_remat_kept_bytes",
+                        "Bytes of the attention kernels' named residuals "
+                        "(output, log-sum-exp) a dispatch of the program "
+                        "keeps from its forward to a recomputed layer's "
+                        "backward, from the shapes in its trace; 0 for a "
+                        "program that recomputes nothing",
+                        labels={"key": prog.key}).set(
+                            float(prog.program_kept_bytes()))
                 ent[1] = prog.dispatches
         if any_dispatch and wall > 0 and flops > 0:
             self.mfu.set(min(1.0, flops / (wall * self.peak_flops)))

@@ -42,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -483,7 +484,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=None,
                softcap=None, fused=False):
     """Per-head Pallas backward; ``res`` carries lse in the per-head
-    [B*KV, G, Sq, 1] layout. ``fused``: one ``flash_dkdv_dq`` call in place
+    layout, [B*KV, G, Sq] as the forward rule keeps it (or with the kernels'
+    trailing unit dimension). ``fused``: one ``flash_dkdv_dq`` call in place
     of ``flash_dq`` and ``flash_dkdv`` (``kernel_dispatch`` decides: the
     float32 dQ of a KV head's whole sequence has to fit in VMEM)."""
     from .kernel_dispatch import flash_vmem_bytes
@@ -522,7 +524,7 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
             compiler_params=params,
             interpret=interpret,
             name="flash_dq",
-        )(qg, kt, vt, dog, lse, delta)
+        )(qg, kt, vt, dog, lse.reshape(delta.shape), delta)
 
     # kv-major grid for dk/dv: q sweep innermost. lse and delta enter as
     # rows of the transposed score tile: [B*KV, q blocks, 1, G*BQ], g-major
@@ -702,10 +704,35 @@ def _dispatched_attention(q, k, v, scale, causal, window, softcap, interpret,
     return o
 
 
+# What a layer's backward needs of an attention kernel's forward, by the
+# names a recomputation's policy can keep them under
+# (``jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)``, the
+# default of ``models/llama.py`` under ``remat`` with no policy named): with
+# both kept the recomputed layer does not run the kernel again. A name is the
+# identity anywhere else.
+RESIDUAL_NAMES = ("ds.attn.out", "ds.attn.lse")
+
+
+def _name_residuals(o, lse):
+    """-> (o, lse) under ``RESIDUAL_NAMES``, ``lse`` WITHOUT the kernels'
+    trailing unit dimension: a float32 ``[..., seq, 1]`` is tiled to 128
+    lanes in HBM, 128 times its bytes, and a kept residual lives from the
+    forward to the layer's backward. The backward legs take this form: they
+    read ``lse`` as rows of a transposed score tile, a reshape of either
+    form, and only a kernel that reads it a query block at a time
+    (``flash_dq``, the folded pair) is handed the unit dimension back. With
+    nothing between the two (no recomputation) XLA folds the reshapes."""
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    if lse is not None:
+        lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return o, lse
+
+
 def _fwd_rule(q, k, v, scale, causal, window, softcap, interpret, fwd_dec,
               bwd_dec):
-    o, lse = _run_fwd(q, k, v, scale, causal, window, softcap, interpret,
-                      fwd_dec, _bwd_lse_layout(bwd_dec))
+    o, lse = _name_residuals(*_run_fwd(
+        q, k, v, scale, causal, window, softcap, interpret, fwd_dec,
+        _bwd_lse_layout(bwd_dec)))
     return o, (q, k, v, o, lse)
 
 
@@ -721,7 +748,7 @@ def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
         return vjp(g)
     if bwd_dec.impl == "folded":
         from .attention_folded import flash_bwd_folded
-        return flash_bwd_folded(q, k, v, lse, o, g, scale, causal,
+        return flash_bwd_folded(q, k, v, lse[..., None], o, g, scale, causal,
                                 bwd_dec.block_q, bwd_dec.block_k, interpret,
                                 window, softcap)
     return _flash_bwd((q, k, v, o, lse), g, scale, causal, bwd_dec.block_q,
@@ -1144,7 +1171,8 @@ def _bd_attention(q, k, v, scale, block_length, fwd_blocks, bwd_blocks, interpre
 
 
 def _bd_fwd_rule(q, k, v, scale, block_length, fwd_blocks, bwd_blocks, interpret):
-    o, lse = _bd_flash_fwd(q, k, v, scale, block_length, *fwd_blocks, interpret)
+    o, lse = _name_residuals(*_bd_flash_fwd(
+        q, k, v, scale, block_length, *fwd_blocks, interpret))
     return o, (q, k, v, o, lse)
 
 

@@ -89,7 +89,12 @@ def _resolve_policy():
         if pol is None:
             raise ValueError(f"unknown remat policy '{name}'")
         return pol
-    return None  # jax default: nothing saveable (full recompute)
+    # no policy named: recompute everything but what an attention kernel gave
+    # (its output and log-sum-exp, ``ops/attention.py::RESIDUAL_NAMES``), as
+    # ``models/llama.py`` does; a function that reaches no such kernel keeps
+    # nothing, and ``remat_policy: "nothing_saveable"`` keeps nothing anywhere
+    from ...ops.attention import RESIDUAL_NAMES
+    return jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
 
 
 def _partition_arg(x):

@@ -593,6 +593,39 @@ class DeepSpeedTpuEngine:
     # state
     # ------------------------------------------------------------------
 
+    def _set_grad_acc(self, acc):
+        """``engine.grad_acc`` and its gauge: None until a path that reads
+        the buffer asks for it (``_ensure_grad_acc``) or a checkpoint that
+        holds one is restored."""
+        self.grad_acc = acc
+        if self._config.observability_config.enabled:
+            from ..observability import get_registry
+            get_registry().gauge(
+                "ds_grad_acc_bytes",
+                "Bytes of the gradient accumulation buffer over all chips "
+                "(0 until the unfused forward/backward/step path, an offload "
+                "step or a restore makes it: the fused steps never do)"
+            ).set(float(sum(x.nbytes for x in jax.tree_util.tree_leaves(acc))))
+
+    def _grad_acc_struct(self):
+        """The buffer's abstract tree: the parameters' shapes (the ZeRO-3
+        store's where that holds them) at ``grad_accum_dtype`` under
+        ``grad_shardings``."""
+        return jax.tree_util.tree_map(
+            lambda p, s: jax.ShapeDtypeStruct(p.shape, self.grad_accum_dtype,
+                                              sharding=s),
+            self.params, self.grad_shardings)
+
+    def _ensure_grad_acc(self):
+        """The accumulation buffer, zeros on first use."""
+        if self.grad_acc is None:
+            struct = self._grad_acc_struct()
+            self._set_grad_acc(jax.jit(
+                lambda: jax.tree_util.tree_map(
+                    lambda x: jnp.zeros(x.shape, x.dtype), struct),
+                out_shardings=self.grad_shardings)())
+        return self.grad_acc
+
     def _init_state(self, params):
         """Master params fp32 (BF16/FP16 optimizer semantics: reference
         bf16_optimizer.py:34 keeps fp32 master weights), sharded per plan."""
@@ -622,12 +655,9 @@ class DeepSpeedTpuEngine:
         with self._tracer.scope("ds.init.opt_state"):
             self.grad_shardings = (self.param_shardings if self._zero3_store is not None
                                    else self.zero_plan.grad_shardings(params))
-            acc_dtype = self.grad_accum_dtype
-            zeros_fn = jax.jit(
-                lambda p: jax.tree_util.tree_map(
-                    lambda x: jnp.zeros(x.shape, acc_dtype), p),
-                out_shardings=self.grad_shardings)
-            self.grad_acc = zeros_fn(self.params)
+            # the accumulation buffer (a tree the size of the parameters) is
+            # made by the first path that reads it: the fused steps never do
+            self._set_grad_acc(None)
 
             if self._offload_device in ("cpu", "nvme") and self._offload_ratio >= 1.0:
                 # no device opt state at all — that's the HBM saving
@@ -1409,8 +1439,8 @@ class DeepSpeedTpuEngine:
         kwargs, static_kv = _split_static_kwargs(kwargs)
         args = jax.device_put(args, self.zero_plan.batch_sharding(args))
         kwargs = jax.device_put(kwargs, self.zero_plan.batch_sharding(kwargs))
-        loss, new_acc = self._fwd_bwd(self.params, self.grad_acc, scale, args, kwargs,
-                                      static_kv)
+        loss, new_acc = self._fwd_bwd(self.params, self._ensure_grad_acc(), scale,
+                                      args, kwargs, static_kv)
         # grad_acc was donated; keep the new buffer, commit on backward()
         self.grad_acc = new_acc
         self._pending = loss
@@ -1496,8 +1526,8 @@ class DeepSpeedTpuEngine:
                 overflow, gnorm = self._host_offload_step()
             else:
                 (self.params, self.opt_state, self.grad_acc, self.scale_state, overflow,
-                 gnorm) = self._apply_step(self.params, self.grad_acc, self.opt_state,
-                                           self.scale_state)
+                 gnorm) = self._apply_step(self.params, self._ensure_grad_acc(),
+                                           self.opt_state, self.scale_state)
             self._last_grad_norm = gnorm
             self.global_steps += 1
             self.global_samples += self.train_batch_size()
@@ -1547,7 +1577,7 @@ class DeepSpeedTpuEngine:
            async host→device upload — uploads overlap the remaining leaves'
            host math (double buffering without CUDA streams)."""
         from .host_offload import flatten_tree, unflatten_like
-        clipped, overflow_d, gnorm_d = self._offload_prep(self.grad_acc,
+        clipped, overflow_d, gnorm_d = self._offload_prep(self._ensure_grad_acc(),
                                                           self.scale_state)
         for v in clipped.values():
             if hasattr(v, "copy_to_host_async"):
@@ -1589,7 +1619,7 @@ class DeepSpeedTpuEngine:
         forces a device/host serialization point; only fp16 loss scaling
         still syncs one scalar (the host Adam must know whether to skip)."""
         from .host_offload import flatten_tree, unflatten_like
-        clipped, overflow_d, _ = self._offload_prep(self.grad_acc,
+        clipped, overflow_d, _ = self._offload_prep(self._ensure_grad_acc(),
                                                     self.scale_state)
         for v in clipped.values():
             if hasattr(v, "copy_to_host_async"):
@@ -2328,11 +2358,14 @@ class DeepSpeedTpuEngine:
         # owning chips — a per-shard save with NO full gather (the reference
         # stage-3 default; consolidation stays the explicit
         # stage3_gather_16bit_weights_on_model_save / save_16bit_model path).
+        # "grad_acc" only where the buffer exists (a run on the unfused or an
+        # offload path; in mid-accumulation it holds the sums to resume on)
         sd = {
             "params": self.params,
-            "grad_acc": self.grad_acc,
             "scale_state": tuple(self.scale_state),
         }
+        if self.grad_acc is not None:
+            sd["grad_acc"] = self.grad_acc
         if self.opt_state is not None:
             sd["opt_state"] = self.opt_state
         return sd
@@ -2379,6 +2412,9 @@ class DeepSpeedTpuEngine:
                                 self.train_micro_batch_size_per_gpu(),
                                 self.gradient_accumulation_steps()],
             "client_state": client_state or {},
+            # whether the arrays hold an accumulation buffer (a checkpoint
+            # from before the key always does)
+            "grad_acc": self.grad_acc is not None,
         }
         if self.lr_scheduler is not None and hasattr(self.lr_scheduler, "state_dict"):
             sd["lr_scheduler"] = self.lr_scheduler.state_dict()
@@ -2538,23 +2574,30 @@ class DeepSpeedTpuEngine:
                 return None, {}
         path = os.path.join(load_dir, str(tag))
 
-        saved_store = self._peek_zero3_store_meta(path)
+        peeked = self._peek_host_state(path)
+        saved_store = peeked.get("zero3_store")
+        # the target names what the checkpoint holds: a buffer it lacks stays
+        # unmade, one it holds is restored whether or not this engine has one
+        has_acc = bool(peeked.get("grad_acc", True))
         if (saved_store is not None) != (getattr(self, "_zero3_store", None)
                                          is not None):
             # the checkpoint's arrays are in the OTHER param format
             # (bucketed ZeRO-3 store vs leaf tree): reshard on load
-            restored, host_state = self._reshard_load(path, saved_store)
+            restored, host_state = self._reshard_load(path, saved_store, has_acc)
         else:
             # abstract target: restore straight into the live shardings
             target = jax.tree_util.tree_map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
                 if hasattr(x, "sharding") else x, self._state_dict())
+            target.pop("grad_acc", None)
+            if has_acc:
+                target["grad_acc"] = self._grad_acc_struct()
             restored, host_state = self.checkpoint_engine.load(path, target=target)
         self.params = restored["params"]
         if load_optimizer_states and not load_module_only:
             if "opt_state" in restored:
                 self.opt_state = restored["opt_state"]
-            self.grad_acc = restored["grad_acc"]
+            self._set_grad_acc(restored.get("grad_acc"))
             from .loss_scaler import LossScaleState
             self.scale_state = LossScaleState(*restored["scale_state"])
             if self._host_optimizer is not None and host_state \
@@ -2584,24 +2627,25 @@ class DeepSpeedTpuEngine:
         self._last_good_tag = str(tag)
         return path, client_state
 
-    def _peek_zero3_store_meta(self, path):
-        """Read the checkpoint's host-state sidecar (tiny pickle, no array
-        data) to learn whether its arrays were saved in ZeRO-3 store form;
-        returns the saved store descriptor or None."""
+    def _peek_host_state(self, path) -> dict:
+        """The checkpoint's host-state sidecar (tiny pickle, no array data),
+        read before the arrays to learn their form: ``zero3_store`` (the
+        saved store's descriptor, where they are in ZeRO-3 store form) and
+        ``grad_acc`` (whether they hold an accumulation buffer); ``{}`` when
+        there is none to read."""
         import pickle
         from ..checkpoint.engine import OrbaxCheckpointEngine
         f = os.path.join(path, OrbaxCheckpointEngine.HOST_STATE_FILE)
         if not os.path.exists(f):
-            return None
+            return {}
         try:
             with open(f, "rb") as fh:
-                hs = pickle.load(fh)
+                return pickle.load(fh) or {}
         except Exception as e:  # legacy/foreign sidecar: same-format load
             logger.warning(f"could not peek host state at {f}: {e}")
-            return None
-        return (hs or {}).get("zero3_store")
+            return {}
 
-    def _reshard_load(self, path, saved_store):
+    def _reshard_load(self, path, saved_store, has_acc=True):
         """Stage 2<->3 reshard-on-load: restore into an abstract target
         shaped like the SAVE-time format, then convert on device into the
         live format. Both directions are exact (pure slice/concat of fp32
@@ -2641,8 +2685,9 @@ class DeepSpeedTpuEngine:
                             for i in meta.p_idx]}
 
             target = {"params": _store_struct(jnp.float32),
-                      "grad_acc": _store_struct(acc_dtype),
                       "scale_state": scale_target}
+            if has_acc:
+                target["grad_acc"] = _store_struct(acc_dtype)
             if self.opt_state is not None:
                 target["opt_state"] = _repl_struct(jax.eval_shape(
                     self.base_tx.init, _store_struct(jnp.float32)))
@@ -2651,10 +2696,11 @@ class DeepSpeedTpuEngine:
             out = {"params": jax.jit(
                        lambda s: materialize_params(s, meta),
                        out_shardings=self.param_shardings)(restored["params"]),
-                   "grad_acc": jax.jit(
-                       lambda s: materialize_params(s, meta),
-                       out_shardings=self.grad_shardings)(restored["grad_acc"]),
                    "scale_state": restored["scale_state"]}
+            if has_acc:
+                out["grad_acc"] = jax.jit(
+                    lambda s: materialize_params(s, meta),
+                    out_shardings=self.grad_shardings)(restored["grad_acc"])
             if "opt_state" in restored:
                 store_def = jax.tree_util.tree_structure(
                     _store_struct(jnp.float32))
@@ -2676,8 +2722,9 @@ class DeepSpeedTpuEngine:
         acc_tree = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, acc_dtype, sharding=repl),
             fp32_tree)
-        target = {"params": fp32_tree, "grad_acc": acc_tree,
-                  "scale_state": scale_target}
+        target = {"params": fp32_tree, "scale_state": scale_target}
+        if has_acc:
+            target["grad_acc"] = acc_tree
         if self.opt_state is not None:
             target["opt_state"] = _repl_struct(jax.eval_shape(
                 self.base_tx.init, fp32_tree))
@@ -2686,10 +2733,11 @@ class DeepSpeedTpuEngine:
         out = {"params": jax.jit(
                    lambda t: store_from_tree(t, meta),
                    out_shardings=self.param_shardings)(restored["params"]),
-               "grad_acc": jax.jit(
-                   lambda t: store_from_tree(t, meta),
-                   out_shardings=self.grad_shardings)(restored["grad_acc"]),
                "scale_state": restored["scale_state"]}
+        if has_acc:
+            out["grad_acc"] = jax.jit(
+                lambda t: store_from_tree(t, meta),
+                out_shardings=self.grad_shardings)(restored["grad_acc"])
         if "opt_state" in restored:
             out["opt_state"] = jax.jit(
                 lambda o: map_store_subtrees(

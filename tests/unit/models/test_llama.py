@@ -285,3 +285,81 @@ def test_step_program_class_compiles_and_learns(program):
     l1 = float(engine.fused_train_step(ids, labels=ids))
     assert np.isfinite(l0) and np.isfinite(l1)
     assert l1 < l0  # same batch twice: the step must actually learn
+
+
+def _kernel_calls(closed_jaxpr):
+    """{kernel name: calls} of a traced program's ``pallas_call`` equations,
+    a ``scan`` body's counted ``length`` times (interpreted kernels lower to
+    no custom call on a CPU: the equations are what can be counted here)."""
+    from jax._src import core
+    calls = {}
+
+    def walk(jaxpr, times):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                calls[name] = calls.get(name, 0) + times
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub, times * (eqn.params["length"]
+                                   if eqn.primitive.name == "scan" else 1))
+
+    walk(closed_jaxpr.jaxpr, 1)
+    return calls
+
+
+# float32: at bf16 XLA keeps excess precision inside whatever it fuses, so two
+# programs of one model differ in the last bit on any backend
+_KEPT_CASES = {
+    "unrolled": (dict(), "flash_fwd", "flash_dkdv_dq"),
+    "scan": (dict(scan_layers=True), "flash_fwd", "flash_dkdv_dq"),
+    "block_diffusion": (dict(objective="block_diffusion", diffusion_mask_id=255),
+                        "bdattn_fwd", "bdattn_bwd"),
+}
+
+
+@pytest.mark.parametrize("case", _KEPT_CASES)
+def test_whole_layer_recomputation_keeps_what_the_attention_kernel_gave(case):
+    """``remat=True`` with no policy named keeps each attention kernel's
+    output and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) for the
+    layer's backward: the gradient of a two-layer model holds ONE forward
+    kernel a layer, two under ``remat_policy="nothing_saveable"``, and the
+    loss and every gradient leaf are the same bits as without recomputation.
+    ``kept_residual_bytes`` reads what is kept off the same trace."""
+    import dataclasses
+    from deepspeed_tpu.models.llama import unbox_params
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    over, fwd, bwd = _KEPT_CASES[case]
+    layers, rows, seq, heads, hd = 2, 2, 256, 4, 16
+    base = LlamaConfig.tiny(num_hidden_layers=layers, max_position_embeddings=seq,
+                            attn_impl="flash", dtype=jnp.float32, **over)
+    ids = jnp.asarray(np.random.default_rng(3).integers(0, 255, (rows, seq)), jnp.int32)
+    if base.block_diffusion_:
+        t = jnp.linspace(0.1, 1.0, rows * seq, dtype=jnp.float32).reshape(rows, seq)
+        args = (jnp.concatenate([jnp.where(t > 0.5, 255, ids), ids], axis=1), ids)
+        kwargs, positions = dict(loss_weights=1.0 / t), 2 * seq
+    else:
+        args, kwargs, positions = (ids, ids), {}, seq
+    params = unbox_params(LlamaForCausalLM(base).init(jax.random.PRNGKey(0), ids))
+
+    def program(**remat):
+        model = LlamaForCausalLM(dataclasses.replace(base, **remat))
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply(p, *args, **kwargs).astype(jnp.float32)))
+
+    kept = program(remat=True)
+    nothing = program(remat=True, remat_policy="nothing_saveable")
+    plain = program()
+    assert _kernel_calls(kept.trace(params).jaxpr) == {fwd: layers, bwd: layers}
+    assert _kernel_calls(nothing.trace(params).jaxpr) == {fwd: 2 * layers, bwd: layers}
+    assert _kernel_calls(plain.trace(params).jaxpr) == {fwd: layers, bwd: layers}
+    # the output as the model computes with it and the log-sum-exp WITHOUT the
+    # kernels' unit lane: a float32 a row and head
+    a_layer = rows * positions * heads * (hd * 4 + 4)
+    assert kept_residual_bytes(kept.trace(params).jaxpr) == layers * a_layer
+    assert kept_residual_bytes(nothing.trace(params).jaxpr) == 0
+    assert kept_residual_bytes(plain.trace(params).jaxpr) == 0
+    want = plain(params)
+    for got in (kept(params), nothing(params)):
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+            got, want)

@@ -142,12 +142,12 @@ def test_the_other_step_programs_carry_the_same_names(program):
         want = set(STEP_SCOPES)
     elif program == "apply_step":
         e = build()
-        names = op_names(e._apply_step, e.params, e.grad_acc, e.opt_state,
+        names = op_names(e._apply_step, e.params, e._ensure_grad_acc(), e.opt_state,
                          e.scale_state)
         want = {"ds.step.grad_norm", "ds.step.optimizer"}
     else:
         e = build()
-        names = op_names(e._fwd_bwd, e.params, e.grad_acc, jnp.float32(1.0),
+        names = op_names(e._fwd_bwd, e.params, e._ensure_grad_acc(), jnp.float32(1.0),
                          (ids, ids), {}, ())
         want = {"ds.step.cast", "ds.step.loss"}
     assert {s for s in scopes_of(names) if s.startswith("ds.step.")} == want

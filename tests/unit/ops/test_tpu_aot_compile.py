@@ -440,6 +440,18 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(
     assert f"bf16[{KV},{H // KV},{seq},{D}]" in fwd
 
 
+def _steer_the_model_to_the_chip(monkeypatch):
+    """Code that asks "is this a TPU" sees the CPU here, and conftest turns
+    interpret mode on: steer both in the test, the kernels are the subject."""
+    from deepspeed_tpu.models import llama
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
+    monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
+                        lambda force=None: True)
+    monkeypatch.setattr("deepspeed_tpu.ops.kernel_dispatch.device_kind",
+                        lambda: "TPU v5 lite")
+
+
 # a whole layer of each training cell's kind at its widths (hidden 2048, the
 # vocabulary cut: the head has no kernel), remat as the LFM2 and Granite cells
 # run it: what each kernel's instruction must still be called when the
@@ -448,7 +460,8 @@ _SCOPED_LAYERS = {
     "attention_rope_dense": (
         dict(num_attention_heads=16, num_key_value_heads=4, head_dim=128,
              qk_norm="head", intermediate_size=8192), 4096,
-        {"flash_fwd": 2, "flash_dkdv_dq": 1}, 0),
+        # whole-layer recomputation keeps the kernel's output: one forward
+        {"flash_fwd": 1, "flash_dkdv_dq": 1}, 0),
     "conv_moe_share": (
         dict(num_attention_heads=32, num_key_value_heads=8, head_dim=64,
              num_local_experts=64, moe_experts_held=8, num_experts_per_tok=4,
@@ -481,7 +494,8 @@ def test_kernels_keep_their_names_under_the_programs_scopes(
     the loss and its gradient under the engine's ``ds.step.loss`` with
     ``ds.rope``, ``ds.moe.*`` and ``ds.head.loss`` inside: every kernel is
     still called what its reader matches, the scopes are on the ops around
-    them, and the recomputed forward's kernels are there (count 2)."""
+    them, and the recomputed forward's kernels are there (count 2; the
+    attention kernel's forward once: its output is kept for the backward)."""
     import dataclasses
     from deepspeed_tpu.models import llama
     from deepspeed_tpu.runtime.engine import _step_scope
@@ -492,14 +506,7 @@ def test_kernels_keep_their_names_under_the_programs_scopes(
         vocab_size=2048, hidden_size=2048, num_hidden_layers=1,
         max_position_embeddings=seq, ce_chunk_size=2048, remat=True,
         layer_specs=(llama.LayerSpec(**spec), ) if spec else None), **over})
-    # code that asks "is this a TPU" sees the CPU here, and conftest turns
-    # interpret mode on: steer both in the test, the kernels are the subject
-    monkeypatch.setattr(llama, "on_tpu", lambda: True)
-    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
-    monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
-                        lambda force=None: True)
-    monkeypatch.setattr("deepspeed_tpu.ops.kernel_dispatch.device_kind",
-                        lambda: "TPU v5 lite")
+    _steer_the_model_to_the_chip(monkeypatch)
     model = llama.LlamaForCausalLM(cfg)
     ids = _sds((1, seq), jnp.int32, one_chip)
     shapes = jax.eval_shape(
@@ -567,8 +574,8 @@ def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
     """One SDAR layer at its widths (16 of 128 experts held, the vocabulary
     cut) under the block-diffusion objective, the weighted loss and its
     gradient under the engine's ``ds.step.loss``: the attention is the
-    ``bdattn`` pair (the forward twice: recomputation) and no ``flash``
-    call, the share's grouped matmuls are XLA's own, and the program's
+    ``bdattn`` pair (the forward ONCE: the recomputed layer takes the kept
+    output) and no ``flash`` call, the share's grouped matmuls are XLA's own, and the program's
     scopes are on the ops around them."""
     from deepspeed_tpu.models import llama
     from deepspeed_tpu.runtime.engine import _step_scope
@@ -579,12 +586,7 @@ def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
         num_local_experts=128, moe_experts_held=16, num_experts_per_tok=8,
         rope_theta=1e6, rms_norm_eps=1e-6, max_position_embeddings=seq,
         ce_chunk_size=2048, remat=True, objective="block_diffusion")
-    monkeypatch.setattr(llama, "on_tpu", lambda: True)
-    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
-    monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
-                        lambda force=None: True)
-    monkeypatch.setattr("deepspeed_tpu.ops.kernel_dispatch.device_kind",
-                        lambda: "TPU v5 lite")
+    _steer_the_model_to_the_chip(monkeypatch)
     model = llama.LlamaForCausalLM(cfg)
     shapes = jax.eval_shape(
         lambda: {"params": llama.unbox_params(model.init(
@@ -606,9 +608,98 @@ def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
     compiled = _compile(step, params, ids, targets, weights)
     names = [n.split(".")[0] for n in _custom_call_names(compiled)
              if not n.startswith("ragged-dot")]
-    assert {k: names.count(k) for k in set(names)} == {"bdattn_fwd": 2, "bdattn_bwd": 1}, names
+    assert {k: names.count(k) for k in set(names)} == {"bdattn_fwd": 1, "bdattn_bwd": 1}, names
     text = compiled.as_text()
     assert len(re.findall(r"%(ragged-dot-none[.\d]*) = ", text)) == 2 * (3 + 3 + 6)
     for scope in ("ds.step.loss", "ds.head.loss", "ds.rope", "ds.moe.route",
                   "ds.moe.dispatch", "ds.moe.combine"):
         assert f"/{scope}/" in text, scope
+
+
+# the three cells that train under whole-layer recomputation: (attention
+# layers, bytes of their kernels' outputs and log-sum-exps kept a step:
+# tokens x heads x (head_dim x 2 + 4) a layer)
+_RECOMPUTING_CELLS = {
+    "train-sdar-1chip-bd4-seq8k": ("bdattn", 6, 6 * 32768 * 32 * (128 * 2 + 4)),
+    "train-lfm2moe-1chip-seq8k": ("flash", 1, 32768 * 32 * (64 * 2 + 4)),
+    "train-granite4hm-1chip-longseq": ("flash", 1, 16384 * 32 * (64 * 2 + 4)),
+}
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+@pytest.mark.parametrize("cell", sorted(_RECOMPUTING_CELLS))
+def test_a_recomputing_cells_step_runs_each_attention_forward_once_and_fits(
+        one_chip, no_compile_cache, monkeypatch, cell):
+    """The cell's whole step at its own configuration and batch (the model
+    the benchmark's runner builds from ``benchmark/configs``, the engine's
+    fused step spelled out: cast, loss and gradient with the sown counters,
+    global norm, AdamW over float32 masters), compiled for the described
+    v5e: ONE forward attention kernel an attention layer (their outputs are
+    kept for the recomputed layers' backward), ``kept_residual_bytes`` (what
+    ``ds_remat_kept_bytes`` publishes) equal to the reckoned 1.64 / 0.14 /
+    0.07 GB, and the program's temporaries beside an engine at rest (12 B a
+    parameter: master and two moments, no accumulation buffer) under the
+    chip's ``bytes_limit``."""
+    import importlib
+    import json
+    import pathlib
+    import optax
+    from deepspeed_tpu.models import llama
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.runtime.engine import _as_apply_fns, _step_scope
+    from deepspeed_tpu.runtime.optimizers import build_optimizer
+    kernel, layers, reckoned = _RECOMPUTING_CELLS[cell]
+    bench = pathlib.Path(__file__).parents[3] / "benchmark"
+    workload = json.loads((bench / "workloads" / f"{cell}.json").read_text())
+    config = json.loads((bench / "configs" / f"{workload['config']}.json").read_text())
+    cfg = importlib.import_module(
+        f"benchmark.runners.{workload['runner']}").model_config(config)
+    assert cfg.remat and cfg.remat_policy is None
+    rows, seq = workload["traffic"]["global_batch"], workload["traffic"]["seq_len"]
+    _steer_the_model_to_the_chip(monkeypatch)
+    monkeypatch.setattr("deepspeed_tpu.ops.grouped_matmul.on_tpu", lambda: True)
+    model = llama.LlamaForCausalLM(cfg)
+    shapes = jax.eval_shape(lambda: llama.unbox_params(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, jnp.float32, one_chip), shapes)
+    n_params = sum(p.size for p in jax.tree_util.tree_leaves(params))
+    tx, _ = build_optimizer("AdamW", {"lr": 1e-4})
+    opt_state = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), jax.eval_shape(tx.init, params))
+    ids = lambda n: _sds((rows, n), jnp.int32, one_chip)        # noqa: E731
+    if cfg.block_diffusion_:
+        args = (ids(2 * seq), ids(seq))
+        kwargs = {"loss_weights": _sds((rows, seq), jnp.float32, one_chip)}
+    else:
+        args, kwargs = (ids(seq), ids(seq)), {}
+    _, apply_with_stats = _as_apply_fns(model)
+
+    def train_step(params, opt_state, args, kwargs):
+        with _step_scope("cast"):
+            compute = jax.tree_util.tree_map(lambda x: x.astype(cfg.dtype), params)
+
+        def loss_of(p):
+            out, stats = apply_with_stats(p, *args, **kwargs)
+            return out.astype(jnp.float32), stats
+
+        with _step_scope("loss"):
+            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(compute)
+        with _step_scope("grad_norm"):
+            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+            gnorm = optax.global_norm(grads)
+        with _step_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return loss, params, opt_state, gnorm, stats
+
+    traced = jax.jit(train_step, donate_argnums=(0, 1)).trace(
+        params, opt_state, args, kwargs)
+    assert kept_residual_bytes(traced.jaxpr) == reckoned
+    compiled = traced.lower().compile()
+    names = [n.split(".")[0] for n in _custom_call_names(compiled)]
+    assert names.count(f"{kernel}_fwd") == layers, names
+    other = "flash" if kernel == "bdattn" else "bdattn"
+    assert not any(n.startswith(other) for n in names), names
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries + 12 * n_params < V5E_BYTES_LIMIT, (temporaries, n_params)

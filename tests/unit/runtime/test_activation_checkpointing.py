@@ -55,6 +55,30 @@ def test_named_policy(setup):
         ckpt._CONFIG["policy"] = None
 
 
+@pytest.mark.parametrize("policy,forwards", [(None, 1), ("nothing_saveable", 2)])
+def test_no_policy_keeps_what_an_attention_kernel_gave(policy, forwards):
+    """``checkpoint`` of a function that reaches the flash kernels: with no
+    policy named their output and log-sum-exp are kept (one forward kernel in
+    the gradient), ``nothing_saveable`` recomputes them (two)."""
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.ops.attention import flash_attention
+    q = jnp.asarray(np.random.default_rng(0).normal(size=(1, 128, 2, 16)), jnp.float32)
+
+    def attend(q):
+        return jnp.sum(flash_attention(jnp.tanh(q), q, q, causal=True,
+                                       interpret=True) ** 2)
+
+    ckpt.configure(partition_activations=False, checkpoint_in_cpu=False)
+    ckpt._CONFIG["policy"] = policy
+    try:
+        traced = jax.jit(jax.grad(lambda q: ckpt.checkpoint(attend, q))).trace(q)
+    finally:
+        ckpt._CONFIG["policy"] = None
+    assert str(traced.jaxpr).count("name=flash_fwd") == forwards
+    assert kept_residual_bytes(traced.jaxpr) == (
+        q.size * 4 + 128 * 2 * 4 if policy is None else 0)
+
+
 def test_unknown_policy_raises(setup):
     params, x = setup
     ckpt._CONFIG["policy"] = "not_a_policy"

@@ -176,6 +176,7 @@ def test_windowed_sync_matches_synchronous_path():
                     jax.tree_util.tree_leaves(e_async.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
     assert e_async.global_steps == e_sync.global_steps == 6
+    assert e_async.grad_acc is None and e_sync.grad_acc is None  # fused steps
     # scheduler advanced once per non-skipped step despite deferred drains
     assert e_async.get_lr() == pytest.approx(e_sync.get_lr())
 
@@ -196,6 +197,7 @@ def test_fused_train_steps_vector_entries_drain():
     assert e.global_steps == 4
     e._drain_async_window()
     assert not e._async_window.entries
+    assert e.grad_acc is None       # K fused steps: no accumulation buffer
     # 4 warmup advances of lr happened at the drain
     assert e.lr_scheduler.last_batch_iteration == sched_pos + 4
 
@@ -253,7 +255,7 @@ def test_offload_prep_matches_host_norm_bitwise_fp32():
     acc = jax.tree_util.tree_map(
         lambda g: jnp.asarray(
             rng.integers(-8, 9, size=g.shape).astype(np.float32)),
-        e.grad_acc)
+        e._ensure_grad_acc())
     clipped_d, overflow_d, gnorm_d = e._offload_prep(acc, e.scale_state)
 
     # host mirror: same flat-key order, same left-fold, pure np.float32
@@ -281,7 +283,7 @@ def test_offload_prep_random_data_close_and_overflow():
     rng = np.random.default_rng(11)
     acc = jax.tree_util.tree_map(
         lambda g: jnp.asarray(rng.normal(size=g.shape), jnp.float32),
-        e.grad_acc)
+        e._ensure_grad_acc())
     clipped, overflow, gnorm = e._offload_prep(acc, e.scale_state)
     flat = np.concatenate([np.asarray(v, np.float64).ravel()
                            for v in jax.tree_util.tree_leaves(acc)])

@@ -25,6 +25,11 @@ def base_config(**over):
     return cfg
 
 
+def acc_gauge() -> float:
+    from deepspeed_tpu.observability import get_registry
+    return get_registry().gauge("ds_grad_acc_bytes").value
+
+
 def train_steps(engine, n=5, hidden=16, seed=0):
     rng = np.random.default_rng(seed)
     losses = []
@@ -74,9 +79,33 @@ def test_gradient_accumulation():
     cfg = base_config(train_batch_size=16, gradient_accumulation_steps=2)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config=cfg)
     assert engine.gradient_accumulation_steps() == 2
+    # the accumulation buffer is the unfused path's: none after initialize,
+    # made by the first forward with the dtype and shardings it always had,
+    # and it holds the sum of the microbatches' gradients of loss / gas
+    assert engine.grad_acc is None and acc_gauge() == 0
+    rng = np.random.default_rng(5)
+    want = None
+    for _ in range(2):
+        x = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
+        g = jax.grad(lambda p: model.apply({"params": p}, x, jnp.zeros_like(x)) / 2)(
+            engine.params)
+        want = g if want is None else jax.tree_util.tree_map(jnp.add, want, g)
+        engine.backward(engine.forward(x, jnp.zeros_like(x)))
+        if want is g:
+            engine.step()           # not a boundary: the sums stay
+    jax.tree_util.tree_map(
+        lambda a, s, w: (np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                                    rtol=1e-5, atol=1e-7),
+                         a.dtype == jnp.float32 or pytest.fail(str(a.dtype)),
+                         a.sharding == s or pytest.fail(str(a.sharding))),
+        engine.grad_acc, engine.grad_shardings, want)
+    assert acc_gauge() == sum(4 * p.size for p in jax.tree_util.tree_leaves(params))
+    engine.step()
+    assert engine.global_steps == 1 and engine.micro_steps == 2
+    assert all(not np.asarray(a).any() for a in jax.tree_util.tree_leaves(engine.grad_acc))
     losses = train_steps(engine, n=3)
-    assert engine.global_steps == 3
-    assert engine.micro_steps == 6
+    assert engine.global_steps == 4
+    assert engine.micro_steps == 8
 
 
 @pytest.mark.world_size(8)
@@ -122,12 +151,30 @@ def test_lr_scheduler_from_config():
 
 
 @pytest.mark.world_size(8)
-def test_checkpoint_save_load(tmp_path):
+@pytest.mark.parametrize("saved_by", ["unfused", "fused", "parent_commit"])
+def test_checkpoint_save_load(tmp_path, saved_by, monkeypatch):
+    """A checkpoint holds the accumulation buffer only where the engine that
+    wrote it had one (the unfused path) and says so in its host state; one
+    written by the commit before this rule always holds one and has no such
+    key. Each restores into an engine with or without a buffer of its own."""
     model, params = simple_model_and_params()
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params,
                                                config=base_config())
-    train_steps(engine, n=3, seed=1)
+    if saved_by == "fused":
+        for x in np.random.default_rng(1).normal(size=(3, 8, 16)).astype(np.float32):
+            engine.fused_train_step(jnp.asarray(x), jnp.zeros((8, 16)))
+        assert engine.grad_acc is None and acc_gauge() == 0
+    else:
+        train_steps(engine, n=3, seed=1)
+        assert engine.grad_acc is not None
+    if saved_by == "parent_commit":
+        host_state = engine._host_state
+        monkeypatch.setattr(engine, "_host_state", lambda client_state: {
+            k: v for k, v in host_state(client_state).items() if k != "grad_acc"})
     engine.save_checkpoint(str(tmp_path), tag="tag3")
+    monkeypatch.undo()
+    assert engine._peek_host_state(str(tmp_path / "tag3")).get("grad_acc") == {
+        "unfused": True, "fused": False, "parent_commit": None}[saved_by]
     p_before = jax.tree_util.tree_map(np.asarray, engine.params)
 
     # keep training, then restore and check exact state return
@@ -137,6 +184,60 @@ def test_checkpoint_save_load(tmp_path):
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_array_equal(np.asarray(a), b), engine.params, p_before)
     assert engine.global_steps == 3
+    assert (engine.grad_acc is None) == (saved_by == "fused")
+    assert (acc_gauge() == 0) == (saved_by == "fused")
+    # a fresh engine (no buffer of its own) takes the same state and goes on
+    # to the same parameters, by either path
+    fresh, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=simple_model_and_params()[1], config=base_config())
+    fresh.load_checkpoint(str(tmp_path), tag="tag3")
+    assert (fresh.grad_acc is None) == (saved_by == "fused")
+    train_steps(engine, n=2, seed=3)
+    for x in np.random.default_rng(3).normal(size=(2, 8, 16)):
+        fresh.fused_train_step(jnp.asarray(x, jnp.float32), jnp.zeros((8, 16)))
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                                rtol=1e-5, atol=1e-7),
+        engine.params, fresh.params)
+
+
+@pytest.mark.world_size(8)
+def test_checkpoint_in_mid_accumulation_resumes_on_the_same_sums(tmp_path):
+    """Saved after the first of two microbatches, the buffer's sums are in
+    the checkpoint: a fresh engine that restores it and takes the second
+    microbatch steps to the parameters of the run that never stopped."""
+    model, params = simple_model_and_params()
+    cfg = base_config(train_batch_size=16, gradient_accumulation_steps=2)
+    rng = np.random.default_rng(9)
+    x1, x2 = (jnp.asarray(rng.normal(size=(8, 16)), jnp.float32) for _ in range(2))
+
+    def micro(engine, x):
+        engine.backward(engine.forward(x, jnp.zeros_like(x)))
+        engine.step()
+
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params,
+                                               config=cfg)
+    micro(engine, x1)
+    assert engine.global_steps == 0 and engine.micro_steps == 1
+    sums = jax.tree_util.tree_map(np.asarray, engine.grad_acc)
+    assert any(a.any() for a in jax.tree_util.tree_leaves(sums))
+    engine.save_checkpoint(str(tmp_path), tag="mid")
+    micro(engine, x2)
+    assert engine.global_steps == 1
+
+    resumed, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=simple_model_and_params()[1], config=cfg)
+    assert resumed.grad_acc is None
+    resumed.load_checkpoint(str(tmp_path), tag="mid")
+    assert resumed.micro_steps == 1
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
+        resumed.grad_acc, sums)
+    micro(resumed, x2)
+    assert resumed.global_steps == 1
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        resumed.params, engine.params)
 
 
 @pytest.mark.world_size(8)
@@ -186,14 +287,19 @@ def test_eval_mode_forward_is_grad_free():
                                                config=base_config())
     x = jnp.ones((8, 16))
     engine.eval()
-    acc0 = jax.tree_util.tree_map(np.asarray, engine.grad_acc)
+    assert engine.grad_acc is None
     l1 = float(engine.forward(x, jnp.zeros_like(x)))
     l2 = float(engine.forward(x, jnp.zeros_like(x)))  # twice: no _pending error
     assert l1 == l2 == float(engine.eval_batch(x, jnp.zeros_like(x)))
+    assert engine.grad_acc is None  # no gradient was made, nor a buffer for one
+    engine.train()
+    loss = engine.forward(x, jnp.zeros_like(x))
+    acc0 = jax.tree_util.tree_map(np.asarray, engine.grad_acc)
+    engine.eval()
+    engine.forward(x, jnp.zeros_like(x))
     jax.tree_util.tree_map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
                            engine.grad_acc, acc0)  # grads untouched
     engine.train()
-    loss = engine.forward(x, jnp.zeros_like(x))
     engine.backward(loss)
     engine.step()
     assert engine.global_steps == 1

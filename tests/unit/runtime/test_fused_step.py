@@ -50,6 +50,10 @@ def test_fused_matches_split_sequence():
                     jax.tree_util.tree_leaves(e2.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
     assert e2.global_steps == 5
+    # the fused step holds no accumulation buffer; the three calls made one
+    from deepspeed_tpu.observability import get_registry
+    assert e2.grad_acc is None and e1.grad_acc is not None
+    assert get_registry().gauge("ds_grad_acc_bytes").value == 0  # e2 wrote last
 
 
 def test_fused_with_fp16_scaling_and_clipping():
@@ -121,6 +125,7 @@ def test_gas_fused_train_batch_matches_micro_loop():
             ls.append(float(loss))
         loop_losses.append(sum(ls) / 4)
 
+    assert eng_fused.grad_acc is None and eng_loop.grad_acc is not None
     np.testing.assert_allclose(fused_losses, loop_losses, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(eng_fused.params),
                     jax.tree_util.tree_leaves(eng_loop.params)):
@@ -214,13 +219,21 @@ def test_grad_accum_dtype_knob():
         losses = [engine.train_batch(data) for _ in range(3)]
         return engine, losses
 
+    def acc_dtypes(engine):
+        # the fused batch carries its sums in the program: the engine's own
+        # buffer is made by the first unfused forward, at the dtype asked for
+        assert engine.grad_acc is None
+        x = jnp.ones((engine.train_micro_batch_size_per_gpu() * engine.dp_world_size, 16))
+        engine.backward(engine.forward(x, jnp.zeros_like(x)))
+        leaves = jax.tree_util.tree_leaves(engine.grad_acc)
+        assert len(leaves) == len(jax.tree_util.tree_leaves(engine.params))
+        return {l.dtype for l in leaves}
+
     ref_engine, ref = run(None)
-    assert all(l.dtype == jnp.float32
-               for l in jax.tree_util.tree_leaves(ref_engine.grad_acc))
+    assert acc_dtypes(ref_engine) == {jnp.dtype(jnp.float32)}
 
     bf_engine, bf = run("bf16")
-    assert all(l.dtype == jnp.bfloat16
-               for l in jax.tree_util.tree_leaves(bf_engine.grad_acc))
+    assert acc_dtypes(bf_engine) == {jnp.dtype(jnp.bfloat16)}
     np.testing.assert_allclose(bf, ref, rtol=5e-3)
 
     with pytest.raises(ValueError, match="grad_accum_dtype"):

@@ -422,7 +422,7 @@ class TestZero3Checkpoint:
         e = _z3()
         e.train_batch(iter(_data()))
         e.save_checkpoint(str(tmp_path), tag="meta")
-        saved = e._peek_zero3_store_meta(str(tmp_path / "meta"))
+        saved = e._peek_host_state(str(tmp_path / "meta")).get("zero3_store")
         assert saved is not None
         assert saved["n_leaves"] == e._zero3_store.n_leaves
         assert saved["persistent_idx"] == list(e._zero3_store.p_idx)
