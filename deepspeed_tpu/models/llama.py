@@ -36,7 +36,8 @@ class LayerSpec:
     """What ONE decoder layer is built from, for models whose layers are not
     all alike (LFM2: gated short convolutions with an attention layer among
     every few, a dense FFN in the leading layers and experts after)."""
-    # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer)
+    # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer) |
+    # "latent" (LatentAttention: DeepSeek-V2/V3's MLA)
     operator: str = "attention"
     ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
     ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
@@ -118,7 +119,8 @@ class LlamaConfig:
     # ``index * held .. (index + 1) * held``. None = all of them
     moe_experts_held: Optional[int] = None
     moe_share_index: int = 0
-    # "softmax" (Mixtral, OLMoE, Qwen2-MoE) | "sigmoid" (LFM2, DeepSeek-V3):
+    # "softmax" (Mixtral, OLMoE, Qwen2-MoE) | "sigmoid" (LFM2; DeepSeek-V3's
+    # ``noaux_tc`` with one group, as Kimi-VL's language model has it):
     # independent scores; with ``moe_selection_bias`` the top-k is taken of
     # score + bias (a buffer: no gradient, its update rule is the training
     # recipe's and not implemented) and weighted by the unbiased scores
@@ -130,14 +132,26 @@ class LlamaConfig:
     # sharded_moe.py l_aux); the engine adds sown "aux_loss" scalars to the
     # training loss
     router_aux_loss_coef: float = 0.0
-    # Qwen2-MoE: dense "shared expert" added to the sparse output, scaled by
-    # a sigmoid gate (None = no shared expert)
+    # A dense "shared expert" this wide added to the sparse output (None =
+    # none): scaled by a sigmoid gate of the token (Qwen2-MoE), or with
+    # ``shared_expert_gated`` False added as it is (DeepSeek-V2/V3:
+    # ``n_shared_experts * moe_intermediate_size`` wide, weight 1)
     shared_expert_intermediate_size: Optional[int] = None
+    shared_expert_gated: bool = True
     moe_grouped: bool = True      # grouped GEMM (FLOPs ∝ top-k) vs dense-over-experts
     # One LayerSpec a layer, for models whose layers differ; None = every
     # layer attention + the one global FFN the fields above describe
     layer_specs: Optional[Tuple[LayerSpec, ...]] = None
     conv_L_cache: int = 3         # taps of the "conv" operator
+    # the "latent" operator (MLA as it trains; nothing is absorbed): q is
+    # ``heads x head_dim`` straight from the stream (no q_lora_rank), its last
+    # ``rotary_dim`` values the rope part (HF ``qk_rope_head_dim``) and the
+    # others the part without (``qk_nope_head_dim``); k and v come from a
+    # latent of ``kv_lora_rank`` under its own RMSNorm, the rotary part of k
+    # is ONE ``rotary_dim`` key a token shared by the heads, v is
+    # ``v_head_dim`` wide
+    kv_lora_rank: int = 0
+    v_head_dim: int = 0
     # the "mamba" operator (Mamba-2): heads of mamba_d_head values, a state
     # of mamba_d_state a value, B and C shared by the heads of a group, the
     # scan in chunks of mamba_chunk_size, mamba_d_conv taps before it
@@ -236,7 +250,14 @@ class LlamaConfig:
         xbc = inner + 2 * self.mamba_n_groups * self.mamba_d_state
         mamba = (h * (inner + xbc + self.mamba_n_heads) + inner * h
                  + (self.mamba_d_conv + 1) * xbc + 3 * self.mamba_n_heads + inner)
-        operator = {"conv": conv, "attention": attn, "mamba": mamba}
+        rope = self.rotary_dim or 0
+        latent = (h * self.num_attention_heads * hd
+                  + h * (self.kv_lora_rank + rope)
+                  + self.kv_lora_rank * (1 + self.num_attention_heads
+                                         * (hd - rope + self.v_head_dim))
+                  + self.num_attention_heads * self.v_head_dim * h)
+        operator = {"conv": conv, "attention": attn, "mamba": mamba,
+                    "latent": latent}
         return max(operator[spec.operator] + ffn(spec.ffn, spec.ffn_width) + 2 * h
                    for spec in self.layer_specs)
 
@@ -583,6 +604,89 @@ class LlamaAttention(nn.Module):
                                             scale=cfg.attn_scale)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3, HF ``modeling_deepseek.
+    py``), the operator of a ``"latent"`` layer, in its training form:
+
+        q = W_q x                      [heads, nope + rope]; rope part rotated
+        [c | k_r] = W_kva x            c: kv_lora_rank; k_r: ONE rope key a token
+        [k_nope | v] = W_kvb rms(c)    [heads, nope + v]
+        k = [k_nope | rope(k_r)]       k_r broadcast over the heads
+        out = W_o softmax_causal(q k^T / sqrt(nope + rope)) v
+
+    q and k are ``nope + rope`` = ``head_dim`` wide (``rope`` is the config's
+    ``rotary_dim``) and v ``v_head_dim``: on one TPU device
+    the kernels are ``ops/attention.py``'s at two widths (``mla_fwd`` /
+    ``mla_bwd``), with no operand padded; anywhere else (a CPU, a mesh of
+    more than one device) XLA's attention with the scores by hand, correct
+    and unmeasured. Nothing is absorbed into the projections: that is
+    decode's form. The gradient of ``k_r`` is the sum over the heads (the
+    broadcast's transpose), that of ``c`` goes through ``kv_a_layernorm``.
+    Sows ``mla_stats`` (only when mutable): the rms of ``c`` before its norm
+    and of ``k_r`` before the rotary embedding."""
+    config: LlamaConfig
+    layer_idx: int = 0
+
+    @nn.compact
+    def __call__(self, x, cos, sin, positions, attn_mask=None):
+        cfg = self.config
+        b, s, _ = x.shape
+        nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        d_rope, d_v = cfg.rotary_dim or 0, cfg.v_head_dim
+        d_nope = cfg.head_dim_ - d_rope
+        if not (rank and d_v and 0 < d_rope < cfg.head_dim_):
+            raise ValueError("the latent operator needs kv_lora_rank, v_head_dim "
+                             "and a rotary_dim short of head_dim")
+        if (attn_mask is not None or _layer_window(cfg, self.layer_idx) is not None
+                or cfg.pos_embedding != "rope" or cfg.block_diffusion_
+                or cfg.attn_logit_softcapping is not None):
+            raise ValueError(
+                "the latent operator is causal attention with a rotary key: "
+                "no padding mask, window, softcapping or other position form")
+        q = _dense(nh * (d_nope + d_rope), "q_proj", (EMBED, HEADS), cfg.dtype)(x)
+        kva = _dense(rank + d_rope, "kv_a_proj_with_mqa", (EMBED, None), cfg.dtype)(x)
+        # every scope closes before the kernel's call below: one that held
+        # it would rename the instruction (docs/observability.md)
+        with jax.named_scope("ds.mla.assemble"):
+            q = q.reshape(b, s, nh, d_nope + d_rope)
+            q_nope, q_rope = q[..., :d_nope], q[..., d_nope:]
+            c, k_r = kva[..., :rank], kva[..., rank:].reshape(b, s, 1, d_rope)
+        if self.is_mutable_collection("mla_stats"):
+            def rms(a):
+                a = jax.lax.stop_gradient(a).astype(jnp.float32)
+                return jnp.sqrt(jnp.mean(a * a))
+            for name, value in (("latent_rms", rms(c)), ("k_rope_rms", rms(k_r))):
+                self.sow("mla_stats", name, value, reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.float32(0.0))
+        c = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="kv_a_layernorm")(c)
+        kvb = _dense(nh * (d_nope + d_v), "kv_b_proj", (None, HEADS), cfg.dtype)(c)
+        with jax.named_scope("ds.rope"):
+            q_rope = apply_rope(q_rope, cos, sin, positions,
+                                interleaved=cfg.rope_interleaved)
+            k_r = apply_rope(k_r, cos, sin, positions,
+                             interleaved=cfg.rope_interleaved)
+        with jax.named_scope("ds.mla.assemble"):
+            kvb = kvb.reshape(b, s, nh, d_nope + d_v)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kvb[..., :d_nope], jnp.broadcast_to(k_r, (b, s, nh, d_rope))],
+                axis=-1)
+            v = kvb[..., d_nope:]
+
+        from ..ops.attention import _xla_attention, flash_attention
+        scale = (cfg.attn_scale if cfg.attn_scale is not None
+                 else 1.0 / float(np.sqrt(d_nope + d_rope)))
+        one_device = all(n == 1 for n in _mesh_shape().values())
+        if (cfg.attn_impl != "xla" and (cfg.attn_impl == "flash" or on_tpu())
+                and one_device and (s <= 128 or s % 128 == 0)):
+            attn = flash_attention(q, k, v, causal=True, scale=scale,
+                                   interpret=interpret_kernels())
+        else:
+            attn = _xla_attention(q, k, v, scale, True)
+        return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype)(
+            attn.reshape(b, s, nh * d_v))
+
+
 class ShortConvOperator(nn.Module):
     """LFM2's gated short convolution, the operator of a ``"conv"`` layer:
     ``in_proj`` to ``B | C | u``, ``y = C * conv(B * u)`` with
@@ -720,9 +824,14 @@ class LlamaMoEBlock(nn.Module):
     module_inject/containers mixtral): a router ``num_local_experts`` wide,
     top-k, SwiGLU experts ``intermediate_size`` wide. Scoring is a softmax
     over the experts (Mixtral, renormalized; OLMoE and Qwen2-MoE, not) or
-    independent sigmoids (LFM2), there with a selection bias added for the
-    top-k only, the chosen experts weighted by their unbiased scores,
-    ``p / (sum p + moe_renorm_eps)`` and ``routed_scaling_factor``.
+    independent sigmoids (LFM2, DeepSeek-V3), there with a selection bias
+    added for the top-k only, the chosen experts weighted by their unbiased
+    scores, ``p / (sum p + moe_renorm_eps)`` and ``routed_scaling_factor``.
+    A shared expert (``shared_expert_intermediate_size``) is one dense
+    SwiGLU every token passes, added to the routed sum: under a sigmoid gate
+    of the token (Qwen2-MoE) or as it is (DeepSeek-V2/V3,
+    ``shared_expert_gated`` False); a share of the experts computes it whole,
+    as every chip of an expert-parallel layer does.
 
     The experts whose matrices live in this block are all of the router's,
     or share ``moe_share_index`` of them (``moe_experts_held``): the block
@@ -827,14 +936,17 @@ class LlamaMoEBlock(nn.Module):
             fn = moe_grouped_mlp if cfg.moe_grouped else moe_dense_mlp
             out = fn(xt, w1, w3, w2, idx, w)
         out = out.reshape(*lead, H)
-        if cfg.shared_expert_intermediate_size:  # Qwen2-MoE
+        if cfg.shared_expert_intermediate_size:
             se_cfg = dataclasses.replace(
                 cfg, intermediate_size=cfg.shared_expert_intermediate_size,
                 num_local_experts=0)
-            shared = LlamaMLP(se_cfg, name="shared_expert")(x)
-            g = _dense(1, "shared_expert_gate", (EMBED, HIDDEN), jnp.float32)(
-                x.astype(jnp.float32))
-            out = out + jax.nn.sigmoid(g).astype(cfg.dtype) * shared
+            with jax.named_scope("ds.moe.shared"):
+                shared = LlamaMLP(se_cfg, name="shared_expert")(x)
+                if cfg.shared_expert_gated:  # Qwen2-MoE
+                    g = _dense(1, "shared_expert_gate", (EMBED, HIDDEN), jnp.float32)(
+                        x.astype(jnp.float32))
+                    shared = jax.nn.sigmoid(g).astype(cfg.dtype) * shared
+            out = out + shared
         return out
 
 
@@ -864,8 +976,10 @@ class LlamaDecoderLayer(nn.Module):
                 h = x + branch(ShortConvOperator(cfg, name="conv")(normed))
             elif spec.operator == "mamba":
                 h = x + branch(Mamba2Mixer(cfg, name="mamba")(normed))
-            elif spec.operator == "attention":
-                h = x + branch(LlamaAttention(cfg, self.layer_idx, name="self_attn")(
+            elif spec.operator in ("attention", "latent"):
+                op_cls = (LatentAttention if spec.operator == "latent"
+                          else LlamaAttention)
+                h = x + branch(op_cls(cfg, self.layer_idx, name="self_attn")(
                     normed, cos, sin, positions, attn_mask))
             else:
                 raise ValueError(f"unknown operator {spec.operator!r}")
@@ -1032,7 +1146,8 @@ class LlamaModel(nn.Module):
             # sums all leaves, so stacking ≡ the unscanned reduce_fn sum)
             ScanLayer = nn.scan(_ScanBody,
                                 variable_axes={"params": 0, "aux_loss": 0,
-                                               "moe_stats": 0, "ssm_stats": 0},
+                                               "moe_stats": 0, "ssm_stats": 0,
+                                               "mla_stats": 0},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,

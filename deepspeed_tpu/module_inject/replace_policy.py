@@ -468,6 +468,102 @@ class Lfm2MoePolicy(HFCheckpointPolicy):
         return out
 
 
+class DeepseekV3Policy(HFCheckpointPolicy):
+    """DeepSeek-V3 text blocks (HF ``modeling_deepseek.py``, ``model_type:
+    deepseek_v3``; Kimi-VL's and Moonlight's language model): pre-norm
+    layers of multi-head latent attention (``q_proj`` straight from the
+    stream where ``q_lora_rank`` is null, ``kv_a_proj_with_mqa`` to a latent
+    of ``kv_lora_rank`` and ONE rope key of ``qk_rope_head_dim``,
+    ``kv_a_layernorm``, ``kv_b_proj`` to each head's ``qk_nope_head_dim``
+    key part and ``v_head_dim`` values, scores over ``sqrt(nope + rope)``);
+    a SwiGLU ``intermediate_size`` wide in the first
+    ``first_k_dense_replace`` layers and afterwards ``n_routed_experts``
+    experts ``moe_intermediate_size`` wide, top-k of sigmoid scores plus
+    ``e_score_correction_bias`` (``topk_method: noaux_tc``, a buffer),
+    weighted by the unbiased scores over ``sum + 1e-20`` (``norm_topk_prob``)
+    times ``routed_scaling_factor``, beside ``n_shared_experts *
+    moe_intermediate_size`` of ungated shared expert. The rotary embedding
+    turns adjacent pairs of the rope slices (the modeling code
+    de-interleaves, then rotates halves: the same rotation). Not built, and
+    refused: ``q_lora_rank``, ``rope_scaling`` (YaRN and its mscale), expert
+    groups (``n_group > 1``), ``moe_layer_freq != 1``, biases. A chip's share
+    of the experts and of the vocabulary is the deployment's to set
+    (``moe_experts_held``, ``vocab_size``), not the checkpoint's."""
+    arch = "deepseek_v3"
+    col_parallel = ["q_proj", "kv_b_proj", "gate_proj", "up_proj"]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        for key in ("q_lora_rank", "rope_scaling", "attention_bias"):
+            if hf_config.get(key):
+                raise ValueError(f"deepseek_v3: {key}={hf_config[key]!r} is not supported")
+        if (hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1
+                or hf_config.get("moe_layer_freq", 1) != 1):
+            raise ValueError("deepseek_v3: expert groups (n_group, topk_group) "
+                             "and moe_layer_freq other than 1 are not supported")
+        if (hf_config.get("scoring_func", "sigmoid") != "sigmoid"
+                or hf_config.get("topk_method", "noaux_tc") != "noaux_tc"):
+            raise ValueError("deepseek_v3: only the sigmoid router with a "
+                             "selection bias (noaux_tc) is supported")
+        depth = hf_config["num_hidden_layers"]
+        dense = min(hf_config.get("first_k_dense_replace", 0), depth)
+        experts = hf_config.get("n_routed_experts") or 0
+        if not experts:
+            dense = depth
+        specs = tuple(
+            LayerSpec(operator="latent", ffn="dense" if i < dense else "moe",
+                      ffn_width=hf_config["intermediate_size"] if i < dense
+                      else hf_config["moe_intermediate_size"])
+            for i in range(depth))
+        nope, rope = hf_config["qk_nope_head_dim"], hf_config["qk_rope_head_dim"]
+        shared = hf_config.get("n_shared_experts") or 0
+        cfg = super().config_from_hf(hf_config)
+        self.bind(dataclasses.replace(
+            cfg, layer_specs=specs, head_dim=nope + rope, rotary_dim=rope,
+            rope_interleaved=True, kv_lora_rank=hf_config["kv_lora_rank"],
+            v_head_dim=hf_config["v_head_dim"],
+            num_local_experts=experts if dense < depth else 0,
+            num_experts_per_tok=hf_config.get("num_experts_per_tok", 8),
+            moe_scoring="sigmoid", moe_selection_bias=True,
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_renorm_eps=1e-20,   # in the modeling code, not a config key
+            routed_scaling_factor=float(hf_config.get("routed_scaling_factor", 1.0)),
+            shared_expert_intermediate_size=(
+                shared * hf_config["moe_intermediate_size"] or None),
+            shared_expert_gated=False))
+        return self._cfg
+
+    def bind(self, cfg: LlamaConfig):
+        """As ``Lfm2MoePolicy.bind``: the name maps depend on the layer's kind."""
+        self._cfg = cfg
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "input_layernorm.weight": (f + "operator_norm/weight", False),
+               p + "post_attention_layernorm.weight": (f + "ffn_norm/weight", False),
+               p + "self_attn.kv_a_layernorm.weight": (
+                   f + "self_attn/kv_a_layernorm/weight", False)}
+        for proj in ("q_proj", "kv_a_proj_with_mqa", "kv_b_proj", "o_proj"):
+            out[p + f"self_attn.{proj}.weight"] = (f + f"self_attn/{proj}/kernel", True)
+        if self._cfg.layer_specs[layer].ffn == "dense":
+            for proj in ("gate_proj", "up_proj", "down_proj"):
+                out[p + f"mlp.{proj}.weight"] = (f + f"mlp/{proj}/kernel", True)
+        return out
+
+    def moe_map(self, layer: int, num_experts: int):
+        if self._cfg.layer_specs[layer].ffn != "moe":
+            return {}, {}
+        gate, experts = _mlp_experts_map(layer, num_experts)
+        p = f"model.layers.{layer}.mlp."
+        f = f"layers_{layer}/block_sparse_moe/"
+        gate[p + "gate.e_score_correction_bias"] = (f + "expert_bias", False)
+        if self._cfg.shared_expert_intermediate_size:
+            for proj in ("gate_proj", "up_proj", "down_proj"):
+                gate[p + f"shared_experts.{proj}.weight"] = (
+                    f + f"shared_expert/{proj}/kernel", True)
+        return gate, experts
+
+
 class GraniteMoeHybridPolicy(HFCheckpointPolicy):
     """Granite 4.0-H, the dense hybrid (HF ``modeling_granitemoehybrid.py``):
     pre-norm layers whose mixer is, by ``layer_types``, a Mamba-2 layer
@@ -1627,6 +1723,8 @@ _POLICIES = {
     "OlmoeForCausalLM": OlmoePolicy,
     "sdar_moe": SdarMoePolicy,
     "SDARMoeForCausalLM": SdarMoePolicy,
+    "deepseek_v3": DeepseekV3Policy,
+    "DeepseekV3ForCausalLM": DeepseekV3Policy,
     "lfm2_moe": Lfm2MoePolicy,
     "Lfm2MoeForCausalLM": Lfm2MoePolicy,
     "granitemoehybrid": GraniteMoeHybridPolicy,

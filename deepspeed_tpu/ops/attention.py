@@ -29,6 +29,12 @@ dead step names the block already resident. Blocks come from the shape
 (``kernel_dispatch.choose_blocks``: 1024 folded query rows a step at any
 group, and 512 keys or as many as the queries, where the sequences allow).
 
+Values may be narrower than keys (latent attention as it trains: q and k
+``[.., 192]``, v and o ``[.., 128]``): the same kernels with v's, dO's, O's
+and dV's blocks at v's width, named ``mla_fwd`` / ``mla_bwd`` (the pair
+``mla_bwd_dq`` + ``mla_bwd_dkdv``) where the widths differ, and no operand
+padded in HBM.
+
 A third structured mask has kernels of its own, under names of their own:
 block-diffusion training's (``block_diffusion_attention``: ``bdattn_fwd``,
 ``bdattn_bwd``; the section at the end of this file says how its tiles are
@@ -86,7 +92,7 @@ def _xla_attention(q, k, v, scale, causal, window=None, softcap=None,
         s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 # Row statistics (running max and sum, the saved LSE, delta) are kept in
@@ -232,7 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
         # GPU flash uses fp16/bf16 P too); the accumulator stays fp32
         pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc[:] = acc[:] * _lanes(corr, d) + pv
+        acc[:] = acc[:] * _lanes(corr, acc.shape[1]) + pv
         m_s[:] = m_cur
 
     _when_live(qi, ki, block_q, block_k, causal, window, _compute)
@@ -250,15 +256,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
 
 
 def _regroup(q, k, v):
-    """[B,S,H,D]/[B,S,KV,D] -> qg [B*KV, G, Sq, D], kt/vt [B*KV, Sk, D]."""
+    """[B,S,H,D]/[B,S,KV,D] -> qg [B*KV, G, Sq, D], kt/vt [B*KV, Sk, D],
+    each operand at its own width (latent attention's v is narrower)."""
     B, Sq, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
     qg = (q.reshape(B, Sq, KV, G, D).transpose(0, 2, 3, 1, 4)
           .reshape(B * KV, G, Sq, D))
-    kt = k.transpose(0, 2, 1, 3).reshape(B * KV, k.shape[1], D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * KV, v.shape[1], D)
+    kt = k.transpose(0, 2, 1, 3).reshape(B * KV, k.shape[1], k.shape[3])
+    vt = v.transpose(0, 2, 1, 3).reshape(B * KV, v.shape[1], v.shape[3])
     return qg, kt, vt
+
+
+def _kernel_name(flash: str, latent: str, D: int, Dv: int) -> str:
+    """A call whose values are as wide as its keys is ``flash_*``; one whose
+    widths differ (latent attention: 192-wide q and k, 128-wide v and o) is
+    ``mla_*``, so that a reader of the device trace, which counts a call's
+    work from ONE width off its first result, tells them apart by name."""
+    return flash if Dv == D else latent
 
 
 def resolved_attention_variant() -> str:
@@ -283,9 +298,9 @@ def _blocked(Sq, Sk, block_q, block_k):
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
                softcap=None):
     """Per-head Pallas forward → (o, lse[B*KV, G, Sq, 1])."""
-    from .kernel_dispatch import flash_vmem_bytes
+    from .kernel_dispatch import flash_vmem_bytes, vmem_width
     B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[3]
     assert H % KV == 0, (H, KV)
     G = H // KV
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
@@ -305,31 +320,31 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
         in_specs=[
             pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((1, block_k, D), kv_map),
-            pl.BlockSpec((1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_k, Dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, G, block_q, Dv), lambda b, i, j: (b, 0, i, 0)),
             # trailing unit lane dim: every reshape of the LSE then keeps the
             # minormost dim intact (a supported Mosaic shape cast), unlike
             # (1,G,BQ)->(G*BQ,1) which fails to lower for G > 1
             pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B * KV, G, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * KV, G, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G * block_q, D), jnp.float32),
+            pltpu.VMEM((G * block_q, Dv), jnp.float32),
             pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
             pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
         ],
         compiler_params=_compiler_params(flash_vmem_bytes(
-            "fwd", G, D, q.dtype.itemsize, block_q, block_k)),
+            "fwd", G, vmem_width(D, Dv), q.dtype.itemsize, block_q, block_k)),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", "mla_fwd", D, Dv),
     )(qg, kt, vt)
-    o = (out.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
-         .reshape(B, Sq, H, D))
+    o = (out.reshape(B, KV, G, Sq, Dv).transpose(0, 3, 1, 2, 4)
+         .reshape(B, Sq, H, Dv))
     return o, lse
 
 
@@ -355,7 +370,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         q = q_ref[0].reshape(g * bq, d)
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0].reshape(g * bq, d)
+        do = do_ref[0].reshape(g * bq, do_ref.shape[3])
         # lse/delta carry a trailing unit lane dim so this reshape is a
         # supported Mosaic cast (minormost dim preserved); no 1D
         # intermediates. Read once a step, the columns cost less here than
@@ -426,7 +441,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         q = q_ref[0].reshape(g * bq, d)
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0].reshape(g * bq, d)
+        do = do_ref[0].reshape(g * bq, do_ref.shape[3])
         lse = lse_ref[0, 0]      # [1, G*BQ]: rows, g-major like q's
         delta = delta_ref[0, 0]
 
@@ -488,15 +503,15 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     trailing unit dimension). ``fused``: one ``flash_dkdv_dq`` call in place
     of ``flash_dq`` and ``flash_dkdv`` (``kernel_dispatch`` decides: the
     float32 dQ of a KV head's whole sequence has to fit in VMEM)."""
-    from .kernel_dispatch import flash_vmem_bytes
+    from .kernel_dispatch import flash_vmem_bytes, vmem_width
     q, k, v, o, lse = res
     B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // KV
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
     params = _compiler_params(flash_vmem_bytes(
-        "fused" if fused else "bwd", G, D, q.dtype.itemsize, block_q, block_k,
-        seq_q=Sq))
+        "fused" if fused else "bwd", G, vmem_width(D, Dv), q.dtype.itemsize,
+        block_q, block_k, seq_q=Sq))
     static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                   window=window, softcap=softcap)
 
@@ -512,18 +527,20 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
                                       window), 0)
 
         q_spec = pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0))
+        do_spec = pl.BlockSpec((1, G, block_q, Dv), lambda b, i, j: (b, 0, i, 0))
         k_spec = pl.BlockSpec((1, block_k, D), kv_map)
+        v_spec = pl.BlockSpec((1, block_k, Dv), kv_map)
         r_spec = pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0))
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, num_kv=num_kv, **static),
             grid=(B * KV, num_q, num_kv),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+            in_specs=[q_spec, k_spec, v_spec, do_spec, r_spec, r_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((G * block_q, D), jnp.float32)],
             compiler_params=params,
             interpret=interpret,
-            name="flash_dq",
+            name=_kernel_name("flash_dq", "mla_bwd_dq", D, Dv),
         )(qg, kt, vt, dog, lse.reshape(delta.shape), delta)
 
     # kv-major grid for dk/dv: q sweep innermost. lse and delta enter as
@@ -538,14 +555,17 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
 
     q_spec2 = pl.BlockSpec((1, G, block_q, D),
                            lambda b, j, i: (b, 0, q_blk(j, i), 0))
+    do_spec2 = pl.BlockSpec((1, G, block_q, Dv),
+                            lambda b, j, i: (b, 0, q_blk(j, i), 0))
     k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    v_spec2 = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
     r_spec2 = pl.BlockSpec((1, 1, 1, G * block_q),
                            lambda b, j, i: (b, q_blk(j, i), 0, 0))
-    out_specs = [k_spec2, k_spec2]
+    out_specs = [k_spec2, v_spec2]
     out_shape = [jax.ShapeDtypeStruct((B * KV, Sk, D), k.dtype),
-                 jax.ShapeDtypeStruct((B * KV, Sk, D), v.dtype)]
+                 jax.ShapeDtypeStruct((B * KV, Sk, Dv), v.dtype)]
     scratch_shapes = [pltpu.VMEM((block_k, D), jnp.float32),
-                      pltpu.VMEM((block_k, D), jnp.float32)]
+                      pltpu.VMEM((block_k, Dv), jnp.float32)]
     if fused:
         # a q block of dQ is complete, and written, in the last kv block's
         # sweep; until then the map names block 0, which that sweep writes
@@ -559,13 +579,14 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         functools.partial(_dkdv_kernel, num_q=num_q, num_kv=num_kv,
                           fused=fused, **static),
         grid=(B * KV, num_kv, num_q),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
+        in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, r_spec2, r_spec2],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         compiler_params=params,
         interpret=interpret,
-        name="flash_dkdv_dq" if fused else "flash_dkdv",
+        name=(_kernel_name("flash_dkdv_dq", "mla_bwd", D, Dv) if fused
+              else _kernel_name("flash_dkdv", "mla_bwd_dkdv", D, Dv)),
     )(qg, kt, vt, dog, rows(lse), rows(delta))
     if fused:
         dk, dv, dq = outs
@@ -575,7 +596,7 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
     dq = (dq.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
           .reshape(B, Sq, H, D))
     dk = dk.reshape(B, KV, Sk, D).transpose(0, 2, 1, 3)
-    dv = dv.reshape(B, KV, Sk, D).transpose(0, 2, 1, 3)
+    dv = dv.reshape(B, KV, Sk, Dv).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 
@@ -617,7 +638,7 @@ def _xla_attention_lse(q, k, v, scale, causal, window=None, softcap=None):
     lse = jnp.where(l_row == 0.0, LSE_MASKED, m_safe + jnp.log(safe_l))
     # [B, KV, G, Sq, 1] -> natural [B, Sq, H, 1]
     lse = lse.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, 1)
-    return out.reshape(B, Sq, H, D), lse
+    return out.reshape(B, Sq, H, v.shape[-1]), lse
 
 
 def _lse_natural_to_perhead(lse, B, Sq, KV, G):
@@ -782,7 +803,9 @@ def flash_attention(q,
                     interpret: bool = False,
                     impl_fwd: Optional[str] = None,
                     impl_bwd: Optional[str] = None):
-    """Blocked attention; q [B, S, H, D], k/v [B, S, KV, D] (GQA native).
+    """Blocked attention; q [B, S, H, D], k/v [B, S, KV, D] (GQA native);
+    v may be ``[B, S, KV, Dv]`` with ``Dv != D`` (the result is then ``Dv``
+    wide and the kernels are the ``mla_*`` calls).
 
     On TPU (or with interpret=True anywhere) the forward and backward
     implementations are selected INDEPENDENTLY per shape by
@@ -801,7 +824,7 @@ def flash_attention(q,
     if not (use_pallas(force_pallas) or interpret):
         return _xla_attention(q, k, v, scale, causal, window, softcap)
     sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, causal,
-                      window, softcap)
+                      window, softcap, v_dim=v.shape[-1])
     blocks = ((block_q, block_k)
               if block_q is not None and block_k is not None else None)
     fwd_dec, bwd_dec = kd.resolve(
@@ -810,6 +833,10 @@ def flash_attention(q,
         impl_fwd=impl_fwd, impl_bwd=impl_bwd, blocks=blocks,
         pallas_only=bool(force_pallas) and impl_fwd is None
         and impl_bwd is None)
+    if sig.v_dim and "folded" in (fwd_dec.impl, bwd_dec.impl):
+        raise ValueError("the folded kernels take one head size: values "
+                         f"{sig.v_dim} wide beside keys {sig.head_dim} wide "
+                         "run the per-head kernels or XLA")
     fwd_dec = _fit_blocks(fwd_dec, q.shape[1], k.shape[1])
     bwd_dec = _fit_blocks(bwd_dec, q.shape[1], k.shape[1])
     return _flash_attention_call(q, k, v, scale, causal, window,

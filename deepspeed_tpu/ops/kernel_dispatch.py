@@ -71,6 +71,9 @@ class ShapeSig(NamedTuple):
     # a structured mask that is neither causal nor a window: "bd<B>" =
     # block-diffusion training over blocks of B tokens (its own kernels)
     pattern: str = ""
+    # the values' width where it is not the keys' (latent attention: keys
+    # 192, values 128; the ``mla_*`` calls); 0 = ``head_dim``
+    v_dim: int = 0
 
 
 class Decision(NamedTuple):
@@ -83,13 +86,14 @@ class Decision(NamedTuple):
 
 
 def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
-             window, softcap, pattern: str = "") -> ShapeSig:
+             window, softcap, pattern: str = "", v_dim: int = 0) -> ShapeSig:
     b, sq, h, d = q_shape
     return ShapeSig(batch=int(b), seq_q=int(sq), seq_k=int(seq_k),
                     heads=int(h), kv_heads=int(kv_heads), head_dim=int(d),
                     dtype=str(dtype), causal=bool(causal),
                     windowed=window is not None,
-                    softcapped=softcap is not None, pattern=pattern)
+                    softcapped=softcap is not None, pattern=pattern,
+                    v_dim=0 if int(v_dim) == int(d) else int(v_dim))
 
 
 def signature(leg: str, sig: ShapeSig, device_kind: str) -> str:
@@ -99,7 +103,8 @@ def signature(leg: str, sig: ShapeSig, device_kind: str) -> str:
     return (f"{leg}|{device_kind}|b{sig.batch}|sq{sig.seq_q}|sk{sig.seq_k}"
             f"|h{sig.heads}|kv{sig.kv_heads}|d{sig.head_dim}|{sig.dtype}"
             f"|c{int(sig.causal)}|w{int(sig.windowed)}"
-            f"|sc{int(sig.softcapped)}" + (f"|p{sig.pattern}" if sig.pattern else ""))
+            f"|sc{int(sig.softcapped)}" + (f"|p{sig.pattern}" if sig.pattern else "")
+            + (f"|dv{sig.v_dim}" if sig.v_dim else ""))
 
 
 def device_kind() -> str:
@@ -228,12 +233,24 @@ def vmem_limit_bytes(estimate: int) -> Optional[int]:
     return estimate * 5 // 4
 
 
+def vmem_width(head_dim: int, v_dim: int = 0) -> int:
+    """The head size the VMEM estimates are asked about: ``head_dim``, or
+    where the values' width differs (``v_dim`` neither 0 nor ``head_dim``)
+    the larger of the two in whole 128-lane tiles for every block (192 lies
+    in VMEM as 256 lanes; the 128-wide v, o and dV blocks are then counted
+    at 256 too: an upper estimate)."""
+    if v_dim in (0, head_dim):
+        return head_dim
+    return -(-max(head_dim, v_dim) // 128) * 128
+
+
 def fused_vmem_bytes(sig: ShapeSig) -> int:
     """``flash_vmem_bytes`` of the fused backward at ``sig``, with the blocks
     the shape gives it: what ``_heuristic_impl`` holds against
     FUSED_VMEM_CAP_BYTES."""
     return flash_vmem_bytes("fused", max(1, sig.heads // sig.kv_heads),
-                            sig.head_dim, 4 if "32" in sig.dtype else 2,
+                            vmem_width(sig.head_dim, sig.v_dim),
+                            4 if "32" in sig.dtype else 2,
                             *choose_blocks(sig, "fused"), seq_q=sig.seq_q)
 
 
@@ -346,8 +363,8 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
     while True:
         bq = _largest_block(sig.seq_q, cap_q)
         bk = _largest_block(sig.seq_k, cap_k)
-        over = flash_vmem_bytes(leg, group, sig.head_dim, itemsize, bq,
-                                bk) > VMEM_SCOPED_DEFAULT_BYTES
+        over = flash_vmem_bytes(leg, group, vmem_width(sig.head_dim, sig.v_dim),
+                                itemsize, bq, bk) > VMEM_SCOPED_DEFAULT_BYTES
         if not over or max(cap_q, cap_k) <= 128:
             return bq, bk
         if group * cap_q > cap_k and cap_q > 128:

@@ -92,12 +92,15 @@ def _as_apply_fns(model):
             # "ssm_stats" is the same contract for a state-space mixer:
             # ``state_absmax`` (the largest |S| a layer's scan held) comes
             # back as the largest over the layers, ``dt_mean`` as their mean
+            # "mla_stats" for latent attention: ``latent_rms`` and
+            # ``k_rope_rms`` (the rms of the latent before its norm and of
+            # the shared rope key) come back as the layers' means
             # "diffusion_stats" for the block-diffusion objective: the
             # batch's data ``tokens``, its ``masked_tokens`` and the sum of
             # their ``t``
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
-                                             "diffusion_stats"])
+                                             "mla_stats", "diffusion_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -122,6 +125,12 @@ def _as_apply_fns(model):
                         if path[-1].key == name]
                 if sown:
                     stats["ssm_" + name] = reduce(jnp.concatenate(sown))
+            mla = jax.tree_util.tree_flatten_with_path(mods.get("mla_stats", {}))[0]
+            for name in ("latent_rms", "k_rope_rms"):
+                sown = [leaf.reshape(-1) for path, leaf in mla
+                        if path[-1].key == name]
+                if sown:
+                    stats["mla_" + name] = jnp.mean(jnp.concatenate(sown))
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                     mods.get("diffusion_stats", {}))[0]:
                 stats["diffusion_" + path[-1].key] = jnp.sum(leaf)
@@ -1713,6 +1722,14 @@ class DeepSpeedTpuEngine:
                 "Mean step size dt = softplus(dt + dt_bias) of the "
                 "state-space layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["ssm_dt_mean"]) for s in fetched])))
+        for name, what in (("latent_rms", "the latent before kv_a_layernorm"),
+                           ("k_rope_rms", "the shared rope key")):
+            if "mla_" + name in fetched[0]:
+                reg.gauge(
+                    "ds_mla_" + name,
+                    f"Root mean square of {what} in the latent-attention "
+                    "layers, their mean over the steps of the last publish"
+                ).set(float(np.mean([np.mean(s["mla_" + name]) for s in fetched])))
         if "diffusion_masked_tokens" in fetched[0]:
             masked, tokens = (sum(float(np.sum(s["diffusion_" + name])) for s in fetched)
                               for name in ("masked_tokens", "tokens"))
@@ -2115,7 +2132,7 @@ class DeepSpeedTpuEngine:
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
         return self._newest_stats(
-            lambda name: not name.startswith(("ssm_", "diffusion_")))
+            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_")))
 
     def diffusion_stats(self):
         """What the block-diffusion objective sowed in the newest fused step
@@ -2138,6 +2155,15 @@ class DeepSpeedTpuEngine:
         :meth:`moe_stats`; ``None`` for a model without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("ssm_"))
         return stats and {name[len("ssm_"):]: v for name, v in stats.items()}
+
+    def mla_stats(self):
+        """What the latent-attention layers sowed in the newest fused step
+        not yet published, as host scalars, each the layers' mean:
+        ``latent_rms`` (of the latent before ``kv_a_layernorm``) and
+        ``k_rope_rms`` (of the shared rope key). Waits for that step, as
+        :meth:`moe_stats`; ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("mla_"))
+        return stats and {name[len("mla_"):]: v for name, v in stats.items()}
 
     def _newest_stats(self, wanted):
         if not self._moe_pending:

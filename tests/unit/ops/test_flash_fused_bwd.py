@@ -5,6 +5,8 @@ the CPU. Both backwards run on the same per-head forward's residuals, so in
 float32 they agree to rounding: the fused kernel sums dQ over the kv blocks
 in the order the dq kernel does."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,7 +49,12 @@ CASES = {
 }
 
 
-def _grads(case, dtype, impl_bwd):
+@functools.lru_cache(maxsize=None)
+def _grads(name, dtype, impl_bwd):
+    """dq, dk, dv of case ``name``, one compiled program a call (op by op,
+    every small op of the regrouping and of the reference's backward is a
+    compile of its own); kept, so the float32 reference serves both tests."""
+    case = CASES[name]
     rng = np.random.default_rng(11)
     shape_q = (case["b"], case["s"], case["h"], case["d"])
     shape_kv = (case["b"], case["sk"], case["kv"], case["d"])
@@ -60,22 +67,25 @@ def _grads(case, dtype, impl_bwd):
         case["window"], case["softcap"]), "fused")
     if impl_bwd == "reference":
         scale = 1.0 / np.sqrt(case["d"])
-        _, pull = jax.vjp(lambda q, k, v: _xla_attention(
-            q, k, v, scale, case["causal"], case["window"], case["softcap"]),
-            q, k, v)
+
+        def fn(q, k, v):
+            return _xla_attention(q, k, v, scale, case["causal"], case["window"],
+                                  case["softcap"])
     else:
-        _, pull = jax.vjp(lambda q, k, v: flash_attention(
-            q, k, v, causal=case["causal"], window=case["window"],
-            softcap=case["softcap"], interpret=True, impl_fwd="pallas",
-            impl_bwd=impl_bwd, block_q=bq, block_k=bk), q, k, v)
-    return [np.asarray(x, np.float32) for x in pull(g)]
+        def fn(q, k, v):
+            return flash_attention(
+                q, k, v, causal=case["causal"], window=case["window"],
+                softcap=case["softcap"], interpret=True, impl_fwd="pallas",
+                impl_bwd=impl_bwd, block_q=bq, block_k=bk)
+    pulled = jax.jit(lambda q, k, v, g: jax.vjp(fn, q, k, v)[1](g))(q, k, v, g)
+    return [np.asarray(x, np.float32) for x in pulled]
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_fused_backward_equals_the_pair_in_float32(name):
-    fused = _grads(CASES[name], jnp.float32, "fused")
-    pair = _grads(CASES[name], jnp.float32, "pallas")
-    reference = _grads(CASES[name], jnp.float32, "reference")
+    fused = _grads(name, jnp.float32, "fused")
+    pair = _grads(name, jnp.float32, "pallas")
+    reference = _grads(name, jnp.float32, "reference")
     for leaf, a, b, c in zip(("dq", "dk", "dv"), fused, pair, reference):
         np.testing.assert_allclose(a, b, atol=1e-6, rtol=0, err_msg=leaf)
         np.testing.assert_allclose(a, c, atol=5e-5, rtol=5e-4, err_msg=leaf)
@@ -86,9 +96,9 @@ def test_fused_backward_in_bfloat16(name):
     """bf16 operands, float32 accumulators and softmax, as on the training
     path: the pair's results to a rounding of the outputs, the reference's
     to bf16's."""
-    fused = _grads(CASES[name], jnp.bfloat16, "fused")
-    pair = _grads(CASES[name], jnp.bfloat16, "pallas")
-    reference = _grads(CASES[name], jnp.float32, "reference")
+    fused = _grads(name, jnp.bfloat16, "fused")
+    pair = _grads(name, jnp.bfloat16, "pallas")
+    reference = _grads(name, jnp.float32, "reference")
     for leaf, a, b, c in zip(("dq", "dk", "dv"), fused, pair, reference):
         np.testing.assert_allclose(a, b, atol=2e-2 * np.abs(b).max(), rtol=0,
                                    err_msg=leaf)
