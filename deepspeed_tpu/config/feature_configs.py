@@ -175,8 +175,9 @@ class ActivationCheckpointingConfig(ConfigModel):
     # TPU extension: jax.checkpoint policy name; the engine wraps the model
     # in jax.checkpoint only when one is named. For
     # activation_checkpointing.checkpoint() None recomputes everything but
-    # what an attention kernel gave (ops/attention.py::RESIDUAL_NAMES);
-    # "nothing_saveable" keeps nothing
+    # what an attention kernel gave (ops/attention.py::RESIDUAL_NAMES) and as
+    # many of the function's named values (ops/remat.py, in its order) as the
+    # chip reports room for; "nothing_saveable" keeps nothing
     remat_policy: Optional[str] = None
 
 

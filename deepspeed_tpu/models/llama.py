@@ -21,6 +21,7 @@ import numpy as np
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
+from ..ops import remat
 from ..ops.registry import interpret_kernels, on_tpu
 
 # logical axis names; mapped onto mesh axes by parallel/tp.py rules
@@ -190,10 +191,11 @@ class LlamaConfig:
     remat: bool = False
     # jax.checkpoint_policies name for selective remat (e.g. "dots_saveable":
     # save matmul outputs, recompute elementwise/norms — most of the memory
-    # saving at a fraction of full remat's recompute). None = the whole layer
-    # is recomputed but for what its attention kernel gave (output and
-    # log-sum-exp, ops/attention.py::RESIDUAL_NAMES: tokens x hidden x 2 B a
-    # layer that has attention, and the kernel's forward runs once a step);
+    # saving at a fraction of full remat's recompute). None = the layer is
+    # recomputed but for what its attention kernel gave (ops/attention.py::
+    # RESIDUAL_NAMES) and, as far as the chip reports room beside the step,
+    # its router's, projections' and FFN's named outputs in ops/remat.py's
+    # order (none on a backend that reports no memory);
     # "nothing_saveable" = nothing kept.
     remat_policy: "Optional[str]" = None
     # chunked unembed+CE (ops/chunked_ce.py): a bound on the transient
@@ -381,6 +383,8 @@ class _BarrierDense(nn.Module):
     kernel_init: Any
     bias_init: Any
     use_bias: bool = False
+    # the name a recomputed layer may keep the output under (ops/remat.py)
+    keep: Optional[str] = None
 
     @nn.compact
     def __call__(self, x):
@@ -392,13 +396,23 @@ class _BarrierDense(nn.Module):
         if self.use_bias:
             bias = self.param("bias", self.bias_init, (self.features, ))
             y = y + _use_cast(bias, self.dtype)
-        return y
+        return remat.keep(y, self.keep) if self.keep else y
 
 
-def _dense(features, name, axes, dtype, use_bias=False):
-    return _BarrierDense(features, use_bias=use_bias, dtype=dtype, name=name,
+def _dense(features, name, axes, dtype, use_bias=False, keep=None):
+    return _BarrierDense(features, use_bias=use_bias, dtype=dtype, name=name, keep=keep,
                          kernel_init=nn.with_partitioning(nn.initializers.lecun_normal(), axes),
                          bias_init=nn.with_partitioning(nn.initializers.zeros, (axes[-1], )))
+
+
+def _keep_out(cfg, inner: int):
+    """The name a mixer's output projection is kept under, by its contraction
+    ``inner``: deeper than the hidden size (SDAR's attention, Mamba: 4,096
+    for 2,048) the matmul a kept output saves is worth two of what reading
+    it back costs the residual add and the FFN's norm; no deeper, about one
+    (``ops/remat.py`` has both prices), so it is taken after the FFN's and
+    the input projections' outputs."""
+    return remat.MIXER_OUT if inner > cfg.hidden_size else remat.MIXER_OUT_NARROW
 
 
 def _make_norm(cfg, name):
@@ -442,9 +456,12 @@ class LlamaAttention(nn.Module):
         hd = cfg.head_dim_
         nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
 
-        q = _dense(nq * hd, "q_proj", (EMBED, HEADS), cfg.dtype, cfg.attention_bias)(x)
-        k = _dense(nkv * hd, "k_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias)(x)
-        v = _dense(nkv * hd, "v_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias)(x)
+        q = _dense(nq * hd, "q_proj", (EMBED, HEADS), cfg.dtype, cfg.attention_bias,
+                   remat.MIXER_IN)(x)
+        k = _dense(nkv * hd, "k_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias,
+                   remat.MIXER_IN)(x)
+        v = _dense(nkv * hd, "v_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias,
+                   remat.MIXER_IN)(x)
         if cfg.clip_qkv is not None:  # OLMo stability clamp
             q = jnp.clip(q, -cfg.clip_qkv, cfg.clip_qkv)
             k = jnp.clip(k, -cfg.clip_qkv, cfg.clip_qkv)
@@ -569,7 +586,7 @@ class LlamaAttention(nn.Module):
                 attn = _core_attn(q, k, v)
         out = attn.reshape(b, s, nq * hd)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
-                      cfg.attention_out_bias)(out)
+                      cfg.attention_out_bias, _keep_out(cfg, nq * hd))(out)
 
 
     def _block_diffusion(self, q, k, v, attn_mask, window, sp_sz, use_kernel):
@@ -643,8 +660,10 @@ class LatentAttention(nn.Module):
             raise ValueError(
                 "the latent operator is causal attention with a rotary key: "
                 "no padding mask, window, softcapping or other position form")
-        q = _dense(nh * (d_nope + d_rope), "q_proj", (EMBED, HEADS), cfg.dtype)(x)
-        kva = _dense(rank + d_rope, "kv_a_proj_with_mqa", (EMBED, None), cfg.dtype)(x)
+        q = _dense(nh * (d_nope + d_rope), "q_proj", (EMBED, HEADS), cfg.dtype,
+                   keep=remat.MIXER_IN)(x)
+        kva = _dense(rank + d_rope, "kv_a_proj_with_mqa", (EMBED, None), cfg.dtype,
+                     keep=remat.MIXER_IN)(x)
         # every scope closes before the kernel's call below: one that held
         # it would rename the instruction (docs/observability.md)
         with jax.named_scope("ds.mla.assemble"):
@@ -659,7 +678,8 @@ class LatentAttention(nn.Module):
                 self.sow("mla_stats", name, value, reduce_fn=lambda a, b: a + b,
                          init_fn=lambda: jnp.float32(0.0))
         c = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="kv_a_layernorm")(c)
-        kvb = _dense(nh * (d_nope + d_v), "kv_b_proj", (None, HEADS), cfg.dtype)(c)
+        kvb = _dense(nh * (d_nope + d_v), "kv_b_proj", (None, HEADS), cfg.dtype,
+                     keep=remat.MIXER_IN)(c)
         with jax.named_scope("ds.rope"):
             q_rope = apply_rope(q_rope, cos, sin, positions,
                                 interleaved=cfg.rope_interleaved)
@@ -683,8 +703,8 @@ class LatentAttention(nn.Module):
                                    interpret=interpret_kernels())
         else:
             attn = _xla_attention(q, k, v, scale, True)
-        return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype)(
-            attn.reshape(b, s, nh * d_v))
+        return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, nh * d_v))(attn.reshape(b, s, nh * d_v))
 
 
 class ShortConvOperator(nn.Module):
@@ -699,7 +719,8 @@ class ShortConvOperator(nn.Module):
         from ..ops.short_conv import short_conv
         cfg = self.config
         H = cfg.hidden_size
-        bcx = _dense(3 * H, "in_proj", (EMBED, HIDDEN), cfg.dtype)(x)
+        bcx = _dense(3 * H, "in_proj", (EMBED, HIDDEN), cfg.dtype,
+                     keep=remat.MIXER_IN)(x)
         taps = self.param(
             "conv_weight",
             nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0,
@@ -709,9 +730,10 @@ class ShortConvOperator(nn.Module):
         # a raw pallas_call is not partitioned under GSPMD: as for flash,
         # the kernel runs where the mesh is one device
         one_device = all(n == 1 for n in _mesh_shape().values())
-        y = short_conv(bcx, taps, use_kernel=on_tpu() and one_device,
-                       interpret=interpret_kernels())
-        return _dense(H, "out_proj", (HIDDEN, EMBED), cfg.dtype)(y)
+        y = remat.keep(short_conv(bcx, taps, use_kernel=on_tpu() and one_device,
+                                  interpret=interpret_kernels()), remat.KERNEL_OUT)
+        return _dense(H, "out_proj", (HIDDEN, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, H))(y)
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -746,7 +768,8 @@ class Mamba2Mixer(nn.Module):
         inner, xbc_width = H * P, H * P + 2 * N
         b, s, _ = u.shape
         f32 = jnp.float32
-        zxbcdt = _dense(inner + xbc_width + H, "in_proj", (EMBED, HIDDEN), cfg.dtype)(u)
+        zxbcdt = _dense(inner + xbc_width + H, "in_proj", (EMBED, HIDDEN), cfg.dtype,
+                        keep=remat.MIXER_IN)(u)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + xbc_width], axis=-1)
         taps = self.param(
             "conv_weight",
@@ -764,8 +787,8 @@ class Mamba2Mixer(nn.Module):
         # raw pallas_calls are not partitioned under GSPMD: as for flash, the
         # kernels run where the mesh is one device
         kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
-        xbc = causal_conv(xbc, taps, conv_bias, use_kernel=kernels,
-                          interpret=interpret_kernels())
+        xbc = remat.keep(causal_conv(xbc, taps, conv_bias, use_kernel=kernels,
+                                     interpret=interpret_kernels()), remat.KERNEL_OUT)
         x, B, C = jnp.split(xbc, [inner, inner + N], axis=-1)
         def per_head(name, init):
             return self.param(name, nn.with_partitioning(init, (HEADS, )), (H, ), f32)
@@ -791,7 +814,8 @@ class Mamba2Mixer(nn.Module):
                             (inner, ), f32)
         var = jnp.mean(gated * gated, axis=-1, keepdims=True)
         y = (gated * jax.lax.rsqrt(var + cfg.rms_norm_eps) * weight).astype(cfg.dtype)
-        return _dense(cfg.hidden_size, "out_proj", (HIDDEN, EMBED), cfg.dtype)(y)
+        return _dense(cfg.hidden_size, "out_proj", (HIDDEN, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, inner))(y)
 
 
 class LlamaMLP(nn.Module):
@@ -802,8 +826,10 @@ class LlamaMLP(nn.Module):
         cfg = self.config
         if cfg.mlp_type in ("swiglu", "geglu_tanh"):
             # gated MLP: silu gate (llama) or tanh-gelu gate (gemma)
-            gate = _dense(cfg.intermediate_size, "gate_proj", (EMBED, HIDDEN), cfg.dtype)(x)
-            up = _dense(cfg.intermediate_size, "up_proj", (EMBED, HIDDEN), cfg.dtype)(x)
+            gate = _dense(cfg.intermediate_size, "gate_proj", (EMBED, HIDDEN), cfg.dtype,
+                          keep=remat.FFN_IN)(x)
+            up = _dense(cfg.intermediate_size, "up_proj", (EMBED, HIDDEN), cfg.dtype,
+                        keep=remat.FFN_IN)(x)
             g = (nn.silu(gate) if cfg.mlp_type == "swiglu"
                  else nn.gelu(gate, approximate=True))
             return _dense(cfg.hidden_size, "down_proj", (HIDDEN, EMBED),
@@ -814,7 +840,7 @@ class LlamaMLP(nn.Module):
                "gelu_tanh_fc": lambda y: nn.gelu(y, approximate=True),
                "relu_fc": nn.relu}[cfg.mlp_type]
         h = _dense(cfg.intermediate_size, "fc1", (EMBED, HIDDEN), cfg.dtype,
-                   cfg.mlp_bias)(x)
+                   cfg.mlp_bias, remat.FFN_IN)(x)
         return _dense(cfg.hidden_size, "fc2", (HIDDEN, EMBED), cfg.dtype,
                       cfg.mlp_bias)(act(h))
 
@@ -865,8 +891,14 @@ class LlamaMoEBlock(nn.Module):
             if cfg.moe_selection_bias:
                 bias = self.param("expert_bias", nn.with_partitioning(
                     nn.initializers.zeros, ("expert", )), (E, ), jnp.float32)
+                # under the router's name (ops/remat.py). A top-k that also
+                # gives the weights, as below, reads its OWN indices in its
+                # backward, which no name reaches: it is made again whatever
+                # is kept, and keeping its logits alone cost the SDAR cell
+                # 4 ms a step (PERF.md §6, PR 41)
                 _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
-                w = jnp.take_along_axis(scores, idx, axis=-1)
+                idx = remat.keep(idx, remat.ROUTE)
+                w = remat.keep(jnp.take_along_axis(scores, idx, axis=-1), remat.ROUTE)
             else:
                 w, idx = jax.lax.top_k(scores, k)
         else:
@@ -889,7 +921,12 @@ class LlamaMoEBlock(nn.Module):
         if not 0 < held <= E or first + held > E:
             raise ValueError(f"experts {first}..{first + held} held of {E}")
         H, F = cfg.hidden_size, cfg.intermediate_size
-        logits = _dense(E, "gate", (EMBED, "expert"), jnp.float32)(x.astype(jnp.float32))
+        # the router's logits, choice and weights under its name where the
+        # choice is a top-k of biased scores (``_route``): there a recomputed
+        # layer that holds them runs no float32 matmul, top-k or gather
+        biased = cfg.moe_scoring == "sigmoid" and cfg.moe_selection_bias
+        logits = _dense(E, "gate", (EMBED, "expert"), jnp.float32,
+                        keep=remat.ROUTE if biased else None)(x.astype(jnp.float32))
         with jax.named_scope("ds.moe.route"):
             probs, w, idx = self._route(logits)
             # (token, choice) assignments per expert: what the engine's fused
@@ -1059,19 +1096,51 @@ class LMHead(nn.Module):
 def _remat_layer_cls(cfg):
     """nn.remat with the configured jax.checkpoint_policies policy (selective
     remat — reference activation_checkpointing config's TPU analog). With no
-    policy named the layer is recomputed whole but for what its attention
-    kernel gave (``ops/attention.py::RESIDUAL_NAMES``): the kernel's forward
-    then runs once a step, for one more activation of the layer input's size
-    a layer that has attention; ``"nothing_saveable"`` keeps nothing."""
+    policy named the layer is recomputed but for the values it names among
+    ``ops/remat.py::KEPT_NAMES``: what its attention kernel gave, so that the
+    kernel's forward runs once a step, and the candidates of its row of
+    ``_kept_plan`` (``remat.keeping`` around its call: the others carry
+    another name); ``"nothing_saveable"`` keeps nothing."""
     if cfg.remat_policy:
         pol = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
         if pol is None:
             raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
         return nn.remat(LlamaDecoderLayer, policy=pol)
-    from ..ops.attention import RESIDUAL_NAMES
-    return nn.remat(LlamaDecoderLayer,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *RESIDUAL_NAMES))
+    return nn.remat(LlamaDecoderLayer, policy=remat.KEPT_POLICY)
+
+
+def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
+    """-> the names each layer keeps under ``remat`` with no policy named
+    (``ops/remat.py``: ``RESIDUAL_NAMES`` and as many of the candidates, in
+    their order, as the chip has room for beside the step; ``RESIDUAL_NAMES``
+    alone on a backend that reports no memory), or None where no name
+    chooses: no recomputation, or a named policy. The prices are the layers'
+    own named values, read off a trace of each kind of layer at ``x``'s
+    shape."""
+    if not cfg.remat or cfg.remat_policy:
+        return None
+    specs = cfg.layer_specs or (None, ) * cfg.num_hidden_layers
+
+    def prices_of():
+        abstract = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+        by_kind = {}
+        for i, spec in enumerate(specs):
+            if spec not in by_kind:
+                by_kind[spec] = remat.price_list(
+                    LlamaDecoderLayer(cfg, i, parent=None).init, jax.random.PRNGKey(0),
+                    abstract(x), cos, sin, abstract(positions), attn_mask)
+        return [by_kind[spec] for spec in specs]
+
+    tokens, itemsize = x.shape[0] * x.shape[1], jnp.dtype(cfg.dtype).itemsize
+    attention = sum(spec is None or spec.operator in ("attention", "latent")
+                    for spec in specs)
+    a_kernels = tokens * cfg.num_attention_heads * (
+        (cfg.v_head_dim or cfg.head_dim_) * itemsize + 4)    # output, log-sum-exp
+    plan = remat.plan_for(
+        (repr(cfg), x.shape), prices_of, rows=x.shape[0],
+        layer_input_bytes=x.size * itemsize, always_kept_bytes=attention * a_kernels,
+        same_in_all_layers=cfg.scan_layers)
+    return plan or (remat.RESIDUAL_NAMES, ) * len(specs)
 
 
 class _ScanBody(nn.Module):
@@ -1124,6 +1193,9 @@ class LlamaModel(nn.Module):
             x = x + pos_table(positions + cfg.pos_offset)
         cos, sin = precompute_rope(cfg.rotary_dim or cfg.head_dim_,
                                    cfg.max_position_embeddings, cfg.rope_theta)
+        # an init has no backward: nothing to plan (and no layer to price)
+        kept = None if self.is_initializing() else _kept_plan(
+            cfg, x, cos, sin, positions, attn_mask)
 
         if cfg.scan_layers:
             # scan over depth: O(1) HLO in layer count (the 70B compile path);
@@ -1152,12 +1224,14 @@ class LlamaModel(nn.Module):
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,
                                 metadata_params={nn.PARTITION_NAME: "layers"})
-            x, _ = ScanLayer(cfg, name="layers")(x, cos, sin, positions, attn_mask)
+            with remat.keeping(kept and kept[0]):     # one body: every layer's names
+                x, _ = ScanLayer(cfg, name="layers")(x, cos, sin, positions, attn_mask)
         else:
             layer_cls = _remat_layer_cls(cfg) if cfg.remat else LlamaDecoderLayer
             for i in range(cfg.num_hidden_layers):
-                x = layer_cls(cfg, i, name=f"layers_{i}")(x, cos, sin, positions,
-                                                          attn_mask)
+                with remat.keeping(kept and kept[i]):
+                    x = layer_cls(cfg, i, name=f"layers_{i}")(x, cos, sin, positions,
+                                                              attn_mask)
         if cfg.block_diffusion_:
             # the clean copy carries no loss: only the noisy half is normed
             # and reaches the head

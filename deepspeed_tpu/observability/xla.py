@@ -72,32 +72,40 @@ def _arg_specs(args, kwargs) -> Tuple[tuple, dict]:
             jax.tree_util.tree_map(spec, kwargs))
 
 
-def kept_residual_bytes(closed_jaxpr, names=None) -> int:
-    """Bytes of the values named ``names`` (``jax.ad_checkpoint.
-    checkpoint_name``; default ``ops/attention.py::RESIDUAL_NAMES``) that a
-    traced program's recomputations keep from the forward instead of making
-    them again: the named values computed outside a recomputation (the body
-    of a differentiated ``jax.checkpoint`` equation) less those computed inside
-    one, from the shapes in the jaxpr, a ``scan`` body counted ``length``
-    times. 0 for a program that recomputes nothing: its autodiff keeps
-    every residual, named or not."""
+def named_residual_bytes(closed_jaxpr, names=None):
+    """-> (kept, offered) bytes of the values named ``names`` (``jax.
+    ad_checkpoint.checkpoint_name``; default ``ops/remat.py::KEPT_NAMES``:
+    the attention kernels' residuals and every candidate the rule may
+    choose). ``kept``: what a traced program's recomputations keep from the
+    forward instead of making it again: the named values computed outside a
+    recomputation (the body of a differentiated ``jax.checkpoint`` equation)
+    less those computed inside one, from the shapes in the jaxpr, a ``scan``
+    body counted ``length`` times; a name the policy did not choose is made
+    again inside and cancels. 0 for a program that recomputes nothing: its
+    autodiff keeps every residual, named or not. ``offered``: all the named
+    values of the forward, those too that their layer did not choose
+    (``ops/remat.py::AGAIN``): what keeping every name would hold."""
     from jax._src import core
     from jax._src.ad_checkpoint import remat_p
+    from ..ops.remat import AGAIN
     if names is None:
-        from ..ops.attention import RESIDUAL_NAMES as names
-    outside = inside = recomputations = 0
+        from ..ops.remat import KEPT_NAMES as names
+    outside = inside = offered = recomputations = 0
 
     def walk(jaxpr, times, recomputed):
-        nonlocal outside, inside, recomputations
+        nonlocal outside, inside, offered, recomputations
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
-            if prim == "name" and eqn.params["name"] in names:
+            if prim == "name" and eqn.params["name"].removesuffix(AGAIN) in names:
                 nbytes = times * sum(v.aval.size * v.aval.dtype.itemsize
                                      for v in eqn.outvars)
-                if recomputed:
-                    inside += nbytes
-                else:
-                    outside += nbytes
+                if not recomputed:
+                    offered += nbytes
+                if eqn.params["name"] in names:
+                    if recomputed:
+                        inside += nbytes
+                    else:
+                        outside += nbytes
             again = eqn.primitive is remat_p and eqn.params["differentiated"]
             recomputations += bool(again)
             for sub in core.jaxprs_in_params(eqn.params):
@@ -105,7 +113,12 @@ def kept_residual_bytes(closed_jaxpr, names=None) -> int:
                      recomputed or again)
 
     walk(closed_jaxpr.jaxpr, 1, False)
-    return max(0, outside - inside) if recomputations else 0
+    return (max(0, outside - inside) if recomputations else 0), offered
+
+
+def kept_residual_bytes(closed_jaxpr, names=None) -> int:
+    """``named_residual_bytes``' kept bytes."""
+    return named_residual_bytes(closed_jaxpr, names)[0]
 
 
 class WatchedJit:
@@ -121,7 +134,7 @@ class WatchedJit:
         self._calls = 0
         self.dispatches = 0       # read by TrainInstruments.publish()
         self._flops: Optional[float] = None
-        self._kept_bytes = 0
+        self._kept_bytes = self._offered_bytes = 0
         self._flops_spec = None
 
     def _cache_entries(self) -> Optional[int]:
@@ -185,17 +198,19 @@ class WatchedJit:
         with get_tracer().scope("ds.compile.cost_analysis", key=self.key):
             try:
                 traced = self._fn.trace(*a, **k)
-                self._kept_bytes = kept_residual_bytes(traced.jaxpr)
+                self._kept_bytes, self._offered_bytes = named_residual_bytes(
+                    traced.jaxpr)
                 self._flops = cost_analysis_flops(traced.lower())
             except Exception:
                 self._flops = 0.0
         return self._flops
 
-    def program_kept_bytes(self) -> int:
-        """``kept_residual_bytes`` of this program, read off the same trace
-        as ``program_flops`` (0 before the program has compiled)."""
+    def program_kept_bytes(self) -> Tuple[int, int]:
+        """``named_residual_bytes`` of this program, (kept, offered), read
+        off the same trace as ``program_flops`` (0 before the program has
+        compiled)."""
         self.program_flops()
-        return self._kept_bytes
+        return self._kept_bytes, self._offered_bytes
 
 
 class CompileWatch:
@@ -443,15 +458,22 @@ class TrainInstruments:
                 if f > 0:
                     flops += f * d
                 if not seen:
+                    kept, offered = prog.program_kept_bytes()
                     self.registry.gauge(
                         "ds_remat_kept_bytes",
-                        "Bytes of the attention kernels' named residuals "
-                        "(output, log-sum-exp) a dispatch of the program "
-                        "keeps from its forward to a recomputed layer's "
-                        "backward, from the shapes in its trace; 0 for a "
-                        "program that recomputes nothing",
-                        labels={"key": prog.key}).set(
-                            float(prog.program_kept_bytes()))
+                        "Bytes of the named values (the attention kernels' "
+                        "output and log-sum-exp, and the candidates the "
+                        "budget admitted: ops/remat.py) a dispatch of the "
+                        "program keeps from its forward to a recomputed "
+                        "layer's backward, from the shapes in its trace; 0 "
+                        "for a program that recomputes nothing",
+                        labels={"key": prog.key}).set(float(kept))
+                    self.registry.gauge(
+                        "ds_remat_offered_bytes",
+                        "Bytes of every named value of the program's "
+                        "forward: what ds_remat_kept_bytes would read with "
+                        "room for the whole list",
+                        labels={"key": prog.key}).set(float(offered))
                 ent[1] = prog.dispatches
         if any_dispatch and wall > 0 and flops > 0:
             self.mfu.set(min(1.0, flops / (wall * self.peak_flops)))

@@ -72,7 +72,7 @@ def is_configured() -> bool:
     return True
 
 
-def _resolve_policy():
+def _resolve_policy(function=None, args=(), kwargs=None):
     name = _CONFIG["policy"]
     if _CONFIG["cpu_checkpointing"]:
         # host-offload the saved residuals when this jax exposes it
@@ -90,11 +90,25 @@ def _resolve_policy():
             raise ValueError(f"unknown remat policy '{name}'")
         return pol
     # no policy named: recompute everything but what an attention kernel gave
-    # (its output and log-sum-exp, ``ops/attention.py::RESIDUAL_NAMES``), as
-    # ``models/llama.py`` does; a function that reaches no such kernel keeps
-    # nothing, and ``remat_policy: "nothing_saveable"`` keeps nothing anywhere
-    from ...ops.attention import RESIDUAL_NAMES
-    return jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+    # (its output and log-sum-exp, ``ops/attention.py::RESIDUAL_NAMES``) and,
+    # as ``models/llama.py`` does, as many of the values ``function`` names
+    # (``ops/remat.py::CANDIDATE_NAMES``, in that order) as the chip has room
+    # for beside the step. A function that reaches no named producer keeps
+    # nothing, ``remat_policy: "nothing_saveable"`` keeps nothing anywhere.
+    # A policy by names, made anew for each plan: jax remembers its trace of
+    # ``function`` by function and policy, so the choice has to be in one
+    from ...ops import remat
+    names = remat.RESIDUAL_NAMES
+    if function is not None:
+        leaves = [a for a in jax.tree_util.tree_leaves((args, kwargs))
+                  if hasattr(a, "shape")]
+        plan = remat.plan_for(
+            (function, tuple((a.shape, str(a.dtype)) for a in leaves)),
+            lambda: [remat.price_list(function, *args, **(kwargs or {}))],
+            rows=leaves[0].shape[0] if leaves and leaves[0].ndim else 1,
+            layer_input_bytes=sum(a.size * a.dtype.itemsize for a in leaves))
+        names = plan[0] if plan else names
+    return jax.checkpoint_policies.save_only_these_names(*names)
 
 
 def _partition_arg(x):
@@ -118,7 +132,7 @@ def _partition_arg(x):
 def checkpoint(function: Callable, *args, **kwargs):
     """Reference checkpoint() :993 — run `function` under remat; activations
     are recomputed in backward rather than saved."""
-    policy = _resolve_policy()
+    policy = _resolve_policy(function, args, kwargs)
     fn = function
     if _CONFIG["partition_activations"]:
         inner = function

@@ -372,6 +372,11 @@ class DeepSpeedTpuEngine:
                              "fp32 accumulation otherwise")
 
         # ---- apply fn (+ activation checkpointing) ----
+        # what a recomputed layer keeps is chosen against what the chip
+        # holds when the step is first traced: this engine's state, not an
+        # earlier one's
+        from ..ops import remat
+        remat.forget_plans()
         self.apply_fn, self._apply_with_stats = _as_apply_fns(model)
         ac = self._config.activation_checkpointing_config
         if ac.remat_policy:

@@ -363,3 +363,101 @@ def test_whole_layer_recomputation_keeps_what_the_attention_kernel_gave(case):
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
             got, want)
+
+
+def _tiny_kinds():
+    from deepspeed_tpu.models.llama import LayerSpec
+    moe = dict(num_local_experts=4, num_experts_per_tok=2)
+    return {
+        "dense": dict(head_dim=32),      # o_proj 128 deep, over the hidden 64: a candidate
+        "scan": dict(scan_layers=True),
+        "conv": dict(layer_specs=(LayerSpec("conv", "dense", 128),
+                                  LayerSpec("conv", "moe", 32)),
+                     moe_experts_held=2, moe_scoring="sigmoid", moe_selection_bias=True,
+                     moe_renormalize=True, moe_renorm_eps=1e-6, **moe),
+        "mamba": dict(layer_specs=(LayerSpec("mamba", "dense", 128),
+                                   LayerSpec("attention", "dense", 128)),
+                      mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+                      mamba_chunk_size=64, pos_embedding="none"),
+        "moe": dict(shared_expert_intermediate_size=32, router_aux_loss_coef=0.01, **moe),
+        "latent": dict(layer_specs=(LayerSpec("latent", "dense", 128),
+                                    LayerSpec("latent", "moe", 32)),
+                       kv_lora_rank=32, v_head_dim=16, head_dim=24, rotary_dim=8,
+                       num_key_value_heads=4, moe_scoring="sigmoid",
+                       moe_selection_bias=True, shared_expert_intermediate_size=32,
+                       shared_expert_gated=False, **moe),
+    }
+
+
+def _value_and_grad_program(cfg, ids):
+    model = LlamaForCausalLM(cfg)
+
+    def loss(p):
+        out = model.apply(p, ids, ids, mutable=["moe_stats", "ssm_stats", "mla_stats",
+                                                "aux_loss"])
+        return out[0].astype(jnp.float32)
+
+    return jax.jit(jax.value_and_grad(loss))
+
+
+@pytest.mark.parametrize("kind", sorted(_tiny_kinds()))
+def test_a_layer_that_keeps_every_candidate_gives_nothing_saveables_bits(kind, monkeypatch):
+    """With a generous budget forced (a chip that reports memory to spare)
+    ``remat=True`` keeps every named candidate of every layer
+    (``ops/remat.py``: router, output projection, FFN, input projections,
+    kernel outputs), ``kept_residual_bytes`` counts them all, and loss and
+    gradients are ``remat_policy="nothing_saveable"``'s bit for bit: the same
+    values, kept instead of made twice. Without a memory report the program
+    keeps what it kept before: the attention kernels' residuals alone."""
+    import dataclasses
+    from deepspeed_tpu.models.llama import unbox_params
+    from deepspeed_tpu.observability.xla import named_residual_bytes
+    from deepspeed_tpu.ops import remat
+    rows, seq = 2, 256
+    base = LlamaConfig.tiny(max_position_embeddings=seq, attn_impl="flash",
+                            dtype=jnp.float32, **_tiny_kinds()[kind])
+    ids = jnp.asarray(np.random.default_rng(3).integers(0, 255, (rows, seq)), jnp.int32)
+    params = {"params": unbox_params(LlamaForCausalLM(base).init(
+        jax.random.PRNGKey(0), ids))["params"]}
+    recomputing = dataclasses.replace(base, remat=True)
+    todays = _value_and_grad_program(recomputing, ids).trace(params)
+    kept_today, offered = named_residual_bytes(todays.jaxpr)
+    attention = sum(s is None or s.operator != "mamba" and s.operator != "conv"
+                    for s in (base.layer_specs or (None, None)))
+    heads, width = base.num_attention_heads, base.v_head_dim or base.head_dim_
+    assert kept_today == attention * rows * seq * heads * (width * 4 + 4)
+    want = _value_and_grad_program(
+        dataclasses.replace(recomputing, remat_policy="nothing_saveable"), ids)(params)
+    monkeypatch.setattr(remat, "device_memory", lambda: (1 << 40, 0))
+    remat.forget_plans()
+    try:
+        generous = _value_and_grad_program(recomputing, ids)
+        kept, offered_again = named_residual_bytes(generous.trace(params).jaxpr)
+        got = generous(params)
+    finally:
+        remat.forget_plans()
+    assert offered_again == offered and kept == offered > kept_today
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), got, want)
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_without_recomputation_the_names_change_nothing_in_the_program(kind, monkeypatch):
+    """``remat=False``: a name is the identity, so the lowered program is the
+    one the same model gives with every ``ops/remat.py::keep`` taken out
+    (the parent's program), to the letter."""
+    import re
+    from deepspeed_tpu.models.llama import unbox_params
+    from deepspeed_tpu.ops import remat
+    cfg = LlamaConfig.tiny(max_position_embeddings=128, attn_impl="xla",
+                           **_tiny_kinds()[kind])
+    ids = jnp.asarray(np.random.default_rng(3).integers(0, 255, (2, 128)), jnp.int32)
+    params = {"params": unbox_params(LlamaForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), ids))["params"]}
+    def lowered():      # less the counter jax appends to its private functions' names
+        text = _value_and_grad_program(cfg, ids).lower(params).as_text()
+        return re.sub(r"(@[A-Za-z_]+?)_\d+\b", r"\1", text)
+
+    named = lowered()
+    monkeypatch.setattr(remat, "keep", lambda x, name: x)
+    assert lowered() == named

@@ -79,6 +79,93 @@ def test_no_policy_keeps_what_an_attention_kernel_gave(policy, forwards):
         q.size * 4 + 128 * 2 * 4 if policy is None else 0)
 
 
+# a price list of three layers (name, bytes): a dense layer, an expert layer
+# with a shared expert, a layer that offers its kernel's output
+_PRICES = (
+    (("ds.mixer.in", 300), ("ds.mixer.out", 100), ("ds.ffn.in", 400), ("ds.ffn.in", 400)),
+    (("ds.mixer.in", 300), ("ds.mixer.out", 100), ("ds.moe.route", 10), ("ds.ffn.in", 50)),
+    (("ds.mixer.in", 200), ("ds.mixer.kernel", 70), ("ds.mixer.out.narrow", 100)),
+)
+
+
+def _walk(prices):
+    """The (name, layer) pairs in the order ``choose_kept`` takes them."""
+    from deepspeed_tpu.ops import remat
+    return [(name, i) for name in remat.CANDIDATE_NAMES
+            for i, layer in enumerate(prices) if any(n == name for n, _ in layer)]
+
+
+@pytest.mark.parametrize("available", [None, 0, -5, 9])
+def test_with_nothing_to_spend_a_layer_keeps_todays_names(available):
+    """No budget (a backend that reports no memory gives None), none left,
+    or less than the cheapest candidate: ``RESIDUAL_NAMES`` in every layer."""
+    from deepspeed_tpu.ops import remat
+    plan = remat.choose_kept(available, _PRICES)
+    assert plan == (remat.RESIDUAL_NAMES, ) * len(_PRICES)
+    assert remat.kept_bytes(plan, _PRICES) == 0
+
+
+@pytest.mark.parametrize("same", [False, True], ids=["unrolled", "scan"])
+def test_the_kept_set_grows_with_the_budget_in_the_lists_order(same):
+    """Over every budget from nothing to everything: the kept bytes never
+    pass the budget, the kept set only grows, and it is a prefix of the walk
+    (router, deep output projection, FFN, input projections, narrow output
+    projection, kernels; layers from the first). A ``scan_layers`` body keeps a name in all layers or in none."""
+    from deepspeed_tpu.ops import remat
+    prices = (_PRICES[0], ) * 3 if same else _PRICES
+    walk, before = _walk(prices), set()
+    everything = sum(b for layer in prices for _, b in layer)
+    for available in range(0, everything + 50, 10):
+        plan = remat.choose_kept(available, prices, same_in_all_layers=same)
+        assert all(names[:2] == remat.RESIDUAL_NAMES for names in plan)
+        kept = {(n, i) for i, names in enumerate(plan) for n in names[2:]}
+        assert remat.kept_bytes(plan, prices) <= available
+        assert before <= kept
+        assert kept == set(walk[:len(kept)])
+        if same:
+            assert len(set(plan)) == 1
+        before = kept
+    assert len(before) == len(walk) and remat.kept_bytes(plan, prices) == everything
+
+
+def test_a_backend_without_a_memory_report_makes_no_plan():
+    """The CPU here reports no ``bytes_limit``: no plan, the price list is
+    never asked for, and ``checkpoint`` of a function that names candidates
+    keeps none of them; with a budget forced it keeps what fits."""
+    from deepspeed_tpu.observability.xla import named_residual_bytes
+    from deepspeed_tpu.ops import remat
+
+    def never():
+        raise AssertionError("no budget: nothing to price")
+
+    assert remat.device_memory() is None
+    assert remat.plan_for("k", never, rows=1, layer_input_bytes=0) is None
+    w = jnp.ones((16, 16), jnp.float32)
+
+    def fn(x):
+        h = remat.keep(x @ w, remat.MIXER_IN)
+        return jnp.sum(remat.keep(jnp.tanh(h) @ w, remat.MIXER_OUT) ** 2)
+
+    x = jnp.ones((4, 16), jnp.float32)
+    assert remat.price_list(fn, x) == ((remat.MIXER_IN, 256), (remat.MIXER_OUT, 256))
+    ckpt.configure(partition_activations=False, checkpoint_in_cpu=False)
+    grad = lambda: jax.jit(jax.grad(lambda x: ckpt.checkpoint(fn, x))).trace(x)  # noqa: E731
+    assert named_residual_bytes(grad().jaxpr) == (0, 512)
+    patch = pytest.MonkeyPatch()
+    try:
+        # 33% of 10,000 B is the reserve: 500 B are left, enough for one of the two
+        patch.setattr(remat, "device_memory", lambda: (10000, 6200))
+        remat.forget_plans()
+        assert named_residual_bytes(grad().jaxpr) == (256, 512)
+        patch.setattr(remat, "device_memory", lambda: (10000, 0))
+        assert named_residual_bytes(grad().jaxpr) == (256, 512)     # the plan is remembered
+        remat.forget_plans()
+        assert named_residual_bytes(grad().jaxpr) == (512, 512)
+    finally:
+        patch.undo()
+        remat.forget_plans()
+
+
 def test_unknown_policy_raises(setup):
     params, x = setup
     ckpt._CONFIG["policy"] = "not_a_policy"
