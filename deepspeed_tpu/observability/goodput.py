@@ -12,8 +12,9 @@ Attribution model — two complementary mechanisms:
 
 - ``mark(category)``: attribute everything since the previous mark (the
   *cursor*) to ``category``. The engine calls ``mark("useful_step")`` at
-  each optimizer-step boundary, so in steady state the whole step wall
-  (dispatch + device wait + dataloader) lands in ``useful_step``.
+  each optimizer-step boundary and as each publish ends, so in steady
+  state the whole step wall (dispatch + device wait + dataloader, and the
+  exports that follow a window's last step) lands in ``useful_step``.
 - ``span(category)``: a context manager for excursions with clear
   boundaries (checkpoint save/load, anomaly rollback, the async-window
   host fetch). A span is also a ``ds.train.<category>`` scope of the

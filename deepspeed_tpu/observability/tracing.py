@@ -72,10 +72,11 @@ class _Timeline:
 
 class _Scope:
     """One open :meth:`RequestTracer.scope`. ``args`` may be filled in
-    while the scope is open (a count known only after the work)."""
+    while the scope is open (a count known only after the work); ``dur_s``
+    is the recorded duration once it has closed."""
 
     __slots__ = ("_tracer", "name", "uid", "args", "_annotation", "_t0",
-                 "_sid", "_parent", "_stack")
+                 "_sid", "_parent", "_stack", "dur_s")
 
     def __init__(self, tracer, name, uid, annotate, args):
         self._tracer = tracer
@@ -100,6 +101,7 @@ class _Scope:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         self._stack.pop()
+        self.dur_s = t1 - self._t0
         self._tracer._record_scope(
             (self.name, self._t0, t1, self._parent,
              None if self.uid is None else str(self.uid),

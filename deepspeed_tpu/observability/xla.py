@@ -11,12 +11,21 @@ Three pieces, all host-side and off the per-step critical path:
   function wrappers, e.g. the grad-comm step builder) the first call
   counts as the compile and later calls as hits.
 - FLOPs accounting: a compiling call captures ``ShapeDtypeStruct`` specs
-  of its arguments so :meth:`WatchedJit.program_flops` can later run
-  ``lower().cost_analysis()`` — HLO-level cost analysis on the lowered
-  (NOT compiled) module, ~10ms once per program, done lazily at publish
-  time, never on the step path. :class:`TrainInstruments` turns (dispatches × program FLOPs)
-  over a wall interval into the ``ds_train_mfu`` gauge; serving uses the
-  same ``program_flops`` for ``ds_serving_wave_mfu``.
+  of its arguments, each with the committed sharding and weak type jit
+  keyed its caches on (a spec without them misses both caches, and the
+  whole program is traced and lowered again), so
+  :meth:`WatchedJit.program_flops` gets the dispatch's own jaxpr and
+  lowering back and runs HLO-level cost analysis on the lowered (NOT
+  compiled) module. Its wall is the ``ds.compile.cost_analysis`` scope,
+  summed in ``ds_cost_analysis_seconds_total{key=...}``. Resolved inside
+  the compiling call for real programs, lazily at publish time for tiny
+  ones, never on the step path. :class:`TrainInstruments` turns
+  (dispatches × program FLOPs) over a wall interval into the
+  ``ds_train_mfu`` gauge; serving uses the same ``program_flops`` for
+  ``ds_serving_wave_mfu``. A lowered module has no cost analysis on a TPU
+  (``cost_analysis()`` is None), so on a chip the FLOPs read 0.0 and both
+  gauges stay unset; the named residual bytes come out everywhere
+  (ROADMAP S9 iii).
 - Device-memory gauges (:func:`refresh_memory_gauges`) from
   ``device.memory_stats()`` — live bytes, peak watermark, allocator
   limit. CPU backends return no stats; the gauges simply stay absent.
@@ -57,15 +66,21 @@ def cost_analysis_flops(stage) -> float:
 
 
 def _arg_specs(args, kwargs) -> Tuple[tuple, dict]:
-    """Shape/dtype skeleton of a call's arguments: arrays become
-    ``ShapeDtypeStruct`` (shape metadata survives donation; no buffers are
-    retained), statics pass through untouched — good enough to re-``lower``
-    the same program for cost analysis."""
+    """Skeleton of a call's arguments as jit keyed its caches on them:
+    arrays become ``ShapeDtypeStruct`` (metadata survives donation; no
+    buffers are retained) with their weak type and, where the array is
+    committed to a sharding, that sharding: jit reads a spec with a sharding
+    as a committed argument and one without as an uncommitted one, so
+    ``trace`` and ``lower`` on these specs return what the dispatch traced
+    and lowered. Statics pass through untouched."""
     import jax
 
     def spec(x):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
-            return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
+            return jax.ShapeDtypeStruct(
+                tuple(x.shape), x.dtype,
+                sharding=x.sharding if getattr(x, "committed", False) else None,
+                weak_type=getattr(x, "weak_type", False))
         return x
 
     return (jax.tree_util.tree_map(spec, args),
@@ -183,19 +198,23 @@ class WatchedJit:
 
     def program_flops(self) -> float:
         """Cost-analysis FLOPs of one dispatch of this program. Lazy and
-        cached: the first call re-lowers from the captured arg specs and
-        runs HLO-level cost analysis on the LOWERED module (~10ms) — it
-        deliberately never calls ``.compile()``, which would pay a full
-        fresh XLA compile (the AOT path shares no executable cache with
-        dispatch). Never invoked on the step path."""
+        cached: the first call asks jit for the trace and the lowering of
+        the captured arg specs, which are the dispatch's own and come from
+        jit's caches (``_arg_specs``), walks the jaxpr for the named
+        residuals and runs HLO-level cost analysis on the LOWERED module
+        (0.0 on a TPU, where a lowered module has none). It deliberately
+        never calls ``.compile()``, which would pay a full fresh XLA compile
+        (the AOT path shares no executable cache with dispatch). Never
+        invoked on the step path."""
         if self._flops is not None:
             return self._flops
         if self._flops_spec is None:
             return 0.0
         a, k = self._flops_spec
-        # the re-lowering is a whole-program trace: timed, so that what the
-        # MFU gauges cost inside a compile stall can be read off the tracer
-        with get_tracer().scope("ds.compile.cost_analysis", key=self.key):
+        # timed: a spec that misses jit's caches costs a whole-program trace
+        # and lowering inside the compile stall, and this is where it shows
+        with get_tracer().scope("ds.compile.cost_analysis",
+                                key=self.key) as scope:
             try:
                 traced = self._fn.trace(*a, **k)
                 self._kept_bytes, self._offered_bytes = named_residual_bytes(
@@ -203,6 +222,7 @@ class WatchedJit:
                 self._flops = cost_analysis_flops(traced.lower())
             except Exception:
                 self._flops = 0.0
+        self._watch.on_cost_analysis(self.key, scope.dur_s)
         return self._flops
 
     def program_kept_bytes(self) -> Tuple[int, int]:
@@ -223,6 +243,9 @@ class CompileWatch:
       — the "why is my steady state recompiling" counter)
     - ``ds_compile_cache_hits_total{key=...}``: dispatches served from the
       jit cache
+    - ``ds_cost_analysis_seconds_total{key=...}``: wall seconds of the
+      program's cost analysis (``WatchedJit.program_flops``, once a
+      program); not part of ``ds_compile_seconds``
 
     ``on_compile_seconds`` (optional) feeds measured compile wall into the
     goodput ledger's pending-compile pool."""
@@ -258,7 +281,13 @@ class CompileWatch:
                          reg.counter(
                             "ds_compile_cache_hits_total",
                             "Dispatches served from the jit cache per "
-                            "compile key", labels=lab))
+                            "compile key", labels=lab),
+                         reg.counter(
+                            "ds_cost_analysis_seconds_total",
+                            "Wall seconds reading a program's FLOPs and named "
+                            "residual bytes off jit's trace and lowering per "
+                            "compile key (once a program; beside, not in, "
+                            "ds_compile_seconds)", labels=lab))
                     self._per_key[key] = h
         return h
 
@@ -270,7 +299,7 @@ class CompileWatch:
         return WatchedJit(fn, key, self)
 
     def on_compile(self, key: str, seconds: float, retrace: bool) -> None:
-        hist, compiles, recompiles, _ = self._handles(key)
+        hist, compiles, recompiles = self._handles(key)[:3]
         hist.record(seconds)
         compiles.inc()
         if retrace:
@@ -282,11 +311,15 @@ class CompileWatch:
     def on_hit(self, key: str) -> None:
         self._handles(key)[3].inc()
 
+    def on_cost_analysis(self, key: str, seconds: float) -> None:
+        self._handles(key)[4].inc(seconds)
+
     def counts(self, key: str) -> dict:
         """Introspection helper for tests/consoles."""
-        hist, compiles, recompiles, hits = self._handles(key)
+        hist, compiles, recompiles, hits, cost = self._handles(key)
         return {"compiles": compiles.value, "recompiles": recompiles.value,
-                "hits": hits.value, "compile_seconds": hist.sum}
+                "hits": hits.value, "compile_seconds": hist.sum,
+                "cost_analysis_seconds": cost.value}
 
 
 def refresh_memory_gauges(registry: Optional[MetricsRegistry] = None) -> dict:
@@ -479,3 +512,12 @@ class TrainInstruments:
             self.mfu.set(min(1.0, flops / (wall * self.peak_flops)))
         if any_dispatch:
             self._mfu_t0 = now
+
+    def publish_done(self) -> None:
+        """Close a publish: what followed :meth:`publish` (the monitor
+        bridge, the textfile) is attributed now, to the category the next
+        step's mark would give it, so a run's last scrape accounts for its
+        whole wall clock."""
+        if self.ledger is not None:
+            self.ledger.mark("useful_step")
+            self.ledger.publish()

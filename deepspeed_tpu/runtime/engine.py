@@ -1815,6 +1815,8 @@ class DeepSpeedTpuEngine:
                     f"observability textfile export to "
                     f"{self._obs_textfile} failed: {e}; disabling")
                 self._obs_textfile = None
+        if self._train_obs is not None:
+            self._train_obs.publish_done()
 
     # ------------------------------------------------------------------
     # async step pipeline (windowed host sync)

@@ -139,6 +139,10 @@ def test_training_observability_acceptance(tmp_path, monkeypatch):
     doc = json.loads(rj.stdout)
     assert doc["goodput_seconds"]["useful_step"] > 0
     assert "train_step_fused" in doc["compiles"]
+    # the program's one cost analysis (published with the MFU) beside its
+    # compile seconds, and in the console's table
+    assert doc["compiles"]["train_step_fused"]["cost_analysis_seconds"] > 0
+    assert "cost an." in r.stdout
 
 
 def test_observability_disabled_is_silent(tmp_path):
@@ -168,6 +172,30 @@ def test_sync_mode_publishes_per_step(tmp_path):
     led = e._train_obs.ledger
     assert led.attributed_seconds() == pytest.approx(
         led.wall_seconds(), rel=0.05)
+    assert (tmp_path / "ds.prom").exists()
+
+
+def test_a_publish_attributes_its_own_seconds(tmp_path, monkeypatch):
+    """What follows the last step's mark, the drain's exports, is attributed
+    as the publish ends: with an export that takes a third of the run the
+    categories still partition the wall clock, and the gauge agrees."""
+    import time
+    from deepspeed_tpu.observability.metrics import MetricsRegistry
+    orig = MetricsRegistry.write_textfile
+
+    def slow(self, path):
+        time.sleep(0.25)
+        return orig(self, path)
+
+    monkeypatch.setattr(MetricsRegistry, "write_textfile", slow)
+    e = make_engine(tmp_path)
+    for x, y in batches(4):
+        e.fused_train_step(x, y)
+    led = e._train_obs.ledger
+    wall, attributed = led.wall_seconds(), led.attributed_seconds()
+    assert attributed == pytest.approx(wall, rel=0.05)
+    assert get_registry().get("ds_goodput_fraction").value == pytest.approx(
+        led.goodput_fraction())
     assert (tmp_path / "ds.prom").exists()
 
 
