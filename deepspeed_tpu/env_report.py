@@ -36,8 +36,8 @@ def op_report():
     _aio.aio_available()
     from .ops import cpu_optim as _cpu_optim  # noqa: F401
     _cpu_optim.cpu_optim_available()
-    for mod in ("attention", "attention_folded", "normalization", "quantizer",
-                "fused_optimizer", "rope",
+    for mod in ("attention", "normalization", "quantizer", "fused_optimizer",
+                "rope",
                 "evoformer_attn", "spatial", "cpu_optim", "paged_attention",
                 "grouped_matmul", "sampling",
                 "sparse_attention.sparse_self_attention"):
@@ -65,29 +65,18 @@ def debug_report():
                      f"{v if v else NO}")
     lines.append(f"python version {'.' * 34} {sys.version.split()[0]}")
     try:
-        # the variant as the dispatcher resolves it, not the raw env var
-        from .ops.attention import resolved_attention_variant
-        lines.append(f"flash-attention variant {'.' * 25} "
-                     f"{resolved_attention_variant()}")
-    except Exception as e:  # pragma: no cover
-        lines.append(f"flash-attention variant {'.' * 25} {NO} ({e})")
-    try:
-        # per-leg kernel dispatch: where the table comes from (measured
-        # autotune cache vs built-in heuristics) and what the bench shape
-        # resolves to right now — so every saved report pins the kernels
+        # what a TPU runs at the 0.4B preset's shape and at each chip's call
+        # in the benchmark's train-zero3-seq4k cell (Mistral-7B widths):
+        # kernels and blocks follow from the shape alone
         from .ops import kernel_dispatch
-        lines.append(f"attn dispatch table {'.' * 29} "
-                     f"{kernel_dispatch.table_source()}")
         lines.append(f"attn dispatch @ bench shape {'.' * 21} "
                      f"{kernel_dispatch.resolved_note()}")
-        # each chip's call in the benchmark's train-zero3-seq4k cell
-        # (Mistral-7B widths): the blocks are chosen from the shape
         lines.append(f"attn dispatch @ [1,4096,32/8,128] w4096 {'.' * 9} "
                      + kernel_dispatch.resolved_note(
                          batch=1, seq=4096, heads=32, kv_heads=8,
                          head_dim=128, window=4096))
     except Exception as e:  # pragma: no cover
-        lines.append(f"attn dispatch table {'.' * 29} {NO} ({e})")
+        lines.append(f"attn dispatch @ bench shape {'.' * 21} {NO} ({e})")
     try:
         # speculative decoding: where drafts come from under the current
         # config — the fused program's on-device ring buffer, or the host

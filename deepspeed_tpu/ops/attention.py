@@ -42,7 +42,6 @@ found live, interior or dead).
 """
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -274,17 +273,6 @@ def _kernel_name(flash: str, latent: str, D: int, Dv: int) -> str:
     ``mla_*``, so that a reader of the device trace, which counts a call's
     work from ONE width off its first result, tells them apart by name."""
     return flash if Dv == D else latent
-
-
-def resolved_attention_variant() -> str:
-    """The flash-attention variant a Pallas leg falls back to when no
-    measurement decides it: the ``DS_TPU_FLASH_FOLDED`` preference resolved
-    as the dispatcher resolves it (``env_report`` prints it). Per-shape
-    dispatch (ops/kernel_dispatch.py) owns the actual folded-vs-per-head
-    choice; for the full per-leg (fwd/bwd × impl × blocks) resolution use
-    ``kernel_dispatch.resolved_note``."""
-    from .kernel_dispatch import IMPL_FOLDED, _variant_preference
-    return "folded" if _variant_preference() == IMPL_FOLDED else "per-head"
 
 
 def _blocked(Sq, Sk, block_q, block_k):
@@ -601,56 +589,8 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
 
 
 # ---------------------------------------------------------------------------
-# shape-aware dispatch (ops/kernel_dispatch.py decides; this wires the legs)
+# the call: ops/kernel_dispatch.py resolves kernels and blocks from the shape
 # ---------------------------------------------------------------------------
-
-
-def _xla_attention_lse(q, k, v, scale, causal, window=None, softcap=None):
-    """XLA forward that ALSO returns the log-sum-exp residual, so a Pallas
-    backward can pair with an XLA forward (the 42.7 ms < 62.9 ms dispatch
-    at hd64/seq1024). Scores accumulate in fp32 (preferred_element_type)
-    so the LSE matches what the Pallas bwd kernels recompute in-kernel;
-    lse comes back in the NATURAL [B, Sq, H, 1] layout."""
-    B, Sq, H, D = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    qg = q.reshape(B, Sq, KV, G, D)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    if softcap is not None:  # Gemma-2: cap BEFORE masking
-        s = softcap_scores(s, softcap)
-    if causal or window is not None:
-        n, m = q.shape[1], k.shape[1]
-        mask = jnp.ones((n, m), bool)
-        if causal:
-            mask &= jnp.tril(mask, k=m - n)
-        if window is not None:
-            qpos = jnp.arange(n)[:, None] + (m - n)
-            mask &= qpos - jnp.arange(m)[None, :] < window
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-    live = s > NEG_INF
-    m_row = jnp.max(s, axis=-1, keepdims=True)
-    m_safe = jnp.where(m_row <= NEG_INF, 0.0, m_row)
-    p = jnp.where(live, jnp.exp(s - m_safe), 0.0)
-    l_row = p.sum(axis=-1, keepdims=True)
-    safe_l = jnp.where(l_row == 0.0, 1.0, l_row)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", (p / safe_l).astype(v.dtype), v)
-    lse = jnp.where(l_row == 0.0, LSE_MASKED, m_safe + jnp.log(safe_l))
-    # [B, KV, G, Sq, 1] -> natural [B, Sq, H, 1]
-    lse = lse.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, 1)
-    return out.reshape(B, Sq, H, v.shape[-1]), lse
-
-
-def _lse_natural_to_perhead(lse, B, Sq, KV, G):
-    """[B, Sq, H, 1] -> [B*KV, G, Sq, 1] (the per-head kernels' layout)."""
-    return (lse.reshape(B, Sq, KV, G, 1).transpose(0, 2, 3, 1, 4)
-            .reshape(B * KV, G, Sq, 1))
-
-
-def _lse_perhead_to_natural(lse, B, Sq, KV, G):
-    """[B*KV, G, Sq, 1] -> [B, Sq, H, 1]."""
-    return (lse.reshape(B, KV, G, Sq, 1).transpose(0, 3, 1, 2, 4)
-            .reshape(B, Sq, KV * G, 1))
 
 
 def _fit_blocks(dec, Sq, Sk):
@@ -675,54 +615,14 @@ def _fit_blocks(dec, Sq, Sk):
                         block_k=_fit(Sk, dec.block_k))
 
 
-def _run_fwd(q, k, v, scale, causal, window, softcap, interpret, dec,
-             lse_layout):
-    """Execute one forward leg per its Decision; returns (o, lse) with lse
-    in ``lse_layout`` ("perhead" | "natural"), or lse=None when the paired
-    backward doesn't need it (lse_layout=None)."""
-    B, Sq, H, D = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    if dec.impl == "xla":
-        if lse_layout is None:
-            return _xla_attention(q, k, v, scale, causal, window, softcap), None
-        o, lse = _xla_attention_lse(q, k, v, scale, causal, window, softcap)
-    elif dec.impl == "folded":
-        from .attention_folded import flash_fwd_folded
-        o, lse = flash_fwd_folded(q, k, v, scale, causal, dec.block_q,
-                                  dec.block_k, interpret, window, softcap)
-        # folded lse is already natural [B, Sq, H, 1]
-    else:
-        o, lse_ph = _flash_fwd(q, k, v, scale, causal, dec.block_q,
-                               dec.block_k, interpret, window, softcap)
-        if lse_layout == "perhead":
-            return o, lse_ph
-        lse = (None if lse_layout is None
-               else _lse_perhead_to_natural(lse_ph, B, Sq, KV, G))
-        return o, lse
-    if lse_layout is None:
-        return o, None
-    if lse_layout == "perhead":
-        lse = _lse_natural_to_perhead(lse, B, Sq, KV, G)
-    return o, lse
-
-
-def _bwd_lse_layout(bwd_dec):
-    """Which lse layout the bwd leg consumes (None: no residual needed)."""
-    return {"xla": None, "folded": "natural", "pallas": "perhead",
-            "fused": "perhead"}[bwd_dec.impl]
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _dispatched_attention(q, k, v, scale, causal, window, softcap, interpret,
                           fwd_dec, bwd_dec):
-    """Attention with INDEPENDENT per-leg kernel selection: ``fwd_dec`` and
-    ``bwd_dec`` are hashable ``kernel_dispatch.Decision`` tuples resolved
-    at trace time from the measured autotune cache / heuristic table —
-    e.g. XLA fused fwd + Pallas flash bwd where XLA wins the forward."""
-    o, _ = _run_fwd(q, k, v, scale, causal, window, softcap, interpret,
-                    fwd_dec, None)
-    return o
+    """The per-head forward at ``fwd_dec``'s blocks; its backward is
+    ``bwd_dec``'s (hashable ``kernel_dispatch.Decision`` tuples, resolved at
+    trace time): the fused kernel or the dq + dk/dv pair."""
+    return _flash_fwd(q, k, v, scale, causal, fwd_dec.block_q,
+                      fwd_dec.block_k, interpret, window, softcap)[0]
 
 
 # What a layer's backward needs of an attention kernel's forward, by the
@@ -738,41 +638,27 @@ def _name_residuals(o, lse):
     """-> (o, lse) under ``RESIDUAL_NAMES``, ``lse`` WITHOUT the kernels'
     trailing unit dimension: a float32 ``[..., seq, 1]`` is tiled to 128
     lanes in HBM, 128 times its bytes, and a kept residual lives from the
-    forward to the layer's backward. The backward legs take this form: they
-    read ``lse`` as rows of a transposed score tile, a reshape of either
-    form, and only a kernel that reads it a query block at a time
-    (``flash_dq``, the folded pair) is handed the unit dimension back. With
-    nothing between the two (no recomputation) XLA folds the reshapes."""
+    forward to the layer's backward. The backward takes this form: it reads
+    ``lse`` as rows of a transposed score tile, a reshape of either form,
+    and only ``flash_dq``, which reads it a query block at a time, is handed
+    the unit dimension back. With nothing between the two (no
+    recomputation) XLA folds the reshapes."""
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
-    if lse is not None:
-        lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
     return o, lse
 
 
 def _fwd_rule(q, k, v, scale, causal, window, softcap, interpret, fwd_dec,
               bwd_dec):
-    o, lse = _name_residuals(*_run_fwd(
-        q, k, v, scale, causal, window, softcap, interpret, fwd_dec,
-        _bwd_lse_layout(bwd_dec)))
+    o, lse = _name_residuals(*_flash_fwd(
+        q, k, v, scale, causal, fwd_dec.block_q, fwd_dec.block_k, interpret,
+        window, softcap))
     return o, (q, k, v, o, lse)
 
 
 def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
               res, g):
-    q, k, v, o, lse = res
-    if bwd_dec.impl == "xla":
-        # standard recompute: differentiate the XLA reference directly (no
-        # LSE residual needed); used where the materialized-scores bwd wins
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _xla_attention(q_, k_, v_, scale, causal,
-                                              window, softcap), q, k, v)
-        return vjp(g)
-    if bwd_dec.impl == "folded":
-        from .attention_folded import flash_bwd_folded
-        return flash_bwd_folded(q, k, v, lse[..., None], o, g, scale, causal,
-                                bwd_dec.block_q, bwd_dec.block_k, interpret,
-                                window, softcap)
-    return _flash_bwd((q, k, v, o, lse), g, scale, causal, bwd_dec.block_q,
+    return _flash_bwd(res, g, scale, causal, bwd_dec.block_q,
                       bwd_dec.block_k, interpret, window, softcap,
                       fused=bwd_dec.impl == "fused")
 
@@ -801,44 +687,43 @@ def flash_attention(q,
                     softcap: Optional[float] = None,
                     force_pallas: Optional[bool] = None,
                     interpret: bool = False,
-                    impl_fwd: Optional[str] = None,
                     impl_bwd: Optional[str] = None):
     """Blocked attention; q [B, S, H, D], k/v [B, S, KV, D] (GQA native);
     v may be ``[B, S, KV, Dv]`` with ``Dv != D`` (the result is then ``Dv``
     wide and the kernels are the ``mla_*`` calls).
 
-    On TPU (or with interpret=True anywhere) the forward and backward
-    implementations are selected INDEPENDENTLY per shape by
-    ``ops/kernel_dispatch.py``: measured autotune-cache entries win, then
-    the built-in heuristic table (XLA fused fwd + Pallas flash bwd at
-    hd64/seq>=1024 — the round-5 chip measurement). ``impl_fwd``/
-    ``impl_bwd`` ("xla" | "pallas" | "folded", and for the backward "fused":
-    one kernel where "pallas" is the dq + dk/dv pair) pin a leg explicitly
-    (tests, the sweep tool); ``block_q``/``block_k`` pin the Pallas tile sizes,
-    which otherwise follow from the shape (``kernel_dispatch.choose_blocks``).
-    Off-TPU without interpret, the pure-XLA fused path runs both legs.
+    On a TPU (or with ``interpret=True`` anywhere) the per-head Pallas
+    forward runs, and under ``jax.grad`` the backward that
+    ``ops/kernel_dispatch.py`` resolves from the shape: the fused kernel
+    where its whole-sequence dQ fits in VMEM, else the dq + dk/dv pair.
+    ``impl_bwd`` ("fused" | "pallas", the pair) and ``block_q``/``block_k``
+    pin them (tests, the sweep tool); blocks otherwise follow from the shape
+    (``kernel_dispatch.choose_blocks``). Off a TPU without ``interpret``,
+    ``_xla_attention`` runs both ways.
+
+    The kernels count a causal mask or a window from the first query and
+    the first key; ``_xla_attention`` aligns the last query with the last
+    key. The two agree where ``seq_q == seq_k``; a masked call with more
+    keys than queries, or fewer, is refused on the kernel path.
     """
     from . import kernel_dispatch as kd
 
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
     if not (use_pallas(force_pallas) or interpret):
         return _xla_attention(q, k, v, scale, causal, window, softcap)
+    if (causal or window is not None) and q.shape[1] != k.shape[1]:
+        raise ValueError(
+            f"flash_attention(causal={causal}, window={window}) with seq_q "
+            f"{q.shape[1]} != seq_k {k.shape[1]}: the kernels align the "
+            "mask's diagonal with the first query and key, the XLA "
+            "reference with the last; pass equal lengths, or no mask")
     sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, causal,
                       window, softcap, v_dim=v.shape[-1])
     blocks = ((block_q, block_k)
               if block_q is not None and block_k is not None else None)
-    fwd_dec, bwd_dec = kd.resolve(
-        sig, "interpret" if interpret and not use_pallas(force_pallas)
-        else None,
-        impl_fwd=impl_fwd, impl_bwd=impl_bwd, blocks=blocks,
-        pallas_only=bool(force_pallas) and impl_fwd is None
-        and impl_bwd is None)
-    if sig.v_dim and "folded" in (fwd_dec.impl, bwd_dec.impl):
-        raise ValueError("the folded kernels take one head size: values "
-                         f"{sig.v_dim} wide beside keys {sig.head_dim} wide "
-                         "run the per-head kernels or XLA")
-    fwd_dec = _fit_blocks(fwd_dec, q.shape[1], k.shape[1])
-    bwd_dec = _fit_blocks(bwd_dec, q.shape[1], k.shape[1])
+    fwd_dec, bwd_dec = (_fit_blocks(dec, q.shape[1], k.shape[1])
+                        for dec in kd.resolve(sig, impl_bwd=impl_bwd,
+                                              blocks=blocks))
     return _flash_attention_call(q, k, v, scale, causal, window,
                                  softcap, interpret, fwd_dec, bwd_dec)
 

@@ -1,59 +1,32 @@
-"""Shape-aware attention kernel dispatch.
+"""Attention kernels and their blocks, from the shape of the call alone.
 
-The round-5 chip breakdown proved the static kernel choice wrong at the
-bench shape: the Pallas flash *forward* lost to XLA's fused attention
-(62.9 ms vs 42.7 ms at hd64/seq1024) while the flash *backward* is the leg
-the Pallas pair actually wins (no [S, S] score materialization in the
-recompute).  DeepCompile (arXiv:2504.09983) argues exactly this: profile-
-guided, per-shape kernel selection should replace static choices in
-distributed training stacks.
+On a TPU ``flash_attention`` runs one forward, the per-head Pallas kernel
+(``flash_fwd``), and one of two backwards: ``flash_dkdv_dq`` ("fused": dQ,
+dK and dV from one walk over the score tiles) where the float32 dQ of a KV
+head's whole sequence fits in VMEM beside its tiles (``fused_vmem_bytes``
+within ``FUSED_VMEM_CAP_BYTES``), else the pair ``flash_dq`` +
+``flash_dkdv`` ("pallas"). ``resolve`` decides; what it reads is the
+``ShapeSig`` and what the caller pinned (``impl_bwd=``, ``block_q=`` /
+``block_k=``: tests and the sweep tool). No environment variable and no
+file is read on the way. The routes this replaced (an XLA forward under a
+rule on the head size, head-folded kernels, a measured table) lost to these
+two at every point of a sweep on the chip: PERF.md, PR 44.
 
-This module picks the forward and backward implementations *independently*
-per (shape, dtype, causal/window/softcap flags, device kind).  Precedence
-per leg, strongest first:
-
-1. explicit ``impl_fwd``/``impl_bwd`` kwargs on ``flash_attention`` (tests,
-   the sweep tool);
-2. ``DS_TPU_ATTN_FWD`` / ``DS_TPU_ATTN_BWD`` env (``xla|pallas|folded``, and
-   ``fused`` for the backward);
-3. legacy ``DS_TPU_FLASH_FOLDED``: nonzero forces the folded Pallas pair on
-   BOTH legs (existing A/B scripts and tests depend on that); ``0`` pins
-   the per-head variant for any leg that resolves to Pallas;
-4. a *measured* entry in the persistent autotune cache
-   (``autotune_cache.py``, written by ``bin/ds_kernel_tune``);
-5. the built-in heuristic table below (which encodes the measured
-   42.7 < 62.9 ms fwd result: XLA fused forward at hd64 / seq >= 1024
-   while its float32 scores stay under 2 GiB; Pallas backward always, the
-   fused kernel wherever its whole-sequence dQ accumulator fits in VMEM,
-   else the dq + dk/dv pair).
-
-Blocks follow the same idea: explicit args > ``DS_TPU_FLASH_BLOCKS`` env >
-measured cache blocks > ``choose_blocks``, a pure function of the shape
-signature and a VMEM estimate of the leg's tiles (1024 folded query rows a
-step at any GQA group, and 512 keys or as many as the queries).
+Blocks are ``choose_blocks``, a pure function of the shape signature and a
+VMEM estimate of the leg's tiles (1024 folded query rows a step at any GQA
+group, and 512 keys or as many as the queries). The block-diffusion kernels'
+tiles (``choose_block_diffusion_blocks``) and the VMEM estimates every
+kernel of ``ops/`` sizes its limit by (``vmem_limit_bytes``) live here too.
 """
 
 import math
-import os
 from typing import NamedTuple, Optional
 
-from .autotune_cache import get_cache
-from ..utils.logging import logger
-
-IMPL_XLA = "xla"
-IMPL_PALLAS = "pallas"  # per-head kernels (ops/attention.py)
-IMPL_FOLDED = "folded"  # head-folded kernels (ops/attention_folded.py)
-# backward only: the per-head kernels' one-pass backward (dQ, dK and dV from
-# one walk over the score tiles, ``flash_dkdv_dq``); "pallas" there is the
-# pair ``flash_dq`` + ``flash_dkdv``
+# the backward's two implementations, both per-head Pallas kernels
+# (ops/attention.py): the pair ``flash_dq`` + ``flash_dkdv``, and the
+# one-pass ``flash_dkdv_dq``. The forward has one, named as the pair is.
+IMPL_PALLAS = "pallas"
 IMPL_FUSED = "fused"
-_IMPLS = {"fwd": (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED),
-          "bwd": (IMPL_XLA, IMPL_PALLAS, IMPL_FOLDED, IMPL_FUSED)}
-
-# candidate (block_q, block_k) grid the offline sweep times, beyond the
-# defaults — the round-5 sweep died at the window edge before reaching them
-SWEEP_BLOCKS = ((256, 512), (512, 512), (512, 1024), (1024, 1024),
-                (128, 128), (256, 256))
 
 
 class ShapeSig(NamedTuple):
@@ -77,12 +50,10 @@ class ShapeSig(NamedTuple):
 
 
 class Decision(NamedTuple):
-    """One leg's resolved choice. ``source`` records provenance for the
-    artifacts: explicit | env | legacy-env | measured | heuristic."""
+    """One leg's kernel and blocks."""
     impl: str
     block_q: int
     block_k: int
-    source: str
 
 
 def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
@@ -94,57 +65,6 @@ def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
                     windowed=window is not None,
                     softcapped=softcap is not None, pattern=pattern,
                     v_dim=0 if int(v_dim) == int(d) else int(v_dim))
-
-
-def signature(leg: str, sig: ShapeSig, device_kind: str) -> str:
-    """Cache key: leg + device kind + the full shape signature.  Versioned
-    at the file level (autotune_cache.CACHE_VERSION), so this string only
-    needs to be collision-free, not forward-compatible."""
-    return (f"{leg}|{device_kind}|b{sig.batch}|sq{sig.seq_q}|sk{sig.seq_k}"
-            f"|h{sig.heads}|kv{sig.kv_heads}|d{sig.head_dim}|{sig.dtype}"
-            f"|c{int(sig.causal)}|w{int(sig.windowed)}"
-            f"|sc{int(sig.softcapped)}" + (f"|p{sig.pattern}" if sig.pattern else "")
-            + (f"|dv{sig.v_dim}" if sig.v_dim else ""))
-
-
-def device_kind() -> str:
-    """Device kind string for cache keys ("TPU v5e", "cpu", ...).  Interpret
-    mode keys as "interpret" so CPU sweep results never masquerade as chip
-    measurements."""
-    import jax
-    d = jax.devices()[0]
-    return getattr(d, "device_kind", None) or d.platform
-
-
-def _env_impl(name: str, leg: str) -> Optional[str]:
-    val = os.environ.get(name, "").strip().lower()
-    if not val:
-        return None
-    if val not in _IMPLS[leg]:
-        logger.warning(f"{name}={val!r} ignored (want one of {_IMPLS[leg]})")
-        return None
-    return val
-
-
-def _variant_preference() -> Optional[str]:
-    """Which Pallas variant (per-head vs folded) a Pallas leg should use
-    when nothing shape-specific decided it: the legacy env, else none."""
-    env = os.environ.get("DS_TPU_FLASH_FOLDED")
-    if env is not None:
-        return IMPL_FOLDED if env not in ("", "0") else IMPL_PALLAS
-    return None
-
-
-def _env_blocks() -> Optional[tuple]:
-    env = os.environ.get("DS_TPU_FLASH_BLOCKS")
-    if not env:
-        return None
-    try:
-        bq, bk = (int(x) for x in env.split(","))
-        return bq, bk
-    except ValueError:
-        logger.warning(f"DS_TPU_FLASH_BLOCKS={env!r} ignored (want 'bq,bk')")
-        return None
 
 
 # What the block choice aims at (sweeps on a v5e: PR 25 at group 4, PR 32 at
@@ -176,13 +96,6 @@ VMEM_SCOPED_DEFAULT_BYTES = 16 * 2**20
 # float32 dQ of one KV head's whole sequence): 16,384 tokens at group 4 are
 # 55 MiB, 24,576 are 71 MiB and take the dq + dk/dv pair.
 FUSED_VMEM_CAP_BYTES = 64 * 2**20
-# The most the XLA forward's materialised float32 scores may take for the
-# heuristic to choose it: four times the one shape the rule was measured at,
-# a bound and not a crossover. A forward-only sweep at head 64, group 4 (v5e,
-# PR 31, docs/kernel_dispatch.md) found no crossover to place it at: the
-# Pallas forward won at every size from 0.5 to 8 GiB, by 2.3x under the
-# bound and by up to 10x over it.
-XLA_FWD_SCORE_BYTES = 2 * 2**30
 
 
 def flash_vmem_bytes(leg: str, group: int, head_dim: int, itemsize: int,
@@ -246,7 +159,7 @@ def vmem_width(head_dim: int, v_dim: int = 0) -> int:
 
 def fused_vmem_bytes(sig: ShapeSig) -> int:
     """``flash_vmem_bytes`` of the fused backward at ``sig``, with the blocks
-    the shape gives it: what ``_heuristic_impl`` holds against
+    the shape gives it: what ``resolve`` holds against
     FUSED_VMEM_CAP_BYTES."""
     return flash_vmem_bytes("fused", max(1, sig.heads // sig.kv_heads),
                             vmem_width(sig.head_dim, sig.v_dim),
@@ -351,7 +264,7 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
     which holds more score tiles at once, reaches it at group 1 and with
     fp32 operands). ``leg`` "fused" (the one-pass backward): FUSED_MAX_ROWS
     rows a step, KEY_BLOCK queries at most, and KEY_BLOCK keys; whether its
-    estimate (``fused_vmem_bytes``) fits is ``_heuristic_impl``'s to ask."""
+    estimate (``fused_vmem_bytes``) fits is ``resolve``'s to ask."""
     group = max(1, sig.heads // sig.kv_heads)
     if leg == "fused":
         cap_q = min(KEY_BLOCK, max(128, FUSED_MAX_ROWS // group))
@@ -373,125 +286,39 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
             cap_k //= 2
 
 
-def _heuristic_impl(leg: str, sig: ShapeSig) -> str:
-    """Built-in table when no measurement exists.
-
-    Forward: XLA's fused softmax-attention beat the Pallas flash forward at
-    the bench shape (42.7 vs 62.9 ms, hd64/seq1024, v5e 2026-08-01);
-    the regime is "scores fit comfortably and XLA fuses the whole chain",
-    which holds for hd64 at seq >= 1024 on sequences that are not
-    window-limited.  Windowed shapes keep the Pallas forward: it skips
-    out-of-window blocks entirely, XLA still materializes [S, S].
-
-    Backward: Pallas flash always — the recompute never materializes
-    scores, which is where the memory and time win lives (the same
-    breakdown measured the pallas pair ahead on fwd+bwd). One kernel for
-    dQ, dK and dV (``fused``: each score tile rebuilt once, not twice)
-    where its estimate, with the blocks the shape gives, fits
-    FUSED_VMEM_CAP_BYTES; else the dq + dk/dv pair.
-    """
-    if leg == "fwd":
-        # "fit comfortably": the float32 scores of the whole call, which the
-        # XLA forward writes to HBM. 0.5 GiB at the measured shape (8 x 16
-        # heads x 1024^2); 32 GiB at 4 x 32 heads x 8192^2, which no chip
-        # holds, so long sequences keep the Pallas forward too
-        scores = 4 * sig.batch * sig.heads * sig.seq_q * sig.seq_k
-        if (sig.head_dim <= 64 and sig.seq_k >= 1024 and not sig.windowed
-                and scores <= XLA_FWD_SCORE_BYTES):
-            return IMPL_XLA
-        return IMPL_PALLAS
-    return (IMPL_FUSED if fused_vmem_bytes(sig) <= FUSED_VMEM_CAP_BYTES
-            else IMPL_PALLAS)
-
-
-def resolve_leg(leg: str, sig: ShapeSig, kind: Optional[str] = None, *,
-                explicit_impl: Optional[str] = None,
-                explicit_blocks: Optional[tuple] = None,
-                pallas_only: bool = False) -> Decision:
-    """Resolve one leg ("fwd" | "bwd") to a Decision.  ``pallas_only``
-    (force_pallas=True callers: kernel-math tests) restricts the choice to
-    the Pallas variants — an XLA pick degrades to the per-head kernel."""
-    kind = kind if kind is not None else device_kind()
-    variant = _variant_preference()
-
-    impl = None
-    source = None
-    if explicit_impl is not None:
-        assert explicit_impl in _IMPLS[leg], (leg, explicit_impl)
-        impl, source = explicit_impl, "explicit"
-    if impl is None:
-        env = _env_impl("DS_TPU_ATTN_FWD" if leg == "fwd" else "DS_TPU_ATTN_BWD",
-                        leg)
-        if env is not None:
-            impl, source = env, "env"
-    if impl is None and os.environ.get("DS_TPU_FLASH_FOLDED") not in (None, "", "0"):
-        # legacy env: the folded kernels run end to end (both legs)
-        impl, source = IMPL_FOLDED, "legacy-env"
-
-    measured = None
-    if impl is None:
-        measured = get_cache().lookup(signature(leg, sig, kind))
-        if measured and measured.get("impl") in _IMPLS[leg]:
-            impl, source = measured["impl"], "measured"
-        else:
-            measured = None
-    if impl is None:
-        impl, source = _heuristic_impl(leg, sig), "heuristic"
-        if impl == IMPL_PALLAS and variant == IMPL_FOLDED:
-            impl = IMPL_FOLDED
-
-    if pallas_only and impl == IMPL_XLA:
-        impl = variant or IMPL_PALLAS
-        source += "+pallas-forced"
-
-    # blocks: explicit > env > measured > chosen from the shape
-    blocks = explicit_blocks or _env_blocks()
-    if blocks is None and measured is not None:
-        try:
-            blocks = (int(measured["block_q"]), int(measured["block_k"]))
-        except (KeyError, TypeError, ValueError):
-            blocks = None
-    if blocks is None:
-        blocks = choose_blocks(sig, "fused" if impl == IMPL_FUSED else leg)
-    return Decision(impl=impl, block_q=int(blocks[0]), block_k=int(blocks[1]),
-                    source=source)
-
-
-def resolve(sig: ShapeSig, kind: Optional[str] = None, *,
-            impl_fwd: Optional[str] = None, impl_bwd: Optional[str] = None,
-            blocks: Optional[tuple] = None, pallas_only: bool = False):
-    """(fwd Decision, bwd Decision) for one attention call site."""
-    fwd = resolve_leg("fwd", sig, kind, explicit_impl=impl_fwd,
-                      explicit_blocks=blocks, pallas_only=pallas_only)
-    bwd = resolve_leg("bwd", sig, kind, explicit_impl=impl_bwd,
-                      explicit_blocks=blocks, pallas_only=pallas_only)
-    return fwd, bwd
+def resolve(sig: ShapeSig, *, impl_bwd: Optional[str] = None,
+            blocks: Optional[tuple] = None):
+    """(forward Decision, backward Decision) of one ``flash_attention`` call.
+    The forward is the per-head kernel. The backward is ``impl_bwd`` if
+    given, else the fused kernel where its estimate, with the blocks the
+    shape gives it, fits FUSED_VMEM_CAP_BYTES, else the dq + dk/dv pair
+    (24,576 tokens at group 4). ``blocks`` pins both legs' (block_q,
+    block_k); else each leg's come from ``choose_blocks``."""
+    if impl_bwd is None:
+        impl_bwd = (IMPL_FUSED if fused_vmem_bytes(sig) <= FUSED_VMEM_CAP_BYTES
+                    else IMPL_PALLAS)
+    elif impl_bwd not in (IMPL_PALLAS, IMPL_FUSED):
+        raise ValueError(f"impl_bwd={impl_bwd!r}: the backward is "
+                         f"{IMPL_PALLAS!r} (the dq + dk/dv pair) or "
+                         f"{IMPL_FUSED!r}")
+    legs = ("fwd", "fused" if impl_bwd == IMPL_FUSED else "bwd")
+    return tuple(
+        Decision(impl, *(int(b) for b in blocks or choose_blocks(sig, leg)))
+        for impl, leg in zip((IMPL_PALLAS, impl_bwd), legs))
 
 
 def describe(fwd: Decision, bwd: Decision) -> str:
-    """Compact per-leg note for bench unit tags / artifacts, e.g.
-    ``attn[fwd=xla:heuristic,bwd=pallas@256x512:measured]``."""
-
-    def leg(d: Decision) -> str:
-        blocks = ("" if d.impl == IMPL_XLA
-                  else f"@{d.block_q}x{d.block_k}")
-        return f"{d.impl}{blocks}:{d.source}"
-
-    return f"attn[fwd={leg(fwd)},bwd={leg(bwd)}]"
-
-
-def table_source() -> str:
-    """One line for ds_report: where dispatch decisions come from."""
-    return get_cache().source_description()
+    """Compact per-leg note for reports and artifacts, e.g.
+    ``attn[fwd=pallas@256x512,bwd=fused@512x512]``."""
+    return (f"attn[fwd={fwd.impl}@{fwd.block_q}x{fwd.block_k},"
+            f"bwd={bwd.impl}@{bwd.block_q}x{bwd.block_k}]")
 
 
 def resolved_note(batch=8, seq=1024, heads=16, kv_heads=None, head_dim=64,
-                  dtype="bfloat16", causal=True, window=None,
-                  kind: Optional[str] = None) -> str:
-    """The per-leg dispatch note at a given (default: THE bench) shape —
-    reporting surfaces call this so every banked artifact records which
-    kernels actually ran."""
+                  dtype="bfloat16", causal=True, window=None) -> str:
+    """The per-leg note at a given shape (default: the 0.4B preset's), so a
+    saved report records which kernels a TPU runs there."""
     sig = make_sig((batch, seq, heads, head_dim),
                    kv_heads if kv_heads is not None else heads, seq, dtype,
                    causal, window, None)
-    return describe(*resolve(sig, kind))
+    return describe(*resolve(sig))

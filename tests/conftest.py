@@ -60,16 +60,6 @@ def _reset_fault_injector():
 
 
 @pytest.fixture(autouse=True)
-def _hermetic_attn_cache(tmp_path, monkeypatch):
-    """Every test sees an EMPTY per-test attention dispatch table: a
-    developer's ~/.cache measurements (or a previous test's commits) must
-    never change which kernels a correctness test dispatches to. Tests that
-    exercise the cache explicitly point DS_TPU_ATTN_CACHE_DIR at their own
-    dir on top of this."""
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path / "attn_cache"))
-
-
-@pytest.fixture(autouse=True)
 def _hermetic_journal_dir(tmp_path, monkeypatch):
     """Every test resolves the serving request journal to its own tmp dir:
     a durable-serving test must never replay requests journaled by a

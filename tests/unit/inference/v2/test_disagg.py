@@ -354,7 +354,6 @@ def _run_child(tmp_path, force_host_devices, scenarios, timeout=1200):
     env = force_host_devices(4, extra={
         "PYTHONPATH": REPO,
         "DS_TPU_JOURNAL_DIR": str(tmp_path / "journal"),
-        "DS_TPU_ATTN_CACHE_DIR": str(tmp_path / "attn"),
     })
     out = subprocess.run([sys.executable, str(script)] + list(scenarios),
                          env=env, capture_output=True, text=True,

@@ -6,6 +6,9 @@ numerics check the reference does per-kernel: incremental paged-KV serving
 must match the dense training-model forward.
 """
 
+import functools
+
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -19,10 +22,19 @@ from deepspeed_tpu.inference.v2.ragged import BlockedAllocator
 CFG = LlamaConfig.tiny(dtype=jnp.float32)
 
 
+@functools.lru_cache(maxsize=None)
+def _dense_forward(model):
+    return jax.jit(lambda params, ids: model.apply({"params": params}, ids))
+
+
 def dense_logits(model, params, tokens):
-    """Reference logits from the training model's full forward."""
-    ids = jnp.asarray(tokens, dtype=jnp.int32)[None, :]
-    return np.asarray(model.apply({"params": params}, ids))[0]
+    """Reference logits from the training model's full forward, [len, vocab].
+    The tokens are padded on the right to a multiple of 32 and the forward is
+    one compiled program a length: the model is causal, so the logits at a
+    position do not read the positions after it."""
+    ids = np.zeros((1, -(-len(tokens) // 32) * 32), np.int32)
+    ids[0, :len(tokens)] = tokens
+    return np.asarray(_dense_forward(model)(params, ids))[0, :len(tokens)]
 
 
 @pytest.fixture(scope="module")
@@ -114,13 +126,14 @@ class TestRaggedServing:
         model, params = llama
         prompt = (np.arange(1, 10) * 3) % CFG.vocab_size
         engine.put([1], [prompt])
-        seq = list(prompt)
-        for step in range(20):  # crosses a 16-token block boundary
-            nxt = (7 * step + 1) % CFG.vocab_size
+        fed = [(7 * step + 1) % CFG.vocab_size for step in range(20)]
+        # one dense forward over all that will have been fed: its row i is
+        # the reference after i + 1 tokens
+        ref = dense_logits(model, params, list(prompt) + fed)
+        for step, nxt in enumerate(fed):  # crosses a 16-token block boundary
             logits = np.asarray(engine.put([1], [[nxt]]))
-            seq.append(nxt)
-            ref = dense_logits(model, params, seq)[-1]
-            np.testing.assert_allclose(logits[0], ref, rtol=5e-4, atol=5e-4)
+            np.testing.assert_allclose(logits[0], ref[len(prompt) + step],
+                                       rtol=5e-4, atol=5e-4)
 
     def test_multi_sequence_ragged_batch(self, llama, engine):
         model, params = llama

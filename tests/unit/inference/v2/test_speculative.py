@@ -174,10 +174,15 @@ def test_score_matches_teacher_forced_apply():
     got = eng.score([0, 1], toks, flush=False)
 
     import jax
+    # both in one compiled forward, the shorter padded on the right: a
+    # causal model's logits at a position read nothing after it
+    ids = np.zeros((len(toks), max(map(len, toks))), np.int32)
     for i, t in enumerate(toks):
-        ids = jnp.asarray([t], jnp.int32)
-        logits = np.asarray(model.apply({"params": params}, ids),
-                            np.float64)[0]  # [T, V]
+        ids[i, :len(t)] = t
+    dense = np.asarray(jax.jit(lambda p, x: model.apply({"params": p}, x))(
+        params, ids), np.float64)
+    for i, t in enumerate(toks):
+        logits = dense[i, :len(t)]  # [T, V]
         rows = logits[:-1]
         logz = np.log(np.exp(rows - rows.max(-1, keepdims=True))
                       .sum(-1)) + rows.max(-1)

@@ -218,8 +218,7 @@ def test_sigkill_mid_decode_stream_resumes_bit_identical(tmp_path):
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))))))
     env = {**os.environ,
            "JAX_PLATFORMS": "cpu",
-           "DS_TPU_JOURNAL_DIR": str(tmp_path / "journal"),
-           "DS_TPU_ATTN_CACHE_DIR": str(tmp_path / "attn")}
+           "DS_TPU_JOURNAL_DIR": str(tmp_path / "journal")}
     # enough decode budget that the kill reliably lands MID-decode (the
     # scheduler decodes independently of how fast the client reads)
     n_tok = 256

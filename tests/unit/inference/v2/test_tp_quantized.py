@@ -310,7 +310,6 @@ def test_ds_serve_tp_quantized_e2e(tmp_path, force_host_devices):
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))))))
     env = force_host_devices(8, extra={
         "PYTHONPATH": repo,
-        "DS_TPU_ATTN_CACHE_DIR": str(tmp_path / "attn"),
         "DS_TPU_JOURNAL_DIR": str(tmp_path / "journal"),
     })
 

@@ -137,13 +137,23 @@ def test_weight_map_round_trip_from_hf_named_tensors():
         np.testing.assert_array_equal(np.asarray(leaf), flat_back[path])
 
 
+@pytest.fixture(scope="module")
+def sound():
+    """``small()`` as the cases below all build it (one seed, so one set of
+    parameters and one batch) and the sound reference's step on it, made
+    once: (cfg, model, params, batch, positions, the reference's parts)."""
+    cfg, model, params, batch = small()
+    at = every_position(batch)
+    return cfg, model, params, batch, at, reference.step_parts(
+        params, batch, SMALL, at)
+
+
 @pytest.mark.parametrize("attn_impl", ["xla", "flash"])
-def test_float32_program_matches_the_reference_for_its_share(attn_impl):
+def test_float32_program_matches_the_reference_for_its_share(attn_impl, sound):
     """Loss, every noisy position's logits and the routing, the attention by
     XLA under the mask and by the interpreted kernels."""
-    cfg, model, params, batch = small(attn_impl=attn_impl)
-    at = every_position(batch)
-    want = reference.step_parts(params, batch, SMALL, at, gradients=False)
+    cfg, _, params, batch, at, want = sound
+    model = LlamaForCausalLM(dataclasses.replace(cfg, attn_impl=attn_impl))
     with jax.default_matmul_precision("highest"):
         loss, stats = program(model, params, batch)
         logits, _ = program(model, params, batch, with_loss=False)
@@ -157,9 +167,8 @@ def test_float32_program_matches_the_reference_for_its_share(attn_impl):
     assert int(np.asarray(stats["expert_counts"]).sum()) == 2 * 2 * SEQ * 4 * 2
 
 
-def test_gradients_match_jax_grad_of_the_reference():
-    cfg, model, params, batch = small()
-    want = reference.step_parts(params, batch, SMALL, every_position(batch))
+def test_gradients_match_jax_grad_of_the_reference(sound):
+    cfg, model, params, batch, _, want = sound
     with jax.default_matmul_precision("highest"):
         got = jax.grad(lambda p: program(model, p, batch)[0])(params)
     compared = 0
@@ -178,16 +187,14 @@ def test_gradients_match_jax_grad_of_the_reference():
 
 
 @pytest.mark.parametrize("wrong", reference.WRONG)
-def test_a_wrong_reference_fails_the_comparison(wrong):
+def test_a_wrong_reference_fails_the_comparison(wrong, sound):
     """Each wrong model the cell's ``correct`` has to tell: its loss or its
     gradients lie far from the program's."""
-    cfg, model, params, batch = small()
-    at = every_position(batch)
-    sound = reference.step_parts(params, batch, SMALL, at)
+    _, _, params, batch, at, right = sound
     bad = reference.step_parts(params, batch, SMALL, at, wrong={wrong})
-    loss_off = abs(bad["ce"] - sound["ce"]) / sound["ce"]
+    loss_off = abs(bad["ce"] - right["ce"]) / right["ce"]
     grad_off = max(np.linalg.norm(b - s) / np.linalg.norm(s) for b, s in zip(
-        jax.tree_util.tree_leaves(bad["grads"]), jax.tree_util.tree_leaves(sound["grads"]))
+        jax.tree_util.tree_leaves(bad["grads"]), jax.tree_util.tree_leaves(right["grads"]))
         if np.any(s))
     assert max(loss_off, grad_off) > 5e-2, (wrong, loss_off, grad_off)
     if wrong == "leak":
@@ -196,19 +203,17 @@ def test_a_wrong_reference_fails_the_comparison(wrong):
         assert loss_off > 1e-4
 
 
-def test_the_configurations_own_precision_is_not_a_wrong_reference():
+def test_the_configurations_own_precision_is_not_a_wrong_reference(sound):
     """A reference at bf16 operands is the program's arithmetic: it moves
     the gradients by what rounding does and no more, where fp8 moves them
     ten times as far."""
-    cfg, model, params, batch = small()
-    at = every_position(batch)
-    sound = reference.step_parts(params, batch, SMALL, at)
+    _, _, params, batch, at, right = sound
 
     def off(wrong):
         bad = reference.step_parts(params, batch, SMALL, at, wrong={wrong})
         return max(np.linalg.norm(b - s) / np.linalg.norm(s) for b, s in zip(
             jax.tree_util.tree_leaves(bad["grads"]),
-            jax.tree_util.tree_leaves(sound["grads"])) if np.any(s))
+            jax.tree_util.tree_leaves(right["grads"])) if np.any(s))
 
     assert reference.OWN_PRECISION == "bf16" and "bf16" not in reference.WRONG
     assert off("bf16") < 0.3 * off("fp8")
@@ -262,8 +267,8 @@ def test_bf16_compute_stays_near_the_reference():
     assert np.median(err) < 2e-2
 
 
-def test_the_objective_refuses_what_its_mask_cannot_carry():
-    cfg, model, params, batch = small()
+def test_the_objective_refuses_what_its_mask_cannot_carry(sound):
+    cfg, model, params, batch = sound[:4]
     args, kw = batch.model_args()
     with pytest.raises(ValueError, match="loss_weights"):
         model.apply({"params": params}, *args, positions=kw["positions"])

@@ -158,13 +158,13 @@ def test_dead_tile_counts(seq, bq, bk):
 def test_blocks_at_the_cell_shape_and_the_backward_cap():
     """Head 128 in groups of 8 at 2 x 8,192 positions a sequence: 2,048
     folded rows (both copies x 8 heads x 128 queries) by 512 keys on both
-    legs; the pattern is in the signature; a sequence whose clean keys' dK
+    legs; the pattern is a field of the signature; a sequence whose clean keys' dK
     and dV pass the cap is refused, there being no two-pass pair."""
     sig = kd.make_sig((2, 16384, 32, 128), 4, 16384, "bfloat16", False, None, None,
                       pattern="bd4")
-    assert sig.pattern == "bd4" and "|pbd4" in kd.signature("fwd", sig, "TPU v5 lite")
+    assert sig.pattern == "bd4"
     plain = kd.make_sig((2, 16384, 32, 128), 4, 16384, "bfloat16", True, None, None)
-    assert plain.pattern == "" and "|p" not in kd.signature("fwd", plain, "TPU v5 lite")
+    assert plain.pattern == "" and plain != sig._replace(causal=True)
     for leg in ("fwd", "bwd"):
         assert kd.choose_block_diffusion_blocks(sig, leg, 4) == (128, 512)
     assert kd.bdattn_vmem_bytes("bwd", 8, 128, 2, 128, 512, 8192) < kd.FUSED_VMEM_CAP_BYTES

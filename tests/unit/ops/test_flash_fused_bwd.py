@@ -75,7 +75,7 @@ def _grads(name, dtype, impl_bwd):
         def fn(q, k, v):
             return flash_attention(
                 q, k, v, causal=case["causal"], window=case["window"],
-                softcap=case["softcap"], interpret=True, impl_fwd="pallas",
+                softcap=case["softcap"], interpret=True,
                 impl_bwd=impl_bwd, block_q=bq, block_k=bk)
     pulled = jax.jit(lambda q, k, v, g: jax.vjp(fn, q, k, v)[1](g))(q, k, v, g)
     return [np.asarray(x, np.float32) for x in pulled]
@@ -110,7 +110,7 @@ def test_the_unpinned_backward_is_the_fused_kernel():
     backward at a shape that fits, and its gradient is the pinned one's."""
     case = CASES["g4_d64"]
     sig = kd.make_sig((1, 512, 4, 64), 1, 512, "float32", True, None, None)
-    assert kd.resolve_leg("bwd", sig, "interpret").impl == kd.IMPL_FUSED
+    assert kd.resolve(sig)[1].impl == kd.IMPL_FUSED
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.normal(size=(1, 512, 4, 64)), jnp.float32)
     k, v = (jnp.asarray(rng.normal(size=(1, 512, 1, 64)), jnp.float32)

@@ -61,51 +61,71 @@ CASES += [
     _case(512, 2, 2, 64, causal=False, window=64, blocks=(128, 128)),
 ]
 
-# the backward's implementation is drawn too: the dq + dk/dv pair or the
-# fused kernel, on the per-head forward's residuals (its own seed, so the
-# shapes above stay the ones they were)
-_bwd_rng = np.random.default_rng(34)
-for _c in CASES:
-    _c["bwd"] = str(_bwd_rng.choice(["pallas", "fused"]))
+# every shape under both backwards, the dq + dk/dv pair and the fused
+# kernel, on the per-head forward's residuals: the pair is the only backward
+# of a long sequence (past the fused kernel's VMEM cap) and no benchmark cell
+# runs it, so this sweep is its guard
+BWDS = ("pallas", "fused")
 
 
-@pytest.mark.parametrize("case", CASES, ids=lambda c: (
-    f"b{c['b']}s{c['s']}h{c['h']}kv{c['kv']}d{c['d']}"
-    f"w{c['window']}c{c['softcap']}"
-    + (f"k{c['sk']}" if c.get("sk") else "")
-    + ("" if c.get("causal", True) else "full")
-    + ("x".join(map(str, ("", ) + c["blocks"])) if c.get("blocks") else "")
-    + c["bwd"]))
-def test_flash_matches_oracle(case):
+def _inputs(case):
     rng = np.random.default_rng(7)
-    causal, sk = case.get("causal", True), case.get("sk") or case["s"]
+    sk = case.get("sk") or case["s"]
+    return tuple(
+        jnp.asarray(rng.normal(size=(case["b"], n, heads, case["d"])),
+                    jnp.float32)
+        for n, heads in ((case["s"], case["h"]), (sk, case["kv"]),
+                         (sk, case["kv"])))
+
+
+_REFERENCE = {}
+
+
+def _reference(i):
+    """The oracle's loss, output and gradients at case ``i``, computed once
+    for both backwards (one compiled program: op by op, every small op of
+    the reference's backward is a compile of its own)."""
+    if i not in _REFERENCE:
+        case = CASES[i]
+        scale = 1.0 / np.sqrt(case["d"])
+
+        def loss_ref(q, k, v):
+            out = _xla_attention(q, k, v, scale, case.get("causal", True),
+                                 case["window"], case["softcap"])
+            return (out.astype(jnp.float32) ** 2).mean(), out
+
+        _REFERENCE[i] = jax.jit(jax.value_and_grad(
+            loss_ref, argnums=(0, 1, 2), has_aux=True))(*_inputs(case))
+    return _REFERENCE[i]
+
+
+def _id(i, bwd):
+    c = CASES[i]
+    return (f"b{c['b']}s{c['s']}h{c['h']}kv{c['kv']}d{c['d']}"
+            f"w{c['window']}c{c['softcap']}"
+            + (f"k{c['sk']}" if c.get("sk") else "")
+            + ("" if c.get("causal", True) else "full")
+            + ("x".join(map(str, ("", ) + c["blocks"])) if c.get("blocks") else "")
+            + bwd)
+
+
+@pytest.mark.parametrize("i,bwd", [
+    pytest.param(i, bwd, id=_id(i, bwd))
+    for i in range(len(CASES)) for bwd in BWDS])
+def test_flash_matches_oracle(i, bwd):
+    case = CASES[i]
     bq, bk = case.get("blocks") or (None, None)
-    q = jnp.asarray(rng.normal(size=(case["b"], case["s"], case["h"], case["d"])),
-                    jnp.float32)
-    k = jnp.asarray(rng.normal(size=(case["b"], sk, case["kv"], case["d"])),
-                    jnp.float32)
-    v = jnp.asarray(rng.normal(size=(case["b"], sk, case["kv"], case["d"])),
-                    jnp.float32)
-    scale = 1.0 / np.sqrt(case["d"])
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, window=case["window"],
-                              softcap=case["softcap"], interpret=True,
-                              impl_fwd="pallas", impl_bwd=case["bwd"],
+        out = flash_attention(q, k, v, causal=case.get("causal", True),
+                              window=case["window"], softcap=case["softcap"],
+                              interpret=True, impl_bwd=bwd,
                               block_q=bq, block_k=bk)
         return (out.astype(jnp.float32) ** 2).mean(), out
 
-    def loss_ref(q, k, v):
-        out = _xla_attention(q, k, v, scale, causal, case["window"],
-                             case["softcap"])
-        return (out.astype(jnp.float32) ** 2).mean(), out
-
-    # each side one compiled program: op by op, every small op of the
-    # regrouping and of the reference's backward is a compile of its own
     (l1, o1), g1 = jax.jit(jax.value_and_grad(loss_flash, argnums=(0, 1, 2),
-                                              has_aux=True))(q, k, v)
-    (l2, o2), g2 = jax.jit(jax.value_and_grad(loss_ref, argnums=(0, 1, 2),
-                                              has_aux=True))(q, k, v)
+                                              has_aux=True))(*_inputs(case))
+    (l2, o2), g2 = _reference(i)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)

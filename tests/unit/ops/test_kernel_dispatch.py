@@ -1,11 +1,10 @@
-"""Shape-aware attention dispatch: correctness of every (fwd, bwd) route
-combination vs the XLA oracle, decision precedence (explicit > env > legacy
-env > measured cache > heuristic), the persistent autotune cache's
-durability contract, and the offline sweep tool end-to-end on CPU.
+"""Attention dispatch: the per-head forward and both backwards (the dq +
+dk/dv pair, the fused kernel) against the XLA oracle, what ``resolve`` makes
+of a shape (the fused kernel where its dQ accumulator fits, blocks from
+``choose_blocks``), what a caller may pin, the masked call the kernels
+refuse, and the sweep tool end-to-end on CPU.
 
-All kernel execution is Pallas interpret mode (CPU); the conftest
-``_hermetic_attn_cache`` fixture points ``DS_TPU_ATTN_CACHE_DIR`` at a
-per-test temp dir, so nothing here ever sees a developer's measured table.
+All kernel execution is Pallas interpret mode (CPU).
 """
 
 import importlib.util
@@ -19,18 +18,15 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops import kernel_dispatch as kd
 from deepspeed_tpu.ops.attention import flash_attention, _xla_attention
-from deepspeed_tpu.ops.autotune_cache import (AutotuneCache, CACHE_VERSION,
-                                              cache_path, get_cache)
 
-IMPLS = (kd.IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FOLDED)
-BWD_IMPLS = IMPLS + (kd.IMPL_FUSED, )   # the backward's one-pass kernel too
+BWD_IMPLS = (kd.IMPL_PALLAS, kd.IMPL_FUSED)   # the pair, the one-pass kernel
 
 
-def _qkv(b=2, s=128, h=4, kv=2, d=32, seed=7, dtype=jnp.float32):
+def _qkv(b=2, s=128, h=4, kv=2, d=32, seed=7, dtype=jnp.float32, sk=None):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, s, h, d)), dtype)
-    k = jnp.asarray(rng.normal(size=(b, s, kv, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(b, s, kv, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, sk or s, kv, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, sk or s, kv, d)), dtype)
     return q, k, v
 
 
@@ -46,16 +42,14 @@ def _ref(q, k, v, causal=True, window=None, softcap=None):
     return o, g
 
 
-def _route(q, k, v, fwd, bwd, causal=True, window=None, softcap=None):
-    # 64x64 blocks pin every Pallas leg to a multi-block grid even at the
-    # small parity shapes, so the online-softmax accumulation across
-    # k-blocks stays covered without paying interpret-mode cost for big
-    # sequences
+def _route(q, k, v, bwd, causal=True, window=None, softcap=None):
+    # 64x64 blocks pin both legs to a multi-block grid even at the small
+    # parity shapes, so the online-softmax accumulation across k-blocks
+    # stays covered without paying interpret-mode cost for big sequences
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=causal, window=window,
                               softcap=softcap, interpret=True,
-                              block_q=64, block_k=64,
-                              impl_fwd=fwd, impl_bwd=bwd)
+                              block_q=64, block_k=64, impl_bwd=bwd)
         return (out.astype(jnp.float32) ** 2).mean(), out
 
     (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
@@ -63,70 +57,53 @@ def _route(q, k, v, fwd, bwd, causal=True, window=None, softcap=None):
     return o, g
 
 
+def _assert_parity(got, want, atol_o=2e-5, atol_g=5e-5):
+    (o, g), (o_ref, g_ref) = got, want
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref, np.float32),
+                               atol=atol_o, rtol=atol_o)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=atol_g, rtol=atol_g)
+
+
 # ---------------------------------------------------------------------------
-# route parity: every fwd x bwd combination vs the XLA oracle
+# route parity: the forward with each backward vs the XLA oracle
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fwd", IMPLS)
 @pytest.mark.parametrize("bwd", BWD_IMPLS)
-def test_route_parity_causal(fwd, bwd):
-    """The custom_vjp must produce oracle values AND oracle grads for all 12
-    per-leg combinations — mixed routes cross LSE layouts (natural vs
-    per-head) and residual provenance (XLA-computed lse consumed by a
-    Pallas bwd), which is exactly where a wiring bug would hide."""
+def test_route_parity_causal(bwd):
+    """The custom_vjp must produce oracle values AND oracle grads under both
+    backwards: each reads the forward's residuals (o, and the log-sum-exp
+    without its unit dimension) in its own block order."""
     q, k, v = _qkv()
-    o_ref, g_ref = _ref(q, k, v)
-    o, g = _route(q, k, v, fwd, bwd)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                               atol=2e-5, rtol=2e-5)
-    for got, ref in zip(g, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=5e-5, rtol=5e-5)
+    _assert_parity(_route(q, k, v, bwd), _ref(q, k, v))
 
 
-@pytest.mark.parametrize("fwd,bwd", [("xla", "pallas"), ("pallas", "xla"),
-                                     ("folded", "pallas"), ("xla", "fused"),
-                                     ("folded", "fused")])
+@pytest.mark.parametrize("bwd", BWD_IMPLS)
 @pytest.mark.parametrize("window,softcap", [(64, None), (None, 20.0),
                                             (64, 20.0)])
-def test_route_parity_window_softcap(fwd, bwd, window, softcap):
-    """Mask variants through the mixed routes: sliding window and Gemma-2
-    softcap change both the forward math and the lse the bwd consumes."""
+def test_route_parity_window_softcap(bwd, window, softcap):
+    """Mask variants: sliding window and Gemma-2 softcap change both the
+    forward math and the lse the bwd consumes."""
     q, k, v = _qkv(s=128, d=32)
-    o_ref, g_ref = _ref(q, k, v, window=window, softcap=softcap)
-    o, g = _route(q, k, v, fwd, bwd, window=window, softcap=softcap)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                               atol=2e-5, rtol=2e-5)
-    for got, ref in zip(g, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=5e-5, rtol=5e-5)
+    _assert_parity(_route(q, k, v, bwd, window=window, softcap=softcap),
+                   _ref(q, k, v, window=window, softcap=softcap))
 
 
 def test_route_parity_gqa_mixed():
-    """GQA head grouping survives the per-head<->natural lse conversion in
-    the xla-fwd + pallas-bwd route (the conversion reshapes over [KV, G])."""
+    """GQA head grouping survives the regrouping of the residuals: the pair
+    reads the lse a query block at a time ([B*KV, G, Sq, 1]) in one kernel
+    and as rows of the transposed tile in the other."""
     q, k, v = _qkv(h=8, kv=2, d=32, s=128)
-    o_ref, g_ref = _ref(q, k, v)
-    o, g = _route(q, k, v, "xla", "pallas")
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                               atol=2e-5, rtol=2e-5)
-    for got, ref in zip(g, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=5e-5, rtol=5e-5)
+    _assert_parity(_route(q, k, v, "pallas"), _ref(q, k, v))
 
 
 def test_bfloat16_route_parity():
     q, k, v = _qkv(dtype=jnp.bfloat16)
-    o_ref, g_ref = _ref(q, k, v)
-    o, g = _route(q, k, v, "xla", "pallas")
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(o_ref, np.float32),
-                               atol=3e-2, rtol=3e-2)
-    for got, ref in zip(g, g_ref):
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(ref, np.float32),
-                                   atol=3e-2, rtol=3e-2)
+    _assert_parity(_route(q, k, v, "pallas"), _ref(q, k, v), 3e-2, 3e-2)
 
 
 @pytest.mark.parametrize("impl_bwd", ["pallas", "fused"])
@@ -145,8 +122,7 @@ def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd,
     2.0e-3 to 3.1e-3 at every block pair here, (256, 512) included."""
     q, k, v = _qkv(b=1, s=s, h=h, kv=kv, d=128, dtype=jnp.bfloat16)
     f_dec, b_dec = kd.resolve(kd.make_sig(q.shape, kv, s, q.dtype, True,
-                                          None, None), "interpret",
-                              impl_bwd=impl_bwd)
+                                          None, None), impl_bwd=impl_bwd)
     assert (f_dec.block_q, f_dec.block_k) == fwd
     # the fused backward's own: (512, 512) at these groups
     assert (b_dec.block_q, b_dec.block_k) == (
@@ -160,7 +136,7 @@ def test_shape_chosen_blocks_match_the_dense_reference(s, h, kv, fwd, bwd,
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, interpret=True,
-                              impl_fwd="pallas", impl_bwd=impl_bwd)
+                              impl_bwd=impl_bwd)
         return (out.astype(jnp.float32) * w).sum(), out
 
     (_, o_ref), g_ref = jax.value_and_grad(ref_loss, (0, 1, 2), has_aux=True)(
@@ -188,15 +164,16 @@ def _bench_sig(**over):
                        base["softcap"])
 
 
-def test_bench_shape_routes_xla_fwd_pallas_bwd():
-    """THE acceptance table entry: at hd64/seq1024 the heuristic must pick
-    the XLA fused forward (measured 42.7 ms < 62.9 ms Pallas) and keep the
-    Pallas flash backward."""
-    fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
-    assert fwd.impl == kd.IMPL_XLA and fwd.source == "heuristic"
-    assert bwd.impl == kd.IMPL_FUSED and bwd.source == "heuristic"
+def test_bench_shape_routes_per_head_fwd_fused_bwd():
+    """The 0.4B preset's shape (head 64, 1,024 keys, group 1), which a rule
+    on the head size once sent to an XLA forward: the per-head forward at
+    the shape's blocks and the fused backward at (512, 512). On the chip
+    the XLA forward lost there as everywhere (PERF.md, PR 44)."""
+    fwd, bwd = kd.resolve(_bench_sig())
+    assert fwd == kd.Decision(kd.IMPL_PALLAS, 1024, 1024)
+    assert (fwd.block_q, fwd.block_k) == kd.choose_blocks(_bench_sig(), "fwd")
+    assert bwd == kd.Decision(kd.IMPL_FUSED, 512, 512)   # the fused kernel's
     assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(_bench_sig(), "fused")
-    assert (bwd.block_q, bwd.block_k) == (512, 512)   # the fused kernel's
 
 
 def _sig(seq, heads, kv, d, dtype="bfloat16", seq_k=None, window=None,
@@ -262,17 +239,14 @@ def test_blocks_follow_from_the_shape(name, sig, fwd, bwd):
     if name == "cell":
         bq, bk = kd.choose_blocks(sig, "bwd")
         assert bk >= 512 and 512 <= group * bq <= 1024
-    # and it is what a Pallas leg resolves to: unpinned forward and back,
-    # but that the backward of a shape that fits is the fused kernel, with
+    # and it is what the legs resolve to: unpinned forward and back, but
+    # that the backward of a shape that fits is the fused kernel, with
     # blocks of its own (below); the pair's are these
-    fwd_dec, dec = kd.resolve(sig, "TPU v5e")
-    if fwd_dec.impl == kd.IMPL_PALLAS:
-        assert (fwd_dec.block_q, fwd_dec.block_k) == fwd
-    assert dec.source == "heuristic"
+    fwd_dec, dec = kd.resolve(sig)
+    assert fwd_dec == kd.Decision(kd.IMPL_PALLAS, *fwd)
     if dec.impl == kd.IMPL_PALLAS:
         assert (dec.block_q, dec.block_k) == bwd
-    pair = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="pallas")
-    assert (pair.block_q, pair.block_k) == bwd
+    assert kd.resolve(sig, impl_bwd="pallas")[1] == kd.Decision("pallas", *bwd)
 
 
 @pytest.mark.parametrize("name,sig,impl", [
@@ -303,8 +277,8 @@ def test_the_backward_is_fused_where_its_accumulator_fits(name, sig, impl):
     estimate, whole-sequence dQ accumulator included, is within the cap;
     past it the dq + dk/dv pair. What a fused call asks of the chip stays
     well inside the core's 128 MiB."""
-    dec = kd.resolve_leg("bwd", sig, "TPU v5e")
-    assert dec.impl == impl and dec.source == "heuristic", name
+    fwd, dec = kd.resolve(sig)
+    assert dec.impl == impl, name
     est = kd.fused_vmem_bytes(sig)
     assert (est <= kd.FUSED_VMEM_CAP_BYTES) == (impl == kd.IMPL_FUSED), est
     # the accumulator, at 128 lanes a row at least, is inside the estimate
@@ -313,7 +287,7 @@ def test_the_backward_is_fused_where_its_accumulator_fits(name, sig, impl):
     if impl == kd.IMPL_FUSED:
         assert (kd.vmem_limit_bytes(est) or 0) <= 80 * 2**20
     # the forward never resolves to it; each backward has its own blocks
-    assert kd.resolve_leg("fwd", sig, "TPU v5e").impl != kd.IMPL_FUSED
+    assert fwd.impl == kd.IMPL_PALLAS
     assert (dec.block_q, dec.block_k) == kd.choose_blocks(
         sig, "fused" if impl == kd.IMPL_FUSED else "bwd")
 
@@ -341,41 +315,60 @@ def test_the_fused_backwards_blocks_follow_from_the_shape(name, sig, blocks):
     halved past 2,048 folded rows."""
     assert kd.choose_blocks(sig, "fused") == blocks, name
     assert sig.seq_q % blocks[0] == 0 and sig.seq_k % blocks[1] == 0
-    dec = kd.resolve_leg("bwd", sig, "TPU v5e")
-    assert (dec.impl, dec.block_q, dec.block_k) == (kd.IMPL_FUSED, ) + blocks
+    assert kd.resolve(sig)[1] == kd.Decision(kd.IMPL_FUSED, *blocks)
     # the pair, pinned, keeps the blocks it had
-    pair = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="pallas")
+    pair = kd.resolve(sig, impl_bwd="pallas")[1]
     assert (pair.block_q, pair.block_k) == kd.choose_blocks(sig, "bwd")
 
 
-def test_fused_is_a_backward_implementation_only(monkeypatch, tmp_path):
-    """The ladder pins either backward through what exists: ``impl_bwd=``,
-    ``DS_TPU_ATTN_BWD`` and a measured entry take "fused" and "pallas" (the
-    pair); the forward's names do not include it."""
-    sig = _sig(32768, 32, 8, 64)        # heuristic: the pair
-    dec = kd.resolve_leg("bwd", sig, "TPU v5e", explicit_impl="fused")
-    assert (dec.impl, dec.source) == ("fused", "explicit")
-    with pytest.raises(AssertionError):
-        kd.resolve_leg("fwd", sig, "TPU v5e", explicit_impl="fused")
-    monkeypatch.setenv("DS_TPU_ATTN_BWD", "fused")
-    monkeypatch.setenv("DS_TPU_ATTN_FWD", "fused")      # ignored, with a warning
-    fwd, bwd = kd.resolve(sig, "TPU v5e")
-    assert (bwd.impl, bwd.source) == ("fused", "env")
-    assert (fwd.impl, fwd.source) == ("pallas", "heuristic")
-    monkeypatch.setenv("DS_TPU_ATTN_BWD", "pallas")
-    small = _sig(4096, 32, 8, 128)      # heuristic: fused
-    assert kd.resolve_leg("bwd", small, "TPU v5e").impl == kd.IMPL_PALLAS
-    monkeypatch.delenv("DS_TPU_ATTN_BWD")
-    monkeypatch.delenv("DS_TPU_ATTN_FWD")
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    for leg in ("fwd", "bwd"):
-        get_cache().commit(kd.signature(leg, sig, "TPU v5e"),
-                           {"impl": "fused", "block_q": 128, "block_k": 512})
-    fwd, bwd = kd.resolve(sig, "TPU v5e")
-    assert (bwd.impl, bwd.block_q, bwd.source) == ("fused", 128, "measured")
-    assert (fwd.impl, fwd.source) == ("pallas", "heuristic")
-    assert kd.describe(fwd, bwd) == (
-        "attn[fwd=pallas@256x512:heuristic,bwd=fused@128x512:measured]")
+def _sweep_rows():
+    """The chip sweep that took the other routes out (PERF.md §6, PR 44): a
+    row a point, its timings in ms by candidate."""
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "docs",
+                        "readings", "attn_sweep_pr44.jsonl")
+    with open(path) as f:
+        rows = [json.loads(line) for line in f]
+    return [r for r in rows if r["kind"] == "main"]
+
+
+@pytest.mark.parametrize("row", _sweep_rows(),
+                         ids=lambda r: "b{}s{}h{}kv{}d{}".format(*r["point"]))
+def test_the_sweep_timed_the_kernels_and_blocks_a_shape_resolves_to(row):
+    """The record the deletion rests on is of THESE choices: at each of its
+    nineteen points (causal bf16) ``resolve`` gives the per-head forward and
+    the fused backward at the very blocks the sweep timed, the pair's blocks
+    are the ones it timed beside them, and that route's forward + backward
+    is the fastest the sweep read there. A change to ``choose_blocks`` or to
+    the fused cap that leaves the table behind fails here."""
+    b, s, h, kv, d = row["point"]
+    sig = kd.make_sig((b, s, h, d), kv, s, "bfloat16", True, None, None)
+    fwd, bwd = kd.resolve(sig)
+    pair = kd.resolve(sig, impl_bwd=kd.IMPL_PALLAS)[1]
+    assert bwd.impl == kd.IMPL_FUSED
+    for leg, dec in (("fwd", fwd), ("bwd", bwd), ("bwd", pair)):
+        assert f"{leg}:{dec.impl}@{dec.block_q}x{dec.block_k}" in row["legs"]
+    steps = {route: sorted(runs)[len(runs) // 2]
+             for route, runs in row["steps"].items() if isinstance(runs, list)}
+    assert min(steps, key=steps.get) == "pallas+fused", steps
+
+
+def test_fused_is_a_backward_implementation_only():
+    """``impl_bwd=`` pins either backward against the shape, "fused" and
+    "pallas" (the pair), and nothing else; the forward has one kernel and no
+    argument to pin."""
+    sig = _sig(32768, 32, 8, 64)        # from the shape: the pair
+    fwd, bwd = kd.resolve(sig, impl_bwd="fused")
+    assert (fwd.impl, bwd.impl) == ("pallas", "fused")
+    assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(sig, "fused")
+    small = _sig(4096, 32, 8, 128)      # from the shape: fused
+    assert kd.resolve(small, impl_bwd="pallas")[1].impl == kd.IMPL_PALLAS
+    for unknown in ("xla", "folded", "fwd"):
+        with pytest.raises(ValueError, match="impl_bwd"):
+            kd.resolve(small, impl_bwd=unknown)
+    q, k, v = _qkv(s=64)
+    with pytest.raises(TypeError):
+        flash_attention(q, k, v, causal=True, interpret=True, impl_fwd="xla")
+    assert kd.describe(fwd, bwd) == "attn[fwd=pallas@256x512,bwd=fused@512x512]"
 
 
 def test_the_fused_estimate_grows_with_the_queries():
@@ -400,159 +393,90 @@ def test_blocks_past_the_default_vmem_ask_for_their_own_limit():
 
 
 def test_heuristic_boundaries():
-    # short sequences keep the Pallas forward
-    fwd, _ = kd.resolve(_bench_sig(q_shape=(8, 512, 16, 64), seq_k=512))
-    assert fwd.impl == kd.IMPL_PALLAS
-    # big heads keep the Pallas forward
-    fwd, _ = kd.resolve(_bench_sig(q_shape=(8, 1024, 8, 128)))
-    assert fwd.impl == kd.IMPL_PALLAS
-    # windowed shapes keep the Pallas forward (it skips out-of-window
-    # blocks; XLA still materializes [S, S])
-    fwd, _ = kd.resolve(_bench_sig(window=256))
-    assert fwd.impl == kd.IMPL_PALLAS
+    """The one rule left: the fused backward up to FUSED_VMEM_CAP_BYTES of
+    its estimate, the pair past it. At group 4 and head 128 the float32 dQ
+    is 2 KiB a token: 20,480 tokens are inside, 24,576 outside; head size,
+    sequence length of the keys, window and softcap do not move the
+    forward, which is one kernel."""
+    inside, outside = _sig(20480, 32, 8, 128), _sig(24576, 32, 8, 128)
+    assert kd.fused_vmem_bytes(inside) <= kd.FUSED_VMEM_CAP_BYTES
+    assert kd.fused_vmem_bytes(outside) > kd.FUSED_VMEM_CAP_BYTES
+    assert kd.resolve(inside)[1].impl == kd.IMPL_FUSED
+    assert kd.resolve(outside)[1].impl == kd.IMPL_PALLAS
+    for sig in (_bench_sig(q_shape=(8, 512, 16, 64), seq_k=512),
+                _bench_sig(q_shape=(8, 1024, 8, 128)), _bench_sig(window=256),
+                _bench_sig(), inside, outside):
+        assert kd.resolve(sig)[0].impl == kd.IMPL_PALLAS
 
 
-def test_measured_entry_beats_heuristic(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    sig = _bench_sig()
-    # heuristic first (cache empty)
-    fwd, _ = kd.resolve(sig, "TPU v5e")
-    assert fwd.source == "heuristic"
-    get_cache().commit(kd.signature("fwd", sig, "TPU v5e"),
-                       {"impl": "folded", "block_q": 512, "block_k": 1024,
-                        "ms": 33.3})
-    fwd, bwd = kd.resolve(sig, "TPU v5e")
-    assert (fwd.impl, fwd.source) == ("folded", "measured")
-    assert (fwd.block_q, fwd.block_k) == (512, 1024)
-    # the OTHER leg has no measurement: stays heuristic
-    assert bwd.source == "heuristic"
-    # a different device kind does not see this measurement
-    fwd_cpu, _ = kd.resolve(sig, "TPU v4")
-    assert fwd_cpu.source == "heuristic"
-
-
-def test_env_overrides_beat_measured(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    sig = _bench_sig()
-    get_cache().commit(kd.signature("fwd", sig, "x"),
-                       {"impl": "folded", "block_q": 256, "block_k": 256})
-    monkeypatch.setenv("DS_TPU_ATTN_FWD", "pallas")
-    monkeypatch.setenv("DS_TPU_ATTN_BWD", "xla")
-    fwd, bwd = kd.resolve(sig, "x")
-    assert (fwd.impl, fwd.source) == ("pallas", "env")
-    assert (bwd.impl, bwd.source) == ("xla", "env")
-    # explicit kwargs beat even the env
-    fwd, bwd = kd.resolve(sig, "x", impl_fwd="xla", impl_bwd="folded")
-    assert (fwd.impl, fwd.source) == ("xla", "explicit")
-    assert (bwd.impl, bwd.source) == ("folded", "explicit")
-
-
-def test_legacy_folded_env_forces_both_legs(monkeypatch):
-    monkeypatch.setenv("DS_TPU_FLASH_FOLDED", "1")
-    fwd, bwd = kd.resolve(_bench_sig())
-    assert fwd.impl == bwd.impl == kd.IMPL_FOLDED
-    assert fwd.source == bwd.source == "legacy-env"
-    # "0" only pins the per-head VARIANT; the fwd=XLA heuristic still wins
-    monkeypatch.setenv("DS_TPU_FLASH_FOLDED", "0")
-    fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
-    assert fwd.impl == kd.IMPL_XLA
-    assert bwd.impl == kd.IMPL_FUSED    # a per-head kernel too
-
-
-def test_pallas_only_restriction(monkeypatch):
-    """force_pallas=True callers (kernel-math tests) must never silently get
-    the XLA path back — an XLA pick degrades to the per-head kernel."""
-    fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e", pallas_only=True)
-    assert fwd.impl == kd.IMPL_PALLAS and "pallas-forced" in fwd.source
-    assert bwd.impl == kd.IMPL_FUSED
-    monkeypatch.setenv("DS_TPU_FLASH_FOLDED", "1")
-    fwd, _ = kd.resolve(_bench_sig(), "TPU v5e", pallas_only=True)
-    assert fwd.impl == kd.IMPL_FOLDED
+def test_resolution_reads_the_shape_and_nothing_else(monkeypatch):
+    """No switch is left to move a decision: resolving reads no environment
+    variable and opens no file, and the module imports neither ``os`` nor a
+    file reader."""
+    reads = []
+    getitem = os._Environ.__getitem__
+    monkeypatch.setattr(os._Environ, "__getitem__", lambda self, key: (
+        reads.append(key), getitem(self, key))[1])
+    monkeypatch.setattr("builtins.open", lambda *a, **kw: reads.append(a))
+    decisions = [kd.resolve(s) for s in (_bench_sig(), _sig(32768, 32, 8, 64))]
+    notes = kd.resolved_note()
+    monkeypatch.undo()
+    assert not reads and notes
+    assert [d[1].impl for d in decisions] == ["fused", "pallas"]
+    assert not {"os", "json", "open"} & set(vars(kd))
 
 
 def test_describe_and_resolved_note():
-    note = kd.resolved_note(kind="TPU v5e")
-    assert note.startswith("attn[fwd=xla:heuristic,bwd=fused@")
-    fwd, bwd = kd.resolve(_bench_sig(), "TPU v5e")
-    d = kd.describe(fwd, bwd)
-    assert "fwd=xla" in d and "bwd=fused@" in d
+    note = kd.resolved_note()
+    assert note == "attn[fwd=pallas@1024x1024,bwd=fused@512x512]"
+    assert kd.describe(*kd.resolve(_bench_sig())) == note
+    assert kd.resolved_note(batch=1, seq=32768, heads=32, kv_heads=8) == (
+        "attn[fwd=pallas@256x512,bwd=pallas@256x512]")
 
 
 # ---------------------------------------------------------------------------
-# persistent cache durability
+# the masked call the kernels refuse (ROADMAP D6's defect)
 # ---------------------------------------------------------------------------
 
 
-def test_cache_round_trip(tmp_path):
-    c = AutotuneCache(str(tmp_path / "t.json"))
-    assert c.lookup("k") is None
-    c.commit("k", {"impl": "xla", "block_q": 128, "block_k": 128, "ms": 1.0})
-    got = c.lookup("k")
-    assert got["impl"] == "xla" and "utc" in got
-    # a second commit merges, never clobbers other keys
-    c.commit("k2", {"impl": "pallas", "block_q": 256, "block_k": 512})
-    assert c.lookup("k")["impl"] == "xla"
-    assert c.lookup("k2")["impl"] == "pallas"
+@pytest.mark.parametrize("leg", ["fwd", "pallas", "fused"])
+@pytest.mark.parametrize("sq,sk", [(64, 128), (128, 64)])
+def test_a_masked_call_with_unequal_lengths_is_refused(sq, sk, leg):
+    """The kernels count the causal diagonal and the window from the first
+    query and key, ``_xla_attention`` from the last: with ``seq_q != seq_k``
+    the two disagree, so the kernel path refuses the call by name, forward
+    and under either backward, rather than answer differently from the path
+    off a TPU. With no mask the same shapes run and match the oracle."""
+    q, k, v = _qkv(s=sq, sk=sk)
+
+    def run(**kw):
+        f = lambda q, k, v: flash_attention(            # noqa: E731
+            q, k, v, interpret=True, block_q=64, block_k=64,
+            impl_bwd=None if leg == "fwd" else leg, **kw)
+        if leg == "fwd":
+            return f(q, k, v)
+        return jax.grad(lambda *a: (f(*a) ** 2).mean(), (0, 1, 2))(q, k, v)
+
+    for kw in (dict(causal=True), dict(window=32), dict(causal=True, window=32)):
+        with pytest.raises(ValueError, match=f"seq_q {sq} != seq_k {sk}"):
+            run(**kw)
+    got = run(causal=False)
+    o_ref, g_ref = _ref(q, k, v, causal=False)
+    for a, b in zip((got, ) if leg == "fwd" else got,
+                    (o_ref, ) if leg == "fwd" else g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
 
 
-def test_cache_tolerates_torn_and_wrong_version(tmp_path):
-    p = tmp_path / "t.json"
-    p.write_text('{"version": 1, "entries": {"k": {"impl": "fol')  # torn
-    c = AutotuneCache(str(p))
-    assert c.lookup("k") is None
-    assert "heuristic" in c.source_description()
-    p.write_text(json.dumps({"version": CACHE_VERSION + 1,
-                             "entries": {"k": {"impl": "xla"}}}))
-    c2 = AutotuneCache(str(p))
-    assert c2.lookup("k") is None
-    # committing over garbage produces a clean valid table
-    c.commit("k", {"impl": "xla", "block_q": 128, "block_k": 128})
-    doc = json.loads(p.read_text())
-    assert doc["version"] == CACHE_VERSION and "k" in doc["entries"]
-
-
-def test_cache_bad_impl_entry_falls_back(monkeypatch, tmp_path):
-    """A table entry naming an impl this build doesn't know (forward compat)
-    must fall through to the heuristic, not crash or dispatch garbage."""
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    sig = _bench_sig()
-    get_cache().commit(kd.signature("fwd", sig, "z"),
-                       {"impl": "cuda-graphs", "block_q": 1, "block_k": 1})
-    fwd, _ = kd.resolve(sig, "z")
-    assert fwd.source == "heuristic"
-
-
-def test_env_dir_precedence(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path / "a"))
-    assert cache_path() == str(tmp_path / "a" / "attn_dispatch.json")
-    # unset: the tracked (empty) table in the checkout — never a home
-    monkeypatch.delenv("DS_TPU_ATTN_CACHE_DIR")
-    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
-    import json
-    import deepspeed_tpu.ops.autotune_cache as ac
-    tracked = os.path.join(os.path.dirname(os.path.abspath(ac.__file__)),
-                           "attn_dispatch.json")
-    assert cache_path() == tracked
-    with open(tracked) as f:
-        assert json.load(f) == {"version": 1, "entries": {}}
-
-
-def test_cache_hit_changes_dispatched_kernels(monkeypatch, tmp_path):
-    """End-to-end: a committed measurement changes which kernels the NEXT
-    flash_attention call traces — and the answer stays oracle-correct."""
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    q, k, v = _qkv(s=128, d=32)
-    sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, True,
-                      None, None)
-    kind = kd.device_kind()
-    get_cache().commit(kd.signature("fwd", sig, kind),
-                       {"impl": "folded", "block_q": 128, "block_k": 128})
-    fwd, _ = kd.resolve(sig, kind)
-    assert (fwd.impl, fwd.source) == ("folded", "measured")
-    o_ref, _ = _ref(q, k, v)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(o_ref),
-                               atol=2e-5, rtol=2e-5)
+def test_off_the_kernel_path_unequal_lengths_align_the_last_query():
+    """``_xla_attention``, the path off a TPU: the last query sees every
+    key (a decode step over a prefix), which is what the refusal names."""
+    q, k, v = _qkv(s=64, sk=128)
+    out = flash_attention(q, k, v, causal=True)      # a CPU, no interpret
+    full = _xla_attention(q, k, v, 1.0 / np.sqrt(q.shape[-1]), False)
+    np.testing.assert_allclose(np.asarray(out[:, -1]), np.asarray(full[:, -1]),
+                               atol=1e-6)
+    assert not np.allclose(np.asarray(out[:, 0]), np.asarray(full[:, 0]))
 
 
 # ---------------------------------------------------------------------------
@@ -560,19 +484,18 @@ def test_cache_hit_changes_dispatched_kernels(monkeypatch, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_explicit_blocks_pin_pallas_tiles(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
+def test_explicit_blocks_pin_pallas_tiles():
+    """``block_q=`` / ``block_k=`` beat the shape on both legs and under
+    either backward, and are not cut to the default VMEM limit."""
     sig = _bench_sig()
-    get_cache().commit(kd.signature("bwd", sig, "y"),
-                       {"impl": "pallas", "block_q": 512, "block_k": 1024})
-    _, bwd = kd.resolve(sig, "y", blocks=(128, 128))
-    assert (bwd.block_q, bwd.block_k) == (128, 128)  # explicit beats measured
-    monkeypatch.setenv("DS_TPU_FLASH_BLOCKS", "256,256")
-    _, bwd = kd.resolve(sig, "y")
-    assert (bwd.block_q, bwd.block_k) == (256, 256)  # env beats measured
-    monkeypatch.delenv("DS_TPU_FLASH_BLOCKS")
-    _, bwd = kd.resolve(sig, "y")
-    assert (bwd.block_q, bwd.block_k) == (512, 1024)  # measured beats default
+    assert kd.resolve(sig)[1] == kd.Decision("fused", 512, 512)
+    for impl in (None, ) + BWD_IMPLS:
+        fwd, bwd = kd.resolve(sig, impl_bwd=impl, blocks=(128, 256))
+        assert (fwd.block_q, fwd.block_k) == (128, 256)
+        assert (bwd.block_q, bwd.block_k) == (128, 256)
+        assert bwd.impl == (impl or "fused")
+    _, bwd = kd.resolve(sig, impl_bwd="pallas", blocks=(1024, 1024))
+    assert (bwd.block_q, bwd.block_k) == (1024, 1024)
 
 
 def test_blocks_fit_short_sequences():
@@ -582,7 +505,7 @@ def test_blocks_fit_short_sequences():
     q, k, v = _qkv(s=128, d=64)
     o_ref, _ = _ref(q, k, v)
     out = flash_attention(q, k, v, causal=True, interpret=True,
-                          impl_fwd="pallas", impl_bwd="pallas")
+                          impl_bwd="pallas")
     np.testing.assert_allclose(np.asarray(out), np.asarray(o_ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -601,49 +524,26 @@ def _load_sweep_module():
     return mod
 
 
-def test_sweep_writes_cache_consumed_by_dispatch(monkeypatch, tmp_path):
-    """Acceptance: the sweep runs end-to-end on CPU (interpret mode), writes
-    a valid version-stamped cache, and the next resolve() consumes it as
-    'measured' for BOTH legs."""
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    sweep = _load_sweep_module()
-    results = sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True,
-                                iters=1, interpret=True, quick=True)
-    assert set(results) == {"fwd", "bwd"}
-    doc = json.loads((tmp_path / "attn_dispatch.json").read_text())
-    assert doc["version"] == CACHE_VERSION and len(doc["entries"]) == 2
-    sig = kd.make_sig((1, 128, 2, 32), 2, 128, "float32", True, None, None)
-    fwd, bwd = kd.resolve(sig, "interpret")
-    assert fwd.source == "measured" and bwd.source == "measured"
-    assert fwd.impl in IMPLS and bwd.impl in BWD_IMPLS
-
-
-def test_sweep_dry_run_commits_nothing(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    sweep = _load_sweep_module()
-    sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True, iters=1,
-                      interpret=True, quick=True, commit=False,
-                      impls=(kd.IMPL_XLA, kd.IMPL_PALLAS))
-    assert not (tmp_path / "attn_dispatch.json").exists()
-
-
-def test_sweep_takes_a_block_grid_and_times_the_backward_alone(monkeypatch,
-                                                              tmp_path):
+def test_sweep_takes_a_block_grid_and_times_the_backward_alone():
     """``--impls`` / ``--blocks``: one impl over a grid of one's own beside
     the chosen blocks (the PR 32 block sweep), each leg with a time of its
-    own from the same residuals."""
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
+    own from the same residuals; with no grid, every implementation and the
+    reference at the shape's own blocks."""
     sweep = _load_sweep_module()
     results = sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True, iters=1,
-                                interpret=True, quick=False, commit=False,
+                                interpret=True, quick=False,
                                 impls=(kd.IMPL_PALLAS, ),
                                 grid=[(64, 64), (128, 64)])
     for leg in ("fwd", "bwd"):
-        entry, rows = results[leg]
+        rows = results[leg]
         assert [r[0] for r in rows] == ["pallas@128x128", "pallas@64x64",
                                         "pallas@128x64"]
-        assert all(r[-1] > 0 for r in rows) and entry["impl"] == "pallas"
-    assert not (tmp_path / "attn_dispatch.json").exists()
+        assert all(r[-1] > 0 and r[1] == "pallas" for r in rows)
+    results = sweep.sweep_shape(1, 128, 2, 2, 32, "float32", True, iters=1,
+                                interpret=True, quick=True)
+    assert [r[0] for r in results["fwd"]] == ["xla", "pallas@128x128"]
+    assert [r[0] for r in results["bwd"]] == ["xla", "pallas@128x128",
+                                              "fused@128x128"]
 
 
 # ---------------------------------------------------------------------------
@@ -654,13 +554,7 @@ def test_sweep_takes_a_block_grid_and_times_the_backward_alone(monkeypatch,
 def test_env_report_includes_dispatch_lines():
     from deepspeed_tpu.env_report import debug_report
     rep = debug_report()
-    assert "attn dispatch table" in rep
     assert "attn dispatch @ bench shape" in rep
-    assert "attn[fwd=" in rep
-
-
-def test_table_source_reflects_cache_state(monkeypatch, tmp_path):
-    monkeypatch.setenv("DS_TPU_ATTN_CACHE_DIR", str(tmp_path))
-    assert "heuristic" in kd.table_source()
-    get_cache().commit("sig", {"impl": "xla", "block_q": 1, "block_k": 1})
-    assert kd.table_source().startswith("measured")
+    assert "attn[fwd=pallas@1024x1024,bwd=fused@512x512]" in rep
+    assert "attn dispatch table" not in rep
+    assert "flash-attention variant" not in rep

