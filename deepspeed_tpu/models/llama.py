@@ -13,6 +13,7 @@ designed TPU-first:
 """
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -153,6 +154,15 @@ class LlamaConfig:
     # ``v_head_dim`` wide
     kv_lora_rank: int = 0
     v_head_dim: int = 0
+    # learned sparse attention on the "attention" operator (DeepSeek Sparse
+    # Attention as it trains, ``ops/dsa_attention.py``): > 0 = an indexer of
+    # ``dsa_index_heads`` heads ``dsa_index_head_dim`` wide with ONE key a
+    # token scores every earlier token, and each query attends the
+    # ``dsa_topk`` of largest score (all its heads alike). The choice passes
+    # no gradient, so the indexer's parameters get none
+    dsa_topk: int = 0
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 0
     # the "mamba" operator (Mamba-2): heads of mamba_d_head values, a state
     # of mamba_d_state a value, B and C shared by the heads of a group, the
     # scan in chunks of mamba_chunk_size, mamba_d_conv taps before it
@@ -236,6 +246,9 @@ class LlamaConfig:
         h, hd = self.hidden_size, self.head_dim_
         attn = h * (self.num_attention_heads * hd) * 2 \
             + h * (self.num_key_value_heads * hd) * 2
+        if self.dsa_topk:   # the indexer: q, k and its LayerNorm, head weights
+            attn += (h * (self.dsa_index_heads + 1) * self.dsa_index_head_dim
+                     + 2 * self.dsa_index_head_dim + h * self.dsa_index_heads)
         proj = 3 if self.mlp_type in ("swiglu", "geglu_tanh") else 2
 
         def ffn(kind, width):
@@ -320,6 +333,18 @@ def apply_rope(x, cos, sin, positions, rotary_dim: Optional[int] = None,
         r1 = x1 * c - x2 * s
         r2 = x2 * c + x1 * s
         return jnp.stack([r1, r2], axis=-1).reshape(x.shape).astype(x.dtype)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+
+
+def rope_at(x, positions, theta: float):
+    """Rotate-half rotary over ALL of x's last axis, the angles made from
+    ``positions`` and not looked up (a second width beside the model's own
+    table: the sparse-attention indexer's). x [b, s, h, d], positions [b, s]."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta**(jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions[:, :, None, None].astype(jnp.float32) * inv_freq
+    c, s = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
@@ -508,7 +533,10 @@ class LlamaAttention(nn.Module):
         # of more than one device (data parallel and ZeRO included) the
         # sharded dispatch below owns the kernel path
         use_flash = flash_shape_ok and on_flash_backend and one_device
-        if cfg.block_diffusion_:
+        if cfg.dsa_topk:
+            attn = self._sparse(x, q, k, v, positions, attn_mask, window, sp_sz,
+                                use_flash)
+        elif cfg.block_diffusion_:
             attn = self._block_diffusion(q, k, v, attn_mask, window, sp_sz,
                                          use_flash)
         elif use_flash:
@@ -588,6 +616,58 @@ class LlamaAttention(nn.Module):
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       cfg.attention_out_bias, _keep_out(cfg, nq * hd))(out)
 
+
+    def _sparse(self, x, q, k, v, positions, attn_mask, window, sp_sz, use_kernel):
+        """Learned sparse attention: the indexer (``indexer_q_proj``,
+        ``indexer_k_proj`` under ``indexer_k_norm``, ``indexer_weights_proj``,
+        all from the layer's normed input under ``stop_gradient``; rotary
+        over the indexer's whole width) and ``ops/dsa_attention.py``'s
+        call: the ``dsa_*`` kernels on one TPU device, else the dense form
+        in blocks of queries. Sows ``dsa_stats`` (only when mutable):
+        ``chosen_pairs``, ``causal_pairs`` and ``kth_score_mean`` (the mean
+        of each row's smallest chosen score), and ``dsa_choice`` (the same):
+        the indexer's operands and each row's smallest chosen score."""
+        from ..ops.dsa_attention import dsa_attention
+        cfg = self.config
+        b, s, _ = x.shape
+        hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+        if not (hi and di):
+            raise ValueError("dsa_topk needs dsa_index_heads and dsa_index_head_dim")
+        if (attn_mask is not None or window is not None or sp_sz > 1
+                or cfg.pos_embedding != "rope" or cfg.block_diffusion_
+                or cfg.attn_logit_softcapping is not None):
+            raise ValueError(
+                "learned sparse attention is causal attention with rotary "
+                "positions: no padding mask, window, softcapping, other "
+                "objective or position form, and no 'seq' mesh axis")
+        # every scope closes before the kernels' call below: one that held
+        # it would rename the instruction (docs/observability.md)
+        with jax.named_scope("ds.dsa.index"):
+            xi = jax.lax.stop_gradient(x)
+            qi = _dense(hi * di, "indexer_q_proj", (EMBED, None), cfg.dtype)(xi)
+            ki = _dense(di, "indexer_k_proj", (EMBED, None), cfg.dtype)(xi)
+            ki = nn.LayerNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.dtype,
+                              name="indexer_k_norm")(ki)
+            w = _dense(hi, "indexer_weights_proj", (EMBED, None), cfg.dtype)(xi)
+            w = w.astype(jnp.float32) * float(hi**-0.5 * di**-0.5)
+            qi = rope_at(qi.reshape(b, s, hi, di), positions, cfg.rope_theta)
+            ki = rope_at(ki[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+        attn, chosen, kth = dsa_attention(
+            q, k, v, qi, ki, w, cfg.dsa_topk, scale=cfg.attn_scale,
+            force_pallas=use_kernel, interpret=use_kernel and interpret_kernels())
+        if self.is_mutable_collection("dsa_stats"):
+            for name, value in (
+                    ("chosen_pairs", chosen.sum(dtype=jnp.int32)),
+                    ("causal_pairs", jnp.float32(b * s * (s + 1) / 2)),
+                    ("kth_score_mean", jax.lax.stop_gradient(kth).mean())):
+                self.sow("dsa_stats", name, value, reduce_fn=lambda a, b: a + b,
+                         init_fn=functools.partial(jnp.zeros, (), value.dtype))
+        if self.is_mutable_collection("dsa_choice"):
+            # what ``ops.dsa_attention.chosen_keys`` rebuilds the choice of any
+            # query from (a check's, not a step's)
+            for name, value in (("qi", qi), ("ki", ki), ("w", w), ("kth", kth)):
+                self.sow("dsa_choice", name, value)
+        return attn
 
     def _block_diffusion(self, q, k, v, attn_mask, window, sp_sz, use_kernel):
         """Attention over ``xt ⊕ x0`` under the block-diffusion mask: the
@@ -1136,6 +1216,8 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
                     for spec in specs)
     a_kernels = tokens * cfg.num_attention_heads * (
         (cfg.v_head_dim or cfg.head_dim_) * itemsize + 4)    # output, log-sum-exp
+    if cfg.dsa_topk:
+        a_kernels += tokens * 2 * 4     # the choice: a row's threshold and tie bound
     plan = remat.plan_for(
         (repr(cfg), x.shape), prices_of, rows=x.shape[0],
         layer_input_bytes=x.size * itemsize, always_kept_bytes=attention * a_kernels,
@@ -1219,7 +1301,7 @@ class LlamaModel(nn.Module):
             ScanLayer = nn.scan(_ScanBody,
                                 variable_axes={"params": 0, "aux_loss": 0,
                                                "moe_stats": 0, "ssm_stats": 0,
-                                               "mla_stats": 0},
+                                               "mla_stats": 0, "dsa_stats": 0},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,

@@ -351,6 +351,55 @@ class SdarMoePolicy(MixtralPolicy):
         return _mlp_experts_map(layer, num_experts)
 
 
+class KeyeVL2Policy(SdarMoePolicy):
+    """Keye-VL-2.0's language model (``model_type: KeyeVL2``): Qwen3-MoE's
+    blocks (``SdarMoePolicy``'s: per-head q/k RMSNorm, ``num_experts`` SwiGLU
+    experts ``moe_intermediate_size`` wide, softmax top-k renormalized, no
+    shared expert) under the causal objective, every attention layer a
+    learned sparse attention: ``sa_config``'s indexer (``indexer_num_heads``
+    heads ``indexer_head_dim`` wide, ONE key a token) scores every earlier
+    token and each query attends its ``topk`` (``ops/dsa_attention.py``).
+    ``rope_scaling`` may only be the default rotary with an ``mrope_section``:
+    on token ids the three position components are equal, so M-RoPE is the
+    one-dimensional rotary over the whole head. The vision tower is not
+    built. The indexer's tensor names follow DeepSeek-V3.2's modeling code
+    (``self_attn.indexer.{wq_b,wk,k_norm,weights_proj}``): assumed, the
+    checkpoint is not here."""
+    arch = "KeyeVL2"
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        rope = dict(hf_config.get("rope_scaling") or {})
+        rope.pop("mrope_section", None)
+        if any(v != "default" for v in rope.values()):
+            raise ValueError(f"KeyeVL2: rope_scaling={hf_config['rope_scaling']!r} "
+                             "is not supported (the default rotary with an "
+                             "mrope_section is)")
+        sa = hf_config.get("sa_config")
+        if not sa:
+            raise ValueError("KeyeVL2: no sa_config (the sparse attention's "
+                             "indexer sizes and topk)")
+        if sa.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError("KeyeVL2: the indexer has ONE key a token; "
+                             f"indexer_num_kv_heads={sa['indexer_num_kv_heads']}")
+        cfg = SdarMoePolicy.config_from_hf(self, {**hf_config, "rope_scaling": None})
+        return dataclasses.replace(
+            cfg, objective="causal_lm", dsa_topk=int(sa["topk"]),
+            dsa_index_heads=int(sa["indexer_num_heads"]),
+            dsa_index_head_dim=int(sa["indexer_head_dim"]))
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        out = super().weight_map(layer, attention_bias)
+        p = f"model.layers.{layer}.self_attn.indexer."
+        f = f"layers_{layer}/self_attn/"
+        out[p + "wq_b.weight"] = (f + "indexer_q_proj/kernel", True)
+        out[p + "wk.weight"] = (f + "indexer_k_proj/kernel", True)
+        out[p + "k_norm.weight"] = (f + "indexer_k_norm/scale", False)
+        out[p + "k_norm.bias"] = (f + "indexer_k_norm/bias", False)
+        out[p + "weights_proj.weight"] = (f + "indexer_weights_proj/kernel", True)
+        return out
+
+
 class Lfm2MoePolicy(HFCheckpointPolicy):
     """LFM2-MoE (HF ``modeling_lfm2_moe.py``): pre-norm layers
     (``operator_norm``, ``ffn_norm``) whose operator is, by ``layer_types``,
@@ -1723,6 +1772,8 @@ _POLICIES = {
     "OlmoeForCausalLM": OlmoePolicy,
     "sdar_moe": SdarMoePolicy,
     "SDARMoeForCausalLM": SdarMoePolicy,
+    "KeyeVL2": KeyeVL2Policy,
+    "KeyeVL2ForConditionalGeneration": KeyeVL2Policy,
     "deepseek_v3": DeepseekV3Policy,
     "DeepseekV3ForCausalLM": DeepseekV3Policy,
     "lfm2_moe": Lfm2MoePolicy,

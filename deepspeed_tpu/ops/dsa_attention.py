@@ -1,0 +1,668 @@
+"""Learned sparse attention as it trains (DeepSeek Sparse Attention): an
+indexer scores every earlier token for each query, the query attends the
+``topk`` tokens of largest score, and all heads of a query share that choice.
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])     s <= t, float32
+    S_t     = the topk keys of largest I[t, .] (all of them when t < topk;
+              ties to the lower index)
+    o[t, h] = sum_{s in S_t} softmax_{s in S_t}(q[t, h] . k[s, g(h)] * scale) v[s, g(h)]
+
+The choice passes no gradient: q, k and v get theirs through the chosen
+pairs, the indexer's operands none.
+
+On one TPU device four Pallas kernels run (``dsa_attention``):
+
+``dsa_index``  a query tile's scores over its causal keys, held in VMEM as
+    order-preserving int32 keys ([key tiles, BQ, BK]: 16 MiB at 32,768 keys
+    and 128 queries; never in HBM), then each row's ``topk``-th largest by
+    bisection over the key's 32 bits (a count of ``key >= candidate`` a
+    bit): the threshold ``tau``. Where more keys than the row may still take
+    equal ``tau`` (float32 scores do collide at 32k keys a row), a second
+    bisection over the position finds ``tie``, the last position a key equal
+    to ``tau`` is taken at, so that exactly ``topk`` are chosen.
+``dsa_fwd``  the flash sweep over the causal key tiles under the mask
+    ``I > tau | (I == tau & s <= tie)``, the scores made again a tile from
+    qI, kI and w by the same code at the same tile shape as ``dsa_index``
+    (bit-equal, so the count is exact: it is written out a row, with the
+    smallest chosen score). A grid step holds all KV heads of a (query, key)
+    tile, so the mask is made once for them; a tile with no chosen pair
+    skips its matmuls.
+``dsa_bwd_dq``, ``dsa_bwd_dkdv``  the backward pair under the same mask,
+    rebuilt from the saved log-sum-exp; the second on transposed tiles, as
+    ``flash_dkdv``.
+
+Every kernel computes every causal pair's indexer score, and the attention
+kernels every causal pair's q . k of a tile that holds a chosen pair: with a
+choice scattered over the sequence that is all of them. What they save is
+HBM (no [T, T] array) and, for a choice that clusters, the skipped tiles.
+
+Anywhere else (a CPU, a mesh of more than one device) ``dsa_attention`` is
+the dense form by hand, a block of queries at a time: scores, ``lax.top_k``,
+a mask; correct and unmeasured.
+"""
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import (LSE_MASKED, NEG_INF, RESIDUAL_NAMES, STAT_LANES,
+                        _compiler_params, _lanes)
+from .registry import use_pallas
+
+INT_MIN = -2**31
+INT_MAX = 2**31 - 1
+# what the backward and a recomputed forward need of the choice, by the names
+# a recomputation's policy keeps them under (ops/remat.py): with these, the
+# kernel's output and its log-sum-exp kept, the choice is made once a step
+CHOICE_NAMES = ("ds.dsa.tau", "ds.dsa.tie")
+
+
+def index_scores(qi, ki, w):
+    """I[b, t, s] in float32, every pair: qi [B, Tq, HI, DI], ki [B, Tk, DI],
+    w [B, Tq, HI] -> [B, Tq, Tk]."""
+    x = jnp.einsum("bqhd,bsd->bqhs", qi, ki, preferred_element_type=jnp.float32)
+    return jnp.einsum("bqhs,bqh->bqs", jnp.maximum(x, 0.0), w.astype(jnp.float32))
+
+
+def chosen_keys(qi, ki, w, kth, positions, topk: int):
+    """The keys some queries chose, rebuilt from what the layer sows under
+    ``dsa_choice``: qi [B, n, HI, DI], w [B, n, HI] and kth [B, n] (each
+    query's smallest chosen score) of the queries at ``positions`` [B, n], ki
+    [B, T, DI] -> [B, n, T] bool. Key ``s <= t`` is chosen where ``I[t, s] >
+    kth[t]``, and of those equal to it the lowest positions, ``min(t + 1,
+    topk)`` in all; equal to the last place but one, since the scores are
+    made again here by another program than the one ``kth`` came from. A
+    check's tool, not a step's."""
+    scores = index_scores(qi, ki, w)
+    T = scores.shape[-1]
+    causal = jnp.arange(T)[None, None, :] <= positions[:, :, None]
+    above = causal & (scores > kth[..., None])
+    equal = causal & ~above & jnp.isclose(scores, kth[..., None], rtol=1e-5, atol=1e-7)
+    need = jnp.minimum(positions + 1, topk)[..., None] - above.sum(-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= need))
+
+
+def _query_block(seq: int, cap: int) -> int:
+    b = min(cap, seq)
+    while seq % b:
+        b -= 1
+    return b
+
+
+def dense_dsa(q, k, v, qi, ki, w, topk: int, scale: float, block_q: int = 256):
+    """The layer's equations by hand, a block of queries at a time, so that
+    [block, T] and never [T, T] is held: -> (o [B, T, H, D], chosen [B, T]
+    int32: pairs a row chose, kth [B, T] float32: its smallest chosen
+    score). ``lax.top_k`` puts the lower index first among equals."""
+    B, T, H, D = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    bq = _query_block(T, block_q)
+    kk = min(topk, T)
+    qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
+
+    def block(args):
+        q_b, qi_b, w_b, start = args
+        t = start + jnp.arange(bq)
+        causal = jnp.arange(T)[None, :] <= t[:, None]                 # [bq, T]
+        sc = jnp.where(causal[None], index_scores(qi_b, ki, w_b), -jnp.inf)
+        with jax.named_scope("ds.dsa.select"):
+            _, idx = jax.lax.top_k(sc, kk)
+            chosen = jnp.zeros((B, bq, T), bool).at[
+                jnp.arange(B)[:, None, None], jnp.arange(bq)[None, :, None],
+                idx].set(True)
+            chosen = chosen & causal[None]
+        s = jnp.einsum("bqkgd,bskd->bkgqs", q_b.reshape(B, bq, KV, G, D),
+                       k).astype(jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(chosen[:, None, None], s, NEG_INF), axis=-1)
+        o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
+        return (o.reshape(B, bq, H, v.shape[-1]), chosen.sum(-1).astype(jnp.int32),
+                jnp.where(chosen, sc, jnp.inf).min(-1))
+
+    def blocks(a):      # [B, T, ...] -> [T / bq, B, bq, ...]
+        return jnp.moveaxis(a.reshape(B, T // bq, bq, *a.shape[2:]), 1, 0)
+
+    o, chosen, kth = jax.lax.map(
+        block, (blocks(q), blocks(qi), blocks(w), jnp.arange(0, T, bq)))
+
+    def whole(a):
+        return jnp.moveaxis(a, 0, 1).reshape(B, T, *a.shape[3:])
+
+    return whole(o), whole(chosen), whole(kth)
+
+
+# ---------------------------------------------------------------------------
+# the kernels
+# ---------------------------------------------------------------------------
+
+
+def _sortable(s):
+    """float32 -> int32 of the same order (an involution on the bits)."""
+    b = jax.lax.bitcast_convert_type(s, jnp.int32)
+    return b ^ ((b >> 31) & jnp.int32(INT_MAX))
+
+
+def sortable_to_float(key):
+    return jax.lax.bitcast_convert_type(
+        key ^ ((key >> 31) & jnp.int32(INT_MAX)), jnp.float32)
+
+
+def _tile_keys(qi_ref, ki, w, transposed: bool):
+    """The indexer's scores of one tile as sortable keys: [BQ, BK], or
+    ``transposed`` [BK, BQ]. qi_ref [1, HI, BQ, DI]; ki [BK, DI]; w [BQ, HI]
+    ([HI, BQ] transposed), float32. The heads are summed in their order, in
+    float32, every kernel alike; a sum of ``w * 0`` terms that came out
+    -0.0 is made +0.0, which is what a matmul's sum gives and what sorts
+    equal to it."""
+    acc = None
+    for j in range(qi_ref.shape[1]):
+        if transposed:
+            x = jax.lax.dot_general(ki, qi_ref[0, j], (((1, ), (1, )), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            term = w[j:j + 1, :] * jnp.maximum(x, 0.0)
+        else:
+            x = jax.lax.dot_general(qi_ref[0, j], ki, (((1, ), (1, )), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            term = w[:, j:j + 1] * jnp.maximum(x, 0.0)
+        acc = term if acc is None else acc + term
+    return _sortable(jnp.where(acc == 0.0, 0.0, acc))
+
+
+def _chosen(key, tau, tie, q_pos, k_pos):
+    return (k_pos <= q_pos) & ((key > tau) | ((key == tau) & (k_pos <= tie)))
+
+
+def _last_key_tile(i, block_q, block_k):
+    return (i * block_q + block_q - 1) // block_k
+
+
+def _lane_fold(x, op, empty):
+    """[rows, n * 128] -> [rows, 128]: the lane tiles folded by ``op`` (``jnp.
+    add``, ``jnp.minimum``); a short test tile: its fold in lane 0 and
+    ``empty``, ``op``'s neutral value, in the others."""
+    n = x.shape[1]
+    if n % STAT_LANES:
+        fold = x.sum if op is jnp.add else x.min
+        lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], STAT_LANES), 1)
+        return jnp.where(lane == 0, fold(axis=1, keepdims=True), empty)
+    out = x[:, :STAT_LANES]
+    for c in range(1, n // STAT_LANES):
+        out = op(out, x[:, c * STAT_LANES:(c + 1) * STAT_LANES])
+    return out
+
+
+def _lane_sum(x):
+    return _lane_fold(x, jnp.add, 0)
+
+
+def _row_total(x):
+    """[rows, 128] partial sums -> the row's total, lane-replicated."""
+    return jnp.broadcast_to(x.sum(axis=1, keepdims=True), x.shape)
+
+
+def _index_kernel(qi_ref, ki_ref, w_ref, tau_ref, tie_ref, keys, *, topk, block_q,
+                  block_k, pos_bits):
+    i, j = pl.program_id(1), pl.program_id(2)
+    last = _last_key_tile(i, block_q, block_k)
+
+    @pl.when(j <= last)
+    def _score():
+        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], False)
+        q_pos, k_pos = _positions(i, j, block_q, block_k, False)
+        keys[j] = jnp.where(k_pos <= q_pos, key, jnp.int32(INT_MIN))
+
+    @pl.when(j == last)
+    def _select():
+        shape = (block_q, STAT_LANES)
+
+        def count(pred):
+            """The row's keys of its live tiles with ``pred(tile, positions)``."""
+            def body(c, acc):
+                k_pos = c * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                return acc + _lane_sum(pred(keys[c], k_pos).astype(jnp.int32))
+            return _row_total(jax.lax.fori_loop(0, last + 1, body,
+                                                jnp.zeros(shape, jnp.int32)))
+
+        def wide(x):
+            return _lanes(x, block_k)
+
+        # the topk-th largest key, bit by bit from the top, in the unsigned
+        # order (the signed key with its sign bit flipped): a causally
+        # masked entry is unsigned 0 and never counted
+        def value_bit(b, prefix):
+            cand = prefix | jnp.left_shift(jnp.int32(1), 31 - b)
+            signed = cand ^ jnp.int32(INT_MIN)
+            n = count(lambda tile, _: tile >= wide(signed))
+            return jnp.where(n >= topk, cand, prefix)
+
+        prefix = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros(shape, jnp.int32))
+        tau = prefix ^ jnp.int32(INT_MIN)
+        tau_ref[0] = tau[:, :1]
+        tie_ref[0] = jnp.full((block_q, 1), INT_MAX, jnp.int32)
+        # a row of fewer than topk candidates (prefix 0) takes them all
+        above = count(lambda tile, _: tile > wide(tau))
+        equal = count(lambda tile, _: tile == wide(tau))
+        need = topk - above
+        tied = (prefix != 0) & (equal > need)
+
+        @pl.when(jnp.max(tied.astype(jnp.int32)) > 0)
+        def _ties():
+            # the largest position X with fewer than ``need`` keys equal to
+            # tau before it: the need-th of them sits at X
+            def pos_bit(b, x):
+                cand = x | jnp.left_shift(jnp.int32(1), pos_bits - 1 - b)
+                n = count(lambda tile, k_pos: (tile == wide(tau))
+                          & (k_pos < wide(cand)))
+                return jnp.where(n < need, cand, x)
+
+            x = jax.lax.fori_loop(0, pos_bits, pos_bit, jnp.zeros(shape, jnp.int32))
+            tie_ref[0] = jnp.where(tied, x, jnp.int32(INT_MAX))[:, :1]
+
+
+def _positions(i, j, block_q, block_k, transposed):
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
+    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
+    return q_pos, k_pos
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, tie_ref,
+                o_ref, lse_ref, cnt_ref, kth_ref, acc, m_s, l_s, cnt_s, kth_s,
+                *, scale, block_q, block_k, num_k):
+    i, j = pl.program_id(1), pl.program_id(2)
+    kv, g, bq, d = q_ref.shape[1:]
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        cnt_s[:] = jnp.zeros_like(cnt_s)
+        kth_s[:] = jnp.full_like(kth_s, jnp.inf)
+
+    @pl.when(j <= _last_key_tile(i, block_q, block_k))
+    def _live():
+        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], False)
+        q_pos, k_pos = _positions(i, j, block_q, block_k, False)
+        chosen = _chosen(key, tau_ref[0], tie_ref[0], q_pos, k_pos)
+        n = _lane_sum(chosen.astype(jnp.int32))
+        cnt_s[:] += n
+        score = sortable_to_float(key)
+        kth_s[:] = jnp.minimum(kth_s[:], _lane_fold(jnp.where(chosen, score, jnp.inf),
+                                                    jnp.minimum, jnp.inf))
+
+        @pl.when(jnp.max(n) > 0)
+        def _attend():
+            rows = jnp.concatenate([chosen] * g, axis=0) if g > 1 else chosen
+            for h in range(kv):
+                q = q_ref[0, h].reshape(g * bq, d)
+                k, v = k_ref[0, h], v_ref[0, h]
+                s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
+                                        preferred_element_type=jnp.float32) * scale
+                s = jnp.where(rows, s, NEG_INF)
+                m_prev, l_prev = m_s[h], l_s[h]
+                m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                m_safe = jnp.where(m_cur <= NEG_INF, 0.0, m_cur)
+                # a masked score's exp underflows to exactly 0 (m_safe is finite)
+                p = jnp.exp(s - _lanes(m_safe, block_k))
+                corr = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - m_safe))
+                l_s[h] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                acc[h] = acc[h] * _lanes(corr, d) + pv
+                m_s[h] = m_cur
+
+    @pl.when(j == num_k - 1)
+    def _finalize():
+        for h in range(kv):
+            l = l_s[h]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc[h] / _lanes(safe_l, d)).reshape(g, bq, d).astype(o_ref.dtype)
+            m_safe = jnp.where(m_s[h] <= NEG_INF, 0.0, m_s[h])
+            lse = jnp.where(l == 0.0, LSE_MASKED, m_safe + jnp.log(safe_l))
+            # as a lane-dense ROW, the form the backward reads (a column
+            # would be padded 128 times in HBM): one transpose a query tile
+            lse_ref[0, h, 0] = lse.T[:1]
+        cnt_ref[0] = cnt_s[:].sum(axis=1, keepdims=True)
+        kth_ref[0] = kth_s[:].min(axis=1, keepdims=True)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
+               w_ref, tau_ref, tie_ref, dq_ref, dq_acc, *, scale, block_q, block_k,
+               num_k):
+    """dQ of one query tile over a sweep of its causal key tiles, on
+    TRANSPOSED tiles as ``_dkdv_kernel`` (what belongs to a query enters as a
+    lane-dense row, where a column would be padded 128 times in HBM): dQ is
+    ``ds^T`` contracted over its first dimension, one transposed tile a
+    step, as the fused flash backward takes it."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    kv, g, bq, d = q_ref.shape[1:]
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(j <= _last_key_tile(i, block_q, block_k))
+    def _live():
+        _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
+                    ki_ref, w_ref, tau_ref, tie_ref, scale, block_q, block_k,
+                    dq_acc=dq_acc)
+
+    @pl.when(j == num_k - 1)
+    def _finalize():
+        for h in range(kv):
+            dq_ref[0, h] = dq_acc[h].reshape(g, bq, d).astype(dq_ref.dtype)
+
+
+def _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
+                w_ref, tau_ref, tie_ref, scale, block_q, block_k, dq_acc=None,
+                dk_acc=None, dv_acc=None):
+    """One transposed tile ([BK, G * BQ] = k . q^T a KV head) of the backward
+    under the choice's mask, added to the accumulators given: dQ, or dK and
+    dV. Nothing is multiplied where the tile holds no chosen pair."""
+    kv, g, bq, d = q_ref.shape[1:]
+    key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], True)
+    q_pos, k_pos = _positions(i, j, block_q, block_k, True)
+    chosen = _chosen(key, tau_ref[0, 0], tie_ref[0, 0], q_pos, k_pos)
+
+    @pl.when(jnp.max(chosen.astype(jnp.int32)) > 0)
+    def _grads():
+        cols = jnp.concatenate([chosen] * g, axis=1) if g > 1 else chosen
+        for h in range(kv):
+            q = q_ref[0, h].reshape(g * bq, d)
+            k, v = k_ref[0, h], v_ref[0, h]
+            do = do_ref[0, h].reshape(g * bq, d)
+            lse = lse_ref[0, h, 0]          # [1, G * BQ]
+            delta = delta_ref[0, h, 0]
+            s = jax.lax.dot_general(k, q, (((1, ), (1, )), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            p = jnp.where(cols, jnp.exp(s - lse), 0.0)
+            if dv_acc is not None:
+                dv_acc[h] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                                 (((1, ), (0, )), ((), ())),
+                                                 preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, (((1, ), (1, )), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta) * scale).astype(q.dtype)
+            if dk_acc is not None:
+                dk_acc[h] += jax.lax.dot_general(ds, q, (((1, ), (0, )), ((), ())),
+                                                 preferred_element_type=jnp.float32)
+            if dq_acc is not None:
+                dq_acc[h] += jax.lax.dot_general(ds, k, (((0, ), (0, )), ((), ())),
+                                                 preferred_element_type=jnp.float32)
+
+
+def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
+                 w_ref, tau_ref, tie_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
+                 block_q, block_k, num_q):
+    """dK and dV of one key tile over a sweep of the query tiles at or past
+    it, on TRANSPOSED tiles ([BK, G * BQ] = k . q^T), as ``flash_dkdv``."""
+    j, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j <= _last_key_tile(i, block_q, block_k))
+    def _live():
+        _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
+                    ki_ref, w_ref, tau_ref, tie_ref, scale, block_q, block_k,
+                    dk_acc=dk_acc, dv_acc=dv_acc)
+
+    @pl.when(i == num_q - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the calls
+# ---------------------------------------------------------------------------
+
+
+def _group(q, k, v):
+    """q [B, T, H, D] -> [B, KV, G, T, D]; k, v [B, T, KV, D] -> [B, KV, T, D]."""
+    B, T, H, D = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, T, KV, H // KV, D).transpose(0, 2, 3, 1, 4)
+    return qg, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+
+
+def _ungroup(x):
+    """[B, KV, G, T, D] -> [B, T, H, D]."""
+    B, KV, G, T, D = x.shape
+    return x.transpose(0, 3, 1, 2, 4).reshape(B, T, KV * G, D)
+
+
+def _tiles(T, blocks):
+    block_q, block_k = (min(b, T) for b in blocks)
+    assert T % block_q == 0 and T % block_k == 0, (T, blocks)
+    return block_q, block_k, T // block_q, T // block_k
+
+
+def _live_key_map(block_q, block_k):
+    """Index-map clamp of the swept key tile to the causal range of query
+    tile ``i``: a dead step names the tile already there."""
+    return lambda j, i: jnp.minimum(j, _last_key_tile(i, block_q, block_k))
+
+
+def dsa_index(qi, ki, w, topk: int, blocks, interpret: bool = False):
+    """-> (tau, tie) [B, T] int32: each row's threshold as a sortable key
+    and the last position a key equal to it is taken at. qi [B, T, HI, DI],
+    ki [B, T, DI], w [B, T, HI] float32."""
+    from .kernel_dispatch import dsa_vmem_bytes
+    B, T, HI, DI = qi.shape
+    block_q, block_k, num_q, num_k = _tiles(T, blocks)
+    live = _live_key_map(block_q, block_k)
+    col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    # the scope closes before the kernel's call: one that held it would
+    # rename the instruction (docs/observability.md)
+    with jax.named_scope("ds.dsa.select"):
+        qit = qi.transpose(0, 2, 1, 3)
+    tau, tie = pl.pallas_call(
+        functools.partial(_index_kernel, topk=topk, block_q=block_q, block_k=block_k,
+                          pos_bits=max(1, int(T - 1).bit_length())),
+        grid=(B, num_q, num_k),
+        in_specs=[pl.BlockSpec((1, HI, block_q, DI), lambda b, i, j: (b, 0, i, 0)),
+                  pl.BlockSpec((1, block_k, DI), lambda b, i, j: (b, live(j, i), 0)),
+                  pl.BlockSpec((1, block_q, HI), lambda b, i, j: (b, i, 0))],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((B, T, 1), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((num_k, block_q, block_k), jnp.int32)],
+        compiler_params=_compiler_params(dsa_vmem_bytes(
+            "index", 1, 1, DI, qi.dtype.itemsize, block_q, block_k, T, HI)),
+        interpret=interpret,
+        name="dsa_index",
+    )(qit, ki, w)
+    return tau[..., 0], tie[..., 0]
+
+
+def _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
+    """-> (o [B, T, H, D], lse [B, KV, q tiles, 1, G * BQ]: a tile's rows
+    g-major as its folded queries are, chosen [B, T], kth [B, T])."""
+    from .kernel_dispatch import dsa_vmem_bytes
+    B, T, H, D = q.shape
+    KV, HI, DI = k.shape[2], qi.shape[2], qi.shape[3]
+    G = H // KV
+    block_q, block_k, num_q, num_k = _tiles(T, blocks)
+    live = _live_key_map(block_q, block_k)
+    qg, kt, vt = _group(q, k, v)
+    q_spec = pl.BlockSpec((1, KV, G, block_q, D), lambda b, i, j: (b, 0, 0, i, 0))
+    kv_spec = pl.BlockSpec((1, KV, block_k, D), lambda b, i, j: (b, 0, live(j, i), 0))
+    col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    out, lse, cnt, kth = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
+                          num_k=num_k),
+        grid=(B, num_q, num_k),
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec((1, HI, block_q, DI), lambda b, i, j: (b, 0, i, 0)),
+                  pl.BlockSpec((1, block_k, DI), lambda b, i, j: (b, live(j, i), 0)),
+                  pl.BlockSpec((1, block_q, HI), lambda b, i, j: (b, i, 0)),
+                  col, col],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, KV, 1, 1, G * block_q),
+                                lambda b, i, j: (b, 0, i, 0, 0)),
+                   col, col],
+        out_shape=[jax.ShapeDtypeStruct(qg.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, KV, num_q, 1, G * block_q), jnp.float32),
+                   jax.ShapeDtypeStruct((B, T, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, T, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((KV, G * block_q, D), jnp.float32),
+                        pltpu.VMEM((KV, G * block_q, STAT_LANES), jnp.float32),
+                        pltpu.VMEM((KV, G * block_q, STAT_LANES), jnp.float32),
+                        pltpu.VMEM((block_q, STAT_LANES), jnp.int32),
+                        pltpu.VMEM((block_q, STAT_LANES), jnp.float32)],
+        compiler_params=_compiler_params(dsa_vmem_bytes(
+            "fwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T, HI)),
+        interpret=interpret,
+        name="dsa_fwd",
+    )(qg, kt, vt, qi.transpose(0, 2, 1, 3), ki, w, tau[..., None], tie[..., None])
+    return _ungroup(out), lse, cnt[..., 0], kth[..., 0]
+
+
+def _dsa_bwd(res, g_out, scale, blocks, interpret):
+    from .kernel_dispatch import dsa_vmem_bytes
+    q, k, v, qi, ki, w, tau, tie, o, lse = res
+    B, T, H, D = q.shape
+    KV, HI, DI = k.shape[2], qi.shape[2], qi.shape[3]
+    G = H // KV
+    block_q, block_k, num_q, num_k = _tiles(T, blocks)
+    live = _live_key_map(block_q, block_k)
+    params = _compiler_params(dsa_vmem_bytes(
+        "bwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T, HI))
+    qg, kt, vt = _group(q, k, v)
+    dog, _, _ = _group(g_out, k, v)
+    og, _, _ = _group(o, k, v)
+    delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
+
+    # both kernels walk TRANSPOSED tiles: what belongs to a query enters as
+    # rows, lane-dense in HBM: [B, (KV,) q tiles, 1, .], g-major inside a
+    # tile like the folded q rows
+    def rows(x):    # [B, KV, G, T] -> [B, KV, q tiles, 1, G * BQ]
+        return (x.reshape(B, KV, G, num_q, block_q).transpose(0, 1, 3, 2, 4)
+                .reshape(B, KV, num_q, 1, G * block_q))
+
+    operands = (qg, kt, vt, dog, lse, rows(delta),
+                qi.transpose(0, 2, 1, 3), ki, w.transpose(0, 2, 1),
+                tau.reshape(B, num_q, 1, block_q), tie.reshape(B, num_q, 1, block_q))
+
+    def specs(q_at, k_at):
+        """The eleven operands' blocks; ``q_at`` / ``k_at`` (b, x, y) -> the
+        query and the key tile of grid step (x, y)."""
+        q_spec = pl.BlockSpec((1, KV, G, block_q, D),
+                              lambda b, x, y: (b, 0, 0, q_at(x, y), 0))
+        kv_spec = pl.BlockSpec((1, KV, block_k, D),
+                               lambda b, x, y: (b, 0, k_at(x, y), 0))
+        r_spec = pl.BlockSpec((1, KV, 1, 1, G * block_q),
+                              lambda b, x, y: (b, 0, q_at(x, y), 0, 0))
+        row = pl.BlockSpec((1, 1, 1, block_q), lambda b, x, y: (b, q_at(x, y), 0, 0))
+        return q_spec, kv_spec, [
+            q_spec, kv_spec, kv_spec, q_spec, r_spec, r_spec,
+            pl.BlockSpec((1, HI, block_q, DI), lambda b, x, y: (b, 0, q_at(x, y), 0)),
+            pl.BlockSpec((1, block_k, DI), lambda b, x, y: (b, k_at(x, y), 0)),
+            pl.BlockSpec((1, HI, block_q), lambda b, x, y: (b, 0, q_at(x, y))),
+            row, row]
+
+    # dQ: the key sweep innermost, clamped to the causal range
+    q_spec, _, in_specs = specs(lambda i, j: i, lambda i, j: live(j, i))
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
+                          num_k=num_k),
+        grid=(B, num_q, num_k),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((KV, G * block_q, D), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="dsa_bwd_dq",
+    )(*operands)
+
+    # dK, dV: key-major, the query sweep innermost, clamped to the first
+    # query tile that sees the key tile
+    _, kv_spec, in_specs = specs(lambda j, i: jnp.maximum(i, (j * block_k) // block_q),
+                                 lambda j, i: j)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkdv_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, num_q=num_q),
+        grid=(B, num_k, num_q),
+        in_specs=in_specs,
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((KV, block_k, D), jnp.float32),
+                        pltpu.VMEM((KV, block_k, D), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="dsa_bwd_dkdv",
+    )(*operands)
+    return _ungroup(dq), dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _dsa_attend(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
+    o, _, chosen, kth = _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret)
+    return o, chosen, kth
+
+
+def _attend_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
+    o, lse, chosen, kth = _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks,
+                                   interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return (o, chosen, kth), (q, k, v, qi, ki, w, tau, tie, o, lse)
+
+
+def _attend_bwd(scale, blocks, interpret, res, g):
+    dq, dk, dv = _dsa_bwd(res, g[0], scale, blocks, interpret)
+    qi, ki, w, tau, tie = res[3:8]
+    no = lambda a: np.zeros(a.shape, jax.dtypes.float0)    # noqa: E731
+    return (dq, dk, dv, jnp.zeros_like(qi), jnp.zeros_like(ki), jnp.zeros_like(w),
+            no(tau), no(tie))
+
+
+_dsa_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+# a jit frame of its own, so that the kernels read ``%dsa_fwd.N`` /
+# ``%dsa_bwd_dq.N`` under ``jax.grad`` (ops/attention.py,
+# ``_flash_attention_call``); XLA inlines the call
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, interpret):
+    qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
+    tau, tie = dsa_index(qi, ki, w, topk, blocks, interpret)
+    tau = checkpoint_name(tau, CHOICE_NAMES[0])
+    tie = checkpoint_name(tie, CHOICE_NAMES[1])
+    return _dsa_attend(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret)
+
+
+def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
+                  blocks: Optional[tuple] = None, force_pallas: Optional[bool] = None,
+                  interpret: bool = False):
+    """Attention of each query over the ``topk`` earlier keys its indexer
+    scores highest. q [B, T, H, D], k/v [B, T, KV, D] (GQA native); the
+    indexer's qi [B, T, HI, DI], its one key a token ki [B, T, DI] and head
+    weights w [B, T, HI] (scaled already; float32). -> (o [B, T, H, D],
+    chosen [B, T] int32: the pairs each row chose, kth [B, T] float32: its
+    smallest chosen score). No gradient reaches qi, ki or w.
+
+    On a TPU (or with ``interpret=True`` anywhere) the ``dsa_*`` kernels run
+    at ``kernel_dispatch.choose_dsa_blocks``' tiles unless ``blocks`` pins
+    (query, key) tiles; elsewhere ``dense_dsa``."""
+    from . import kernel_dispatch as kd
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]))
+    w = w.astype(jnp.float32)
+    if not (use_pallas(force_pallas) or interpret):
+        return dense_dsa(q, k, v, qi, ki, w, topk, scale)
+    sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, True, None, None,
+                      pattern=f"dsa{topk}")
+    blocks = tuple(blocks or kd.choose_dsa_blocks(sig, qi.shape[2], qi.shape[3]))
+    return _dsa_call(q, k, v, qi, ki, w, int(topk), scale, blocks, interpret)

@@ -239,6 +239,63 @@ def choose_block_diffusion_blocks(sig: ShapeSig, leg: str,
     return bq, bk
 
 
+# Learned sparse attention (``ops/dsa_attention.py``, ``dsa_index`` /
+# ``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkdv``): a grid step holds ALL KV
+# heads of one (query, key) tile, so that the indexer's mask is made once for
+# them; the query tile folds with its group to MAX_ROWS rows a KV head, the
+# key tile is KEY_BLOCK. ``dsa_index`` keeps a query tile's scores over the
+# whole row in VMEM (seq * block_q * 4 bytes: 16 MiB at 32,768 keys and 128
+# queries), which caps the query tile; every call carries its own limit.
+DSA_ROW_SCORES_CAP_BYTES = 32 * 2**20
+
+
+def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
+                   itemsize: int, block_q: int, block_k: int, seq: int,
+                   index_heads: int) -> int:
+    """Upper estimate of the VMEM one grid step of the ``dsa_*`` kernels
+    holds, counted as ``flash_vmem_bytes`` counts: ``leg`` "index" (the
+    row's scores over ``seq`` keys), "fwd" or "bwd" (the larger of the
+    pair). The indexer's operands are counted at 128 lanes."""
+    rows = kv_heads * group * block_q
+    lanes = max(head_dim, 128)
+    index = (2 * (index_heads * block_q + block_k) * 128 * itemsize   # qi, ki
+             + 2 * 3 * block_q * 128 * 4                              # w, tau, tie
+             + 6 * block_q * block_k * 4)                             # the tile
+    if leg == "index":
+        return index + seq * block_q * 4 + 2 * 2 * block_q * 128 * 4
+    q_blk = rows * lanes * itemsize
+    kv_blk = kv_heads * block_k * lanes * itemsize
+    stat = rows * 128 * 4
+    tile = group * block_q * block_k
+    if leg == "fwd":
+        blocks = 2 * q_blk + 2 * kv_blk + stat          # q, o; k, v; lse
+        scratch = rows * lanes * 4 + 2 * stat           # acc; m, l
+        temps = tile * (2 * 4 + itemsize)
+    else:
+        blocks = max(3 * q_blk + 2 * kv_blk + 2 * stat,
+                     2 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4)
+        scratch = max(rows * lanes * 4, 2 * kv_heads * block_k * lanes * 4)
+        temps = tile * (3 * 4 + 2 * itemsize)
+    return index + 2 * blocks + scratch + temps
+
+
+def choose_dsa_blocks(sig: ShapeSig, index_heads: int, index_dim: int) -> tuple:
+    """(block_q, block_k) of every ``dsa_*`` kernel of one call (the forward
+    makes the indexer's scores again at ``dsa_index``'s tile shape, so that
+    they are bit-equal): MAX_ROWS folded rows a KV head, fewer while a row
+    tile's scores pass DSA_ROW_SCORES_CAP_BYTES, and KEY_BLOCK keys."""
+    group = max(1, sig.heads // sig.kv_heads)
+    cap_q = max(128, MAX_ROWS // group)
+    while cap_q > 8 and cap_q * sig.seq_k * 4 > DSA_ROW_SCORES_CAP_BYTES:
+        cap_q //= 2
+    if cap_q * sig.seq_k * 4 > DSA_ROW_SCORES_CAP_BYTES:
+        raise ValueError(
+            f"learned sparse attention at {sig.seq_k} keys: a tile of {cap_q} "
+            f"rows' indexer scores pass {DSA_ROW_SCORES_CAP_BYTES >> 20} MiB of "
+            f"VMEM (shorten the sequence)")
+    return _largest_block(sig.seq_q, cap_q), _largest_block(sig.seq_k, KEY_BLOCK)
+
+
 def _largest_block(seq: int, cap: int) -> int:
     """The largest multiple of 128 that divides ``seq`` and is at most
     ``cap``; for a sequence with none (shorter than 128, or not a multiple

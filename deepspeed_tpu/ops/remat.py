@@ -3,7 +3,9 @@
 ``remat: true`` with ``remat_policy: null`` (``models/llama.py``) and
 ``activation_checkpointing.checkpoint()`` with no policy recompute a layer
 in its backward but for the values named here. The attention kernels' output
-and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) are always kept; the
+and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) are always kept, as is
+a learned sparse attention's choice (``ops/dsa_attention.py::CHOICE_NAMES``:
+two int32 a token and layer); the
 candidates below are kept as far down the list as the chip has room for, so
 that the matmuls that make them run once a step:
 
@@ -48,6 +50,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.logging import logger
 from .attention import RESIDUAL_NAMES
+from .dsa_attention import CHOICE_NAMES
 
 ROUTE = "ds.moe.route"
 MIXER_OUT = "ds.mixer.out"
@@ -57,7 +60,9 @@ MIXER_OUT_NARROW = "ds.mixer.out.narrow"
 KERNEL_OUT = "ds.mixer.kernel"
 # the walk's order: ms of recomputation returned a byte, falling
 CANDIDATE_NAMES = (ROUTE, MIXER_OUT, FFN_IN, MIXER_IN, MIXER_OUT_NARROW, KERNEL_OUT)
-KEPT_NAMES = RESIDUAL_NAMES + CANDIDATE_NAMES
+# a learned sparse attention's choice (its rows' thresholds) is kept with the
+# kernels' residuals, whatever the plan: it is made once a step
+KEPT_NAMES = RESIDUAL_NAMES + CHOICE_NAMES + CANDIDATE_NAMES
 
 # The step's own temporaries, in one place. Compiled for a described v5e
 # (tests/unit/ops/test_tpu_aot_compile.py, ..._mla.py; PR 41) the four

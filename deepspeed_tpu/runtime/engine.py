@@ -98,9 +98,14 @@ def _as_apply_fns(model):
             # "diffusion_stats" for the block-diffusion objective: the
             # batch's data ``tokens``, its ``masked_tokens`` and the sum of
             # their ``t``
+            # "dsa_stats" for learned sparse attention: ``chosen_pairs``
+            # (int32) and ``causal_pairs`` come back a layer (their sum over
+            # a deep model passes 32 bits: the host adds them up) and
+            # ``kth_score_mean`` as the layers' mean
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
-                                             "mla_stats", "diffusion_stats"])
+                                             "mla_stats", "diffusion_stats",
+                                             "dsa_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -134,6 +139,14 @@ def _as_apply_fns(model):
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                     mods.get("diffusion_stats", {}))[0]:
                 stats["diffusion_" + path[-1].key] = jnp.sum(leaf)
+            dsa = jax.tree_util.tree_flatten_with_path(mods.get("dsa_stats", {}))[0]
+            for name in ("chosen_pairs", "causal_pairs", "kth_score_mean"):
+                sown = [leaf.reshape(-1) for path, leaf in dsa
+                        if path[-1].key == name]
+                if sown:
+                    sown = jnp.concatenate(sown)
+                    stats["dsa_" + name] = (jnp.mean(sown) if name == "kth_score_mean"
+                                            else sown)
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -1735,6 +1748,20 @@ class DeepSpeedTpuEngine:
                     f"Root mean square of {what} in the latent-attention "
                     "layers, their mean over the steps of the last publish"
                 ).set(float(np.mean([np.mean(s["mla_" + name]) for s in fetched])))
+        if "dsa_chosen_pairs" in fetched[0]:
+            chosen, causal = (sum(float(np.sum(s["dsa_" + name], dtype=np.float64))
+                                  for s in fetched)
+                              for name in ("chosen_pairs", "causal_pairs"))
+            reg.counter(
+                "ds_dsa_chosen_pairs_total",
+                "(query, key) pairs the learned sparse attention's indexer "
+                "chose, summed over layers and steps"
+            ).inc(chosen)
+            reg.gauge(
+                "ds_dsa_chosen_share",
+                "Chosen over causal (query, key) pairs of the sparse-attention "
+                "layers, over the steps of the last publish"
+            ).set(chosen / max(causal, 1.0))
         if "diffusion_masked_tokens" in fetched[0]:
             masked, tokens = (sum(float(np.sum(s["diffusion_" + name])) for s in fetched)
                               for name in ("masked_tokens", "tokens"))
@@ -2139,7 +2166,7 @@ class DeepSpeedTpuEngine:
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
         return self._newest_stats(
-            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_")))
+            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_")))
 
     def diffusion_stats(self):
         """What the block-diffusion objective sowed in the newest fused step
@@ -2171,6 +2198,25 @@ class DeepSpeedTpuEngine:
         :meth:`moe_stats`; ``None`` for a model without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("mla_"))
         return stats and {name[len("mla_"):]: v for name, v in stats.items()}
+
+    def dsa_stats(self):
+        """What the learned-sparse-attention layers sowed in the newest fused
+        step not yet published, as host numbers: ``chosen_pairs`` and
+        ``causal_pairs`` (summed over the layers; the first exact, and a
+        layer at a time as ``chosen_pairs_by_layer``),
+        ``chosen_share`` and ``kth_score_mean`` (the mean over rows and
+        layers of a row's smallest chosen score). Waits for that step, as
+        :meth:`moe_stats`; ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("dsa_"))
+        if not stats:
+            return None
+        chosen = int(np.sum(stats["dsa_chosen_pairs"], dtype=np.int64))
+        causal = float(np.sum(stats["dsa_causal_pairs"], dtype=np.float64))
+        return {"chosen_pairs": chosen, "causal_pairs": int(causal),
+                "chosen_pairs_by_layer": [int(n) for n in
+                                          np.ravel(stats["dsa_chosen_pairs"])],
+                "chosen_share": chosen / max(causal, 1.0),
+                "kth_score_mean": float(np.mean(stats["dsa_kth_score_mean"]))}
 
     def _newest_stats(self, wanted):
         if not self._moe_pending:

@@ -1,0 +1,143 @@
+"""Learned sparse attention's kernels (``ops/dsa_attention.py``: ``dsa_index``,
+``dsa_fwd``, ``dsa_bwd_dq``, ``dsa_bwd_dkdv``), interpreted, against the dense
+form by hand (scores, ``lax.top_k``, a mask): the forward and every gradient,
+the count a row chose and its smallest chosen score, a row with fewer than
+``topk`` candidates, ties at the threshold, a tile with no chosen pair, a
+group of eight; and the blocks ``kernel_dispatch`` gives the call."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import dsa_attention as dsa
+from deepspeed_tpu.ops import kernel_dispatch as kd
+
+T, D, HI, DI, TOPK = 256, 32, 2, 16, 32
+SCALE = 1.0 / np.sqrt(D)
+
+
+def _operands(heads, kv, dtype=jnp.float32, seed=0, rows=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shapes = ((rows, T, heads, D), (rows, T, kv, D), (rows, T, kv, D),
+              (rows, T, HI, DI), (rows, T, DI), (rows, T, HI))
+    q, k, v, qi, ki, w = (jax.random.normal(key, s, jnp.float32)
+                          for key, s in zip(ks, shapes))
+    return [a.astype(dtype) for a in (q, k, v, qi, ki)] + [w]
+
+
+def _kernels(topk=TOPK, blocks=(64, 128)):
+    return lambda *a: dsa.dsa_attention(*a, topk, blocks=blocks, interpret=True)
+
+
+def _dense(topk=TOPK):
+    return lambda *a: dsa.dense_dsa(*a, topk, SCALE)
+
+
+@pytest.mark.parametrize("heads,kv", [(8, 1), (4, 2)], ids=["group8", "group2"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 4e-2)],
+                         ids=["float32", "bfloat16"])
+def test_forward_and_every_gradient_match_the_dense_form(heads, kv, dtype, tol):
+    """Four query tiles by two key tiles (a causal diagonal, an interior tile
+    and a skipped one), rows 0-30 with fewer than ``topk`` candidates: the
+    output, the pairs each row chose (exactly ``min(t + 1, topk)``), its
+    smallest chosen score, dQ, dK and dV; the indexer's operands get zeros."""
+    a = _operands(heads, kv, dtype)
+    (out, chosen, kth), vjp = jax.vjp(_kernels(), *a)
+    (want, want_chosen, want_kth), want_vjp = jax.vjp(_dense(), *a)
+    assert out.shape == want.shape and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    np.testing.assert_array_equal(chosen, want_chosen)
+    np.testing.assert_array_equal(chosen[0], np.minimum(np.arange(T) + 1, TOPK))
+    np.testing.assert_allclose(kth, want_kth, rtol=1e-6, atol=1e-6)
+    g = jax.random.normal(jax.random.PRNGKey(9), out.shape, jnp.float32).astype(dtype)
+    no = np.zeros(chosen.shape, jax.dtypes.float0)
+    got, ref = vjp((g, no, jnp.zeros_like(kth))), want_vjp((g, no, jnp.zeros_like(kth)))
+    for name, x, y in zip(("q", "k", "v"), got, ref):
+        scale = float(jnp.abs(y.astype(jnp.float32)).max())
+        np.testing.assert_allclose(np.asarray(x, np.float32), np.asarray(y, np.float32),
+                                   atol=tol * scale, rtol=tol, err_msg=f"d{name}")
+    for x in got[3:]:
+        assert not np.any(np.asarray(x, np.float32))
+
+
+def test_the_choice_is_lax_top_ks_and_ties_go_to_the_lower_position():
+    """Scores that collide (small whole numbers: a dozen distinct values a
+    row): the kernels' threshold and tie bound choose exactly ``topk`` a row,
+    the very keys ``lax.top_k`` returns (the lower index first among equals)."""
+    q, k, v, qi, ki, w = _operands(8, 1, seed=1, rows=2)
+    qi, ki, w = jnp.round(qi), jnp.round(ki), jnp.round(2 * w)
+    tau, tie = dsa.dsa_index(qi, ki, w, TOPK, (64, 128), interpret=True)
+    scores = dsa.index_scores(qi, ki, w)
+    causal = np.tril(np.ones((T, T), bool))
+    assert len(np.unique(np.asarray(scores)[0, 200, :201])) < 40   # they do collide
+    key = np.asarray(dsa._sortable(scores))
+    pos = np.arange(T)
+    mine = causal & ((key > np.asarray(tau)[..., None])
+                     | ((key == np.asarray(tau)[..., None])
+                        & (pos[None, None, :] <= np.asarray(tie)[..., None])))
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), TOPK)
+    want = np.zeros_like(mine)
+    np.put_along_axis(want, np.asarray(idx), True, axis=-1)
+    np.testing.assert_array_equal(mine, want & causal)
+    assert (np.asarray(tie) < T).any()      # the tie path ran
+    out, chosen, _ = _kernels()(q, k, v, qi, ki, w)
+    np.testing.assert_array_equal(chosen, mine.sum(-1))
+    np.testing.assert_allclose(out, _dense()(q, k, v, qi, ki, w)[0], atol=2e-5, rtol=2e-5)
+
+
+def test_a_tile_with_no_chosen_pair_is_skipped_and_changes_nothing():
+    """An indexer that scores the first 64 keys far above the rest: every
+    query past them chooses among those alone, the second key tile holds no
+    chosen pair (its matmuls are skipped), and output and gradients are the
+    dense form's."""
+    q, k, v, qi, ki, w = _operands(8, 1, seed=2)
+    qi = jnp.abs(qi)
+    ki = jnp.abs(ki) * jnp.where(jnp.arange(T) < 64, 50.0, 1e-3)[None, :, None]
+    w = jnp.abs(w)
+    a = (q, k, v, qi, ki, w)
+    loss = lambda f: lambda *x: jnp.sum(jnp.square(f(*x)[0]))    # noqa: E731
+    scores = np.asarray(dsa.index_scores(qi, ki, w))[0]
+    assert (np.argsort(-scores[200, :201])[:TOPK] < 64).all()
+    np.testing.assert_allclose(_kernels()(*a)[0], _dense()(*a)[0], atol=2e-5, rtol=2e-5)
+    for x, y in zip(jax.grad(loss(_kernels()), argnums=(0, 1, 2))(*a),
+                    jax.grad(loss(_dense()), argnums=(0, 1, 2))(*a)):
+        np.testing.assert_allclose(x, y, atol=2e-4, rtol=2e-4)
+    assert not np.any(jax.grad(loss(_kernels()), argnums=1)(*a)[0, 128:])
+
+
+def test_a_sequence_shorter_than_topk_is_causal_attention():
+    from deepspeed_tpu.ops.attention import _xla_attention
+    q, k, v, qi, ki, w = _operands(4, 2, seed=3)
+    out, chosen, _ = _kernels(topk=4 * T)(q, k, v, qi, ki, w)
+    np.testing.assert_array_equal(chosen[0], np.arange(T) + 1)
+    np.testing.assert_allclose(out, _xla_attention(q, k, v, SCALE, True),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_the_sortable_key_keeps_float32s_order():
+    x = jnp.asarray([-np.inf, -3.5, -1e-30, 0.0, 1e-30, 2.0, np.inf], jnp.float32)
+    key = np.asarray(dsa._sortable(x))
+    assert (np.diff(key.astype(np.int64)) > 0).all() and key.min() > dsa.INT_MIN
+    np.testing.assert_array_equal(dsa.sortable_to_float(jnp.asarray(key)), x)
+
+
+def test_blocks_and_vmem_of_the_cells_call():
+    """1 x 32,768 tokens, 32 heads in groups of 8, head 128, a 16 x 64
+    indexer: 128 queries (1,024 folded rows a KV head) by 512 keys; the
+    indexer kernel's row scores are 16 MiB and every call carries its own
+    limit; a group of 1 is capped by the row scores, not by MAX_ROWS."""
+    sig = kd.make_sig((1, 32768, 32, 128), 4, 32768, "bfloat16", True, None, None,
+                      pattern="dsa2048")
+    assert kd.choose_dsa_blocks(sig, 16, 64) == (128, 512)
+    index = kd.dsa_vmem_bytes("index", 1, 1, 64, 2, 128, 512, 32768, 16)
+    assert 16 * 2**20 < index < 24 * 2**20
+    for leg in ("fwd", "bwd"):
+        need = kd.dsa_vmem_bytes(leg, 4, 8, 128, 2, 128, 512, 32768, 16)
+        assert kd.VMEM_SCOPED_DEFAULT_BYTES < need < kd.FUSED_VMEM_CAP_BYTES, (leg, need)
+    mha = kd.make_sig((1, 32768, 8, 128), 8, 32768, "bfloat16", True, None, None)
+    assert kd.choose_dsa_blocks(mha, 16, 64) == (256, 512)
+    with pytest.raises(ValueError, match="shorten the sequence"):
+        kd.choose_dsa_blocks(kd.make_sig((1, 2**21, 8, 128), 8, 2**21, "bfloat16", True,
+                                         None, None), 16, 64)

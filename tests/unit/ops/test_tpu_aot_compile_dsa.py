@@ -1,0 +1,158 @@
+"""Learned sparse attention's kernels (``dsa_index``, ``dsa_fwd``,
+``dsa_bwd_dq``, ``dsa_bwd_dkdv``) compiled for a described (not attached) TPU
+v5e at Keye-VL-2.0-30B-A3B's published widths and the cell's 1 x 32,768
+tokens, in the engine's fused step: no chip time, nothing runs.
+
+A file of its own beside ``test_tpu_aot_compile_mla.py`` (a worker's whole
+share under ``--dist loadfile``), whose ``step_of`` spells the step out: two
+layers of the cell's configuration, compiled ONCE for the module. The whole
+six-layer cell by hand before a chip call:
+``python tests/unit/ops/test_tpu_aot_compile_dsa.py`` (its temporaries beside
+12 B a parameter are in PERF.md).
+"""
+
+import dataclasses
+import importlib
+import json
+import pathlib
+import re
+import sys
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import test_tpu_aot_compile_mla as mla
+
+ROOT = pathlib.Path(__file__).parents[3]
+CELL = "train-keyevl2-1chip-dsa-seq32k"
+HEADS, KV, D, HI, DI, TOPK = 32, 4, 128, 16, 64, 2048
+# what the chip reported in use when the six-layer cell's step was first
+# traced (my chip run, PR 45): 12 B for each of its 659,190,016 parameters
+IN_USE = 7_913_383_936
+
+
+def cell_config(layers: int):
+    sys.path.insert(0, str(ROOT))
+    bench = ROOT / "benchmark"
+    workload = json.loads((bench / "workloads" / f"{CELL}.json").read_text())
+    config = json.loads((bench / "configs" / f"{workload['config']}.json").read_text())
+    cfg = importlib.import_module(
+        f"benchmark.runners.{workload['runner']}").model_config(config)
+    return (dataclasses.replace(cfg, num_hidden_layers=layers),
+            workload["traffic"]["global_batch"], workload["traffic"]["seq_len"])
+
+
+def steer_to_the_chip(setattr_):
+    mla.steer_to_the_chip(setattr_)
+    from deepspeed_tpu.ops import remat
+    setattr_(remat, "device_memory", lambda: (mla.V5E_BYTES_LIMIT, IN_USE))
+    remat.forget_plans()
+
+
+@pytest.fixture(scope="module")
+def step():
+    """Two layers at the published widths and the cell's batch, traced and
+    compiled for a described v5e, once."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    patch = pytest.MonkeyPatch()
+    try:
+        steer_to_the_chip(patch.setattr)
+        cfg, rows, seq = cell_config(2)
+        traced, n_params = mla.step_of(cfg, rows, seq, SingleDeviceSharding(topo.devices[0]))
+        yield {"cfg": cfg, "rows": rows, "seq": seq, "traced": traced,
+               "n_params": n_params, "compiled": traced.lower().compile()}
+    finally:
+        patch.undo()
+        from deepspeed_tpu.ops import remat
+        remat.forget_plans()
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def test_every_layer_chooses_and_attends_once_a_step(step):
+    """Under whole-layer recomputation each layer's ``dsa_index`` and
+    ``dsa_fwd`` run once (the choice, the output and the log-sum-exp are kept
+    by name for the recomputed layer's backward) and its backward is the one
+    pair; no ``flash_*`` call is in the program."""
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
+             for line in mla.custom_calls(step["compiled"])]
+    kernels = {n: names.count(n) for n in set(names) if not n.startswith("ragged-dot")}
+    for name in ("dsa_index", "dsa_fwd", "dsa_bwd_dq", "dsa_bwd_dkdv"):
+        assert kernels.pop(name) == 2, (name, names)
+    assert all(n.startswith("moe_rows_to_tokens") for n in kernels), names
+    tokens = step["rows"] * step["seq"]
+    always = 2 * tokens * (HEADS * (D * 2 + 4) + 2 * 4)    # o, lse; tau, tie
+    assert kept_residual_bytes(step["traced"].jaxpr) >= always
+
+
+def test_the_calls_have_the_blocks_dispatch_chose_and_their_own_vmem(step):
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    rows, seq = step["rows"], step["seq"]
+    sig = kd.make_sig((rows, seq, HEADS, D), KV, seq, "bfloat16", True, None, None,
+                      pattern=f"dsa{TOPK}")
+    assert kd.choose_dsa_blocks(sig, HI, DI) == (128, 512)
+    calls = {line.split(" = ")[0].split("%")[-1].split(".")[0]: line
+             for line in mla.custom_calls(step["compiled"])}
+    grouped = f"bf16[{rows},{KV},{HEADS // KV},{seq},{D}]"
+    assert f"s32[{rows},{seq},1]" in calls["dsa_index"].split("custom-call(")[0]
+    assert f"bf16[{rows},{HI},{seq},{DI}]" in calls["dsa_index"].split("custom-call(")[1]
+    fwd = calls["dsa_fwd"].split("custom-call(")[0]
+    assert grouped in fwd and f"f32[{rows},{KV},{seq // 128},1,{HEADS // KV * 128}]" in fwd
+    assert grouped in calls["dsa_bwd_dq"].split("custom-call(")[0]
+    assert f"bf16[{rows},{KV},{seq},{D}]" in calls["dsa_bwd_dkdv"].split("custom-call(")[0]
+    for name, leg in (("dsa_index", "index"), ("dsa_fwd", "fwd"), ("dsa_bwd_dq", "bwd")):
+        need = kd.dsa_vmem_bytes(leg, *((1, 1, DI) if leg == "index" else (KV, HEADS // KV, D)),
+                                 2, 128, 512, seq, HI)
+        asked = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', calls[name])[0]
+        assert int(re.search(r'"size":"(\d+)"', asked).group(1)) == kd.vmem_limit_bytes(need)
+
+
+def test_no_score_matrix_is_in_hbm_the_scopes_stand_and_it_fits(step):
+    """No array of ``seq x seq`` in the compiled step; ``ds.dsa.index`` is on
+    the ops before the kernels (closed before their call: the instructions
+    above keep their names; on this path the whole selection is inside
+    ``dsa_index``, and XLA folds the one transpose ``ds.dsa.select`` held
+    into the kernel's operand layout); two layers' temporaries
+    beside their state at rest are far under the chip's ``bytes_limit``."""
+    text = step["compiled"].as_text()
+    seq = step["seq"]
+    assert not re.search(rf"\[(\d+,)*{seq},{seq}\]", text)
+    for scope in ("ds.step.loss", "ds.dsa.index", "ds.rope",
+                  "ds.moe.route", "ds.head.loss"):
+        assert f"/{scope}/" in text, scope
+    assert "/self_attn/" in text and "ds.dsa.index/indexer_q_proj/" in text
+    temporaries = step["compiled"].memory_analysis().temp_size_in_bytes
+    assert temporaries + IN_USE <= mla.V5E_BYTES_LIMIT - 0.8e9
+
+
+if __name__ == "__main__":
+    # the whole cell by hand: python tests/unit/ops/test_tpu_aot_compile_dsa.py [layers]
+    import time
+    from jax.experimental import topologies
+    sys.path.insert(0, str(ROOT))
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    steer_to_the_chip(pytest.MonkeyPatch().setattr)
+    cfg, rows, seq = cell_config(int(sys.argv[1]) if len(sys.argv) > 1 else 6)
+    t0 = time.monotonic()
+    traced, n_params = mla.step_of(cfg, rows, seq, SingleDeviceSharding(topo.devices[0]))
+    compiled = traced.lower().compile()
+    mem = compiled.memory_analysis()
+    print(f"{rows} x {seq}: {n_params} parameters, 12 B each {12 * n_params / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, kept residuals "
+          f"{kept_residual_bytes(traced.jaxpr) / 1e9:.3f} GB, together "
+          f"{(12 * n_params + mem.temp_size_in_bytes) / 1e9:.3f} GB of "
+          f"{mla.V5E_BYTES_LIMIT / 1e9:.3f} GB; arrays of seq x seq: "
+          f"{len(re.findall(rf'[\\[,]{seq},{seq}\\]', compiled.as_text()))}; "
+          f"{time.monotonic() - t0:.0f} s")
