@@ -624,8 +624,10 @@ class LlamaAttention(nn.Module):
         over the indexer's whole width) and ``ops/dsa_attention.py``'s
         call: the ``dsa_*`` kernels on one TPU device, else the dense form
         in blocks of queries. Sows ``dsa_stats`` (only when mutable):
-        ``chosen_pairs``, ``causal_pairs`` and ``kth_score_mean`` (the mean
-        of each row's smallest chosen score), and ``dsa_choice`` (the same):
+        ``chosen_pairs``, ``causal_pairs``, ``kth_score_mean`` (the mean
+        of each row's smallest chosen score) and ``masks_kept`` (1 where the
+        layer's backward reads the forward's mask, ``ops/remat.py::DSA_MASK``,
+        0 where it makes it again), and ``dsa_choice`` (the same):
         the indexer's operands and each row's smallest chosen score."""
         from ..ops.dsa_attention import dsa_attention
         cfg = self.config
@@ -652,14 +654,20 @@ class LlamaAttention(nn.Module):
             w = w.astype(jnp.float32) * float(hi**-0.5 * di**-0.5)
             qi = rope_at(qi.reshape(b, s, hi, di), positions, cfg.rope_theta)
             ki = rope_at(ki[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+        # the kernels' mask across this layer's recomputation: handed to the
+        # backward where the plan keeps it, else made again there
+        keep_mask = remat.keeps(remat.DSA_MASK)
         attn, chosen, kth = dsa_attention(
             q, k, v, qi, ki, w, cfg.dsa_topk, scale=cfg.attn_scale,
-            force_pallas=use_kernel, interpret=use_kernel and interpret_kernels())
+            force_pallas=use_kernel, interpret=use_kernel and interpret_kernels(),
+            keep_mask=keep_mask)
         if self.is_mutable_collection("dsa_stats"):
             for name, value in (
                     ("chosen_pairs", chosen.sum(dtype=jnp.int32)),
                     ("causal_pairs", jnp.float32(b * s * (s + 1) / 2)),
-                    ("kth_score_mean", jax.lax.stop_gradient(kth).mean())):
+                    ("kth_score_mean", jax.lax.stop_gradient(kth).mean()),
+                    # the dense form has no mask to keep
+                    ("masks_kept", jnp.int32(bool(use_kernel) and keep_mask))):
                 self.sow("dsa_stats", name, value, reduce_fn=lambda a, b: a + b,
                          init_fn=functools.partial(jnp.zeros, (), value.dtype))
         if self.is_mutable_collection("dsa_choice"):
@@ -1217,7 +1225,7 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
     a_kernels = tokens * cfg.num_attention_heads * (
         (cfg.v_head_dim or cfg.head_dim_) * itemsize + 4)    # output, log-sum-exp
     if cfg.dsa_topk:
-        a_kernels += tokens * 2 * 4     # the choice: a row's threshold and tie bound
+        a_kernels += tokens * 2 * 4     # a row's threshold and tie bound
     plan = remat.plan_for(
         (repr(cfg), x.shape), prices_of, rows=x.shape[0],
         layer_input_bytes=x.size * itemsize, always_kept_bytes=attention * a_kernels,

@@ -10,7 +10,9 @@ indexer scores every earlier token for each query, the query attends the
 The choice passes no gradient: q, k and v get theirs through the chosen
 pairs, the indexer's operands none.
 
-On one TPU device four Pallas kernels run (``dsa_attention``):
+On one TPU device the indexer's scores are made ONCE a layer and step, by
+``dsa_index``, which writes its choice out as a packed bit mask; the three
+attention kernels read the mask (``dsa_attention``):
 
 ``dsa_index``  a query tile's scores over its causal keys, held in VMEM as
     order-preserving int32 keys ([key tiles, BQ, BK]: 16 MiB at 32,768 keys
@@ -19,22 +21,35 @@ On one TPU device four Pallas kernels run (``dsa_attention``):
     bit): the threshold ``tau``. Where more keys than the row may still take
     equal ``tau`` (float32 scores do collide at 32k keys a row), a second
     bisection over the position finds ``tie``, the last position a key equal
-    to ``tau`` is taken at, so that exactly ``topk`` are chosen.
-``dsa_fwd``  the flash sweep over the causal key tiles under the mask
-    ``I > tau | (I == tau & s <= tie)``, the scores made again a tile from
-    qI, kI and w by the same code at the same tile shape as ``dsa_index``
-    (bit-equal, so the count is exact: it is written out a row, with the
-    smallest chosen score). A grid step holds all KV heads of a (query, key)
-    tile, so the mask is made once for them; a tile with no chosen pair
-    skips its matmuls.
-``dsa_bwd_dq``, ``dsa_bwd_dkdv``  the backward pair under the same mask,
-    rebuilt from the saved log-sum-exp; the second on transposed tiles, as
-    ``flash_dkdv``.
+    to ``tau`` is taken at, so that exactly ``topk`` are chosen. Then one more
+    walk over the keys it still holds writes the choice ``I > tau | (I == tau
+    & s <= tie)`` as words of 32 queries (``mask_layout``: T * T / 8 bytes a
+    row of the batch, 134 MB at 32,768; no [T, T] array), and with it the
+    pairs each row chose and its smallest chosen score.
+``dsa_fwd``  the flash sweep over the causal key tiles under the mask: a
+    tile's words expand by a sublane broadcast, a shift and an AND. A grid
+    step holds all KV heads of a (query, key) tile, so the words are expanded
+    once for them; a tile whose words are all zero skips its matmuls.
+``dsa_bwd_dq``, ``dsa_bwd_dkdv``  the backward pair under the same words,
+    rebuilt from the saved log-sum-exp, on transposed tiles as ``flash_dkdv``
+    (the expanded tile is transposed: one orientation is stored).
+``dsa_mask``  the words again from ``tau`` and ``tie``: one pass of scores by
+    ``dsa_index``'s code at its tile shape (bit-equal), no selection. Only in
+    the backward of a recomputed layer that did not keep its mask.
 
-Every kernel computes every causal pair's indexer score, and the attention
-kernels every causal pair's q . k of a tile that holds a chosen pair: with a
-choice scattered over the sequence that is all of them. What they save is
-HBM (no [T, T] array) and, for a choice that clusters, the skipped tiles.
+Across a recomputation the thresholds are kept by name with the kernel's
+output and log-sum-exp (``ops/remat.py``: ``DSA_CHOICE``), and the mask is a
+priced candidate (``DSA_MASK``, the walk's first: a scoring pass returned for
+T * T / 8 bytes): a layer that keeps it hands it to its backward, which then
+needs neither the thresholds nor the indexer's operands; one that does not
+calls ``dsa_mask`` once. Either way the selection runs once a step.
+
+The attention kernels compute every causal pair's q . k of a tile that holds
+a chosen pair: with a choice scattered over the sequence that is all of them
+(a late query keeps 2,048 of 32,768 keys, so a key is chosen by none of a
+tile's 128 queries with chance 0.9375^128: the union of a query tile's keys
+is every key). What they save is HBM (no [T, T] array) and, for a choice that
+clusters, the skipped tiles.
 
 Anywhere else (a CPU, a mesh of more than one device) ``dsa_attention`` is
 the dense form by hand, a block of queries at a time: scores, ``lax.top_k``,
@@ -51,16 +66,13 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import remat
 from .attention import (LSE_MASKED, NEG_INF, RESIDUAL_NAMES, STAT_LANES,
                         _compiler_params, _lanes)
 from .registry import use_pallas
 
 INT_MIN = -2**31
 INT_MAX = 2**31 - 1
-# what the backward and a recomputed forward need of the choice, by the names
-# a recomputation's policy keeps them under (ops/remat.py): with these, the
-# kernel's output and its log-sum-exp kept, the choice is made once a step
-CHOICE_NAMES = ("ds.dsa.tau", "ds.dsa.tie")
 
 
 def index_scores(qi, ki, w):
@@ -138,7 +150,7 @@ def dense_dsa(q, k, v, qi, ki, w, topk: int, scale: float, block_q: int = 256):
 
 
 # ---------------------------------------------------------------------------
-# the kernels
+# a tile's scores and what is folded from them
 # ---------------------------------------------------------------------------
 
 
@@ -153,23 +165,18 @@ def sortable_to_float(key):
         key ^ ((key >> 31) & jnp.int32(INT_MAX)), jnp.float32)
 
 
-def _tile_keys(qi_ref, ki, w, transposed: bool):
-    """The indexer's scores of one tile as sortable keys: [BQ, BK], or
-    ``transposed`` [BK, BQ]. qi_ref [1, HI, BQ, DI]; ki [BK, DI]; w [BQ, HI]
-    ([HI, BQ] transposed), float32. The heads are summed in their order, in
-    float32, every kernel alike; a sum of ``w * 0`` terms that came out
-    -0.0 is made +0.0, which is what a matmul's sum gives and what sorts
+def _tile_keys(qi_ref, ki, w):
+    """The indexer's scores of one tile as sortable keys [BQ, BK]. qi_ref
+    [1, HI, BQ, DI]; ki [BK, DI]; w [BQ, HI], float32. The heads are summed
+    in their order, in float32, ``dsa_index`` and ``dsa_mask`` alike (at one
+    tile shape the two are bit-equal); a sum of ``w * 0`` terms that came
+    out -0.0 is made +0.0, which is what a matmul's sum gives and what sorts
     equal to it."""
     acc = None
     for j in range(qi_ref.shape[1]):
-        if transposed:
-            x = jax.lax.dot_general(ki, qi_ref[0, j], (((1, ), (1, )), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            term = w[j:j + 1, :] * jnp.maximum(x, 0.0)
-        else:
-            x = jax.lax.dot_general(qi_ref[0, j], ki, (((1, ), (1, )), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            term = w[:, j:j + 1] * jnp.maximum(x, 0.0)
+        x = jax.lax.dot_general(qi_ref[0, j], ki, (((1, ), (1, )), ((), ())),
+                                preferred_element_type=jnp.float32)
+        term = w[:, j:j + 1] * jnp.maximum(x, 0.0)
         acc = term if acc is None else acc + term
     return _sortable(jnp.where(acc == 0.0, 0.0, acc))
 
@@ -180,6 +187,12 @@ def _chosen(key, tau, tie, q_pos, k_pos):
 
 def _last_key_tile(i, block_q, block_k):
     return (i * block_q + block_q - 1) // block_k
+
+
+def _positions(i, j, block_q, block_k):
+    shape = (block_q, block_k)
+    return (i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
 
 
 def _lane_fold(x, op, empty):
@@ -206,15 +219,107 @@ def _row_total(x):
     return jnp.broadcast_to(x.sum(axis=1, keepdims=True), x.shape)
 
 
-def _index_kernel(qi_ref, ki_ref, w_ref, tau_ref, tie_ref, keys, *, topk, block_q,
-                  block_k, pos_bits):
+# ---------------------------------------------------------------------------
+# the choice as a bit mask
+# ---------------------------------------------------------------------------
+#
+# mask [B, key tiles, T / bits, BK] int32: bit ``b`` of word [., c, r, s] says
+# whether query ``bits * r + b`` chose key ``c * BK + s``. A word packs the
+# queries, the sublanes of the forward's [BQ, BK] tile, so that a tile's
+# words [BQ / bits, BK] expand by a sublane broadcast, a shift and an AND; the
+# backward pair, which walks transposed tiles, transposes the expanded tile.
+# 32 queries a word where the query tile allows (T * T / 8 bytes a row of the
+# batch); a query tile of 128 is 4 word rows, half of the 8 a block of int32
+# needs, so a block then holds two query tiles' rows and a kernel takes its
+# own (``_word_rows``). The layout is the call's: every kernel of one
+# ``dsa_attention`` has the same tiles.
+
+
+def mask_layout(seq: int, block_q: int):
+    """-> (bits of a word, word rows a query tile, word rows a block)."""
+    bits = 32
+    while block_q % bits:
+        bits //= 2
+    rows, total = block_q // bits, seq // bits
+    if rows % 8 == 0 or rows == total:
+        return bits, rows, rows
+    return bits, rows, 8 if 8 % rows == 0 and total % 8 == 0 else total
+
+
+def _mask_shape(rows: int, seq: int, blocks):
+    block_q, block_k, _, num_k = _tiles(seq, blocks)
+    return rows, num_k, seq // mask_layout(seq, block_q)[0], block_k
+
+
+def _word_rows(i, mask_ref, block_q, bits):
+    """Where query tile ``i``'s word rows start in its block of ``mask_ref``
+    [1, key tiles, rows, BK], and how many they are."""
+    rows, block = block_q // bits, mask_ref.shape[2]
+    return (0 if block == rows else (i * rows) % block), rows
+
+
+def _put_words(mask_ref, c, i, chosen, bits):
+    """Key tile ``c`` of query tile ``i``'s choice [BQ, BK] into its words.
+    Mosaic takes no sublane offset it cannot prove a multiple of 8: the
+    block's rows are read, the tile's own replaced by a select, and written."""
+    at, rows = _word_rows(i, mask_ref, chosen.shape[0], bits)
+    bit = jax.lax.broadcasted_iota(jnp.int32, chosen.shape, 0) & (bits - 1)
+    shifted = jax.lax.shift_left(chosen.astype(jnp.int32), bit)
+    # the bits are disjoint: their sum is their OR
+    words = [shifted[r * bits:(r + 1) * bits].sum(axis=0, keepdims=True)
+             for r in range(rows)]
+    block = mask_ref[0, c]
+    row = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
+    for r, word in enumerate(words):
+        block = jnp.where(row == at + r, jnp.broadcast_to(word, block.shape), block)
+    mask_ref[0, c] = block
+
+
+def _tile_words(mask_ref, i, block_q, bits):
+    """Query tile ``i``'s words [BQ / bits, BK] of the key tile ``mask_ref``
+    [1, 1, rows, BK] holds: of the tiles that share the block, its own."""
+    at, rows = _word_rows(i, mask_ref, block_q, bits)
+    block = mask_ref[0, 0]
+    words = block[:rows]
+    for n in range(1, block.shape[0] // rows):
+        words = jnp.where(at == n * rows, block[n * rows:(n + 1) * rows], words)
+    return words
+
+
+def _expand(words, bits):
+    """Words [BQ / bits, BK] -> the choice [BQ, BK] as int32 0 / 1."""
+    rows, block_k = words.shape
+    wide = [jnp.broadcast_to(words[r:r + 1], (bits, block_k)) for r in range(rows)]
+    wide = jnp.concatenate(wide, axis=0) if rows > 1 else wide[0]
+    bit = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 0) & (bits - 1)
+    return jax.lax.shift_right_logical(wide, bit) & 1
+
+
+def _clear_first(mask_ref, i, j, block_q, bits):
+    """A block's first visit zeroes it: the words of a key tile past a query
+    tile's causal range are never written (and never read)."""
+    at, _ = _word_rows(i, mask_ref, block_q, bits)
+
+    @pl.when((j == 0) & (at == 0))
+    def _clear():
+        mask_ref[...] = jnp.zeros_like(mask_ref)
+
+
+# ---------------------------------------------------------------------------
+# the kernels
+# ---------------------------------------------------------------------------
+
+
+def _index_kernel(qi_ref, ki_ref, w_ref, tau_ref, tie_ref, cnt_ref, kth_ref, mask_ref,
+                  keys, *, topk, block_q, block_k, pos_bits, bits):
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_key_tile(i, block_q, block_k)
+    _clear_first(mask_ref, i, j, block_q, bits)
 
     @pl.when(j <= last)
     def _score():
-        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], False)
-        q_pos, k_pos = _positions(i, j, block_q, block_k, False)
+        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0])
+        q_pos, k_pos = _positions(i, j, block_q, block_k)
         keys[j] = jnp.where(k_pos <= q_pos, key, jnp.int32(INT_MIN))
 
     @pl.when(j == last)
@@ -265,17 +370,43 @@ def _index_kernel(qi_ref, ki_ref, w_ref, tau_ref, tie_ref, keys, *, topk, block_
             x = jax.lax.fori_loop(0, pos_bits, pos_bit, jnp.zeros(shape, jnp.int32))
             tie_ref[0] = jnp.where(tied, x, jnp.int32(INT_MAX))[:, :1]
 
+        # the choice itself, once more over the row's keys while they are
+        # here: its words, the pairs the row chose and its smallest chosen key
+        tau_col, tie_col = tau_ref[0], tie_ref[0]
 
-def _positions(i, j, block_q, block_k, transposed):
-    shape = (block_k, block_q) if transposed else (block_q, block_k)
-    q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
-    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
-    return q_pos, k_pos
+        def put(c, carry):
+            n, low = carry
+            key = keys[c]
+            q_pos, k_pos = _positions(i, c, block_q, block_k)
+            chosen = _chosen(key, tau_col, tie_col, q_pos, k_pos)
+            _put_words(mask_ref, c, i, chosen, bits)
+            low = jnp.minimum(low, _lane_fold(
+                jnp.where(chosen, key, jnp.int32(INT_MAX)), jnp.minimum, INT_MAX))
+            return n + _lane_sum(chosen.astype(jnp.int32)), low
+
+        n, low = jax.lax.fori_loop(
+            0, last + 1, put, (jnp.zeros(shape, jnp.int32),
+                               jnp.full(shape, INT_MAX, jnp.int32)))
+        cnt_ref[0] = n.sum(axis=1, keepdims=True)
+        kth_ref[0] = sortable_to_float(low.min(axis=1, keepdims=True))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, tie_ref,
-                o_ref, lse_ref, cnt_ref, kth_ref, acc, m_s, l_s, cnt_s, kth_s,
-                *, scale, block_q, block_k, num_k):
+def _mask_kernel(qi_ref, ki_ref, w_ref, tau_ref, tie_ref, mask_ref, *, block_q,
+                 block_k, bits):
+    """The words again from thresholds that are known: one pass of scores."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    _clear_first(mask_ref, i, j, block_q, bits)
+
+    @pl.when(j <= _last_key_tile(i, block_q, block_k))
+    def _live():
+        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0])
+        q_pos, k_pos = _positions(i, j, block_q, block_k)
+        _put_words(mask_ref, j, i, _chosen(key, tau_ref[0], tie_ref[0], q_pos, k_pos),
+                   bits)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc, m_s, l_s,
+                *, scale, block_q, block_k, num_k, bits):
     i, j = pl.program_id(1), pl.program_id(2)
     kv, g, bq, d = q_ref.shape[1:]
 
@@ -284,22 +415,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, tie_ref,
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
-        cnt_s[:] = jnp.zeros_like(cnt_s)
-        kth_s[:] = jnp.full_like(kth_s, jnp.inf)
 
     @pl.when(j <= _last_key_tile(i, block_q, block_k))
     def _live():
-        key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], False)
-        q_pos, k_pos = _positions(i, j, block_q, block_k, False)
-        chosen = _chosen(key, tau_ref[0], tie_ref[0], q_pos, k_pos)
-        n = _lane_sum(chosen.astype(jnp.int32))
-        cnt_s[:] += n
-        score = sortable_to_float(key)
-        kth_s[:] = jnp.minimum(kth_s[:], _lane_fold(jnp.where(chosen, score, jnp.inf),
-                                                    jnp.minimum, jnp.inf))
+        words = _tile_words(mask_ref, i, block_q, bits)
 
-        @pl.when(jnp.max(n) > 0)
+        @pl.when(jnp.max((words != 0).astype(jnp.int32)) > 0)
         def _attend():
+            chosen = _expand(words, bits) != 0
             rows = jnp.concatenate([chosen] * g, axis=0) if g > 1 else chosen
             for h in range(kv):
                 q = q_ref[0, h].reshape(g * bq, d)
@@ -330,13 +453,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, tie_ref,
             # as a lane-dense ROW, the form the backward reads (a column
             # would be padded 128 times in HBM): one transpose a query tile
             lse_ref[0, h, 0] = lse.T[:1]
-        cnt_ref[0] = cnt_s[:].sum(axis=1, keepdims=True)
-        kth_ref[0] = kth_s[:].min(axis=1, keepdims=True)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
-               w_ref, tau_ref, tie_ref, dq_ref, dq_acc, *, scale, block_q, block_k,
-               num_k):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, dq_ref,
+               dq_acc, *, scale, block_q, block_k, num_k, bits):
     """dQ of one query tile over a sweep of its causal key tiles, on
     TRANSPOSED tiles as ``_dkdv_kernel`` (what belongs to a query enters as a
     lane-dense row, where a column would be padded 128 times in HBM): dQ is
@@ -351,9 +471,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
 
     @pl.when(j <= _last_key_tile(i, block_q, block_k))
     def _live():
-        _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                    ki_ref, w_ref, tau_ref, tie_ref, scale, block_q, block_k,
-                    dq_acc=dq_acc)
+        _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                    scale, bits, dq_acc=dq_acc)
 
     @pl.when(j == num_k - 1)
     def _finalize():
@@ -361,19 +480,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
             dq_ref[0, h] = dq_acc[h].reshape(g, bq, d).astype(dq_ref.dtype)
 
 
-def _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
-                w_ref, tau_ref, tie_ref, scale, block_q, block_k, dq_acc=None,
-                dk_acc=None, dv_acc=None):
+def _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, scale,
+                bits, dq_acc=None, dk_acc=None, dv_acc=None):
     """One transposed tile ([BK, G * BQ] = k . q^T a KV head) of the backward
-    under the choice's mask, added to the accumulators given: dQ, or dK and
-    dV. Nothing is multiplied where the tile holds no chosen pair."""
+    under the choice's mask (query tile ``i``'s words, expanded and
+    transposed), added to the accumulators given: dQ, or dK and dV. Nothing
+    is multiplied where the tile holds no chosen pair."""
     kv, g, bq, d = q_ref.shape[1:]
-    key = _tile_keys(qi_ref, ki_ref[0], w_ref[0], True)
-    q_pos, k_pos = _positions(i, j, block_q, block_k, True)
-    chosen = _chosen(key, tau_ref[0, 0], tie_ref[0, 0], q_pos, k_pos)
+    words = _tile_words(mask_ref, i, bq, bits)
 
-    @pl.when(jnp.max(chosen.astype(jnp.int32)) > 0)
+    @pl.when(jnp.max((words != 0).astype(jnp.int32)) > 0)
     def _grads():
+        chosen = _expand(words, bits).T != 0
         cols = jnp.concatenate([chosen] * g, axis=1) if g > 1 else chosen
         for h in range(kv):
             q = q_ref[0, h].reshape(g * bq, d)
@@ -399,9 +517,8 @@ def _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, k
                                                  preferred_element_type=jnp.float32)
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref,
-                 w_ref, tau_ref, tie_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                 block_q, block_k, num_q):
+def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, dk_ref,
+                 dv_ref, dk_acc, dv_acc, *, scale, block_q, block_k, num_q, bits):
     """dK and dV of one key tile over a sweep of the query tiles at or past
     it, on TRANSPOSED tiles ([BK, G * BQ] = k . q^T), as ``flash_dkdv``."""
     j, i = pl.program_id(1), pl.program_id(2)
@@ -413,9 +530,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref
 
     @pl.when(j <= _last_key_tile(i, block_q, block_k))
     def _live():
-        _tile_grads(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                    ki_ref, w_ref, tau_ref, tie_ref, scale, block_q, block_k,
-                    dk_acc=dk_acc, dv_acc=dv_acc)
+        _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                    scale, bits, dk_acc=dk_acc, dv_acc=dv_acc)
 
     @pl.when(i == num_q - 1)
     def _finalize():
@@ -454,128 +570,160 @@ def _live_key_map(block_q, block_k):
     return lambda j, i: jnp.minimum(j, _last_key_tile(i, block_q, block_k))
 
 
+def _index_specs(T, HI, DI, blocks):
+    """What ``dsa_index`` and ``dsa_mask`` share: the blocks of qi
+    (transposed to [B, HI, T, DI]), ki and w, a [B, T, 1] column's, and the
+    mask's whole row of key tiles, which stays in VMEM over the query tiles
+    that share its word rows."""
+    block_q, block_k, _, num_k = _tiles(T, blocks)
+    live = _live_key_map(block_q, block_k)
+    _, rows, block = mask_layout(T, block_q)
+    return ([pl.BlockSpec((1, HI, block_q, DI), lambda b, i, j: (b, 0, i, 0)),
+             pl.BlockSpec((1, block_k, DI), lambda b, i, j: (b, live(j, i), 0)),
+             pl.BlockSpec((1, block_q, HI), lambda b, i, j: (b, i, 0))],
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, num_k, block, block_k),
+                         lambda b, i, j: (b, 0, (i * rows) // block, 0)))
+
+
 def dsa_index(qi, ki, w, topk: int, blocks, interpret: bool = False):
-    """-> (tau, tie) [B, T] int32: each row's threshold as a sortable key
-    and the last position a key equal to it is taken at. qi [B, T, HI, DI],
-    ki [B, T, DI], w [B, T, HI] float32."""
+    """-> (tau, tie [B, T] int32: each row's threshold as a sortable key and
+    the last position a key equal to it is taken at; chosen [B, T] int32: the
+    pairs the row chose; kth [B, T] float32: its smallest chosen score; mask:
+    the choice in words, ``mask_layout``). qi [B, T, HI, DI], ki [B, T, DI],
+    w [B, T, HI] float32."""
     from .kernel_dispatch import dsa_vmem_bytes
     B, T, HI, DI = qi.shape
     block_q, block_k, num_q, num_k = _tiles(T, blocks)
-    live = _live_key_map(block_q, block_k)
-    col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    in_specs, col, mask_spec = _index_specs(T, HI, DI, blocks)
     # the scope closes before the kernel's call: one that held it would
     # rename the instruction (docs/observability.md)
     with jax.named_scope("ds.dsa.select"):
         qit = qi.transpose(0, 2, 1, 3)
-    tau, tie = pl.pallas_call(
+    column = jax.ShapeDtypeStruct((B, T, 1), jnp.int32)
+    tau, tie, cnt, kth, mask = pl.pallas_call(
         functools.partial(_index_kernel, topk=topk, block_q=block_q, block_k=block_k,
-                          pos_bits=max(1, int(T - 1).bit_length())),
+                          pos_bits=max(1, int(T - 1).bit_length()),
+                          bits=mask_layout(T, block_q)[0]),
         grid=(B, num_q, num_k),
-        in_specs=[pl.BlockSpec((1, HI, block_q, DI), lambda b, i, j: (b, 0, i, 0)),
-                  pl.BlockSpec((1, block_k, DI), lambda b, i, j: (b, live(j, i), 0)),
-                  pl.BlockSpec((1, block_q, HI), lambda b, i, j: (b, i, 0))],
-        out_specs=[col, col],
-        out_shape=[jax.ShapeDtypeStruct((B, T, 1), jnp.int32)] * 2,
+        in_specs=in_specs,
+        out_specs=[col, col, col, col, mask_spec],
+        out_shape=[column, column, column, jax.ShapeDtypeStruct((B, T, 1), jnp.float32),
+                   jax.ShapeDtypeStruct(_mask_shape(B, T, blocks), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((num_k, block_q, block_k), jnp.int32)],
         compiler_params=_compiler_params(dsa_vmem_bytes(
             "index", 1, 1, DI, qi.dtype.itemsize, block_q, block_k, T, HI)),
         interpret=interpret,
         name="dsa_index",
     )(qit, ki, w)
-    return tau[..., 0], tie[..., 0]
+    return tau[..., 0], tie[..., 0], cnt[..., 0], kth[..., 0], mask
 
 
-def _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
+def dsa_mask(qi, ki, w, tau, tie, blocks, interpret: bool = False):
+    """``dsa_index``'s mask again from its ``tau`` and ``tie``: the scores
+    once, no selection (a backward whose layer did not keep the mask)."""
+    from .kernel_dispatch import dsa_vmem_bytes
+    B, T, HI, DI = qi.shape
+    block_q, block_k, num_q, num_k = _tiles(T, blocks)
+    in_specs, col, mask_spec = _index_specs(T, HI, DI, blocks)
+    return pl.pallas_call(
+        functools.partial(_mask_kernel, block_q=block_q, block_k=block_k,
+                          bits=mask_layout(T, block_q)[0]),
+        grid=(B, num_q, num_k),
+        in_specs=in_specs + [col, col],
+        out_specs=mask_spec,
+        out_shape=jax.ShapeDtypeStruct(_mask_shape(B, T, blocks), jnp.int32),
+        compiler_params=_compiler_params(dsa_vmem_bytes(
+            "mask", 1, 1, DI, qi.dtype.itemsize, block_q, block_k, T, HI)),
+        interpret=interpret,
+        name="dsa_mask",
+    )(qi.transpose(0, 2, 1, 3), ki, w, tau[..., None], tie[..., None])
+
+
+def _mask_spec(T, blocks, q_at, k_at):
+    """A (query, key) tile's words: grid step (b, x, y) -> the block that
+    holds query tile ``q_at(x, y)``'s rows of key tile ``k_at(x, y)``."""
+    block_q, block_k, _, _ = _tiles(T, blocks)
+    _, rows, block = mask_layout(T, block_q)
+    return pl.BlockSpec((1, 1, block, block_k),
+                        lambda b, x, y: (b, k_at(x, y), (q_at(x, y) * rows) // block, 0))
+
+
+def _dsa_fwd(q, k, v, mask, scale, blocks, interpret):
     """-> (o [B, T, H, D], lse [B, KV, q tiles, 1, G * BQ]: a tile's rows
-    g-major as its folded queries are, chosen [B, T], kth [B, T])."""
+    g-major as its folded queries are)."""
     from .kernel_dispatch import dsa_vmem_bytes
     B, T, H, D = q.shape
-    KV, HI, DI = k.shape[2], qi.shape[2], qi.shape[3]
+    KV = k.shape[2]
     G = H // KV
     block_q, block_k, num_q, num_k = _tiles(T, blocks)
     live = _live_key_map(block_q, block_k)
     qg, kt, vt = _group(q, k, v)
     q_spec = pl.BlockSpec((1, KV, G, block_q, D), lambda b, i, j: (b, 0, 0, i, 0))
     kv_spec = pl.BlockSpec((1, KV, block_k, D), lambda b, i, j: (b, 0, live(j, i), 0))
-    col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    out, lse, cnt, kth = pl.pallas_call(
+    out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-                          num_k=num_k),
+                          num_k=num_k, bits=mask_layout(T, block_q)[0]),
         grid=(B, num_q, num_k),
         in_specs=[q_spec, kv_spec, kv_spec,
-                  pl.BlockSpec((1, HI, block_q, DI), lambda b, i, j: (b, 0, i, 0)),
-                  pl.BlockSpec((1, block_k, DI), lambda b, i, j: (b, live(j, i), 0)),
-                  pl.BlockSpec((1, block_q, HI), lambda b, i, j: (b, i, 0)),
-                  col, col],
+                  _mask_spec(T, blocks, lambda i, j: i, lambda i, j: live(j, i))],
         out_specs=[q_spec,
                    pl.BlockSpec((1, KV, 1, 1, G * block_q),
-                                lambda b, i, j: (b, 0, i, 0, 0)),
-                   col, col],
+                                lambda b, i, j: (b, 0, i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(qg.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, KV, num_q, 1, G * block_q), jnp.float32),
-                   jax.ShapeDtypeStruct((B, T, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((B, T, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, KV, num_q, 1, G * block_q), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((KV, G * block_q, D), jnp.float32),
                         pltpu.VMEM((KV, G * block_q, STAT_LANES), jnp.float32),
-                        pltpu.VMEM((KV, G * block_q, STAT_LANES), jnp.float32),
-                        pltpu.VMEM((block_q, STAT_LANES), jnp.int32),
-                        pltpu.VMEM((block_q, STAT_LANES), jnp.float32)],
+                        pltpu.VMEM((KV, G * block_q, STAT_LANES), jnp.float32)],
         compiler_params=_compiler_params(dsa_vmem_bytes(
-            "fwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T, HI)),
+            "fwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T)),
         interpret=interpret,
         name="dsa_fwd",
-    )(qg, kt, vt, qi.transpose(0, 2, 1, 3), ki, w, tau[..., None], tie[..., None])
-    return _ungroup(out), lse, cnt[..., 0], kth[..., 0]
+    )(qg, kt, vt, mask)
+    return _ungroup(out), lse
 
 
-def _dsa_bwd(res, g_out, scale, blocks, interpret):
+def _dsa_bwd(q, k, v, mask, o, lse, g_out, scale, blocks, interpret):
     from .kernel_dispatch import dsa_vmem_bytes
-    q, k, v, qi, ki, w, tau, tie, o, lse = res
     B, T, H, D = q.shape
-    KV, HI, DI = k.shape[2], qi.shape[2], qi.shape[3]
+    KV = k.shape[2]
     G = H // KV
     block_q, block_k, num_q, num_k = _tiles(T, blocks)
     live = _live_key_map(block_q, block_k)
+    static = dict(scale=scale, block_q=block_q, block_k=block_k,
+                  bits=mask_layout(T, block_q)[0])
     params = _compiler_params(dsa_vmem_bytes(
-        "bwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T, HI))
+        "bwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T))
     qg, kt, vt = _group(q, k, v)
     dog, _, _ = _group(g_out, k, v)
     og, _, _ = _group(o, k, v)
     delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
 
     # both kernels walk TRANSPOSED tiles: what belongs to a query enters as
-    # rows, lane-dense in HBM: [B, (KV,) q tiles, 1, .], g-major inside a
-    # tile like the folded q rows
+    # rows, lane-dense in HBM: [B, KV, q tiles, 1, .], g-major inside a tile
+    # like the folded q rows
     def rows(x):    # [B, KV, G, T] -> [B, KV, q tiles, 1, G * BQ]
         return (x.reshape(B, KV, G, num_q, block_q).transpose(0, 1, 3, 2, 4)
                 .reshape(B, KV, num_q, 1, G * block_q))
 
-    operands = (qg, kt, vt, dog, lse, rows(delta),
-                qi.transpose(0, 2, 1, 3), ki, w.transpose(0, 2, 1),
-                tau.reshape(B, num_q, 1, block_q), tie.reshape(B, num_q, 1, block_q))
+    operands = (qg, kt, vt, dog, lse, rows(delta), mask)
 
     def specs(q_at, k_at):
-        """The eleven operands' blocks; ``q_at`` / ``k_at`` (b, x, y) -> the
-        query and the key tile of grid step (x, y)."""
+        """The seven operands' blocks; ``q_at`` / ``k_at`` (x, y) -> the
+        query and the key tile of grid step (b, x, y)."""
         q_spec = pl.BlockSpec((1, KV, G, block_q, D),
                               lambda b, x, y: (b, 0, 0, q_at(x, y), 0))
         kv_spec = pl.BlockSpec((1, KV, block_k, D),
                                lambda b, x, y: (b, 0, k_at(x, y), 0))
         r_spec = pl.BlockSpec((1, KV, 1, 1, G * block_q),
                               lambda b, x, y: (b, 0, q_at(x, y), 0, 0))
-        row = pl.BlockSpec((1, 1, 1, block_q), lambda b, x, y: (b, q_at(x, y), 0, 0))
-        return q_spec, kv_spec, [
-            q_spec, kv_spec, kv_spec, q_spec, r_spec, r_spec,
-            pl.BlockSpec((1, HI, block_q, DI), lambda b, x, y: (b, 0, q_at(x, y), 0)),
-            pl.BlockSpec((1, block_k, DI), lambda b, x, y: (b, k_at(x, y), 0)),
-            pl.BlockSpec((1, HI, block_q), lambda b, x, y: (b, 0, q_at(x, y))),
-            row, row]
+        return q_spec, kv_spec, [q_spec, kv_spec, kv_spec, q_spec, r_spec, r_spec,
+                                 _mask_spec(T, blocks, q_at, k_at)]
 
     # dQ: the key sweep innermost, clamped to the causal range
     q_spec, _, in_specs = specs(lambda i, j: i, lambda i, j: live(j, i))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
-                          num_k=num_k),
+        functools.partial(_dq_kernel, num_k=num_k, **static),
         grid=(B, num_q, num_k),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -591,8 +739,7 @@ def _dsa_bwd(res, g_out, scale, blocks, interpret):
     _, kv_spec, in_specs = specs(lambda j, i: jnp.maximum(i, (j * block_k) // block_q),
                                  lambda j, i: j)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, num_q=num_q),
+        functools.partial(_dkdv_kernel, num_q=num_q, **static),
         grid=(B, num_k, num_q),
         in_specs=in_specs,
         out_specs=[kv_spec, kv_spec],
@@ -607,26 +754,32 @@ def _dsa_bwd(res, g_out, scale, blocks, interpret):
     return _ungroup(dq), dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _dsa_attend(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
-    o, _, chosen, kth = _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret)
-    return o, chosen, kth
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _dsa_attend(q, k, v, mask, again, scale, blocks, interpret):
+    """Attention under ``mask``. ``again``: None where the backward is handed
+    the forward's mask (it is a residual), else what ``dsa_mask`` makes it
+    again from (qi, ki, w, tau, tie), and the mask is no residual."""
+    return _dsa_fwd(q, k, v, mask, scale, blocks, interpret)[0]
 
 
-def _attend_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret):
-    o, lse, chosen, kth = _dsa_fwd(q, k, v, qi, ki, w, tau, tie, scale, blocks,
-                                   interpret)
+def _attend_fwd(q, k, v, mask, again, scale, blocks, interpret):
+    o, lse = _dsa_fwd(q, k, v, mask, scale, blocks, interpret)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
-    return (o, chosen, kth), (q, k, v, qi, ki, w, tau, tie, o, lse)
+    return o, (q, k, v, mask if again is None else None, again, o, lse)
 
 
 def _attend_bwd(scale, blocks, interpret, res, g):
-    dq, dk, dv = _dsa_bwd(res, g[0], scale, blocks, interpret)
-    qi, ki, w, tau, tie = res[3:8]
+    q, k, v, mask, again, o, lse = res
+    if mask is None:
+        mask = dsa_mask(*again, blocks, interpret)
+    dq, dk, dv = _dsa_bwd(q, k, v, mask, o, lse, g, scale, blocks, interpret)
     no = lambda a: np.zeros(a.shape, jax.dtypes.float0)    # noqa: E731
-    return (dq, dk, dv, jnp.zeros_like(qi), jnp.zeros_like(ki), jnp.zeros_like(w),
-            no(tau), no(tie))
+    if again is not None:
+        qi, ki, w, tau, tie = again
+        again = (jnp.zeros_like(qi), jnp.zeros_like(ki), jnp.zeros_like(w), no(tau),
+                 no(tie))
+    return dq, dk, dv, no(mask), again
 
 
 _dsa_attend.defvjp(_attend_fwd, _attend_bwd)
@@ -635,18 +788,22 @@ _dsa_attend.defvjp(_attend_fwd, _attend_bwd)
 # a jit frame of its own, so that the kernels read ``%dsa_fwd.N`` /
 # ``%dsa_bwd_dq.N`` under ``jax.grad`` (ops/attention.py,
 # ``_flash_attention_call``); XLA inlines the call
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, interpret, keep_mask):
     qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
-    tau, tie = dsa_index(qi, ki, w, topk, blocks, interpret)
-    tau = checkpoint_name(tau, CHOICE_NAMES[0])
-    tie = checkpoint_name(tie, CHOICE_NAMES[1])
-    return _dsa_attend(q, k, v, qi, ki, w, tau, tie, scale, blocks, interpret)
+    tau, tie, chosen, kth, mask = dsa_index(qi, ki, w, topk, blocks, interpret)
+    tau = checkpoint_name(tau, remat.DSA_CHOICE[0])
+    tie = checkpoint_name(tie, remat.DSA_CHOICE[1])
+    mask = checkpoint_name(mask, remat.DSA_MASK if keep_mask
+                           else remat.DSA_MASK + remat.AGAIN)
+    o = _dsa_attend(q, k, v, mask, None if keep_mask else (qi, ki, w, tau, tie),
+                    scale, blocks, interpret)
+    return o, chosen, kth
 
 
 def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
                   blocks: Optional[tuple] = None, force_pallas: Optional[bool] = None,
-                  interpret: bool = False):
+                  interpret: bool = False, keep_mask: bool = True):
     """Attention of each query over the ``topk`` earlier keys its indexer
     scores highest. q [B, T, H, D], k/v [B, T, KV, D] (GQA native); the
     indexer's qi [B, T, HI, DI], its one key a token ki [B, T, DI] and head
@@ -656,7 +813,10 @@ def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
 
     On a TPU (or with ``interpret=True`` anywhere) the ``dsa_*`` kernels run
     at ``kernel_dispatch.choose_dsa_blocks``' tiles unless ``blocks`` pins
-    (query, key) tiles; elsewhere ``dense_dsa``."""
+    (query, key) tiles; elsewhere ``dense_dsa``. ``keep_mask``: whether the
+    backward is handed the forward's mask (named ``remat.DSA_MASK``: what a
+    caller inside a recomputation asks ``remat.keeps``) or makes it again
+    with ``dsa_mask``."""
     from . import kernel_dispatch as kd
     scale = float(scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]))
     w = w.astype(jnp.float32)
@@ -665,4 +825,5 @@ def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
     sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, True, None, None,
                       pattern=f"dsa{topk}")
     blocks = tuple(blocks or kd.choose_dsa_blocks(sig, qi.shape[2], qi.shape[3]))
-    return _dsa_call(q, k, v, qi, ki, w, int(topk), scale, blocks, interpret)
+    return _dsa_call(q, k, v, qi, ki, w, int(topk), scale, blocks, interpret,
+                     bool(keep_mask))

@@ -240,29 +240,35 @@ def choose_block_diffusion_blocks(sig: ShapeSig, leg: str,
 
 
 # Learned sparse attention (``ops/dsa_attention.py``, ``dsa_index`` /
-# ``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkdv``): a grid step holds ALL KV
-# heads of one (query, key) tile, so that the indexer's mask is made once for
-# them; the query tile folds with its group to MAX_ROWS rows a KV head, the
-# key tile is KEY_BLOCK. ``dsa_index`` keeps a query tile's scores over the
-# whole row in VMEM (seq * block_q * 4 bytes: 16 MiB at 32,768 keys and 128
-# queries), which caps the query tile; every call carries its own limit.
+# ``dsa_mask`` / ``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkdv``): a grid step
+# holds ALL KV heads of one (query, key) tile, so that the choice's words are
+# expanded once for them; the query tile folds with its group to MAX_ROWS rows
+# a KV head, the key tile is KEY_BLOCK. ``dsa_index`` keeps a query tile's
+# scores over the whole row in VMEM (seq * block_q * 4 bytes: 16 MiB at 32,768
+# keys and 128 queries), which caps the query tile; every call carries its
+# own limit.
 DSA_ROW_SCORES_CAP_BYTES = 32 * 2**20
 
 
 def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
                    itemsize: int, block_q: int, block_k: int, seq: int,
-                   index_heads: int) -> int:
+                   index_heads: int = 0) -> int:
     """Upper estimate of the VMEM one grid step of the ``dsa_*`` kernels
     holds, counted as ``flash_vmem_bytes`` counts: ``leg`` "index" (the
-    row's scores over ``seq`` keys), "fwd" or "bwd" (the larger of the
-    pair). The indexer's operands are counted at 128 lanes."""
+    row's scores over ``seq`` keys and its words), "mask" (the words and one
+    tile's scores), "fwd" or "bwd" (the larger of the pair; a tile's words
+    and their expansion). The indexer's operands are counted at 128 lanes."""
+    from .dsa_attention import mask_layout
+    word_rows = mask_layout(seq, block_q)[2]
+    if leg in ("index", "mask"):
+        scores = (2 * (index_heads * block_q + block_k) * 128 * itemsize   # qi, ki
+                  + 2 * 5 * block_q * 128 * 4               # w and the columns
+                  + 6 * block_q * block_k * 4               # the tile
+                  + 2 * word_rows * seq * 4)                # a row of key tiles' words
+        return scores + (seq * block_q * 4 if leg == "index" else 0)
     rows = kv_heads * group * block_q
     lanes = max(head_dim, 128)
-    index = (2 * (index_heads * block_q + block_k) * 128 * itemsize   # qi, ki
-             + 2 * 3 * block_q * 128 * 4                              # w, tau, tie
-             + 6 * block_q * block_k * 4)                             # the tile
-    if leg == "index":
-        return index + seq * block_q * 4 + 2 * 2 * block_q * 128 * 4
+    words = 2 * word_rows * block_k * 4 + 3 * block_q * block_k * 4
     q_blk = rows * lanes * itemsize
     kv_blk = kv_heads * block_k * lanes * itemsize
     stat = rows * 128 * 4
@@ -276,14 +282,15 @@ def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
                      2 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4)
         scratch = max(rows * lanes * 4, 2 * kv_heads * block_k * lanes * 4)
         temps = tile * (3 * 4 + 2 * itemsize)
-    return index + 2 * blocks + scratch + temps
+    return words + 2 * blocks + scratch + temps
 
 
 def choose_dsa_blocks(sig: ShapeSig, index_heads: int, index_dim: int) -> tuple:
-    """(block_q, block_k) of every ``dsa_*`` kernel of one call (the forward
-    makes the indexer's scores again at ``dsa_index``'s tile shape, so that
-    they are bit-equal): MAX_ROWS folded rows a KV head, fewer while a row
-    tile's scores pass DSA_ROW_SCORES_CAP_BYTES, and KEY_BLOCK keys."""
+    """(block_q, block_k) of every ``dsa_*`` kernel of one call (the mask's
+    words are laid out by them, and ``dsa_mask`` makes the indexer's scores
+    again at ``dsa_index``'s tile shape, so that they are bit-equal):
+    MAX_ROWS folded rows a KV head, fewer while a row tile's scores pass
+    DSA_ROW_SCORES_CAP_BYTES, and KEY_BLOCK keys."""
     group = max(1, sig.heads // sig.kv_heads)
     cap_q = max(128, MAX_ROWS // group)
     while cap_q > 8 and cap_q * sig.seq_k * 4 > DSA_ROW_SCORES_CAP_BYTES:

@@ -3,12 +3,16 @@
 ``remat: true`` with ``remat_policy: null`` (``models/llama.py``) and
 ``activation_checkpointing.checkpoint()`` with no policy recompute a layer
 in its backward but for the values named here. The attention kernels' output
-and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) are always kept, as is
-a learned sparse attention's choice (``ops/dsa_attention.py::CHOICE_NAMES``:
-two int32 a token and layer); the
+and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) are always kept, as are
+a learned sparse attention's thresholds (``DSA_CHOICE``: two int32 a token
+and layer); the
 candidates below are kept as far down the list as the chip has room for, so
-that the matmuls that make them run once a step:
+that what makes them runs once a step:
 
+    ds.dsa.mask      a learned sparse attention's choice as a bit mask
+                     (``ops/dsa_attention.py``: seq x seq / 8 bytes a row of
+                     the batch, 134 MB at 32,768); a layer without it makes
+                     the indexer's scores once more in its backward
     ds.moe.route     the router's logits, choice and weights where the choice
                      is a top-k of biased scores (~10 MB a layer: nearly free)
     ds.mixer.out     a mixer's output projection (``o_proj``, ``out_proj``)
@@ -22,7 +26,8 @@ that the matmuls that make them run once a step:
     ds.mixer.kernel  the convolution kernels' outputs
 
 The order is milliseconds of recomputation returned a byte kept, measured on
-a v5e (PERF.md §6, PR 41): a router's gather, top-k and float32 matmul for a
+a v5e (PERF.md §6, PR 41; the mask PR 46): a scoring pass of 15.8 ms for
+0.134 GB is 118 ms a GB; a router's gather, top-k and float32 matmul for a
 few megabytes; a bf16 matmul's output returns its contraction depth in FLOPs
 a byte, so a 4,096-deep output projection (SDAR's attention, Mamba) 17-23 ms
 a GB, gate / up and the 2,048-deep input projections 10-12; a 2,048-deep
@@ -50,8 +55,12 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.logging import logger
 from .attention import RESIDUAL_NAMES
-from .dsa_attention import CHOICE_NAMES
 
+# a learned sparse attention (ops/dsa_attention.py): its rows' thresholds,
+# kept with the kernels' residuals whatever the plan (two int32 a token), and
+# its choice as a bit mask, a candidate (T * T / 8 bytes a row of the batch)
+DSA_CHOICE = ("ds.dsa.tau", "ds.dsa.tie")
+DSA_MASK = "ds.dsa.mask"
 ROUTE = "ds.moe.route"
 MIXER_OUT = "ds.mixer.out"
 FFN_IN = "ds.ffn.in"
@@ -59,10 +68,9 @@ MIXER_IN = "ds.mixer.in"
 MIXER_OUT_NARROW = "ds.mixer.out.narrow"
 KERNEL_OUT = "ds.mixer.kernel"
 # the walk's order: ms of recomputation returned a byte, falling
-CANDIDATE_NAMES = (ROUTE, MIXER_OUT, FFN_IN, MIXER_IN, MIXER_OUT_NARROW, KERNEL_OUT)
-# a learned sparse attention's choice (its rows' thresholds) is kept with the
-# kernels' residuals, whatever the plan: it is made once a step
-KEPT_NAMES = RESIDUAL_NAMES + CHOICE_NAMES + CANDIDATE_NAMES
+CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, FFN_IN, MIXER_IN, MIXER_OUT_NARROW,
+                   KERNEL_OUT)
+KEPT_NAMES = RESIDUAL_NAMES + DSA_CHOICE + CANDIDATE_NAMES
 
 # The step's own temporaries, in one place. Compiled for a described v5e
 # (tests/unit/ops/test_tpu_aot_compile.py, ..._mla.py; PR 41) the four
@@ -111,12 +119,17 @@ def keeping(names: Optional[Tuple[str, ...]]):
         _KEEPING.reset(token)
 
 
+def keeps(name: str) -> bool:
+    """Whether the layer being traced (``keeping``) keeps ``name``."""
+    kept = _KEEPING.get()
+    return kept is None or name in kept
+
+
 def keep(x, name: str):
     """``x`` under ``name``, which a recomputation's policy may keep; under
     ``name + AGAIN``, which none does, where the layer being traced
     (``keeping``) does not keep it."""
-    kept = _KEEPING.get()
-    return checkpoint_name(x, name if kept is None or name in kept else name + AGAIN)
+    return checkpoint_name(x, name if keeps(name) else name + AGAIN)
 
 
 def choose_kept(available: Optional[int], prices: Prices,

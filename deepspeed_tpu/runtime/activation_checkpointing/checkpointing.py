@@ -108,7 +108,7 @@ def _resolve_policy(function=None, args=(), kwargs=None):
             rows=leaves[0].shape[0] if leaves and leaves[0].ndim else 1,
             layer_input_bytes=sum(a.size * a.dtype.itemsize for a in leaves))
         names = plan[0] if plan else names
-    return jax.checkpoint_policies.save_only_these_names(*names, *remat.CHOICE_NAMES)
+    return jax.checkpoint_policies.save_only_these_names(*names, *remat.DSA_CHOICE)
 
 
 def _partition_arg(x):

@@ -100,8 +100,9 @@ def _as_apply_fns(model):
             # their ``t``
             # "dsa_stats" for learned sparse attention: ``chosen_pairs``
             # (int32) and ``causal_pairs`` come back a layer (their sum over
-            # a deep model passes 32 bits: the host adds them up) and
-            # ``kth_score_mean`` as the layers' mean
+            # a deep model passes 32 bits: the host adds them up),
+            # ``kth_score_mean`` as the layers' mean and ``masks_kept`` as
+            # their sum
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
                                              "mla_stats", "diffusion_stats",
@@ -140,13 +141,13 @@ def _as_apply_fns(model):
                     mods.get("diffusion_stats", {}))[0]:
                 stats["diffusion_" + path[-1].key] = jnp.sum(leaf)
             dsa = jax.tree_util.tree_flatten_with_path(mods.get("dsa_stats", {}))[0]
-            for name in ("chosen_pairs", "causal_pairs", "kth_score_mean"):
+            for name, reduce in (("chosen_pairs", None), ("causal_pairs", None),
+                                 ("kth_score_mean", jnp.mean), ("masks_kept", jnp.sum)):
                 sown = [leaf.reshape(-1) for path, leaf in dsa
                         if path[-1].key == name]
                 if sown:
                     sown = jnp.concatenate(sown)
-                    stats["dsa_" + name] = (jnp.mean(sown) if name == "kth_score_mean"
-                                            else sown)
+                    stats["dsa_" + name] = reduce(sown) if reduce else sown
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -2204,8 +2205,11 @@ class DeepSpeedTpuEngine:
         step not yet published, as host numbers: ``chosen_pairs`` and
         ``causal_pairs`` (summed over the layers; the first exact, and a
         layer at a time as ``chosen_pairs_by_layer``),
-        ``chosen_share`` and ``kth_score_mean`` (the mean over rows and
-        layers of a row's smallest chosen score). Waits for that step, as
+        ``chosen_share``, ``kth_score_mean`` (the mean over rows and
+        layers of a row's smallest chosen score) and ``masks_kept`` (the
+        layers whose backward read the forward's mask, ``ds.dsa.mask``, kept
+        by ``ops/remat.py``'s plan; the others made it again with one more
+        pass of the indexer's scores). Waits for that step, as
         :meth:`moe_stats`; ``None`` for a model without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("dsa_"))
         if not stats:
@@ -2216,7 +2220,8 @@ class DeepSpeedTpuEngine:
                 "chosen_pairs_by_layer": [int(n) for n in
                                           np.ravel(stats["dsa_chosen_pairs"])],
                 "chosen_share": chosen / max(causal, 1.0),
-                "kth_score_mean": float(np.mean(stats["dsa_kth_score_mean"]))}
+                "kth_score_mean": float(np.mean(stats["dsa_kth_score_mean"])),
+                "masks_kept": int(np.sum(stats["dsa_masks_kept"]))}
 
     def _newest_stats(self, wanted):
         if not self._moe_pending:

@@ -84,6 +84,24 @@ def _program(cfg, params, ids):
     return float(loss), grads, np.asarray(logits, np.float32), sown
 
 
+def _kernel_calls(closed_jaxpr):
+    """{kernel name: calls} of a traced program, a shared sub-program counted
+    each time it is called."""
+    from jax._src import core
+    calls = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                calls[name] = calls.get(name, 0) + 1
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(closed_jaxpr.jaxpr)
+    return calls
+
+
 def _rel(a, b):
     return float(np.linalg.norm(np.asarray(a, np.float32) - b) / np.linalg.norm(b))
 
@@ -235,6 +253,53 @@ def test_the_kernels_under_the_model_and_its_recomputation_make_the_choice_once(
         assert f"name={kernel}" in text, kernel
     tokens = ROWS * 2 * SEQ
     assert kept_residual_bytes(traced.jaxpr) == 2 * tokens * (8 * (16 * 4 + 4) + 8)
+    # no memory report, no mask kept: each layer's backward makes it again from
+    # the kept thresholds, and the selection is not in the recomputed forward
+    assert _kernel_calls(traced.jaxpr) == {
+        "dsa_index": 2, "dsa_fwd": 2, "dsa_mask": 2, "dsa_bwd_dq": 2, "dsa_bwd_dkdv": 2}
+
+
+@pytest.mark.parametrize("masks", [0, 1, 2])
+def test_the_mask_is_the_walks_first_candidate_and_a_layer_without_it_rebuilds(small, masks):
+    """A chip with room for ``masks`` of the two layers' masks and a half
+    (``ops/remat.py``'s walk takes ``ds.dsa.mask`` first, layer by layer, and
+    stops at the first that does not fit): those layers' backward reads the
+    forward's words, the others call ``dsa_mask`` once; the layers sow how
+    many kept it, the kept bytes rise by seq x seq / 8 a row and kept mask
+    less its thresholds (nothing reads them then: they are not in the
+    program), and the gradients are the same to the bit whatever was kept."""
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.ops import remat
+    cfg = dataclasses.replace(small["cfg"], attn_impl="flash", remat=True)
+    ids = jnp.tile(small["ids"], (1, 2))
+    model = llama.LlamaForCausalLM(cfg)
+    fn = jax.jit(jax.value_and_grad(lambda p: model.apply({"params": p}, ids, labels=ids)))
+    _, want = fn(small["params"])                           # the CPU: nothing to spend
+    tokens, seq = ROWS * 2 * SEQ, 2 * SEQ
+    always, mask = 2 * tokens * (8 * (16 * 4 + 4) + 8), ROWS * seq * seq // 8
+    patch = pytest.MonkeyPatch()
+    try:
+        patch.setattr(remat, "step_reserve_bytes", lambda *a: 0)
+        patch.setattr(remat, "device_memory",
+                      lambda: (10**9, 10**9 - always - (2 * masks + 1) * mask // 2))
+        remat.forget_plans()
+        again = jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p}, ids, labels=ids)))
+        _, grads = again(small["params"])
+        traced = again.trace(small["params"])
+        plan = next(iter(remat._PLANS.values()))
+        sown = jax.jit(lambda p: model.apply({"params": p}, ids, mutable=["dsa_stats"]))(
+            small["params"])[1]["dsa_stats"]["model"]
+    finally:
+        patch.undo()
+        remat.forget_plans()
+    assert [names[2:] for names in plan] == [(remat.DSA_MASK, )] * masks + [()] * (2 - masks)
+    calls = _kernel_calls(traced.jaxpr)
+    assert calls.get("dsa_mask", 0) == 2 - masks and calls["dsa_index"] == 2
+    assert sum(int(sown[f"layers_{i}"]["self_attn"]["masks_kept"]) for i in range(2)) == masks
+    assert kept_residual_bytes(traced.jaxpr) == always + masks * (mask - tokens * 8)
+    for g, w in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_policy_reads_the_catalogs_keys_and_the_cells_file():

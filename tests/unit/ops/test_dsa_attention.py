@@ -3,7 +3,9 @@
 form by hand (scores, ``lax.top_k``, a mask): the forward and every gradient,
 the count a row chose and its smallest chosen score, a row with fewer than
 ``topk`` candidates, ties at the threshold, a tile with no chosen pair, a
-group of eight; and the blocks ``kernel_dispatch`` gives the call."""
+group of eight; the choice as ``dsa_index`` packs it (a bit mask, read back in
+both orientations) and as ``dsa_mask`` makes it again; and the blocks
+``kernel_dispatch`` gives the call."""
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +70,7 @@ def test_the_choice_is_lax_top_ks_and_ties_go_to_the_lower_position():
     the very keys ``lax.top_k`` returns (the lower index first among equals)."""
     q, k, v, qi, ki, w = _operands(8, 1, seed=1, rows=2)
     qi, ki, w = jnp.round(qi), jnp.round(ki), jnp.round(2 * w)
-    tau, tie = dsa.dsa_index(qi, ki, w, TOPK, (64, 128), interpret=True)
+    tau, tie, *_ = dsa.dsa_index(qi, ki, w, TOPK, (64, 128), interpret=True)
     scores = dsa.index_scores(qi, ki, w)
     causal = np.tril(np.ones((T, T), bool))
     assert len(np.unique(np.asarray(scores)[0, 200, :201])) < 40   # they do collide
@@ -85,6 +87,105 @@ def test_the_choice_is_lax_top_ks_and_ties_go_to_the_lower_position():
     out, chosen, _ = _kernels()(q, k, v, qi, ki, w)
     np.testing.assert_array_equal(chosen, mine.sum(-1))
     np.testing.assert_allclose(out, _dense()(q, k, v, qi, ki, w)[0], atol=2e-5, rtol=2e-5)
+
+
+def _colliding(rows=2):
+    """Operands whose scores collide (small whole numbers): the tie path."""
+    q, k, v, qi, ki, w = _operands(8, 1, seed=1, rows=rows)
+    return q, k, v, jnp.round(qi), jnp.round(ki), jnp.round(2 * w)
+
+
+def _unpacked(mask, blocks):
+    """The whole choice [B, T, T] bool from ``dsa_index``'s words, by the
+    layout ``mask_layout`` documents: bit ``t % bits`` of word [b, s // BK,
+    t // bits, s % BK]."""
+    bits = dsa.mask_layout(T, blocks[0])[0]
+    t, s = np.arange(T)[:, None], np.arange(T)[None, :]
+    words = np.asarray(mask)[:, s // blocks[1], t // bits, s % blocks[1]]
+    return ((words >> (t % bits)) & 1).astype(bool)
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["forward", "transposed"])
+@pytest.mark.parametrize("case", ["ties", "short_rows"])
+def test_the_packed_mask_expands_to_the_choice_in_both_orientations(case, transposed):
+    """``dsa_index``'s words, expanded a tile at a time as ``dsa_fwd`` does
+    and transposed as the backward pair does, are ``_chosen`` under its
+    ``tau`` and ``tie``: where scores collide and the tie bound decides, and
+    in the first query tile, whose rows 0-30 have fewer than ``topk``
+    candidates and take them all. (Whole-number operands: the host's scores
+    are then the kernel's to the bit.)"""
+    blocks = (64, 128)
+    _, _, _, qi, ki, w = _colliding()
+    tau, tie, _, _, mask = dsa.dsa_index(qi, ki, w, TOPK, blocks, interpret=True)
+    assert (np.asarray(tie) < T).any()
+    key = dsa._sortable(dsa.index_scores(qi, ki, w))
+    bits, rows, block = dsa.mask_layout(T, blocks[0])
+    assert (bits, rows, block) == (32, 2, 8) and mask.shape == (2, 2, T // 32, 128)
+    tiles = [(0, 0)] if case == "short_rows" else [(1, 0), (3, 0), (3, 1), (2, 1)]
+    for i, j in tiles:
+        qs, ks = slice(i * 64, i * 64 + 64), slice(j * 128, j * 128 + 128)
+        q_pos, k_pos = dsa._positions(i, j, *blocks)
+        want = dsa._chosen(key[:, qs, ks], tau[:, qs, None], tie[:, qs, None], q_pos, k_pos)
+        for b in range(2):
+            got = dsa._expand(mask[b, j, i * rows:(i + 1) * rows], bits)
+            if case == "short_rows":
+                np.testing.assert_array_equal(got[:TOPK - 1] != 0, (k_pos <= q_pos)[:TOPK - 1])
+            got, ref = (got.T, want[b].T) if transposed else (got, want[b])
+            np.testing.assert_array_equal(got != 0, ref)
+    # and the whole array, dead tiles zero
+    causal = np.tril(np.ones((T, T), bool))
+    whole = dsa._chosen(key, tau[..., None], tie[..., None],
+                        jnp.arange(T)[:, None], jnp.arange(T)[None, :])
+    np.testing.assert_array_equal(_unpacked(mask, blocks), np.asarray(whole) & causal)
+
+
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 256), (256, 128)],
+                         ids=["two_word_rows", "four_word_rows", "a_block_a_tile"])
+@pytest.mark.parametrize("case", ["ties", "seeded"])
+def test_the_index_kernels_count_and_smallest_score_are_the_dense_forms(case, blocks):
+    """What ``dsa_stats`` reads comes from ``dsa_index`` alone: the pairs a
+    row chose and its smallest chosen score equal ``dense_dsa``'s, at every
+    layout of the words (2 and 4 word rows of a block of 8, a block a tile)."""
+    a = _colliding() if case == "ties" else _operands(8, 1, rows=2)
+    _, _, chosen, kth, mask = dsa.dsa_index(*a[3:], TOPK, blocks, interpret=True)
+    _, want_chosen, want_kth = _dense()(*a)
+    np.testing.assert_array_equal(chosen, want_chosen)
+    np.testing.assert_array_equal(chosen[0], np.minimum(np.arange(T) + 1, TOPK))
+    np.testing.assert_allclose(kth, want_kth, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(_unpacked(mask, blocks).sum(-1), want_chosen)
+    out, same, kth_out = _kernels(blocks=blocks)(*a)
+    np.testing.assert_array_equal(same, chosen)
+    np.testing.assert_array_equal(kth_out, kth)
+    np.testing.assert_allclose(out, _dense()(*a)[0], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads,kv", [(8, 1), (4, 2)], ids=["group8", "group2"])
+@pytest.mark.parametrize("case", ["ties", "seeded"])
+def test_a_backward_that_makes_the_mask_again_is_the_kept_paths_bit_for_bit(case, heads, kv):
+    """``keep_mask=False`` (a recomputed layer that did not keep
+    ``ds.dsa.mask``): the backward's ``dsa_mask`` gives ``dsa_index``'s very
+    words from its ``tau`` and ``tie``, and dQ, dK, dV are the kept path's
+    to the bit; the traced gradient holds one ``dsa_mask`` and no second
+    ``dsa_index``."""
+    a = _operands(heads, kv, seed=4, rows=2)
+    if case == "ties":
+        a[3:] = _colliding()[3:]
+    tau, tie, _, _, mask = dsa.dsa_index(*a[3:], TOPK, (64, 128), interpret=True)
+    np.testing.assert_array_equal(
+        dsa.dsa_mask(*a[3:], tau, tie, (64, 128), interpret=True), mask)
+
+    def loss(keep):
+        return lambda *x: jnp.sum(jnp.square(dsa.dsa_attention(
+            *x, TOPK, blocks=(64, 128), interpret=True, keep_mask=keep)[0]))
+
+    kept = jax.grad(loss(True), argnums=(0, 1, 2))(*a)
+    again = jax.grad(loss(False), argnums=(0, 1, 2))(*a)
+    for x, y in zip(kept, again):
+        np.testing.assert_array_equal(x, y)
+    for keep, masks in ((True, 0), (False, 1)):
+        text = str(jax.make_jaxpr(jax.grad(loss(keep), argnums=(0, 1, 2)))(*a))
+        assert text.count("name=dsa_mask") == masks, keep
+        assert text.count("name=dsa_index") == 1, keep
 
 
 def test_a_tile_with_no_chosen_pair_is_skipped_and_changes_nothing():
@@ -133,8 +234,13 @@ def test_blocks_and_vmem_of_the_cells_call():
     assert kd.choose_dsa_blocks(sig, 16, 64) == (128, 512)
     index = kd.dsa_vmem_bytes("index", 1, 1, 64, 2, 128, 512, 32768, 16)
     assert 16 * 2**20 < index < 24 * 2**20
+    # the words: 32 queries each, 4 word rows a query tile in blocks of 8,
+    # 1 MiB a row of key tiles in VMEM, T * T / 8 bytes in HBM
+    assert dsa.mask_layout(32768, 128) == (32, 4, 8)
+    assert np.prod(dsa._mask_shape(1, 32768, (128, 512))) * 4 == 32768**2 // 8
+    assert index - kd.dsa_vmem_bytes("mask", 1, 1, 64, 2, 128, 512, 32768, 16) == 16 * 2**20
     for leg in ("fwd", "bwd"):
-        need = kd.dsa_vmem_bytes(leg, 4, 8, 128, 2, 128, 512, 32768, 16)
+        need = kd.dsa_vmem_bytes(leg, 4, 8, 128, 2, 128, 512, 32768)
         assert kd.VMEM_SCOPED_DEFAULT_BYTES < need < kd.FUSED_VMEM_CAP_BYTES, (leg, need)
     mha = kd.make_sig((1, 32768, 8, 128), 8, 32768, "bfloat16", True, None, None)
     assert kd.choose_dsa_blocks(mha, 16, 64) == (256, 512)

@@ -80,19 +80,73 @@ def step():
 
 def test_every_layer_chooses_and_attends_once_a_step(step):
     """Under whole-layer recomputation each layer's ``dsa_index`` and
-    ``dsa_fwd`` run once (the choice, the output and the log-sum-exp are kept
-    by name for the recomputed layer's backward) and its backward is the one
-    pair; no ``flash_*`` call is in the program."""
+    ``dsa_fwd`` run once (the thresholds, the output and the log-sum-exp are
+    kept by name for the recomputed layer's backward) and its backward is the
+    one pair; with the described chip's room both layers keep their mask
+    (``ds.dsa.mask``, first in the walk: no ``dsa_mask`` call makes it
+    again) and the kept bytes hold it; no ``flash_*`` call is in the
+    program."""
     from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.ops import remat
     names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
              for line in mla.custom_calls(step["compiled"])]
     kernels = {n: names.count(n) for n in set(names) if not n.startswith("ragged-dot")}
     for name in ("dsa_index", "dsa_fwd", "dsa_bwd_dq", "dsa_bwd_dkdv"):
         assert kernels.pop(name) == 2, (name, names)
     assert all(n.startswith("moe_rows_to_tokens") for n in kernels), names
-    tokens = step["rows"] * step["seq"]
-    always = 2 * tokens * (HEADS * (D * 2 + 4) + 2 * 4)    # o, lse; tau, tie
-    assert kept_residual_bytes(step["traced"].jaxpr) >= always
+    rows, seq = step["rows"], step["seq"]
+    plan = next(iter(remat._PLANS.values()))
+    assert [names[2] for names in plan] == [remat.DSA_MASK] * 2, plan
+    # o, lse; a layer that keeps its mask has no reader for tau and tie
+    always = 2 * rows * seq * HEADS * (D * 2 + 4)
+    masks = 2 * rows * seq * seq // 8
+    kept = kept_residual_bytes(step["traced"].jaxpr)
+    assert kept >= always + masks
+    assert kept - kept_residual_bytes(
+        step["traced"].jaxpr, tuple(n for n in remat.KEPT_NAMES if n != remat.DSA_MASK)
+    ) == masks
+
+
+def kernel_operands(closed_jaxpr):
+    """{kernel name: the shapes of its operands, as ``bf16[1,4,...]``} of a
+    traced program's Pallas calls."""
+    from jax._src import core
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = [v.aval.str_short(short_dtypes=True)
+                                             for v in eqn.invars]
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(closed_jaxpr.jaxpr)
+    return found
+
+
+def test_the_attention_kernels_take_the_mask_and_none_of_the_indexers_operands(step):
+    """The indexer's scores are made in ``dsa_index`` alone: ``dsa_fwd`` takes
+    q, k, v and the words ``i32[rows, key tiles, seq / 32, 512]``, the pair
+    those with dO, the log-sum-exp and delta: no qi ``bf16[rows, 16, seq,
+    64]``, ki, w or threshold; and in the compiled step ``dsa_index`` writes
+    the words after its first results (``tau``, ``tie``: where
+    ``benchmark/dsa_cost.py`` reads the call's shape)."""
+    rows, seq, group = step["rows"], step["seq"], HEADS // KV
+    words = f"i32[{rows},{seq // 512},{seq // 32},512]"
+    q = f"bf16[{rows},{KV},{group},{seq},{D}]"
+    kv = f"bf16[{rows},{KV},{seq},{D}]"
+    stat = f"f32[{rows},{KV},{seq // 128},1,{group * 128}]"
+    operands = kernel_operands(step["traced"].jaxpr)
+    assert operands["dsa_fwd"] == [q, kv, kv, words]
+    assert operands["dsa_bwd_dq"] == operands["dsa_bwd_dkdv"] == [
+        q, kv, kv, q, stat, stat, words]
+    assert operands["dsa_index"] == [f"bf16[{rows},{HI},{seq},{DI}]",
+                                     f"bf16[{rows},{seq},{DI}]", f"f32[{rows},{seq},{HI}]"]
+    results = next(line for line in mla.custom_calls(step["compiled"])
+                   if "%dsa_index" in line.split(" = ")[0]).split("custom-call(")[0]
+    assert (results.index(f"s32[{rows},{seq},1]")
+            < results.index(f"s32[{rows},{seq // 512},{seq // 32},512]"))
 
 
 def test_the_calls_have_the_blocks_dispatch_chose_and_their_own_vmem(step):
@@ -110,7 +164,8 @@ def test_the_calls_have_the_blocks_dispatch_chose_and_their_own_vmem(step):
     assert grouped in fwd and f"f32[{rows},{KV},{seq // 128},1,{HEADS // KV * 128}]" in fwd
     assert grouped in calls["dsa_bwd_dq"].split("custom-call(")[0]
     assert f"bf16[{rows},{KV},{seq},{D}]" in calls["dsa_bwd_dkdv"].split("custom-call(")[0]
-    for name, leg in (("dsa_index", "index"), ("dsa_fwd", "fwd"), ("dsa_bwd_dq", "bwd")):
+    for name, leg in (("dsa_index", "index"), ("dsa_fwd", "fwd"), ("dsa_bwd_dq", "bwd"),
+                      ("dsa_bwd_dkdv", "bwd")):
         need = kd.dsa_vmem_bytes(leg, *((1, 1, DI) if leg == "index" else (KV, HEADS // KV, D)),
                                  2, 128, 512, seq, HI)
         asked = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', calls[name])[0]

@@ -128,6 +128,25 @@ def test_the_kept_set_grows_with_the_budget_in_the_lists_order(same):
     assert len(before) == len(walk) and remat.kept_bytes(plan, prices) == everything
 
 
+@pytest.mark.parametrize("available,masks", [(999, 0), (1000, 1), (2999, 2), (3000, 3),
+                                             (3010, 3), (3500, 3)])
+def test_a_sparse_attentions_mask_is_the_walks_first_name(available, masks):
+    """Three layers that each offer ``ds.dsa.mask`` (1,000 B) before the
+    names they offered: the masks are taken first, layer by layer; a budget
+    that fits some and not all keeps those and nothing after them (the kept
+    set is a prefix of the walk); with all three in, what is left is spent
+    as a program without the name spends it."""
+    from deepspeed_tpu.ops import remat
+    assert remat.CANDIDATE_NAMES[0] == remat.DSA_MASK == "ds.dsa.mask"
+    prices = tuple(((remat.DSA_MASK, 1000), ) + layer for layer in _PRICES)
+    plan = remat.choose_kept(available, prices)
+    assert [remat.DSA_MASK in names for names in plan] == [True] * masks + [False] * (3 - masks)
+    assert all(names[2] == remat.DSA_MASK for names in plan[:masks])
+    without = remat.choose_kept(available - 3000 if masks == 3 else 0, _PRICES)
+    assert tuple(tuple(n for n in names if n != remat.DSA_MASK) for names in plan) == without
+    assert remat.kept_bytes(plan, prices) == 1000 * masks + remat.kept_bytes(without, _PRICES)
+
+
 def test_a_backend_without_a_memory_report_makes_no_plan():
     """The CPU here reports no ``bytes_limit``: no plan, the price list is
     never asked for, and ``checkpoint`` of a function that names candidates
