@@ -9,12 +9,18 @@ every expert for every token and masked: E/k× wasted FLOPs).
 TPU design: sort the (token, choice) assignments by expert id (one XLA
 sort, giving the permutation ``order`` and its inverse ``inv``), gather the
 tokens into expert order, run the three expert MLPs as ragged grouped GEMMs
-with ``jax.lax.ragged_dot`` — on TPU/GPU this lowers to the native
-``chlo.ragged_dot`` grouped-GEMM instruction (MXU, FLOPs ∝ top-k; the CPU
-backend decomposes to a dense-masked form, which only the test harness
-sees), the grouped-GEMM analog of the reference's CUTLASS kernel — then
-gather the rows back by ``inv`` and sum each token's k rows, weighted, in
-float32. Dispatch and combine are permutations, so each carries its exact
+(``grouped_matmul``, FLOPs ∝ top-k, the grouped-GEMM analog of the
+reference's CUTLASS kernel): on one TPU device, at bfloat16, widths on the
+128-lane grid and 2,048 rows or more, the program's own Pallas kernels
+``moe_gmm_rows`` / ``moe_gmm_d_rows`` / ``moe_gmm_weights`` (a tile of sorted
+rows by one expert's whole matrix a grid step, stopped at the rows held;
+0.26 to 0.89 of XLA's time on a v5e at every shape the training cells call,
+the same bits: ``docs/kernel_dispatch.md``, "moe_gmm"), and everywhere else
+``jax.lax.ragged_dot``, which on a TPU lowers to XLA's grouped-matmul kernel
+(``%ragged-dot-none*``) and on the CPU to a dense masked form that only the
+test harness sees (``kernel_dispatch.gmm_impl`` chooses, from the shape, the
+dtype and the placement) — then gather the rows back by ``inv`` and sum each
+token's k rows, weighted, in float32. Dispatch and combine are permutations, so each carries its exact
 transpose as a ``jax.custom_vjp``: forward and backward move rows with
 gathers only, never a scatter-add (which XLA serialises, not knowing the
 indices cannot collide). The ``[T*k, ·]`` arrays between the matmuls cross
@@ -58,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_dispatch as kd
 from .registry import interpret_kernels, on_tpu, registry
 
 
@@ -142,6 +149,254 @@ def _moe_combine_bwd(res, dout):
 moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The grouped matmul itself: XLA's ``ragged_dot``, or the program's own
+# kernel where ``kernel_dispatch.gmm_impl`` says so from the shape, the dtype
+# and where the call runs (docs/kernel_dispatch.md, "moe_gmm").
+#
+# Three passes, one ``pallas_call`` each (``moe_gmm_rows``, ``moe_gmm_d_rows``,
+# ``moe_gmm_weights``): a grid step is one VISIT of a tile of ``tile`` sorted
+# rows by one expert, whole widths in VMEM. A tile that straddles experts is
+# visited once for each with the other's rows masked, so the static grid is
+# ``tiles + E - 1`` steps; which tile and expert a step visits is a table
+# made on the device from ``group_sizes`` and scalar-prefetched. An empty
+# expert has no visit, tiles past the rows held (``sum(group_sizes)``) are
+# written as zeros and nothing is loaded or multiplied for them, and the
+# steps left over repeat the last visit's blocks and do nothing.
+# ---------------------------------------------------------------------------
+
+
+def _gmm_visits(group_sizes, rows: int, tile: int, weights: bool):
+    """The visit table of a grid of ``tiles + E - 1`` steps, int32 ``[steps]``
+    each: ``(expert, tile, out, lo, hi, flags)``. Step ``s`` multiplies rows
+    ``lo[s] .. hi[s]`` (none where ``hi == lo``) of row tile ``tile[s]`` by
+    ``expert[s]``. The rows' passes write row tile ``out[s]``, ``flags`` 1
+    on its first step (tiles past the rows held follow the visits, a step
+    each); the weights' pass gives every expert a visit, an empty one too
+    (its gradient is written as zeros), ``flags`` bit 0 on an expert's first
+    step and bit 1 on its last."""
+    E = group_sizes.shape[0]
+    tiles = -(-rows // tile)
+    ends = jnp.minimum(jnp.cumsum(group_sizes.astype(jnp.int32)), rows)
+    starts = jnp.concatenate([jnp.zeros((1, ), jnp.int32), ends[:-1]])
+    first_tile = starts // tile
+    span = jnp.where(ends > starts, (ends - 1) // tile - first_tile + 1,
+                     int(weights))
+    v_end = jnp.cumsum(span)
+    n_visits = v_end[-1]
+    s = jnp.arange(tiles + E - 1, dtype=jnp.int32)
+    live = s < n_visits
+    at = jnp.minimum(s, jnp.maximum(n_visits - 1, 0))   # the rest repeat the last
+    e = jnp.minimum(jnp.sum(v_end[None, :] <= at[:, None], axis=1,
+                            dtype=jnp.int32), E - 1)
+    t = jnp.minimum(first_tile[e] + at - (v_end - span)[e], tiles - 1)
+    lo = jnp.where(live, jnp.maximum(starts[e], t * tile), 0)
+    hi = jnp.where(live, jnp.minimum(ends[e], (t + 1) * tile), 0)
+
+    def changes(v, to_next=False):
+        other = jnp.roll(v, -1 if to_next else 1)
+        edge = s == (n_visits - 1 if to_next else 0)
+        return (v != other) | edge
+
+    if weights:
+        flags = live * (changes(e) + 2 * changes(e, to_next=True))
+        return e, t, t, lo, hi, flags.astype(jnp.int32)
+    out = jnp.where(live, t, jnp.minimum(-(-ends[-1] // tile) + s - n_visits,
+                                         tiles - 1))
+    return e, t, out, lo, hi, changes(out).astype(jnp.int32)
+
+
+# the rows XLA's kernel adds to an expert's float32 sums at a time (v5e, PR
+# 47's sweep: the weights' pass is ``ragged_dot``'s to the bit summed so)
+GMM_SUM_ROWS = 128
+
+
+def _gmm_rows_kernel(e_ref, t_ref, out_ref, lo_ref, hi_ref, first_ref, x_ref,
+                     w_ref, o_ref, *, tile, transposed):
+    """``o[lo:hi] = x[lo:hi] @ w[e]`` (``@ w[e].T`` if ``transposed``), float32
+    sums rounded once; the tile's other rows keep what an earlier visit wrote,
+    zeros on its first."""
+    s = pl.program_id(0)
+    lo, hi, first = lo_ref[s], hi_ref[s], first_ref[s] == 1
+
+    @pl.when(hi > lo)
+    def _():
+        prod = jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1, ), (1 if transposed else 0, )), ((), ())),
+            preferred_element_type=jnp.float32)
+        row = out_ref[s] * tile + jax.lax.broadcasted_iota(
+            jnp.int32, prod.shape, 0)
+        kept = jnp.where(first, 0.0, o_ref[...].astype(jnp.float32))
+        o_ref[...] = jnp.where((row >= lo) & (row < hi), prod,
+                               kept).astype(o_ref.dtype)
+
+    @pl.when((hi <= lo) & first)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_weights_kernel(e_ref, t_ref, _, lo_ref, hi_ref, flags_ref, x_ref,
+                        dy_ref, o_ref, acc_ref, *, tile):
+    """``o[e] = x[rows of e].T @ dy[rows of e]``: float32 sums over the
+    expert's visits, rounded once on its last. Both operands are masked: a
+    row that is not the expert's, or no expert's, is never read as a number.
+    The sums grow GMM_SUM_ROWS rows at a time whatever the tile, which is the
+    order XLA's kernel adds them in: the same bits."""
+    s, step = pl.program_id(0), min(tile, GMM_SUM_ROWS)
+    lo, hi, flags = lo_ref[s], hi_ref[s], flags_ref[s]
+
+    @pl.when(flags % 2 == 1)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(hi > lo)
+    def _():
+        def add(i, _):
+            at = pl.multiple_of(i * step, step)
+
+            def own(ref):
+                row = t_ref[s] * tile + at + jax.lax.broadcasted_iota(
+                    jnp.int32, (step, ref.shape[1]), 0)
+                return jnp.where((row >= lo) & (row < hi),
+                                 ref[pl.ds(at, step), :], 0)
+
+            acc_ref[...] += jax.lax.dot_general(
+                own(x_ref), own(dy_ref), (((0, ), (0, )), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        jax.lax.fori_loop(0, tile // step, add, None)
+
+    @pl.when(flags >= 2)
+    def _():
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _gmm_call(leg: str, table, lhs, rhs, tile: int, interpret: bool):
+    """One pass over the visits of ``table`` (:func:`_gmm_visits`) as one
+    ``pallas_call`` with one result. ``rows``: ``lhs [R, K] x rhs [E, K, N]
+    -> [R, N]``; ``d_rows``: ``lhs [R, N] x rhs [E, K, N] -> [R, K]`` (the
+    contraction over ``N`` by the dot's dimension numbers: no transposed copy
+    of the weights); ``weights``: ``lhs [R, K], rhs [R, N] -> [E, K, N]``."""
+    R, steps = lhs.shape[0], table[0].shape[0]
+    experts = steps - -(-R // tile) + 1
+
+    def row_tile(width):
+        return pl.BlockSpec((tile, width), lambda s, e, t, *_: (t[s], 0))
+
+    def expert(k, n):
+        return pl.BlockSpec((1, k, n), lambda s, e, *_: (e[s], 0, 0))
+
+    if leg == "weights":
+        k, n = lhs.shape[1], rhs.shape[1]
+        kernel = functools.partial(_gmm_weights_kernel, tile=tile)
+        in_specs, out_specs = [row_tile(k), row_tile(n)], expert(k, n)
+        out_shape, scratch = (experts, k, n), [pltpu.VMEM((k, n), jnp.float32)]
+    else:
+        _, k, n = rhs.shape
+        width = k if leg == "d_rows" else n
+        kernel = functools.partial(_gmm_rows_kernel, tile=tile,
+                                   transposed=leg == "d_rows")
+        in_specs = [row_tile(lhs.shape[1]), expert(k, n)]
+        out_specs = pl.BlockSpec((tile, width),
+                                 lambda s, e, t, out, *_: (out[s], 0))
+        out_shape, scratch = (R, width), []
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table), grid=(steps, ), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(out_shape, lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", ),
+            vmem_limit_bytes=kd.vmem_limit_bytes(
+                kd.gmm_vmem_bytes(leg, tile, k, n, lhs.dtype.itemsize))),
+        interpret=interpret,
+        name=f"moe_gmm_{leg}",
+    )(*table, lhs, rhs)
+
+
+# One jitted body a pass, at module level, and one for the table: jit
+# remembers the jaxpr it traced for a (shapes, dtype, tile), so every call
+# site of a program holds the SAME ``pallas_call``, and the lowering emits it,
+# through Mosaic, once a program and calls it from each site (some 120 of 6
+# shapes in a share cell's step). The table is the rows' and the weights', not
+# a pass's or a width's: two traces and lowerings a program, not six.
+def _jitted_leg(leg: str):
+    def call(table, lhs, rhs, tile, interpret):
+        return _gmm_call(leg, table, lhs, rhs, tile, interpret)
+    call.__name__ = call.__qualname__ = f"moe_gmm_{leg}"
+    return jax.jit(call, static_argnames=("tile", "interpret"))
+
+
+_GMM_LEG = {leg: _jitted_leg(leg) for leg in ("rows", "d_rows", "weights")}
+_GMM_VISITS = jax.jit(_gmm_visits, static_argnames=("rows", "tile", "weights"))
+
+
+def _count_traced(leg: str, impl: str):
+    from ..observability import get_registry
+    get_registry().counter(
+        "ds_moe_gmm_traced_total",
+        "Grouped matmuls of the experts traced into a program, by pass (rows, "
+        "d_rows, weights) and implementation: moe_gmm, the program's kernel, "
+        "or ragged_dot, XLA's, whose backward is jax's own and is not counted",
+        labels={"leg": leg, "impl": impl}).inc()
+
+
+def _gmm_leg(leg: str, lhs, rhs, group_sizes, tile):
+    _count_traced(leg, "moe_gmm")
+    tile = tile or kd.GMM_ROW_TILE
+    table = _GMM_VISITS(group_sizes, rows=lhs.shape[0], tile=tile,
+                        weights=leg == "weights")
+    return _GMM_LEG[leg](table, lhs, rhs, tile=tile,
+                         interpret=interpret_kernels())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, ))
+def moe_gmm(rows, w, group_sizes, tile=None):
+    """``rows [R, K] x w [E, K, N] -> [R, N]`` with the sorted rows' groups
+    given by ``group_sizes [E]``, as ``jax.lax.ragged_dot(...,
+    preferred_element_type=rows.dtype)`` gives it, bit for bit, rows from
+    ``sum(group_sizes)`` on zeros; the Pallas kernel, forward and backward.
+    ``tile``: sorted rows a grid step (tests and the sweep pin it)."""
+    return _gmm_leg("rows", rows, w, group_sizes, tile)
+
+
+def _moe_gmm_fwd(rows, w, group_sizes, tile):
+    return (_gmm_leg("rows", rows, w, group_sizes, tile),
+            (rows, w, group_sizes))
+
+
+def _moe_gmm_bwd(tile, res, dy):
+    rows, w, group_sizes = res
+    return (_gmm_leg("d_rows", dy, w, group_sizes, tile),
+            _gmm_leg("weights", rows, dy, group_sizes, tile), None)
+
+
+moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)
+
+
+def grouped_matmul(rows, w, group_sizes):
+    """The experts' matmul over sorted rows, ``[R, K] x [E, K, N] -> [R, N]``
+    in ``rows.dtype``: float32 sums rounded once on write (the same bits as a
+    float32 result cast afterwards, half the bytes). :func:`moe_gmm` where
+    ``kernel_dispatch.gmm_impl`` says so, else ``jax.lax.ragged_dot``."""
+    if kd.gmm_impl(rows.shape[0], w.shape[1], w.shape[2], rows.dtype,
+                   _kernel_here()) == kd.IMPL_PALLAS:
+        return moe_gmm(rows, w, group_sizes)
+    _count_traced("rows", "ragged_dot")
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=rows.dtype)
+
+
+def traced_note() -> str:
+    """What ``ds_moe_gmm_traced_total`` has counted in this process, for a
+    log line: ``grouped_matmul[rows=moe_gmm:36,d_rows=moe_gmm:24,...]``."""
+    from ..observability import get_registry
+    found = [f"{m.labels['leg']}={m.labels['impl']}:{int(m.value)}"
+             for m in get_registry().series("ds_moe_gmm_traced_total")]
+    return f"grouped_matmul[{','.join(found)}]"
+
+
 def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
     """Token-choice MoE MLP via grouped GEMMs.
 
@@ -157,20 +412,14 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
       ``[T, H]`` in x.dtype.
     """
     k = top_idx.shape[1]
-
-    def gmm(rows, w):
-        # the kernel accumulates in float32 and rounds once on write: the
-        # same bits as a float32 result cast afterwards, half the bytes
-        return jax.lax.ragged_dot(rows, w, group_sizes,
-                                  preferred_element_type=x.dtype)
-
     # the gathers have a scope each (the sort is dispatch's); the grouped
     # matmuls between them keep the block's own, and their instruction names
     with jax.named_scope("ds.moe.dispatch"):
         order, inv = moe_sort_permutation(top_idx)
         group_sizes = expert_counts(top_idx, w1.shape[0])
         xs = moe_dispatch(x, order, inv, k)  # [T*k, H] expert-contiguous
-    y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
+    y = grouped_matmul(activation(grouped_matmul(xs, w1, group_sizes))
+                       * grouped_matmul(xs, w3, group_sizes), w2, group_sizes)
     with jax.named_scope("ds.moe.combine"):
         return moe_combine(y, top_w, order, inv)
 
@@ -265,8 +514,7 @@ def _sum_sorted_rows(rows, scale, tok, num_tokens, per_token, interpret):
 
     scalars = pl.BlockSpec((1, 1, ROW_CHUNK), lambda *a: (chunk(*a), 0, 0))
     # double-buffered blocks in and out, the float32 sums and three products
-    from .kernel_dispatch import vmem_limit_bytes
-    limit = vmem_limit_bytes(TOKEN_BLOCK * H * (4 * rows.dtype.itemsize + 16))
+    limit = kd.vmem_limit_bytes(TOKEN_BLOCK * H * (4 * rows.dtype.itemsize + 16))
     out = pl.pallas_call(
         functools.partial(_sum_rows_kernel, weighted=scale is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -433,17 +681,13 @@ def _share_rows_mlp(x, w1, w3, w2, top_w, order, group_sizes, *, rows,
     whether they go back to their tokens by the rows; by default as
     :func:`share_walks_rows` says for ``rows`` of all of ``order``)."""
     n_held = jnp.sum(group_sizes)
-
-    def gmm(lhs, w):
-        return jax.lax.ragged_dot(lhs, w, group_sizes,
-                                  preferred_element_type=x.dtype)
-
     if by_rows is None:
         by_rows = share_walks_rows(rows, order.size)
     with jax.named_scope("ds.moe.dispatch"):
         inv = None if by_rows else jnp.argsort(order).astype(jnp.int32)
         xs = share_dispatch(x, order[:rows], inv, n_held, top_w.shape[1])
-    y = gmm(activation(gmm(xs, w1)) * gmm(xs, w3), w2)
+    y = grouped_matmul(activation(grouped_matmul(xs, w1, group_sizes))
+                       * grouped_matmul(xs, w3, group_sizes), w2, group_sizes)
     with jax.named_scope("ds.moe.combine"):
         return share_combine(y, top_w, order[:rows], inv, n_held)
 
@@ -580,6 +824,8 @@ def moe_dense_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
 
 registry.register("moe_rows_to_tokens", "pallas", True,
                   "a share's token-sorted rows summed into their tokens")
-registry.register("grouped_matmul", "xla", True,
+registry.register("grouped_matmul", "pallas", True,
                   "MoE grouped GEMM, FLOPs proportional to top-k (reference "
-                  "cutlass_ops moe_gemm)")
+                  "cutlass_ops moe_gemm): moe_gmm_* on one TPU device at bf16, "
+                  "widths on the 128 grid and 2,048 rows or more; "
+                  "jax.lax.ragged_dot (xla) elsewhere")

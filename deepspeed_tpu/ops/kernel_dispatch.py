@@ -350,6 +350,51 @@ def choose_blocks(sig: ShapeSig, leg: str) -> tuple:
             cap_k //= 2
 
 
+# The experts' grouped matmuls (``ops/grouped_matmul.py``: ``moe_gmm_rows``,
+# ``moe_gmm_d_rows``, ``moe_gmm_weights``): a grid step is a tile of sorted
+# rows by one expert's WHOLE matrix (v5e sweeps, PRs 42 and 47,
+# docs/kernel_dispatch.md: 256 rows by the whole output width was the fastest
+# tile at every point, widths 768 to 2,048), so a step's weights are read
+# once for the rows of their expert and the float32 sums never leave VMEM.
+IMPL_XLA = "xla"
+GMM_ROW_TILE = 256
+# the fewest rows the sweeps measured (32 rows a group over 64 experts);
+# below them a decode wave's few rows, where every step is a weight's load
+GMM_MIN_ROWS = 2048
+
+
+def gmm_vmem_bytes(leg: str, tile: int, k: int, n: int, itemsize: int) -> int:
+    """Upper estimate of the VMEM one grid step of a ``moe_gmm_*`` kernel
+    holds, for an expert of ``[k, n]`` and ``tile`` rows, counted as
+    ``flash_vmem_bytes`` counts: the pipelined blocks twice, the float32
+    product (and, in "weights", the float32 sums beside it), the masked
+    copies and the row numbers."""
+    if leg == "weights":
+        blocks = (tile * (k + n) + k * n) * itemsize    # x, dy; the gradient
+        return 2 * blocks + 2 * k * n * 4 + tile * (k + n) * (itemsize + 4)
+    wide_in, wide_out = (n, k) if leg == "d_rows" else (k, n)
+    blocks = (tile * (wide_in + wide_out) + k * n) * itemsize
+    return 2 * blocks + 3 * tile * wide_out * 4
+
+
+def gmm_impl(rows: int, k: int, n: int, dtype, kernel_here: bool) -> str:
+    """IMPL_PALLAS (``moe_gmm``) or IMPL_XLA (``jax.lax.ragged_dot``) for
+    ``[rows, k] x [experts, k, n]``, forward and backward alike, from the
+    shape, the dtype and the placement alone: the kernel where a raw
+    ``pallas_call`` can run (``kernel_here``: a TPU, a mesh of one device),
+    the operands are bfloat16, both widths lie on the 128-lane grid and all
+    three passes fit FUSED_VMEM_CAP_BYTES at GMM_ROW_TILE rows, and there are
+    GMM_MIN_ROWS rows or more. At every measured point inside these edges the
+    kernel took 0.26 to 0.89 of XLA's time; outside them nothing was
+    measured (a LoRA delta's rank-wide matmuls, a decode wave, float32)."""
+    fits = max(gmm_vmem_bytes(leg, GMM_ROW_TILE, k, n, 2)
+               for leg in ("rows", "d_rows", "weights")) <= FUSED_VMEM_CAP_BYTES
+    if (kernel_here and "bfloat16" in str(dtype) and k % 128 == 0
+            and n % 128 == 0 and fits and rows >= GMM_MIN_ROWS):
+        return IMPL_PALLAS
+    return IMPL_XLA
+
+
 def resolve(sig: ShapeSig, *, impl_bwd: Optional[str] = None,
             blocks: Optional[tuple] = None):
     """(forward Decision, backward Decision) of one ``flash_attention`` call.

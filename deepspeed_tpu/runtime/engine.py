@@ -1778,6 +1778,12 @@ class DeepSpeedTpuEngine:
             ).set(masked / max(tokens, 1.0))
         if "expert_counts" not in fetched[0]:
             return
+        if not getattr(self, "_kernel_line_logged", False):
+            # once, after the first MoE step was traced: which grouped
+            # matmul its call sites took, by pass (ds_moe_gmm_traced_total)
+            from ..ops.grouped_matmul import traced_note
+            log_dist(f"kernels: {traced_note()}", ranks=[0])
+            self._kernel_line_logged = True
         # [E] a step, [K, E] a K-step dispatch
         counts = sum(np.asarray(s["expert_counts"], np.int64)
                      .reshape(-1, s["expert_counts"].shape[-1]).sum(axis=0)

@@ -271,8 +271,9 @@ def test_reference_experts_see_only_their_own_rows(capacity):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
-def test_fused_step_returns_the_counts_and_publishes_the_gauges():
+def test_fused_step_returns_the_counts_and_publishes_the_gauges(caplog):
     from deepspeed_tpu.comm import reset_mesh_context
+    from deepspeed_tpu.utils.logging import logger
     from deepspeed_tpu.comm.mesh import MeshContext, set_mesh_context
     from deepspeed_tpu.observability import get_registry
     cfg, model, params, ids = small()
@@ -288,6 +289,7 @@ def test_fused_step_returns_the_counts_and_publishes_the_gauges():
     routed = reg.counter("ds_moe_tokens_routed_total")
     before = routed.value
     want = reference.loss_parts(engine.params, ids, SMALL)
+    logger.addHandler(caplog.handler)
     loss = engine.train_batch(iter([(ids, ids)]))
     stats = engine.moe_stats()
     # float32 compute on both sides: the counts are exact
@@ -302,4 +304,11 @@ def test_fused_step_returns_the_counts_and_publishes_the_gauges():
     assert load == pytest.approx(counts.max() / counts.mean())
     assert reg.get("ds_moe_aux_loss").value == pytest.approx(float(want["aux"]), rel=1e-5)
     assert engine._train_step_fused._cache_size() == 1
+    # the engine's kernel line, once: which grouped matmul the trace took (a
+    # CPU, float32: XLA's; its backward is jax's own and has no count)
+    engine.train_batch(iter([(ids, ids)]))
+    logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records if "kernels: " in r.getMessage()]
+    assert len(lines) == 1 and "kernels: grouped_matmul[" in lines[0] \
+        and "rows=ragged_dot:" in lines[0] and "d_rows" not in lines[0], lines
     reset_mesh_context()

@@ -16,6 +16,7 @@ module is imported (every xdist worker imports every test file), and the
 compile stays in the test's process.
 """
 
+import contextlib
 import re
 
 import jax
@@ -75,12 +76,70 @@ def _custom_call_names(compiled):
             for line in _custom_calls(compiled)]
 
 
+def _grouped_matmuls(compiled):
+    """The compiled program's grouped matmuls by pass, ``{"rows": n, "d_rows":
+    n, "weights": n}`` of ``moe_gmm_*`` calls (a pass that has none left out):
+    on one chip at these shapes every one is the program's kernel, and XLA's
+    (``%ragged-dot-none*``) is in no line."""
+    text = compiled.as_text()
+    assert not re.findall(r"%(ragged-dot-none[.\d]*) = ", text)
+    names = [n.split(".")[0] for n in _custom_call_names(compiled)]
+    return {leg: names.count(f"moe_gmm_{leg}") for leg in ("rows", "d_rows", "weights")
+            if f"moe_gmm_{leg}" in names}
+
+
+def _other_kernels(compiled):
+    """Counts of the program's Mosaic kernels by name, the experts' own
+    (``moe_gmm_*``, ``moe_rows_to_tokens``: counted by their own tests) left
+    out."""
+    names = [n.split(".")[0] for n in _custom_call_names(compiled)
+             if not n.startswith(("moe_gmm", "moe_rows_to_tokens"))]
+    return {k: names.count(k) for k in set(names)}
+
+
+def _pallas_call_sites(jaxpr, counts=None):
+    """Call sites of each Pallas kernel in a traced program, by the kernel's
+    name: the ``pallas_call`` equations of ``jaxpr`` and of every jaxpr an
+    equation holds (a jitted body is counted at each equation that calls it)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value, ):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _pallas_call_sites(inner, counts)
+    return counts
+
+
+@contextlib.contextmanager
+def _mosaic_lowerings():
+    """The times jax lowered a ``pallas_call`` through Mosaic inside the
+    block, by the kernel's name: its own lowering rule's calls (what a
+    program's lowering pays in Python, a call site or a cache's hit apart)."""
+    from jax._src.pallas.mosaic import pallas_call_registration as registration
+    rule, counts = registration.pallas_call_tpu_lowering_rule, {}
+
+    def counting(ctx, *args, **params):
+        counts[params["name"]] = counts.get(params["name"], 0) + 1
+        return rule(ctx, *args, **params)
+
+    registration.pallas_call_tpu_lowering_rule = counting
+    try:
+        yield counts
+    finally:
+        registration.pallas_call_tpu_lowering_rule = rule
+
+
 def _steer_the_model_to_the_chip(monkeypatch):
     """Code that asks "is this a TPU" sees the CPU here, and conftest turns
     interpret mode on: steer both in the test, the kernels are the subject."""
     from deepspeed_tpu.models import llama
-    monkeypatch.setattr(llama, "on_tpu", lambda: True)
-    monkeypatch.setattr(llama, "interpret_kernels", lambda: False)
+    from deepspeed_tpu.ops import grouped_matmul
+    for module in (llama, grouped_matmul):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+        monkeypatch.setattr(module, "interpret_kernels", lambda: False)
     monkeypatch.setattr("deepspeed_tpu.ops.attention.use_pallas",
                         lambda force=None: True)
 
@@ -94,7 +153,7 @@ _SCOPED_LAYERS = {
         dict(num_attention_heads=16, num_key_value_heads=4, head_dim=128,
              qk_norm="head", intermediate_size=8192), 4096,
         # whole-layer recomputation keeps the kernel's output: one forward
-        {"flash_fwd": 1, "flash_dkdv_dq": 1}, 0),
+        {"flash_fwd": 1, "flash_dkdv_dq": 1}, {}),
     "conv_moe_share": (
         dict(num_attention_heads=32, num_key_value_heads=8, head_dim=64,
              num_local_experts=64, moe_experts_held=8, num_experts_per_tok=4,
@@ -102,41 +161,35 @@ _SCOPED_LAYERS = {
              moe_renorm_eps=1e-6, operator="conv", ffn="moe", ffn_width=1536),
         # either branch of the share's cond: the forward's three, a
         # recomputed forward's three, the six gradients
-        4096, {"short_conv_fwd": 2, "short_conv_bwd": 1}, 2 * (3 + 3 + 6)),
+        4096, {"short_conv_fwd": 2, "short_conv_bwd": 1},
+        {"rows": 2 * (3 + 3), "d_rows": 2 * 3, "weights": 2 * 3}),
     "mamba_dense": (
         dict(num_attention_heads=32, num_key_value_heads=8, head_dim=64,
              mamba_n_heads=64, pos_embedding="none", residual_multiplier=0.22,
              operator="mamba", ffn="dense", ffn_width=8192), 4096,
         {"ssd_chunk_fwd": 2, "ssd_chunk_bwd": 1, "causal_conv_fwd": 2,
-         "causal_conv_bwd": 1}, 0),
+         "causal_conv_bwd": 1}, {}),
     "attention_moe": (
         dict(num_attention_heads=16, num_key_value_heads=16, head_dim=128,
              qk_norm=True, num_local_experts=64, num_experts_per_tok=8,
              moe_renormalize=False, router_aux_loss_coef=0.01,
              intermediate_size=1024, remat=False), 4096,
-        {"flash_fwd": 1, "flash_dkdv_dq": 1}, 9),
+        {"flash_fwd": 1, "flash_dkdv_dq": 1}, {"rows": 3, "d_rows": 3, "weights": 3}),
 }
 
 
-def kernels_keep_their_names_under_the_programs_scopes(one_chip, monkeypatch, kind):
-    """Hazard (i) of the device scopes: an instruction is named by the
-    innermost name scope of the frame that holds it, and twelve admitted
-    metrics match kernels by instruction name. One layer of each cell's kind,
-    the loss and its gradient under the engine's ``ds.step.loss`` with
-    ``ds.rope``, ``ds.moe.*`` and ``ds.head.loss`` inside: every kernel is
-    still called what its reader matches, the scopes are on the ops around
-    them, and the recomputed forward's kernels are there (count 2; the
-    attention kernel's forward once: its output is kept for the backward)."""
-    import dataclasses
+def _layers_step(one_chip, monkeypatch, kind, layers=1):
+    """``layers`` layers of a cell's kind (``_SCOPED_LAYERS``) steered to the
+    chip: the loss and its gradient under the engine's ``ds.step.loss``, with
+    the abstract parameters and ids to trace it over, and the configuration."""
     from deepspeed_tpu.models import llama
     from deepspeed_tpu.runtime.engine import _step_scope
-    over, seq, kernels, ragged = _SCOPED_LAYERS[kind]
-    over = dict(over)
+    over, seq = (dict(_SCOPED_LAYERS[kind][0]), _SCOPED_LAYERS[kind][1])
     spec = {k: over.pop(k) for k in ("operator", "ffn", "ffn_width") if k in over}
     cfg = llama.LlamaConfig(**{**dict(
-        vocab_size=2048, hidden_size=2048, num_hidden_layers=1,
+        vocab_size=2048, hidden_size=2048, num_hidden_layers=layers,
         max_position_embeddings=seq, ce_chunk_size=2048, remat=True,
-        layer_specs=(llama.LayerSpec(**spec), ) if spec else None), **over})
+        layer_specs=(llama.LayerSpec(**spec), ) * layers if spec else None), **over})
     _steer_the_model_to_the_chip(monkeypatch)
     model = llama.LlamaForCausalLM(cfg)
     ids = _sds((1, seq), jnp.int32, one_chip)
@@ -153,17 +206,29 @@ def kernels_keep_their_names_under_the_programs_scopes(one_chip, monkeypatch, ki
         with _step_scope("loss"):
             return jax.value_and_grad(loss)(params)
 
+    return step, params, ids, cfg
+
+
+def kernels_keep_their_names_under_the_programs_scopes(one_chip, monkeypatch, kind):
+    """Hazard (i) of the device scopes: an instruction is named by the
+    innermost name scope of the frame that holds it, and twelve admitted
+    metrics match kernels by instruction name. One layer of each cell's kind,
+    the loss and its gradient under the engine's ``ds.step.loss`` with
+    ``ds.rope``, ``ds.moe.*`` and ``ds.head.loss`` inside: every kernel is
+    still called what its reader matches, the scopes are on the ops around
+    them, and the recomputed forward's kernels are there (count 2; the
+    attention kernel's forward once: its output is kept for the backward)."""
+    _, _, kernels, grouped = _SCOPED_LAYERS[kind]
+    step, params, ids, cfg = _layers_step(one_chip, monkeypatch, kind)
+    spec = cfg.layer_specs
     compiled = _compile(step, params, ids)
-    names = [n.split(".")[0] for n in _custom_call_names(compiled)
-             if not n.startswith("ragged-dot")]
-    assert {k: names.count(k) for k in set(names)} == kernels, names
+    assert _other_kernels(compiled) == kernels, _custom_call_names(compiled)
+    assert _grouped_matmuls(compiled) == grouped, _custom_call_names(compiled)
     text = compiled.as_text()
-    grouped = re.findall(r"%(ragged-dot-none[.\d]*) = ", text)
-    assert len(grouped) == ragged, grouped
     for scope in (["ds.step.loss", "ds.head.loss"]
                   + ["ds.rope"] * (cfg.pos_embedding == "rope" and not spec)
                   + ["ds.moe.route", "ds.moe.dispatch", "ds.moe.combine"]
-                  * bool(ragged)):
+                  * bool(grouped)):
         assert f"/{scope}/" in text, scope
 
 
@@ -257,7 +322,6 @@ def a_recomputing_cells_step_runs_each_attention_forward_once_and_fits(
     assert cfg.remat and cfg.remat_policy is None
     rows, seq = workload["traffic"]["global_batch"], workload["traffic"]["seq_len"]
     _steer_the_model_to_the_chip(monkeypatch)
-    monkeypatch.setattr("deepspeed_tpu.ops.grouped_matmul.on_tpu", lambda: True)
     monkeypatch.setattr(remat, "device_memory", lambda: (V5E_BYTES_LIMIT, in_use))
     remat.forget_plans()
     model = llama.LlamaForCausalLM(cfg)
@@ -321,5 +385,10 @@ def a_recomputing_cells_step_runs_each_attention_forward_once_and_fits(
     assert names.count(f"{kernel}_fwd") == layers, names
     other = "flash" if kernel == "bdattn" else "bdattn"
     assert not any(n.startswith(other) for n in names), names
+    # every grouped matmul of the step is the program's own, in both branches
+    # of each expert layer's cond: 24 call sites a layer
+    expert_layers = (sum(spec.ffn == "moe" for spec in cfg.layer_specs) if cfg.layer_specs
+                     else cfg.num_hidden_layers * (cfg.num_local_experts > 0))
+    assert sum(_grouped_matmuls(compiled).values()) == 24 * expert_layers, names
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries + 12 * n_params <= V5E_BYTES_LIMIT - 0.8e9, (temporaries, n_params)

@@ -547,6 +547,64 @@ def test_sweep_takes_a_block_grid_and_times_the_backward_alone():
 
 
 # ---------------------------------------------------------------------------
+# the experts' grouped matmuls: moe_gmm or ragged_dot, from what the call shows
+# ---------------------------------------------------------------------------
+
+# (sorted rows a call, hidden, expert width) of the five training cells with
+# experts: a share's rows array is twice its even share of tokens x top-k
+GMM_CELLS = {
+    "train-olmoe-1chip-seq4k": (16384 * 8, 2048, 1024),
+    "train-lfm2moe-1chip-seq8k": (2 * 32768 * 4 // 8, 2048, 1536),
+    "train-kimivl-1chip-seq8k": (2 * 32768 * 6 // 8, 2048, 1408),
+    "train-sdar-1chip-bd4-seq8k": (2 * 32768 * 8 // 8, 2048, 768),
+    "train-keyevl2-1chip-dsa-seq32k": (2 * 32768 * 8 // 8, 2048, 768),
+}
+
+
+@pytest.mark.parametrize("cell", list(GMM_CELLS))
+def test_the_cells_grouped_matmuls_take_the_kernel_on_one_tpu_device(cell):
+    rows, hidden, width = GMM_CELLS[cell]
+    for k, n in ((hidden, width), (width, hidden)):        # w1 | w3, and w2
+        assert kd.gmm_impl(rows, k, n, jnp.bfloat16, True) == kd.IMPL_PALLAS
+        assert kd.gmm_impl(rows, k, n, jnp.bfloat16, False) == kd.IMPL_XLA
+        # the call asks for what the rule counted, under the cap it is held to
+        assert max(kd.gmm_vmem_bytes(leg, kd.GMM_ROW_TILE, k, n, 2)
+                   for leg in ("rows", "d_rows", "weights")) <= kd.FUSED_VMEM_CAP_BYTES
+
+
+@pytest.mark.parametrize("why,call", {
+    "float32": (131072, 2048, 1024, jnp.float32),
+    "lora_down_to_the_rank": (8192, 4096, 16, jnp.bfloat16),
+    "lora_up_from_the_rank": (8192, 16, 4096, jnp.bfloat16),
+    "a_decode_waves_rows": (64 * 8, 2048, 1024, jnp.bfloat16),
+    "one_row_under_the_floor": (kd.GMM_MIN_ROWS - 1, 2048, 1024, jnp.bfloat16),
+    "a_width_off_the_128_grid": (131072, 2048, 1000, jnp.bfloat16),
+    "a_depth_off_the_128_grid": (131072, 1000, 2048, jnp.bfloat16),
+    "an_expert_too_wide_for_vmem": (131072, 4096, 4096, jnp.bfloat16),
+}.items())
+def test_every_other_grouped_matmul_keeps_ragged_dot(why, call):
+    assert kd.gmm_impl(*call, True) == kd.IMPL_XLA
+    assert kd.gmm_impl(kd.GMM_MIN_ROWS, 2048, 1024, jnp.bfloat16, True) == kd.IMPL_PALLAS
+
+
+@pytest.mark.parametrize("tpu,devices,here", [(False, 1, False), (True, 1, True),
+                                              (True, 2, False), (True, 0, True)],
+                         ids=["a_cpu", "one_tpu_device", "a_mesh_of_two", "no_mesh_yet"])
+def test_the_kernel_runs_where_a_raw_pallas_call_can(monkeypatch, tpu, devices, here):
+    from deepspeed_tpu.comm import reset_mesh_context
+    from deepspeed_tpu.comm.mesh import MeshContext, set_mesh_context
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "on_tpu", lambda: tpu)
+    reset_mesh_context()
+    if devices:
+        set_mesh_context(MeshContext.create(devices=jax.devices()[:devices]))
+    try:
+        assert gm._kernel_here() is here
+    finally:
+        reset_mesh_context()
+
+
+# ---------------------------------------------------------------------------
 # reporting surfaces
 # ---------------------------------------------------------------------------
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from aot_v5e import (_compile, _custom_call_names, _custom_calls, _sds,  # noqa: F401
+                     _grouped_matmuls, _mosaic_lowerings, _steer_the_model_to_the_chip,
                      kernels_keep_their_names_under_the_programs_scopes,
                      no_compile_cache, one_chip, topo)
 from deepspeed_tpu.ops.attention import flash_attention
@@ -191,29 +192,46 @@ def test_rms_norm_compiles(one_chip, no_compile_cache):
     _compile(functools.partial(rms_norm, force_pallas=True), x, w)
 
 
+@pytest.mark.parametrize("placed", ["where_no_kernel_runs", "on_one_chip"])
 def test_grouped_matmul_compiles_to_the_native_kernel_at_olmoe_widths(
-        one_chip, no_compile_cache):
+        one_chip, no_compile_cache, monkeypatch, placed):
     """OLMoE's MoE block a step: 16,384 tokens x top-8 = 131,072 rows over 64
-    experts of 2048 x 1024, forward and gradient. ``jax.lax.ragged_dot`` must
-    lower to the chip's grouped-matmul kernel (``%ragged-dot*`` custom calls,
-    FLOPs proportional to top-k: what ``benchmark/moe_cost.py`` matches in a
-    trace), three forward and nine with the gradient, and fit the chip."""
-    from deepspeed_tpu.ops.grouped_matmul import moe_grouped_mlp
+    experts of 2048 x 1024, forward and gradient, three grouped matmuls
+    forward and nine with the gradient, FLOPs proportional to top-k, named as
+    ``benchmark/moe_cost.py`` matches them in a trace, and fitting the chip.
+    Where no raw kernel runs (a mesh of several devices, or, here, a process
+    that is not on a TPU) ``jax.lax.ragged_dot`` lowers to the chip's own
+    (``%ragged-dot*``); on one chip they are the program's ``moe_gmm_*``,
+    three of each pass, and the nine call sites take six lowerings through
+    Mosaic: one a distinct (pass, shape)."""
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    kernel = placed == "on_one_chip"
+    if kernel:
+        _steer_the_model_to_the_chip(monkeypatch)
     T, HID, F, E, K = 16384, 2048, 1024, 64, 8
     sds = functools.partial(_sds, sharding=one_chip)
     args = [sds((T, HID), jnp.bfloat16), sds((E, HID, F), jnp.bfloat16),
             sds((E, HID, F), jnp.bfloat16), sds((E, F, HID), jnp.bfloat16),
             sds((T, K), jnp.int32), sds((T, K), jnp.bfloat16)]
 
-    def loss(*a):
-        return jnp.sum(moe_grouped_mlp(*a).astype(jnp.float32) ** 2)
+    def forward(*a):        # traced anew: jit remembers a function it has seen
+        return gm.moe_grouped_mlp(*a)
 
-    for fn, calls in ((moe_grouped_mlp, 3),
-                      (jax.grad(loss, argnums=(0, 1, 2, 3)), 9)):
-        compiled = _compile(fn, *args)
-        names = [n for n in _custom_call_names(compiled)
-                 if n.startswith("ragged-dot") and "metadata" not in n]
-        assert len(names) == calls, names
+    def loss(*a):
+        return jnp.sum(forward(*a).astype(jnp.float32) ** 2)
+
+    for fn, calls in ((forward, 3), (jax.grad(loss, argnums=(0, 1, 2, 3)), 9)):
+        with _mosaic_lowerings() as lowered:
+            compiled = _compile(fn, *args)
+        if kernel:
+            legs = ("rows", ) if calls == 3 else ("rows", "d_rows", "weights")
+            assert _grouped_matmuls(compiled) == {leg: 3 for leg in legs}
+            # [2048 -> 1024] for w1 and w3, [1024 -> 2048] for w2
+            assert lowered == {f"moe_gmm_{leg}": 2 for leg in legs}
+        else:
+            names = [n for n in _custom_call_names(compiled)
+                     if n.startswith("ragged-dot") and "metadata" not in n]
+            assert len(names) == calls, names
         assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
 
 

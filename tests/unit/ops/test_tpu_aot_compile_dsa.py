@@ -90,9 +90,12 @@ def test_every_layer_chooses_and_attends_once_a_step(step):
     from deepspeed_tpu.ops import remat
     names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
              for line in mla.custom_calls(step["compiled"])]
-    kernels = {n: names.count(n) for n in set(names) if not n.startswith("ragged-dot")}
+    kernels = {n: names.count(n) for n in set(names)}
     for name in ("dsa_index", "dsa_fwd", "dsa_bwd_dq", "dsa_bwd_dkdv"):
         assert kernels.pop(name) == 2, (name, names)
+    # two layers' shares, either branch of their cond: the program's own
+    assert (kernels.pop("moe_gmm_rows"), kernels.pop("moe_gmm_d_rows"),
+            kernels.pop("moe_gmm_weights")) == (4 * (3 + 3), 4 * 3, 4 * 3), names
     assert all(n.startswith("moe_rows_to_tokens") for n in kernels), names
     rows, seq = step["rows"], step["seq"]
     plan = next(iter(remat._PLANS.values()))

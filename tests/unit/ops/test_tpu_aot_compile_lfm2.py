@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from aot_v5e import (_compile, _custom_call_names, _custom_calls, _sds,  # noqa: F401
+                     _grouped_matmuls, _layers_step, _mosaic_lowerings, _pallas_call_sites,
                      a_recomputing_cells_step_runs_each_attention_forward_once_and_fits,
                      kernels_keep_their_names_under_the_programs_scopes,
                      no_compile_cache, one_chip, topo)
@@ -72,7 +73,9 @@ def test_the_shares_rows_go_back_to_tokens_through_the_kernel_on_one_chip(
     the static 32,768 rows sums token-sorted rows in ``moe_rows_to_tokens``
     (the combine's forward, also recomputed, and the dispatch's transpose);
     the exact branch over all 131,072 keeps its gathers, and the grouped
-    matmuls are what they were."""
+    matmuls of both branches are the program's ``moe_gmm_*``: in each the
+    forward's three and the recomputed forward's, and three of each
+    gradient."""
     from deepspeed_tpu.ops import grouped_matmul as gm
     monkeypatch.setattr(gm, "_kernel_here", lambda: True)
     monkeypatch.setattr(gm, "interpret_kernels", lambda: False)
@@ -88,9 +91,9 @@ def test_the_shares_rows_go_back_to_tokens_through_the_kernel_on_one_chip(
 
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 5), has_aux=True), *args)
     names = _custom_call_names(compiled)
-    grouped = [n for n in names if n.startswith("ragged-dot") and "metadata" not in n]
-    assert len(grouped) == 2 * (3 + 3 + 6), names
-    kernels = [n for n in names if not n.startswith("ragged-dot")]
+    assert _grouped_matmuls(compiled) == {
+        "rows": 2 * (3 + 3), "d_rows": 2 * 3, "weights": 2 * 3}, names
+    kernels = [n for n in names if not n.startswith("moe_gmm")]
     assert 2 <= len(kernels) <= 4 and all(
         n.startswith("moe_rows_to_tokens") for n in kernels), names
     # the kernel's blocks: 128 sorted rows of 2,048 in, 128 tokens out
@@ -98,6 +101,30 @@ def test_the_shares_rows_go_back_to_tokens_through_the_kernel_on_one_chip(
              if "%moe_rows_to_tokens" in line.split(" = ")[0]][:1]
     assert "bf16[32768,2048]" in call
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+def test_a_shares_step_lowers_each_pass_and_shape_through_mosaic_once(
+        one_chip, no_compile_cache, monkeypatch):
+    """Two LFM2 layers' loss and gradient (whole-layer recomputation, the
+    share's ``cond`` with the windows' ``scan`` in its exact branch, a
+    ``jax.checkpoint`` in each): the grouped matmuls stand at 24 call sites a
+    layer, 12 a branch (the forward's three, the recomputed forward's three,
+    three of each gradient), and the program's lowering takes each kernel
+    through Mosaic once a distinct (pass, shape): ``[2048 -> 1536]`` for w1
+    and w3 and ``[1536 -> 2048]`` for w2, six in all, whatever the layers and
+    the sites (every site calls the one jitted body of its pass, so they hold
+    the same equation and jax lowers it once)."""
+    layers = 2
+    step, params, ids, _ = _layers_step(one_chip, monkeypatch, "conv_moe_share", layers)
+    traced = jax.jit(step).trace(params, ids)
+    sites = _pallas_call_sites(traced.jaxpr)
+    assert {k: v for k, v in sites.items() if k.startswith("moe_gmm")} == {
+        "moe_gmm_rows": layers * 2 * (3 + 3), "moe_gmm_d_rows": layers * 2 * 3,
+        "moe_gmm_weights": layers * 2 * 3}, sites
+    with _mosaic_lowerings() as lowered:
+        traced.lower()
+    assert {k: v for k, v in lowered.items() if k.startswith("moe_gmm")} == {
+        "moe_gmm_rows": 2, "moe_gmm_d_rows": 2, "moe_gmm_weights": 2}, lowered
 
 
 @pytest.mark.parametrize("kind", ['conv_moe_share'])

@@ -104,6 +104,7 @@ def steer_to_the_chip(setattr_):
     setattr_(llama, "interpret_kernels", lambda: False)
     setattr_("deepspeed_tpu.ops.attention.use_pallas", lambda force=None: True)
     setattr_("deepspeed_tpu.ops.grouped_matmul.on_tpu", lambda: True)
+    setattr_("deepspeed_tpu.ops.grouped_matmul.interpret_kernels", lambda: False)
 
 
 def custom_calls(compiled):
@@ -151,8 +152,11 @@ def test_every_layers_attention_is_the_mla_kernels_once_a_step(step):
     from deepspeed_tpu.observability.xla import kept_residual_bytes
     names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
              for line in custom_calls(step["compiled"])]
-    kernels = {n: names.count(n) for n in set(names) if not n.startswith("ragged-dot")}
+    kernels = {n: names.count(n) for n in set(names)}
     assert kernels.pop("mla_fwd") == 2 and kernels.pop("mla_bwd") == 2, names
+    # the one MoE layer's share, either branch of its cond: the program's own
+    assert (kernels.pop("moe_gmm_rows"), kernels.pop("moe_gmm_d_rows"),
+            kernels.pop("moe_gmm_weights")) == (2 * (3 + 3), 2 * 3, 2 * 3), names
     assert all(n.startswith("moe_rows_to_tokens") for n in kernels), names
     tokens = step["rows"] * step["seq"]
     assert kept_residual_bytes(step["traced"].jaxpr) == (

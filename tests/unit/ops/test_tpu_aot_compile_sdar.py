@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from aot_v5e import (_compile, _custom_call_names, _custom_calls, _sds,  # noqa: F401
-                     _steer_the_model_to_the_chip,
+                     _grouped_matmuls, _other_kernels, _steer_the_model_to_the_chip,
                      a_recomputing_cells_step_runs_each_attention_forward_once_and_fits,
                      no_compile_cache, one_chip, topo)
 
@@ -58,8 +58,9 @@ def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
     cut) under the block-diffusion objective, the weighted loss and its
     gradient under the engine's ``ds.step.loss``: the attention is the
     ``bdattn`` pair (the forward ONCE: the recomputed layer takes the kept
-    output) and no ``flash`` call, the share's grouped matmuls are XLA's own, and the program's
-    scopes are on the ops around them."""
+    output) and no ``flash`` call, the share's grouped matmuls are the
+    program's own (``moe_gmm_*``, in either branch of the ``cond``), and the
+    program's scopes are on the ops around them."""
     from deepspeed_tpu.models import llama
     from deepspeed_tpu.runtime.engine import _step_scope
     seq = 4096
@@ -89,11 +90,11 @@ def test_the_block_diffusion_layer_keeps_its_kernels_names_under_the_scopes(
             return jax.value_and_grad(loss)(params)
 
     compiled = _compile(step, params, ids, targets, weights)
-    names = [n.split(".")[0] for n in _custom_call_names(compiled)
-             if not n.startswith("ragged-dot")]
-    assert {k: names.count(k) for k in set(names)} == {"bdattn_fwd": 1, "bdattn_bwd": 1}, names
+    assert _other_kernels(compiled) == {"bdattn_fwd": 1, "bdattn_bwd": 1}, \
+        _custom_call_names(compiled)
+    assert _grouped_matmuls(compiled) == {
+        "rows": 2 * (3 + 3), "d_rows": 2 * 3, "weights": 2 * 3}
     text = compiled.as_text()
-    assert len(re.findall(r"%(ragged-dot-none[.\d]*) = ", text)) == 2 * (3 + 3 + 6)
     for scope in ("ds.step.loss", "ds.head.loss", "ds.rope", "ds.moe.route",
                   "ds.moe.dispatch", "ds.moe.combine"):
         assert f"/{scope}/" in text, scope
