@@ -39,7 +39,7 @@ class LayerSpec:
     all alike (LFM2: gated short convolutions with an attention layer among
     every few, a dense FFN in the leading layers and experts after)."""
     # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer) |
-    # "latent" (LatentAttention: DeepSeek-V2/V3's MLA)
+    # "latent" (LatentAttention: DeepSeek-V2/V3's MLA) | "kda" (KimiDeltaMixer)
     operator: str = "attention"
     ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
     ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
@@ -173,6 +173,26 @@ class LlamaConfig:
     mamba_chunk_size: int = 256
     mamba_d_conv: int = 4
     mamba_conv_bias: bool = True
+    # the "kda" operator (Kimi Delta Attention, ``ops/kda.py``):
+    # ``num_attention_heads`` heads of ``kda_head_dim`` keys and values,
+    # ``kda_d_conv`` causal taps with SiLU on q, k and v, a decay for every key
+    # channel bounded below by ``kda_gate_floor`` (``g = floor * sigmoid(...)``),
+    # the scan in chunks of ``kda_chunk_size``
+    kda_head_dim: int = 128
+    kda_d_conv: int = 4
+    kda_gate_floor: float = -5.0
+    kda_chunk_size: int = 64
+    # "head": the "latent" operator's output times a sigmoid gate of the layer's
+    # normed input, one value a head and token, before ``o_proj`` (Ling-3.0's
+    # ``gated_attention_proj_granularity_type: head_wise``); None = no gate
+    attn_output_gate: Optional[str] = None
+    # group-limited choice of a sigmoid router with a selection bias (DeepSeek-V3's
+    # ``noaux_tc``): the router's experts in ``moe_n_group`` groups of
+    # consecutive experts, a group's score the sum of its two largest biased
+    # scores, the top-k taken inside the ``moe_topk_group`` best groups. 1, 1 =
+    # no groups
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # Granite: x + residual_multiplier * sublayer(norm(x)) on both branches
     # of a layer_specs layer (None = 1)
     residual_multiplier: Optional[float] = None
@@ -271,8 +291,14 @@ class LlamaConfig:
                   + self.kv_lora_rank * (1 + self.num_attention_heads
                                          * (hd - rope + self.v_head_dim))
                   + self.num_attention_heads * self.v_head_dim * h)
+        if self.attn_output_gate:
+            latent += h * self.num_attention_heads
+        kda_inner = self.num_attention_heads * self.kda_head_dim
+        kda = (6 * h * kda_inner + h * self.num_attention_heads
+               + 3 * self.kda_d_conv * kda_inner
+               + self.num_attention_heads + kda_inner + self.kda_head_dim)
         operator = {"conv": conv, "attention": attn, "mamba": mamba,
-                    "latent": latent}
+                    "latent": latent, "kda": kda}
         return max(operator[spec.operator] + ffn(spec.ffn, spec.ffn_width) + 2 * h
                    for spec in self.layer_specs)
 
@@ -791,6 +817,13 @@ class LatentAttention(nn.Module):
                                    interpret=interpret_kernels())
         else:
             attn = _xla_attention(q, k, v, scale, True)
+        if cfg.attn_output_gate == "head":
+            gate = _dense(nh, "gate_proj", (EMBED, None), cfg.dtype)(x)
+            with jax.named_scope("ds.mla.gate"):
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
+        elif cfg.attn_output_gate:
+            raise ValueError(f"unknown attn_output_gate {cfg.attn_output_gate!r}")
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       keep=_keep_out(cfg, nh * d_v))(attn.reshape(b, s, nh * d_v))
 
@@ -906,6 +939,113 @@ class Mamba2Mixer(nn.Module):
                       keep=_keep_out(cfg, inner))(y)
 
 
+class KimiDeltaMixer(nn.Module):
+    """Kimi Delta Attention, the operator of a ``"kda"`` layer (Kimi Linear,
+    arXiv:2510.26692; flash-linear-attention's ``KimiDeltaAttention`` with the
+    bounded gate): ``q_proj``, ``k_proj``, ``v_proj`` to ``num_attention_heads`` heads
+    of ``kda_head_dim``, each through its own ``kda_d_conv`` causal depthwise
+    taps without bias and SiLU (``ops/short_conv.py::causal_conv``); per head
+    ``q = l2norm(q) / sqrt(d)``, ``k = l2norm(k)``; the decay of every key
+    channel ``g = kda_gate_floor * sigmoid(exp(A_log_h) * (f_proj(x) +
+    dt_bias))`` (made inside the kernels where they run), ``beta =
+    sigmoid(b_proj(x))`` one a head; the delta-rule scan with a state of ``d x
+    d`` a head (``ops/kda.py::kda_scan``, in chunks of ``kda_chunk_size``);
+    ``RMSNorm_d(o) * o_norm * sigmoid(g_proj(x))`` head by head; ``o_proj``. Named ``self_attn`` by its layer, as the other sequence
+    mixers' projections are read (``benchmark/scope_time.py``). Sows
+    ``kda_stats`` (only when mutable): the largest ``|S|``, the mean decay
+    ``exp(g)`` and the mean ``beta``."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.kda import GATE_FLOOR, bounded_gate, kda_scan
+        from ..ops.short_conv import causal_conv
+        cfg = self.config
+        H, d = cfg.num_attention_heads, cfg.kda_head_dim
+        if not GATE_FLOOR <= cfg.kda_gate_floor < 0:
+            raise ValueError(f"kda_gate_floor={cfg.kda_gate_floor}: the chunked scan "
+                             f"holds a gate in [{GATE_FLOOR}, 0)")
+        inner = H * d
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        # raw pallas_calls are not partitioned under GSPMD: as for flash, the
+        # kernels run where the mesh is one device
+        kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
+        no_bias = jnp.zeros((inner, ), f32)
+
+        def projected(name, keep=remat.MIXER_IN):
+            return _dense(inner, name, (EMBED, HEADS), cfg.dtype, keep=keep)(x)
+
+        def conv_silu(name):
+            taps = self.param(
+                name + "_conv_weight",
+                nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0, out_axis=1),
+                                     (None, HEADS)),
+                (cfg.kda_d_conv, inner), f32)
+            y = causal_conv(projected(name + "_proj"), taps, no_bias, use_kernel=kernels,
+                            interpret=interpret_kernels())
+            return remat.keep(y, remat.KERNEL_OUT).reshape(b, s, H, d)
+
+        q, k, v = conv_silu("q"), conv_silu("k"), conv_silu("v")
+        decay_in = projected("f_proj")
+        beta_in = _dense(H, "b_proj", (EMBED, None), cfg.dtype)(x)
+        gate_in = projected("g_proj")
+        a_log = self.param("A_log", nn.with_partitioning(
+            lambda key, shape, dtype=f32: jnp.log(jax.random.uniform(
+                key, shape, dtype, 1.0, 16.0)), (HEADS, )), (H, ), f32)
+        dt_bias = self.param("dt_bias", nn.with_partitioning(_dt_bias_init, (HEADS, )),
+                             (inner, ), f32)
+        o_norm = self.param("o_norm", nn.with_partitioning(nn.initializers.ones, (None, )),
+                            (d, ), f32)
+
+        # the norms' float32 insides are made again in the backward from
+        # their bf16 operands (a checkpoint each): kept, they are five float32
+        # arrays of tokens x inner a layer, 1.3 GB at 16,384 tokens
+        @jax.checkpoint
+        def l2norm(a, scale):
+            a = a.astype(f32)
+            return (a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+                    * scale).astype(cfg.dtype)
+
+        @jax.checkpoint
+        def gated_norm(o, gate, weight):
+            o = o.astype(f32)
+            var = jnp.mean(o * o, axis=-1, keepdims=True)
+            y = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * weight
+                 * jax.nn.sigmoid(gate.astype(f32)).reshape(o.shape))
+            return y.astype(cfg.dtype).reshape(b, s, inner)
+
+        # every scope closes before the kernels' call below: one that held it
+        # would rename the instruction (docs/observability.md)
+        with jax.named_scope("ds.kda.norm"):
+            q, k = l2norm(q, float(d) ** -0.5), l2norm(k, 1.0)
+        with jax.named_scope("ds.kda.gates"):
+            rate = jnp.exp(a_log)
+            beta = jax.nn.sigmoid(beta_in.astype(f32))
+        want_stats = self.is_mutable_collection("kda_stats")
+        # the kernels make the gate g = floor * sigmoid(rate * (f_proj + dt_bias))
+        # and its running sum themselves (ops/kda.py)
+        o = kda_scan(q, k, v, decay_in.reshape(b, s, H, d), rate, dt_bias, beta,
+                     cfg.kda_chunk_size, floor=cfg.kda_gate_floor,
+                     use_kernel=kernels and d % 128 == 0,
+                     interpret=interpret_kernels() and d % 128 == 0,
+                     with_state_absmax=want_stats, keep=remat.keeps(remat.KDA_SCAN))
+        if want_stats:
+            o, top = o
+            self.sow("kda_stats", "state_absmax", top, reduce_fn=jnp.maximum,
+                     init_fn=lambda: jnp.zeros((), f32))
+            with jax.named_scope("ds.kda.gates"):
+                decay = jnp.mean(jnp.exp(bounded_gate(
+                    decay_in.reshape(b, s, H, d), rate, dt_bias, cfg.kda_gate_floor)))
+            for name, value in (("decay_mean", decay), ("beta_mean", jnp.mean(beta))):
+                self.sow("kda_stats", name, jax.lax.stop_gradient(value),
+                         reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+        with jax.named_scope("ds.kda.norm"):
+            y = gated_norm(o, gate_in, o_norm)
+        return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, inner))(y)
+
+
 class LlamaMLP(nn.Module):
     config: LlamaConfig
 
@@ -984,19 +1124,47 @@ class LlamaMoEBlock(nn.Module):
                 # backward, which no name reaches: it is made again whatever
                 # is kept, and keeping its logits alone cost the SDAR cell
                 # 4 ms a step (PERF.md §6, PR 41)
-                _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+                biased = scores + jax.lax.stop_gradient(bias)
+                if cfg.moe_n_group > 1:
+                    biased = self._in_best_groups(biased)
+                _, idx = jax.lax.top_k(biased, k)
                 idx = remat.keep(idx, remat.ROUTE)
                 w = remat.keep(jnp.take_along_axis(scores, idx, axis=-1), remat.ROUTE)
             else:
                 w, idx = jax.lax.top_k(scores, k)
         else:
             raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
+        if cfg.moe_n_group > 1 and not (cfg.moe_scoring == "sigmoid"
+                                        and cfg.moe_selection_bias):
+            raise ValueError("expert groups (moe_n_group > 1) belong to the sigmoid "
+                             "router with a selection bias")
         if cfg.moe_renormalize:  # Mixtral; Qwen2-MoE keeps raw softmax mass
             total = jnp.sum(w, -1, keepdims=True)
             w = w / (total + cfg.moe_renorm_eps if cfg.moe_renorm_eps else total)
         if cfg.routed_scaling_factor != 1.0:
             w = w * cfg.routed_scaling_factor
         return scores, w, idx
+
+    def _in_best_groups(self, biased):
+        """The biased scores ``[..., E]`` with every expert outside the
+        ``moe_topk_group`` best of ``moe_n_group`` groups at -inf: a group is
+        ``E / moe_n_group`` consecutive experts, its score the sum of its two
+        largest biased scores. Sows ``group_counts`` (``moe_stats``): the
+        tokens that kept each group."""
+        cfg = self.config
+        groups, best = cfg.moe_n_group, cfg.moe_topk_group
+        if biased.shape[-1] % groups or not 0 < best <= groups:
+            raise ValueError(f"{biased.shape[-1]} experts in {groups} groups, "
+                             f"{best} of them kept")
+        grouped = biased.reshape(*biased.shape[:-1], groups, -1)
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(score, best)
+        is_kept = jnp.sum(jax.nn.one_hot(kept, groups, dtype=jnp.int32), axis=-2) > 0
+        self.sow("moe_stats", "group_counts",
+                 jnp.sum(is_kept.reshape(-1, groups), axis=0, dtype=jnp.int32),
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((groups, ), jnp.int32))
+        return jnp.where(is_kept[..., None], grouped, -jnp.inf).reshape(biased.shape)
 
     @nn.compact
     def __call__(self, x):
@@ -1053,8 +1221,14 @@ class LlamaMoEBlock(nn.Module):
         if held < E:
             if not cfg.moe_grouped:
                 raise ValueError("a share of the experts needs moe_grouped")
+            # a share inside one routing group gets n_group / topk_group times
+            # its even share of the tokens that keep its group, and every
+            # token may: the static rows are reckoned from that (1 without
+            # groups: the program as it was)
+            inside = held * cfg.moe_n_group <= E
             out, rows_held, fell_back = moe_grouped_mlp_share(
-                xt, w1, w3, w2, idx, w, first_expert=first, num_experts=E)
+                xt, w1, w3, w2, idx, w, first_expert=first, num_experts=E,
+                crowding=cfg.moe_n_group // cfg.moe_topk_group if inside else 1)
             sow_stat("rows_held", rows_held)
             sow_stat("share_fallback", fell_back)
         else:
@@ -1101,6 +1275,8 @@ class LlamaDecoderLayer(nn.Module):
                 h = x + branch(ShortConvOperator(cfg, name="conv")(normed))
             elif spec.operator == "mamba":
                 h = x + branch(Mamba2Mixer(cfg, name="mamba")(normed))
+            elif spec.operator == "kda":
+                h = x + branch(KimiDeltaMixer(cfg, name="self_attn")(normed))
             elif spec.operator in ("attention", "latent"):
                 op_cls = (LatentAttention if spec.operator == "latent"
                           else LlamaAttention)
@@ -1217,7 +1393,18 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
                 by_kind[spec] = remat.price_list(
                     LlamaDecoderLayer(cfg, i, parent=None).init, jax.random.PRNGKey(0),
                     abstract(x), cos, sin, abstract(positions), attn_mask)
-        return [by_kind[spec] for spec in specs]
+        return [by_kind[spec] + scan_price(spec) for spec in specs]
+
+    def scan_price(spec):
+        # the kda kernel's output and chunk states are named inside its
+        # forward rule, which no trace of the layer's forward shows
+        if spec is None or spec.operator != "kda":
+            return ()
+        from ..ops.kda import scan_bytes
+        return ((remat.KDA_SCAN, scan_bytes(
+            x.shape[0], x.shape[1], cfg.num_attention_heads, cfg.kda_head_dim,
+            cfg.kda_head_dim,
+            cfg.kda_chunk_size, jnp.dtype(cfg.dtype).itemsize)), )
 
     tokens, itemsize = x.shape[0] * x.shape[1], jnp.dtype(cfg.dtype).itemsize
     attention = sum(spec is None or spec.operator in ("attention", "latent")
@@ -1309,7 +1496,8 @@ class LlamaModel(nn.Module):
             ScanLayer = nn.scan(_ScanBody,
                                 variable_axes={"params": 0, "aux_loss": 0,
                                                "moe_stats": 0, "ssm_stats": 0,
-                                               "mla_stats": 0, "dsa_stats": 0},
+                                               "mla_stats": 0, "dsa_stats": 0,
+                                               "kda_stats": 0},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,

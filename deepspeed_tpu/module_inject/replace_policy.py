@@ -533,9 +533,10 @@ class DeepseekV3Policy(HFCheckpointPolicy):
     times ``routed_scaling_factor``, beside ``n_shared_experts *
     moe_intermediate_size`` of ungated shared expert. The rotary embedding
     turns adjacent pairs of the rope slices (the modeling code
-    de-interleaves, then rotates halves: the same rotation). Not built, and
-    refused: ``q_lora_rank``, ``rope_scaling`` (YaRN and its mscale), expert
-    groups (``n_group > 1``), ``moe_layer_freq != 1``, biases. A chip's share
+    de-interleaves, then rotates halves: the same rotation). ``n_group`` /
+    ``topk_group`` limit the choice to the best groups (``LlamaConfig.
+    moe_n_group``). Not built, and refused: ``q_lora_rank``, ``rope_scaling``
+    (YaRN and its mscale), ``moe_layer_freq != 1``, biases. A chip's share
     of the experts and of the vocabulary is the deployment's to set
     (``moe_experts_held``, ``vocab_size``), not the checkpoint's."""
     arch = "deepseek_v3"
@@ -546,10 +547,8 @@ class DeepseekV3Policy(HFCheckpointPolicy):
         for key in ("q_lora_rank", "rope_scaling", "attention_bias"):
             if hf_config.get(key):
                 raise ValueError(f"deepseek_v3: {key}={hf_config[key]!r} is not supported")
-        if (hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1
-                or hf_config.get("moe_layer_freq", 1) != 1):
-            raise ValueError("deepseek_v3: expert groups (n_group, topk_group) "
-                             "and moe_layer_freq other than 1 are not supported")
+        if hf_config.get("moe_layer_freq", 1) != 1:
+            raise ValueError("deepseek_v3: moe_layer_freq other than 1 is not supported")
         if (hf_config.get("scoring_func", "sigmoid") != "sigmoid"
                 or hf_config.get("topk_method", "noaux_tc") != "noaux_tc"):
             raise ValueError("deepseek_v3: only the sigmoid router with a "
@@ -576,6 +575,8 @@ class DeepseekV3Policy(HFCheckpointPolicy):
             moe_scoring="sigmoid", moe_selection_bias=True,
             moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
             moe_renorm_eps=1e-20,   # in the modeling code, not a config key
+            moe_n_group=int(hf_config.get("n_group") or 1),
+            moe_topk_group=int(hf_config.get("topk_group") or 1),
             routed_scaling_factor=float(hf_config.get("routed_scaling_factor", 1.0)),
             shared_expert_intermediate_size=(
                 shared * hf_config["moe_intermediate_size"] or None),
@@ -611,6 +612,110 @@ class DeepseekV3Policy(HFCheckpointPolicy):
                 gate[p + f"shared_experts.{proj}.weight"] = (
                     f + f"shared_expert/{proj}/kernel", True)
         return gate, experts
+
+
+class BailingHybridPolicy(DeepseekV3Policy):
+    """Ling-3.0's hybrid blocks (``model_type: bailing_hybrid``): pre-norm
+    layers whose mixer is Kimi Delta Attention (``KimiDeltaMixer``: ``q/k/v_
+    proj`` through ``short_conv_kernel_size`` causal taps and SiLU
+    (``linear_silu``), L2-normed q and k (``use_qk_norm``), a decay for every
+    key channel from one full ``f_proj`` (``no_kda_lora``) bounded below by
+    ``kda_lower_bound`` (``kda_safe_gate``), ``b_proj``, a sigmoid output gate
+    ``g_proj`` under a per-head RMSNorm) and, every ``layer_group_size``-th
+    layer, multi-head latent attention as ``DeepseekV3Policy`` reads it with a
+    head-wise sigmoid gate before ``o_proj`` (``gated_attention_proj_
+    granularity_type: head_wise``); a SwiGLU ``intermediate_size`` wide in the
+    first ``first_k_dense_replace`` layers and afterwards ``num_experts``
+    experts ``moe_intermediate_size`` wide, top-k of sigmoid scores plus a bias
+    (``moe_router_enable_expert_bias``) inside the ``topk_group`` best of
+    ``n_group`` groups, beside ``num_shared_experts`` of ungated shared expert.
+    ``layer_types`` (``"kda"`` | ``"mla"`` a layer) states the kinds where a
+    file keeps layers that do not start a group (``layer_offset`` then says
+    which published layer is the first: the SwiGLU limits are read from
+    there); else layer ``i`` is MLA where ``(i + 1) % layer_group_size == 0``.
+    Not built, and refused by name: a non-zero ``expert_swiglu_limit_list`` /
+    ``share_expert_swiglu_limit_list`` entry of a kept layer (the clamp's form
+    is not in the config), ``mtp_loss_scaling_factor`` above 0 (the
+    multi-token-prediction module: at the published 0 it adds nothing to the
+    loss and is left out), an unbounded gate (``kda_safe_gate`` false), a
+    low-rank decay gate (``use_kda_lora``), ``use_nGPT``, ``value_norm``,
+    ``up_proj_norm``, ``scale_router_input``, ``use_mla_nope``, biases,
+    ``q_lora_rank``, ``rope_scaling``, fewer key heads than query heads in the
+    linear layers."""
+    arch = "bailing_hybrid"
+    col_parallel = DeepseekV3Policy.col_parallel + ["f_proj", "g_proj"]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        hf = dict(hf_config)
+        refused = {key: bool(hf.get(key)) for key in (
+            "use_kda_lora", "use_nGPT", "value_norm", "up_proj_norm",
+            "scale_router_input", "use_mla_nope", "use_bias", "use_qkv_bias",
+            "num_kv_heads_for_linear_attn")}
+        refused.update({
+            "kda_safe_gate": not hf.get("kda_safe_gate", True),
+            "no_kda_lora": not hf.get("no_kda_lora", True),
+            "linear_silu": not hf.get("linear_silu", True),
+            "use_qk_norm": not hf.get("use_qk_norm", True),
+            "group_norm_size": hf.get("group_norm_size", 1) != 1,
+            "mtp_loss_scaling_factor": (hf.get("mtp_loss_scaling_factor") or 0) > 0,
+            "gated_attention_proj_granularity_type": hf.get(
+                "gated_attention_proj_granularity_type", "head_wise") != "head_wise",
+            "moe_router_enable_expert_bias": not hf.get(
+                "moe_router_enable_expert_bias", True)})
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(f"bailing_hybrid: {key}={hf.get(key)!r} is not supported")
+        depth, group = hf["num_hidden_layers"], int(hf.get("layer_group_size", 1))
+        offset = int(hf.get("layer_offset", 0))
+        types = hf.get("layer_types") or [
+            "mla" if (offset + i + 1) % group == 0 else "kda" for i in range(depth)]
+        if len(types) != depth or set(types) - {"kda", "mla"}:
+            raise ValueError(f"bailing_hybrid: layer_types {types} for {depth} layers")
+        for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+            kept = list(hf.get(key) or ())[offset:offset + depth]
+            if any(kept):
+                raise ValueError(
+                    f"bailing_hybrid: {key} is {kept} in layers {offset}-"
+                    f"{offset + depth - 1}: a clamped SwiGLU is not supported (its "
+                    "form is not in the config)")
+        cfg = super().config_from_hf({
+            **hf, "n_routed_experts": hf["num_experts"],
+            "n_shared_experts": hf.get("num_shared_experts") or 0,
+            "scoring_func": hf.get("score_function", hf.get("scoring_func", "sigmoid")),
+            "moe_intermediate_size": hf["moe_intermediate_size"]})
+        shared = ((hf.get("num_shared_experts") or 0)
+                  * hf.get("moe_shared_expert_intermediate_size", 0)) or None
+        specs = tuple(dataclasses.replace(spec, operator="latent" if kind == "mla" else "kda")
+                      for spec, kind in zip(cfg.layer_specs, types))
+        self.bind(dataclasses.replace(
+            cfg, layer_specs=specs, attn_output_gate="head",
+            shared_expert_intermediate_size=shared,
+            kda_head_dim=hf["head_dim"],
+            kda_d_conv=int(hf.get("short_conv_kernel_size", 4)),
+            kda_gate_floor=float(hf.get("kda_lower_bound", -5))))
+        return self._cfg
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        if self._cfg.layer_specs[layer].operator == "latent":
+            out = super().weight_map(layer)
+            out[f"model.layers.{layer}.self_attn.gate_proj.weight"] = (
+                f"layers_{layer}/self_attn/gate_proj/kernel", True)
+            return out
+        # the linear layers' names are this repo's (the published modeling
+        # code is not in the catalog row): projections under ``self_attn``
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "input_layernorm.weight": (f + "operator_norm/weight", False),
+               p + "post_attention_layernorm.weight": (f + "ffn_norm/weight", False)}
+        for proj in ("q_proj", "k_proj", "v_proj", "f_proj", "b_proj", "g_proj", "o_proj"):
+            out[p + f"self_attn.{proj}.weight"] = (f + f"self_attn/{proj}/kernel", True)
+        for leaf in ("A_log", "dt_bias", "o_norm", "q_conv_weight", "k_conv_weight",
+                     "v_conv_weight"):
+            out[p + f"self_attn.{leaf}"] = (f + f"self_attn/{leaf}", False)
+        if self._cfg.layer_specs[layer].ffn == "dense":
+            for proj in ("gate_proj", "up_proj", "down_proj"):
+                out[p + f"mlp.{proj}.weight"] = (f + f"mlp/{proj}/kernel", True)
+        return out
 
 
 class GraniteMoeHybridPolicy(HFCheckpointPolicy):
@@ -1774,6 +1879,8 @@ _POLICIES = {
     "SDARMoeForCausalLM": SdarMoePolicy,
     "KeyeVL2": KeyeVL2Policy,
     "KeyeVL2ForConditionalGeneration": KeyeVL2Policy,
+    "bailing_hybrid": BailingHybridPolicy,
+    "BailingHybridForCausalLM": BailingHybridPolicy,
     "deepseek_v3": DeepseekV3Policy,
     "DeepseekV3ForCausalLM": DeepseekV3Policy,
     "lfm2_moe": Lfm2MoePolicy,

@@ -35,8 +35,8 @@ the others: the partial sum an expert-parallel layer's exchange would bring
 home, without the exchange. The assignments are sorted with every absent
 expert's last, so the rows held come first, expert by expert, and only a
 prefix of the sorted rows is gathered, multiplied and combined. That prefix
-has a static length, ``share_rows``: twice the even share
-(``SHARE_ROWS_FACTOR``), so a router sending this chip twice its share still
+has a static length, ``share_rows``: twice the even share (``SHARE_ROWS_FACTOR``; of the share
+when all tokens keep the group held: ``crowding``), so a router sending this chip twice that still
 costs one pass over a quarter of the ``T*k`` rows at 8 of 64 experts held. A
 step that sends more takes the same function over all ``T*k`` rows under a
 ``lax.cond``: slower, exact, counted (``share_fallback``). Rows between the
@@ -427,11 +427,11 @@ def moe_grouped_mlp(x, w1, w3, w2, top_idx, top_w, *, activation=jax.nn.silu):
 SHARE_ROWS_FACTOR = 2
 
 
-def share_rows(assignments: int, held: int, num_experts: int) -> int:
+def share_rows(assignments: int, held: int, num_experts: int, crowding: int = 1) -> int:
     """Static length of the sorted-rows prefix a share works on:
-    ``SHARE_ROWS_FACTOR`` times the even share of the ``assignments``, in
-    whole tiles of 8 rows, and never more than all of them."""
-    even = -(-assignments * held // num_experts)
+    ``SHARE_ROWS_FACTOR`` times the even share of the ``assignments`` (``crowding`` times it: what a
+    group-limited router sends when every token keeps the group held), in 8-row tiles, at most all."""
+    even = -(-assignments * held * crowding // num_experts)
     return min(assignments, -(-SHARE_ROWS_FACTOR * even // 8) * 8)
 
 
@@ -720,7 +720,7 @@ def _share_all_rows_mlp(x, w1, w3, w2, top_w, order, group_sizes, *, window,
 
 
 def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
-                          num_experts: int, activation=jax.nn.silu):
+                          num_experts: int, activation=jax.nn.silu, crowding=1):
     """The part of a token-choice MoE MLP that the experts held here give.
 
     ``y[t] = Σ_j [first_expert <= top_idx[t,j] < first_expert + E_held]
@@ -730,14 +730,14 @@ def moe_grouped_mlp_share(x, w1, w3, w2, top_idx, top_w, *, first_expert: int,
 
     Returns ``(y [T, H], rows_held, fell_back)``: the (token, choice) rows
     sent to the experts held and whether they outran ``share_rows``, both
-    int32 scalars.
+    int32 scalars. ``crowding``: as ``share_rows`` takes it (1: no groups).
     """
     held, assignments = w1.shape[0], top_idx.size
     with jax.named_scope("ds.moe.dispatch"):
         order, group_sizes = moe_share_permutation(top_idx, first_expert,
                                                    held)
     rows_held = jnp.sum(group_sizes)
-    bound = share_rows(assignments, held, num_experts)
+    bound = share_rows(assignments, held, num_experts, crowding)
 
     def over(rows):
         # nothing between the matmuls is kept for the backward: the cond

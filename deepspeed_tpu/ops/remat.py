@@ -17,6 +17,11 @@ that what makes them runs once a step:
                      is a top-k of biased scores (~10 MB a layer: nearly free)
     ds.mixer.out     a mixer's output projection (``o_proj``, ``out_proj``)
                      whose contraction is deeper than the hidden size
+    ds.kda.scan      a Kimi Delta Attention scan's output and the float32
+                     states leaving its chunks (``ops/kda.py``: 1.34 GB a
+                     layer at 32,768 tokens, 32 heads of 128 and chunks of
+                     64); a layer without them runs ``kda_chunk_fwd`` once
+                     more in its backward
     ds.ffn.in        a dense FFN's ``gate`` / ``up`` (``fc1``) outputs, a
                      shared expert's too
     ds.mixer.in      a mixer's input projections as they leave their matmuls
@@ -63,13 +68,14 @@ DSA_CHOICE = ("ds.dsa.tau", "ds.dsa.tie")
 DSA_MASK = "ds.dsa.mask"
 ROUTE = "ds.moe.route"
 MIXER_OUT = "ds.mixer.out"
+KDA_SCAN = "ds.kda.scan"
 FFN_IN = "ds.ffn.in"
 MIXER_IN = "ds.mixer.in"
 MIXER_OUT_NARROW = "ds.mixer.out.narrow"
 KERNEL_OUT = "ds.mixer.kernel"
 # the walk's order: ms of recomputation returned a byte, falling
-CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, FFN_IN, MIXER_IN, MIXER_OUT_NARROW,
-                   KERNEL_OUT)
+CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, KDA_SCAN, FFN_IN, MIXER_IN,
+                   MIXER_OUT_NARROW, KERNEL_OUT)
 KEPT_NAMES = RESIDUAL_NAMES + DSA_CHOICE + CANDIDATE_NAMES
 
 # The step's own temporaries, in one place. Compiled for a described v5e

@@ -106,7 +106,7 @@ def _as_apply_fns(model):
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
                                              "mla_stats", "diffusion_stats",
-                                             "dsa_stats"])
+                                             "dsa_stats", "kda_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -120,7 +120,7 @@ def _as_apply_fns(model):
                 # its last axis, a scalar a block (``rows_held``,
                 # ``share_fallback``: a block that holds a share) is summed
                 name = next(k.key for k in reversed(path) if hasattr(k, "key"))
-                total = (jnp.sum(leaf) if name != "expert_counts"
+                total = (jnp.sum(leaf) if name not in ("expert_counts", "group_counts")
                          else leaf.reshape(-1, leaf.shape[-1]).sum(axis=0))
                 stats[name] = stats[name] + total if name in stats else total
             if stats and aux:
@@ -148,6 +148,16 @@ def _as_apply_fns(model):
                 if sown:
                     sown = jnp.concatenate(sown)
                     stats["dsa_" + name] = reduce(sown) if reduce else sown
+            # "kda_stats" for a Kimi Delta Attention mixer: ``state_absmax``
+            # comes back as the largest over the layers, ``decay_mean`` and
+            # ``beta_mean`` as the layers' means
+            kda = jax.tree_util.tree_flatten_with_path(mods.get("kda_stats", {}))[0]
+            for name, reduce in (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
+                                 ("beta_mean", jnp.mean)):
+                sown = [leaf.reshape(-1) for path, leaf in kda
+                        if path[-1].key == name]
+                if sown:
+                    stats["kda_" + name] = reduce(jnp.concatenate(sown))
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -1741,6 +1751,18 @@ class DeepSpeedTpuEngine:
                 "Mean step size dt = softplus(dt + dt_bias) of the "
                 "state-space layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["ssm_dt_mean"]) for s in fetched])))
+        if "kda_state_absmax" in fetched[0]:
+            reg.gauge(
+                "ds_kda_state_absmax",
+                "Largest |S| the Kimi Delta Attention scans held (the chunks' "
+                "states where the kernels run), over the layers and the steps "
+                "of the last publish"
+            ).set(float(max(np.max(s["kda_state_absmax"]) for s in fetched)))
+            reg.gauge(
+                "ds_kda_decay_mean",
+                "Mean decay exp(g) a key channel and token of the Kimi Delta "
+                "Attention layers, over the steps of the last publish"
+            ).set(float(np.mean([np.mean(s["kda_decay_mean"]) for s in fetched])))
         for name, what in (("latent_rms", "the latent before kv_a_layernorm"),
                            ("k_rope_rms", "the shared rope key")):
             if "mla_" + name in fetched[0]:
@@ -2173,7 +2195,8 @@ class DeepSpeedTpuEngine:
         A device→host fetch that waits for that step; ``None`` for a model
         that sows none."""
         return self._newest_stats(
-            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_")))
+            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_",
+                                              "kda_")))
 
     def diffusion_stats(self):
         """What the block-diffusion objective sowed in the newest fused step
@@ -2196,6 +2219,15 @@ class DeepSpeedTpuEngine:
         :meth:`moe_stats`; ``None`` for a model without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("ssm_"))
         return stats and {name[len("ssm_"):]: v for name, v in stats.items()}
+
+    def kda_stats(self):
+        """What the Kimi Delta Attention layers sowed in the newest fused
+        step not yet published, as host scalars: ``state_absmax`` (the largest
+        |S| over the layers), ``decay_mean`` (of ``exp(g)``) and ``beta_mean``
+        (the layers' means). Waits for that step, as :meth:`moe_stats`;
+        ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("kda_"))
+        return stats and {name[len("kda_"):]: v for name, v in stats.items()}
 
     def mla_stats(self):
         """What the latent-attention layers sowed in the newest fused step

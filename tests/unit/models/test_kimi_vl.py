@@ -221,10 +221,14 @@ def test_policy_reads_the_catalogs_keys_and_the_cells_file():
     if row:     # the file holds every published key as published but the three cut
         for key, value in row["config"].items():
             assert body[key] == value or key in body["reduced"], key
-    for key, value in (("q_lora_rank", 1536), ("n_group", 8),
+    for key, value in (("q_lora_rank", 1536), ("moe_layer_freq", 2),
                        ("rope_scaling", {"type": "yarn"}), ("scoring_func", "softmax")):
         with pytest.raises(ValueError, match="deepseek_v3"):
             DeepseekV3Policy().config_from_hf({**body, key: value})
+    # expert groups are read since PR 48 (they were refused by name before it)
+    grouped = DeepseekV3Policy().config_from_hf({**body, "n_group": 8, "topk_group": 4})
+    assert (grouped.moe_n_group, grouped.moe_topk_group) == (8, 4)
+    assert (cfg.moe_n_group, cfg.moe_topk_group, cfg.attn_output_gate) == (1, 1, None)
 
 
 def test_the_shared_expert_has_two_forms_and_a_weight_map():

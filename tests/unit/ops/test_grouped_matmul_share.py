@@ -357,3 +357,29 @@ def test_which_side_walks_is_a_rule_of_the_shape_alone():
     assert gm.share_walks_rows(share_rows(131072, 16, 64), 131072)
     assert not gm.share_walks_rows(131072, 131072)
     assert not gm.share_walks_rows(share_rows(131072, 32, 64), 131072)
+
+
+@pytest.mark.parametrize("crowding,fallback", [(1, True), (2, False)])
+def test_a_share_inside_one_routing_group_has_rows_for_every_token_keeping_it(
+        crowding, fallback):
+    """Every token keeps the half of the experts that holds the share's (a
+    group-limited router with 2 groups of which 1 is kept, ``crowding`` 2):
+    the share gets twice its even share and more, which outruns twice the
+    even share and not twice what such a router sends at most."""
+    assert share_rows(1024, 2, 16, 2) == 512 == share_rows(1024, 4, 16)
+    assert share_rows(256, 8, 16, 2) == 256                  # never more than all
+    x, w1, w3, w2, _, _ = _layer()
+    logits = jax.random.normal(jax.random.PRNGKey(9), (T, E))
+    logits = jnp.where(jnp.arange(E) < E // 2, logits, -jnp.inf)     # group 0 of 2
+    p, idx = jax.lax.top_k(jax.nn.sigmoid(logits), K)
+    p = p / p.sum(-1, keepdims=True)
+    y, rows, fell = moe_grouped_mlp_share(x, w1[:HELD], w3[:HELD], w2[:HELD], idx, p,
+                                          first_expert=0, num_experts=E,
+                                          crowding=crowding)
+    assert share_rows(T * K, HELD, E) < int(rows) <= share_rows(T * K, HELD, E, 2)
+    assert bool(fell) == fallback
+    held = jnp.where(idx < HELD, p, 0.0)                     # the others add nothing
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(moe_dense_mlp(x, w1, w3, w2, idx, held)),
+                               rtol=0, atol=2e-6)
+

@@ -1,0 +1,166 @@
+"""Kimi Delta Attention's kernels (``kda_chunk_fwd``, ``kda_chunk_bwd``)
+compiled for a described (not attached) TPU v5e at Ling-3.0-flash's published
+widths and the cell's 1 x 32,768 tokens, in the engine's fused step: no chip
+time, nothing runs.
+
+A file of its own beside ``test_tpu_aot_compile_mla.py`` (a worker's whole
+share under ``--dist loadfile``), whose ``step_of`` spells the step out: two
+layers of the cell's configuration (a KDA layer with the dense FFN and one with
+experts), compiled ONCE for the module. The whole six-layer cell by hand
+before a chip call: ``python tests/unit/ops/test_tpu_aot_compile_kda.py
+[layers] [seq]`` (its temporaries beside 12 B a parameter are in PERF.md).
+"""
+
+import dataclasses
+import importlib
+import json
+import pathlib
+import re
+import sys
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import test_tpu_aot_compile_mla as mla
+
+ROOT = pathlib.Path(__file__).parents[3]
+CELL = "train-ling3flash-1chip-kda-longseq"
+HEADS, D, CHUNK = 32, 128, 64
+# what the chip holds at rest when the six-layer cell's step is first traced:
+# 12 B for each of its 767,009,056 parameters
+IN_USE = 9_204_108_672
+
+
+def cell_config(layers: int, seq=None):
+    sys.path.insert(0, str(ROOT))
+    bench = ROOT / "benchmark"
+    workload = json.loads((bench / "workloads" / f"{CELL}.json").read_text())
+    config = json.loads((bench / "configs" / f"{workload['config']}.json").read_text())
+    cfg = importlib.import_module(
+        f"benchmark.runners.{workload['runner']}").model_config(config)
+    return (dataclasses.replace(cfg, num_hidden_layers=layers,
+                                layer_specs=cfg.layer_specs[:layers]),
+            workload["traffic"]["global_batch"], seq or workload["traffic"]["seq_len"])
+
+
+def steer_to_the_chip(setattr_, in_use=IN_USE):
+    mla.steer_to_the_chip(setattr_)
+    from deepspeed_tpu.ops import remat
+    setattr_(remat, "device_memory", lambda: (mla.V5E_BYTES_LIMIT, in_use))
+    remat.forget_plans()
+
+
+@pytest.fixture(scope="module")
+def step():
+    """Two layers at the published widths and the cell's batch, traced and
+    compiled for a described v5e, once."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    patch = pytest.MonkeyPatch()
+    try:
+        # two layers leave the described chip room: both keep their scan
+        steer_to_the_chip(patch.setattr, in_use=3_000_000_000)
+        cfg, rows, seq = cell_config(2)
+        traced, n_params = mla.step_of(cfg, rows, seq, SingleDeviceSharding(topo.devices[0]))
+        yield {"cfg": cfg, "rows": rows, "seq": seq, "traced": traced,
+               "n_params": n_params, "compiled": traced.lower().compile()}
+    finally:
+        patch.undo()
+        from deepspeed_tpu.ops import remat
+        remat.forget_plans()
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def test_every_kda_layer_scans_once_a_step_and_keeps_what_its_backward_reads(step):
+    """Under whole-layer recomputation a layer whose plan keeps ``ds.kda.scan``
+    runs ``kda_chunk_fwd`` once (its output and chunk states are handed to the
+    recomputed layer's backward) and ``kda_chunk_bwd`` once; the three
+    convolutions run forward and backward; the kept bytes hold the scans."""
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.ops import remat
+    from deepspeed_tpu.ops.kda import scan_bytes
+    names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
+             for line in mla.custom_calls(step["compiled"])]
+    kernels = {n: names.count(n) for n in set(names)}
+    assert kernels["kda_chunk_fwd"] == 2 and kernels["kda_chunk_bwd"] == 2, kernels
+    assert kernels["causal_conv_bwd"] == 6, kernels
+    plan = next(iter(remat._PLANS.values()))
+    assert all(remat.KDA_SCAN in names for names in plan), plan
+    rows, seq = step["rows"], step["seq"]
+    scans = 2 * scan_bytes(rows, seq, HEADS, D, D, CHUNK, 2)
+    assert scans == 2 * (rows * seq * HEADS * D * 2 + rows * (seq // CHUNK) * HEADS * D * D * 4)
+    kept = kept_residual_bytes(step["traced"].jaxpr)
+    assert kept - kept_residual_bytes(
+        step["traced"].jaxpr, tuple(n for n in remat.KEPT_NAMES if n != remat.KDA_SCAN)
+    ) == scans
+
+
+def test_the_kernels_shapes_are_what_the_cost_file_reads_and_the_scopes_stand(step):
+    """``kda_chunk_fwd`` writes ``o [rows, seq, heads * 128]`` first and the
+    chunk states ``[rows, seq / 64, 128, heads * 128]`` in float32,
+    ``kda_chunk_bwd`` writes ``dq`` first (``benchmark/kda_cost.py`` reads
+    the first result); ``ds.kda.gates`` and ``ds.kda.norm`` are on the ops
+    around the kernels, closed before their call (the instructions keep their
+    names); no array of ``seq x seq`` is in the program."""
+    sys.path.insert(0, str(ROOT))
+    from benchmark import kda_cost
+    rows, seq = step["rows"], step["seq"]
+    calls = {line.split(" = ")[0].split("%")[-1].split(".")[0]: line
+             for line in mla.custom_calls(step["compiled"])}
+    o = f"bf16[{rows},{seq},{HEADS * D}]"
+    fwd = calls["kda_chunk_fwd"].split("custom-call(")[0]
+    assert fwd.index(o) < fwd.index(f"f32[{rows},{seq // CHUNK},{D},{HEADS * D}]")
+    assert o in calls["kda_chunk_bwd"].split("custom-call(")[0]
+    config = {"kda_chunk_size": CHUNK, "head_dim": D}
+    for name in ("kda_chunk_fwd", "kda_chunk_bwd"):
+        hlo = "%" + calls[name].split("%", 1)[1]
+        cost = kda_cost.call_cost(hlo, config)
+        assert cost is not None and cost["flops"] > 0 and cost["bytes"] > 0, hlo[:200]
+    text = step["compiled"].as_text()
+    assert not re.search(rf"\[(\d+,)*{seq},{seq}\]", text)
+    for scope in ("ds.step.loss", "ds.kda.gates", "ds.kda.norm", "ds.moe.route",
+                  "ds.head.loss"):
+        assert f"/{scope}/" in text, scope
+    temporaries = step["compiled"].memory_analysis().temp_size_in_bytes
+    assert temporaries + 12 * step["n_params"] <= mla.V5E_BYTES_LIMIT - 0.8e9
+
+
+if __name__ == "__main__":
+    # the whole cell by hand:
+    # python tests/unit/ops/test_tpu_aot_compile_kda.py [layers] [seq] [names]
+    # (names "none": every candidate dropped, the fallback's question)
+    import time
+    from jax.experimental import topologies
+    sys.path.insert(0, str(ROOT))
+    from deepspeed_tpu.observability.xla import kept_residual_bytes
+    from deepspeed_tpu.ops import remat
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    in_use = mla.V5E_BYTES_LIMIT if "none" in sys.argv[3:] else IN_USE
+    steer_to_the_chip(pytest.MonkeyPatch().setattr, in_use=in_use)
+    cfg, rows, seq = cell_config(int(sys.argv[1]) if len(sys.argv) > 1 else 6,
+                                 int(sys.argv[2]) if len(sys.argv) > 2 else None)
+    t0 = time.monotonic()
+    traced, n_params = mla.step_of(cfg, rows, seq, SingleDeviceSharding(topo.devices[0]))
+    compiled = traced.lower().compile()
+    mem = compiled.memory_analysis()
+    names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
+             for line in mla.custom_calls(compiled)]
+    print({n: names.count(n) for n in sorted(set(names))})
+    print("plan:", next(iter(remat._PLANS.values()), None))
+    print(f"{rows} x {seq}: {n_params} parameters, 12 B each {12 * n_params / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, kept residuals "
+          f"{kept_residual_bytes(traced.jaxpr) / 1e9:.3f} GB, together "
+          f"{(12 * n_params + mem.temp_size_in_bytes) / 1e9:.3f} GB of "
+          f"{mla.V5E_BYTES_LIMIT / 1e9:.3f} GB; arrays of seq x seq: "
+          f"{len(re.findall(rf'[\\[,]{seq},{seq}\\]', compiled.as_text()))}; "
+          f"{time.monotonic() - t0:.0f} s")
