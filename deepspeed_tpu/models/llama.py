@@ -958,7 +958,7 @@ class KimiDeltaMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.kda import GATE_FLOOR, bounded_gate, kda_scan
+        from ..ops.kda import GATE_FLOOR, bounded_gate, grid_of, kda_scan
         from ..ops.short_conv import causal_conv
         cfg = self.config
         H, d = cfg.num_attention_heads, cfg.kda_head_dim
@@ -1023,12 +1023,12 @@ class KimiDeltaMixer(nn.Module):
             rate = jnp.exp(a_log)
             beta = jax.nn.sigmoid(beta_in.astype(f32))
         want_stats = self.is_mutable_collection("kda_stats")
+        use_kernel, interpret = kernels and d % 128 == 0, interpret_kernels() and d % 128 == 0
         # the kernels make the gate g = floor * sigmoid(rate * (f_proj + dt_bias))
         # and its running sum themselves (ops/kda.py)
         o = kda_scan(q, k, v, decay_in.reshape(b, s, H, d), rate, dt_bias, beta,
                      cfg.kda_chunk_size, floor=cfg.kda_gate_floor,
-                     use_kernel=kernels and d % 128 == 0,
-                     interpret=interpret_kernels() and d % 128 == 0,
+                     use_kernel=use_kernel, interpret=interpret,
                      with_state_absmax=want_stats, keep=remat.keeps(remat.KDA_SCAN))
         if want_stats:
             o, top = o
@@ -1040,6 +1040,13 @@ class KimiDeltaMixer(nn.Module):
             for name, value in (("decay_mean", decay), ("beta_mean", jnp.mean(beta))):
                 self.sow("kda_stats", name, jax.lax.stop_gradient(value),
                          reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+            if use_kernel or interpret:
+                # how the kernels' grid was laid over this call: the heads a
+                # grid step took and the steps a call (kernel_dispatch.choose_kda_heads)
+                grid = grid_of(b, s, H, d, cfg.kda_chunk_size, jnp.dtype(v.dtype).itemsize)
+                for name, value in zip(("head_block", "grid_steps"), grid):
+                    self.sow("kda_stats", name, jnp.asarray(value, f32),
+                             reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
         with jax.named_scope("ds.kda.norm"):
             y = gated_norm(o, gate_in, o_norm)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,

@@ -40,12 +40,17 @@ lower triangular, so ``(I + A)^{-1} = (I - A)(I + A^2)(I + A^4)...`` exactly
 (``A^Q = 0``): ``2 log2(Q) - 2`` matmuls of ``Q x Q`` in float32
 (``contract_precision<fp32>``), no approximation and no ``[seq, seq]`` array.
 
-A chunk and head is one grid step of ``kda_chunk_fwd``: the chunks of a
-sequence run in order with the state carried in VMEM in float32, TRANSPOSED
-(``[d_v, d_k]``: the decay then scales lanes), the states leaving the chunks
-written out for the backward and the largest ``|S|`` kept as the kernel goes
-(``kda_stats``). ``kda_chunk_bwd`` walks the chunks in reverse with the
-state's gradient in VMEM, makes ``A``, ``P``, ``T`` and ``U`` again from the
+A chunk of a BLOCK of heads is one grid step of ``kda_chunk_fwd`` (``block *
+d`` contiguous lanes of every operand; how many heads follows from the shape,
+``kernel_dispatch.choose_kda_heads``): a head's chunk is one long chain of
+small dependent matmuls, which alone leaves the MXUs waiting, so the heads of
+the block, which share no value, are issued a matmul stage abreast
+(``_abreast``), each head's mathematics what it is alone, bit for bit. The
+chunks of a sequence run in order with each head's state carried in VMEM in
+float32, TRANSPOSED (``[d_v, d_k]``: the decay then scales lanes), the states
+leaving the chunks written out for the backward and the largest ``|S|`` kept
+as the kernel goes (``kda_stats``). ``kda_chunk_bwd`` walks the chunks in
+reverse with the state's gradient in VMEM, makes ``A``, ``P``, ``T`` and ``U`` again from the
 inputs and the saved state, and returns the gradients of ``q``, ``k``, ``kb``,
 ``vb`` and ``pre`` and, summed over the tokens as it goes, of ``bias`` and
 ``rate`` (a lane each); ``beta``'s follows outside (a product, XLA's).
@@ -121,10 +126,29 @@ def _dot(a, b, dims=((1, ), (0, )), precision=None):
                                preferred_element_type=jnp.float32)
 
 
+def _abreast(chains):
+    """What each of the generators ``chains`` returns, every one advanced a
+    stage (a ``yield``: a matmul or a few that wait for nothing of each
+    other) in turn. The heads of a grid step's block share no value, but an
+    MXU takes its matmuls in program order: written one head after another,
+    a head's first product queues behind the last of the head before it, and
+    the heads' chains run end to end; written a stage abreast, each chain's
+    waits are filled by the others' work."""
+    chains, done = list(chains), {}
+    while len(done) < len(chains):
+        for i, chain in enumerate(chains):
+            if i not in done:
+                try:
+                    next(chain)
+                except StopIteration as end:
+                    done[i] = end.value
+    return [done[i] for i in range(len(chains))]
+
+
 def _triangles(q, k, kb, c, mm):
-    """-> ``A`` (strictly lower), ``P`` (lower), both ``[Q, Q]`` float32, and
-    for each block of ``SUB`` rows what made it (the backward's operands):
-    the columns' decay ``[Q, d]`` float32 (0 after the block), the columns
+    """A chain (``_abreast``) -> ``A`` (strictly lower), ``P`` (lower), both
+    ``[Q, Q]`` float32, and for each block of ``SUB`` rows what made it (the
+    backward's operands): the columns' decay ``[Q, d]`` float32 (0 after the block), the columns
     ``k * decay`` in ``mm``, the rows' decay ``[SUB, d]`` float32 and the
     rows of ``kb`` and ``q`` times it in ``mm``."""
     f32 = jnp.float32
@@ -143,6 +167,7 @@ def _triangles(q, k, kb, c, mm):
         a_rows.append(_dot(rk, cols, _NT))
         p_rows.append(_dot(rq, cols, _NT))
         blocks.append((decay, cols, grow, rk, rq))
+    yield
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     A = jnp.where(row > col, jnp.concatenate(a_rows, axis=0), 0.0)
@@ -151,8 +176,8 @@ def _triangles(q, k, kb, c, mm):
 
 
 def _inverse(A):
-    """``(I + A)^{-1}`` of a strictly lower triangular ``[Q, Q]`` float32
-    ``A``: ``(I - A)(I + A^2)(I + A^4)...`` up to ``A^{Q/2}``, exact because
+    """A chain -> ``(I + A)^{-1}`` of a strictly lower triangular ``[Q, Q]``
+    float32 ``A``: ``(I - A)(I + A^2)(I + A^4)...`` up to ``A^{Q/2}``, exact because
     ``A^Q = 0``; the factors are polynomials in ``A`` and commute."""
     Q = A.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
@@ -161,14 +186,16 @@ def _inverse(A):
     power, n = A, 2
     while n < Q:
         power = _dot(power, power, precision=_HIGHEST)
+        yield
         T = T + _dot(T, power, precision=_HIGHEST)
+        yield
         n *= 2
     return T
 
 
 def _chunk(q, k, kb, vb, pre, lanes, floor, Z, mm):
-    """What both kernels make of a chunk and the state entering it (``Z``,
-    ``[d_v, d_k]``: the state transposed). ``lanes``: the head's rate in row
+    """A chain -> what both kernels make of a chunk and the state entering it
+    (``Z``, ``[d_v, d_k]``: the state transposed). ``lanes``: the head's rate in row
     0 and the channels' bias in row 1."""
     f32 = jnp.float32
     Q = pre.shape[0]
@@ -178,19 +205,47 @@ def _chunk(q, k, kb, vb, pre, lanes, floor, Z, mm):
     ones = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
                      >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1), 1.0, 0.0)
     c = _dot(ones, floor * sig, precision=_HIGHEST)       # the running sum of g
-    A, P, blocks = _triangles(q, k, kb, c, mm)
-    T = _inverse(A)
+    yield
+    A, P, blocks = yield from _triangles(q, k, kb, c, mm)
+    T = yield from _inverse(A)
     E = jnp.exp(c)
     qp, kbp = q.astype(f32) * E, kb.astype(f32) * E
     zs = _split(Z, mm)
     R = vb.astype(f32) - sum(_dot(kbp.astype(mm), z, _NT) for z in zs)
+    yield
     U = _dot(T, R, precision=_HIGHEST)                           # [Q, d_v]
+    yield
     cend = c[-1:, :]
     to_end = jnp.exp(cend - c)
     ke = k.astype(f32) * to_end
     return dict(A=A, P=P, blocks=blocks, T=T, E=E, qp=qp, kbp=kbp, zs=zs, U=U,
                 cend=cend, to_end=to_end, ke=ke, c=c, ones=ones, sig=sig, rate=rate,
                 shifted=shifted)
+
+
+def _fwd_head(q, k, kb, vb, pre, lanes, Z, top, floor, mm):
+    """A chain, one head's chunk: -> its output ``[Q, d_v]``, the state leaving
+    the chunk and the largest ``|S|`` so far, eight rows a lane."""
+    w = yield from _chunk(q, k, kb, vb, pre, lanes, floor, Z, mm)
+    Umm = w["U"].astype(mm)
+    o = (sum(_dot(w["qp"].astype(mm), z, _NT) for z in w["zs"])
+         + _dot(w["P"].astype(mm), Umm))
+    yield
+    new = jnp.exp(w["cend"]) * Z + _dot(Umm, w["ke"].astype(mm), _TN)
+    yield
+    # the state is in VMEM here, so the statistic costs no pass over the
+    # saved states
+    size = jnp.abs(new)
+    for r in range(0, size.shape[0], SUBLANES):
+        top = jnp.maximum(top, size[r:r + SUBLANES])
+    return o, new, top
+
+
+def _heads(state):
+    """The lane slices of the heads of a grid step's block (``state``: the
+    scratch, ``[Hb, d, d]``): a head is ``d`` lanes of every block."""
+    block, d = state.shape[:2]
+    return [slice(h * d, (h + 1) * d) for h in range(block)]
 
 
 def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, o_ref, st_ref,
@@ -202,46 +257,34 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, o_ref, st_ref,
         state[...] = jnp.zeros_like(state)
         top_ref[...] = jnp.zeros_like(top_ref)
 
-    Z = state[...]
-    w = _chunk(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], pre_ref[0], lanes_ref[...],
-               floor, Z, mm)
-    Umm = w["U"].astype(mm)
-    o = (sum(_dot(w["qp"].astype(mm), z, _NT) for z in w["zs"])
-         + _dot(w["P"].astype(mm), Umm))
-    o_ref[0] = o.astype(o_ref.dtype)
-    new = jnp.exp(w["cend"]) * Z + _dot(Umm, w["ke"].astype(mm), _TN)
-    state[...] = new
-    st_ref[0, 0] = new
-    # the largest |S| so far, eight rows a lane: the state is in VMEM here,
-    # so the statistic costs no pass over the saved states
-    size, top = jnp.abs(new), top_ref[0, 0]
-    for r in range(0, size.shape[0], SUBLANES):
-        top = jnp.maximum(top, size[r:r + SUBLANES])
-    top_ref[0, 0] = top
+    heads = list(enumerate(_heads(state)))
+    done = _abreast(
+        _fwd_head(q_ref[0, :, sl], k_ref[0, :, sl], kb_ref[0, :, sl],
+                  vb_ref[0, :, sl], pre_ref[0, :, sl], lanes_ref[:, sl],
+                  state[h], top_ref[0, h], floor, mm)
+        for h, sl in heads)
+    for (h, sl), (o, new, top) in zip(heads, done):
+        o_ref[0, :, sl] = o.astype(o_ref.dtype)
+        state[h] = new
+        st_ref[0, 0, :, sl] = new
+        top_ref[0, h] = top
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, do_ref, st_ref,
-                dq_ref, dk_ref, dkb_ref, dvb_ref, dpre_ref, dlanes_ref, dstate, *, floor):
-    f32, mm = jnp.float32, q_ref.dtype
-    step, steps = pl.program_id(2), pl.num_programs(2)
-
-    @pl.when(step == 0)
-    def _():
-        dstate[...] = jnp.zeros_like(dstate)
-        dlanes_ref[...] = jnp.zeros_like(dlanes_ref)
-
-    q, k, kb = q_ref[0], k_ref[0], kb_ref[0]
+def _bwd_head(q, k, kb, vb, pre, lanes, dO, Z, dZ, floor, mm):
+    """A chain, one head's chunk backward: -> dq, dk, d(kb), d(vb), d(pre)
+    ``[Q, d]`` float32, the state's gradient entering the chunk before it and the
+    two lanes ``[1, d]`` the bias and the rate receive from this chunk."""
+    f32 = jnp.float32
     Q = q.shape[0]
-    # the chunks run in reverse: the last step is the sequence's first chunk,
-    # which no state enters
-    Z = jnp.where(step == steps - 1, 0.0, st_ref[0, 0])
-    w = _chunk(q, k, kb, vb_ref[0], pre_ref[0], lanes_ref[...], floor, Z, mm)
+    w = yield from _chunk(q, k, kb, vb, pre, lanes, floor, Z, mm)
     c = w["c"]
-    zs, Umm, dO, dZ = w["zs"], w["U"].astype(mm), do_ref[0], dstate[...]
+    zs, Umm = w["zs"], w["U"].astype(mm)
     dzs = _split(dZ, mm)
     kemm = w["ke"].astype(mm)
     dU = _dot(w["P"].astype(mm), dO, _TN) + sum(_dot(kemm, z, _NT) for z in dzs)
+    yield
     dR = _dot(w["T"], dU, _TN, precision=_HIGHEST)               # T^T dU
+    yield
     dRmm = dR.astype(mm)
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
@@ -250,8 +293,9 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, do_ref, st_ref
     dqp = sum(_dot(dO, z) for z in zs)                           # [Q, d_k]
     dkbp = -sum(_dot(dRmm, z) for z in zs)
     dke = sum(_dot(Umm, z) for z in dzs)
-    dstate[...] = (jnp.exp(w["cend"]) * dZ + _dot(dO, w["qp"].astype(mm), _TN)
-                   - _dot(dRmm, w["kbp"].astype(mm), _TN))
+    before = (jnp.exp(w["cend"]) * dZ + _dot(dO, w["qp"].astype(mm), _TN)
+              - _dot(dRmm, w["kbp"].astype(mm), _TN))
+    yield
     via_end = dke * w["ke"]
     dq = dqp * w["E"]
     dkb = dkbp * w["E"]
@@ -274,72 +318,102 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, do_ref, st_ref
         through = (_dot(dPi, rq, _TN) + _dot(dAi, rk, _TN)) * decay      # [Q, d_k]
         dk = dk + through
         dc = dc - through * kf
-    dq_ref[0] = (dq + jnp.concatenate(dq_rows, axis=0)).astype(dq_ref.dtype)
-    dkb_ref[0] = (dkb + jnp.concatenate(dkb_rows, axis=0)).astype(dkb_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dvb_ref[0] = dR.astype(dvb_ref.dtype)
+    yield
+    dq = dq + jnp.concatenate(dq_rows, axis=0)
+    dkb = dkb + jnp.concatenate(dkb_rows, axis=0)
     dc = dc + jnp.concatenate(dc_rows, axis=0)
     # g_s reaches every c_t with t >= s; then through the bounded gate
     dg = _dot(w["ones"], dc, _TN, precision=_HIGHEST)
+    yield
     dz = dg * floor * w["sig"] * (1.0 - w["sig"])
-    dpre_ref[0] = (dz * w["rate"]).astype(dpre_ref.dtype)
-    # the bias's and the rate's, summed over the tokens as the kernel goes:
-    # rows 0 and 1 of a block held over the chunks
-    dlanes_ref[0, 0:1, :] += jnp.sum(dz * w["rate"], axis=0, keepdims=True)
-    dlanes_ref[0, 1:2, :] += jnp.sum(dz * w["shifted"], axis=0, keepdims=True)
+    dpre = dz * w["rate"]
+    return (dq, dk, dkb, dR, dpre, before, jnp.sum(dpre, axis=0, keepdims=True),
+            jnp.sum(dz * w["shifted"], axis=0, keepdims=True))
 
 
-def _compiler_params(chunk: int, width: int, itemsize: int, arrays: int):
-    # double-buffered [chunk, width] blocks, the state's float32 blocks and
-    # scratch, and some four dozen [chunk, width] float32 temporaries
-    from .kernel_dispatch import vmem_limit_bytes
-    limit = vmem_limit_bytes(2 * arrays * chunk * width * max(itemsize, 4)
-                             + 6 * 4 * width * width + 48 * 4 * chunk * width)
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, pre_ref, lanes_ref, do_ref, st_ref,
+                dq_ref, dk_ref, dkb_ref, dvb_ref, dpre_ref, dlanes_ref, dstate, *, floor):
+    mm = q_ref.dtype
+    step, steps = pl.program_id(2), pl.num_programs(2)
+
+    @pl.when(step == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+        dlanes_ref[...] = jnp.zeros_like(dlanes_ref)
+
+    # the chunks run in reverse: the last step is the sequence's first chunk,
+    # which no state enters
+    heads = list(enumerate(_heads(dstate)))
+    done = _abreast(
+        _bwd_head(q_ref[0, :, sl], k_ref[0, :, sl], kb_ref[0, :, sl],
+                  vb_ref[0, :, sl], pre_ref[0, :, sl], lanes_ref[:, sl],
+                  do_ref[0, :, sl],
+                  jnp.where(step == steps - 1, 0.0, st_ref[0, 0, :, sl]),
+                  dstate[h], floor, mm)
+        for h, sl in heads)
+    for (h, sl), (*grads, before, dbias, drate) in zip(heads, done):
+        for ref, grad in zip((dq_ref, dk_ref, dkb_ref, dvb_ref, dpre_ref), grads):
+            ref[0, :, sl] = grad.astype(ref.dtype)
+        dstate[h] = before
+        # the bias's and the rate's, summed over the tokens as the kernel
+        # goes: rows 0 and 1 of a block held over the chunks
+        dlanes_ref[0, 0:1, sl] += dbias
+        dlanes_ref[0, 1:2, sl] += drate
+
+
+def _compiler_params(chunk: int, d: int, block: int, itemsize: int, arrays: int):
+    from .kernel_dispatch import kda_vmem_bytes, vmem_limit_bytes
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=limit)
+        vmem_limit_bytes=vmem_limit_bytes(
+            kda_vmem_bytes(block, d, chunk, itemsize, arrays)))
 
 
-def _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret):
+def _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, block):
+    """A grid step is a chunk of ``block`` heads (``kernel_dispatch.
+    choose_kda_heads``): ``block * d`` contiguous lanes of every operand."""
     b, s, width = q.shape
     d, nc = width // heads, s // chunk
-    x = pl.BlockSpec((1, chunk, d), lambda b, h, n: (b, n, h))
+    x = pl.BlockSpec((1, chunk, block * d), lambda b, h, n: (b, n, h))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, floor=floor),
-        grid=(b, heads, nc),
-        in_specs=[x] * 5 + [pl.BlockSpec((SUBLANES, d), lambda b, h, n: (0, h))],
+        grid=(b, heads // block, nc),
+        in_specs=[x] * 5 + [pl.BlockSpec((SUBLANES, block * d), lambda b, h, n: (0, h))],
         out_specs=[x,
-                   pl.BlockSpec((1, 1, d, d), lambda b, h, n: (b, n, 0, h)),
+                   pl.BlockSpec((1, 1, d, block * d), lambda b, h, n: (b, n, 0, h)),
                    # one block a (batch, head), held over its chunks
-                   pl.BlockSpec((1, 1, SUBLANES, d), lambda b, h, n: (b, h, 0, 0))],
+                   pl.BlockSpec((1, block, SUBLANES, d), lambda b, h, n: (b, h, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, nc, d, width), jnp.float32),
                    jax.ShapeDtypeStruct((b, heads, SUBLANES, d), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_compiler_params(chunk, d, q.dtype.itemsize, 6),
+        scratch_shapes=[pltpu.VMEM((block, d, d), jnp.float32)],
+        compiler_params=_compiler_params(chunk, d, block, q.dtype.itemsize, 6),
         interpret=interpret,
         name="kda_chunk_fwd",
     )(q, k, kb, vb, pre, lanes)
 
 
-def _bwd_call(q, k, kb, vb, pre, lanes, states, do, heads, chunk, floor, interpret):
+def _bwd_call(q, k, kb, vb, pre, lanes, states, do, heads, chunk, floor, interpret,
+              block):
     b, s, width = q.shape
     d, nc = width // heads, s // chunk
-    x = pl.BlockSpec((1, chunk, d), lambda b, h, n: (b, nc - 1 - n, h))
+    x = pl.BlockSpec((1, chunk, block * d), lambda b, h, n: (b, nc - 1 - n, h))
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)      # noqa: E731
     *grads, dlanes = pl.pallas_call(
         functools.partial(_bwd_kernel, floor=floor),
-        grid=(b, heads, nc),
-        in_specs=[x] * 5 + [pl.BlockSpec((SUBLANES, d), lambda b, h, n: (0, h)), x] + [
+        grid=(b, heads // block, nc),
+        in_specs=[x] * 5 + [pl.BlockSpec((SUBLANES, block * d),
+                                         lambda b, h, n: (0, h)), x] + [
             # the state ENTERING the chunk is the one the chunk before it
             # wrote; chunk 0 reads a block it does not use
-            pl.BlockSpec((1, 1, d, d), lambda b, h, n: (
+            pl.BlockSpec((1, 1, d, block * d), lambda b, h, n: (
                 b, jnp.maximum(nc - 2 - n, 0), 0, h))],
-        out_specs=[x] * 5 + [pl.BlockSpec((1, SUBLANES, d), lambda b, h, n: (b, 0, h))],
+        out_specs=[x] * 5 + [pl.BlockSpec((1, SUBLANES, block * d),
+                                          lambda b, h, n: (b, 0, h))],
         out_shape=[like(q), like(k), like(kb), like(vb), like(pre),
                    jax.ShapeDtypeStruct((b, SUBLANES, width), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_compiler_params(chunk, d, q.dtype.itemsize, 11),
+        scratch_shapes=[pltpu.VMEM((block, d, d), jnp.float32)],
+        compiler_params=_compiler_params(chunk, d, block, q.dtype.itemsize, 11),
         interpret=interpret,
         name="kda_chunk_bwd",
     )(q, k, kb, vb, pre, lanes, do.astype(q.dtype), states)
@@ -349,14 +423,16 @@ def _bwd_call(q, k, kb, vb, pre, lanes, states, do, heads, chunk, floor, interpr
     return (*grads, jnp.zeros_like(lanes).at[0].set(dlanes[1]).at[1].set(dlanes[0]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _kda_chunks(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep):
-    o, _, tops = _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _kda_chunks(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep, block):
+    o, _, tops = _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret,
+                           block)
     return o, tops
 
 
-def _kda_vjp_fwd(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep):
-    o, states, tops = _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret)
+def _kda_vjp_fwd(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep, block):
+    o, states, tops = _fwd_call(q, k, kb, vb, pre, lanes, heads, chunk, floor,
+                                interpret, block)
     # what the backward needs of the forward kernel, under the name a
     # recomputation may keep them by (its layer's plan said which)
     name = SCAN_NAME if keep else SCAN_NAME + AGAIN
@@ -364,19 +440,28 @@ def _kda_vjp_fwd(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep)
     return (o, tops), (q, k, kb, vb, pre, lanes, states)
 
 
-def _kda_vjp_bwd(heads, chunk, floor, interpret, keep, res, cotangents):
-    return _bwd_call(*res, cotangents[0], heads, chunk, floor, interpret)
+def _kda_vjp_bwd(heads, chunk, floor, interpret, keep, block, res, cotangents):
+    return _bwd_call(*res, cotangents[0], heads, chunk, floor, interpret, block)
 
 
 _kda_chunks.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "chunk", "floor", "interpret",
-                                             "keep"))
-def _kda_jit(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep):
+                                             "keep", "block"))
+def _kda_jit(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep, block):
     # a frame of its own in the name stack, as for the state-space scan: the
     # kernels keep their names (``%kda_chunk_fwd*``, ``%kda_chunk_bwd*``)
-    return _kda_chunks(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep)
+    return _kda_chunks(q, k, kb, vb, pre, lanes, heads, chunk, floor, interpret, keep,
+                       block)
+
+
+def grid_of(batch: int, seq: int, heads: int, d: int, chunk: int, itemsize: int):
+    """(heads a grid step, grid steps a call) of the kernels at this call:
+    what ``kernel_dispatch.choose_kda_heads`` gives the shape."""
+    from .kernel_dispatch import choose_kda_heads
+    block = choose_kda_heads(heads, d, chunk, itemsize)
+    return block, batch * (heads // block) * -(-seq // chunk)
 
 
 def scan_bytes(batch: int, seq: int, heads: int, d_k: int, d_v: int, chunk: int,
@@ -445,8 +530,9 @@ def kda_scan(q, k, v, pre, rate, bias, beta, chunk: int, *, use_kernel: bool,
         vb = (bt * v.astype(f32)).astype(dtype)
         lanes = jnp.zeros((SUBLANES, H * d), f32)
         lanes = lanes.at[0].set(jnp.repeat(rate.astype(f32), d)).at[1].set(bias.astype(f32))
+    block, _ = grid_of(b, s, H, d, chunk, dtype.itemsize)
     o, tops = _kda_jit(flat(q), flat(k), flat(kb), flat(vb), flat(pre, _PAD_PRE), lanes,
-                       H, chunk, float(floor), interpret, bool(keep))
+                       H, chunk, float(floor), interpret, bool(keep), block)
     o = o[:, :s].reshape(b, s, H, d)
     if with_state_absmax:
         return o, jax.lax.stop_gradient(jnp.max(tops))

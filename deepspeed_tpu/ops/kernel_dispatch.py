@@ -395,6 +395,40 @@ def gmm_impl(rows: int, k: int, n: int, dtype, kernel_here: bool) -> str:
     return IMPL_XLA
 
 
+# Kimi Delta Attention's chunk kernels (``ops/kda.py``: ``kda_chunk_fwd``,
+# ``kda_chunk_bwd``): a grid step is one chunk of a BLOCK of heads, whose
+# chains of small dependent matmuls (the running sum, the triangular products,
+# ``(I + A)^{-1}``) share no value and are issued a stage abreast. The list is
+# the sweep's on a v5e (PR 49, docs/kernel_dispatch.md: ``[1, 16384, 32 x
+# 128]`` forward 18.0, 10.9, 8.0 ms a call at 1, 2, 4 heads, backward 23.0,
+# 14.0, 11.0; 8 heads within 3% of 4, the MXUs' own time being the step by
+# then, for up to three times the seconds to compile: not on the list).
+KDA_HEAD_BLOCKS = (4, 2, 1)
+
+
+def kda_vmem_bytes(block: int, d: int, chunk: int, itemsize: int,
+                   arrays: int = 11) -> int:
+    """Upper estimate of the VMEM one grid step of a ``kda_chunk_*`` kernel
+    holds at ``block`` heads of ``d`` lanes: its ``arrays`` pipelined ``[chunk,
+    block * d]`` blocks twice (6 forward, 11 backward, the larger by
+    default), the float32 state's blocks and scratch, and some four dozen
+    ``[chunk, d]`` float32 temporaries a head."""
+    width = block * d
+    return (2 * arrays * chunk * width * max(itemsize, 4) + 6 * 4 * d * width
+            + 48 * 4 * chunk * width)
+
+
+def choose_kda_heads(heads: int, d: int, chunk: int, itemsize: int) -> int:
+    """Heads a grid step of the ``kda_chunk_*`` kernels, from the shape alone:
+    the first of KDA_HEAD_BLOCKS that divides ``heads`` and whose estimate
+    (the backward's, the larger) fits FUSED_VMEM_CAP_BYTES, else 1."""
+    for block in KDA_HEAD_BLOCKS:
+        if (heads % block == 0
+                and kda_vmem_bytes(block, d, chunk, itemsize) <= FUSED_VMEM_CAP_BYTES):
+            return block
+    return 1
+
+
 def resolve(sig: ShapeSig, *, impl_bwd: Optional[str] = None,
             blocks: Optional[tuple] = None):
     """(forward Decision, backward Decision) of one ``flash_attention`` call.

@@ -150,10 +150,13 @@ def _as_apply_fns(model):
                     stats["dsa_" + name] = reduce(sown) if reduce else sown
             # "kda_stats" for a Kimi Delta Attention mixer: ``state_absmax``
             # comes back as the largest over the layers, ``decay_mean`` and
-            # ``beta_mean`` as the layers' means
+            # ``beta_mean`` as the layers' means; where the chunk kernels ran,
+            # ``head_block`` and ``grid_steps`` (the heads a grid step took,
+            # the steps a call: one value, the layers' calls are alike)
             kda = jax.tree_util.tree_flatten_with_path(mods.get("kda_stats", {}))[0]
             for name, reduce in (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
-                                 ("beta_mean", jnp.mean)):
+                                 ("beta_mean", jnp.mean), ("head_block", jnp.max),
+                                 ("grid_steps", jnp.max)):
                 sown = [leaf.reshape(-1) for path, leaf in kda
                         if path[-1].key == name]
                 if sown:
@@ -1763,6 +1766,17 @@ class DeepSpeedTpuEngine:
                 "Mean decay exp(g) a key channel and token of the Kimi Delta "
                 "Attention layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["kda_decay_mean"]) for s in fetched])))
+        if "kda_head_block" in fetched[0]:
+            reg.gauge(
+                "ds_kda_head_block",
+                "Heads a grid step of the Kimi Delta Attention chunk kernels "
+                "took in the last step (kernel_dispatch.choose_kda_heads)"
+            ).set(float(fetched[-1]["kda_head_block"]))
+            reg.gauge(
+                "ds_kda_grid_steps",
+                "Grid steps a call of the Kimi Delta Attention chunk kernels "
+                "made in the last step: batch x heads / ds_kda_head_block x chunks"
+            ).set(float(fetched[-1]["kda_grid_steps"]))
         for name, what in (("latent_rms", "the latent before kv_a_layernorm"),
                            ("k_rope_rms", "the shared rope key")):
             if "mla_" + name in fetched[0]:
@@ -2224,8 +2238,10 @@ class DeepSpeedTpuEngine:
         """What the Kimi Delta Attention layers sowed in the newest fused
         step not yet published, as host scalars: ``state_absmax`` (the largest
         |S| over the layers), ``decay_mean`` (of ``exp(g)``) and ``beta_mean``
-        (the layers' means). Waits for that step, as :meth:`moe_stats`;
-        ``None`` for a model without such a layer."""
+        (the layers' means) and, where the chunk kernels ran, ``head_block``
+        and ``grid_steps`` (the heads a grid step took, the steps a call).
+        Waits for that step, as :meth:`moe_stats`; ``None`` for a model
+        without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("kda_"))
         return stats and {name[len("kda_"):]: v for name, v in stats.items()}
 

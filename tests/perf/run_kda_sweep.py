@@ -1,0 +1,139 @@
+"""Kimi Delta Attention's chunk kernels alone (``ops/kda.py``: ``kda_chunk_fwd``,
+``kda_chunk_bwd``) over the heads a grid step takes, at the Ling-3.0 cell's
+call ``[1, 16384, 32 x 128]`` in bf16 with chunks of 64: milliseconds a call,
+the seconds a kernel takes to lower (Mosaic's part) and to compile, the VMEM
+its call asks for, and whether a block of heads gives what one head a step
+gives, bit for bit. It wrote ``docs/readings/kda_heads_sweep_pr49.jsonl`` and
+is how a change to the kernels or to ``kernel_dispatch.choose_kda_heads`` is
+checked.
+
+Not a pytest assertion: a measurement tool, as ``run_attn_sweep.py`` is.
+
+    python tests/perf/run_kda_sweep.py --out chiprun_out/kda_sweep.jsonl   # chip
+    JAX_PLATFORMS=cpu python tests/perf/run_kda_sweep.py --interpret
+
+On a CPU the kernels run interpreted at a cut size: the comparison holds,
+the timings measure the emulation.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
+
+import numpy as np
+
+BATCH, SEQ, HEADS, D, CHUNK = 1, 16384, 32, 128, 64
+HBM_BYTES_S = 819e9     # one TPU v5e chip (benchmark/peaks.json has the source)
+
+
+def _time(fn, iters: int) -> float:
+    import jax
+    jax.block_until_ready(fn())
+    jax.block_until_ready(fn())
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn()
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def _operands(seed: int, seq: int, heads: int):
+    """The kernels' operands as ``kda_scan`` hands them over: unit q and k, a
+    gate's pre-activation that decays a few per cent a token, ``beta`` in
+    (0, 1) folded into ``kb`` and ``vb``."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.ssd import SUBLANES
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    shape = (BATCH, seq, heads, D)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)     # noqa: E731
+    q = unit(jax.random.normal(ks[0], shape)) * D ** -0.5
+    k = unit(jax.random.normal(ks[1], shape))
+    v = jax.random.normal(ks[2], shape)
+    pre = 2.0 * jax.random.normal(ks[3], shape) - 4.0
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))[..., None]
+    do = jax.random.normal(ks[5], shape)
+    rate = jax.random.uniform(ks[6], (heads, ), minval=1.0, maxval=4.0)
+    bias = 0.3 * jax.random.normal(ks[7], (heads * D, ))
+    lanes = jnp.zeros((SUBLANES, heads * D), jnp.float32)
+    lanes = lanes.at[0].set(jnp.repeat(rate, D)).at[1].set(bias)
+    flat = lambda a: a.astype(jnp.bfloat16).reshape(BATCH, seq, heads * D)  # noqa: E731
+    return [flat(a) for a in (q, k, beta * k, beta * v, pre)] + [lanes], flat(do)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", default="")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--blocks", default="1,2,4,8", help="heads a grid step")
+    ap.add_argument("--interpret", action="store_true",
+                    help="CPU: interpreted kernels at 256 tokens of 8 heads")
+    args = ap.parse_args(argv)
+    import jax
+    from deepspeed_tpu.ops import kda
+    from deepspeed_tpu.ops import kernel_dispatch as kd
+    seq, heads = (256, 8) if args.interpret else (SEQ, HEADS)
+    device = jax.devices()[0].device_kind
+    out = open(args.out, "a") if args.out else None
+
+    def emit(row):
+        line = json.dumps({**row, "device": device, "seq": seq, "heads": heads,
+                           "chunk": CHUNK, "rule": kd.choose_kda_heads(heads, D, CHUNK, 2)})
+        print(line, flush=True)
+        if out:
+            out.write(line + "\n")
+            out.flush()
+
+    operands, do = _operands(args.seed, seq, heads)
+    static = (heads, CHUNK, kda.GATE_FLOOR, args.interpret)
+    # the least time of a call by the bytes of the mathematics, as
+    # benchmark/kda_cost.py counts them
+    values, betas = BATCH * seq * heads * D, BATCH * seq * heads
+    states = 4.0 * BATCH * (seq // CHUNK) * heads * D * D
+    least_ms = {"fwd": (2 * (5 * values + betas) + states) / HBM_BYTES_S * 1e3,
+                "bwd": (2 * (9 * values + 2 * betas) + states) / HBM_BYTES_S * 1e3}
+    want = {}
+    for block in (int(b) for b in args.blocks.split(",")):
+        legs = {
+            "fwd": (lambda *a: kda._fwd_call(*a, *static, block), operands),
+            "bwd": (lambda *a: kda._bwd_call(*a, *static, block), None),
+        }
+        for leg, (call, given) in legs.items():
+            if given is None:       # the backward reads the states its forward wrote
+                given = operands + [want["fwd"][1], do]
+            row = {"leg": leg, "block": block, "grid_steps": BATCH * (heads // block)
+                   * (seq // CHUNK), "vmem_estimate": kd.kda_vmem_bytes(
+                       block, D, CHUNK, 2, 6 if leg == "fwd" else 11)}
+            row["vmem_limit"] = kd.vmem_limit_bytes(row["vmem_estimate"])
+            try:
+                t0 = time.perf_counter()
+                lowered = jax.jit(call).lower(*given)
+                t1 = time.perf_counter()
+                fn = lowered.compile()
+                t2 = time.perf_counter()
+                got = fn(*given)
+                ms = _time(lambda: fn(*given), args.iters)
+            except Exception as e:       # a block the compiler refuses
+                emit({**row, "error": str(e)[:300]})
+                continue
+            first = want.setdefault(leg, got)
+            emit({**row, "ms": ms, "us_a_step": 1e3 * ms / row["grid_steps"],
+                  "us_a_chunk_and_head": 1e3 * ms / (BATCH * heads * (seq // CHUNK)),
+                  "least_ms": least_ms[leg], "roofline_share": least_ms[leg] / ms,
+                  "lower_s": t1 - t0, "compile_s": t2 - t1,
+                  # against the first block of the list (one head a step)
+                  "bit_equal_to_first": all(
+                      np.array_equal(np.asarray(a), np.asarray(b))
+                      for a, b in zip(got, first)),
+                  "finite": all(bool(np.isfinite(np.asarray(a, np.float32)).all())
+                                for a in got)})
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

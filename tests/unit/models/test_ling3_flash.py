@@ -373,6 +373,48 @@ def test_the_chunk_kernels_under_the_model_and_its_recomputation(room):
     assert kept - less == (2 * scan if room else 0)
 
 
+@pytest.mark.parametrize("head_dim,heads,block", [(128, 2, 2), (32, 2, None)],
+                         ids=["the_kernels_two_heads_a_step", "the_recurrence"])
+def test_the_engine_says_which_block_of_heads_the_chunk_kernels_took(head_dim, heads, block):
+    """``head_block`` and ``grid_steps`` ride in ``kda_stats`` beside the
+    largest ``|S|`` where the chunk kernels run (interpreted here at heads of
+    128), and come out as ``ds_kda_head_block`` / ``ds_kda_grid_steps`` with
+    the step's publish; where the recurrence runs neither exists."""
+    import deepspeed_tpu
+    from deepspeed_tpu.comm import MeshContext, reset_mesh_context, set_mesh_context
+    from deepspeed_tpu.observability import get_registry
+    hf = {**HF, "head_dim": head_dim, "num_attention_heads": heads,
+          "num_key_value_heads": heads, "num_hidden_layers": 2, "kda_chunk_size": 64}
+    cfg = _config(hf)
+    ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128), dtype=np.int32))
+    reset_mesh_context()
+    set_mesh_context(MeshContext.create(devices=jax.devices()[:1]))
+    reg = get_registry()
+    reg.reset()
+    try:
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=llama.LlamaForCausalLM(cfg), model_parameters=_seeded(cfg),
+            config={"train_batch_size": 1, "steps_per_print": 0,
+                    "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+        engine.train_batch(iter([(ids, ids)]))
+        stats = engine.kda_stats()
+        engine.train_batch(iter([(ids, ids)]))      # publishes the step before
+        assert float(stats["state_absmax"]) > 0.0
+        if block is None:
+            assert set(stats) == {"state_absmax", "decay_mean", "beta_mean"}
+            unset = reg.get("ds_kda_head_block")    # the registry is the process's
+            assert unset is None or unset.value == 0
+        else:
+            steps = heads // block * (128 // 64)
+            assert (float(stats["head_block"]), float(stats["grid_steps"])) == (block, steps)
+            assert reg.get("ds_kda_head_block").value == block
+            assert reg.get("ds_kda_grid_steps").value == steps
+        assert reg.get("ds_kda_state_absmax").value == pytest.approx(
+            float(stats["state_absmax"]))
+    finally:
+        reset_mesh_context()
+
+
 def _row():
     for line in CATALOG.read_text().splitlines():
         row = json.loads(line)

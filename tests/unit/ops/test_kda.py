@@ -3,8 +3,10 @@
 (``kda_reference`` under ``bounded_gate``): the forward and the gradient of
 every input; a sequence of one chunk, of several and one the chunk does not
 divide; a gate at its bound ``g = -5`` over a whole chunk; ``beta = 0`` and
-``beta = 1`` rows; chunks of 16 and 64; the largest ``|S|`` at the chunks'
-ends; what the call refuses."""
+``beta = 1`` rows; chunks of 16 and 64; one, two and four heads a grid step
+(``kernel_dispatch.choose_kda_heads``) and a block of heads against one head
+a step bit for bit; the largest ``|S|`` at the chunks' ends; what the call
+refuses."""
 
 import jax
 import jax.numpy as jnp
@@ -12,12 +14,13 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops import kda
+from deepspeed_tpu.ops import kernel_dispatch as kd
 
 H, D = 2, 128
 NAMES = ("q", "k", "v", "pre", "rate", "bias", "beta")
 
 
-def _operands(seq, seed=1, gate="random", beta="random", dtype=jnp.float32):
+def _operands(seq, seed=1, gate="random", beta="random", dtype=jnp.float32, H=H):
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)     # noqa: E731
     q = unit(jax.random.normal(ks[0], (1, seq, H, D))) * D ** -0.5
@@ -49,20 +52,66 @@ def _both(args, weight, chunk):
             of(_recurrence))
 
 
-@pytest.mark.parametrize("chunk,seq,gate,beta", [
-    (64, 64, "random", "random"), (64, 192, "random", "random"),
-    (16, 48, "random", "random"), (64, 100, "random", "random"),
-    (64, 128, "floor", "random"), (16, 32, "floor", "ends"), (64, 128, "random", "ends")],
+def _pin(monkeypatch, block):
+    """``block`` heads a grid step, whatever the rule would give the shape."""
+    monkeypatch.setattr(kd, "choose_kda_heads", lambda *shape: block)
+
+
+# heads, then the heads a grid step: the rule's own (2 of 2, 2 of 6) or pinned
+@pytest.mark.parametrize("chunk,seq,gate,beta,heads,block", [
+    (64, 64, "random", "random", 2, None), (64, 192, "random", "random", 2, None),
+    (16, 48, "random", "random", 2, None), (64, 100, "random", "random", 2, None),
+    (64, 128, "floor", "random", 2, None), (16, 32, "floor", "ends", 2, None),
+    (64, 128, "random", "ends", 2, None), (64, 128, "random", "random", 4, 1),
+    (64, 128, "random", "ends", 4, 2), (64, 100, "random", "random", 4, 4),
+    (64, 128, "random", "random", 6, None)],
     ids=["one_chunk", "three_chunks", "chunk16", "padded", "gate_at_its_bound",
-         "bound_chunk16_beta_ends", "beta_0_and_1"])
-def test_forward_and_every_gradient_match_the_recurrence(chunk, seq, gate, beta):
-    args, weight = _operands(seq, gate=gate, beta=beta)
+         "bound_chunk16_beta_ends", "beta_0_and_1", "four_heads_one_a_step",
+         "four_heads_two_a_step", "four_heads_a_step_padded", "six_heads_fall_to_two"])
+def test_forward_and_every_gradient_match_the_recurrence(chunk, seq, gate, beta, heads,
+                                                         block, monkeypatch):
+    if block is None:
+        assert kda.grid_of(1, seq, heads, D, chunk, 4) == (2, heads // 2 * -(-seq // chunk))
+    else:
+        _pin(monkeypatch, block)
+    args, weight = _operands(seq, gate=gate, beta=beta, H=heads)
     ((_, out), grads), ((_, want), want_grads) = _both(args, weight, chunk)
-    assert out.shape == want.shape == (1, seq, H, D)
+    assert out.shape == want.shape == (1, seq, heads, D)
     np.testing.assert_allclose(out, want, atol=2e-6, rtol=2e-5)
     for name, g, w in zip(NAMES, grads, want_grads):
         scale = max(float(jnp.abs(w).max()), 1e-3)
         np.testing.assert_allclose(g, w, atol=2e-5 * scale, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_a_block_of_heads_is_one_head_a_step_bit_for_bit(dtype, monkeypatch):
+    """The mathematics of a head does not change with the heads a grid step
+    takes: the outputs, the chunk states, the largest ``|S|`` a head and lane
+    and the seven gradients at two and four heads a step are those of one."""
+    args, weight = _operands(128, dtype=dtype, H=4)
+
+    def loss(*a):
+        out = kda.kda_scan(*a, 64, use_kernel=False, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * weight), out
+
+    def at(block):
+        _pin(monkeypatch, block)
+        (_, out), grads = jax.value_and_grad(loss, argnums=tuple(range(7)),
+                                             has_aux=True)(*args)
+        q, k, v, pre, rate, bias, beta = args
+        flat = lambda a: a.reshape(1, 128, 4 * D)       # noqa: E731
+        lanes = jnp.zeros((kda.SUBLANES, 4 * D)).at[0].set(jnp.repeat(rate, D)).at[1].set(bias)
+        kernel = kda._fwd_call(flat(q), flat(k), flat((beta[..., None] * k).astype(dtype)),
+                               flat((beta[..., None] * v).astype(dtype)), flat(pre), lanes,
+                               4, 64, kda.GATE_FLOOR, True, block)
+        return dict(zip(("out", *NAMES, "o", "states", "tops"), (out, *grads, *kernel)))
+
+    one = at(1)
+    assert one["states"].shape == (1, 2, D, 4 * D) and one["tops"].shape == (1, 4, 8, D)
+    for block in (2, 4):
+        for name, got in at(block).items():
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(one[name], np.float32), err_msg=name)
 
 
 def test_bf16_operands_stay_within_bf16_of_the_recurrence():

@@ -609,6 +609,38 @@ def test_the_kernel_runs_where_a_raw_pallas_call_can(monkeypatch, tpu, devices, 
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# Kimi Delta Attention's chunk kernels: the heads a grid step takes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("why,heads,d,chunk,itemsize,block", [
+    ("the Ling-3.0 cell: 32 heads of 128, chunks of 64, bf16", 32, 128, 64, 2, 4),
+    ("float32 operands are counted as the float32 temporaries are", 4, 128, 64, 4, 4),
+    ("eight heads take four, not eight", 8, 128, 64, 2, 4),
+    ("six heads fall to two", 6, 128, 64, 2, 2),
+    ("two heads", 2, 128, 64, 2, 2),
+    ("an odd count falls to one", 3, 128, 64, 2, 1),
+    ("one head", 1, 128, 64, 2, 1),
+    ("heads of 512: four are 62 MB of the 64 MiB cap", 32, 512, 64, 2, 4),
+    ("a chunk of 512: two heads fit the cap, four do not", 32, 128, 512, 2, 2),
+    ("a chunk of 1,024: one head alone fits", 32, 128, 1024, 2, 1),
+    ("heads of 1,024: 87 MB at two", 32, 1024, 64, 2, 1),
+], ids=lambda v: v.split(":")[0].replace(" ", "_") if isinstance(v, str) else None)
+def test_the_kda_head_block_divides_the_heads_fits_the_cap_and_falls_to_one(
+        why, heads, d, chunk, itemsize, block):
+    got = kd.choose_kda_heads(heads, d, chunk, itemsize)
+    assert got == block, why
+    assert heads % got == 0
+    assert got == 1 or kd.kda_vmem_bytes(got, d, chunk, itemsize) <= kd.FUSED_VMEM_CAP_BYTES
+    # the largest of the list that does both
+    for larger in (b for b in kd.KDA_HEAD_BLOCKS if b > got):
+        assert heads % larger or kd.kda_vmem_bytes(
+            larger, d, chunk, itemsize) > kd.FUSED_VMEM_CAP_BYTES
+    # the estimate is the backward's unless told, and grows with the block
+    assert kd.kda_vmem_bytes(got, d, chunk, itemsize, 6) < kd.kda_vmem_bytes(
+        got, d, chunk, itemsize) == got * kd.kda_vmem_bytes(1, d, chunk, itemsize)
+
+
 def test_env_report_includes_dispatch_lines():
     from deepspeed_tpu.env_report import debug_report
     rep = debug_report()

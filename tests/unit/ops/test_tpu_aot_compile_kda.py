@@ -30,6 +30,9 @@ HEADS, D, CHUNK = 32, 128, 64
 # what the chip holds at rest when the six-layer cell's step is first traced:
 # 12 B for each of its 767,009,056 parameters
 IN_USE = 9_204_108_672
+# the two-layer step's temporaries as the tree before PR 49 (one head a grid
+# step) compiled them for the described v5e, the ``step`` fixture's way
+PARENT_TEMPORARIES = 6_560_886_272
 
 
 def cell_config(layers: int, seq=None):
@@ -132,6 +135,47 @@ def test_the_kernels_shapes_are_what_the_cost_file_reads_and_the_scopes_stand(st
         assert f"/{scope}/" in text, scope
     temporaries = step["compiled"].memory_analysis().temp_size_in_bytes
     assert temporaries + 12 * step["n_params"] <= mla.V5E_BYTES_LIMIT - 0.8e9
+
+
+def test_a_block_of_heads_a_grid_step_moves_neither_the_cost_nor_the_memory(step):
+    """PR 49: a grid step of both kernels is a chunk of FOUR heads at the
+    cell's 32 heads of 128 (``kernel_dispatch.choose_kda_heads``), 2,048 steps
+    a call where 8,192 were; what ``benchmark/kda_cost.py`` reads of the two
+    lines (the first result's shape) gives the operations and bytes it gave
+    before, and the step's temporaries for the described v5e are within 0.05
+    GB of what the tree before compiled to the same way (the block changes
+    VMEM, not HBM)."""
+    from jax._src import core
+    sys.path.insert(0, str(ROOT))
+    from benchmark import kda_cost
+    from deepspeed_tpu.ops import kda
+    rows, seq = step["rows"], step["seq"]
+    chunks = seq // CHUNK
+    assert kda.grid_of(rows, seq, HEADS, D, CHUNK, 2) == (4, rows * HEADS // 4 * chunks)
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.setdefault(eqn.params["name"], set()).add(
+                    tuple(eqn.params["grid_mapping"].grid))
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(step["traced"].jaxpr.jaxpr)
+    assert grids["kda_chunk_fwd"] == grids["kda_chunk_bwd"] == {(rows, HEADS // 4, chunks)}
+    calls = {line.split(" = ")[0].split("%")[-1].split(".")[0]: "%" + line.split("%", 1)[1]
+             for line in mla.custom_calls(step["compiled"])}
+    config = {"kda_chunk_size": CHUNK, "head_dim": D}
+    values, betas = rows * seq * HEADS * D, rows * seq * HEADS
+    states = 4.0 * rows * chunks * HEADS * D * D
+    fwd = rows * seq * HEADS * (2.0 * CHUNK * 5 * D + 6.0 * D * D)
+    assert kda_cost.call_cost(calls["kda_chunk_fwd"], config) == {
+        "flops": fwd, "bytes": 2 * (5 * values + betas) + states}
+    assert kda_cost.call_cost(calls["kda_chunk_bwd"], config) == {
+        "flops": 2.0 * fwd, "bytes": 2 * (9 * values + 2 * betas) + states}
+    temporaries = step["compiled"].memory_analysis().temp_size_in_bytes
+    assert temporaries <= PARENT_TEMPORARIES + 0.05e9, temporaries
 
 
 if __name__ == "__main__":
