@@ -7,6 +7,9 @@ Public API mirrors the reference (``deepspeed/__init__.py``):
   comm              — functional collectives over the device mesh
 """
 
+import time as _time
+_IMPORT_T0 = _time.monotonic()  # first: the package's own import is a span
+
 from .version import __version__
 from . import comm
 from . import zero
@@ -121,3 +124,9 @@ def add_config_arguments(parser):
     group.add_argument("--deepscale", default=False, action="store_true")
     group.add_argument("--local_rank", type=int, default=-1)
     return parser
+
+
+# last: what this package's import took, whatever was imported before it
+# (jax, flax), as the span ``ds.import`` of the tracer's kept ring
+from .observability.tracing import get_tracer as _get_tracer
+_get_tracer().closed_scope("ds.import", _IMPORT_T0, _time.monotonic())

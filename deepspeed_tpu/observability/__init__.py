@@ -24,7 +24,9 @@ request timelines, the goodput ledger) is ``time.monotonic()`` /
 - :mod:`profiler` — guarded on-demand ``jax.profiler`` captures (one at
   a time, duration-bounded) behind ``POST /debug/profile``.
 - :mod:`xla` — compile observability: per-compile-key compile/retrace/hit
-  telemetry (:class:`CompileWatch` wrapping every jit entry point),
+  telemetry (:class:`CompileWatch` wrapping every jit entry point), a
+  compiling call taken apart into jax's own trace / lowering / backend
+  compile or cache load (one tap on ``jax.monitoring``),
   cost-analysis FLOPs feeding the ``ds_train_mfu`` /
   ``ds_serving_wave_mfu`` gauges, and device-memory gauges.
 - :mod:`goodput` — a wall-clock ledger attributing every training second

@@ -32,8 +32,15 @@ Storage is bounded so a long-lived daemon cannot grow:
   a pathological million-token request keeps its most recent spans),
 - a global ``max_waves`` ring of wave/global/scope spans for the bulk
   ``GET /debug/trace`` Chrome export,
-- a small ring of its own for set-up spans (``ds.init*``, ``ds.compile.*``:
-  once per engine or program), which the window's traffic cannot evict.
+- a small ring of its own for set-up spans (``ds.init*``, ``ds.compile.*``,
+  ``ds.import``: once per engine, program or process), which the window's
+  traffic cannot evict.
+
+A phase that nobody knows to be worth a span until it is over (a call that
+turned out to compile, the pieces jax reports of it afterwards) is recorded
+after the fact: :meth:`RequestTracer.closed_scope` takes the closed
+interval, and a scope that is opened late is told when it
+:meth:`~_Scope.began`.
 
 Export formats:
 
@@ -56,7 +63,7 @@ from jax.profiler import TraceAnnotation
 
 # scopes recorded once per engine or per compiled program: kept in a ring
 # of their own so that steady traffic cannot evict them
-KEPT_PREFIXES = ("ds.init", "ds.compile.")
+KEPT_PREFIXES = ("ds.init", "ds.compile.", "ds.import")
 
 
 class _Timeline:
@@ -73,7 +80,8 @@ class _Timeline:
 class _Scope:
     """One open :meth:`RequestTracer.scope`. ``args`` may be filled in
     while the scope is open (a count known only after the work); ``dur_s``
-    is the recorded duration once it has closed."""
+    is the recorded duration once it has closed; ``sid`` is what a span
+    recorded after the fact names as its ``parent``."""
 
     __slots__ = ("_tracer", "name", "uid", "args", "_annotation", "_t0",
                  "_sid", "_parent", "_stack", "dur_s")
@@ -95,6 +103,15 @@ class _Scope:
             self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
+
+    @property
+    def sid(self) -> int:
+        return self._sid
+
+    def began(self, t0: float) -> None:
+        """The phase began at ``t0`` (``time.monotonic()``), before anyone
+        knew it was worth a span: the open scope is recorded from there."""
+        self._t0 = t0
 
     def __exit__(self, *exc):
         t1 = time.monotonic()
@@ -151,6 +168,17 @@ class RequestTracer:
                 tl = self._timelines.get(uid)
                 if tl is not None:
                     tl.spans.append((name, t0, t1, args))
+
+    def closed_scope(self, name: str, t0: float, t1: float,
+                     parent: Optional[int] = None,
+                     args: Optional[dict] = None) -> int:
+        """Record the closed interval ``[t0, t1]`` (``time.monotonic()``) as
+        a scope after the fact, under the scope whose ``sid`` is ``parent``;
+        ring only, since the profiler takes no event that is already over.
+        Returns its ``sid``."""
+        sid = next(self._sids)
+        self._record_scope((name, t0, t1, parent, None, args or None, sid))
+        return sid
 
     def scopes(self, prefix: str = "ds.", since: float = 0.0) -> List[dict]:
         """The recorded scopes whose name starts with ``prefix`` and that

@@ -1,6 +1,6 @@
 """Compile & runtime observability for the XLA layer.
 
-Three pieces, all host-side and off the per-step critical path:
+Four pieces, all host-side and off the per-step critical path:
 
 - :class:`CompileWatch` + :class:`WatchedJit`: transparent wrappers around
   jitted callables that classify every dispatch as compile / retrace /
@@ -10,6 +10,28 @@ Three pieces, all host-side and off the per-step critical path:
   cheap C call per dispatch; when the attribute is missing (plain
   function wrappers, e.g. the grad-comm step builder) the first call
   counts as the compile and later calls as hits.
+- The anatomy of a compiling call (:func:`install_backend_compile_listener`,
+  the program's one tap on ``jax.monitoring``): jax reports, each at its
+  end and on the thread that compiled, the seconds it traced a function,
+  turned the jaxpr into an MLIR module and had the backend compile it or
+  load it from the persistent cache, with the function's name, and whether
+  that cache hit, what the load took and what it saved. The tap stamps each
+  piece on the tracer ring's clock as it arrives (``t1 = time.monotonic()``,
+  ``t0 = t1 - seconds``) and holds it on its thread; a trace or a lowering
+  that ends inside another piece (every inner ``jit``'s trace, a helper a
+  lowering rule traces) belongs to the outermost and is dropped. A
+  :class:`WatchedJit` call that turned out to compile then claims what
+  began at or after its own start: per-key counters
+  (``ds_compile_trace_seconds_total{key}``, ``..._lower_...``,
+  ``..._backend_...``, ``ds_compile_cache_load_seconds_total{key}``,
+  ``ds_compile_persistent_hits_total{key}``, ``..._misses_total{key}``) and
+  the spans ``ds.compile.call`` > ``ds.compile.trace`` / ``.lower`` /
+  ``.backend`` / ``.cost_analysis`` in the tracer's kept ring. What no
+  watched call claims (model init, eager ops, a reference) is charged to
+  ``key="-"`` at the next claim or :meth:`TrainInstruments.publish`, with a
+  span only where a piece took ``SPAN_FLOOR_S`` or more. A dispatch that
+  hits jit's cache does none of this. ``ds_xla_backend_compile_seconds``
+  stays the unlabeled histogram of every backend compile process-wide.
 - FLOPs accounting: a compiling call captures ``ShapeDtypeStruct`` specs
   of its arguments, each with the committed sharding and weak type jit
   keyed its caches on (a spec without them misses both caches, and the
@@ -29,16 +51,11 @@ Three pieces, all host-side and off the per-step critical path:
 - Device-memory gauges (:func:`refresh_memory_gauges`) from
   ``device.memory_stats()`` — live bytes, peak watermark, allocator
   limit. CPU backends return no stats; the gauges simply stay absent.
-
-``install_backend_compile_listener`` additionally taps jax's monitoring
-event ``/jax/core/compile/backend_compile_duration`` into an unlabeled
-histogram — it catches XLA compiles that bypass the wrapped entry points
-(model init, eager ops, persistent-cache misses during deserialization).
 """
 
 import threading
 import time
-from typing import Any, Optional, Tuple
+from typing import Optional, Tuple
 
 from .metrics import Histogram, MetricsRegistry, get_registry
 from .tracing import get_tracer
@@ -47,6 +64,24 @@ from .tracing import get_tracer
 _COMPILE_HIST = dict(lo=1e-3, hi=1e4, buckets_per_decade=5)
 # step times: µs-scale fused CPU steps to minutes-long K-step waves
 _STEP_HIST = dict(lo=1e-6, hi=1e3, buckets_per_decade=10)
+
+# the pieces of one compile as jax reports them (jax 0.9.0: jax/_src/
+# dispatch.py, compiler.py, compilation_cache.py). Each duration event is
+# preceded by a scalar event of the same name when its piece BEGINS.
+_PIECES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "backend"}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_CACHE_STATE = {"/jax/compilation_cache/cache_hits": "hit",
+                "/jax/compilation_cache/cache_misses": "miss"}
+# the key of what no watched call claimed
+UNCLAIMED = "-"
+# an unclaimed piece gets a span in the kept ring only from this length on:
+# a cell runs some hundred small programs before its window
+SPAN_FLOOR_S = 0.25
+# pieces one thread may hold before they are charged to UNCLAIMED unasked
+_PENDING_CAP = 4096
 
 
 def cost_analysis_flops(stage) -> float:
@@ -160,7 +195,7 @@ class WatchedJit:
 
     def __call__(self, *args, **kwargs):
         before = self._cache_entries()
-        t0 = time.perf_counter()
+        t0 = time.monotonic()       # the tracer ring's clock
         out = self._fn(*args, **kwargs)
         after = self._cache_entries()
         if after is None:
@@ -172,23 +207,32 @@ class WatchedJit:
         self._calls += 1
         self.dispatches += 1
         if compiled:
-            # wall of a compiling call ≈ trace + compile: execution is
-            # dispatched async, so the device work barely contributes
-            dt = time.perf_counter() - t0
+            # the wall of a compiling call: the trace, the lowering and the
+            # backend's compile or load, which the tap's pieces split below,
+            # plus what jax does around them (the caches' keys, the argument
+            # handling, the dispatch). Execution is dispatched async, so the
+            # device work barely contributes
+            dt = time.monotonic() - t0
             self._watch.on_compile(self.key, dt, retrace)
-            if self._flops_spec is None:
-                try:
-                    self._flops_spec = _arg_specs(args, kwargs)
-                except Exception:
-                    pass
-                # real programs (compile cost ≫ lowering cost): resolve the
-                # cost analysis NOW, inside the compile event — deferring it
-                # would bill the first steady-state publish() a
-                # whole-program lowering. Tiny programs (unit tests) stay
-                # lazy: their lowering is milliseconds wherever it lands,
-                # and doing it eagerly taxes every engine construction.
-                if dt > 0.5:
-                    self.program_flops()
+            with get_tracer().scope("ds.compile.call", annotate=False,
+                                    key=self.key, retrace=retrace) as call:
+                call.began(t0)
+                call.args["programs"], call.args["persistent"] = (
+                    self._watch.claim_events(self.key, t0, call.sid))
+                if self._flops_spec is None:
+                    try:
+                        self._flops_spec = _arg_specs(args, kwargs)
+                    except Exception:
+                        pass
+                    # real programs (compile cost ≫ lowering cost): resolve
+                    # the cost analysis NOW, inside the compile event —
+                    # deferring it would bill the first steady-state
+                    # publish() a whole-program lowering. Tiny programs
+                    # (unit tests) stay lazy: their lowering is milliseconds
+                    # wherever it lands, and doing it eagerly taxes every
+                    # engine construction.
+                    if dt > 0.5:
+                        self.program_flops()
         else:
             self._watch.on_hit(self.key)
         return out
@@ -205,7 +249,12 @@ class WatchedJit:
         (0.0 on a TPU, where a lowered module has none). It deliberately
         never calls ``.compile()``, which would pay a full fresh XLA compile
         (the AOT path shares no executable cache with dispatch). Never
-        invoked on the step path."""
+        invoked on the step path. Its wall is the ``ds.compile.cost_analysis``
+        scope: inside a compiling call a child of that call's
+        ``ds.compile.call``, which ends after it. A spec that misses jit's
+        caches traces and lowers again AFTER the call has claimed its
+        pieces: those seconds are then in this scope and, as pieces no call
+        claimed, under ``key="-"``."""
         if self._flops is not None:
             return self._flops
         if self._flops_spec is None:
@@ -241,11 +290,18 @@ class CompileWatch:
     - ``ds_compiles_total{key=...}``: compile events (first + retraces)
     - ``ds_recompiles_total{key=...}``: retraces only (cache already warm
       — the "why is my steady state recompiling" counter)
-    - ``ds_compile_cache_hits_total{key=...}``: dispatches served from the
-      jit cache
+    - ``ds_compile_cache_hits_total{key=...}``: dispatches served from
+      jit's in-memory cache (NOT the persistent cache: that is
+      ``ds_compile_persistent_hits_total``)
     - ``ds_cost_analysis_seconds_total{key=...}``: wall seconds of the
       program's cost analysis (``WatchedJit.program_flops``, once a
       program); not part of ``ds_compile_seconds``
+    - the pieces jax reports of a compiling call (:meth:`charge`):
+      ``ds_compile_trace_seconds_total``, ``ds_compile_lower_seconds_total``,
+      ``ds_compile_backend_seconds_total`` (a load from the persistent cache
+      included), ``ds_compile_cache_load_seconds_total`` (that load alone),
+      ``ds_compile_persistent_hits_total``,
+      ``ds_compile_persistent_misses_total``, all ``{key=...}``
 
     ``on_compile_seconds`` (optional) feeds measured compile wall into the
     goodput ledger's pending-compile pool."""
@@ -255,6 +311,7 @@ class CompileWatch:
         self.registry = registry if registry is not None else get_registry()
         self._lock = threading.Lock()
         self._per_key = {}
+        self._per_key_pieces = {}
         self._on_compile_seconds = on_compile_seconds
 
     def _handles(self, key: str):
@@ -280,8 +337,9 @@ class CompileWatch:
                             labels=lab),
                          reg.counter(
                             "ds_compile_cache_hits_total",
-                            "Dispatches served from the jit cache per "
-                            "compile key", labels=lab),
+                            "Dispatches served from jit's in-memory cache "
+                            "per compile key (not the persistent cache: "
+                            "ds_compile_persistent_hits_total)", labels=lab),
                          reg.counter(
                             "ds_cost_analysis_seconds_total",
                             "Wall seconds reading a program's FLOPs and named "
@@ -290,6 +348,82 @@ class CompileWatch:
                             "ds_compile_seconds)", labels=lab))
                     self._per_key[key] = h
         return h
+
+    def _piece_handles(self, key: str) -> dict:
+        h = self._per_key_pieces.get(key)
+        if h is None:   # the registry gets or makes: a race makes the same six
+            lab = {"key": key}
+            counter = self.registry.counter
+            h = self._per_key_pieces[key] = {
+                "trace": counter(
+                    "ds_compile_trace_seconds_total",
+                    "Seconds jax traced the functions of compiling calls "
+                    "into jaxprs per compile key, a trace inside another "
+                    "piece left to that piece (jax.monitoring "
+                    "jaxpr_trace_duration)", labels=lab),
+                "lower": counter(
+                    "ds_compile_lower_seconds_total",
+                    "Seconds jax turned the jaxprs of compiling calls into "
+                    "MLIR modules per compile key "
+                    "(jaxpr_to_mlir_module_duration)", labels=lab),
+                "backend": counter(
+                    "ds_compile_backend_seconds_total",
+                    "Seconds the backend compiled the programs of compiling "
+                    "calls, or loaded them from the persistent cache, per "
+                    "compile key (backend_compile_duration)", labels=lab),
+                "load": counter(
+                    "ds_compile_cache_load_seconds_total",
+                    "Seconds of ds_compile_backend_seconds_total spent "
+                    "reading executables back from the persistent cache per "
+                    "compile key (cache_retrieval_time_sec)", labels=lab),
+                "hit": counter(
+                    "ds_compile_persistent_hits_total",
+                    "Programs loaded from the persistent compilation cache "
+                    "per compile key", labels=lab),
+                "miss": counter(
+                    "ds_compile_persistent_misses_total",
+                    "Programs compiled and written to the persistent "
+                    "compilation cache per compile key (one the cache is "
+                    "off for, or does not keep, counts as neither)",
+                    labels=lab)}
+        return h
+
+    def charge(self, key: str, pieces, parent: Optional[int] = None,
+               span_floor_s: float = 0.0) -> Tuple[int, str]:
+        """Charge ``pieces`` (the tap's records ``(kind, t0, t1, fun_name,
+        cache)``) to ``key``: the per-key counters, and for each piece of
+        ``span_floor_s`` or more a ``ds.compile.<kind>`` span under
+        ``parent``. -> (backend programs among them, ``hit`` | ``miss`` |
+        ``mixed`` | ``off``: what the persistent cache did for them)."""
+        h = self._piece_handles(key)
+        tracer = get_tracer()
+        programs, states = 0, set()
+        for kind, t0, t1, fun_name, cache in pieces:
+            h[kind].inc(t1 - t0)
+            args = {"key": key, "fun_name": fun_name}
+            if kind == "backend":
+                programs += 1
+                state, load_s, saved_s = cache
+                args.update(cache=state, load_s=load_s, saved_s=saved_s)
+                if state != "off":
+                    states.add(state)
+                    h[state].inc()
+                    h["load"].inc(load_s)
+            if t1 - t0 >= span_floor_s:
+                tracer.closed_scope("ds.compile." + kind, t0, t1, parent, args)
+        return programs, (states.pop() if len(states) == 1
+                          else "mixed" if states else "off")
+
+    def claim_events(self, key: str, since: float,
+                     parent: Optional[int] = None) -> Tuple[int, str]:
+        """Called by a watched call that turned out to compile: charge to
+        ``key`` the pieces jax reported on this thread since the call began
+        (``since``, ``time.monotonic()``), as children of the span
+        ``parent``. -> :meth:`charge`'s summary; (0, "off") with no tap."""
+        tap = _TAP
+        if tap is None:
+            return 0, "off"
+        return tap.claim(self, key, since, parent)
 
     def wrap(self, fn, key: str) -> Optional[WatchedJit]:
         if fn is None:
@@ -317,9 +451,13 @@ class CompileWatch:
     def counts(self, key: str) -> dict:
         """Introspection helper for tests/consoles."""
         hist, compiles, recompiles, hits, cost = self._handles(key)
-        return {"compiles": compiles.value, "recompiles": recompiles.value,
-                "hits": hits.value, "compile_seconds": hist.sum,
-                "cost_analysis_seconds": cost.value}
+        out = {"compiles": compiles.value, "recompiles": recompiles.value,
+               "hits": hits.value, "compile_seconds": hist.sum,
+               "cost_analysis_seconds": cost.value}
+        for name, c in self._per_key_pieces.get(key, {}).items():
+            out[{"hit": "persistent_hits", "miss": "persistent_misses"}.get(
+                name, name + "_seconds")] = c.value
+        return out
 
 
 def refresh_memory_gauges(registry: Optional[MetricsRegistry] = None) -> dict:
@@ -347,36 +485,163 @@ def refresh_memory_gauges(registry: Optional[MetricsRegistry] = None) -> dict:
     return out
 
 
-_BACKEND_LISTENER_INSTALLED = False
+class _ThreadPieces:
+    """One thread's compile pieces that no key has been charged for yet."""
+
+    __slots__ = ("lock", "pending", "open", "cache", "self_s")
+
+    def __init__(self):
+        self.lock = threading.Lock()    # the owner against a flush from elsewhere
+        self.pending = []               # (kind, t0, t1, fun_name, cache), by arrival
+        self.open = 0                   # pieces begun and not ended
+        self.cache = ["off", 0.0, 0.0]  # state, load_s, saved_s of the backend compile under way
+        self.self_s = 0.0               # the tap's own seconds on this thread
+
+
+class _CompileTap:
+    """The program's listeners on ``jax.monitoring`` (module docstring).
+    One a process, made by :func:`install_backend_compile_listener`: jax
+    offers listeners no scope smaller than the process."""
+
+    def __init__(self, registry: MetricsRegistry):
+        self.unclaimed = CompileWatch(registry=registry)
+        self._backend_hist = registry.histogram(
+            "ds_xla_backend_compile_seconds",
+            "XLA backend_compile wall seconds (jax.monitoring event, all "
+            "compiles process-wide)", **_COMPILE_HIST)
+        self._self_seconds = registry.counter(
+            "ds_compile_tap_seconds_total",
+            "Seconds the program's own jax.monitoring listeners took over "
+            "the pieces they kept, and the charging of those, all threads (a "
+            "piece dropped inside another is not timed)")
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self._threads = []
+
+    def _mine(self) -> _ThreadPieces:
+        st = getattr(self._local, "pieces", None)
+        if st is None:
+            st = self._local.pieces = _ThreadPieces()
+            with self._lock:
+                self._threads.append(st)
+        return st
+
+    # ---- the listeners: on the compiling thread; a begin and an end for
+    # every piece jax reports, which is every inner jit of a trace ----
+
+    def on_scalar(self, name, value, **kw):
+        if name in _PIECES:         # a piece begins
+            (getattr(self._local, "pieces", None) or self._mine()).open += 1
+
+    def on_event(self, name, **kw):
+        state = _CACHE_STATE.get(name)
+        if state is not None:
+            self._mine().cache[0] = state
+
+    def on_duration(self, name, secs, **kw):
+        kind = _PIECES.get(name)
+        if kind is None:
+            if name == _CACHE_LOAD:
+                self._mine().cache[1] = float(secs)
+            elif name == _CACHE_SAVED:
+                self._mine().cache[2] = float(secs)
+            return
+        st = getattr(self._local, "pieces", None) or self._mine()
+        inside = st.open = st.open - 1 if st.open > 0 else 0
+        if inside and kind != "backend":
+            # a trace or a lowering that ends inside another piece (an inner
+            # jit's trace in the program's, a helper traced by a lowering
+            # rule) is in that piece's seconds: some hundred thousand a large
+            # program, so nothing more is done for one, not even timing this
+            return
+        t1 = time.monotonic()
+        secs = float(secs)
+        cache = None
+        if kind == "backend":
+            # always its own piece, so that the keys sum to every compile
+            self._backend_hist.record(secs)
+            cache, st.cache = tuple(st.cache), ["off", 0.0, 0.0]
+        with st.lock:
+            st.pending.append((kind, t1 - secs, t1,
+                               str(kw.get("fun_name", "")), cache))
+            full = len(st.pending) >= _PENDING_CAP
+            st.self_s += time.monotonic() - t1
+        if full:
+            self._flush(st)
+
+    # ---- charging: off the dispatch path --------------------------------
+
+    def _take(self, st: _ThreadPieces) -> list:
+        with st.lock:
+            pieces, st.pending = st.pending, []
+            self._self_seconds.inc(st.self_s)
+            st.self_s = 0.0
+        return pieces
+
+    def _flush(self, st: _ThreadPieces) -> None:
+        t_in = time.monotonic()
+        self.unclaimed.charge(UNCLAIMED, self._take(st),
+                              span_floor_s=SPAN_FLOOR_S)
+        self._self_seconds.inc(time.monotonic() - t_in)
+
+    def claim(self, watch: "CompileWatch", key: str, since: float,
+              parent: Optional[int]) -> Tuple[int, str]:
+        """This thread's pieces that began at or after ``since`` to
+        ``watch`` under ``key``; the older ones to ``UNCLAIMED``."""
+        t_in = time.monotonic()
+        mine, older = [], []
+        for piece in self._take(self._mine()):
+            (older if piece[1] < since else mine).append(piece)
+        if older:
+            self.unclaimed.charge(UNCLAIMED, older, span_floor_s=SPAN_FLOOR_S)
+        summary = watch.charge(key, mine, parent)
+        self._self_seconds.inc(time.monotonic() - t_in)
+        return summary
+
+    def flush(self) -> None:
+        """Every thread's pieces to ``UNCLAIMED``. A compile under way on
+        ANOTHER thread loses the pieces it has finished to ``"-"``: the sums
+        over keys stay whole."""
+        with self._lock:
+            threads = list(self._threads)
+        for st in threads:
+            if st.pending or st.self_s:
+                self._flush(st)
+
+
+_TAP: Optional[_CompileTap] = None
 
 
 def install_backend_compile_listener(
         registry: Optional[MetricsRegistry] = None) -> bool:
-    """Tap jax's ``/jax/core/compile/backend_compile_duration`` monitoring
-    event into ``ds_xla_backend_compile_seconds`` — XLA compile wall as the
-    runtime itself measures it, including compiles outside any watched
-    entry point. Idempotent per process (jax.monitoring offers no listener
-    removal); returns False when the hook isn't available."""
-    global _BACKEND_LISTENER_INSTALLED
-    if _BACKEND_LISTENER_INSTALLED:
+    """Install the program's one tap on ``jax.monitoring``
+    (:class:`_CompileTap`): every backend compile process-wide into
+    ``ds_xla_backend_compile_seconds`` — XLA compile wall as the runtime
+    itself measures it, including compiles outside any watched entry point —
+    and the pieces of each compile held for the watched call that claims
+    them (module docstring). What no call claims is counted in ``registry``
+    (default: the process-wide one). Idempotent per process; returns False
+    when the hook isn't available."""
+    global _TAP
+    if _TAP is not None:
         return True
-    reg = registry if registry is not None else get_registry()
-    hist = reg.histogram(
-        "ds_xla_backend_compile_seconds",
-        "XLA backend_compile wall seconds (jax.monitoring event, all "
-        "compiles process-wide)", **_COMPILE_HIST)
+    tap = _CompileTap(registry if registry is not None else get_registry())
     try:
-        import jax.monitoring as _monitoring
-
-        def _on_event(name, secs, **kw):
-            if name.endswith("backend_compile_duration"):
-                hist.record(float(secs))
-
-        _monitoring.register_event_duration_secs_listener(_on_event)
+        import jax.monitoring as monitoring
+        monitoring.register_scalar_listener(tap.on_scalar)
+        monitoring.register_event_listener(tap.on_event)
+        monitoring.register_event_duration_secs_listener(tap.on_duration)
     except Exception:
         return False
-    _BACKEND_LISTENER_INSTALLED = True
+    _TAP = tap
     return True
+
+
+def flush_compile_events() -> None:
+    """Charge what no watched call has claimed, on any thread, to
+    ``key="-"`` now (:meth:`TrainInstruments.publish` does)."""
+    if _TAP is not None:
+        _TAP.flush()
 
 
 def peak_device_flops() -> float:
@@ -473,6 +738,7 @@ class TrainInstruments:
         at the async-window drain (or per step in sync mode) — the lazy
         ``program_flops`` cost analyses land here, not on the step path."""
         refresh_memory_gauges(self.registry)
+        flush_compile_events()
         if self.ledger is not None:
             self.ledger.publish()
         now = time.perf_counter()

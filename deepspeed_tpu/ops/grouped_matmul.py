@@ -388,12 +388,23 @@ def grouped_matmul(rows, w, group_sizes):
                               preferred_element_type=rows.dtype)
 
 
-def traced_note() -> str:
-    """What ``ds_moe_gmm_traced_total`` has counted in this process, for a
-    log line: ``grouped_matmul[rows=moe_gmm:36,d_rows=moe_gmm:24,...]``."""
+def traced_counts() -> dict:
+    """``ds_moe_gmm_traced_total`` as the process-wide registry has it now:
+    ``{(leg, impl): count}``, every program this process traced."""
     from ..observability import get_registry
-    found = [f"{m.labels['leg']}={m.labels['impl']}:{int(m.value)}"
-             for m in get_registry().series("ds_moe_gmm_traced_total")]
+    return {(m.labels["leg"], m.labels["impl"]): int(m.value)
+            for m in get_registry().series("ds_moe_gmm_traced_total")}
+
+
+def traced_note(since=None) -> str:
+    """What ``ds_moe_gmm_traced_total`` has counted since ``since`` (an
+    earlier :func:`traced_counts`: the registry is the process's, and an
+    engine's line is about its own programs; default: in this process), for
+    a log line: ``grouped_matmul[rows=moe_gmm:36,d_rows=moe_gmm:24,...]``."""
+    since = since or {}
+    found = [f"{leg}={impl}:{n - since.get((leg, impl), 0)}"
+             for (leg, impl), n in traced_counts().items()
+             if n > since.get((leg, impl), 0)]
     return f"grouped_matmul[{','.join(found)}]"
 
 

@@ -492,6 +492,10 @@ class DeepSpeedTpuEngine:
         self._async_window = (_AsyncStepWindow(apc.sync_interval)
                               if apc.enabled else None)
         self._moe_pending = []   # device stats of fused MoE steps not yet published
+        # the process's grouped-matmul trace counts before this engine's
+        # programs: its one `kernels:` line reports what came after
+        from ..ops.grouped_matmul import traced_counts
+        self._gmm_traced_before = traced_counts()
         # the block-diffusion objective's noising of raw token batches
         # (data_pipeline/block_diffusion.py), seeded by the config's ``seed``
         self._diffusion_noiser = None
@@ -1816,9 +1820,11 @@ class DeepSpeedTpuEngine:
             return
         if not getattr(self, "_kernel_line_logged", False):
             # once, after the first MoE step was traced: which grouped
-            # matmul its call sites took, by pass (ds_moe_gmm_traced_total)
+            # matmul this engine's call sites took, by pass (what
+            # ds_moe_gmm_traced_total counted since the engine was built)
             from ..ops.grouped_matmul import traced_note
-            log_dist(f"kernels: {traced_note()}", ranks=[0])
+            log_dist(f"kernels: {traced_note(self._gmm_traced_before)}",
+                     ranks=[0])
             self._kernel_line_logged = True
         # [E] a step, [K, E] a K-step dispatch
         counts = sum(np.asarray(s["expert_counts"], np.int64)

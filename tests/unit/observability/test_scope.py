@@ -173,11 +173,11 @@ def test_program_flops_relowering_is_a_kept_scope():
     watch = CompileWatch(registry=MetricsRegistry())
     fn = watch.wrap(jax.jit(lambda x: x @ x), "unit:matmul")
     fn(jnp.ones((4, 4)))
-    assert get_tracer().scopes("ds.compile.") == []    # lazy for tiny programs
+    # lazy for tiny programs: the compiling call left its own span alone
+    assert get_tracer().scopes("ds.compile.cost_analysis") == []
     assert fn.program_flops() > 0
     assert fn.program_flops() > 0                      # cached: lowered once
-    scope, = get_tracer().scopes("ds.compile.")
-    assert scope["name"] == "ds.compile.cost_analysis"
+    scope, = get_tracer().scopes("ds.compile.cost_analysis")
     assert scope["args"] == {"key": "unit:matmul"}
 
 

@@ -143,6 +143,18 @@ def test_training_observability_acceptance(tmp_path, monkeypatch):
     # compile seconds, and in the console's table
     assert doc["compiles"]["train_step_fused"]["cost_analysis_seconds"] > 0
     assert "cost an." in r.stdout
+    # and what jax reported of that compiling call, piece by piece: the
+    # trace, the lowering and the backend's compile within its wall; what no
+    # watched call claimed (the model's init) under the key "-"
+    step = doc["compiles"]["train_step_fused"]
+    pieces = step["pieces"]
+    assert all(pieces[k] > 0 for k in ("trace", "lower", "backend"))
+    assert pieces["trace"] + pieces["lower"] + pieces["backend"] <= step["seconds"]
+    assert pieces["load"] <= pieces["backend"]
+    assert doc["compiles"]["-"]["pieces"]["backend"] > 0
+    header, = [ln for ln in r.stdout.splitlines() if ln.startswith("compile key")]
+    assert header.split()[-6:] == ["trace", "lower", "backend", "load",
+                                   "p.hit", "p.miss"]
 
 
 def test_observability_disabled_is_silent(tmp_path):
