@@ -33,8 +33,8 @@ Storage is bounded so a long-lived daemon cannot grow:
 - a global ``max_waves`` ring of wave/global/scope spans for the bulk
   ``GET /debug/trace`` Chrome export,
 - a small ring of its own for set-up spans (``ds.init*``, ``ds.compile.*``,
-  ``ds.import``: once per engine, program or process), which the window's
-  traffic cannot evict.
+  ``ds.import``, ``ds.checkpoint.engine_build``: once per engine, program
+  or process), which the window's traffic cannot evict.
 
 A phase that nobody knows to be worth a span until it is over (a call that
 turned out to compile, the pieces jax reports of it afterwards) is recorded
@@ -63,7 +63,7 @@ from jax.profiler import TraceAnnotation
 
 # scopes recorded once per engine or per compiled program: kept in a ring
 # of their own so that steady traffic cannot evict them
-KEPT_PREFIXES = ("ds.init", "ds.compile.", "ds.import")
+KEPT_PREFIXES = ("ds.init", "ds.compile.", "ds.import", "ds.checkpoint.")
 
 
 class _Timeline:

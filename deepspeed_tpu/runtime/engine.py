@@ -591,9 +591,13 @@ class DeepSpeedTpuEngine:
             except (TypeError, ValueError):
                 pass
 
-        with self._tracer.scope("ds.init.checkpoint_engine"):
-            # the first engine of a process imports orbax here (seconds)
-            self.checkpoint_engine = OrbaxCheckpointEngine()
+        # built at its first use (the `checkpoint_engine` property): a run
+        # that never saves or loads never imports orbax. A run that will save
+        # on a signal has a grace period to save in, so it pays the import now
+        self._checkpoint_engine = None
+        rc = self._config.resilience_config
+        if rc.enabled and rc.preempt_save:
+            self._build_checkpoint_engine()
         dist.configure(deepspeed_config=self._config)
 
         # training data loader (reference deepspeed_io, engine.py:1743)
@@ -2586,6 +2590,25 @@ class DeepSpeedTpuEngine:
                 "n_leaves": int(m.n_leaves),
             }
         return sd
+
+    @property
+    def checkpoint_engine(self):
+        """The engine checkpoints are written and read through: an
+        ``OrbaxCheckpointEngine`` built when first asked for, or whatever
+        the caller assigned (an ``AsyncCheckpointEngine``, say)."""
+        if self._checkpoint_engine is None:
+            self._build_checkpoint_engine()
+        return self._checkpoint_engine
+
+    @checkpoint_engine.setter
+    def checkpoint_engine(self, engine):
+        self._checkpoint_engine = engine
+
+    def _build_checkpoint_engine(self):
+        # the first one of a process imports orbax (seconds): the span says
+        # where they fell, and a run without it never paid them
+        with self._tracer.scope("ds.checkpoint.engine_build"):
+            self._checkpoint_engine = OrbaxCheckpointEngine()
 
     def save_checkpoint(self, save_dir, tag=None, client_state=None, save_latest=True,
                         exclude_frozen_parameters=False):

@@ -224,8 +224,7 @@ def test_training_engine_spans_its_construction_and_its_step():
     init = _by_name(get_tracer().scopes("ds.init"))
     assert set(init) == {"ds.init", "ds.init.mesh", "ds.init.zero_plan",
                          "ds.init.place_params", "ds.init.opt_state",
-                         "ds.init.build_step", "ds.init.checkpoint_engine",
-                         "ds.init.resume"}
+                         "ds.init.build_step", "ds.init.resume"}
     assert all(s["parent"] == init["ds.init"]["sid"]
                for n, s in init.items() if n != "ds.init")
     assert 0 <= init["ds.init"]["self_s"] <= init["ds.init"]["dur_s"]
