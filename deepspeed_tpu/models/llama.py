@@ -40,9 +40,21 @@ class LayerSpec:
     every few, a dense FFN in the leading layers and experts after)."""
     # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer) |
     # "latent" (LatentAttention: DeepSeek-V2/V3's MLA) | "kda" (KimiDeltaMixer)
+    # | "mamba1" (SelectiveScanMixer) | "gmu" (GatedMemoryUnit)
     operator: str = "attention"
     ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
     ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
+    # an "attention" layer's own sliding window (0: what the config's
+    # ``sliding_window`` / ``sliding_window_layers`` say of it)
+    window: int = 0
+    # "attention" in the differential form (``LlamaAttention._differential``)
+    differential: bool = False
+    # what the layer reads of an EARLIER layer of the stack (-1: nothing): a
+    # differential "attention" layer the keys and values of layer ``kv_from``
+    # (it then has no k or v projection of its own), a "gmu" layer the scan
+    # output of the "mamba1" layer ``memory_from``
+    kv_from: int = -1
+    memory_from: int = -1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,6 +194,17 @@ class LlamaConfig:
     kda_d_conv: int = 4
     kda_gate_floor: float = -5.0
     kda_chunk_size: int = 64
+    # the "mamba1" operator (Mamba-1, ``ops/selective_scan.py``):
+    # ``mamba1_d_inner`` channels, each with ``mamba_d_state`` states of a decay
+    # rate of their own, ``mamba_d_conv`` taps (``mamba_conv_bias``) before the
+    # scan, the step size through ``mamba1_dt_rank``; a "gmu" layer gates such a
+    # layer's scan output, ``mamba1_d_inner`` wide
+    mamba1_d_inner: int = 0
+    mamba1_dt_rank: int = 0
+    # the published index of this stack's layer 0, for a stack cut out of a
+    # deeper model: differential attention's ``lambda_init`` follows the
+    # published depth
+    layer_index_offset: int = 0
     # "head": the "latent" operator's output times a sigmoid gate of the layer's
     # normed input, one value a head and token, before ``o_proj`` (Ling-3.0's
     # ``gated_attention_proj_granularity_type: head_wise``); None = no gate
@@ -259,6 +282,43 @@ class LlamaConfig:
         return (self.vocab_size - 1 if self.diffusion_mask_id is None
                 else self.diffusion_mask_id)
 
+    def shared_sources(self) -> Tuple[Optional[int], ...]:
+        """For every layer, the earlier layer whose keys and values (a
+        differential "attention" layer with ``kv_from``) or scan output (a
+        "gmu" layer) it reads, None for a layer that reads nothing. A reader
+        whose source is not an earlier layer of the right kind IN THIS STACK
+        is refused by name: a stack cut out of a deeper model has to keep the
+        sources of the readers it keeps."""
+        out = []
+        for i, spec in enumerate(self.layer_specs or ()):
+            source, wanted = None, None
+            if spec.operator == "gmu":
+                source, wanted = spec.memory_from, "mamba1"
+            elif spec.operator == "attention" and spec.kv_from >= 0:
+                source, wanted = spec.kv_from, "attention"
+                if not spec.differential:
+                    raise ValueError(f"layer {i}: keys and values of another layer "
+                                     "(kv_from) are read by the differential form alone")
+            elif spec.kv_from >= 0 or spec.memory_from >= 0:
+                raise ValueError(f"layer {i} ({spec.operator!r}) reads nothing of "
+                                 f"another layer: kv_from {spec.kv_from}, memory_from "
+                                 f"{spec.memory_from}")
+            if wanted is not None:
+                what = "memory" if wanted == "mamba1" else "keys and values"
+                if not 0 <= source < i:
+                    raise ValueError(
+                        f"layer {i} ({spec.operator!r}) reads the {what} of layer "
+                        f"{source}, which is not an earlier layer of this stack of "
+                        f"{len(self.layer_specs)}: keep the source with its readers")
+                have = self.layer_specs[source]
+                if have.operator != wanted or have.kv_from >= 0 or (
+                        wanted == "attention" and not have.differential):
+                    raise ValueError(
+                        f"layer {i} ({spec.operator!r}) reads the {what} of layer "
+                        f"{source}, a {have.operator!r} layer that makes none")
+            out.append(source)
+        return tuple(out)
+
     def per_layer_elements(self) -> int:
         """Analytic element count of one decoder layer (operator + MLP/MoE
         + norms) — the unit of the ZeRO-3 live-parameter budget; with
@@ -297,8 +357,13 @@ class LlamaConfig:
         kda = (6 * h * kda_inner + h * self.num_attention_heads
                + 3 * self.kda_d_conv * kda_inner
                + self.num_attention_heads + kda_inner + self.kda_head_dim)
+        wide, rank = self.mamba1_d_inner, self.mamba1_dt_rank
+        mamba1 = (3 * h * wide + (self.mamba_d_conv + 1) * wide
+                  + wide * (rank + 2 * self.mamba_d_state) + (rank + 1) * wide
+                  + wide * self.mamba_d_state + wide)
         operator = {"conv": conv, "attention": attn, "mamba": mamba,
-                    "latent": latent, "kda": kda}
+                    "latent": latent, "kda": kda, "mamba1": mamba1,
+                    "gmu": 2 * h * wide}
         return max(operator[spec.operator] + ffn(spec.ffn, spec.ffn_width) + 2 * h
                    for spec in self.layer_specs)
 
@@ -480,7 +545,10 @@ def _make_norm(cfg, name):
 
 
 def _layer_window(cfg, layer_idx: int):
-    """Sliding window for this layer (None = global attention)."""
+    """Sliding window for this layer (None = global attention): its own
+    (``LayerSpec.window``), else the config's."""
+    if cfg.layer_specs is not None and cfg.layer_specs[layer_idx].window:
+        return cfg.layer_specs[layer_idx].window
     if cfg.sliding_window is None:
         return None
     if (cfg.sliding_window_layers is not None
@@ -495,14 +563,34 @@ def _mesh_shape() -> dict:
             if mesh_is_initialized() else {})
 
 
+def _causal_attention_at_two_widths(cfg, q, k, v, scale, window=None):
+    """Causal attention whose values are not as wide as its keys (latent
+    attention: 192 | 128; differential attention's stacked call: 64 | 128),
+    under ``window`` where there is one: the ``mla_*`` kernels on one TPU
+    device where the sequence tiles, XLA's attention with the scores by hand
+    anywhere else (a CPU, a mesh of more than one device: correct and
+    unmeasured)."""
+    from ..ops.attention import _xla_attention, flash_attention
+    s = q.shape[1]
+    one_device = all(n == 1 for n in _mesh_shape().values())
+    if (cfg.attn_impl != "xla" and (cfg.attn_impl == "flash" or on_tpu())
+            and one_device and (s <= 128 or s % 128 == 0)):
+        return flash_attention(q, k, v, causal=True, scale=scale, window=window,
+                               interpret=interpret_kernels())
+    return _xla_attention(q, k, v, scale, True, window)
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
     layer_idx: int = 0
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions, attn_mask=None):
+    def __call__(self, x, cos, sin, positions, attn_mask=None, shared=None,
+                 hand_on=False):
         cfg = self.config
         window = _layer_window(cfg, self.layer_idx)
+        if cfg.layer_specs is not None and cfg.layer_specs[self.layer_idx].differential:
+            return self._differential(x, attn_mask, window, shared, hand_on)
         b, s, _ = x.shape
         hd = cfg.head_dim_
         nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
@@ -642,6 +730,86 @@ class LlamaAttention(nn.Module):
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       cfg.attention_out_bias, _keep_out(cfg, nq * hd))(out)
 
+
+    def _differential(self, x, attn_mask, window, shared, hand_on):
+        """Differential attention (Ye et al., arXiv:2410.05258, as
+        Phi-4-mini-flash has it): the heads in adjacent pairs, query pair
+        ``p`` reading key/value pair ``g = p // group``:
+
+            A1 = softmax(q_{2p} k_{2g}^T * scale + m),  A2 = softmax(q_{2p+1} k_{2g+1}^T * scale + m)
+            o_p = (1 - l_init) * RMSNorm((A1 - l * A2) [v_{2g} | v_{2g+1}]) * subln
+            l = exp(lambda_q1 . lambda_k1) - exp(lambda_q2 . lambda_k2) + l_init
+            l_init = 0.8 - 0.6 * exp(-0.3 * published layer index)
+
+        ``m`` the causal mask and the layer's window, no position embedding.
+        ``(A1 - l A2) V = A1 V - l A2 V``: with the query heads ordered ``[even
+        | odd]``, the key heads likewise and the paired values once for each
+        half, both products are ONE call of the two-width attention (q and k
+        ``head_dim``, v ``2 * head_dim``; the ``mla_*`` kernels on one TPU
+        device, XLA's attention anywhere else: correct and unmeasured), the
+        first half of its heads ``A1 V`` and the second ``A2 V``. ``shared``:
+        the ``(k, v)`` of an earlier layer, ``[b, s, kv heads, head_dim]``
+        as its projections gave them; the layer then has ``q_proj`` and
+        ``o_proj`` alone. ``hand_on``: also return this layer's ``(k, v)``.
+        Sows ``diffattn_stats`` (only when mutable): ``lambda_mean``, the
+        layer's ``l``."""
+        cfg = self.config
+        b, s, _ = x.shape
+        hd, nq, nkv = cfg.head_dim_, cfg.num_attention_heads, cfg.num_key_value_heads
+        if (attn_mask is not None or cfg.pos_embedding != "none" or cfg.qk_norm
+                or cfg.dsa_topk or cfg.block_diffusion_ or cfg.clip_qkv is not None
+                or cfg.attn_logit_softcapping is not None or nq % 2 or nkv % 2
+                or (nq // 2) % (nkv // 2)):
+            raise ValueError(
+                "differential attention is causal attention over pairs of heads "
+                "without a position embedding: no padding mask, q/k norm, clamp, "
+                "softcapping, learned sparsity or other objective, and an even "
+                "number of query and of key heads")
+        q = _dense(nq * hd, "q_proj", (EMBED, HEADS), cfg.dtype, cfg.attention_bias,
+                   remat.MIXER_IN)(x).reshape(b, s, nq, hd)
+        if shared is None:
+            k, v = (_dense(nkv * hd, name, (EMBED, KV), cfg.dtype, cfg.attention_bias,
+                           remat.MIXER_IN)(x).reshape(b, s, nkv, hd)
+                    for name in ("k_proj", "v_proj"))
+            if hand_on:     # the layers after read them: kept once, whatever the plan
+                k, v = (remat.handed_on(a, remat.SHARED_KV) for a in (k, v))
+        else:
+            k, v = shared
+
+        def lam(name):
+            return self.param(name, nn.with_partitioning(
+                nn.initializers.normal(0.1), (None, )), (hd, ), jnp.float32)
+
+        lq1, lk1, lq2, lk2 = (lam("lambda_" + n) for n in ("q1", "k1", "q2", "k2"))
+        subln = self.param("subln", nn.with_partitioning(nn.initializers.ones, (None, )),
+                           (2 * hd, ), jnp.float32)
+        l_init = 0.8 - 0.6 * float(np.exp(-0.3 * (self.layer_idx + cfg.layer_index_offset)))
+        # every scope closes before the kernel's call below: one that held it
+        # would rename the instruction (docs/observability.md)
+        with jax.named_scope("ds.diffattn.combine"):
+            by_parity = lambda a, n: (a.reshape(b, s, n // 2, 2, hd)      # noqa: E731
+                                      .transpose(0, 1, 3, 2, 4).reshape(b, s, n, hd))
+            paired = v.reshape(b, s, nkv // 2, 2 * hd)
+            qs, ks = by_parity(q, nq), by_parity(k, nkv)
+            vs = jnp.concatenate([paired, paired], axis=2)
+        scale = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / float(np.sqrt(hd))
+        attn = _causal_attention_at_two_widths(cfg, qs, ks, vs, scale, window)
+        with jax.named_scope("ds.diffattn.combine"):
+            f32 = jnp.float32       # the parameters may arrive in the compute dtype
+            lam_full = (jnp.exp(jnp.sum(lq1.astype(f32) * lk1.astype(f32)))
+                        - jnp.exp(jnp.sum(lq2.astype(f32) * lk2.astype(f32))) + l_init)
+            o = (attn[:, :, :nq // 2].astype(f32)
+                 - lam_full * attn[:, :, nq // 2:].astype(f32))
+            var = jnp.mean(o * o, axis=-1, keepdims=True)
+            o = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * subln.astype(f32)
+                 * (1.0 - l_init))
+            o = o.astype(cfg.dtype).reshape(b, s, nq * hd)
+        if self.is_mutable_collection("diffattn_stats"):
+            self.sow("diffattn_stats", "lambda_mean", jax.lax.stop_gradient(lam_full),
+                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+        out = _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
+                     cfg.attention_out_bias, _keep_out(cfg, nq * hd))(o)
+        return (out, (k, v)) if hand_on else out
 
     def _sparse(self, x, q, k, v, positions, attn_mask, window, sp_sz, use_kernel):
         """Learned sparse attention: the indexer (``indexer_q_proj``,
@@ -807,16 +975,9 @@ class LatentAttention(nn.Module):
                 axis=-1)
             v = kvb[..., d_nope:]
 
-        from ..ops.attention import _xla_attention, flash_attention
         scale = (cfg.attn_scale if cfg.attn_scale is not None
                  else 1.0 / float(np.sqrt(d_nope + d_rope)))
-        one_device = all(n == 1 for n in _mesh_shape().values())
-        if (cfg.attn_impl != "xla" and (cfg.attn_impl == "flash" or on_tpu())
-                and one_device and (s <= 128 or s % 128 == 0)):
-            attn = flash_attention(q, k, v, causal=True, scale=scale,
-                                   interpret=interpret_kernels())
-        else:
-            attn = _xla_attention(q, k, v, scale, True)
+        attn = _causal_attention_at_two_widths(cfg, q, k, v, scale)
         if cfg.attn_output_gate == "head":
             gate = _dense(nh, "gate_proj", (EMBED, None), cfg.dtype)(x)
             with jax.named_scope("ds.mla.gate"):
@@ -864,6 +1025,27 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
+def _mamba_conv_params(module, cfg, width: int):
+    """-> (taps ``[mamba_d_conv, width]``, bias ``[width]``) of a Mamba mixer's
+    causal depthwise convolution, ``conv_weight`` and (under
+    ``mamba_conv_bias``; zeros without) ``conv_bias`` of ``module``: the taps
+    lecun-normal over the taps, the bias as torch's Conv1d draws it."""
+    f32 = jnp.float32
+    taps = module.param(
+        "conv_weight",
+        nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0, out_axis=1),
+                             (None, HIDDEN)),
+        (cfg.mamba_d_conv, width), f32)
+    if not cfg.mamba_conv_bias:
+        return taps, jnp.zeros((width, ), f32)
+    bound = cfg.mamba_d_conv ** -0.5     # torch's Conv1d: U(+-1/sqrt(fan in))
+    return taps, module.param(
+        "conv_bias",
+        nn.with_partitioning(lambda key, shape, dtype=f32: jax.random.uniform(
+            key, shape, dtype, -bound, bound), (HIDDEN, )),
+        (width, ), f32)
+
+
 class Mamba2Mixer(nn.Module):
     """Mamba-2, the operator of a ``"mamba"`` layer (HF
     ``GraniteMoeHybridMambaLayer``): ``z | xBC | dt = in_proj(u)``; ``xBC =
@@ -892,19 +1074,7 @@ class Mamba2Mixer(nn.Module):
         zxbcdt = _dense(inner + xbc_width + H, "in_proj", (EMBED, HIDDEN), cfg.dtype,
                         keep=remat.MIXER_IN)(u)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + xbc_width], axis=-1)
-        taps = self.param(
-            "conv_weight",
-            nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0, out_axis=1),
-                                 (None, HIDDEN)),
-            (cfg.mamba_d_conv, xbc_width), f32)
-        conv_bias = jnp.zeros((xbc_width, ), f32)
-        if cfg.mamba_conv_bias:
-            bound = cfg.mamba_d_conv ** -0.5     # torch's Conv1d: U(+-1/sqrt(fan in))
-            conv_bias = self.param(
-                "conv_bias",
-                nn.with_partitioning(lambda key, shape, dtype=f32: jax.random.uniform(
-                    key, shape, dtype, -bound, bound), (HIDDEN, )),
-                (xbc_width, ), f32)
+        taps, conv_bias = _mamba_conv_params(self, cfg, xbc_width)
         # raw pallas_calls are not partitioned under GSPMD: as for flash, the
         # kernels run where the mesh is one device
         kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
@@ -1257,11 +1427,15 @@ class LlamaMoEBlock(nn.Module):
 
 
 class LlamaDecoderLayer(nn.Module):
+    """One decoder layer. ``shared``: what a layer that reads an earlier
+    layer's keys and values or scan output is handed (``LlamaConfig.
+    shared_sources``); a layer that later layers read returns ``(its output,
+    what it hands on)``, every other layer its output alone."""
     config: LlamaConfig
     layer_idx: int = 0
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions, attn_mask=None):
+    def __call__(self, x, cos, sin, positions, attn_mask=None, shared=None):
         cfg = self.config
         if cfg.layer_specs is not None:
             # this layer's own operator and FFN (LFM2's names and residual
@@ -1278,7 +1452,11 @@ class LlamaDecoderLayer(nn.Module):
                         * cfg.residual_multiplier).astype(out.dtype)
 
             normed = _make_norm(cfg, "operator_norm")(x)
-            if spec.operator == "conv":
+            handed = None
+            if spec.operator in ("mamba1", "gmu") or spec.differential:
+                mixed, handed = self._sharing_mixer(spec, normed, attn_mask, shared)
+                h = x + branch(mixed)
+            elif spec.operator == "conv":
                 h = x + branch(ShortConvOperator(cfg, name="conv")(normed))
             elif spec.operator == "mamba":
                 h = x + branch(Mamba2Mixer(cfg, name="mamba")(normed))
@@ -1294,8 +1472,10 @@ class LlamaDecoderLayer(nn.Module):
             ffn_cfg = dataclasses.replace(cfg, intermediate_size=spec.ffn_width)
             normed2 = _make_norm(cfg, "ffn_norm")(h)
             if spec.ffn == "moe":
-                return h + branch(LlamaMoEBlock(ffn_cfg, name="block_sparse_moe")(normed2))
-            return h + branch(LlamaMLP(ffn_cfg, name="mlp")(normed2))
+                out = h + branch(LlamaMoEBlock(ffn_cfg, name="block_sparse_moe")(normed2))
+            else:
+                out = h + branch(LlamaMLP(ffn_cfg, name="mlp")(normed2))
+            return out if handed is None else (out, handed)
         if cfg.sandwich_norm:
             # Gemma-2: pre AND post norms around both sublayers
             attn_out = LlamaAttention(cfg, self.layer_idx, name="self_attn")(
@@ -1331,6 +1511,28 @@ class LlamaDecoderLayer(nn.Module):
         else:
             h = h + LlamaMLP(cfg, name="mlp")(normed2)
         return h
+
+    def _sharing_mixer(self, spec, normed, attn_mask, shared):
+        """-> (the mixer's output, what the layer hands on or None) of the
+        kinds that pass something between layers beside the residual stream:
+        a differential "attention" layer (its own keys and values, or
+        ``shared``'s), a "mamba1" layer, a "gmu" layer (``shared`` the scan
+        output it gates)."""
+        cfg = self.config
+        sources = cfg.shared_sources()
+        hands_on = self.layer_idx in sources
+        if (sources[self.layer_idx] is None) != (shared is None):
+            raise ValueError(f"layer {self.layer_idx} ({spec.operator!r}) reads layer "
+                             f"{sources[self.layer_idx]} and was handed "
+                             f"{'nothing' if shared is None else 'something'}")
+        if spec.operator == "mamba1":
+            out = SelectiveScanMixer(cfg, name="mamba")(normed, hands_on)
+        elif spec.operator == "gmu":
+            out = GatedMemoryUnit(cfg, name="mamba")(normed, shared)
+        else:
+            out = LlamaAttention(cfg, self.layer_idx, name="self_attn")(
+                normed, None, None, None, attn_mask, shared, hands_on)
+        return out if hands_on else (out, None)
 
 
 class LMHead(nn.Module):
@@ -1399,12 +1601,18 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
             if spec not in by_kind:
                 by_kind[spec] = remat.price_list(
                     LlamaDecoderLayer(cfg, i, parent=None).init, jax.random.PRNGKey(0),
-                    abstract(x), cos, sin, abstract(positions), attn_mask)
+                    abstract(x), cos, sin, abstract(positions), attn_mask,
+                    *_shared_like(cfg, spec, x))
         return [by_kind[spec] + scan_price(spec) for spec in specs]
 
     def scan_price(spec):
-        # the kda kernel's output and chunk states are named inside its
-        # forward rule, which no trace of the layer's forward shows
+        # a scan kernel's output and states are named inside its forward
+        # rule, which no trace of the layer's forward shows
+        if spec is not None and spec.operator == "mamba1":
+            from ..ops.selective_scan import scan_bytes
+            return ((remat.SELSCAN_SCAN, scan_bytes(
+                x.shape[0], x.shape[1], cfg.mamba1_d_inner, cfg.mamba_d_state,
+                jnp.dtype(cfg.dtype).itemsize)), )
         if spec is None or spec.operator != "kda":
             return ()
         from ..ops.kda import scan_bytes
@@ -1420,11 +1628,35 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
         (cfg.v_head_dim or cfg.head_dim_) * itemsize + 4)    # output, log-sum-exp
     if cfg.dsa_topk:
         a_kernels += tokens * 2 * 4     # a row's threshold and tie bound
+    always_kept, sources = attention * a_kernels, cfg.shared_sources()
+    for i, spec in enumerate(specs):
+        if spec is not None and spec.differential:   # its values are two heads wide
+            always_kept += tokens * cfg.num_attention_heads * cfg.head_dim_ * itemsize
+        if spec is not None and i in sources:        # what it hands on, once
+            always_kept += sum(a.size * itemsize for a in jax.tree_util.tree_leaves(
+                _shared_like(cfg, spec, x, source=True)))
     plan = remat.plan_for(
         (repr(cfg), x.shape), prices_of, rows=x.shape[0],
-        layer_input_bytes=x.size * itemsize, always_kept_bytes=attention * a_kernels,
+        layer_input_bytes=x.size * itemsize, always_kept_bytes=always_kept,
         same_in_all_layers=cfg.scan_layers)
     return plan or (remat.RESIDUAL_NAMES, ) * len(specs)
+
+
+def _shared_like(cfg, spec, x, source: bool = False) -> tuple:
+    """What a layer of ``spec`` is handed beside the stream ``x``, abstractly
+    and as the trailing arguments of its call: () for a layer that reads
+    nothing, else ``(keys and values, )`` or ``(memory, )``. ``source``: what
+    such a layer hands ON instead."""
+    if spec is None:
+        return ()
+    b, s = x.shape[:2]
+    kv = jax.ShapeDtypeStruct((b, s, cfg.num_key_value_heads, cfg.head_dim_), cfg.dtype)
+    memory = jax.ShapeDtypeStruct((b, s, cfg.mamba1_d_inner), cfg.dtype)
+    if source:
+        return ((kv, kv), ) if spec.operator == "attention" else (memory, )
+    if spec.operator == "gmu":
+        return (memory, )
+    return ((kv, kv), ) if spec.kv_from >= 0 else ()
 
 
 class _ScanBody(nn.Module):
@@ -1513,10 +1745,17 @@ class LlamaModel(nn.Module):
                 x, _ = ScanLayer(cfg, name="layers")(x, cos, sin, positions, attn_mask)
         else:
             layer_cls = _remat_layer_cls(cfg) if cfg.remat else LlamaDecoderLayer
+            # what a layer hands to the layers after it beside the stream
+            # (``shared_sources``): an argument of every layer that reads it,
+            # so an input of its recomputation, its gradient summed over them
+            sources, handed = cfg.shared_sources(), {}
             for i in range(cfg.num_hidden_layers):
+                reads = () if not sources or sources[i] is None else (handed[sources[i]], )
                 with remat.keeping(kept and kept[i]):
                     x = layer_cls(cfg, i, name=f"layers_{i}")(x, cos, sin, positions,
-                                                              attn_mask)
+                                                              attn_mask, *reads)
+                if i in sources:
+                    x, handed[i] = x
         if cfg.block_diffusion_:
             # the clean copy carries no loss: only the noisy half is normed
             # and reaches the head
@@ -1660,3 +1899,113 @@ def init_llama(config: LlamaConfig, seed: int = 0, seq_len: int = 8,
         return model, _init(key)
     return model, jax.jit(lambda k: jax.tree_util.tree_map(
         lambda x: x.astype(dtype), _init(k)))(key)
+
+
+class _StepSize(nn.Module):
+    """Mamba-1's ``dt_proj`` and its softplus: ``softplus(W_dt delta + b_dt)``
+    with the sum and the bias in float32 (the bias sits near ``-7`` where a
+    step size is ``1e-3``: rounded to bf16 it would move the step size by
+    percents). ``W_dt`` uniform within ``rank^-1/2``, ``b_dt`` so that the step
+    sizes are log-uniform in [1e-3, 1e-1] (Mamba's own initialisation)."""
+    features: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, delta):
+        bound = delta.shape[-1] ** -0.5
+        kernel = self.param("kernel", nn.with_partitioning(
+            lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+                key, shape, dtype, -bound, bound), (None, HIDDEN)),
+            (delta.shape[-1], self.features), jnp.float32)
+        bias = self.param("bias", nn.with_partitioning(_dt_bias_init, (HIDDEN, )),
+                          (self.features, ), jnp.float32)
+        pre = jax.lax.dot_general(
+            delta.astype(self.dtype), _use_cast(kernel, self.dtype),
+            (((delta.ndim - 1, ), (0, )), ((), ())),
+            preferred_element_type=jnp.float32)
+        return jax.nn.softplus(pre + bias)
+
+
+class SelectiveScanMixer(nn.Module):
+    """Mamba-1 (Gu & Dao, arXiv:2312.00752), the operator of a ``"mamba1"``
+    layer: ``x | z = in_proj(u)`` (``mamba1_d_inner`` each); ``x = silu(conv(x)
+    + bias)`` (``mamba_d_conv`` causal depthwise taps, ``ops/short_conv.py::
+    causal_conv``); ``delta | B | C = x_proj(x)`` (``mamba1_dt_rank``,
+    ``mamba_d_state``, ``mamba_d_state``); ``dt = softplus(dt_proj(delta))``,
+    ``A = -exp(A_log)`` one rate a (channel, state); the selective scan with a
+    state of ``mamba_d_state`` a channel (``ops/selective_scan.py``); ``out_proj(y
+    * silu(z))``. ``hand_on``: also return ``y`` (the scan's output with its
+    ``D`` term, before the gate): the memory a later ``"gmu"`` layer gates.
+    Named ``mamba`` by its layer, as Mamba-2's mixer is. Sows ``selscan_stats``
+    (only when mutable): the largest ``|h|`` and the mean ``dt``."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, u, hand_on=False):
+        from ..ops.selective_scan import selective_scan
+        from ..ops.short_conv import causal_conv
+        cfg = self.config
+        E, N, R = cfg.mamba1_d_inner, cfg.mamba_d_state, cfg.mamba1_dt_rank
+        if not (E and R):
+            raise ValueError("the mamba1 operator needs mamba1_d_inner and "
+                             "mamba1_dt_rank")
+        f32 = jnp.float32
+        xz = _dense(2 * E, "in_proj", (EMBED, HIDDEN), cfg.dtype, keep=remat.MIXER_IN)(u)
+        x, z = jnp.split(xz, 2, axis=-1)
+        taps, conv_bias = _mamba_conv_params(self, cfg, E)
+        # raw pallas_calls are not partitioned under GSPMD: as for flash, the
+        # kernels run where the mesh is one device
+        kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
+        x = remat.keep(causal_conv(x, taps, conv_bias, use_kernel=kernels,
+                                   interpret=interpret_kernels()), remat.KERNEL_OUT)
+        a_log = self.param("A_log", nn.with_partitioning(
+            lambda key, shape, dtype=f32: jnp.broadcast_to(jnp.log(
+                jnp.arange(1, shape[1] + 1, dtype=dtype)), shape), (HIDDEN, None)),
+            (E, N), f32)
+        d_skip = self.param("D", nn.with_partitioning(nn.initializers.ones, (HIDDEN, )),
+                            (E, ), f32)
+        # the scope closes before the kernels' call below: one that held it
+        # would rename the instruction (docs/observability.md)
+        with jax.named_scope("ds.selscan.dt"):
+            dbc = _dense(R + 2 * N, "x_proj", (HIDDEN, None), cfg.dtype)(x)
+            delta, B, C = jnp.split(dbc, [R, R + N], axis=-1)
+            dt = _StepSize(E, cfg.dtype, name="dt_proj")(delta)
+            rates = -jnp.exp(a_log.astype(f32))
+        want_stats = self.is_mutable_collection("selscan_stats")
+        y = selective_scan(x, dt, rates, B, C, d_skip, use_kernel=kernels,
+                           interpret=interpret_kernels(), with_state_absmax=want_stats,
+                           keep=remat.keeps(remat.SELSCAN_SCAN))
+        if want_stats:
+            y, top = y
+            self.sow("selscan_stats", "state_absmax", top, reduce_fn=jnp.maximum,
+                     init_fn=lambda: jnp.zeros((), f32))
+            self.sow("selscan_stats", "dt_mean", jax.lax.stop_gradient(jnp.mean(dt)),
+                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+        if hand_on:     # the layers after read it: kept once, whatever the plan
+            y = remat.handed_on(y, remat.SHARED_MEMORY)
+        gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
+        out = _dense(cfg.hidden_size, "out_proj", (HIDDEN, EMBED), cfg.dtype,
+                     keep=_keep_out(cfg, E))(gated)
+        return (out, y) if hand_on else out
+
+
+class GatedMemoryUnit(nn.Module):
+    """The Gated Memory Unit of a ``"gmu"`` layer (SambaY, arXiv:2507.06607):
+    ``out_proj(memory * silu(in_proj(u)))``, ``memory`` the scan output of an
+    earlier ``"mamba1"`` layer (``mamba1_d_inner`` wide), no state and no
+    token mixing of its own. Named ``mamba`` by its layer: in the family's
+    checkpoints it is the Mamba module's ``in_proj`` and ``out_proj``."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, u, memory):
+        cfg = self.config
+        E = cfg.mamba1_d_inner
+        if memory.shape[-1] != E:
+            raise ValueError(f"a gmu layer gates a memory {E} wide: got {memory.shape}")
+        gate = _dense(E, "in_proj", (EMBED, HIDDEN), cfg.dtype, keep=remat.MIXER_IN)(u)
+        with jax.named_scope("ds.gmu.gate"):
+            gated = (memory.astype(jnp.float32)
+                     * jax.nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
+        return _dense(cfg.hidden_size, "out_proj", (HIDDEN, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, E))(gated)

@@ -842,6 +842,190 @@ class GraniteMoeHybridPolicy(HFCheckpointPolicy):
         return out
 
 
+class Phi4FlashPolicy(HFCheckpointPolicy):
+    """Phi-4-mini-flash (``model_type: phi4flash``; SambaY, arXiv:2507.06607, with
+    differential attention): pre-norm layers (``LayerNorm`` with bias, no
+    position embedding) whose mixer follows from the layer's PUBLISHED index
+    ``i`` of ``num_hidden_layers`` (half = ``num_hidden_layers // 2``, the
+    self-decoder; every ``mb_per_layer``-th layer a state-space one):
+
+        i even: Mamba-1 for i <= half (layer ``half`` also hands on its scan
+                output), a Gated Memory Unit over that output for i > half
+        i odd:  differential attention under ``sliding_window`` for i < half,
+                full causal at i = half + 1 (which hands on its keys and
+                values), cross-attention to those for i > half + 1
+
+    and a SwiGLU without bias in every layer (the checkpoint's fused
+    ``gate_up_proj`` is split here), a final LayerNorm, embedding and head
+    tied. The state-space sizes are the family's (``d_state`` 16, ``d_conv``
+    4, ``expand`` 2, ``dt_rank`` hidden / 16): the config has no key for
+    them, a key of Mamba's name (``mamba_d_state``, ``mamba_d_conv``,
+    ``mamba_expand``, ``mamba_dt_rank``) overrides. A stack cut out of the
+    model gives its kinds as ``layer_types`` (``mamba`` | ``sliding_attention``
+    | ``full_attention`` | ``gmu`` | ``cross_attention``) with ``layer_offset``,
+    the published index of its first layer; the kinds must be the rule's, and
+    a stack that keeps a reader without its source is refused by name.
+    Refused too: ``mlp_bias``, ``lm_head_bias``, an ``mb_per_layer`` other than
+    2, another activation."""
+    arch = "phi4flash"
+    row_parallel = ["o_proj", "down_proj", "out_proj"]
+    col_parallel = HFCheckpointPolicy.col_parallel + ["in_proj"]
+    KINDS = ("mamba", "sliding_attention", "full_attention", "gmu", "cross_attention")
+
+    @staticmethod
+    def published_kinds(depth: int, mb_per_layer: int = 2):
+        """The kind of every layer of the whole model, by the rule above."""
+        half = depth // 2
+
+        def kind(i):
+            if i % mb_per_layer == 0:
+                return "mamba" if i <= half else "gmu"
+            if i < half:
+                return "sliding_attention"
+            return "full_attention" if i == half + 1 else "cross_attention"
+
+        return [kind(i) for i in range(depth)]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        refused = {"mlp_bias": bool(hf_config.get("mlp_bias", False)),
+                   "lm_head_bias": bool(hf_config.get("lm_head_bias", False)),
+                   "mb_per_layer": hf_config.get("mb_per_layer", 2) != 2,
+                   "hidden_act": hf_config.get("hidden_act", "silu") != "silu"}
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(f"phi4flash: {key}={hf_config[key]!r} is not supported")
+        depth, hidden = hf_config["num_hidden_layers"], hf_config["hidden_size"]
+        published = hf_config.get("published", {}).get("num_hidden_layers", depth)
+        offset = int(hf_config.get("layer_offset", 0))
+        types = hf_config.get("layer_types") or self.published_kinds(published)
+        rule = self.published_kinds(published)[offset:offset + depth]
+        if len(types) != depth or list(types) != rule:
+            raise ValueError(f"phi4flash: layer_types {list(types)} are not the kinds of "
+                             f"published layers {offset}..{offset + depth - 1} of "
+                             f"{published}: {rule}")
+        half = published // 2
+        memory, kv = half - offset, half + 1 - offset   # where the sources stand here
+        window = hf_config.get("sliding_window")
+        width = hf_config["intermediate_size"]
+
+        def spec(kind):
+            if kind == "mamba":
+                return LayerSpec(operator="mamba1", ffn="dense", ffn_width=width)
+            if kind == "gmu":
+                return LayerSpec(operator="gmu", ffn="dense", ffn_width=width,
+                                 memory_from=memory)
+            return LayerSpec(
+                operator="attention", ffn="dense", ffn_width=width, differential=True,
+                window=int(window or 0) if kind == "sliding_attention" else 0,
+                kv_from=kv if kind == "cross_attention" else -1)
+
+        cfg = super().config_from_hf({
+            **hf_config, "rms_norm_eps": hf_config.get("layer_norm_eps", 1e-5),
+            "tie_word_embeddings": hf_config.get("tie_word_embeddings", True)})
+        inner = int(hf_config.get("mamba_expand", 2)) * hidden
+        cfg = dataclasses.replace(
+            cfg, layer_specs=tuple(spec(kind) for kind in types),
+            norm_type="layernorm", pos_embedding="none", num_local_experts=0,
+            attention_bias=True, attention_out_bias=True, layer_index_offset=offset,
+            mamba1_d_inner=inner,
+            mamba1_dt_rank=int(hf_config.get("mamba_dt_rank", -(-hidden // 16))),
+            mamba_d_state=int(hf_config.get("mamba_d_state", 16)),
+            mamba_d_conv=int(hf_config.get("mamba_d_conv", 4)), mamba_conv_bias=True)
+        try:
+            cfg.shared_sources()
+        except ValueError as e:
+            raise ValueError(f"phi4flash: published layers {offset}..{offset + depth - 1} "
+                             f"of {published}: {e}") from None
+        self.bind(cfg)
+        return cfg
+
+    def bind(self, cfg: LlamaConfig):
+        """As ``Lfm2MoePolicy.bind``: the name maps depend on the layer's kind."""
+        self._cfg = cfg
+
+    def global_map(self, tie_embeddings: bool):
+        out = {"model.embed_tokens.weight": ("embed_tokens/embedding", False),
+               "model.final_layernorm.weight": ("norm/scale", False),
+               "model.final_layernorm.bias": ("norm/bias", False)}
+        if not tie_embeddings:
+            out["lm_head.weight"] = ("lm_head/kernel", True)
+        return out
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "mlp.down_proj.weight": (f + "mlp/down_proj/kernel", True)}
+        for hf, ours in (("input_layernorm", "operator_norm"),
+                         ("post_attention_layernorm", "ffn_norm")):
+            out[p + hf + ".weight"] = (f + ours + "/scale", False)
+            out[p + hf + ".bias"] = (f + ours + "/bias", False)
+        spec = self._cfg.layer_specs[layer]
+        a = p + "attn."
+        if spec.operator == "attention":
+            fa = f + "self_attn/"
+            out.update({a + "out_proj.weight": (fa + "o_proj/kernel", True),
+                        a + "out_proj.bias": (fa + "o_proj/bias", False),
+                        a + "inner_cross_attn.subln.weight": (fa + "subln", False)})
+            for n in ("q1", "k1", "q2", "k2"):
+                out[a + "inner_cross_attn.lambda_" + n] = (fa + "lambda_" + n, False)
+            if spec.kv_from >= 0:       # a cross layer's Wqkv is its queries'
+                out[a + "Wqkv.weight"] = (fa + "q_proj/kernel", True)
+                out[a + "Wqkv.bias"] = (fa + "q_proj/bias", False)
+            return out
+        fm = f + "mamba/"
+        out.update({a + "in_proj.weight": (fm + "in_proj/kernel", True),
+                    a + "out_proj.weight": (fm + "out_proj/kernel", True)})
+        if spec.operator == "mamba1":
+            out.update({a + "x_proj.weight": (fm + "x_proj/kernel", True),
+                        a + "dt_proj.weight": (fm + "dt_proj/kernel", True),
+                        a + "dt_proj.bias": (fm + "dt_proj/bias", False),
+                        a + "conv1d.bias": (fm + "conv_bias", False),
+                        a + "A_log": (fm + "A_log", False), a + "D": (fm + "D", False)})
+        return out
+
+    def _special(self, layer: int):
+        """HF name -> our path(s) of the tensors a plain map cannot express."""
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "mlp.gate_up_proj.weight":
+               (f + "mlp/gate_proj/kernel", f + "mlp/up_proj/kernel")}
+        spec = self._cfg.layer_specs[layer]
+        if spec.operator == "mamba1":
+            out[p + "attn.conv1d.weight"] = (f + "mamba/conv_weight", )
+        elif spec.operator == "attention" and spec.kv_from < 0:
+            for kind in ("weight", "bias"):
+                leaf = "kernel" if kind == "weight" else "bias"
+                out[p + "attn.Wqkv." + kind] = tuple(
+                    f + f"self_attn/{proj}/{leaf}" for proj in ("q_proj", "k_proj", "v_proj"))
+        return out
+
+    def special_hf_names(self, layer: int):
+        return list(self._special(layer))
+
+    def convert_special(self, layer: int, cfg: LlamaConfig, get_tensor, put):
+        """``gate_up_proj`` ``[2 F, hidden]`` -> gate and up kernels ``[hidden,
+        F]``; ``Wqkv`` (rows q | k | v) -> the three projections; torch
+        Conv1d's depthwise weight ``[C, 1, L]`` -> taps ``[L, C]``."""
+        hd = cfg.head_dim_
+        rows = np.cumsum([cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd])
+        for hf_name, paths in self._special(layer).items():
+            w = get_tensor(hf_name)
+            if len(paths) == 1:
+                put(paths[0], w[:, 0, :].T)
+                continue
+            parts = np.split(w, 2 if len(paths) == 2 else rows, axis=0)
+            for path, part in zip(paths, parts):
+                put(path, part.T if part.ndim == 2 else part)
+
+    def export_special(self, layer: int, cfg: LlamaConfig, flat):
+        out = {}
+        for hf_name, paths in self._special(layer).items():
+            if len(paths) == 1:
+                out[hf_name] = flat[paths[0]].T[:, None, :]
+            else:
+                out[hf_name] = np.concatenate([flat[path].T for path in paths])
+        return out
+
+
 class GemmaPolicy(HFCheckpointPolicy):
     """Gemma (v1): llama graph with (1+weight) RMSNorm, sqrt(hidden) embed
     normalizer (rounded through the compute dtype, as HF does), tanh-gelu
@@ -1909,6 +2093,8 @@ _POLICIES = {
     "InternLMForCausalLM": InternLMPolicy,
     "phi3": Phi3Policy,
     "Phi3ForCausalLM": Phi3Policy,
+    "phi4flash": Phi4FlashPolicy,
+    "Phi4FlashForCausalLM": Phi4FlashPolicy,
     "baichuan": BaichuanPolicy,
     "BaichuanForCausalLM": BaichuanPolicy,
     "bloom": BloomPolicy,

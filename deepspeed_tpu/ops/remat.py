@@ -5,7 +5,7 @@
 in its backward but for the values named here. The attention kernels' output
 and log-sum-exp (``ops/attention.py::RESIDUAL_NAMES``) are always kept, as are
 a learned sparse attention's thresholds (``DSA_CHOICE``: two int32 a token
-and layer); the
+and layer) and what a layer hands to the layers after it (``SHARED``); the
 candidates below are kept as far down the list as the chip has room for, so
 that what makes them runs once a step:
 
@@ -22,6 +22,11 @@ that what makes them runs once a step:
                      layer at 32,768 tokens, 32 heads of 128 and chunks of
                      64); a layer without them runs ``kda_chunk_fwd`` once
                      more in its backward
+    ds.selscan.scan  a Mamba-1 selective scan's output and the float32
+                     states entering its blocks (``ops/selective_scan.py``:
+                     0.21 GB a layer at 16,384 tokens and 5,120 channels of
+                     16 states); a layer without them runs ``selscan_fwd``
+                     once more in its backward
     ds.ffn.in        a dense FFN's ``gate`` / ``up`` (``fc1``) outputs, a
                      shared expert's too
     ds.mixer.in      a mixer's input projections as they leave their matmuls
@@ -66,17 +71,26 @@ from .attention import RESIDUAL_NAMES
 # its choice as a bit mask, a candidate (T * T / 8 bytes a row of the batch)
 DSA_CHOICE = ("ds.dsa.tau", "ds.dsa.tie")
 DSA_MASK = "ds.dsa.mask"
+# what a layer hands to the layers after it beside the residual stream (a
+# differential attention layer's keys and values, a Mamba-1 layer's scan
+# output: ``models/llama.py``): inputs of every recomputed reader, so alive
+# from their source to its backward whatever is planned, and kept by name so
+# that the source's own recomputation does not make them again
+SHARED_KV = "ds.shared.kv"
+SHARED_MEMORY = "ds.shared.memory"
+SHARED = (SHARED_KV, SHARED_MEMORY)
 ROUTE = "ds.moe.route"
 MIXER_OUT = "ds.mixer.out"
 KDA_SCAN = "ds.kda.scan"
+SELSCAN_SCAN = "ds.selscan.scan"
 FFN_IN = "ds.ffn.in"
 MIXER_IN = "ds.mixer.in"
 MIXER_OUT_NARROW = "ds.mixer.out.narrow"
 KERNEL_OUT = "ds.mixer.kernel"
 # the walk's order: ms of recomputation returned a byte, falling
-CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, KDA_SCAN, FFN_IN, MIXER_IN,
-                   MIXER_OUT_NARROW, KERNEL_OUT)
-KEPT_NAMES = RESIDUAL_NAMES + DSA_CHOICE + CANDIDATE_NAMES
+CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, KDA_SCAN, SELSCAN_SCAN, FFN_IN,
+                   MIXER_IN, MIXER_OUT_NARROW, KERNEL_OUT)
+KEPT_NAMES = RESIDUAL_NAMES + DSA_CHOICE + SHARED + CANDIDATE_NAMES
 
 # The step's own temporaries, in one place. Compiled for a described v5e
 # (tests/unit/ops/test_tpu_aot_compile.py, ..._mla.py; PR 41) the four
@@ -136,6 +150,11 @@ def keep(x, name: str):
     ``name + AGAIN``, which none does, where the layer being traced
     (``keeping``) does not keep it."""
     return checkpoint_name(x, name if keeps(name) else name + AGAIN)
+
+
+def handed_on(x, name: str):
+    """``x`` under ``name`` of ``SHARED``, which every plan keeps."""
+    return checkpoint_name(x, name)
 
 
 def choose_kept(available: Optional[int], prices: Prices,

@@ -106,7 +106,8 @@ def _as_apply_fns(model):
             out, mods = model.apply({"params": params}, *args, **kwargs,
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
                                              "mla_stats", "diffusion_stats",
-                                             "dsa_stats", "kda_stats"])
+                                             "dsa_stats", "kda_stats",
+                                             "selscan_stats", "diffattn_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -161,6 +162,21 @@ def _as_apply_fns(model):
                         if path[-1].key == name]
                 if sown:
                     stats["kda_" + name] = reduce(jnp.concatenate(sown))
+            # "selscan_stats" for a Mamba-1 mixer, as "ssm_stats":
+            # ``state_absmax`` the largest over the layers, ``dt_mean`` their
+            # mean; "diffattn_stats" for differential attention: ``lambda_mean``
+            # comes back a layer (the differential layers in their order)
+            for prefix, wanted in (
+                    ("selscan", (("state_absmax", jnp.max), ("dt_mean", jnp.mean))),
+                    ("diffattn", (("lambda_mean", None), ))):
+                sown_all = jax.tree_util.tree_flatten_with_path(
+                    mods.get(prefix + "_stats", {}))[0]
+                for name, reduce in wanted:
+                    sown = [leaf.reshape(-1) for path, leaf in sown_all
+                            if path[-1].key == name]
+                    if sown:
+                        sown = jnp.concatenate(sown)
+                        stats[f"{prefix}_{name}"] = reduce(sown) if reduce else sown
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -1738,6 +1754,15 @@ class DeepSpeedTpuEngine:
             get_registry().gauge(
                 "ds_model_layers", "Decoder layers by kind (operator+ffn)",
                 labels={"kind": kind}).set(float(n))
+        if any(s.kv_from >= 0 or s.memory_from >= 0 for s in specs or ()):
+            for name, what, n in (
+                    ("ds_model_shared_kv_readers", "the keys and values of an earlier "
+                     "layer", sum(s.kv_from >= 0 for s in specs)),
+                    ("ds_model_shared_memory_readers", "the scan output of an earlier "
+                     "state-space layer", sum(s.memory_from >= 0 for s in specs))):
+                get_registry().gauge(
+                    name, f"Decoder layers that read {what} (passed beside the "
+                    "residual stream)").set(float(n))
 
     def _publish_moe_stats(self):
         """Routing counters of the fused MoE steps dispatched since the last
@@ -1774,6 +1799,25 @@ class DeepSpeedTpuEngine:
                 "Mean decay exp(g) a key channel and token of the Kimi Delta "
                 "Attention layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["kda_decay_mean"]) for s in fetched])))
+        if "selscan_state_absmax" in fetched[0]:
+            reg.gauge(
+                "ds_selscan_state_absmax",
+                "Largest |h| the Mamba-1 selective scans held (the blocks' "
+                "states where the kernels run), over the layers and the steps "
+                "of the last publish"
+            ).set(float(max(np.max(s["selscan_state_absmax"]) for s in fetched)))
+            reg.gauge(
+                "ds_selscan_dt_mean",
+                "Mean step size dt = softplus(dt_proj(delta)) of the Mamba-1 "
+                "layers, over the steps of the last publish"
+            ).set(float(np.mean([np.mean(s["selscan_dt_mean"]) for s in fetched])))
+        if "diffattn_lambda_mean" in fetched[0]:
+            reg.gauge(
+                "ds_diffattn_lambda_mean",
+                "Mean over the differential attention layers of the weight "
+                "lambda their second softmax map is subtracted with, over the "
+                "steps of the last publish"
+            ).set(float(np.mean([np.mean(s["diffattn_lambda_mean"]) for s in fetched])))
         if "kda_head_block" in fetched[0]:
             reg.gauge(
                 "ds_kda_head_block",
@@ -2220,7 +2264,7 @@ class DeepSpeedTpuEngine:
         that sows none."""
         return self._newest_stats(
             lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_",
-                                              "kda_")))
+                                              "kda_", "selscan_", "diffattn_")))
 
     def diffusion_stats(self):
         """What the block-diffusion objective sowed in the newest fused step
@@ -2254,6 +2298,22 @@ class DeepSpeedTpuEngine:
         without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("kda_"))
         return stats and {name[len("kda_"):]: v for name, v in stats.items()}
+
+    def selscan_stats(self):
+        """What the Mamba-1 layers sowed in the newest fused step not yet
+        published, as host scalars: ``state_absmax`` (the largest |h| over
+        the layers) and ``dt_mean``. Waits for that step, as
+        :meth:`moe_stats`; ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("selscan_"))
+        return stats and {name[len("selscan_"):]: v for name, v in stats.items()}
+
+    def diffattn_stats(self):
+        """What the differential attention layers sowed in the newest fused
+        step not yet published: ``lambda_mean``, a host array with each such
+        layer's ``lambda`` in the layers' order. Waits for that step, as
+        :meth:`moe_stats`; ``None`` for a model without such a layer."""
+        stats = self._newest_stats(lambda name: name.startswith("diffattn_"))
+        return stats and {name[len("diffattn_"):]: v for name, v in stats.items()}
 
     def mla_stats(self):
         """What the latent-attention layers sowed in the newest fused step

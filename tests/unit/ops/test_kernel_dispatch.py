@@ -641,6 +641,24 @@ def test_the_kda_head_block_divides_the_heads_fits_the_cap_and_falls_to_one(
         got, d, chunk, itemsize) == got * kd.kda_vmem_bytes(1, d, chunk, itemsize)
 
 
+@pytest.mark.parametrize("window", [None, 512])
+def test_values_wider_than_keys_take_one_lane_tile_and_the_fused_backward(window):
+    """Differential attention's stacked call (PR 52): 40 query heads of 64 in
+    groups of 2 over 20 key heads, values 128 wide, 16,384 keys. The VMEM
+    estimates are asked about 128 lanes (64 | 128 is one lane tile, where 192
+    | 128 is two), both legs take (512, 512) and the backward is the fused
+    kernel; the window does not enter the rule."""
+    sig = kd.make_sig((1, 16384, 40, 64), 20, 16384, "bfloat16", True, window, None,
+                      v_dim=128)
+    assert sig.v_dim == 128 and sig.windowed == (window is not None)
+    assert kd.vmem_width(64, 128) == 128 and kd.vmem_width(192, 128) == 256
+    fwd, bwd = kd.resolve(sig)
+    assert (fwd, bwd) == (kd.Decision(kd.IMPL_PALLAS, 512, 512),
+                          kd.Decision(kd.IMPL_FUSED, 512, 512))
+    assert kd.flash_vmem_bytes("fwd", 2, 128, 2, 512, 512) <= kd.VMEM_SCOPED_DEFAULT_BYTES
+    assert 28 * 2**20 <= kd.fused_vmem_bytes(sig) <= kd.FUSED_VMEM_CAP_BYTES
+
+
 def test_env_report_includes_dispatch_lines():
     from deepspeed_tpu.env_report import debug_report
     rep = debug_report()
