@@ -1117,18 +1117,25 @@ class KimiDeltaMixer(nn.Module):
     taps without bias and SiLU (``ops/short_conv.py::causal_conv``); per head
     ``q = l2norm(q) / sqrt(d)``, ``k = l2norm(k)``; the decay of every key
     channel ``g = kda_gate_floor * sigmoid(exp(A_log_h) * (f_proj(x) +
-    dt_bias))`` (made inside the kernels where they run), ``beta =
-    sigmoid(b_proj(x))`` one a head; the delta-rule scan with a state of ``d x
-    d`` a head (``ops/kda.py::kda_scan``, in chunks of ``kda_chunk_size``);
-    ``RMSNorm_d(o) * o_norm * sigmoid(g_proj(x))`` head by head; ``o_proj``. Named ``self_attn`` by its layer, as the other sequence
-    mixers' projections are read (``benchmark/scope_time.py``). Sows
-    ``kda_stats`` (only when mutable): the largest ``|S|``, the mean decay
-    ``exp(g)`` and the mean ``beta``."""
+    dt_bias))``, ``beta = sigmoid(b_proj(x))`` one a head; the delta-rule scan
+    with a state of ``d x d`` a head, in chunks of ``kda_chunk_size``;
+    ``RMSNorm_d(o) * o_norm * sigmoid(g_proj(x))`` head by head; ``o_proj``.
+    Everything between the convolutions and ``o_proj`` is one call,
+    ``ops/kda.py::kda_fused``: where the chunk kernels run (a TPU, a mesh of
+    one device, heads of a multiple of 128) they make the norms, ``beta k``,
+    ``beta v``, the gate and the gated output norm on the tiles they hold, and
+    no pass over ``[tokens, heads * d]`` is XLA's; elsewhere XLA makes the
+    norms around the recurrence (``ds.kda.norm``). Named ``self_attn`` by its
+    layer, as the other sequence mixers' projections are read
+    (``benchmark/scope_time.py``). Sows ``kda_stats`` (only when mutable): the
+    largest ``|S|``, the mean decay ``exp(g)``, the mean ``beta`` and
+    ``fused_rows`` (1.0 where the kernels made the rows' norms and products,
+    0.0 where XLA did)."""
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.kda import GATE_FLOOR, bounded_gate, grid_of, kda_scan
+        from ..ops.kda import GATE_FLOOR, grid_of, kda_fused
         from ..ops.short_conv import causal_conv
         cfg = self.config
         H, d = cfg.num_attention_heads, cfg.kda_head_dim
@@ -1168,47 +1175,30 @@ class KimiDeltaMixer(nn.Module):
         o_norm = self.param("o_norm", nn.with_partitioning(nn.initializers.ones, (None, )),
                             (d, ), f32)
 
-        # the norms' float32 insides are made again in the backward from
-        # their bf16 operands (a checkpoint each): kept, they are five float32
-        # arrays of tokens x inner a layer, 1.3 GB at 16,384 tokens
-        @jax.checkpoint
-        def l2norm(a, scale):
-            a = a.astype(f32)
-            return (a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-                    * scale).astype(cfg.dtype)
-
-        @jax.checkpoint
-        def gated_norm(o, gate, weight):
-            o = o.astype(f32)
-            var = jnp.mean(o * o, axis=-1, keepdims=True)
-            y = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * weight
-                 * jax.nn.sigmoid(gate.astype(f32)).reshape(o.shape))
-            return y.astype(cfg.dtype).reshape(b, s, inner)
-
-        # every scope closes before the kernels' call below: one that held it
-        # would rename the instruction (docs/observability.md)
-        with jax.named_scope("ds.kda.norm"):
-            q, k = l2norm(q, float(d) ** -0.5), l2norm(k, 1.0)
         with jax.named_scope("ds.kda.gates"):
             rate = jnp.exp(a_log)
             beta = jax.nn.sigmoid(beta_in.astype(f32))
         want_stats = self.is_mutable_collection("kda_stats")
         use_kernel, interpret = kernels and d % 128 == 0, interpret_kernels() and d % 128 == 0
-        # the kernels make the gate g = floor * sigmoid(rate * (f_proj + dt_bias))
-        # and its running sum themselves (ops/kda.py)
-        o = kda_scan(q, k, v, decay_in.reshape(b, s, H, d), rate, dt_bias, beta,
-                     cfg.kda_chunk_size, floor=cfg.kda_gate_floor,
-                     use_kernel=use_kernel, interpret=interpret,
-                     with_state_absmax=want_stats, keep=remat.keeps(remat.KDA_SCAN))
+        # where the kernels run they make everything between the convolutions
+        # and o_proj on the tiles they hold: the norms, beta k and beta v, the
+        # gate g = floor * sigmoid(rate * (f_proj + dt_bias)) and its running
+        # sum, the gated output norm (ops/kda.py); elsewhere XLA makes the
+        # norms around the recurrence. Every scope closes before the kernels'
+        # call: one that held it would rename the instruction
+        # (docs/observability.md)
+        y = kda_fused(q, k, v, decay_in.reshape(b, s, H, d), rate, dt_bias, beta,
+                      gate_in.reshape(b, s, H, d), o_norm, cfg.kda_chunk_size,
+                      eps=cfg.rms_norm_eps, floor=cfg.kda_gate_floor,
+                      use_kernel=use_kernel, interpret=interpret,
+                      with_stats=want_stats, keep=remat.keeps(remat.KDA_SCAN))
         if want_stats:
-            o, top = o
-            self.sow("kda_stats", "state_absmax", top, reduce_fn=jnp.maximum,
-                     init_fn=lambda: jnp.zeros((), f32))
-            with jax.named_scope("ds.kda.gates"):
-                decay = jnp.mean(jnp.exp(bounded_gate(
-                    decay_in.reshape(b, s, H, d), rate, dt_bias, cfg.kda_gate_floor)))
-            for name, value in (("decay_mean", decay), ("beta_mean", jnp.mean(beta))):
-                self.sow("kda_stats", name, jax.lax.stop_gradient(value),
+            y, stats = y
+            self.sow("kda_stats", "state_absmax", stats["state_absmax"],
+                     reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
+            stats["beta_mean"] = jax.lax.stop_gradient(jnp.mean(beta))
+            for name in ("decay_mean", "beta_mean", "fused_rows"):
+                self.sow("kda_stats", name, stats[name],
                          reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
             if use_kernel or interpret:
                 # how the kernels' grid was laid over this call: the heads a
@@ -1217,8 +1207,7 @@ class KimiDeltaMixer(nn.Module):
                 for name, value in zip(("head_block", "grid_steps"), grid):
                     self.sow("kda_stats", name, jnp.asarray(value, f32),
                              reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
-        with jax.named_scope("ds.kda.norm"):
-            y = gated_norm(o, gate_in, o_norm)
+        y = y.reshape(b, s, inner)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       keep=_keep_out(cfg, inner))(y)
 

@@ -410,12 +410,16 @@ def kda_vmem_bytes(block: int, d: int, chunk: int, itemsize: int,
                    arrays: int = 11) -> int:
     """Upper estimate of the VMEM one grid step of a ``kda_chunk_*`` kernel
     holds at ``block`` heads of ``d`` lanes: its ``arrays`` pipelined ``[chunk,
-    block * d]`` blocks twice (6 forward, 11 backward, the larger by
-    default), the float32 state's blocks and scratch, and some four dozen
-    ``[chunk, d]`` float32 temporaries a head."""
+    block * d]`` blocks twice (6 forward: q, k, v, the two gates'
+    pre-activations and the output; 11 backward: those five, the output's
+    gradient and five gradients; the larger by default), the blocks of
+    ``beta`` and its gradient (a head a lane, 128 lanes of float32), the
+    float32 state's blocks and scratch, and some four and a half dozen
+    ``[chunk, d]`` float32 temporaries a head (since PR 53 the unit rows, the
+    norms and the gated output norm's are among them)."""
     width = block * d
-    return (2 * arrays * chunk * width * max(itemsize, 4) + 6 * 4 * d * width
-            + 48 * 4 * chunk * width)
+    return (2 * arrays * chunk * width * max(itemsize, 4) + 2 * 2 * chunk * 128 * 4
+            + 6 * 4 * d * width + 56 * 4 * chunk * width)
 
 
 def choose_kda_heads(heads: int, d: int, chunk: int, itemsize: int) -> int:

@@ -150,14 +150,16 @@ def _as_apply_fns(model):
                     sown = jnp.concatenate(sown)
                     stats["dsa_" + name] = reduce(sown) if reduce else sown
             # "kda_stats" for a Kimi Delta Attention mixer: ``state_absmax``
-            # comes back as the largest over the layers, ``decay_mean`` and
-            # ``beta_mean`` as the layers' means; where the chunk kernels ran,
+            # comes back as the largest over the layers, ``decay_mean``,
+            # ``beta_mean`` and ``fused_rows`` (1.0 from a layer whose norms
+            # and beta products rode inside the kernels, 0.0 from one XLA made
+            # them for) as the layers' means; where the chunk kernels ran,
             # ``head_block`` and ``grid_steps`` (the heads a grid step took,
             # the steps a call: one value, the layers' calls are alike)
             kda = jax.tree_util.tree_flatten_with_path(mods.get("kda_stats", {}))[0]
             for name, reduce in (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
-                                 ("beta_mean", jnp.mean), ("head_block", jnp.max),
-                                 ("grid_steps", jnp.max)):
+                                 ("beta_mean", jnp.mean), ("fused_rows", jnp.mean),
+                                 ("head_block", jnp.max), ("grid_steps", jnp.max)):
                 sown = [leaf.reshape(-1) for path, leaf in kda
                         if path[-1].key == name]
                 if sown:
@@ -1799,6 +1801,12 @@ class DeepSpeedTpuEngine:
                 "Mean decay exp(g) a key channel and token of the Kimi Delta "
                 "Attention layers, over the steps of the last publish"
             ).set(float(np.mean([np.mean(s["kda_decay_mean"]) for s in fetched])))
+            reg.gauge(
+                "ds_kda_fused_rows",
+                "Share of the Kimi Delta Attention layers whose row norms, beta "
+                "products and gated output norm rode inside the chunk kernels in "
+                "the last step (0: XLA made them around the recurrence)"
+            ).set(float(np.mean(fetched[-1]["kda_fused_rows"])))
         if "selscan_state_absmax" in fetched[0]:
             reg.gauge(
                 "ds_selscan_state_absmax",
@@ -2291,9 +2299,11 @@ class DeepSpeedTpuEngine:
     def kda_stats(self):
         """What the Kimi Delta Attention layers sowed in the newest fused
         step not yet published, as host scalars: ``state_absmax`` (the largest
-        |S| over the layers), ``decay_mean`` (of ``exp(g)``) and ``beta_mean``
-        (the layers' means) and, where the chunk kernels ran, ``head_block``
-        and ``grid_steps`` (the heads a grid step took, the steps a call).
+        |S| over the layers), ``decay_mean`` (of ``exp(g)``), ``beta_mean`` and
+        ``fused_rows`` (the layers' means; the last is 1.0 where the kernels
+        made the norms and beta products, 0.0 where XLA did) and, where the
+        chunk kernels ran, ``head_block`` and ``grid_steps`` (the heads a
+        grid step took, the steps a call).
         Waits for that step, as :meth:`moe_stats`; ``None`` for a model
         without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("kda_"))

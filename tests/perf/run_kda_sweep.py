@@ -3,9 +3,12 @@
 call ``[1, 16384, 32 x 128]`` in bf16 with chunks of 64: milliseconds a call,
 the seconds a kernel takes to lower (Mosaic's part) and to compile, the VMEM
 its call asks for, and whether a block of heads gives what one head a step
-gives, bit for bit. It wrote ``docs/readings/kda_heads_sweep_pr49.jsonl`` and
-is how a change to the kernels or to ``kernel_dispatch.choose_kda_heads`` is
-checked.
+gives, bit for bit. It wrote ``docs/readings/kda_heads_sweep_pr49.jsonl`` and,
+since the kernels make the row norms, the beta products and the gated output
+norm themselves (PR 53: their operands are the convolutions' raw q, k and v,
+both gates' pre-activations and beta a head a lane),
+``docs/readings/kda_heads_sweep_pr53.jsonl``; it is how a change to the kernels
+or to ``kernel_dispatch.choose_kda_heads`` is checked.
 
 Not a pytest assertion: a measurement tool, as ``run_attn_sweep.py`` is.
 
@@ -42,27 +45,31 @@ def _time(fn, iters: int) -> float:
 
 
 def _operands(seed: int, seq: int, heads: int):
-    """The kernels' operands as ``kda_scan`` hands them over: unit q and k, a
-    gate's pre-activation that decays a few per cent a token, ``beta`` in
-    (0, 1) folded into ``kb`` and ``vb``."""
+    """The kernels' operands as ``kda_fused`` hands them over: q, k and v as a
+    convolution and SiLU leave them (no row of unit length), a gate's
+    pre-activation that decays a few per cent a token, the output gate's,
+    ``beta`` in (0, 1) a head a lane, the lanes of rate, bias and the output
+    norm's weight; and the output's gradient."""
     import jax
     import jax.numpy as jnp
+    from deepspeed_tpu.ops.kda import LANES
     from deepspeed_tpu.ops.ssd import SUBLANES
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 10)
     shape = (BATCH, seq, heads, D)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)     # noqa: E731
-    q = unit(jax.random.normal(ks[0], shape)) * D ** -0.5
-    k = unit(jax.random.normal(ks[1], shape))
-    v = jax.random.normal(ks[2], shape)
+    q, k, v = (jax.nn.silu(jax.random.normal(key, shape)) for key in ks[:3])
     pre = 2.0 * jax.random.normal(ks[3], shape) - 4.0
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))[..., None]
-    do = jax.random.normal(ks[5], shape)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+    dy = jax.random.normal(ks[5], shape)
     rate = jax.random.uniform(ks[6], (heads, ), minval=1.0, maxval=4.0)
     bias = 0.3 * jax.random.normal(ks[7], (heads * D, ))
+    gate = jax.random.normal(ks[8], shape)
+    weight = 1.0 + 0.1 * jax.random.normal(ks[9], (D, ))
     lanes = jnp.zeros((SUBLANES, heads * D), jnp.float32)
-    lanes = lanes.at[0].set(jnp.repeat(rate, D)).at[1].set(bias)
+    lanes = lanes.at[0].set(jnp.repeat(rate, D)).at[1].set(bias).at[2].set(
+        jnp.tile(weight, heads))
     flat = lambda a: a.astype(jnp.bfloat16).reshape(BATCH, seq, heads * D)  # noqa: E731
-    return [flat(a) for a in (q, k, beta * k, beta * v, pre)] + [lanes], flat(do)
+    beta = jnp.pad(beta, ((0, 0), (0, 0), (0, -heads % LANES)))
+    return [flat(a) for a in (q, k, v, pre, gate)] + [beta, lanes], flat(dy)
 
 
 def main(argv=None) -> int:
@@ -89,8 +96,8 @@ def main(argv=None) -> int:
             out.write(line + "\n")
             out.flush()
 
-    operands, do = _operands(args.seed, seq, heads)
-    static = (heads, CHUNK, kda.GATE_FLOOR, args.interpret)
+    operands, dy = _operands(args.seed, seq, heads)
+    static = (heads, CHUNK, kda.GATE_FLOOR, 1e-6, args.interpret)
     # the least time of a call by the bytes of the mathematics, as
     # benchmark/kda_cost.py counts them
     values, betas = BATCH * seq * heads * D, BATCH * seq * heads
@@ -105,7 +112,7 @@ def main(argv=None) -> int:
         }
         for leg, (call, given) in legs.items():
             if given is None:       # the backward reads the states its forward wrote
-                given = operands + [want["fwd"][1], do]
+                given = operands + [want["fwd"][1], dy]
             row = {"leg": leg, "block": block, "grid_steps": BATCH * (heads // block)
                    * (seq // CHUNK), "vmem_estimate": kd.kda_vmem_bytes(
                        block, D, CHUNK, 2, 6 if leg == "fwd" else 11)}

@@ -379,7 +379,10 @@ def test_the_engine_says_which_block_of_heads_the_chunk_kernels_took(head_dim, h
     """``head_block`` and ``grid_steps`` ride in ``kda_stats`` beside the
     largest ``|S|`` where the chunk kernels run (interpreted here at heads of
     128), and come out as ``ds_kda_head_block`` / ``ds_kda_grid_steps`` with
-    the step's publish; where the recurrence runs neither exists."""
+    the step's publish; where the recurrence runs neither exists.
+    ``fused_rows`` says who made the row norms and the beta products: 1.0
+    where they rode inside the kernels, 0.0 where XLA made them around the
+    recurrence, and ``ds_kda_fused_rows`` shows it either way."""
     import deepspeed_tpu
     from deepspeed_tpu.comm import MeshContext, reset_mesh_context, set_mesh_context
     from deepspeed_tpu.observability import get_registry
@@ -400,8 +403,10 @@ def test_the_engine_says_which_block_of_heads_the_chunk_kernels_took(head_dim, h
         stats = engine.kda_stats()
         engine.train_batch(iter([(ids, ids)]))      # publishes the step before
         assert float(stats["state_absmax"]) > 0.0
+        assert float(stats["fused_rows"]) == (0.0 if block is None else 1.0)
+        assert reg.get("ds_kda_fused_rows").value == float(stats["fused_rows"])
         if block is None:
-            assert set(stats) == {"state_absmax", "decay_mean", "beta_mean"}
+            assert set(stats) == {"state_absmax", "decay_mean", "beta_mean", "fused_rows"}
             unset = reg.get("ds_kda_head_block")    # the registry is the process's
             assert unset is None or unset.value == 0
         else:

@@ -614,17 +614,18 @@ def test_the_kernel_runs_where_a_raw_pallas_call_can(monkeypatch, tpu, devices, 
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("why,heads,d,chunk,itemsize,block", [
-    ("the Ling-3.0 cell: 32 heads of 128, chunks of 64, bf16", 32, 128, 64, 2, 4),
+    ("the Ling-3.0 cell: 32 heads of 128, chunks of 64, bf16 (11.9 MB backward, 10.6 "
+     "forward at the array counts since PR 53: under the 16 MiB default)", 32, 128, 64, 2, 4),
     ("float32 operands are counted as the float32 temporaries are", 4, 128, 64, 4, 4),
     ("eight heads take four, not eight", 8, 128, 64, 2, 4),
     ("six heads fall to two", 6, 128, 64, 2, 2),
     ("two heads", 2, 128, 64, 2, 2),
     ("an odd count falls to one", 3, 128, 64, 2, 1),
     ("one head", 1, 128, 64, 2, 1),
-    ("heads of 512: four are 62 MB of the 64 MiB cap", 32, 512, 64, 2, 4),
+    ("heads of 512: four are 66.2 MB of the 67.1 MB (64 MiB) cap", 32, 512, 64, 2, 4),
     ("a chunk of 512: two heads fit the cap, four do not", 32, 128, 512, 2, 2),
     ("a chunk of 1,024: one head alone fits", 32, 128, 1024, 2, 1),
-    ("heads of 1,024: 87 MB at two", 32, 1024, 64, 2, 1),
+    ("heads of 1,024: 91 MB at two", 32, 1024, 64, 2, 1),
 ], ids=lambda v: v.split(":")[0].replace(" ", "_") if isinstance(v, str) else None)
 def test_the_kda_head_block_divides_the_heads_fits_the_cap_and_falls_to_one(
         why, heads, d, chunk, itemsize, block):
@@ -637,8 +638,24 @@ def test_the_kda_head_block_divides_the_heads_fits_the_cap_and_falls_to_one(
         assert heads % larger or kd.kda_vmem_bytes(
             larger, d, chunk, itemsize) > kd.FUSED_VMEM_CAP_BYTES
     # the estimate is the backward's unless told, and grows with the block
+    # (all of it but beta's two blocks, a head a lane whatever the block)
+    betas = 2 * 2 * chunk * 128 * 4
     assert kd.kda_vmem_bytes(got, d, chunk, itemsize, 6) < kd.kda_vmem_bytes(
-        got, d, chunk, itemsize) == got * kd.kda_vmem_bytes(1, d, chunk, itemsize)
+        got, d, chunk, itemsize) == got * (kd.kda_vmem_bytes(1, d, chunk, itemsize)
+                                           - betas) + betas
+
+
+def test_the_kda_estimate_counts_the_kernels_blocks_as_they_are_since_the_rows_moved_in():
+    """PR 53: the forward pipelines q, k, v, both gates' pre-activations and
+    the output (6), the backward those five, the output's gradient and five
+    gradients (11): the counts XLA's ``beta k`` / ``beta v`` gave, so the
+    Ling-3.0 cell's call still takes four heads under the compiler's default
+    limit, and asks for none."""
+    fwd, bwd = (kd.kda_vmem_bytes(4, 128, 64, 2, n) for n in (6, 11))
+    assert (fwd, bwd) == (10_616_832, 11_927_552)
+    assert bwd - fwd == 2 * 5 * 64 * 512 * 4
+    assert bwd <= kd.VMEM_SCOPED_DEFAULT_BYTES and kd.vmem_limit_bytes(bwd) is None
+    assert kd.choose_kda_heads(32, 128, 64, 2) == 4
 
 
 @pytest.mark.parametrize("window", [None, 512])
