@@ -40,7 +40,8 @@ class LayerSpec:
     every few, a dense FFN in the leading layers and experts after)."""
     # "attention" | "conv" (ops/short_conv.py) | "mamba" (Mamba2Mixer) |
     # "latent" (LatentAttention: DeepSeek-V2/V3's MLA) | "kda" (KimiDeltaMixer)
-    # | "mamba1" (SelectiveScanMixer) | "gmu" (GatedMemoryUnit)
+    # | "gdn" (GatedDeltaNetMixer) | "mamba1" (SelectiveScanMixer) | "gmu"
+    # (GatedMemoryUnit)
     operator: str = "attention"
     ffn: str = "dense"              # "dense" (LlamaMLP) | "moe" (LlamaMoEBlock)
     ffn_width: int = 0              # the dense FFN's, or ONE expert's, width
@@ -194,6 +195,18 @@ class LlamaConfig:
     kda_d_conv: int = 4
     kda_gate_floor: float = -5.0
     kda_chunk_size: int = 64
+    # the "gdn" operator (Gated DeltaNet, ``ops/gdn.py``): ``gdn_k_heads`` key
+    # heads of ``gdn_k_head_dim`` under ``gdn_v_heads`` value heads of
+    # ``gdn_v_head_dim`` (a multiple: consecutive value heads share a key head's
+    # q and k), ``gdn_d_conv`` causal taps with SiLU over q | k | v together, one
+    # log decay a value head and token with no floor (``-exp(A_log) *
+    # softplus(.)``), the scan in chunks of ``gdn_chunk_size``
+    gdn_k_heads: int = 0
+    gdn_v_heads: int = 0
+    gdn_k_head_dim: int = 128
+    gdn_v_head_dim: int = 128
+    gdn_d_conv: int = 4
+    gdn_chunk_size: int = 64
     # the "mamba1" operator (Mamba-1, ``ops/selective_scan.py``):
     # ``mamba1_d_inner`` channels, each with ``mamba_d_state`` states of a decay
     # rate of their own, ``mamba_d_conv`` taps (``mamba_conv_bias``) before the
@@ -207,7 +220,10 @@ class LlamaConfig:
     layer_index_offset: int = 0
     # "head": the "latent" operator's output times a sigmoid gate of the layer's
     # normed input, one value a head and token, before ``o_proj`` (Ling-3.0's
-    # ``gated_attention_proj_granularity_type: head_wise``); None = no gate
+    # ``gated_attention_proj_granularity_type: head_wise``); "elementwise": the
+    # "attention" operator's ``q_proj`` is twice as wide, its second half a gate
+    # of every output value, ``o_proj(attn * sigmoid(gate))`` (Qwen3-Next);
+    # None = no gate
     attn_output_gate: Optional[str] = None
     # group-limited choice of a sigmoid router with a selection bias (DeepSeek-V3's
     # ``noaux_tc``): the router's experts in ``moe_n_group`` groups of
@@ -357,12 +373,19 @@ class LlamaConfig:
         kda = (6 * h * kda_inner + h * self.num_attention_heads
                + 3 * self.kda_d_conv * kda_inner
                + self.num_attention_heads + kda_inner + self.kda_head_dim)
+        gdn_k = self.gdn_k_heads * self.gdn_k_head_dim
+        gdn_v = self.gdn_v_heads * self.gdn_v_head_dim
+        gdn = (h * (2 * gdn_k + 2 * gdn_v) + h * 2 * self.gdn_v_heads
+               + self.gdn_d_conv * (2 * gdn_k + gdn_v) + 2 * self.gdn_v_heads
+               + self.gdn_v_head_dim + gdn_v * h)
+        if self.attn_output_gate == "elementwise":
+            attn += h * self.num_attention_heads * hd
         wide, rank = self.mamba1_d_inner, self.mamba1_dt_rank
         mamba1 = (3 * h * wide + (self.mamba_d_conv + 1) * wide
                   + wide * (rank + 2 * self.mamba_d_state) + (rank + 1) * wide
                   + wide * self.mamba_d_state + wide)
         operator = {"conv": conv, "attention": attn, "mamba": mamba,
-                    "latent": latent, "kda": kda, "mamba1": mamba1,
+                    "latent": latent, "kda": kda, "gdn": gdn, "mamba1": mamba1,
                     "gmu": 2 * h * wide}
         return max(operator[spec.operator] + ffn(spec.ffn, spec.ffn_width) + 2 * h
                    for spec in self.layer_specs)
@@ -595,8 +618,14 @@ class LlamaAttention(nn.Module):
         hd = cfg.head_dim_
         nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
 
-        q = _dense(nq * hd, "q_proj", (EMBED, HEADS), cfg.dtype, cfg.attention_bias,
-                   remat.MIXER_IN)(x)
+        gated = cfg.attn_output_gate == "elementwise"
+        if cfg.attn_output_gate and not gated:
+            raise ValueError(f"attn_output_gate {cfg.attn_output_gate!r}: the attention "
+                             "operator knows the elementwise gate alone")
+        q = _dense(nq * hd * (2 if gated else 1), "q_proj", (EMBED, HEADS), cfg.dtype,
+                   cfg.attention_bias, remat.MIXER_IN)(x)
+        if gated:   # the queries of every head, then the gates of every head
+            q, gate = q[..., :nq * hd], q[..., nq * hd:]
         k = _dense(nkv * hd, "k_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias,
                    remat.MIXER_IN)(x)
         v = _dense(nkv * hd, "v_proj", (EMBED, KV), cfg.dtype, cfg.attention_bias,
@@ -615,8 +644,8 @@ class LlamaAttention(nn.Module):
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
         if per_head:  # LFM2: each head's hd normalized, one weight for all
-            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_plus_one, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_plus_one, name="k_norm")(k)
         if cfg.pos_embedding == "rope":
             # closed before the kernel's call below: a scope that held it
             # would rename the instruction (docs/observability.md)
@@ -727,6 +756,14 @@ class LlamaAttention(nn.Module):
             if attn is None:
                 attn = _core_attn(q, k, v)
         out = attn.reshape(b, s, nq * hd)
+        if gated:
+            with jax.named_scope("ds.attn.gate"):
+                open_ = jax.nn.sigmoid(gate.astype(jnp.float32))
+                out = (out.astype(jnp.float32) * open_).astype(cfg.dtype)
+            if self.is_mutable_collection("attn_stats"):
+                self.sow("attn_stats", "gate_mean", jax.lax.stop_gradient(jnp.mean(open_)),
+                         reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.zeros((), jnp.float32))
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       cfg.attention_out_bias, _keep_out(cfg, nq * hd))(out)
 
@@ -1130,7 +1167,17 @@ class KimiDeltaMixer(nn.Module):
     (``benchmark/scope_time.py``). Sows ``kda_stats`` (only when mutable): the
     largest ``|S|``, the mean decay ``exp(g)``, the mean ``beta`` and
     ``fused_rows`` (1.0 where the kernels made the rows' norms and products,
-    0.0 where XLA did)."""
+    0.0 where XLA did).
+
+    ``GatedDeltaNetMixer`` is the same recurrence at a decay that is constant
+    over a head's key channels, and shares with this mixer the chunk algebra's
+    code (the triangular solve, the state passing, the unit rows, the matmul
+    stages abreast: ``ops/kda.py``), the convolution kernel and the shape of
+    its statistics. It does not share: the gate (here one a key channel under
+    a floor, made in the kernels from ``f_proj``; there one a value head with
+    no floor, made by XLA at ``[tokens, heads]``), the heads (here as many key
+    heads as value heads), the output gate (here a sigmoid), the projections
+    (here six, and three convolutions)."""
     config: LlamaConfig
 
     @nn.compact
@@ -1210,6 +1257,102 @@ class KimiDeltaMixer(nn.Module):
         y = y.reshape(b, s, inner)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       keep=_keep_out(cfg, inner))(y)
+
+
+class GatedDeltaNetMixer(nn.Module):
+    """Gated DeltaNet, the operator of a ``"gdn"`` layer (Yang et al.,
+    arXiv:2412.06464; HF ``Qwen3NextGatedDeltaNet``): ONE projection
+    ``in_proj_qkvz`` to ``q | k`` (``gdn_k_heads`` heads of ``gdn_k_head_dim``
+    each) ``| v | z`` (``gdn_v_heads`` heads of ``gdn_v_head_dim`` each) and one
+    ``in_proj_ba`` to ``b | a`` (a value head each); ``q | k | v`` through ONE
+    ``gdn_d_conv``-tap causal depthwise convolution without bias and SiLU
+    (``ops/short_conv.py::causal_conv``); ``beta = sigmoid(b)``, ``g =
+    -exp(A_log_h) * softplus(a + dt_bias_h)``, one float32 a value head and
+    token, made by XLA at ``[tokens, heads]`` and never wider; value head ``h``
+    reads key head ``h // (gdn_v_heads / gdn_k_heads)`` (HF repeats q and k;
+    nothing is repeated here); per head ``q = l2norm(q) / sqrt(d)``, ``k =
+    l2norm(k)``; the delta-rule scan with a state of ``d_k x d_v`` a value
+    head, in chunks of ``gdn_chunk_size``; ``RMSNorm_d(o) * norm_weight *
+    silu(z)`` head by head (the weight as it is, not ``1 + w``); ``out_proj``.
+    Everything between the convolution and ``out_proj`` is one call,
+    ``ops/gdn.py::gdn_fused`` (``KimiDeltaMixer`` says what the two share).
+    The columns of ``in_proj_qkvz`` and ``in_proj_ba`` are laid out kind by
+    kind (all of q, then k, v, z; all of b, then a): the HF checkpoint's
+    key-head-by-key-head order is ``Qwen3NextPolicy``'s to permute. Named
+    ``self_attn`` by its layer. Sows ``gdn_stats`` (only when mutable): the
+    largest ``|S|``, the mean decay ``exp(g)``, the mean ``beta`` and
+    ``fused_rows``."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.gdn import gdn_fused, grid_of, log_decay
+        from ..ops.short_conv import causal_conv
+        cfg = self.config
+        Hk, Hv, dk, dv = (cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_k_head_dim,
+                          cfg.gdn_v_head_dim)
+        if not (Hk and Hv) or Hv % Hk:
+            raise ValueError(f"the gdn operator needs gdn_k_heads and gdn_v_heads, the "
+                             f"second a multiple of the first: got {Hk}, {Hv}")
+        keys, values = Hk * dk, Hv * dv
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        # raw pallas_calls are not partitioned under GSPMD: as for flash, the
+        # kernels run where the mesh is one device
+        kernels = on_tpu() and all(n == 1 for n in _mesh_shape().values())
+        qkvz = _dense(2 * keys + 2 * values, "in_proj_qkvz", (EMBED, HEADS), cfg.dtype,
+                      keep=remat.MIXER_IN)(x)
+        ba = _dense(2 * Hv, "in_proj_ba", (EMBED, None), cfg.dtype)(x)
+        taps = self.param(
+            "conv_weight",
+            nn.with_partitioning(nn.initializers.lecun_normal(in_axis=0, out_axis=1),
+                                 (None, HEADS)),
+            (cfg.gdn_d_conv, 2 * keys + values), f32)
+        with jax.named_scope("ds.gdn.split"):
+            mixed, z = qkvz[..., :2 * keys + values], qkvz[..., 2 * keys + values:]
+        qkv = remat.keep(
+            causal_conv(mixed, taps, jnp.zeros((2 * keys + values, ), f32),
+                        use_kernel=kernels, interpret=interpret_kernels()),
+            remat.KERNEL_OUT)
+        with jax.named_scope("ds.gdn.split"):
+            q = qkv[..., :keys].reshape(b, s, Hk, dk)
+            k = qkv[..., keys:2 * keys].reshape(b, s, Hk, dk)
+            v = qkv[..., 2 * keys:].reshape(b, s, Hv, dv)
+        a_log = self.param("A_log", nn.with_partitioning(
+            lambda key, shape, dtype=f32: jnp.log(jax.random.uniform(
+                key, shape, dtype, 1.0, 16.0)), (HEADS, )), (Hv, ), f32)
+        dt_bias = self.param("dt_bias", nn.with_partitioning(_dt_bias_init, (HEADS, )),
+                             (Hv, ), f32)
+        norm_weight = self.param("norm_weight", nn.with_partitioning(
+            nn.initializers.ones, (None, )), (dv, ), f32)
+        with jax.named_scope("ds.gdn.gates"):
+            beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
+            g = log_decay(ba[..., Hv:], a_log, dt_bias)
+        want_stats = self.is_mutable_collection("gdn_stats")
+        fits = dk == dv and dk % 128 == 0 and Hv <= 128
+        use_kernel, interpret = kernels and fits, interpret_kernels() and fits
+        # every scope closes before the kernels' call: one that held it would
+        # rename the instruction (docs/observability.md)
+        y = gdn_fused(q, k, v, g, beta, z.reshape(b, s, Hv, dv), norm_weight,
+                      cfg.gdn_chunk_size, eps=cfg.rms_norm_eps, use_kernel=use_kernel,
+                      interpret=interpret, with_stats=want_stats,
+                      keep=remat.keeps(remat.GDN_SCAN))
+        if want_stats:
+            y, stats = y
+            self.sow("gdn_stats", "state_absmax", stats["state_absmax"],
+                     reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
+            stats["beta_mean"] = jax.lax.stop_gradient(jnp.mean(beta))
+            for name in ("decay_mean", "beta_mean", "fused_rows"):
+                self.sow("gdn_stats", name, stats[name],
+                         reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+            if use_kernel or interpret:
+                grid = grid_of(b, s, Hk, Hv, dk, cfg.gdn_chunk_size,
+                               jnp.dtype(v.dtype).itemsize)
+                for name, value in zip(("head_block", "grid_steps"), grid):
+                    self.sow("gdn_stats", name, jnp.asarray(value, f32),
+                             reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
+        return _dense(cfg.hidden_size, "out_proj", (HEADS, EMBED), cfg.dtype,
+                      keep=_keep_out(cfg, values))(y.reshape(b, s, values))
 
 
 class LlamaMLP(nn.Module):
@@ -1451,6 +1594,8 @@ class LlamaDecoderLayer(nn.Module):
                 h = x + branch(Mamba2Mixer(cfg, name="mamba")(normed))
             elif spec.operator == "kda":
                 h = x + branch(KimiDeltaMixer(cfg, name="self_attn")(normed))
+            elif spec.operator == "gdn":
+                h = x + branch(GatedDeltaNetMixer(cfg, name="self_attn")(normed))
             elif spec.operator in ("attention", "latent"):
                 op_cls = (LatentAttention if spec.operator == "latent"
                           else LlamaAttention)
@@ -1601,6 +1746,12 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
             from ..ops.selective_scan import scan_bytes
             return ((remat.SELSCAN_SCAN, scan_bytes(
                 x.shape[0], x.shape[1], cfg.mamba1_d_inner, cfg.mamba_d_state,
+                jnp.dtype(cfg.dtype).itemsize)), )
+        if spec is not None and spec.operator == "gdn":
+            from ..ops.gdn import scan_bytes
+            return ((remat.GDN_SCAN, scan_bytes(
+                x.shape[0], x.shape[1], cfg.gdn_v_heads, cfg.gdn_k_head_dim,
+                cfg.gdn_v_head_dim, cfg.gdn_chunk_size,
                 jnp.dtype(cfg.dtype).itemsize)), )
         if spec is None or spec.operator != "kda":
             return ()

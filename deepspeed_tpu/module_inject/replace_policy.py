@@ -266,6 +266,139 @@ class Qwen2MoePolicy(MixtralPolicy):
         return gate, experts
 
 
+class Qwen3NextPolicy(Qwen2MoePolicy):
+    """Qwen3-Next (HF ``modeling_qwen3_next.py``, ``model_type: qwen3_next``):
+    pre-norm layers, every RMSNorm zero-centred (``x / rms(x) * (1 + w)``) but
+    the linear layers' output norm, whose mixer is, by ``layer_types`` or
+    ``full_attention_interval`` (layer ``i`` is full attention where ``(i + 1)
+    % interval == 0``), Gated DeltaNet (``GatedDeltaNetMixer``:
+    ``linear_attn.in_proj_qkvz`` laid out key head by key head ``[q | k | v x
+    r | z x r]``, ``in_proj_ba`` ``[b x r | a x r]`` alike, one ``conv1d`` over
+    ``q | k | v``, ``A_log``, ``dt_bias``, ``norm``, ``out_proj``) or gated
+    softmax attention (``q_proj`` twice as wide, a head's query and its gate
+    side by side; per-head ``q_norm`` / ``k_norm``; rope on the first
+    ``partial_rotary_factor`` of a head's lanes; ``o_proj(attn *
+    sigmoid(gate))``); every FFN ``num_experts`` experts
+    ``moe_intermediate_size`` wide, top-k of a softmax, renormalised
+    (``norm_topk_prob``), beside a shared expert under a sigmoid gate of the
+    token. The model holds the fused projections' columns kind by kind (all of
+    q, then k, v, z; b, then a; the queries, then the gates): the special
+    conversions permute them. A chip's share of the experts is the
+    deployment's to set (``moe_experts_held``). Refused by name:
+    ``mlp_only_layers``, a ``decoder_sparse_step`` other than 1, biases,
+    ``rope_scaling``, another activation. The multi-token-prediction module is
+    not built (the config has no key of it)."""
+    arch = "qwen3_next"
+    supports_bias = False
+    row_parallel = ["o_proj", "down_proj", "out_proj"]
+    col_parallel = HFCheckpointPolicy.col_parallel + ["in_proj_qkvz", "in_proj_ba"]
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        hf = dict(hf_config)
+        refused = {"mlp_only_layers": bool(hf.get("mlp_only_layers")),
+                   "decoder_sparse_step": hf.get("decoder_sparse_step", 1) != 1,
+                   "attention_bias": bool(hf.get("attention_bias")),
+                   "rope_scaling": bool(hf.get("rope_scaling")),
+                   "hidden_act": hf.get("hidden_act", "silu") != "silu"}
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(f"qwen3_next: {key}={hf.get(key)!r} is not supported")
+        depth, every = hf["num_hidden_layers"], int(hf.get("full_attention_interval", 4))
+        types = hf.get("layer_types") or [
+            "full_attention" if (i + 1) % every == 0 else "linear_attention"
+            for i in range(depth)]
+        if len(types) != depth or set(types) - {"linear_attention", "full_attention"}:
+            raise ValueError(f"qwen3_next: layer_types {types} for {depth} layers")
+        width = hf["moe_intermediate_size"]
+        head = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
+        cfg = HFCheckpointPolicy.config_from_hf(
+            self, {**hf, "intermediate_size": width,
+                   "rms_norm_eps": hf.get("rms_norm_eps", 1e-6)})
+        self.bind(dataclasses.replace(
+            cfg, head_dim=head,
+            rotary_dim=int(head * float(hf.get("partial_rotary_factor", 0.25))),
+            qk_norm="head", norm_plus_one=True, attn_output_gate="elementwise",
+            layer_specs=tuple(
+                LayerSpec(operator="attention" if kind == "full_attention" else "gdn",
+                          ffn="moe", ffn_width=width) for kind in types),
+            num_local_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+            shared_expert_intermediate_size=hf.get("shared_expert_intermediate_size"),
+            shared_expert_gated=True,
+            gdn_k_heads=hf["linear_num_key_heads"],
+            gdn_v_heads=hf["linear_num_value_heads"],
+            gdn_k_head_dim=hf["linear_key_head_dim"],
+            gdn_v_head_dim=hf["linear_value_head_dim"],
+            gdn_d_conv=hf.get("linear_conv_kernel_dim", 4)))
+        return self._cfg
+
+    def bind(self, cfg: LlamaConfig):
+        """As ``Lfm2MoePolicy.bind``: the name maps depend on the layer's kind."""
+        self._cfg = cfg
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        out = {p + "input_layernorm.weight": (f + "operator_norm/weight", False),
+               p + "post_attention_layernorm.weight": (f + "ffn_norm/weight", False)}
+        if self._cfg.layer_specs[layer].operator == "gdn":
+            m, fm = p + "linear_attn.", f + "self_attn/"
+            out.update({m + "out_proj.weight": (fm + "out_proj/kernel", True),
+                        m + "A_log": (fm + "A_log", False),
+                        m + "dt_bias": (fm + "dt_bias", False),
+                        m + "norm.weight": (fm + "norm_weight", False)})
+        else:
+            for proj in ("k_proj", "v_proj", "o_proj"):
+                out[p + f"self_attn.{proj}.weight"] = (f + f"self_attn/{proj}/kernel", True)
+            for norm in ("q_norm", "k_norm"):
+                out[p + f"self_attn.{norm}.weight"] = (f + f"self_attn/{norm}/weight", False)
+        return out
+
+    def _special(self, layer: int):
+        """HF name -> (our path, the HF row each of our columns is, or None
+        for the convolution's taps)."""
+        cfg = self._cfg
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/self_attn/"
+        if cfg.layer_specs[layer].operator != "gdn":
+            nq, hd = cfg.num_attention_heads, cfg.head_dim_
+            rows = np.arange(nq * 2 * hd).reshape(nq, 2, hd)     # [head, query | gate, lane]
+            return {p + "self_attn.q_proj.weight":
+                    (f + "q_proj/kernel", rows.transpose(1, 0, 2).reshape(-1))}
+        Hk, r = cfg.gdn_k_heads, cfg.gdn_v_heads // cfg.gdn_k_heads
+        dk, dv = cfg.gdn_k_head_dim, cfg.gdn_v_head_dim
+        by_head = np.arange(Hk * (2 * dk + 2 * r * dv)).reshape(Hk, -1)
+        cuts = np.split(by_head, [dk, 2 * dk, 2 * dk + r * dv], axis=1)   # q, k, v, z
+        ba = np.arange(Hk * 2 * r).reshape(Hk, 2, r)
+        return {p + "linear_attn.in_proj_qkvz.weight":
+                (f + "in_proj_qkvz/kernel", np.concatenate([c.reshape(-1) for c in cuts])),
+                p + "linear_attn.in_proj_ba.weight":
+                (f + "in_proj_ba/kernel", ba.transpose(1, 0, 2).reshape(-1)),
+                p + "linear_attn.conv1d.weight": (f + "conv_weight", None)}
+
+    def special_hf_names(self, layer: int):
+        return list(self._special(layer))
+
+    def convert_special(self, layer: int, cfg: LlamaConfig, get_tensor, put):
+        """A fused projection ``[out, hidden]`` in HF's head-by-head order ->
+        our kernel ``[hidden, out]`` kind by kind; torch Conv1d's depthwise
+        weight ``[C, 1, L]`` -> taps ``[L, C]`` (q | k | v, as HF convolves
+        them)."""
+        for hf_name, (path, rows) in self._special(layer).items():
+            w = get_tensor(hf_name)
+            put(path, w[:, 0, :].T if rows is None else w[rows].T)
+
+    def export_special(self, layer: int, cfg: LlamaConfig, flat):
+        out = {}
+        for hf_name, (path, rows) in self._special(layer).items():
+            if rows is None:
+                out[hf_name] = flat[path].T[:, None, :]
+            else:
+                out[hf_name] = np.empty_like(flat[path].T)
+                out[hf_name][rows] = flat[path].T
+        return out
+
+
 class OlmoePolicy(MixtralPolicy):
     """OLMoE (HF ``modeling_olmoe.py``): PRE-norm llama layers whose
     attention RMS-normalizes the flat q/k projections (OLMo2's q_norm/k_norm
@@ -2074,6 +2207,8 @@ _POLICIES = {
     "qwen2_moe": Qwen2MoePolicy,
     "qwen2moe": Qwen2MoePolicy,
     "Qwen2MoeForCausalLM": Qwen2MoePolicy,
+    "qwen3_next": Qwen3NextPolicy,
+    "Qwen3NextForCausalLM": Qwen3NextPolicy,
     "gemma": GemmaPolicy,
     "GemmaForCausalLM": GemmaPolicy,
     "gemma2": Gemma2Policy,

@@ -22,6 +22,8 @@ that what makes them runs once a step:
                      layer at 32,768 tokens, 32 heads of 128 and chunks of
                      64); a layer without them runs ``kda_chunk_fwd`` once
                      more in its backward
+    ds.gdn.scan      a Gated DeltaNet scan's output and states, the same
+                     (``ops/gdn.py``: the value heads count)
     ds.selscan.scan  a Mamba-1 selective scan's output and the float32
                      states entering its blocks (``ops/selective_scan.py``:
                      0.21 GB a layer at 16,384 tokens and 5,120 channels of
@@ -82,13 +84,14 @@ SHARED = (SHARED_KV, SHARED_MEMORY)
 ROUTE = "ds.moe.route"
 MIXER_OUT = "ds.mixer.out"
 KDA_SCAN = "ds.kda.scan"
+GDN_SCAN = "ds.gdn.scan"
 SELSCAN_SCAN = "ds.selscan.scan"
 FFN_IN = "ds.ffn.in"
 MIXER_IN = "ds.mixer.in"
 MIXER_OUT_NARROW = "ds.mixer.out.narrow"
 KERNEL_OUT = "ds.mixer.kernel"
 # the walk's order: ms of recomputation returned a byte, falling
-CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, KDA_SCAN, SELSCAN_SCAN, FFN_IN,
+CANDIDATE_NAMES = (DSA_MASK, ROUTE, MIXER_OUT, KDA_SCAN, GDN_SCAN, SELSCAN_SCAN, FFN_IN,
                    MIXER_IN, MIXER_OUT_NARROW, KERNEL_OUT)
 KEPT_NAMES = RESIDUAL_NAMES + DSA_CHOICE + SHARED + CANDIDATE_NAMES
 

@@ -107,7 +107,8 @@ def _as_apply_fns(model):
                                     mutable=["aux_loss", "moe_stats", "ssm_stats",
                                              "mla_stats", "diffusion_stats",
                                              "dsa_stats", "kda_stats",
-                                             "selscan_stats", "diffattn_stats"])
+                                             "selscan_stats", "diffattn_stats",
+                                             "gdn_stats", "attn_stats"])
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
@@ -167,10 +168,17 @@ def _as_apply_fns(model):
             # "selscan_stats" for a Mamba-1 mixer, as "ssm_stats":
             # ``state_absmax`` the largest over the layers, ``dt_mean`` their
             # mean; "diffattn_stats" for differential attention: ``lambda_mean``
-            # comes back a layer (the differential layers in their order)
+            # comes back a layer (the differential layers in their order);
+            # "gdn_stats" for a Gated DeltaNet mixer, as "kda_stats";
+            # "attn_stats" for gated softmax attention: ``gate_mean``, the
+            # mean of ``sigmoid(gate)``, as the layers' mean
             for prefix, wanted in (
                     ("selscan", (("state_absmax", jnp.max), ("dt_mean", jnp.mean))),
-                    ("diffattn", (("lambda_mean", None), ))):
+                    ("diffattn", (("lambda_mean", None), )),
+                    ("gdn", (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
+                             ("beta_mean", jnp.mean), ("fused_rows", jnp.mean),
+                             ("head_block", jnp.max), ("grid_steps", jnp.max))),
+                    ("attn", (("gate_mean", jnp.mean), ))):
                 sown_all = jax.tree_util.tree_flatten_with_path(
                     mods.get(prefix + "_stats", {}))[0]
                 for name, reduce in wanted:
@@ -1807,6 +1815,24 @@ class DeepSpeedTpuEngine:
                 "products and gated output norm rode inside the chunk kernels in "
                 "the last step (0: XLA made them around the recurrence)"
             ).set(float(np.mean(fetched[-1]["kda_fused_rows"])))
+        if "gdn_state_absmax" in fetched[0]:
+            reg.gauge(
+                "ds_gdn_state_absmax",
+                "Largest |S| the Gated DeltaNet scans held (the chunks' states "
+                "where the kernels run), over the layers and the steps of the "
+                "last publish"
+            ).set(float(max(np.max(s["gdn_state_absmax"]) for s in fetched)))
+            reg.gauge(
+                "ds_gdn_decay_mean",
+                "Mean decay exp(g) a value head and token of the Gated DeltaNet "
+                "layers, over the steps of the last publish"
+            ).set(float(np.mean([np.mean(s["gdn_decay_mean"]) for s in fetched])))
+        if "attn_gate_mean" in fetched[0]:
+            reg.gauge(
+                "ds_attn_gate_mean",
+                "Mean of sigmoid(gate) over the gated softmax attention layers' "
+                "outputs, over the steps of the last publish"
+            ).set(float(np.mean([np.mean(s["attn_gate_mean"]) for s in fetched])))
         if "selscan_state_absmax" in fetched[0]:
             reg.gauge(
                 "ds_selscan_state_absmax",
@@ -2272,7 +2298,8 @@ class DeepSpeedTpuEngine:
         that sows none."""
         return self._newest_stats(
             lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_",
-                                              "kda_", "selscan_", "diffattn_")))
+                                              "kda_", "selscan_", "diffattn_", "gdn_",
+                                              "attn_")))
 
     def diffusion_stats(self):
         """What the block-diffusion objective sowed in the newest fused step
@@ -2308,6 +2335,23 @@ class DeepSpeedTpuEngine:
         without such a layer."""
         stats = self._newest_stats(lambda name: name.startswith("kda_"))
         return stats and {name[len("kda_"):]: v for name, v in stats.items()}
+
+    def gdn_stats(self):
+        """What the Gated DeltaNet layers sowed in the newest fused step not
+        yet published, as host scalars, named and reduced as
+        :meth:`kda_stats`' (``decay_mean`` here is over heads and tokens: the
+        decay is one a value head). ``None`` for a model without such a
+        layer."""
+        stats = self._newest_stats(lambda name: name.startswith("gdn_"))
+        return stats and {name[len("gdn_"):]: v for name, v in stats.items()}
+
+    def attn_stats(self):
+        """What the gated softmax attention layers sowed in the newest fused
+        step not yet published: ``gate_mean``, the mean of ``sigmoid(gate)``
+        over values, tokens and layers. ``None`` for a model without such a
+        layer."""
+        stats = self._newest_stats(lambda name: name.startswith("attn_"))
+        return stats and {name[len("attn_"):]: v for name, v in stats.items()}
 
     def selscan_stats(self):
         """What the Mamba-1 layers sowed in the newest fused step not yet

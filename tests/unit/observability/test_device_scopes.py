@@ -189,9 +189,11 @@ def test_named_scope_goes_through_one_helper_in_the_engine():
                     found[os.path.relpath(os.path.join(folder, f), root)] = hits
     assert found == {
         "runtime/engine.py": ['"ds.step." + region'],
-        "models/llama.py": ['"ds.rope"', '"ds.diffattn.combine"', '"ds.diffattn.combine"',
+        "models/llama.py": ['"ds.rope"', '"ds.attn.gate"', '"ds.diffattn.combine"',
+                            '"ds.diffattn.combine"',
                             '"ds.dsa.index"', '"ds.mla.assemble"', '"ds.rope"',
                             '"ds.mla.assemble"', '"ds.mla.gate"', '"ds.kda.gates"',
+                            '"ds.gdn.split"', '"ds.gdn.split"', '"ds.gdn.gates"',
                             '"ds.moe.route"', '"ds.moe.shared"',
                             '"ds.head.loss"', '"ds.head.loss"', '"ds.selscan.dt"',
                             '"ds.gmu.gate"'],
@@ -200,6 +202,11 @@ def test_named_scope_goes_through_one_helper_in_the_engine():
         # norms and mean decay around the recurrence where no kernel runs
         "ops/kda.py": ['"ds.kda.gates"', '"ds.kda.norm"', '"ds.kda.norm"',
                        '"ds.kda.gates"', '"ds.kda.gates"', '"ds.kda.gates"'],
+        # the same around the Gated DeltaNet kernels: the gates' sums of what
+        # the backward kernel returns, the mean decay, XLA's norms where no
+        # kernel runs, the lane layout of g and beta
+        "ops/gdn.py": ['"ds.gdn.gates"', '"ds.gdn.gates"', '"ds.gdn.norm"',
+                       '"ds.gdn.norm"', '"ds.gdn.gates"'],
         "ops/selective_scan.py": ['"ds.selscan.dt"'],
         "ops/grouped_matmul.py": ['"ds.moe.dispatch"', '"ds.moe.combine"',
                                   '"ds.moe.dispatch"', '"ds.moe.combine"',
