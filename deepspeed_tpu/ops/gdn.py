@@ -18,8 +18,10 @@ has more than one device. :func:`gdn_fused` is the mixer between its
 convolution and ``out_proj``: the recurrence at ``q = l2norm(q) / sqrt(d)``,
 ``k = l2norm(k)``, then ``RMSNorm_d(o) * weight * silu(z)`` head by head.
 
-The chunk algebra is KDA's, and its triangular solve (``_inverse``), its
-state passing, its unit rows and its matmul stages abreast are KDA's code.
+The chunk algebra is KDA's, and its triangular solve (``_inverse``: by
+blocks, the diagonal blocks of eight rows first, then the blocks below them a
+level at a time), its state passing, its unit rows and its matmul stages
+abreast are KDA's code.
 What differs, and why the kernels are these and not those:
 
 - **The gate is one float32 a head and token and is never spread over a

@@ -46,9 +46,13 @@ the block and at most ``e^{15 * 5}`` inside it (the gate's bound,
 ``GATE_FLOOR``, is what keeps that inside float32: a lower floor is refused),
 and the columns after the block, which the mask drops, are zeroed before the
 matmul. ``A`` is strictly
-lower triangular, so ``(I + A)^{-1} = (I - A)(I + A^2)(I + A^4)...`` exactly
-(``A^Q = 0``): ``2 log2(Q) - 2`` matmuls of ``Q x Q`` in float32
-(``contract_precision<fp32>``), no approximation and no ``[seq, seq]`` array.
+lower triangular and ``T`` is its exact inverse by blocks (``_inverse``): the
+diagonal blocks of eight rows as ``(I - A)(I + A^2)(I + A^4)`` (``A^8 = 0``
+in a block), all of them at once with the blocks on the lanes, then the
+blocks below the diagonal a level at a time (8 -> 16 -> 32 -> ``Q``), two
+products a level: ``T10 = -T1 (A10 T0)``. Every product is float32
+(``contract_precision<fp32>``): nine a chunk of 64 whose left rows sum to
+224, no approximation and no ``[seq, seq]`` array.
 
 A chunk of a BLOCK of heads is one grid step of ``kda_chunk_fwd`` (``block *
 d`` contiguous lanes of every operand; how many heads follows from the shape,
@@ -193,19 +197,74 @@ def _triangles(q, k, kb, c, mm):
 
 def _inverse(A):
     """A chain -> ``(I + A)^{-1}`` of a strictly lower triangular ``[Q, Q]``
-    float32 ``A``: ``(I - A)(I + A^2)(I + A^4)...`` up to ``A^{Q/2}``, exact because
-    ``A^Q = 0``; the factors are polynomials in ``A`` and commute."""
+    float32 ``A`` (``Q`` a multiple of ``SUB``), exactly, by blocks, because an
+    MXU product costs by its left operand's rows. The diagonal blocks of
+    ``SUBLANES`` = 8 rows first, as ``(I - A)(I + A^2)(I + A^4)`` (``A^8 = 0``
+    inside a block; the factors are polynomials in ``A`` and commute), all at
+    once with the blocks riding the LANES: ``[8, Q]`` against the
+    block-diagonal ``[Q, Q]``, a factor and the next square one product. Then
+    the blocks below the diagonal a level at a time, two neighbours into one
+    of twice the size, ``[[T0, 0], [-T1 A10 T0, T1]]``, every pair of a level
+    at once: two products of the lower blocks' rows. Nine products a chunk of
+    64, their left rows 32 + 3 x 64 (the same factors up to ``A^32`` on the
+    whole chunk are ten of 64 rows). The blocks are no larger than eight for
+    the rounding's sake: with keys nearly collinear and ``beta`` near 1
+    (entries of ``A`` near 1) a partial product ``sum_k (-A)^k`` holds entries
+    the size of a binomial coefficient that the last factor has to cancel, 35
+    in a block of 8 (4e-6 of ``|T|`` is lost), 6,435 in one of 16 (7e-4) and
+    everything in a chunk of 64; the levels above multiply inverses, whose
+    entries stay near 1."""
+    f32 = jnp.float32
     Q = A.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    T = jnp.where(row == col, 1.0, 0.0) - A
-    power, n = A, 2
-    while n < Q:
-        power = _dot(power, power, precision=_HIGHEST)
+
+    @functools.cache
+    def same(size):                        # a mask: in one diagonal block of ``size``
+        bits = size.bit_length() - 1
+        return (row >> bits) == (col >> bits)
+
+    def spread(S):                         # [8, Q], a block a lane group -> block diagonal
+        return jnp.where(same(SUBLANES),
+                         jnp.concatenate([S] * (Q // SUBLANES), axis=0), 0.0)
+
+    wide = jnp.where(same(SUBLANES), A, 0.0)
+    power = jnp.sum(wide.reshape(Q // SUBLANES, SUBLANES, Q), axis=0)
+    # (iotas of their own: Mosaic refuses a slice of ``row``)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, Q), 0)
+           == (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, Q), 1) & (SUBLANES - 1)))
+    T = jnp.where(eye, 1.0, 0.0) - power                                 # I - A
+    power = _dot(power, wide, precision=_HIGHEST)                        # A^2
+    yield
+    both = _dot(jnp.concatenate([T, power], axis=0), spread(power), precision=_HIGHEST)
+    yield
+    T, power = T + both[:SUBLANES], both[SUBLANES:]                      # (I + A^2), A^4
+    T = spread(T + _dot(T, spread(power), precision=_HIGHEST))           # (I + A^4)
+    yield
+    size = SUBLANES
+    while size < Q:
+        lower = range(size, Q, 2 * size)   # the first row of each pair's lower block
+        below = jnp.where(same(2 * size) & ~same(size), A, 0.0)          # A10 of each pair
+        zeros = jnp.zeros((size, Q), f32)
+
+        def rows(M):                       # the lower blocks' rows, stacked
+            return jnp.concatenate([M[lo:lo + size] for lo in lower], axis=0)
+
+        def placed(M):                     # ``rows``' rows where they came from, in zeros
+            parts, at = [], 0
+            for lo in range(0, Q, size):
+                n = min(size, Q - lo)
+                parts.append(M[at:at + n] if lo in lower else zeros[:n])
+                at += n if lo in lower else 0
+            return jnp.concatenate(parts, axis=0)
+
+        T1 = rows(T)
+        X = _dot(rows(below), T, precision=_HIGHEST)                     # A10 T0
         yield
-        T = T + _dot(T, power, precision=_HIGHEST)
+        X = _dot(T1, placed(X), precision=_HIGHEST)                      # T1 (A10 T0)
         yield
-        n *= 2
+        T = T - placed(X)
+        size *= 2
     return T
 
 

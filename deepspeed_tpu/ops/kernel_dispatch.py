@@ -416,7 +416,8 @@ def kda_vmem_bytes(block: int, d: int, chunk: int, itemsize: int,
     ``beta`` and its gradient (a head a lane, 128 lanes of float32), the
     float32 state's blocks and scratch, and some four and a half dozen
     ``[chunk, d]`` float32 temporaries a head (since PR 53 the unit rows, the
-    norms and the gated output norm's are among them)."""
+    norms and the gated output norm's are among them; the triangular solve's
+    are half a dozen ``[chunk, chunk]`` tiles)."""
     width = block * d
     return (2 * arrays * chunk * width * max(itemsize, 4) + 2 * 2 * chunk * 128 * 4
             + 6 * 4 * d * width + 56 * 4 * chunk * width)

@@ -9,7 +9,9 @@ its bound ``g = -5`` over a whole chunk; ``beta = 0`` and ``beta = 1`` rows;
 rows of zeros in q and k (the norm's epsilon); chunks of 16 and 64; one, two
 and four heads a grid step (``kernel_dispatch.choose_kda_heads``) and a block
 of heads against one head a step bit for bit; the largest ``|S|`` at the
-chunks' ends, the mean decay and ``fused_rows``; what the call refuses."""
+chunks' ends, the mean decay and ``fused_rows``; what the call refuses. And
+the triangular solve alone (``kda._inverse``, which ``ops/gdn.py`` runs too)
+against ``solve_triangular`` in float64, with what its products cost."""
 
 import jax
 import jax.numpy as jnp
@@ -216,3 +218,80 @@ def test_off_the_kernels_the_call_is_the_recurrence_and_bad_shapes_are_refused()
     with pytest.raises(ValueError, match="want q, k, pre, gate"):
         kda.kda_fused(*args[:8], args[8][:64], 16, eps=EPS, use_kernel=False)
     assert kda.scan_bytes(1, 100, 2, 128, 128, 64, 2) == 2 * (128 * 128 * 2 + 2 * 128 * 128 * 4)
+
+
+def _to_its_end(chain):
+    """What a chain (``kda._abreast``) returns, run alone."""
+    return kda._abreast([chain])[0]
+
+
+def _strictly_lower(kind, Q, seed=0):
+    """A chunk's ``A = strictly_lower(beta k k^T o decay)`` of three kinds."""
+    rng = np.random.default_rng(seed)
+    unit = lambda k: k / np.linalg.norm(k, axis=-1, keepdims=True)   # noqa: E731
+    if kind == "small":
+        A = 0.1 * rng.normal(size=(Q, Q))
+    elif kind == "collinear":
+        # one direction and a twentieth of noise a key, beta within a
+        # hundredth of 1, no decay: entries near 1
+        k = unit(rng.normal(size=(1, D)) + 0.05 * rng.normal(size=(Q, D)))
+        A = ((1.0 - 0.01 * rng.uniform(size=(Q, 1))) * k) @ k.T
+    else:
+        # every seventh token forgets everything before it
+        k = unit(rng.normal(size=(Q, D)))
+        g = -rng.uniform(0.0, 5.0, size=Q)
+        g[Q // 3::7] = -200.0
+        c = np.cumsum(g)
+        A = (rng.uniform(size=(Q, 1)) * k) @ k.T * np.exp(
+            np.minimum(c[:, None] - c[None, :], 0.0))
+    return np.tril(A, -1).astype(np.float32)
+
+
+@pytest.mark.parametrize("Q", [16, 32, 64, 128])
+@pytest.mark.parametrize("kind", ["small", "collinear", "decayed"])
+def test_the_solve_is_the_triangular_inverse_to_float32(kind, Q):
+    """``(I + A)^{-1}`` by blocks against ``solve_triangular`` in float64,
+    within 1e-5 of ``|T|``'s largest entry, and ``T (I + A) = I`` to the same:
+    for small entries, for entries near 1 (where the doubling product on
+    blocks of 16 reads 3e-4 to 8e-4 and on the whole chunk overflows: why the
+    diagonal blocks are eight rows) and for rows that a decay sent to 0."""
+    A = _strictly_lower(kind, Q)
+    if kind == "collinear":
+        assert np.abs(A[np.tril_indices(Q, -1)]).min() > 0.95
+    if kind == "decayed":
+        assert (A[1:] == 0.0).all(axis=1).any()
+    eye = np.eye(Q)
+    with jax.enable_x64(True):
+        want = np.asarray(jax.scipy.linalg.solve_triangular(
+            jnp.asarray(eye + A, jnp.float64), jnp.asarray(eye), lower=True))
+    assert want.dtype == np.float64
+    T = _to_its_end(kda._inverse(jnp.asarray(A)))
+    assert T.dtype == jnp.float32 and T.shape == (Q, Q)
+    T, scale = np.asarray(T, np.float64), np.abs(want).max()
+    assert np.abs(T - want).max() <= 1e-5 * scale
+    assert np.abs(T @ (eye + A) - eye).max() <= 1e-5 * scale
+    assert (np.triu(T, 1) == 0.0).all() and (np.diag(T) == 1.0).all()
+
+
+def test_the_solves_products_are_float32_and_their_left_rows_a_third_of_the_chains():
+    """What the blocked solve is for, from its jaxpr at a chunk of 64: an MXU
+    product costs by its left operand's rows, and every product of the solve
+    is ``contract_precision<fp32>`` (six passes). The chain ``(I - A)(I +
+    A^2)...(I + A^32)`` on the whole chunk was ten products of 64 rows, 640;
+    by blocks it is nine whose rows sum to 224, a stage (a ``yield``) each."""
+    A = jnp.asarray(_strictly_lower("small", 64))
+
+    def products(jaxpr):                # at any depth: ``jnp.where`` is a jit of its own
+        for e in jaxpr.eqns:
+            if e.primitive.name == "dot_general":
+                yield e
+            for inner in jax.core.jaxprs_in_params(e.params):
+                yield from products(inner)
+
+    dots = list(products(jax.make_jaxpr(lambda: _to_its_end(kda._inverse(A)))().jaxpr))
+    for e in dots:
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST, ) * 2
+        assert {v.aval.dtype for v in (*e.invars, *e.outvars)} == {jnp.dtype("float32")}
+    rows = [e.invars[0].aval.shape[0] for e in dots]
+    assert len(dots) <= 9 and sum(rows) <= 224 and max(rows) <= 32, rows
+    assert sum(1 for _ in kda._inverse(A)) == len(dots)
