@@ -13,7 +13,6 @@ designed TPU-first:
 """
 
 import dataclasses
-import functools
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -22,6 +21,7 @@ import numpy as np
 import jax.numpy as jnp
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning
+from . import sown
 from ..ops import remat
 from ..ops.registry import interpret_kernels, on_tpu
 
@@ -760,10 +760,8 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("ds.attn.gate"):
                 open_ = jax.nn.sigmoid(gate.astype(jnp.float32))
                 out = (out.astype(jnp.float32) * open_).astype(cfg.dtype)
-            if self.is_mutable_collection("attn_stats"):
-                self.sow("attn_stats", "gate_mean", jax.lax.stop_gradient(jnp.mean(open_)),
-                         reduce_fn=lambda a, b: a + b,
-                         init_fn=lambda: jnp.zeros((), jnp.float32))
+            if sown.wanted(self, "attn"):
+                sown.sow(self, "attn", {"gate_mean": jax.lax.stop_gradient(jnp.mean(open_))})
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       cfg.attention_out_bias, _keep_out(cfg, nq * hd))(out)
 
@@ -841,9 +839,8 @@ class LlamaAttention(nn.Module):
             o = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * subln.astype(f32)
                  * (1.0 - l_init))
             o = o.astype(cfg.dtype).reshape(b, s, nq * hd)
-        if self.is_mutable_collection("diffattn_stats"):
-            self.sow("diffattn_stats", "lambda_mean", jax.lax.stop_gradient(lam_full),
-                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+        if sown.wanted(self, "diffattn"):
+            sown.sow(self, "diffattn", {"lambda_mean": jax.lax.stop_gradient(lam_full)})
         out = _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                      cfg.attention_out_bias, _keep_out(cfg, nq * hd))(o)
         return (out, (k, v)) if hand_on else out
@@ -892,15 +889,13 @@ class LlamaAttention(nn.Module):
             q, k, v, qi, ki, w, cfg.dsa_topk, scale=cfg.attn_scale,
             force_pallas=use_kernel, interpret=use_kernel and interpret_kernels(),
             keep_mask=keep_mask)
-        if self.is_mutable_collection("dsa_stats"):
-            for name, value in (
-                    ("chosen_pairs", chosen.sum(dtype=jnp.int32)),
-                    ("causal_pairs", jnp.float32(b * s * (s + 1) / 2)),
-                    ("kth_score_mean", jax.lax.stop_gradient(kth).mean()),
-                    # the dense form has no mask to keep
-                    ("masks_kept", jnp.int32(bool(use_kernel) and keep_mask))):
-                self.sow("dsa_stats", name, value, reduce_fn=lambda a, b: a + b,
-                         init_fn=functools.partial(jnp.zeros, (), value.dtype))
+        if sown.wanted(self, "dsa"):
+            sown.sow(self, "dsa", {
+                "chosen_pairs": chosen.sum(dtype=jnp.int32),
+                "causal_pairs": jnp.float32(b * s * (s + 1) / 2),
+                "kth_score_mean": jax.lax.stop_gradient(kth).mean(),
+                # the dense form has no mask to keep
+                "masks_kept": jnp.int32(bool(use_kernel) and keep_mask)})
         if self.is_mutable_collection("dsa_choice"):
             # what ``ops.dsa_attention.chosen_keys`` rebuilds the choice of any
             # query from (a check's, not a step's)
@@ -989,13 +984,11 @@ class LatentAttention(nn.Module):
             q = q.reshape(b, s, nh, d_nope + d_rope)
             q_nope, q_rope = q[..., :d_nope], q[..., d_nope:]
             c, k_r = kva[..., :rank], kva[..., rank:].reshape(b, s, 1, d_rope)
-        if self.is_mutable_collection("mla_stats"):
+        if sown.wanted(self, "mla"):
             def rms(a):
                 a = jax.lax.stop_gradient(a).astype(jnp.float32)
                 return jnp.sqrt(jnp.mean(a * a))
-            for name, value in (("latent_rms", rms(c)), ("k_rope_rms", rms(k_r))):
-                self.sow("mla_stats", name, value, reduce_fn=lambda a, b: a + b,
-                         init_fn=lambda: jnp.float32(0.0))
+            sown.sow(self, "mla", {"latent_rms": rms(c), "k_rope_rms": rms(k_r)})
         c = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="kv_a_layernorm")(c)
         kvb = _dense(nh * (d_nope + d_v), "kv_b_proj", (None, HEADS), cfg.dtype,
                      keep=remat.MIXER_IN)(c)
@@ -1126,16 +1119,14 @@ class Mamba2Mixer(nn.Module):
             jnp.arange(1, shape[0] + 1, dtype=dtype)))
         d_skip = per_head("D", nn.initializers.ones)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
-        want_stats = self.is_mutable_collection("ssm_stats")
+        want_stats = sown.wanted(self, "ssm")
         y = ssd_scan(x.reshape(b, s, H, P), dt, -jnp.exp(a_log), B, C, d_skip,
                      cfg.mamba_chunk_size, use_kernel=kernels,
                      interpret=interpret_kernels(), with_state_absmax=want_stats)
         if want_stats:
             y, top = y
-            self.sow("ssm_stats", "state_absmax", top, reduce_fn=jnp.maximum,
-                     init_fn=lambda: jnp.zeros((), f32))
-            self.sow("ssm_stats", "dt_mean", jax.lax.stop_gradient(jnp.mean(dt)),
-                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+            sown.sow(self, "ssm", {"state_absmax": top,
+                                   "dt_mean": jax.lax.stop_gradient(jnp.mean(dt))})
         gated = y.reshape(b, s, inner).astype(f32) * jax.nn.silu(z.astype(f32))
         weight = self.param("norm_weight",
                             nn.with_partitioning(nn.initializers.ones, (HIDDEN, )),
@@ -1225,7 +1216,7 @@ class KimiDeltaMixer(nn.Module):
         with jax.named_scope("ds.kda.gates"):
             rate = jnp.exp(a_log)
             beta = jax.nn.sigmoid(beta_in.astype(f32))
-        want_stats = self.is_mutable_collection("kda_stats")
+        want_stats = sown.wanted(self, "kda")
         use_kernel, interpret = kernels and d % 128 == 0, interpret_kernels() and d % 128 == 0
         # where the kernels run they make everything between the convolutions
         # and o_proj on the tiles they hold: the norms, beta k and beta v, the
@@ -1241,19 +1232,13 @@ class KimiDeltaMixer(nn.Module):
                       with_stats=want_stats, keep=remat.keeps(remat.KDA_SCAN))
         if want_stats:
             y, stats = y
-            self.sow("kda_stats", "state_absmax", stats["state_absmax"],
-                     reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
             stats["beta_mean"] = jax.lax.stop_gradient(jnp.mean(beta))
-            for name in ("decay_mean", "beta_mean", "fused_rows"):
-                self.sow("kda_stats", name, stats[name],
-                         reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
             if use_kernel or interpret:
                 # how the kernels' grid was laid over this call: the heads a
                 # grid step took and the steps a call (kernel_dispatch.choose_kda_heads)
                 grid = grid_of(b, s, H, d, cfg.kda_chunk_size, jnp.dtype(v.dtype).itemsize)
-                for name, value in zip(("head_block", "grid_steps"), grid):
-                    self.sow("kda_stats", name, jnp.asarray(value, f32),
-                             reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
+                stats["head_block"], stats["grid_steps"] = (jnp.asarray(n, f32) for n in grid)
+            sown.sow(self, "kda", stats)
         y = y.reshape(b, s, inner)
         return _dense(cfg.hidden_size, "o_proj", (HEADS, EMBED), cfg.dtype,
                       keep=_keep_out(cfg, inner))(y)
@@ -1328,7 +1313,7 @@ class GatedDeltaNetMixer(nn.Module):
         with jax.named_scope("ds.gdn.gates"):
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
             g = log_decay(ba[..., Hv:], a_log, dt_bias)
-        want_stats = self.is_mutable_collection("gdn_stats")
+        want_stats = sown.wanted(self, "gdn")
         fits = dk == dv and dk % 128 == 0 and Hv <= 128
         use_kernel, interpret = kernels and fits, interpret_kernels() and fits
         # every scope closes before the kernels' call: one that held it would
@@ -1339,18 +1324,12 @@ class GatedDeltaNetMixer(nn.Module):
                       keep=remat.keeps(remat.GDN_SCAN))
         if want_stats:
             y, stats = y
-            self.sow("gdn_stats", "state_absmax", stats["state_absmax"],
-                     reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
             stats["beta_mean"] = jax.lax.stop_gradient(jnp.mean(beta))
-            for name in ("decay_mean", "beta_mean", "fused_rows"):
-                self.sow("gdn_stats", name, stats[name],
-                         reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
             if use_kernel or interpret:
                 grid = grid_of(b, s, Hk, Hv, dk, cfg.gdn_chunk_size,
                                jnp.dtype(v.dtype).itemsize)
-                for name, value in zip(("head_block", "grid_steps"), grid):
-                    self.sow("gdn_stats", name, jnp.asarray(value, f32),
-                             reduce_fn=jnp.maximum, init_fn=lambda: jnp.zeros((), f32))
+                stats["head_block"], stats["grid_steps"] = (jnp.asarray(n, f32) for n in grid)
+            sown.sow(self, "gdn", stats)
         return _dense(cfg.hidden_size, "out_proj", (HEADS, EMBED), cfg.dtype,
                       keep=_keep_out(cfg, values))(y.reshape(b, s, values))
 
@@ -1469,10 +1448,8 @@ class LlamaMoEBlock(nn.Module):
         score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
         _, kept = jax.lax.top_k(score, best)
         is_kept = jnp.sum(jax.nn.one_hot(kept, groups, dtype=jnp.int32), axis=-2) > 0
-        self.sow("moe_stats", "group_counts",
-                 jnp.sum(is_kept.reshape(-1, groups), axis=0, dtype=jnp.int32),
-                 reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((groups, ), jnp.int32))
+        sown.sow(self, "moe", {"group_counts": jnp.sum(is_kept.reshape(-1, groups), axis=0,
+                                                       dtype=jnp.int32)})
         return jnp.where(is_kept[..., None], grouped, -jnp.inf).reshape(biased.shape)
 
     @nn.compact
@@ -1495,14 +1472,9 @@ class LlamaMoEBlock(nn.Module):
         with jax.named_scope("ds.moe.route"):
             probs, w, idx = self._route(logits)
             # (token, choice) assignments per expert: what the engine's fused
-            # step returns beside the loss ("moe_stats", read only when mutable)
+            # step returns beside the loss (the "moe" family, read only when mutable)
             counts = expert_counts(idx, E)
-
-        def sow_stat(name, value):
-            self.sow("moe_stats", name, value, reduce_fn=lambda a, b: a + b,
-                     init_fn=lambda: jnp.zeros_like(value))
-
-        sow_stat("expert_counts", counts)
+        sown.sow(self, "moe", {"expert_counts": counts})
         if cfg.router_aux_loss_coef > 0:
             # Switch/Mixtral load balance: E * sum_e(frac_routed_e * mean_prob_e)
             pe = probs.reshape(-1, E).mean(axis=0)
@@ -1538,8 +1510,7 @@ class LlamaMoEBlock(nn.Module):
             out, rows_held, fell_back = moe_grouped_mlp_share(
                 xt, w1, w3, w2, idx, w, first_expert=first, num_experts=E,
                 crowding=cfg.moe_n_group // cfg.moe_topk_group if inside else 1)
-            sow_stat("rows_held", rows_held)
-            sow_stat("share_fallback", fell_back)
+            sown.sow(self, "moe", {"rows_held": rows_held, "share_fallback": fell_back})
         else:
             fn = moe_grouped_mlp if cfg.moe_grouped else moe_dense_mlp
             out = fn(xt, w1, w3, w2, idx, w)
@@ -1874,9 +1845,8 @@ class LlamaModel(nn.Module):
             # sums all leaves, so stacking ≡ the unscanned reduce_fn sum)
             ScanLayer = nn.scan(_ScanBody,
                                 variable_axes={"params": 0, "aux_loss": 0,
-                                               "moe_stats": 0, "ssm_stats": 0,
-                                               "mla_stats": 0, "dsa_stats": 0,
-                                               "kda_stats": 0},
+                                               **{family.collection: 0
+                                                  for family in sown.FAMILIES.values()}},
                                 split_rngs={"params": True},
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,
@@ -1960,6 +1930,9 @@ class LlamaForCausalLM(nn.Module):
     ``sum(w * CE(logits_i, x0_i)) / (rows * L)``, unshifted; without labels
     the noisy half's logits [rows, L, V] come back."""
     config: LlamaConfig
+    # what its operators sow for the host: all the training engine knows of
+    # them (models/sown.py)
+    sown_families = tuple(sown.FAMILIES.values())
 
     @nn.compact
     def __call__(self, input_ids, labels=None, positions=None, attn_mask=None,
@@ -1970,14 +1943,11 @@ class LlamaForCausalLM(nn.Module):
                 raise ValueError("the block-diffusion loss needs loss_weights "
                                  "(data_pipeline.block_diffusion makes them)")
             masked = loss_weights > 0
-            for name, value in (
-                    ("tokens", jnp.float32(labels.size)),
-                    ("masked_tokens", masked.sum().astype(jnp.float32)),
-                    ("t_sum", jnp.where(masked, 1.0 / jnp.where(masked, loss_weights, 1.0),
-                                        0.0).sum().astype(jnp.float32))):
-                self.sow("diffusion_stats", name, value,
-                         reduce_fn=lambda a, b: a + b,
-                         init_fn=lambda: jnp.float32(0.0))
+            sown.sow(self, "diffusion", {
+                "tokens": jnp.float32(labels.size),
+                "masked_tokens": masked.sum().astype(jnp.float32),
+                "t_sum": jnp.where(masked, 1.0 / jnp.where(masked, loss_weights, 1.0),
+                                   0.0).sum().astype(jnp.float32)})
         elif loss_weights is not None:
             raise ValueError("loss_weights belong to the block-diffusion objective")
         if labels is not None and cfg.ce_chunk_size:
@@ -2111,16 +2081,14 @@ class SelectiveScanMixer(nn.Module):
             delta, B, C = jnp.split(dbc, [R, R + N], axis=-1)
             dt = _StepSize(E, cfg.dtype, name="dt_proj")(delta)
             rates = -jnp.exp(a_log.astype(f32))
-        want_stats = self.is_mutable_collection("selscan_stats")
+        want_stats = sown.wanted(self, "selscan")
         y = selective_scan(x, dt, rates, B, C, d_skip, use_kernel=kernels,
                            interpret=interpret_kernels(), with_state_absmax=want_stats,
                            keep=remat.keeps(remat.SELSCAN_SCAN))
         if want_stats:
             y, top = y
-            self.sow("selscan_stats", "state_absmax", top, reduce_fn=jnp.maximum,
-                     init_fn=lambda: jnp.zeros((), f32))
-            self.sow("selscan_stats", "dt_mean", jax.lax.stop_gradient(jnp.mean(dt)),
-                     reduce_fn=lambda a, b: a + b, init_fn=lambda: jnp.zeros((), f32))
+            sown.sow(self, "selscan", {"state_absmax": top,
+                                       "dt_mean": jax.lax.stop_gradient(jnp.mean(dt))})
         if hand_on:     # the layers after read it: kept once, whatever the plan
             y = remat.handed_on(y, remat.SHARED_MEMORY)
         gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)

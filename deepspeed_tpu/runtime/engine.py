@@ -26,7 +26,7 @@ ZeRO stages are *sharding plans* (see ``zero_sharding.py``), not subclasses.
 import os
 import time
 from contextlib import nullcontext
-from functools import partial
+from functools import partial, partialmethod
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -78,115 +78,33 @@ def _as_apply_fns(model):
     model sowed for the host (``{}`` when nothing), and is ``None`` for a
     raw callable."""
     if _HAS_FLAX and isinstance(model, nn.Module):
+        # the families of statistics the model's operators declare: their
+        # collections, reductions over the layers and names (docs/TRAINING.md,
+        # "What an operator sows for the host"); none on a module that has none
+        families = tuple(getattr(model, "sown_families", ()))
+        mutable = ["aux_loss", *(family.collection for family in families)]
 
         def apply_with_stats(params, *args, **kwargs):
             # "aux_loss" is the contract for modules that sow auxiliary
             # training losses (MoE router load-balancing — reference
             # sharded_moe.py l_aux): sown scalars are ADDED to a scalar
             # model loss; logits outputs pass through untouched.
-            # "moe_stats" is the contract for routing counters: sown
-            # ``expert_counts`` ([..., E] per MoE block, E the router's
-            # width, a leading axis under a layer scan) come back summed
-            # over blocks, with the aux term and, from blocks that hold a
-            # share of the experts, ``rows_held`` and ``share_fallback``
-            # "ssm_stats" is the same contract for a state-space mixer:
-            # ``state_absmax`` (the largest |S| a layer's scan held) comes
-            # back as the largest over the layers, ``dt_mean`` as their mean
-            # "mla_stats" for latent attention: ``latent_rms`` and
-            # ``k_rope_rms`` (the rms of the latent before its norm and of
-            # the shared rope key) come back as the layers' means
-            # "diffusion_stats" for the block-diffusion objective: the
-            # batch's data ``tokens``, its ``masked_tokens`` and the sum of
-            # their ``t``
-            # "dsa_stats" for learned sparse attention: ``chosen_pairs``
-            # (int32) and ``causal_pairs`` come back a layer (their sum over
-            # a deep model passes 32 bits: the host adds them up),
-            # ``kth_score_mean`` as the layers' mean and ``masks_kept`` as
-            # their sum
-            out, mods = model.apply({"params": params}, *args, **kwargs,
-                                    mutable=["aux_loss", "moe_stats", "ssm_stats",
-                                             "mla_stats", "diffusion_stats",
-                                             "dsa_stats", "kda_stats",
-                                             "selscan_stats", "diffattn_stats",
-                                             "gdn_stats", "attn_stats"])
+            out, mods = model.apply({"params": params}, *args, **kwargs, mutable=mutable)
             aux = jax.tree_util.tree_leaves(mods.get("aux_loss", {}))
             aux_total = sum(jnp.sum(a) for a in aux) if aux else None
             if aux and hasattr(out, "ndim") and out.ndim == 0:
                 out = out + aux_total
             stats = {}
-            sown = jax.tree_util.tree_flatten_with_path(
-                mods.get("moe_stats", {}))[0]
-            for path, leaf in sown:
-                # by the name it was sown under, summed over the blocks: a
-                # vector over the router's width (``expert_counts``) keeps
-                # its last axis, a scalar a block (``rows_held``,
-                # ``share_fallback``: a block that holds a share) is summed
-                name = next(k.key for k in reversed(path) if hasattr(k, "key"))
-                total = (jnp.sum(leaf) if name not in ("expert_counts", "group_counts")
-                         else leaf.reshape(-1, leaf.shape[-1]).sum(axis=0))
-                stats[name] = stats[name] + total if name in stats else total
-            if stats and aux:
-                stats["aux_loss"] = aux_total.astype(jnp.float32)
-            ssm = jax.tree_util.tree_flatten_with_path(mods.get("ssm_stats", {}))[0]
-            for name, reduce in (("state_absmax", jnp.max), ("dt_mean", jnp.mean)):
-                sown = [leaf.reshape(-1) for path, leaf in ssm
-                        if path[-1].key == name]
-                if sown:
-                    stats["ssm_" + name] = reduce(jnp.concatenate(sown))
-            mla = jax.tree_util.tree_flatten_with_path(mods.get("mla_stats", {}))[0]
-            for name in ("latent_rms", "k_rope_rms"):
-                sown = [leaf.reshape(-1) for path, leaf in mla
-                        if path[-1].key == name]
-                if sown:
-                    stats["mla_" + name] = jnp.mean(jnp.concatenate(sown))
-            for path, leaf in jax.tree_util.tree_flatten_with_path(
-                    mods.get("diffusion_stats", {}))[0]:
-                stats["diffusion_" + path[-1].key] = jnp.sum(leaf)
-            dsa = jax.tree_util.tree_flatten_with_path(mods.get("dsa_stats", {}))[0]
-            for name, reduce in (("chosen_pairs", None), ("causal_pairs", None),
-                                 ("kth_score_mean", jnp.mean), ("masks_kept", jnp.sum)):
-                sown = [leaf.reshape(-1) for path, leaf in dsa
-                        if path[-1].key == name]
-                if sown:
-                    sown = jnp.concatenate(sown)
-                    stats["dsa_" + name] = reduce(sown) if reduce else sown
-            # "kda_stats" for a Kimi Delta Attention mixer: ``state_absmax``
-            # comes back as the largest over the layers, ``decay_mean``,
-            # ``beta_mean`` and ``fused_rows`` (1.0 from a layer whose norms
-            # and beta products rode inside the kernels, 0.0 from one XLA made
-            # them for) as the layers' means; where the chunk kernels ran,
-            # ``head_block`` and ``grid_steps`` (the heads a grid step took,
-            # the steps a call: one value, the layers' calls are alike)
-            kda = jax.tree_util.tree_flatten_with_path(mods.get("kda_stats", {}))[0]
-            for name, reduce in (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
-                                 ("beta_mean", jnp.mean), ("fused_rows", jnp.mean),
-                                 ("head_block", jnp.max), ("grid_steps", jnp.max)):
-                sown = [leaf.reshape(-1) for path, leaf in kda
-                        if path[-1].key == name]
-                if sown:
-                    stats["kda_" + name] = reduce(jnp.concatenate(sown))
-            # "selscan_stats" for a Mamba-1 mixer, as "ssm_stats":
-            # ``state_absmax`` the largest over the layers, ``dt_mean`` their
-            # mean; "diffattn_stats" for differential attention: ``lambda_mean``
-            # comes back a layer (the differential layers in their order);
-            # "gdn_stats" for a Gated DeltaNet mixer, as "kda_stats";
-            # "attn_stats" for gated softmax attention: ``gate_mean``, the
-            # mean of ``sigmoid(gate)``, as the layers' mean
-            for prefix, wanted in (
-                    ("selscan", (("state_absmax", jnp.max), ("dt_mean", jnp.mean))),
-                    ("diffattn", (("lambda_mean", None), )),
-                    ("gdn", (("state_absmax", jnp.max), ("decay_mean", jnp.mean),
-                             ("beta_mean", jnp.mean), ("fused_rows", jnp.mean),
-                             ("head_block", jnp.max), ("grid_steps", jnp.max))),
-                    ("attn", (("gate_mean", jnp.mean), ))):
-                sown_all = jax.tree_util.tree_flatten_with_path(
-                    mods.get(prefix + "_stats", {}))[0]
-                for name, reduce in wanted:
-                    sown = [leaf.reshape(-1) for path, leaf in sown_all
-                            if path[-1].key == name]
-                    if sown:
-                        sown = jnp.concatenate(sown)
-                        stats[f"{prefix}_{name}"] = reduce(sown) if reduce else sown
+            for family in families:
+                sown = {}   # by the name it was sown under: every layer's leaf
+                for path, leaf in jax.tree_util.tree_flatten_with_path(
+                        mods.get(family.collection, {}))[0]:
+                    sown.setdefault(path[-1].key, []).append(leaf)
+                for name, over_layers in family.stats.items():
+                    if name in sown:
+                        stats[family.key(name)] = over_layers.across(sown[name])
+                if family.aux_loss and sown and aux:
+                    stats[family.key("aux_loss")] = aux_total.astype(jnp.float32)
             return out, stats
 
         def apply_fn(params, *args, **kwargs):
@@ -431,6 +349,7 @@ class DeepSpeedTpuEngine:
         from ..ops import remat
         remat.forget_plans()
         self.apply_fn, self._apply_with_stats = _as_apply_fns(model)
+        self._sown_families = tuple(getattr(model, "sown_families", ()))
         ac = self._config.activation_checkpointing_config
         if ac.remat_policy:
             policy = getattr(jax.checkpoint_policies, ac.remat_policy, None)
@@ -517,7 +436,8 @@ class DeepSpeedTpuEngine:
         apc = self._config.async_pipeline_config
         self._async_window = (_AsyncStepWindow(apc.sync_interval)
                               if apc.enabled else None)
-        self._moe_pending = []   # device stats of fused MoE steps not yet published
+        self._sown_pending = []  # device stats of fused steps not yet published
+        self._kernel_line_logged = False
         # the process's grouped-matmul trace counts before this engine's
         # programs: its one `kernels:` line reports what came after
         from ..ops.grouped_matmul import traced_counts
@@ -1774,181 +1694,45 @@ class DeepSpeedTpuEngine:
                     name, f"Decoder layers that read {what} (passed beside the "
                     "residual stream)").set(float(n))
 
-    def _publish_moe_stats(self):
-        """Routing counters of the fused MoE steps dispatched since the last
-        call, in one fetch. Called before the newest step's stats are held,
+    def _publish_sown_stats(self):
+        """What the model's operators sowed in the fused steps dispatched
+        since the last call, in one fetch, as the gauges and counters their
+        families declare. Called before the newest step's stats are held,
         so what it reads has been computed (the step before was read, or
         the window drained) and the device never waits for it: the gauges
         lag the step counter by one dispatch."""
-        if not self._moe_pending:
+        if not self._sown_pending:
             return
         from ..observability import get_registry
-        fetched, self._moe_pending = host_fetch(self._moe_pending), []
+        fetched, self._sown_pending = host_fetch(self._sown_pending), []
         reg = get_registry()
-        if "ssm_state_absmax" in fetched[0]:
-            reg.gauge(
-                "ds_ssm_state_absmax",
-                "Largest |S| the state-space scans held (the chunks' states "
-                "where the kernels run), over the layers and the steps of "
-                "the last publish"
-            ).set(float(max(np.max(s["ssm_state_absmax"]) for s in fetched)))
-            reg.gauge(
-                "ds_ssm_dt_mean",
-                "Mean step size dt = softplus(dt + dt_bias) of the "
-                "state-space layers, over the steps of the last publish"
-            ).set(float(np.mean([np.mean(s["ssm_dt_mean"]) for s in fetched])))
-        if "kda_state_absmax" in fetched[0]:
-            reg.gauge(
-                "ds_kda_state_absmax",
-                "Largest |S| the Kimi Delta Attention scans held (the chunks' "
-                "states where the kernels run), over the layers and the steps "
-                "of the last publish"
-            ).set(float(max(np.max(s["kda_state_absmax"]) for s in fetched)))
-            reg.gauge(
-                "ds_kda_decay_mean",
-                "Mean decay exp(g) a key channel and token of the Kimi Delta "
-                "Attention layers, over the steps of the last publish"
-            ).set(float(np.mean([np.mean(s["kda_decay_mean"]) for s in fetched])))
-            reg.gauge(
-                "ds_kda_fused_rows",
-                "Share of the Kimi Delta Attention layers whose row norms, beta "
-                "products and gated output norm rode inside the chunk kernels in "
-                "the last step (0: XLA made them around the recurrence)"
-            ).set(float(np.mean(fetched[-1]["kda_fused_rows"])))
-        if "gdn_state_absmax" in fetched[0]:
-            reg.gauge(
-                "ds_gdn_state_absmax",
-                "Largest |S| the Gated DeltaNet scans held (the chunks' states "
-                "where the kernels run), over the layers and the steps of the "
-                "last publish"
-            ).set(float(max(np.max(s["gdn_state_absmax"]) for s in fetched)))
-            reg.gauge(
-                "ds_gdn_decay_mean",
-                "Mean decay exp(g) a value head and token of the Gated DeltaNet "
-                "layers, over the steps of the last publish"
-            ).set(float(np.mean([np.mean(s["gdn_decay_mean"]) for s in fetched])))
-        if "attn_gate_mean" in fetched[0]:
-            reg.gauge(
-                "ds_attn_gate_mean",
-                "Mean of sigmoid(gate) over the gated softmax attention layers' "
-                "outputs, over the steps of the last publish"
-            ).set(float(np.mean([np.mean(s["attn_gate_mean"]) for s in fetched])))
-        if "selscan_state_absmax" in fetched[0]:
-            reg.gauge(
-                "ds_selscan_state_absmax",
-                "Largest |h| the Mamba-1 selective scans held (the blocks' "
-                "states where the kernels run), over the layers and the steps "
-                "of the last publish"
-            ).set(float(max(np.max(s["selscan_state_absmax"]) for s in fetched)))
-            reg.gauge(
-                "ds_selscan_dt_mean",
-                "Mean step size dt = softplus(dt_proj(delta)) of the Mamba-1 "
-                "layers, over the steps of the last publish"
-            ).set(float(np.mean([np.mean(s["selscan_dt_mean"]) for s in fetched])))
-        if "diffattn_lambda_mean" in fetched[0]:
-            reg.gauge(
-                "ds_diffattn_lambda_mean",
-                "Mean over the differential attention layers of the weight "
-                "lambda their second softmax map is subtracted with, over the "
-                "steps of the last publish"
-            ).set(float(np.mean([np.mean(s["diffattn_lambda_mean"]) for s in fetched])))
-        if "kda_head_block" in fetched[0]:
-            reg.gauge(
-                "ds_kda_head_block",
-                "Heads a grid step of the Kimi Delta Attention chunk kernels "
-                "took in the last step (kernel_dispatch.choose_kda_heads)"
-            ).set(float(fetched[-1]["kda_head_block"]))
-            reg.gauge(
-                "ds_kda_grid_steps",
-                "Grid steps a call of the Kimi Delta Attention chunk kernels "
-                "made in the last step: batch x heads / ds_kda_head_block x chunks"
-            ).set(float(fetched[-1]["kda_grid_steps"]))
-        for name, what in (("latent_rms", "the latent before kv_a_layernorm"),
-                           ("k_rope_rms", "the shared rope key")):
-            if "mla_" + name in fetched[0]:
-                reg.gauge(
-                    "ds_mla_" + name,
-                    f"Root mean square of {what} in the latent-attention "
-                    "layers, their mean over the steps of the last publish"
-                ).set(float(np.mean([np.mean(s["mla_" + name]) for s in fetched])))
-        if "dsa_chosen_pairs" in fetched[0]:
-            chosen, causal = (sum(float(np.sum(s["dsa_" + name], dtype=np.float64))
-                                  for s in fetched)
-                              for name in ("chosen_pairs", "causal_pairs"))
-            reg.counter(
-                "ds_dsa_chosen_pairs_total",
-                "(query, key) pairs the learned sparse attention's indexer "
-                "chose, summed over layers and steps"
-            ).inc(chosen)
-            reg.gauge(
-                "ds_dsa_chosen_share",
-                "Chosen over causal (query, key) pairs of the sparse-attention "
-                "layers, over the steps of the last publish"
-            ).set(chosen / max(causal, 1.0))
-        if "diffusion_masked_tokens" in fetched[0]:
-            masked, tokens = (sum(float(np.sum(s["diffusion_" + name])) for s in fetched)
-                              for name in ("masked_tokens", "tokens"))
-            reg.counter(
-                "ds_diffusion_masked_tokens_total",
-                "Data tokens the block-diffusion noising replaced by the mask "
-                "id (those that carry loss), summed over steps"
-            ).inc(masked)
-            reg.gauge(
-                "ds_diffusion_mask_rate",
-                "Masked tokens over data tokens, over the steps of the last "
-                "publish (the mean noise level t the batches drew)"
-            ).set(masked / max(tokens, 1.0))
-        if "expert_counts" not in fetched[0]:
-            return
-        if not getattr(self, "_kernel_line_logged", False):
-            # once, after the first MoE step was traced: which grouped
+        for family in self._sown_families:
+            steps = [family.of(step) for step in fetched]
+            if not steps[0]:
+                continue
+            derived = family.derive(steps) if family.derive else {}
+            for gauge in family.gauges:
+                if gauge.steps is None:
+                    value = derived.get(gauge.source)
+                elif gauge.source in steps[0]:
+                    value = gauge.steps([step[gauge.source] for step in steps])
+                else:
+                    continue
+                if value is None:
+                    continue
+                if gauge.counter:
+                    reg.counter(gauge.name, gauge.help).inc(float(value))
+                else:
+                    reg.gauge(gauge.name, gauge.help).set(float(value))
+        if not self._kernel_line_logged:
+            # once, after the first step that sowed was traced: which grouped
             # matmul this engine's call sites took, by pass (what
             # ds_moe_gmm_traced_total counted since the engine was built)
-            from ..ops.grouped_matmul import traced_note
-            log_dist(f"kernels: {traced_note(self._gmm_traced_before)}",
-                     ranks=[0])
+            from ..ops.grouped_matmul import traced_counts, traced_note
             self._kernel_line_logged = True
-        # [E] a step, [K, E] a K-step dispatch
-        counts = sum(np.asarray(s["expert_counts"], np.int64)
-                     .reshape(-1, s["expert_counts"].shape[-1]).sum(axis=0)
-                     for s in fetched)
-        reg.counter(
-            "ds_moe_tokens_routed_total",
-            "(token, expert) assignments the router made, summed over MoE "
-            "layers and steps (top_k per token and layer: none is dropped)"
-        ).inc(float(counts.sum()))
-        reg.gauge(
-            "ds_moe_expert_load_max_over_mean",
-            "Busiest expert's assignments over the mean expert's, counts "
-            "summed over MoE layers and the steps of the last publish"
-        ).set(float(counts.max() / max(counts.mean(), 1.0)))
-        if "rows_held" in fetched[0]:
-            # blocks that hold a share of the router's experts
-            held = sum(float(np.sum(s["rows_held"])) for s in fetched)
-            reg.counter(
-                "ds_moe_rows_held_total",
-                "(token, expert) assignments sent to the experts held on "
-                "this chip, summed over MoE layers and steps"
-            ).inc(held)
-            reg.gauge(
-                "ds_moe_rows_held_share",
-                "Rows held over all assignments the router made, over the "
-                "steps of the last publish (experts held / router width "
-                "when the router is even)"
-            ).set(held / max(float(counts.sum()), 1.0))
-            reg.counter(
-                "ds_moe_share_fallback_total",
-                "MoE layers of a step whose rows held outran the static "
-                "rows array and took the exact pass over all assignments"
-            ).inc(sum(float(np.sum(s["share_fallback"])) for s in fetched))
-        aux = [np.asarray(s["aux_loss"], np.float64).mean()
-               for s in fetched if "aux_loss" in s]
-        if aux:
-            reg.gauge(
-                "ds_moe_aux_loss",
-                "Router load-balancing term as added to the loss "
-                "(coefficient included, summed over layers), mean over the "
-                "steps of the last publish").set(float(np.mean(aux)))
+            if traced_counts() != self._gmm_traced_before:
+                log_dist(f"kernels: {traced_note(self._gmm_traced_before)}",
+                         ranks=[0])
 
     def _publish_registry_events(self, window_start=None, window_len=None):
         """Registry publish cadence: refresh derived observability views
@@ -2060,7 +1844,7 @@ class DeepSpeedTpuEngine:
         with self._tracer.scope("ds.train.publish"):
             if self.monitor is not None:
                 self.monitor.flush_events(fetch=host_fetch)
-            self._publish_moe_stats()
+            self._publish_sown_stats()
             self._publish_registry_events(
                 window_start=self.global_steps - total_steps,
                 window_len=total_steps)
@@ -2280,133 +2064,42 @@ class DeepSpeedTpuEngine:
                         loss_f = float(loss)
                     self.monitor.write_events([("Train/Samples/train_loss", loss_f,
                                                 self.global_samples)])
-                self._publish_moe_stats()   # of the steps before this one
+                self._publish_sown_stats()   # of the steps before this one
                 self._publish_registry_events()
             self._flops_profile_post()
             self._resilience_step_boundary(loss=loss, overflow=overflow)
         if stats:
-            self._moe_pending.append(stats)
+            self._sown_pending.append(stats)
         return loss
 
-    def moe_stats(self):
-        """Routing stats of the newest fused MoE step not yet published, as
-        host arrays: ``expert_counts`` ``[E]`` ((token, expert) assignments
-        over the router's width, summed over the MoE layers), with a router
-        loss ``aux_loss``, and where the blocks hold a share of the experts
-        ``rows_held`` and ``share_fallback`` (summed over the layers).
-        A device→host fetch that waits for that step; ``None`` for a model
-        that sows none."""
-        return self._newest_stats(
-            lambda name: not name.startswith(("ssm_", "mla_", "diffusion_", "dsa_",
-                                              "kda_", "selscan_", "diffattn_", "gdn_",
-                                              "attn_")))
-
-    def diffusion_stats(self):
-        """What the block-diffusion objective sowed in the newest fused step
-        not yet published, as host scalars: ``masked_tokens``, ``mask_rate``
-        (over the step's data tokens) and ``t_mean_masked`` (the mean noise
-        level of the masked tokens' blocks). Waits for that step, as
-        :meth:`moe_stats`; ``None`` under another objective."""
-        stats = self._newest_stats(lambda name: name.startswith("diffusion_"))
-        if not stats:
+    def sown_stats(self, family: str):
+        """What the operators of one family (a name of the model's
+        ``sown_families``: docs/TRAINING.md, "What an operator sows for the
+        host") sowed in the newest fused step not yet published, as host
+        values under the family's own names, or the view its ``derive`` makes
+        of them. A device→host fetch that waits for that step; ``None`` for a
+        model that sows none of the family."""
+        declared = next((f for f in self._sown_families if f.name == family), None)
+        if declared is None or not self._sown_pending:
             return None
-        masked = float(stats["diffusion_masked_tokens"])
-        return {"masked_tokens": int(masked),
-                "mask_rate": masked / max(float(stats["diffusion_tokens"]), 1.0),
-                "t_mean_masked": float(stats["diffusion_t_sum"]) / max(masked, 1.0)}
-
-    def ssm_stats(self):
-        """What the state-space layers sowed in the newest fused step not
-        yet published, as host scalars: ``state_absmax`` (the largest |S|
-        over the layers) and ``dt_mean``. Waits for that step, as
-        :meth:`moe_stats`; ``None`` for a model without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("ssm_"))
-        return stats and {name[len("ssm_"):]: v for name, v in stats.items()}
-
-    def kda_stats(self):
-        """What the Kimi Delta Attention layers sowed in the newest fused
-        step not yet published, as host scalars: ``state_absmax`` (the largest
-        |S| over the layers), ``decay_mean`` (of ``exp(g)``), ``beta_mean`` and
-        ``fused_rows`` (the layers' means; the last is 1.0 where the kernels
-        made the norms and beta products, 0.0 where XLA did) and, where the
-        chunk kernels ran, ``head_block`` and ``grid_steps`` (the heads a
-        grid step took, the steps a call).
-        Waits for that step, as :meth:`moe_stats`; ``None`` for a model
-        without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("kda_"))
-        return stats and {name[len("kda_"):]: v for name, v in stats.items()}
-
-    def gdn_stats(self):
-        """What the Gated DeltaNet layers sowed in the newest fused step not
-        yet published, as host scalars, named and reduced as
-        :meth:`kda_stats`' (``decay_mean`` here is over heads and tokens: the
-        decay is one a value head). ``None`` for a model without such a
-        layer."""
-        stats = self._newest_stats(lambda name: name.startswith("gdn_"))
-        return stats and {name[len("gdn_"):]: v for name, v in stats.items()}
-
-    def attn_stats(self):
-        """What the gated softmax attention layers sowed in the newest fused
-        step not yet published: ``gate_mean``, the mean of ``sigmoid(gate)``
-        over values, tokens and layers. ``None`` for a model without such a
-        layer."""
-        stats = self._newest_stats(lambda name: name.startswith("attn_"))
-        return stats and {name[len("attn_"):]: v for name, v in stats.items()}
-
-    def selscan_stats(self):
-        """What the Mamba-1 layers sowed in the newest fused step not yet
-        published, as host scalars: ``state_absmax`` (the largest |h| over
-        the layers) and ``dt_mean``. Waits for that step, as
-        :meth:`moe_stats`; ``None`` for a model without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("selscan_"))
-        return stats and {name[len("selscan_"):]: v for name, v in stats.items()}
-
-    def diffattn_stats(self):
-        """What the differential attention layers sowed in the newest fused
-        step not yet published: ``lambda_mean``, a host array with each such
-        layer's ``lambda`` in the layers' order. Waits for that step, as
-        :meth:`moe_stats`; ``None`` for a model without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("diffattn_"))
-        return stats and {name[len("diffattn_"):]: v for name, v in stats.items()}
-
-    def mla_stats(self):
-        """What the latent-attention layers sowed in the newest fused step
-        not yet published, as host scalars, each the layers' mean:
-        ``latent_rms`` (of the latent before ``kv_a_layernorm``) and
-        ``k_rope_rms`` (of the shared rope key). Waits for that step, as
-        :meth:`moe_stats`; ``None`` for a model without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("mla_"))
-        return stats and {name[len("mla_"):]: v for name, v in stats.items()}
-
-    def dsa_stats(self):
-        """What the learned-sparse-attention layers sowed in the newest fused
-        step not yet published, as host numbers: ``chosen_pairs`` and
-        ``causal_pairs`` (summed over the layers; the first exact, and a
-        layer at a time as ``chosen_pairs_by_layer``),
-        ``chosen_share``, ``kth_score_mean`` (the mean over rows and
-        layers of a row's smallest chosen score) and ``masks_kept`` (the
-        layers whose backward read the forward's mask, ``ds.dsa.mask``, kept
-        by ``ops/remat.py``'s plan; the others made it again with one more
-        pass of the indexer's scores). Waits for that step, as
-        :meth:`moe_stats`; ``None`` for a model without such a layer."""
-        stats = self._newest_stats(lambda name: name.startswith("dsa_"))
-        if not stats:
+        newest = declared.of(self._sown_pending[-1])
+        if not newest:
             return None
-        chosen = int(np.sum(stats["dsa_chosen_pairs"], dtype=np.int64))
-        causal = float(np.sum(stats["dsa_causal_pairs"], dtype=np.float64))
-        return {"chosen_pairs": chosen, "causal_pairs": int(causal),
-                "chosen_pairs_by_layer": [int(n) for n in
-                                          np.ravel(stats["dsa_chosen_pairs"])],
-                "chosen_share": chosen / max(causal, 1.0),
-                "kth_score_mean": float(np.mean(stats["dsa_kth_score_mean"])),
-                "masks_kept": int(np.sum(stats["dsa_masks_kept"]))}
+        newest = host_fetch(newest)
+        return declared.derive([newest]) if declared.derived_view else newest
 
-    def _newest_stats(self, wanted):
-        if not self._moe_pending:
-            return None
-        newest = {name: v for name, v in self._moe_pending[-1].items()
-                  if wanted(name)}
-        return host_fetch(newest) if newest else None
+    # the families' names as they were before sown_stats: what
+    # benchmark/runners and benchmark/layers call
+    moe_stats = partialmethod(sown_stats, "moe")
+    diffusion_stats = partialmethod(sown_stats, "diffusion")
+    ssm_stats = partialmethod(sown_stats, "ssm")
+    kda_stats = partialmethod(sown_stats, "kda")
+    gdn_stats = partialmethod(sown_stats, "gdn")
+    attn_stats = partialmethod(sown_stats, "attn")
+    selscan_stats = partialmethod(sown_stats, "selscan")
+    diffattn_stats = partialmethod(sown_stats, "diffattn")
+    mla_stats = partialmethod(sown_stats, "mla")
+    dsa_stats = partialmethod(sown_stats, "dsa")
 
     def eval_batch(self, *args, **kwargs):
         """Forward-only compiled path for evaluation.
@@ -2486,13 +2179,13 @@ class DeepSpeedTpuEngine:
                         [("Train/Samples/train_loss", float(l),
                           base + i * self.train_batch_size())
                          for i, l in enumerate(np.asarray(losses))])
-                self._publish_moe_stats()   # of the dispatches before this one
+                self._publish_sown_stats()   # of the dispatches before this one
                 self._publish_registry_events(
                     window_start=self.global_steps - K, window_len=K)
             self._flops_profile_post()
             self._resilience_step_boundary(losses_vec=losses, overflows_vec=overflows)
         if stats:
-            self._moe_pending.append(stats)
+            self._sown_pending.append(stats)
         return losses
 
     def module_forward(self, *args, **kwargs):
