@@ -65,9 +65,9 @@ def debug_report():
                      f"{v if v else NO}")
     lines.append(f"python version {'.' * 34} {sys.version.split()[0]}")
     try:
-        # what a TPU runs at the 0.4B preset's shape and at each chip's call
-        # in the benchmark's train-zero3-seq4k cell (Mistral-7B widths):
-        # kernels and blocks follow from the shape alone
+        # what a TPU runs at the 0.4B preset's shape, at each chip's call in
+        # the benchmark's train-zero3-seq4k cell (Mistral-7B widths) and at
+        # the Qwen3-Next cell's: kernels and blocks follow from the shape alone
         from .ops import kernel_dispatch
         lines.append(f"attn dispatch @ bench shape {'.' * 21} "
                      f"{kernel_dispatch.resolved_note()}")
@@ -75,6 +75,11 @@ def debug_report():
                      + kernel_dispatch.resolved_note(
                          batch=1, seq=4096, heads=32, kv_heads=8,
                          head_dim=128, window=4096))
+        # past the fused backward's VMEM cap whole: walked by query ranges
+        lines.append(f"attn dispatch @ [1,32768,16/2,256] {'.' * 14} "
+                     + kernel_dispatch.resolved_note(
+                         batch=1, seq=32768, heads=16, kv_heads=2,
+                         head_dim=256))
     except Exception as e:  # pragma: no cover
         lines.append(f"attn dispatch @ bench shape {'.' * 21} {NO} ({e})")
     try:

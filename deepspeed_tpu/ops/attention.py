@@ -20,9 +20,12 @@ kernel (``flash_dkdv_dq``) sweeps q blocks per kv block on the TRANSPOSED
 score tile (``k . q^T``, so dv and dk contract the minor dimension) and
 takes dq from the same tile, accumulating the dQ of a KV head's whole
 sequence in float32 in VMEM; where that does not fit
-(``kernel_dispatch`` decides from the shape) the two-pass pair runs, that
-sweep for dk/dv alone and a dq kernel that sweeps kv blocks per q block.
-All rebuild p from the saved LSE (no second online softmax). A block wholly
+(``kernel_dispatch`` decides from the shape) the same walk is made a query
+RANGE at a time, one range's dQ in VMEM, and a kv block's dk and dv leave as
+a float32 partial a range, summed after the call. The two-pass pair (that
+sweep for dk/dv alone and a dq kernel that sweeps kv blocks per q block) is
+what ``impl_bwd="pallas"`` pins: the tests' second oracle and the sweep's
+other column. All rebuild p from the saved LSE (no second online softmax). A block wholly
 above the causal diagonal or outside the window is skipped and fetches
 nothing: the swept operand's index map is clamped to the live range, so a
 dead step names the block already resident. Blocks come from the shape
@@ -398,21 +401,36 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                  scale, causal, block_q, block_k, num_q, num_kv, fused,
-                 window=None, softcap=None):
+                 ranges=1, window=None, softcap=None):
     """dK and dV of one kv block over a sweep of the q blocks (innermost)
     and, ``fused``, dQ from the same score tiles: the dQ of this KV head's
-    whole sequence accumulates in float32 in ``dq_acc`` [q blocks, G*BQ, D]
-    over the kv sweep, ascending as the dq kernel sums it, and the last kv
+    queries accumulates in float32 in ``dq_acc`` [q blocks, G*BQ, D] over
+    the kv sweep, ascending as the dq kernel sums it, and the last live kv
     block's sweep writes it out, one q block a step. ``refs``: the results
-    dk, dv (, dq), then their float32 accumulators."""
-    if fused:
-        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
-    else:
-        dk_ref, dv_ref, dk_acc, dv_acc = refs
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    dk, dv (, dq), then their float32 accumulators.
 
-    @pl.when(qi == 0)
+    ``ranges`` > 1: the walk is made a RANGE of ``num_q / ranges`` q blocks
+    at a time (grid ``(B*KV, range, kv block, q block of the range)``), so
+    that ``dq_acc`` holds one range's dQ; a kv block's dK and dV then leave
+    as one float32 partial a range, for the caller to sum, and the results
+    come dq first (``benchmark/flash_cost.py`` reads a call's heads,
+    sequence and head size off its first result)."""
+    if ranges == 1:
+        if fused:
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+        else:
+            dk_ref, dv_ref, dk_acc, dv_acc = refs
+        ki, qr = pl.program_id(1), pl.program_id(2)
+        qi, last_kv = qr, num_kv - 1
+    else:
+        dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc = refs
+        # qr: the q block within the range; qi: within the sequence
+        r, ki, qr = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        qi = r * (num_q // ranges) + qr
+        last_kv = _live_kv_block(r, num_kv - 1, block_q * (num_q // ranges),
+                                 block_k, num_kv, causal, window)
+
+    @pl.when(qr == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -420,7 +438,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     if fused:
         @pl.when(ki == 0)
         def _init_dq():
-            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+            dq_acc[qr] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
     def _compute(masked):
         g, bq, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
@@ -466,40 +484,49 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         if fused:
             # dq += ds @ k from the same tile: ds^T contracted over its
             # first dimension, so Mosaic transposes this one tile a step
-            dq_acc[qi] += jax.lax.dot_general(
+            dq_acc[qr] += jax.lax.dot_general(
                 ds, k, (((0, ), (0, )), ((), ())),
                 preferred_element_type=jnp.float32)
 
     _when_live(qi, ki, block_q, block_k, causal, window, _compute)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(qr == num_q // ranges - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        if ranges == 1:
+            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        else:   # this range's partial, in the accumulators' float32
+            dk_ref[0, 0] = dk_acc[:]
+            dv_ref[0, 0] = dv_acc[:]
 
     if fused:
-        @pl.when(ki == num_kv - 1)
+        @pl.when(ki == last_kv)
         def _finalize_dq():
             g, bq = dq_ref.shape[1], dq_ref.shape[2]
-            dq_ref[0] = dq_acc[qi].reshape(g, bq, -1).astype(dq_ref.dtype)
+            dq_ref[0] = dq_acc[qr].reshape(g, bq, -1).astype(dq_ref.dtype)
 
 
 def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=None,
-               softcap=None, fused=False):
+               softcap=None, fused=False, ranges=1):
     """Per-head Pallas backward; ``res`` carries lse in the per-head
     layout, [B*KV, G, Sq] as the forward rule keeps it (or with the kernels'
     trailing unit dimension). ``fused``: one ``flash_dkdv_dq`` call in place
-    of ``flash_dq`` and ``flash_dkdv`` (``kernel_dispatch`` decides: the
-    float32 dQ of a KV head's whole sequence has to fit in VMEM)."""
+    of ``flash_dq`` and ``flash_dkdv``, its walk made ``ranges`` query ranges
+    at a time (``kernel_dispatch`` decides: the float32 dQ of a KV head's
+    range has to fit in VMEM)."""
     from .kernel_dispatch import flash_vmem_bytes, vmem_width
     q, k, v, o, lse = res
     B, Sq, H, D = q.shape
     Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // KV
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
+    assert fused or ranges == 1, "only the fused backward walks by ranges"
+    assert num_q % ranges == 0, (
+        f"{ranges} ranges must each hold whole q blocks: {num_q} of {block_q}")
+    range_q = num_q // ranges       # q blocks a range
     params = _compiler_params(flash_vmem_bytes(
         "fused" if fused else "bwd", G, vmem_width(D, Dv), q.dtype.itemsize,
-        block_q, block_k, seq_q=Sq))
+        block_q, block_k, seq_q=Sq // ranges))
     static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                   window=window, softcap=softcap)
 
@@ -538,35 +565,85 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         return (x.reshape(B * KV, G, num_q, block_q).transpose(0, 2, 1, 3)
                 .reshape(B * KV, num_q, 1, G * block_q))
 
-    def q_blk(j, i):
-        return _live_q_block(j, i, block_q, block_k, num_q, causal, window)
+    if ranges == 1:
+        grid = (B * KV, num_kv, num_q)
+
+        def q_blk(j, i):
+            return _live_q_block(j, i, block_q, block_k, num_q, causal, window)
+
+        def kv_blk(j, i):
+            return j
+
+        def dq_blk(j, i):
+            # a q block of dQ is complete, and written, in the last kv
+            # block's sweep; until then the map names block 0, which that
+            # sweep writes first, so nothing leaves VMEM before it holds a
+            # result
+            return jnp.where(j == num_kv - 1, i, 0)
+
+        dkv_shape, dkv_dtype = (B * KV, Sk), (k.dtype, v.dtype)
+
+        def dkv_map(width):
+            return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
+    else:
+        # (KV head, range, kv block, q block of the range): a dead step,
+        # and a dead sweep (a kv block past a causal range, or before its
+        # window), names the blocks its neighbour holds, inside the range
+        grid = (B * KV, ranges, num_kv, range_q)
+
+        def live_kv(r, j):
+            return _live_kv_block(r, j, range_q * block_q, block_k, num_kv,
+                                  causal, window)
+
+        def q_blk(r, j, i):
+            first = r * range_q
+            return jnp.clip(_live_q_block(j, first + i, block_q, block_k,
+                                          num_q, causal, window),
+                            first, first + range_q - 1)
+
+        def kv_blk(r, j, i):
+            return live_kv(r, j)
+
+        def dq_blk(r, j, i):
+            # the range's dQ leaves in its last live kv block's sweep: its
+            # first q block named before it, its last one after it
+            last = live_kv(r, num_kv - 1)
+            return r * range_q + jnp.where(
+                j < last, 0, jnp.where(j == last, i, range_q - 1))
+
+        # a kv block's dK and dV of one range: float32, summed below
+        dkv_shape, dkv_dtype = (B * KV, ranges, Sk), (jnp.float32, ) * 2
+
+        def dkv_map(width):
+            return pl.BlockSpec((1, 1, block_k, width),
+                                lambda b, r, j, i: (b, r, j, 0))
 
     q_spec2 = pl.BlockSpec((1, G, block_q, D),
-                           lambda b, j, i: (b, 0, q_blk(j, i), 0))
+                           lambda b, *ids: (b, 0, q_blk(*ids), 0))
     do_spec2 = pl.BlockSpec((1, G, block_q, Dv),
-                            lambda b, j, i: (b, 0, q_blk(j, i), 0))
-    k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    v_spec2 = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
+                            lambda b, *ids: (b, 0, q_blk(*ids), 0))
+    k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, *ids: (b, kv_blk(*ids), 0))
+    v_spec2 = pl.BlockSpec((1, block_k, Dv), lambda b, *ids: (b, kv_blk(*ids), 0))
     r_spec2 = pl.BlockSpec((1, 1, 1, G * block_q),
-                           lambda b, j, i: (b, q_blk(j, i), 0, 0))
-    out_specs = [k_spec2, v_spec2]
-    out_shape = [jax.ShapeDtypeStruct((B * KV, Sk, D), k.dtype),
-                 jax.ShapeDtypeStruct((B * KV, Sk, Dv), v.dtype)]
+                           lambda b, *ids: (b, q_blk(*ids), 0, 0))
+    out_specs = [dkv_map(D), dkv_map(Dv)]
+    out_shape = [jax.ShapeDtypeStruct(dkv_shape + (D, ), dkv_dtype[0]),
+                 jax.ShapeDtypeStruct(dkv_shape + (Dv, ), dkv_dtype[1])]
     scratch_shapes = [pltpu.VMEM((block_k, D), jnp.float32),
                       pltpu.VMEM((block_k, Dv), jnp.float32)]
     if fused:
-        # a q block of dQ is complete, and written, in the last kv block's
-        # sweep; until then the map names block 0, which that sweep writes
-        # first, so nothing leaves VMEM before it holds a result
-        out_specs.append(pl.BlockSpec(
-            (1, G, block_q, D),
-            lambda b, j, i: (b, 0, jnp.where(j == num_kv - 1, i, 0), 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype))
-        scratch_shapes.append(pltpu.VMEM((num_q, G * block_q, D), jnp.float32))
+        dq_spec = pl.BlockSpec((1, G, block_q, D),
+                               lambda b, *ids: (b, 0, dq_blk(*ids), 0))
+        dq_shape = jax.ShapeDtypeStruct((B * KV, G, Sq, D), q.dtype)
+        # dq last, where one range walks; first, where several do
+        at = len(out_specs) if ranges == 1 else 0
+        out_specs.insert(at, dq_spec)
+        out_shape.insert(at, dq_shape)
+        scratch_shapes.append(pltpu.VMEM((range_q, G * block_q, D), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(_dkdv_kernel, num_q=num_q, num_kv=num_kv,
-                          fused=fused, **static),
-        grid=(B * KV, num_kv, num_q),
+                          fused=fused, ranges=ranges, **static),
+        grid=grid,
         in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, r_spec2, r_spec2],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -576,10 +653,14 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         name=(_kernel_name("flash_dkdv_dq", "mla_bwd", D, Dv) if fused
               else _kernel_name("flash_dkdv", "mla_bwd_dkdv", D, Dv)),
     )(qg, kt, vt, dog, rows(lse), rows(delta))
-    if fused:
+    if not fused:
+        dk, dv = outs
+    elif ranges == 1:
         dk, dv, dq = outs
     else:
-        dk, dv = outs
+        dq, dk, dv = outs
+        dk, dv = (jnp.sum(x, axis=1).astype(like.dtype)
+                  for x, like in ((dk, k), (dv, v)))
 
     dq = (dq.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
           .reshape(B, Sq, H, D))
@@ -620,7 +701,8 @@ def _dispatched_attention(q, k, v, scale, causal, window, softcap, interpret,
                           fwd_dec, bwd_dec):
     """The per-head forward at ``fwd_dec``'s blocks; its backward is
     ``bwd_dec``'s (hashable ``kernel_dispatch.Decision`` tuples, resolved at
-    trace time): the fused kernel or the dq + dk/dv pair."""
+    trace time): the fused kernel in its ranges, or the dq + dk/dv pair
+    where a caller pinned it."""
     return _flash_fwd(q, k, v, scale, causal, fwd_dec.block_q,
                       fwd_dec.block_k, interpret, window, softcap)[0]
 
@@ -660,7 +742,7 @@ def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
               res, g):
     return _flash_bwd(res, g, scale, causal, bwd_dec.block_q,
                       bwd_dec.block_k, interpret, window, softcap,
-                      fused=bwd_dec.impl == "fused")
+                      fused=bwd_dec.impl == "fused", ranges=bwd_dec.ranges)
 
 
 _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -670,7 +752,7 @@ _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
 # frame (``jvp(flash_fwd)`` reads ``%jvp_flash_fwd_``). A jit boundary
 # outside the custom_vjp starts a new frame, so the kernels read
 # ``%flash_fwd.N`` / ``%flash_dkdv_dq.N`` (or ``%flash_dq.N`` +
-# ``%flash_dkdv.N`` where the backward is the pair) under ``jax.grad``
+# ``%flash_dkdv.N`` where the pair is pinned) under ``jax.grad``
 # on one device as they do inside a ``shard_map``. XLA inlines the call.
 _flash_attention_call = jax.jit(_dispatched_attention,
                                 static_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -687,17 +769,19 @@ def flash_attention(q,
                     softcap: Optional[float] = None,
                     force_pallas: Optional[bool] = None,
                     interpret: bool = False,
-                    impl_bwd: Optional[str] = None):
+                    impl_bwd: Optional[str] = None,
+                    ranges: Optional[int] = None):
     """Blocked attention; q [B, S, H, D], k/v [B, S, KV, D] (GQA native);
     v may be ``[B, S, KV, Dv]`` with ``Dv != D`` (the result is then ``Dv``
     wide and the kernels are the ``mla_*`` calls).
 
     On a TPU (or with ``interpret=True`` anywhere) the per-head Pallas
     forward runs, and under ``jax.grad`` the backward that
-    ``ops/kernel_dispatch.py`` resolves from the shape: the fused kernel
-    where its whole-sequence dQ fits in VMEM, else the dq + dk/dv pair.
-    ``impl_bwd`` ("fused" | "pallas", the pair) and ``block_q``/``block_k``
-    pin them (tests, the sweep tool); blocks otherwise follow from the shape
+    ``ops/kernel_dispatch.py`` resolves from the shape: the fused kernel,
+    walked in as few query ranges as put a range's float32 dQ in VMEM (one
+    where the whole sequence's fits). ``impl_bwd`` ("fused" | "pallas", the
+    dq + dk/dv pair), ``block_q``/``block_k`` and ``ranges`` pin them
+    (tests, the sweep tool); blocks otherwise follow from the shape
     (``kernel_dispatch.choose_blocks``). Off a TPU without ``interpret``,
     ``_xla_attention`` runs both ways.
 
@@ -723,7 +807,7 @@ def flash_attention(q,
               if block_q is not None and block_k is not None else None)
     fwd_dec, bwd_dec = (_fit_blocks(dec, q.shape[1], k.shape[1])
                         for dec in kd.resolve(sig, impl_bwd=impl_bwd,
-                                              blocks=blocks))
+                                              blocks=blocks, ranges=ranges))
     return _flash_attention_call(q, k, v, scale, causal, window,
                                  softcap, interpret, fwd_dec, bwd_dec)
 

@@ -1,14 +1,16 @@
 """Attention kernels and their blocks, from the shape of the call alone.
 
 On a TPU ``flash_attention`` runs one forward, the per-head Pallas kernel
-(``flash_fwd``), and one of two backwards: ``flash_dkdv_dq`` ("fused": dQ,
-dK and dV from one walk over the score tiles) where the float32 dQ of a KV
-head's whole sequence fits in VMEM beside its tiles (``fused_vmem_bytes``
-within ``FUSED_VMEM_CAP_BYTES``), else the pair ``flash_dq`` +
-``flash_dkdv`` ("pallas"). ``resolve`` decides; what it reads is the
+(``flash_fwd``), and one backward, ``flash_dkdv_dq`` ("fused": dQ, dK and
+dV from one walk over the score tiles), which holds the float32 dQ of a KV
+head's queries in VMEM beside its tiles: of the whole sequence where that
+fits (``fused_vmem_bytes`` within ``FUSED_VMEM_CAP_BYTES``), else of one
+query RANGE at a time, the fewest ranges that fit (``choose_ranges``). The
+pair ``flash_dq`` + ``flash_dkdv`` ("pallas") is what no shape resolves to
+and ``impl_bwd="pallas"`` pins. ``resolve`` decides; what it reads is the
 ``ShapeSig`` and what the caller pinned (``impl_bwd=``, ``block_q=`` /
-``block_k=``: tests and the sweep tool). No environment variable and no
-file is read on the way. The routes this replaced (an XLA forward under a
+``block_k=``, ``ranges=``: tests and the sweep tool). No environment
+variable and no file is read on the way. The routes this replaced (an XLA forward under a
 rule on the head size, head-folded kernels, a measured table) lost to these
 two at every point of a sweep on the chip: PERF.md, PR 44.
 
@@ -50,10 +52,12 @@ class ShapeSig(NamedTuple):
 
 
 class Decision(NamedTuple):
-    """One leg's kernel and blocks."""
+    """One leg's kernel and blocks, and the query ranges the fused backward
+    makes its walk in (1: the whole sequence at once; every other leg)."""
     impl: str
     block_q: int
     block_k: int
+    ranges: int = 1
 
 
 def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
@@ -87,14 +91,14 @@ FUSED_MAX_ROWS = 2048
 # it). The blocks chosen here stay under it by the estimate below; explicit
 # or measured blocks past it get their own limit on the call.
 VMEM_SCOPED_DEFAULT_BYTES = 16 * 2**20
-# The most the fused backward's estimate may be for the heuristic to choose
-# it: half the core's 128 MiB. The call asks for its estimate and a quarter
-# more (``vmem_limit_bytes``), 80 MiB at the cap, which leaves 48 MiB for
-# what Mosaic keeps beyond the estimate (its own stack: the state-space
-# scan's refusal at 1.25x, docs/kernel_dispatch.md) and for what XLA holds
-# in VMEM around the call. The estimate grows with ``group * seq_q`` (the
-# float32 dQ of one KV head's whole sequence): 16,384 tokens at group 4 are
-# 55 MiB, 24,576 are 71 MiB and take the dq + dk/dv pair.
+# The most the fused backward's estimate may be: half the core's 128 MiB.
+# The call asks for its estimate and a quarter more (``vmem_limit_bytes``),
+# 80 MiB at the cap, which leaves 48 MiB for what Mosaic keeps beyond the
+# estimate (its own stack: the state-space scan's refusal at 1.25x,
+# docs/kernel_dispatch.md) and for what XLA holds in VMEM around the call.
+# The estimate grows with ``group * seq_q`` (the float32 dQ of one KV
+# head's queries): 16,384 tokens at group 4 are 55 MiB; 24,576 are 71 MiB
+# and walk in two ranges of 12,288 (47 MiB).
 FUSED_VMEM_CAP_BYTES = 64 * 2**20
 
 
@@ -157,14 +161,35 @@ def vmem_width(head_dim: int, v_dim: int = 0) -> int:
     return -(-max(head_dim, v_dim) // 128) * 128
 
 
-def fused_vmem_bytes(sig: ShapeSig) -> int:
-    """``flash_vmem_bytes`` of the fused backward at ``sig``, with the blocks
-    the shape gives it: what ``resolve`` holds against
-    FUSED_VMEM_CAP_BYTES."""
+def fused_vmem_bytes(sig: ShapeSig, ranges: int = 1,
+                     blocks: Optional[tuple] = None) -> int:
+    """``flash_vmem_bytes`` of the fused backward at ``sig`` walked in
+    ``ranges`` query ranges, with ``blocks`` or those the shape gives it:
+    what ``choose_ranges`` holds against FUSED_VMEM_CAP_BYTES."""
     return flash_vmem_bytes("fused", max(1, sig.heads // sig.kv_heads),
                             vmem_width(sig.head_dim, sig.v_dim),
                             4 if "32" in sig.dtype else 2,
-                            *choose_blocks(sig, "fused"), seq_q=sig.seq_q)
+                            *(blocks or choose_blocks(sig, "fused")),
+                            seq_q=sig.seq_q // ranges)
+
+
+def choose_ranges(sig: ShapeSig, blocks: Optional[tuple] = None) -> int:
+    """Query ranges of the fused backward's walk, from the shape alone: the
+    fewest that divide the sequence into whole q blocks and put the
+    estimate, one range's float32 dQ in it, within FUSED_VMEM_CAP_BYTES (1
+    at every shape that fits whole). Tiles that alone pass the cap (pinned
+    ones) take a q block a range; a q block that does not divide the
+    sequence is the kernels' to refuse, in one range."""
+    blocks = blocks or choose_blocks(sig, "fused")
+    block_q = min(blocks[0], sig.seq_q)
+    if sig.seq_q % block_q:
+        return 1
+    num_q = sig.seq_q // block_q
+    for ranges in range(1, num_q):
+        if (num_q % ranges == 0 and fused_vmem_bytes(sig, ranges, blocks)
+                <= FUSED_VMEM_CAP_BYTES):
+            return ranges
+    return num_q
 
 
 # Block-diffusion attention (``ops/attention.py``, ``bdattn_fwd`` /
@@ -435,31 +460,43 @@ def choose_kda_heads(heads: int, d: int, chunk: int, itemsize: int) -> int:
 
 
 def resolve(sig: ShapeSig, *, impl_bwd: Optional[str] = None,
-            blocks: Optional[tuple] = None):
+            blocks: Optional[tuple] = None, ranges: Optional[int] = None):
     """(forward Decision, backward Decision) of one ``flash_attention`` call.
-    The forward is the per-head kernel. The backward is ``impl_bwd`` if
-    given, else the fused kernel where its estimate, with the blocks the
-    shape gives it, fits FUSED_VMEM_CAP_BYTES, else the dq + dk/dv pair
-    (24,576 tokens at group 4). ``blocks`` pins both legs' (block_q,
-    block_k); else each leg's come from ``choose_blocks``."""
+    The forward is the per-head kernel. The backward is the fused kernel at
+    every shape, walked in the fewest query ranges whose float32 dQ fits
+    FUSED_VMEM_CAP_BYTES beside the tiles (``choose_ranges``: 1 up to 20,480
+    tokens at group 4 and head 128, 2 at 24,576); ``impl_bwd`` pins it or
+    the dq + dk/dv pair, ``blocks`` both legs' (block_q, block_k), else each
+    leg's come from ``choose_blocks``, and ``ranges`` the fused walk's."""
     if impl_bwd is None:
-        impl_bwd = (IMPL_FUSED if fused_vmem_bytes(sig) <= FUSED_VMEM_CAP_BYTES
-                    else IMPL_PALLAS)
+        impl_bwd = IMPL_FUSED
     elif impl_bwd not in (IMPL_PALLAS, IMPL_FUSED):
         raise ValueError(f"impl_bwd={impl_bwd!r}: the backward is "
                          f"{IMPL_PALLAS!r} (the dq + dk/dv pair) or "
                          f"{IMPL_FUSED!r}")
-    legs = ("fwd", "fused" if impl_bwd == IMPL_FUSED else "bwd")
-    return tuple(
-        Decision(impl, *(int(b) for b in blocks or choose_blocks(sig, leg)))
-        for impl, leg in zip((IMPL_PALLAS, impl_bwd), legs))
+    fused = impl_bwd == IMPL_FUSED
+    bwd_blocks = blocks or choose_blocks(sig, "fused" if fused else "bwd")
+    if ranges is None:
+        ranges = choose_ranges(sig, bwd_blocks) if fused else 1
+    elif not fused and ranges != 1:
+        raise ValueError(f"ranges={ranges}: only the fused backward walks by "
+                         f"query ranges")
+    elif ranges != 1 and (ranges < 1 or sig.seq_q % ranges or (
+            sig.seq_q // ranges) % min(bwd_blocks[0], sig.seq_q)):
+        raise ValueError(
+            f"ranges={ranges}: a range of the fused backward holds whole "
+            f"q blocks ({sig.seq_q} queries in blocks of {bwd_blocks[0]})")
+    return (Decision(IMPL_PALLAS, *map(int, blocks or choose_blocks(sig, "fwd"))),
+            Decision(impl_bwd, *map(int, bwd_blocks), ranges=int(ranges)))
 
 
 def describe(fwd: Decision, bwd: Decision) -> str:
     """Compact per-leg note for reports and artifacts, e.g.
-    ``attn[fwd=pallas@256x512,bwd=fused@512x512]``."""
+    ``attn[fwd=pallas@256x512,bwd=fused@512x512]``; a backward walked in
+    several query ranges says how many (``bwd=fused@256x512/r8``)."""
     return (f"attn[fwd={fwd.impl}@{fwd.block_q}x{fwd.block_k},"
-            f"bwd={bwd.impl}@{bwd.block_q}x{bwd.block_k}]")
+            f"bwd={bwd.impl}@{bwd.block_q}x{bwd.block_k}"
+            f"{f'/r{bwd.ranges}' if bwd.ranges > 1 else ''}]")
 
 
 def resolved_note(batch=8, seq=1024, heads=16, kv_heads=None, head_dim=64,

@@ -17,10 +17,13 @@ Per shape the tool times:
   fwd:  xla (the reference, ``_xla_attention``), pallas (the per-head
         kernel) x blocks
   bwd:  xla (the reference's vjp), pallas x blocks (the dq + dk/dv pair),
-        fused x blocks (the one-pass backward); the pullback alone, on
-        residuals an untimed forward left
+        fused x blocks (the one-pass backward, in the query ranges the shape
+        gives it or ``--ranges``'s); the pullback alone, on residuals an
+        untimed forward left
 ``--impls`` and ``--blocks`` narrow the candidates (a block sweep of one
-impl: ``--impls pallas --blocks 512x512,1024x512,1024x1024``).
+impl: ``--impls pallas --blocks 512x512,1024x512,1024x1024``); ``--ranges
+8,16`` walks the fused backward in 8 and in 16 query ranges at each block
+pair (``fused@256x512/r8``).
 """
 
 import argparse
@@ -62,9 +65,10 @@ def _blocks_for(impl: str, sig, leg: str, quick: bool, grid=None):
 
 
 def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
-                iters, interpret, quick, impls=None, grid=None):
+                iters, interpret, quick, impls=None, grid=None, ranges=None):
     """Sweep one shape; returns {leg: [(label, impl, blocks, ms), ...]}.
-    ``grid`` replaces the kernels' block grid."""
+    ``grid`` replaces the kernels' block grid; ``ranges`` lists the query
+    ranges to walk the fused backward in (default: what the shape gives)."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kernel_dispatch as kd
@@ -80,24 +84,24 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
     sig = kd.make_sig(shp_q, kv_heads, seq, q.dtype, causal, None, None)
     impls = impls or (IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FUSED)
 
-    def attend(impl, blocks):
+    def attend(impl, blocks, walk=None):
         if impl == IMPL_XLA:
             scale = 1.0 / np.sqrt(head_dim)
             return lambda q, k, v: _xla_attention(q, k, v, scale, causal)
         bq, bk = blocks
         return lambda q, k, v: flash_attention(
             q, k, v, causal=causal, interpret=interpret, impl_bwd=impl,
-            block_q=bq, block_k=bk)
+            block_q=bq, block_k=bk, ranges=walk)
 
-    def fwd_fn(impl, blocks):
+    def fwd_fn(impl, blocks, walk=None):
         f = jax.jit(attend(impl, blocks))
         return lambda: f(q, k, v)
 
-    def bwd_fn(impl, blocks):
+    def bwd_fn(impl, blocks, walk=None):
         # the pullback alone: one untimed forward leaves its residuals, and
         # only the backward's kernels are in the timing (the reference's
         # float32 scores are 4 GiB at 4 x 16 x 4096^2)
-        out, pull = jax.vjp(attend(impl, blocks), q, k, v)
+        out, pull = jax.vjp(attend(impl, blocks, walk), q, k, v)
         g = jnp.ones_like(out)
         run = jax.jit(lambda pull, g: pull(g))
         return lambda: run(pull, g)
@@ -109,18 +113,24 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
             if leg == "fwd" and impl == kd.IMPL_FUSED:
                 continue    # a backward: its forward is the per-head one
             seen = set()
-            for blocks in _blocks_for(impl, sig, leg, quick, grid):
+            walks = ranges if leg == "bwd" and impl == kd.IMPL_FUSED and ranges else [None]
+            for blocks, walk in ((b, w) for b in _blocks_for(impl, sig, leg, quick, grid)
+                                 for w in walks):
                 if blocks is not None:
                     # a tile can't exceed the sequence — clamp, then dedupe
                     # (several candidates can clamp to the same point)
                     blocks = (min(blocks[0], seq), min(blocks[1], seq))
-                    if blocks in seen:
+                    if (blocks, walk) in seen:
                         continue
-                    seen.add(blocks)
+                    seen.add((blocks, walk))
                 label = impl if blocks is None else (
                     f"{impl}@{blocks[0]}x{blocks[1]}")
                 try:
-                    ms = _time(make(impl, blocks), iters)
+                    if leg == "bwd" and impl == kd.IMPL_FUSED:
+                        walked = kd.resolve(sig, impl_bwd=impl, blocks=blocks,
+                                            ranges=walk)[1].ranges
+                        label += f"/r{walked}" if walked > 1 else ""
+                    ms = _time(make(impl, blocks, walk), iters)
                 except Exception as e:  # noqa: BLE001 — report, keep sweeping
                     print(f"  {leg} {label: <18} FAILED: "
                           f"{type(e).__name__}: {e}", flush=True)
@@ -158,6 +168,9 @@ def main(argv=None):
     ap.add_argument("--blocks", default=None,
                     help="block grid as 'bqxbk,bqxbk,...' in place of "
                          "SWEEP_BLOCKS")
+    ap.add_argument("--ranges", default=None,
+                    help="comma list of query-range counts to walk the fused "
+                         "backward in (default: what the shape gives)")
     args = ap.parse_args(argv)
 
     import jax
@@ -179,7 +192,8 @@ def main(argv=None):
                 interpret=args.interpret, quick=args.quick,
                 impls=args.impls and tuple(args.impls.split(",")),
                 grid=args.blocks and [tuple(int(x) for x in b.split("x"))
-                                      for b in args.blocks.split(",")])
+                                      for b in args.blocks.split(",")],
+                ranges=args.ranges and [int(r) for r in args.ranges.split(",")])
     return 0
 
 

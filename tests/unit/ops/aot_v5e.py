@@ -97,20 +97,27 @@ def _other_kernels(compiled):
     return {k: names.count(k) for k in set(names)}
 
 
-def _pallas_call_sites(jaxpr, counts=None):
-    """Call sites of each Pallas kernel in a traced program, by the kernel's
-    name: the ``pallas_call`` equations of ``jaxpr`` and of every jaxpr an
-    equation holds (a jitted body is counted at each equation that calls it)."""
-    counts = {} if counts is None else counts
+def _pallas_calls(jaxpr, found=None):
+    """The ``pallas_call`` equations of ``jaxpr`` and of every jaxpr an
+    equation holds, in program order (a jitted body's at each equation that
+    calls it)."""
+    found = [] if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+            found.append(eqn)
         for value in eqn.params.values():
             for inner in value if isinstance(value, (tuple, list)) else (value, ):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _pallas_call_sites(inner, counts)
-    return counts
+                    _pallas_calls(inner, found)
+    return found
+
+
+def _pallas_call_sites(jaxpr):
+    """Call sites of each Pallas kernel in a traced program, by the kernel's
+    name."""
+    names = [eqn.params["name"] for eqn in _pallas_calls(jaxpr)]
+    return {name: names.count(name) for name in names}
 
 
 @contextlib.contextmanager

@@ -3,7 +3,9 @@ over the score tiles) against the pair it replaces (``flash_dq`` +
 ``flash_dkdv``) and against ``jax.vjp`` of the XLA reference, interpreted on
 the CPU. Both backwards run on the same per-head forward's residuals, so in
 float32 they agree to rounding: the fused kernel sums dQ over the kv blocks
-in the order the dq kernel does."""
+in the order the dq kernel does. The same kernel walked a query range at a
+time is ``test_flash_ranged_bwd.py``'s (a file of its own: a worker's share
+under ``--dist loadfile``)."""
 
 import functools
 
@@ -17,8 +19,8 @@ from deepspeed_tpu.ops.attention import _xla_attention, flash_attention
 
 
 def _case(s, h, kv, d, blocks, *, b=1, sk=None, causal=True, window=None,
-          softcap=None):
-    return dict(b=b, s=s, sk=sk or s, h=h, kv=kv, d=d, blocks=blocks,
+          softcap=None, dv=None):
+    return dict(b=b, s=s, sk=sk or s, h=h, kv=kv, d=d, dv=dv or d, blocks=blocks,
                 causal=causal, window=window, softcap=softcap)
 
 
@@ -48,18 +50,24 @@ CASES = {
     "g4_d64_one_step": _case(128, 4, 1, 64, (128, 128)),
 }
 
+# test_flash_ranged_bwd.py's cases (the same kernel walked a query range at a
+# time) register here, so that ``_grads`` serves both files
+RANGED_CASES = {}
+
 
 @functools.lru_cache(maxsize=None)
-def _grads(name, dtype, impl_bwd):
+def _grads(name, dtype, impl_bwd, ranges=None):
     """dq, dk, dv of case ``name``, one compiled program a call (op by op,
     every small op of the regrouping and of the reference's backward is a
     compile of its own); kept, so the float32 reference serves both tests."""
-    case = CASES[name]
+    case = CASES.get(name) or RANGED_CASES[name]
     rng = np.random.default_rng(11)
     shape_q = (case["b"], case["s"], case["h"], case["d"])
     shape_kv = (case["b"], case["sk"], case["kv"], case["d"])
-    q, g = (jnp.asarray(rng.normal(size=shape_q), dtype) for _ in range(2))
-    k, v = (jnp.asarray(rng.normal(size=shape_kv), dtype) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=shape_q), dtype)
+    g = jnp.asarray(rng.normal(size=shape_q[:3] + (case["dv"], )), dtype)
+    k = jnp.asarray(rng.normal(size=shape_kv), dtype)
+    v = jnp.asarray(rng.normal(size=shape_kv[:3] + (case["dv"], )), dtype)
     # no blocks given: those the dispatcher picks for the fused kernel, for
     # the pair too (other blocks sum in another order)
     bq, bk = case["blocks"] or kd.choose_blocks(kd.make_sig(
@@ -76,7 +84,7 @@ def _grads(name, dtype, impl_bwd):
             return flash_attention(
                 q, k, v, causal=case["causal"], window=case["window"],
                 softcap=case["softcap"], interpret=True,
-                impl_bwd=impl_bwd, block_q=bq, block_k=bk)
+                impl_bwd=impl_bwd, block_q=bq, block_k=bk, ranges=ranges)
     pulled = jax.jit(lambda q, k, v, g: jax.vjp(fn, q, k, v)[1](g))(q, k, v, g)
     return [np.asarray(x, np.float32) for x in pulled]
 

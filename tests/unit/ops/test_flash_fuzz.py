@@ -62,10 +62,21 @@ CASES += [
 ]
 
 # every shape under both backwards, the dq + dk/dv pair and the fused
-# kernel, on the per-head forward's residuals: the pair is the only backward
-# of a long sequence (past the fused kernel's VMEM cap) and no benchmark cell
-# runs it, so this sweep is its guard
-BWDS = ("pallas", "fused")
+# kernel, on the per-head forward's residuals: the pair is what no shape
+# resolves to and ``impl_bwd="pallas"`` pins (the fused kernel's second
+# oracle), so this sweep is its guard. "ranged" is the fused kernel walked
+# in a drawn number of query ranges, as a sequence past its VMEM cap is.
+BWDS = ("pallas", "fused", "ranged")
+
+
+def _drawn_ranges(i):
+    """A divisor above 1 of case ``i``'s count of q blocks, drawn by the
+    case's number (1 where it has one q block: the whole walk)."""
+    case = CASES[i]
+    bq = min((case.get("blocks") or (128, 128))[0], case["s"])
+    num_q = case["s"] // bq
+    divisors = [r for r in range(2, num_q + 1) if num_q % r == 0]
+    return int(np.random.default_rng(100 + i).choice(divisors)) if divisors else 1
 
 
 def _inputs(case):
@@ -111,16 +122,22 @@ def _id(i, bwd):
 
 @pytest.mark.parametrize("i,bwd", [
     pytest.param(i, bwd, id=_id(i, bwd))
-    for i in range(len(CASES)) for bwd in BWDS])
+    for i in range(len(CASES)) for bwd in BWDS
+    # one q block walks whole: the "fused" case already is that call
+    if bwd != "ranged" or _drawn_ranges(i) > 1])
 def test_flash_matches_oracle(i, bwd):
     case = CASES[i]
     bq, bk = case.get("blocks") or (None, None)
+    pins = dict(impl_bwd=bwd)
+    if bwd == "ranged":
+        # blocks the count of ranges was drawn for: the case's, or 128 x 128
+        bq, bk = case.get("blocks") or (128, 128)
+        pins = dict(impl_bwd="fused", ranges=_drawn_ranges(i))
 
     def loss_flash(q, k, v):
         out = flash_attention(q, k, v, causal=case.get("causal", True),
                               window=case["window"], softcap=case["softcap"],
-                              interpret=True, impl_bwd=bwd,
-                              block_q=bq, block_k=bk)
+                              interpret=True, block_q=bq, block_k=bk, **pins)
         return (out.astype(jnp.float32) ** 2).mean(), out
 
     (l1, o1), g1 = jax.jit(jax.value_and_grad(loss_flash, argnums=(0, 1, 2),

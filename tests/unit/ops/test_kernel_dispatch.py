@@ -1,8 +1,8 @@
 """Attention dispatch: the per-head forward and both backwards (the dq +
 dk/dv pair, the fused kernel) against the XLA oracle, what ``resolve`` makes
-of a shape (the fused kernel where its dQ accumulator fits, blocks from
-``choose_blocks``), what a caller may pin, the masked call the kernels
-refuse, and the sweep tool end-to-end on CPU.
+of a shape (the fused kernel at every shape, in the fewest query ranges whose
+dQ accumulator fits, blocks from ``choose_blocks``), what a caller may pin,
+the masked call the kernels refuse, and the sweep tool end-to-end on CPU.
 
 All kernel execution is Pallas interpret mode (CPU).
 """
@@ -239,57 +239,124 @@ def test_blocks_follow_from_the_shape(name, sig, fwd, bwd):
     if name == "cell":
         bq, bk = kd.choose_blocks(sig, "bwd")
         assert bk >= 512 and 512 <= group * bq <= 1024
-    # and it is what the legs resolve to: unpinned forward and back, but
-    # that the backward of a shape that fits is the fused kernel, with
-    # blocks of its own (below); the pair's are these
+    # and it is what the legs resolve to: unpinned forward, and the pair
+    # where it is pinned (the backward of every shape is the fused kernel,
+    # with blocks of its own: below)
     fwd_dec, dec = kd.resolve(sig)
     assert fwd_dec == kd.Decision(kd.IMPL_PALLAS, *fwd)
-    if dec.impl == kd.IMPL_PALLAS:
-        assert (dec.block_q, dec.block_k) == bwd
+    assert dec.impl == kd.IMPL_FUSED
     assert kd.resolve(sig, impl_bwd="pallas")[1] == kd.Decision("pallas", *bwd)
 
 
-@pytest.mark.parametrize("name,sig,impl", [
+@pytest.mark.parametrize("name,sig,ranges", [
     # the benchmark's four cells
-    ("cell", _sig(4096, 32, 8, 128, window=4096), kd.IMPL_FUSED),
-    ("lfm2_cell", _sig(8192, 32, 8, 64, batch=4), kd.IMPL_FUSED),
-    ("granite_cell", _sig(16384, 32, 8, 64), kd.IMPL_FUSED),
-    ("olmoe_cell", _sig(4096, 16, 16, 128, batch=4), kd.IMPL_FUSED),
-    ("chip_smoke", _sig(2048, 32, 8, 128, window=4096), kd.IMPL_FUSED),
-    ("hd64_1024", _bench_sig(), kd.IMPL_FUSED),
-    ("llama3_70b", _sig(4096, 64, 8, 128), kd.IMPL_FUSED),
-    ("gemma2_hd256", _sig(4096, 16, 8, 256), kd.IMPL_FUSED),
-    ("fp32", _sig(4096, 32, 8, 128, "float32"), kd.IMPL_FUSED),
-    ("seq64", _sig(64, 4, 4, 16), kd.IMPL_FUSED),
+    ("cell", _sig(4096, 32, 8, 128, window=4096), 1),
+    ("lfm2_cell", _sig(8192, 32, 8, 64, batch=4), 1),
+    ("granite_cell", _sig(16384, 32, 8, 64), 1),
+    ("olmoe_cell", _sig(4096, 16, 16, 128, batch=4), 1),
+    ("chip_smoke", _sig(2048, 32, 8, 128, window=4096), 1),
+    ("hd64_1024", _bench_sig(), 1),
+    ("llama3_70b", _sig(4096, 64, 8, 128), 1),
+    ("gemma2_hd256", _sig(4096, 16, 8, 256), 1),
+    ("fp32", _sig(4096, 32, 8, 128, "float32"), 1),
+    ("seq64", _sig(64, 4, 4, 16), 1),
     # the float32 dQ of a KV head's sequence grows with group x seq_q:
-    # 16,384 tokens at group 4 are 55 MiB with the tiles, 24,576 are 71
-    ("group4_24k", _sig(24576, 32, 8, 128), kd.IMPL_PALLAS),
-    ("group4_32k_hd64", _sig(32768, 32, 8, 64), kd.IMPL_PALLAS),
-    ("ulysses_32k", _sig(32768, 8, 2, 128), kd.IMPL_PALLAS),
-    ("group8_16k", _sig(16384, 64, 8, 128), kd.IMPL_PALLAS),
-    ("mha_64k", _sig(65536, 16, 16, 128), kd.IMPL_FUSED),
-    ("mha_128k", _sig(131072, 16, 16, 128), kd.IMPL_PALLAS),
+    # 16,384 tokens at group 4 are 55 MiB with the tiles, 24,576 are 71 and
+    # walk in two ranges of 12,288 (47 MiB)
+    ("group4_24k", _sig(24576, 32, 8, 128), 2),
+    ("group4_32k_hd64", _sig(32768, 32, 8, 64), 2),
+    ("ulysses_32k", _sig(32768, 8, 2, 128), 2),
+    ("group8_16k", _sig(16384, 64, 8, 128), 2),
+    ("mha_64k", _sig(65536, 16, 16, 128), 1),
+    ("mha_128k", _sig(131072, 16, 16, 128), 2),
+    # train-qwen3next-1chip-gdn-longseq: group 8 at head 256, 268 MB of dQ
+    ("qwen3next_cell", _sig(32768, 16, 2, 256), 8),
     # the accumulator follows the queries, not the keys
-    ("keys_ne_queries", _sig(256, 8, 2, 128, seq_k=65536), kd.IMPL_FUSED),
+    ("keys_ne_queries", _sig(256, 8, 2, 128, seq_k=65536), 1),
 ])
-def test_the_backward_is_fused_where_its_accumulator_fits(name, sig, impl):
-    """The backward's heuristic: one kernel for dQ, dK and dV where its VMEM
-    estimate, whole-sequence dQ accumulator included, is within the cap;
-    past it the dq + dk/dv pair. What a fused call asks of the chip stays
-    well inside the core's 128 MiB."""
+def test_the_backward_is_fused_in_the_fewest_ranges_whose_accumulator_fits(
+        name, sig, ranges):
+    """The backward's rule: one kernel for dQ, dK and dV at every shape; its
+    walk whole where the VMEM estimate, whole-sequence dQ accumulator
+    included, is within the cap, and past it in the fewest query ranges
+    (whole q blocks each, the sequence divisible) that bring the estimate
+    back within it. What a call asks of the chip stays well inside the
+    core's 128 MiB."""
     fwd, dec = kd.resolve(sig)
-    assert dec.impl == impl, name
-    est = kd.fused_vmem_bytes(sig)
-    assert (est <= kd.FUSED_VMEM_CAP_BYTES) == (impl == kd.IMPL_FUSED), est
-    # the accumulator, at 128 lanes a row at least, is inside the estimate
-    assert est >= (sig.heads // sig.kv_heads * sig.seq_q
+    assert (dec.impl, dec.ranges) == (kd.IMPL_FUSED, ranges), name
+    assert kd.choose_ranges(sig) == ranges
+    whole, est = kd.fused_vmem_bytes(sig), kd.fused_vmem_bytes(sig, ranges)
+    assert (whole <= kd.FUSED_VMEM_CAP_BYTES) == (ranges == 1), whole
+    assert est <= kd.FUSED_VMEM_CAP_BYTES
+    # no fewer would do
+    num_q = sig.seq_q // dec.block_q
+    assert num_q % ranges == 0 and sig.seq_q % ranges == 0
+    for fewer in range(1, ranges):
+        assert num_q % fewer or kd.fused_vmem_bytes(sig, fewer) > kd.FUSED_VMEM_CAP_BYTES
+    # a range's accumulator, at 128 lanes a row at least, is inside the estimate
+    assert est >= (sig.heads // sig.kv_heads * (sig.seq_q // ranges)
                    * max(sig.head_dim, 128) * 4)
-    if impl == kd.IMPL_FUSED:
-        assert (kd.vmem_limit_bytes(est) or 0) <= 80 * 2**20
+    assert (kd.vmem_limit_bytes(est) or 0) <= 80 * 2**20
     # the forward never resolves to it; each backward has its own blocks
-    assert fwd.impl == kd.IMPL_PALLAS
-    assert (dec.block_q, dec.block_k) == kd.choose_blocks(
-        sig, "fused" if impl == kd.IMPL_FUSED else "bwd")
+    assert fwd.impl == kd.IMPL_PALLAS and fwd.ranges == 1
+    assert (dec.block_q, dec.block_k) == kd.choose_blocks(sig, "fused")
+    # the pair: only where it is pinned, in one walk, with its own blocks
+    pair = kd.resolve(sig, impl_bwd="pallas")[1]
+    assert pair == kd.Decision(kd.IMPL_PALLAS, *kd.choose_blocks(sig, "bwd"))
+
+
+def test_the_qwen3_next_cells_call_walks_in_eight_ranges_of_4096_queries():
+    """``[1, 32768, 16/2, 256]``: the tiles at (256, 512), group 8 and 256
+    lanes are 27.25 MiB of the estimate; the whole sequence's dQ makes it
+    283.25, a range of 8,192 queries 91.25, one of 4,096 59.25: R = 8. The
+    three shapes the docs name past the cap walk in two (46.75, 54.75 and
+    54.75 MiB)."""
+    mib = 2**20
+    cell = _sig(32768, 16, 2, 256)
+    assert kd.choose_blocks(cell, "fused") == (256, 512)
+    assert [kd.fused_vmem_bytes(cell, r) / mib for r in (1, 4, 8)] == [
+        283.25, 91.25, 59.25]
+    assert kd.fused_vmem_bytes(cell, 8) - 8 * 4096 * 256 * 4 == 27.25 * mib
+    assert kd.resolve(cell)[1] == kd.Decision(kd.IMPL_FUSED, 256, 512, ranges=8)
+    assert [kd.fused_vmem_bytes(s, 2) / mib for s in (
+        _sig(24576, 32, 8, 128), _sig(32768, 32, 8, 64), _sig(32768, 8, 2, 128))
+            ] == [46.75, 54.75, 54.75]
+    # pinned blocks move the count with the tiles: (128, 512) halves them
+    assert kd.resolve(cell, blocks=(128, 512))[1].ranges == 8
+    assert kd.resolve(cell, blocks=(512, 512))[1].ranges == 32
+
+
+def test_ranges_are_pinned_as_blocks_are_and_only_for_the_fused_backward():
+    """``ranges=`` beats the shape: any count that leaves a range whole q
+    blocks of a divisible sequence (one q block a range at the most); the
+    pair has no ranges to pin; tiles that alone pass the cap take a q block
+    a range, and a q block that does not divide the sequence one range."""
+    sig = _sig(4096, 32, 8, 128)
+    assert kd.resolve(sig)[1].ranges == 1
+    for r in (1, 2, 4, 8):
+        assert kd.resolve(sig, ranges=r)[1] == kd.Decision("fused", 512, 512, r)
+    assert kd.resolve(sig, blocks=(128, 256), ranges=32)[1].ranges == 32
+    for bad in (0, 3, 16):          # 8 q blocks of 512
+        with pytest.raises(ValueError, match="ranges"):
+            kd.resolve(sig, ranges=bad)
+    with pytest.raises(ValueError, match="ranges"):
+        kd.resolve(sig, impl_bwd="pallas", ranges=2)
+    assert kd.resolve(sig, impl_bwd="pallas", ranges=1)[1].ranges == 1
+    # (2048, 2048) at group 4: 16,384 folded rows' tiles are past the cap
+    assert kd.fused_vmem_bytes(sig, 2, (2048, 2048)) > kd.FUSED_VMEM_CAP_BYTES
+    assert kd.resolve(sig, blocks=(2048, 2048))[1].ranges == 2
+    assert kd.choose_ranges(_sig(1536, 16, 16, 128), (1024, 512)) == 1
+    assert kd.resolve(_sig(1536, 16, 16, 128), blocks=(1024, 512), ranges=1)[1].ranges == 1
+    # the call takes the pin, and refuses a count the sequence does not take
+    q, k, v = _qkv(s=128)
+    grads = [jax.grad(lambda q: flash_attention(
+        q, k, v, causal=True, interpret=True, block_q=32, block_k=64,
+        ranges=r).sum())(q) for r in (1, 2, 4)]
+    for g in grads[1:]:
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(grads[0]))
+    with pytest.raises(ValueError, match="ranges=3"):
+        flash_attention(q, k, v, causal=True, interpret=True, block_q=32,
+                        block_k=64, ranges=3)
 
 
 @pytest.mark.parametrize("name,sig,blocks", [
@@ -356,11 +423,12 @@ def test_fused_is_a_backward_implementation_only():
     """``impl_bwd=`` pins either backward against the shape, "fused" and
     "pallas" (the pair), and nothing else; the forward has one kernel and no
     argument to pin."""
-    sig = _sig(32768, 32, 8, 64)        # from the shape: the pair
+    sig = _sig(32768, 32, 8, 64)        # from the shape: fused, in two ranges
     fwd, bwd = kd.resolve(sig, impl_bwd="fused")
     assert (fwd.impl, bwd.impl) == ("pallas", "fused")
+    assert (fwd, bwd) == kd.resolve(sig)
     assert (bwd.block_q, bwd.block_k) == kd.choose_blocks(sig, "fused")
-    small = _sig(4096, 32, 8, 128)      # from the shape: fused
+    small = _sig(4096, 32, 8, 128)      # from the shape: fused, in one
     assert kd.resolve(small, impl_bwd="pallas")[1].impl == kd.IMPL_PALLAS
     for unknown in ("xla", "folded", "fwd"):
         with pytest.raises(ValueError, match="impl_bwd"):
@@ -368,7 +436,10 @@ def test_fused_is_a_backward_implementation_only():
     q, k, v = _qkv(s=64)
     with pytest.raises(TypeError):
         flash_attention(q, k, v, causal=True, interpret=True, impl_fwd="xla")
-    assert kd.describe(fwd, bwd) == "attn[fwd=pallas@256x512,bwd=fused@512x512]"
+    assert kd.describe(fwd, bwd) == "attn[fwd=pallas@256x512,bwd=fused@512x512/r2]"
+    assert kd.describe(*kd.resolve(small)) == "attn[fwd=pallas@256x512,bwd=fused@512x512]"
+    assert kd.describe(*kd.resolve(sig, impl_bwd="pallas")) == (
+        "attn[fwd=pallas@256x512,bwd=pallas@256x512]")
 
 
 def test_the_fused_estimate_grows_with_the_queries():
@@ -393,16 +464,16 @@ def test_blocks_past_the_default_vmem_ask_for_their_own_limit():
 
 
 def test_heuristic_boundaries():
-    """The one rule left: the fused backward up to FUSED_VMEM_CAP_BYTES of
-    its estimate, the pair past it. At group 4 and head 128 the float32 dQ
-    is 2 KiB a token: 20,480 tokens are inside, 24,576 outside; head size,
-    sequence length of the keys, window and softcap do not move the
-    forward, which is one kernel."""
+    """The one rule left: the fused backward in one walk up to
+    FUSED_VMEM_CAP_BYTES of its estimate, in ranges past it. At group 4 and
+    head 128 the float32 dQ is 2 KiB a token: 20,480 tokens are inside,
+    24,576 outside (two ranges); head size, sequence length of the keys,
+    window and softcap do not move the forward, which is one kernel."""
     inside, outside = _sig(20480, 32, 8, 128), _sig(24576, 32, 8, 128)
     assert kd.fused_vmem_bytes(inside) <= kd.FUSED_VMEM_CAP_BYTES
     assert kd.fused_vmem_bytes(outside) > kd.FUSED_VMEM_CAP_BYTES
-    assert kd.resolve(inside)[1].impl == kd.IMPL_FUSED
-    assert kd.resolve(outside)[1].impl == kd.IMPL_PALLAS
+    assert kd.resolve(inside)[1] == kd.Decision(kd.IMPL_FUSED, 512, 512, 1)
+    assert kd.resolve(outside)[1] == kd.Decision(kd.IMPL_FUSED, 512, 512, 2)
     for sig in (_bench_sig(q_shape=(8, 512, 16, 64), seq_k=512),
                 _bench_sig(q_shape=(8, 1024, 8, 128)), _bench_sig(window=256),
                 _bench_sig(), inside, outside):
@@ -422,7 +493,7 @@ def test_resolution_reads_the_shape_and_nothing_else(monkeypatch):
     notes = kd.resolved_note()
     monkeypatch.undo()
     assert not reads and notes
-    assert [d[1].impl for d in decisions] == ["fused", "pallas"]
+    assert [(d[1].impl, d[1].ranges) for d in decisions] == [("fused", 1), ("fused", 2)]
     assert not {"os", "json", "open"} & set(vars(kd))
 
 
@@ -431,7 +502,10 @@ def test_describe_and_resolved_note():
     assert note == "attn[fwd=pallas@1024x1024,bwd=fused@512x512]"
     assert kd.describe(*kd.resolve(_bench_sig())) == note
     assert kd.resolved_note(batch=1, seq=32768, heads=32, kv_heads=8) == (
-        "attn[fwd=pallas@256x512,bwd=pallas@256x512]")
+        "attn[fwd=pallas@256x512,bwd=fused@512x512/r2]")
+    assert kd.resolved_note(batch=1, seq=32768, heads=16, kv_heads=2,
+                            head_dim=256) == (
+        "attn[fwd=pallas@128x512,bwd=fused@256x512/r8]")
 
 
 # ---------------------------------------------------------------------------
@@ -681,5 +755,6 @@ def test_env_report_includes_dispatch_lines():
     rep = debug_report()
     assert "attn dispatch @ bench shape" in rep
     assert "attn[fwd=pallas@1024x1024,bwd=fused@512x512]" in rep
+    assert "attn[fwd=pallas@128x512,bwd=fused@256x512/r8]" in rep
     assert "attn dispatch table" not in rep
     assert "flash-attention variant" not in rep

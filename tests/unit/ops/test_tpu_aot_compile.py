@@ -1,6 +1,7 @@
 """The main path's Pallas kernels, compiled for a described (not attached)
 TPU v5e at Mistral-7B and OLMoE widths: paged attention, flash forward and
-backward at thirteen shapes, the fused backward at the cells' calls, rms_norm,
+backward at thirteen shapes, the fused backward at the cells' calls (walked in
+query ranges past its VMEM cap), rms_norm,
 the grouped matmuls, and one layer of the dense and the OLMoE cell's kind
 under the program's scopes. ``aot_v5e.py`` has what these files share and
 why a topology is described in a fixture; the families with kernels of their
@@ -61,7 +62,7 @@ def test_paged_attention_compiles(one_chip, no_compile_cache, n_new, window,
     (1536, None, 16, 16, D),    # group 1, a sequence 1024 does not divide
     (8192, None, 32, 8, 64),    # LFM2: head size 64, group 4, 8,192 keys
     (16384, None, 32, 8, 64),   # Granite: 16,384 keys, a 32 MiB dQ accumulator
-    (32768, None, 32, 8, 64),   # past the fused backward's cap: the pair
+    (32768, None, 32, 8, 64),   # past the cap whole: two ranges of 16,384
     (4096, WINDOW, H, KV, "fp32"),  # float32 operands: twice the tiles
 ])
 def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
@@ -69,13 +70,13 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
     """The blocks ``kernel_dispatch.choose_blocks`` picks for each shape fit
     the chip's VMEM and tile, and so does the backward the shape resolves
     to: the fused kernel with its whole-sequence dQ accumulator, or past the
-    cap the dq + dk/dv pair; a refusal here costs no chip time."""
+    cap with one query range's; a refusal here costs no chip time."""
     from deepspeed_tpu.ops import kernel_dispatch as kd
     dtype, d = (jnp.float32, D) if d == "fp32" else (jnp.bfloat16, d)
     sig = kd.make_sig((1, seq, heads, d), kv, seq, jnp.dtype(dtype).name, True,
                       window, None)
-    fused = kd.resolve(sig)[1].impl == kd.IMPL_FUSED
-    assert fused == (seq < 32768)
+    dec = kd.resolve(sig)[1]
+    assert (dec.impl, dec.ranges) == (kd.IMPL_FUSED, 1 if seq < 32768 else 2)
 
     def sds(n):
         return _sds((1, seq, n, d), dtype, one_chip)
@@ -88,8 +89,7 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, no_compile_cache, seq,
     compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
                         sds(heads), sds(kv), sds(kv))
     names = sorted(n.split(".")[0] for n in _custom_call_names(compiled))
-    assert names == (["flash_dkdv_dq", "flash_fwd"] if fused else
-                     ["flash_dkdv", "flash_dq", "flash_fwd"]), names
+    assert names == ["flash_dkdv_dq", "flash_fwd"], names
 
 
 def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
@@ -139,21 +139,27 @@ def test_flash_kernels_compile_at_the_olmoe_cells_shape(one_chip,
     ("train-olmoe-1chip-seq4k", 4, 4096, None, 16, 16, D),
     ("train-lfm2moe-1chip-seq8k", 4, 8192, None, 32, 8, 64),
     ("train-granite4hm-1chip-longseq", 1, 16384, None, 32, 8, 64),
+    # past the cap whole: eight ranges of 4,096 queries, two of 16,384
+    ("train-qwen3next-1chip-gdn-longseq", 1, 32768, None, 16, 2, 256),
+    ("32k-tokens-at-group-4-and-head-64", 1, 32768, None, 32, 8, 64),
 ])
 def test_the_fused_backward_compiles_at_the_cells_shapes(
         one_chip, no_compile_cache, cell, batch, seq, window, heads, kv, d):
     """Each cell's attention call and its gradient: the backward is one
     ``flash_dkdv_dq`` call that asks for what ``flash_vmem_bytes`` estimates
-    and a quarter more, within the cap's 80 MiB, and is given no more than it
-    asked for; dQ leaves it in the input dtype (no float32 dQ in HBM), and
-    nothing in the program is laid out as the pair's ``f32[.., seq, 1]``
-    delta column."""
+    (of one query range, where the shape walks in several) and a quarter
+    more, within the cap's 80 MiB, and is given no more than it asked for;
+    dQ leaves it in the input dtype (no float32 dQ in HBM), first of the
+    results where the ranges' float32 partials of dK and dV follow it (what
+    ``benchmark/flash_cost.py`` reads the call's shape off), and nothing in
+    the program is laid out as the pair's ``f32[.., seq, 1]`` delta column."""
     from deepspeed_tpu.ops import kernel_dispatch as kd
     sig = kd.make_sig((batch, seq, heads, d), kv, seq, "bfloat16", True,
                       window, None)
     dec = kd.resolve(sig)[1]
     assert dec.impl == kd.IMPL_FUSED
-    est = kd.fused_vmem_bytes(sig)
+    assert dec.ranges == {256: 8, 64: 2}[d] if seq == 32768 else dec.ranges == 1
+    est = kd.fused_vmem_bytes(sig, dec.ranges)
     assert est <= kd.FUSED_VMEM_CAP_BYTES
 
     def loss(q, k, v):
@@ -182,8 +188,16 @@ def test_the_fused_backward_compiles_at_the_cells_shapes(
         assert limit <= 80 * 2**20 and end(given) <= end(asked) <= 128 * 2**20
     group, bkv = heads // kv, batch * kv
     result = bwd.split(" = ")[1].split(" custom-call(")[0]
-    assert f"bf16[{bkv},{group},{seq},{d}]" in result and "f32[" not in result
+    assert f"bf16[{bkv},{group},{seq},{d}]" in result
+    if dec.ranges == 1:
+        assert "f32[" not in result
+    else:
+        assert result.startswith(f"(bf16[{bkv},{group},{seq},{d}]")
+        assert result.count(f"f32[{bkv},{dec.ranges},{seq},{d}]") == 2
+        assert result.count("f32[") == 2
     assert f"f32[{bkv},{group},{seq},1]" not in bwd
+    assert sorted(n.split(".")[0] for n in _custom_call_names(compiled)) == [
+        "flash_dkdv_dq", "flash_fwd"]
 
 
 def test_rms_norm_compiles(one_chip, no_compile_cache):

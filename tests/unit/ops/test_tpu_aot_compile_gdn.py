@@ -92,8 +92,9 @@ def test_the_gdn_layer_scans_once_and_head_256_goes_through_the_flash_kernels(st
     assert kernels["gdn_chunk_fwd"] == 1 and kernels["gdn_chunk_bwd"] == 1, kernels
     assert kernels["causal_conv_bwd"] == 1, kernels
     assert kernels["flash_fwd"] == 1, kernels
-    assert sum(n for name, n in kernels.items()
-               if name.startswith(("flash_dq", "flash_dkdv"))) >= 1, kernels
+    # one backward call, the fused kernel, and neither kernel of the pair
+    assert kernels["flash_dkdv_dq"] == 1, kernels
+    assert not {"flash_dq", "flash_dkdv"} & set(kernels), kernels
     plan = next(iter(remat._PLANS.values()))
     assert remat.GDN_SCAN in plan[0], plan
     rows, seq = step["rows"], step["seq"]
