@@ -249,6 +249,21 @@ class LlamaConfig:
     diffusion_block_length: int = 4
     diffusion_mask_id: Optional[int] = None
     diffusion_t_min: float = 1e-3
+    # A looped stack (Ouro's LoopLM, ``total_ut_steps``): the layers are
+    # applied this many times over the SAME parameters; the one final norm
+    # runs after every pass and its output is what the next pass reads, and
+    # the head reads every pass's normed stream. 1 = every layer once.
+    total_ut_steps: int = 1
+    # a ``hidden -> 1`` exit gate (``early_exit_gate``, with a bias) reads the
+    # normed stream of every pass but the last: ``lambda_t = sigmoid(g_t)``, a
+    # token's exit distribution ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``,
+    # the last pass taking what is left
+    exit_gate: bool = False
+    # the weight beta of the exit distribution's entropy in the training loss
+    # ``mean_tokens [sum_t p_t CE_t - beta H(p)]`` (the LoopLM paper's Stage I
+    # objective). None: the loss is the last pass's CE alone, which is what
+    # the published ``early_exit_threshold`` 1 makes of HF's ``labels`` path
+    exit_entropy_weight: Optional[float] = None
     attn_impl: str = "auto"       # "auto" | "flash" (Pallas) | "xla"
     dtype: Any = jnp.bfloat16
     scan_layers: bool = False
@@ -282,6 +297,18 @@ class LlamaConfig:
     @property
     def head_dim_(self):
         return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def looped_(self) -> bool:
+        """Whether the model makes a stream a pass and not one."""
+        return self.total_ut_steps > 1 or self.exit_gate
+
+    @property
+    def exit_loss_(self) -> bool:
+        """Whether the training loss is the exit distribution's (a gate to
+        read and an entropy weight), not the last pass's CE alone."""
+        return (self.exit_gate and self.total_ut_steps > 1
+                and self.exit_entropy_weight is not None)
 
     @property
     def experts_held_(self) -> int:
@@ -1694,10 +1721,14 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
     alone on a backend that reports no memory), or None where no name
     chooses: no recomputation, or a named policy. The prices are the layers'
     own named values, read off a trace of each kind of layer at ``x``'s
-    shape."""
+    shape. A looped stack (``total_ut_steps`` passes) is planned an
+    APPLICATION of a layer at a time, pass by pass: row ``t * N + i`` is layer
+    ``i`` in pass ``t``, since each holds its own input and kept values until
+    its own backward."""
     if not cfg.remat or cfg.remat_policy:
         return None
     specs = cfg.layer_specs or (None, ) * cfg.num_hidden_layers
+    passes = cfg.total_ut_steps
 
     def prices_of():
         abstract = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
@@ -1708,7 +1739,7 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
                     LlamaDecoderLayer(cfg, i, parent=None).init, jax.random.PRNGKey(0),
                     abstract(x), cos, sin, abstract(positions), attn_mask,
                     *_shared_like(cfg, spec, x))
-        return [by_kind[spec] + scan_price(spec) for spec in specs]
+        return [by_kind[spec] + scan_price(spec) for spec in specs] * passes
 
     def scan_price(spec):
         # a scan kernel's output and states are named inside its forward
@@ -1746,11 +1777,14 @@ def _kept_plan(cfg, x, cos, sin, positions, attn_mask):
         if spec is not None and i in sources:        # what it hands on, once
             always_kept += sum(a.size * itemsize for a in jax.tree_util.tree_leaves(
                 _shared_like(cfg, spec, x, source=True)))
+    always_kept *= passes
+    if cfg.looped_:     # each pass's normed stream, which the head reads
+        always_kept += passes * x.size * itemsize
     plan = remat.plan_for(
         (repr(cfg), x.shape), prices_of, rows=x.shape[0],
         layer_input_bytes=x.size * itemsize, always_kept_bytes=always_kept,
         same_in_all_layers=cfg.scan_layers)
-    return plan or (remat.RESIDUAL_NAMES, ) * len(specs)
+    return plan or (remat.RESIDUAL_NAMES, ) * (len(specs) * passes)
 
 
 def _shared_like(cfg, spec, x, source: bool = False) -> tuple:
@@ -1792,7 +1826,14 @@ class LlamaModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, attn_mask=None,
-                 return_unembed=False):
+                 return_unembed=False, logits_to_keep=None, all_passes=False):
+        """-> logits ``[B, S, V]``, or with ``return_unembed`` ``(x, w, b)``:
+        the final norm's output and the raw head. A looped model
+        (``LlamaConfig.looped_``) makes a stream a pass and hands back the
+        last pass's, or with ``all_passes`` every pass's: logits ``[B, T, S,
+        V]``, ``x`` ``[B, T, S, H]`` with the gates' pre-activations ``[B, T -
+        1, S]`` float32 (None without ``exit_gate``) fourth. ``logits_to_keep``
+        ``[n]``: the positions (the same in every row) whose logits are made."""
         cfg = self.config
         if positions is None:
             # block diffusion: both copies carry their tokens' own positions
@@ -1851,35 +1892,56 @@ class LlamaModel(nn.Module):
                                 in_axes=nn.broadcast,
                                 length=cfg.num_hidden_layers // cfg.scan_chunk_size,
                                 metadata_params={nn.PARTITION_NAME: "layers"})
-            with remat.keeping(kept and kept[0]):     # one body: every layer's names
-                x, _ = ScanLayer(cfg, name="layers")(x, cos, sin, positions, attn_mask)
+            scanned = ScanLayer(cfg, name="layers")
+
+            def stack(x, first):
+                with remat.keeping(kept and kept[0]):     # one body: every layer's names
+                    return scanned(x, cos, sin, positions, attn_mask)[0]
         else:
             layer_cls = _remat_layer_cls(cfg) if cfg.remat else LlamaDecoderLayer
             # what a layer hands to the layers after it beside the stream
             # (``shared_sources``): an argument of every layer that reads it,
             # so an input of its recomputation, its gradient summed over them
-            sources, handed = cfg.shared_sources(), {}
-            for i in range(cfg.num_hidden_layers):
-                reads = () if not sources or sources[i] is None else (handed[sources[i]], )
-                with remat.keeping(kept and kept[i]):
-                    x = layer_cls(cfg, i, name=f"layers_{i}")(x, cos, sin, positions,
-                                                              attn_mask, *reads)
-                if i in sources:
-                    x, handed[i] = x
-        if cfg.block_diffusion_:
-            # the clean copy carries no loss: only the noisy half is normed
-            # and reaches the head
-            x = x[:, :x.shape[1] // 2]
-        x = _make_norm(cfg, "norm")(x)
+            sources = cfg.shared_sources()
+            layers = [layer_cls(cfg, i, name=f"layers_{i}")
+                      for i in range(cfg.num_hidden_layers)]
+
+            def stack(x, first):
+                handed = {}
+                for i, layer in enumerate(layers):
+                    reads = () if not sources or sources[i] is None else (handed[sources[i]], )
+                    with remat.keeping(kept and kept[first + i]):
+                        x = layer(x, cos, sin, positions, attn_mask, *reads)
+                    if i in sources:
+                        x, handed[i] = x
+                return x
+
+        norm = _make_norm(cfg, "norm")
+        gates = None
+        if cfg.looped_:
+            x, gates = _passes(self, stack, norm, x)
+        else:
+            x = stack(x, 0)
+            if cfg.block_diffusion_:
+                # the clean copy carries no loss: only the noisy half is normed
+                # and reaches the head
+                x = x[:, :x.shape[1] // 2]
+            x = norm(x)
+        if logits_to_keep is not None:
+            x = jnp.take(x, logits_to_keep, axis=-2)
+        every = cfg.looped_ and all_passes
+        if cfg.looped_ and not every:
+            x = x[:, -1]
         if return_unembed:
             # chunked-CE path (ops/chunked_ce.py): hand back the raw unembed
             # weight [H, V] (+bias) instead of materialized logits; scale and
             # softcap are applied per chunk inside the op
             if cfg.tie_word_embeddings:
-                return x, embed.embedding.T, None
+                w = embed.embedding.T
+                return (x, w, None, gates) if every else (x, w, None)
             w, b = LMHead(cfg.vocab_size, cfg.dtype, use_bias=cfg.lm_head_bias,
                           name="lm_head")(x, return_params=True)
-            return x, w, b
+            return (x, w, b, gates) if every else (x, w, b)
         # unembed: bf16 inputs ride the MXU fast path (fp32 matmul is several×
         # slower), but the accumulator stays fp32 and the *output* is emitted
         # fp32 (preferred_element_type) — rounding logits to bf16 before the
@@ -1898,6 +1960,32 @@ class LlamaModel(nn.Module):
             cap = jnp.float32(cfg.final_logit_softcapping)
             logits = cap * jnp.tanh(logits / cap)
         return logits
+
+
+def _passes(model, stack, norm, x):
+    """A looped stack inside ``LlamaModel``: ``total_ut_steps`` passes over the
+    same layers (``stack(x, first row of the plan)``), the ONE final norm
+    after each, whose output the next pass reads and the head too -> (the
+    normed streams ``[B, T, S, H]``, the exit gates' pre-activations ``[B, T
+    - 1, S]`` float32 of every pass but the last, which is not read; None
+    without ``exit_gate``)."""
+    cfg = model.config
+    if cfg.block_diffusion_:
+        raise ValueError("a looped stack under block diffusion is not built")
+    passes, streams, gates = cfg.total_ut_steps, [], []
+    gate = None
+    if cfg.exit_gate and passes > 1:
+        gate = nn.Dense(1, dtype=jnp.float32, name="early_exit_gate", parent=model,
+                        kernel_init=nn.initializers.normal(0.02))
+    with sown.repeated(passes):
+        for t in range(passes):
+            with jax.named_scope(f"ds.loop.pass{t}"):
+                x = norm(stack(x, t * cfg.num_hidden_layers))
+                streams.append(x)
+                if gate is not None and t < passes - 1:
+                    with jax.named_scope("ds.loop.exit"):
+                        gates.append(gate(x)[..., 0])
+    return jnp.stack(streams, axis=1), jnp.stack(gates, axis=1) if gates else None
 
 
 def cross_entropy_loss(logits, labels, ignore_index: int = -100, weights=None):
@@ -1936,8 +2024,15 @@ class LlamaForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, positions=None, attn_mask=None,
-                 loss_weights=None):
+                 loss_weights=None, logits_to_keep=None, all_passes=False):
         cfg = self.config
+        if labels is not None and (logits_to_keep is not None or all_passes):
+            raise ValueError("logits_to_keep and all_passes are for a call without labels")
+        if cfg.exit_loss_ and labels is not None:
+            if loss_weights is not None:
+                raise ValueError("the exit distribution's loss weighs each token "
+                                 "itself: loss_weights are not its argument")
+            return _exit_loss(self, input_ids, labels, positions, attn_mask)
         if cfg.block_diffusion_ and labels is not None:
             if loss_weights is None:
                 raise ValueError("the block-diffusion loss needs loss_weights "
@@ -1962,11 +2057,79 @@ class LlamaForCausalLM(nn.Module):
                     logit_scale=cfg.logit_scale,
                     softcap=cfg.final_logit_softcapping,
                     compute_dtype=cfg.dtype, weights=loss_weights)
-        logits = LlamaModel(cfg, name="model")(input_ids, positions, attn_mask)
+        logits = LlamaModel(cfg, name="model")(input_ids, positions, attn_mask,
+                                               logits_to_keep=logits_to_keep,
+                                               all_passes=all_passes)
         if labels is None:
             return logits
         with jax.named_scope("ds.head.loss"):
             return cross_entropy_loss(logits, labels, weights=loss_weights)
+
+
+def exit_distribution(gates):
+    """``gates`` ``[B, T - 1, S]``, the exit gates' pre-activations of every
+    pass but the last -> ``(p [B, T, S], H(p) [B, S])`` in float32: ``lambda_t
+    = sigmoid(g_t)``, ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``, the last
+    pass taking what is left, ``prod_j (1 - lambda_j)``; ``H(p) = -sum_t p_t
+    log p_t``. In logs: ``log p_t = log_sigmoid(g_t) + sum_{j<t}
+    log_sigmoid(-g_j)``, so that no mass underflows to a ``0 * -inf``."""
+    g = gates.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=1)       # log prod_{j<=t} (1 - lambda_j)
+    before = jnp.pad(stay, ((0, 0), (1, 0), (0, 0)))        # ... prod_{j<t}
+    log_p = before + jnp.pad(jax.nn.log_sigmoid(g), ((0, 0), (0, 1), (0, 0)))
+    p = jnp.exp(log_p)
+    return p, -(p * log_p).sum(axis=1)
+
+
+def _exit_loss(module, input_ids, labels, positions, attn_mask):
+    """The training loss of a looped model with an exit gate and
+    ``exit_entropy_weight`` beta (``LlamaConfig.exit_loss_``), inside
+    ``LlamaForCausalLM``: ``mean over the counted positions of [sum_t p_t CE_t
+    - beta H(p)]``, ``p`` carrying a gradient through both terms; sows the
+    ``loop`` family."""
+    cfg = module.config
+    x, w, b, gates = LlamaModel(cfg, name="model", parent=module)(
+        input_ids, positions, attn_mask, return_unembed=True, all_passes=True)
+    with jax.named_scope("ds.loop.exit"):
+        p, entropy = exit_distribution(gates)
+    with jax.named_scope("ds.head.loss"):
+        if cfg.ce_chunk_size:
+            from ..ops.chunked_ce import chunked_exit_cross_entropy
+            loss, nll, counted = chunked_exit_cross_entropy(
+                x, w, b, labels, p, cfg.ce_chunk_size, logit_scale=cfg.logit_scale,
+                softcap=cfg.final_logit_softcapping, compute_dtype=cfg.dtype)
+        else:
+            loss, nll, counted = _dense_exit_cross_entropy(cfg, x, w, b, labels, p)
+    with jax.named_scope("ds.loop.exit"):
+        n = jnp.maximum(counted.sum(), 1.0)
+        entropy = (entropy * counted).sum() / n
+        if sown.wanted(module, "loop"):
+            passes = lambda a: (a * counted[:, None]).sum(axis=(0, 2)) / n   # noqa: E731
+            sown.sow(module, "loop", jax.lax.stop_gradient(
+                {"exit_mass": passes(p), "ce": passes(nll), "exit_entropy": entropy}))
+        return loss - jnp.float32(cfg.exit_entropy_weight) * entropy
+
+
+def _dense_exit_cross_entropy(cfg, x, w, b, labels, weights, ignore_index: int = -100):
+    """``ops.chunked_ce.chunked_exit_cross_entropy`` with the logits whole:
+    the same three values."""
+    logits = jax.lax.dot_general(x.astype(cfg.dtype), w.astype(cfg.dtype),
+                                 (((3, ), (0, )), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    if b is not None:
+        logits = logits + b
+    if cfg.logit_scale is not None:
+        logits = logits * jnp.float32(cfg.logit_scale)
+    if cfg.final_logit_softcapping is not None:
+        cap = jnp.float32(cfg.final_logit_softcapping)
+        logits = cap * jnp.tanh(logits / cap)
+    targets = jnp.pad(labels[:, 1:], ((0, 0), (0, 1)), constant_values=ignore_index)
+    counted = (targets != ignore_index).astype(jnp.float32)
+    targets = jnp.where(targets == ignore_index, 0, targets)
+    gold = jnp.take_along_axis(logits, targets[:, None, :, None], axis=-1)[..., 0]
+    nll = (jax.nn.logsumexp(logits, axis=-1) - gold) * counted[:, None]
+    loss = (nll * weights.astype(jnp.float32)).sum() / jnp.maximum(counted.sum(), 1.0)
+    return loss, jax.lax.stop_gradient(nll), counted
 
 
 def unbox_params(params):

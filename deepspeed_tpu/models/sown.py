@@ -10,6 +10,8 @@ reductions, the publish and ``engine.sown_stats(family)`` all come from here
 (docs/observability.md, "What an operator sows for the host").
 """
 
+import contextlib
+import contextvars
 import functools
 import operator
 from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple
@@ -22,6 +24,9 @@ class OverLayers(NamedTuple):
     """How a statistic becomes one value of a step."""
     within: Callable    # two calls of one module: the sow's ``reduce_fn``
     across: Callable    # the sown leaves of every layer, in the tree's order
+    # a mean: each call of a module that is called ``repeated`` times sows its
+    # share, so that ``within`` (a sum) leaves the calls' mean
+    averaged: bool = False
 
 
 def _flat(leaves):
@@ -34,7 +39,7 @@ def _summed(each):
 
 
 MAX = OverLayers(jnp.maximum, lambda leaves: jnp.max(_flat(leaves)))
-MEAN = OverLayers(operator.add, lambda leaves: jnp.mean(_flat(leaves)))
+MEAN = OverLayers(operator.add, lambda leaves: jnp.mean(_flat(leaves)), averaged=True)
 SUM = OverLayers(operator.add, _summed(jnp.sum))
 # kept a layer, in the layers' order (a sum over a deep model may pass 32 bits:
 # the host adds them up)
@@ -55,6 +60,12 @@ def steps_mean(values):
     return np.mean([np.mean(v) for v in values])
 
 
+def steps_mean_kept(values):
+    # a vector a step (a position a pass): the steps' mean, the positions kept
+    return np.mean([np.asarray(v).reshape(-1, np.shape(v)[-1]).mean(axis=0)
+                    for v in values], axis=0)
+
+
 def last_step(values):
     return np.mean(values[-1])
 
@@ -71,10 +82,23 @@ class Gauge(NamedTuple):
     source: str                 # a statistic of the family, or a key of its ``derive``
     steps: Optional[Callable]   # over the steps of a publish; None: ``derive`` made it
 
+    # a vector's positions as series of one name: the label each is told by
+    label: Optional[str] = None
+
     @property
     def counter(self) -> bool:
         """A counter the value is added to, not a gauge set to it."""
         return self.steps is steps_total
+
+    def publish(self, registry, value) -> None:
+        """``value`` (of a publish's steps) into the registry's series."""
+        labelled = ([(None, value)] if self.label is None else
+                    [({self.label: str(i)}, v) for i, v in enumerate(np.ravel(value))])
+        for labels, v in labelled:
+            if self.counter:
+                registry.counter(self.name, self.help, labels).inc(float(v))
+            else:
+                registry.gauge(self.name, self.help, labels).set(float(v))
 
 
 class Family(NamedTuple):
@@ -116,6 +140,22 @@ def _declared(family) -> Family:
     return family if isinstance(family, Family) else FAMILIES[family]
 
 
+# how many times the modules being traced are called in one apply (a looped
+# stack: ``models/llama.py``, ``total_ut_steps``), for ``OverLayers.averaged``
+_CALLS = contextvars.ContextVar("ds_sown_calls", default=1)
+
+
+@contextlib.contextmanager
+def repeated(calls: int):
+    """While a stack that is applied ``calls`` times over the same modules is
+    traced inside."""
+    token = _CALLS.set(calls)
+    try:
+        yield
+    finally:
+        _CALLS.reset(token)
+
+
 def wanted(module, family) -> bool:
     """Whether this apply collects the family's statistics: ask before making
     a value that costs something."""
@@ -133,6 +173,8 @@ def sow(module, family, values: dict):
     for name, how in fam.stats.items():
         if name in values:
             value = jnp.asarray(values[name])
+            if how.averaged and _CALLS.get() > 1:
+                value = value / _CALLS.get()
             module.sow(fam.collection, name, value, reduce_fn=how.within,
                        init_fn=functools.partial(jnp.zeros, value.shape, value.dtype))
 
@@ -318,4 +360,24 @@ FAMILIES = {family.name: family for family in (
                   "Mean of sigmoid(gate) over the gated softmax attention layers' "
                   "outputs, over the steps of the last publish",
                   "gate_mean", steps_mean), )),
+    Family(
+        "loop",
+        # a looped model's exits (``total_ut_steps`` passes over one stack,
+        # an exit gate after each): ``[passes]`` the mean exit mass p_t and
+        # the mean next-token CE of each pass's logits over the counted
+        # positions, and the mean entropy of p; sown once a step, by the model
+        stats={"exit_mass": A_LAYER, "ce": A_LAYER, "exit_entropy": MEAN},
+        gauges=(
+            Gauge("ds_loop_exit_mass",
+                  "Mean over the counted positions of the exit distribution's "
+                  "mass p_t on each pass of a looped model, over the steps of "
+                  "the last publish", "exit_mass", steps_mean_kept, label="pass"),
+            Gauge("ds_loop_ce",
+                  "Mean next-token cross-entropy of each pass's own logits of a "
+                  "looped model, over the steps of the last publish",
+                  "ce", steps_mean_kept, label="pass"),
+            Gauge("ds_loop_exit_entropy",
+                  "Mean entropy of the exit distribution over a looped model's "
+                  "passes (nats), over the steps of the last publish",
+                  "exit_entropy", steps_mean))),
 )}

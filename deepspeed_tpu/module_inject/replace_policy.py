@@ -1207,6 +1207,60 @@ class Gemma2Policy(GemmaPolicy):
         return out
 
 
+class OuroPolicy(HFCheckpointPolicy):
+    """Ouro (ByteDance's LoopLM, HF ``modeling_ouro.py``, ``model_type:
+    ouro``): a Llama stack whose layers carry four norms, ``x +
+    input_layernorm_2(attn(input_layernorm(x)))`` then ``a +
+    post_attention_layernorm_2(mlp(post_attention_layernorm(a)))`` (the model's
+    ``sandwich_norm`` layer, whose tree keeps Gemma-2's names: the map below
+    renames three of the four), and which is applied ``total_ut_steps`` times
+    over the same weights, the one final norm after every pass, an
+    ``early_exit_gate`` (hidden -> 1, with a bias) reading each pass's normed
+    stream. ``exit_entropy_weight`` is the training recipe's to set (the config
+    has no key of it): without it the loss is the last pass's CE, HF's
+    ``labels`` path at ``early_exit_threshold`` 1. Refused by name: a sliding
+    window, ``rope_scaling``, biases, another activation, a layer type other
+    than ``full_attention``, an exit threshold under 1 (serving's early exit
+    is not built)."""
+    arch = "ouro"
+
+    def config_from_hf(self, hf_config):
+        import dataclasses
+        hf = dict(hf_config)
+        refused = {"use_sliding_window": bool(hf.get("use_sliding_window")),
+                   "rope_scaling": bool(hf.get("rope_scaling")),
+                   "attention_bias": bool(hf.get("attention_bias")),
+                   "hidden_act": hf.get("hidden_act", "silu") != "silu",
+                   "layer_types": bool(set(hf.get("layer_types") or ())
+                                       - {"full_attention"}),
+                   "early_exit_threshold": float(hf.get("early_exit_threshold", 1.0)) < 1.0}
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(f"ouro: {key}={hf.get(key)!r} is not supported")
+        if hf.get("layer_types") and len(hf["layer_types"]) != hf["num_hidden_layers"]:
+            raise ValueError(f"ouro: {len(hf['layer_types'])} layer_types for "
+                             f"{hf['num_hidden_layers']} layers")
+        cfg = super().config_from_hf({**hf, "rms_norm_eps": hf.get("rms_norm_eps", 1e-6)})
+        return dataclasses.replace(
+            cfg, head_dim=hf.get("head_dim"), sandwich_norm=True,
+            total_ut_steps=int(hf.get("total_ut_steps", 4)), exit_gate=True)
+
+    def weight_map(self, layer: int, attention_bias: bool = False):
+        out = super().weight_map(layer, attention_bias)
+        p, f = f"model.layers.{layer}.", f"layers_{layer}/"
+        for hf_name, ours in (("input_layernorm_2", "post_attention_layernorm"),
+                              ("post_attention_layernorm", "pre_feedforward_layernorm"),
+                              ("post_attention_layernorm_2", "post_feedforward_layernorm")):
+            out[p + hf_name + ".weight"] = (f + ours + "/weight", False)
+        return out
+
+    def global_map(self, tie_embeddings: bool):
+        out = super().global_map(tie_embeddings)
+        out["model.early_exit_gate.weight"] = ("early_exit_gate/kernel", True)
+        out["model.early_exit_gate.bias"] = ("early_exit_gate/bias", False)
+        return out
+
+
 class OPTPolicy(HFCheckpointPolicy):
     """OPT (reference ``module_inject/containers/opt.py`` +
     ``inference/v2/model_implementations/opt``): learned positions (table
@@ -2209,6 +2263,8 @@ _POLICIES = {
     "Qwen2MoeForCausalLM": Qwen2MoePolicy,
     "qwen3_next": Qwen3NextPolicy,
     "Qwen3NextForCausalLM": Qwen3NextPolicy,
+    "ouro": OuroPolicy,
+    "OuroForCausalLM": OuroPolicy,
     "gemma": GemmaPolicy,
     "GemmaForCausalLM": GemmaPolicy,
     "gemma2": Gemma2Policy,

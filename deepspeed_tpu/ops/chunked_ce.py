@@ -36,6 +36,12 @@ Cohere ``logit_scale`` and Gemma-2 ``final_logit_softcapping`` are applied
 per chunk (elementwise), so the models that most need chunking keep their
 exact logit semantics.
 
+A model that reads its head T times a step (a looped stack) takes
+``chunked_exit_cross_entropy``: the T streams are rows of ONE sweep, the head
+read once a chunk, and each stream's weight at a position is an argument that
+is differentiated too: the sum is linear in it, so its cotangent is the
+position's own loss term, which the forward sweep already has.
+
 Under a mesh that shards the batch (``data``, ``fsdp``) the sweep runs in a
 ``shard_map`` over those axes: the scan slices the sequence axis, never the
 batch axis, so each device sweeps its own sequences' ``[B/n * sc, V]`` logits
@@ -90,10 +96,11 @@ def _dl_shift(compute_dtype) -> float:
 
 
 def _local_sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
-                 compute_dtype, with_grads):
-    """One scan over chunks of ``sc`` positions. Returns ``sum(mask * nll)``
-    and, ``with_grads``, its gradients: dx in x's dtype, dwᵀ ``[V, H]`` (times
-    ``_dl_shift``) and dbias in fp32 (else None)."""
+                 compute_dtype, with_grads, with_nll=False):
+    """One scan over chunks of ``sc`` positions. Returns ``sum(mask * nll)``,
+    ``with_grads`` its gradients: dx in x's dtype, dwᵀ ``[V, H]`` (times
+    ``_dl_shift``) and dbias in fp32 (else None), and ``with_nll`` every
+    position's own ``nll`` ``[B, S]`` in fp32, unmasked (else None)."""
     B, S, H = x.shape
     V = w.shape[1]
     xc_all = x.astype(compute_dtype)
@@ -115,8 +122,9 @@ def _local_sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
         hit = jax.lax.broadcasted_iota(jnp.int32, lc.shape, 1) == tg[:, None]
         gold = jnp.where(hit, lc, 0.0).sum(axis=-1)
         loss = loss + ((lse - gold) * mk).sum()
+        nll = lse - gold if with_nll else None
         if not with_grads:
-            return (loss, dx, dwt, dbias), None
+            return (loss, dx, dwt, dbias), nll
         dl = (jnp.exp(lc - lse[:, None]) - hit) * mk[:, None]
         # chain back through softcap then logit_scale (applied in that order
         # forward: scale -> softcap)
@@ -137,7 +145,7 @@ def _local_sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
             dx, dxc.astype(dx.dtype).reshape(B, sc, H), s0, axis=1)
         dwt = dwt + jax.lax.dot_general(dl, xc, (((0, ), (0, )), ((), ())),
                                         preferred_element_type=jnp.float32)
-        return (loss, dx, dwt, dbias), None
+        return (loss, dx, dwt, dbias), nll
 
     # dw is summed as its transpose [V, H], the layout XLA gives the
     # vocabulary matmuls on a TPU: summed as [H, V] a one-layer OLMoE step
@@ -146,9 +154,11 @@ def _local_sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
             jnp.zeros(x.shape, x.dtype) if with_grads else None,
             jnp.zeros((V, H), jnp.float32) if with_grads else None,
             jnp.zeros((V, ), jnp.float32) if grad_bias else None)
-    (loss, dx, dwt, dbias), _ = jax.lax.scan(
+    (loss, dx, dwt, dbias), nll = jax.lax.scan(
         step, init, jnp.arange(0, S, sc, dtype=jnp.int32))
-    return loss, ((dx, dwt, dbias) if with_grads else None)
+    if with_nll:    # [chunks, B * sc] -> [B, S]
+        nll = nll.reshape(-1, B, sc).swapaxes(0, 1).reshape(B, S)
+    return loss, ((dx, dwt, dbias) if with_grads else None), nll
 
 
 def _batch_axes(B: int):
@@ -168,34 +178,41 @@ def _batch_axes(B: int):
 
 
 def _sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
-           compute_dtype, with_grads):
+           compute_dtype, with_grads, with_nll=False):
     """``_local_sweep`` of each device's own sequences: under a mesh that
     shards the batch the scan runs inside a ``shard_map`` over those axes,
     with the head whole on every device (gathered once, as ZeRO-3 gathers a
     layer) and ``dw`` summed over the devices once, after the last chunk. Left
     to GSPMD, a ``dw`` whose rows are spread over devices is all-reduced
     inside the loop, once a chunk."""
-    static = (sc, logit_scale, softcap, compute_dtype, with_grads)
+    static = (sc, logit_scale, softcap, compute_dtype, with_grads, with_nll)
     mesh, dp = _batch_axes(x.shape[0])
     if not dp:
         return _local_sweep(x, w, bias, targets, mask, *static)
 
     def body(*local):
-        loss, grads = _local_sweep(*local, *static)
+        loss, grads, nll = _local_sweep(*local, *static)
         if grads is not None:
             dx, *sums = grads
             grads = (dx, *(None if d is None else jax.lax.psum(d, dp)
                            for d in sums))
-        return jax.lax.psum(loss, dp), grads
+        return jax.lax.psum(loss, dp), grads, nll
 
     rows, whole = P(dp), P()
     # jitted for a caller outside any jit: a partly manual shard_map (the
     # mesh's other axes stay GSPMD's) only lowers under one
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(rows, whole, whole, rows, rows),
-        out_specs=(whole, (rows, whole, whole) if with_grads else None),
+        out_specs=(whole, (rows, whole, whole) if with_grads else None,
+                   rows if with_nll else None),
         axis_names=frozenset(dp), check_vma=False))(
             x, w.astype(compute_dtype), bias, targets, mask)   # gathered as cast
+
+
+def _dtypes_of(w, bias):
+    # the backward casts to the weights' dtypes, which it learns from these
+    return tuple(None if a is None else jnp.zeros((0, ), a.dtype)
+                 for a in (w, bias))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
@@ -212,12 +229,9 @@ def _mean_ce(x, w, bias, targets, mask, inv_n, sc, logit_scale, softcap,
 
 def _ce_fwd(x, w, bias, targets, mask, inv_n, sc, logit_scale, softcap,
             compute_dtype):
-    loss, grads = _sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
-                         compute_dtype, True)
-    # the backward casts to the weights' dtypes, which it learns from these
-    like = tuple(None if a is None else jnp.zeros((0, ), a.dtype)
-                 for a in (w, bias))
-    return inv_n * loss, (grads, inv_n, like)
+    loss, grads, _ = _sweep(x, w, bias, targets, mask, sc, logit_scale, softcap,
+                            compute_dtype, True)
+    return inv_n * loss, (grads, inv_n, _dtypes_of(w, bias))
 
 
 def _ce_bwd(sc, logit_scale, softcap, compute_dtype, res, g):
@@ -231,6 +245,49 @@ def _ce_bwd(sc, logit_scale, softcap, compute_dtype, res, g):
 
 
 _mean_ce.defvjp(_ce_fwd, _ce_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _weighted_ce(x, w, bias, targets, weights, inv_n, sc, logit_scale, softcap,
+                 compute_dtype):
+    """-> ``(inv_n * sum(weights * nll), nll [B, S])``: ``_mean_ce`` with the
+    weights an argument that is differentiated too. The sum is linear in
+    them, so a weight's cotangent is ``inv_n`` times its position's own
+    ``nll``, which the forward sweep has. ``nll`` comes back for the host's
+    statistics and as that residual; its own cotangent is dropped (it is a
+    reading, not a term of the loss)."""
+    loss, _, nll = _sweep(x, w, bias, targets, weights, sc, logit_scale, softcap,
+                          compute_dtype, False, True)
+    return inv_n * loss, nll
+
+
+def _wce_fwd(x, w, bias, targets, weights, inv_n, sc, logit_scale, softcap,
+             compute_dtype):
+    loss, grads, nll = _sweep(x, w, bias, targets, weights, sc, logit_scale,
+                              softcap, compute_dtype, True, True)
+    return (inv_n * loss, nll), (grads, nll, inv_n, _dtypes_of(w, bias))
+
+
+def _wce_bwd(sc, logit_scale, softcap, compute_dtype, res, g):
+    grads, nll, inv_n, like = res
+    dx, dw, dbias, *_ = _ce_bwd(sc, logit_scale, softcap, compute_dtype,
+                                (grads, inv_n, like), g[0])
+    return dx, dw, dbias, None, g[0].astype(jnp.float32) * inv_n * nll, None
+
+
+_weighted_ce.defvjp(_wce_fwd, _wce_bwd)
+
+
+def _shifted_targets(labels, pad: int, ignore_index: int):
+    """The shift as shifted targets: position s predicts labels[s + 1], the
+    last position predicts nothing (weight 0), and S stays whole; so does a
+    tail padded up to a multiple of sc. -> (targets in range, which positions
+    count as fp32, one over their number)."""
+    tg = jnp.pad(labels[:, 1:], ((0, 0), (0, 1 + pad)),
+                 constant_values=ignore_index)
+    mask = (tg != ignore_index).astype(jnp.float32)
+    tg = jnp.where(tg == ignore_index, 0, tg)
+    return tg, mask, 1.0 / jnp.maximum(mask.sum(), 1.0)
 
 
 def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
@@ -252,14 +309,7 @@ def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
     sc = seq_chunk(S, chunk, w.shape[1])
     pad = -S % sc
     if weights is None:
-        # the shift as shifted targets: position s predicts labels[s + 1],
-        # the last position predicts nothing (weight 0), and S stays whole;
-        # so does a tail padded up to a multiple of sc
-        tg = jnp.pad(labels[:, 1:], ((0, 0), (0, 1 + pad)),
-                     constant_values=ignore_index)
-        mask = (tg != ignore_index).astype(jnp.float32)
-        tg = jnp.where(tg == ignore_index, 0, tg)
-        inv_n = 1.0 / jnp.maximum(mask.sum(), 1.0)
+        tg, mask, inv_n = _shifted_targets(labels, pad, ignore_index)
     else:
         tg = jnp.pad(labels, ((0, 0), (0, pad)))
         mask = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pad)))
@@ -268,3 +318,41 @@ def chunked_cross_entropy_loss(x, w, bias, labels, chunk: int,
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     return _mean_ce(x, w, bias, tg, mask, inv_n, sc, logit_scale, softcap,
                     compute_dtype)
+
+
+def chunked_exit_cross_entropy(xs, w, bias, labels, weights, chunk: int,
+                               ignore_index: int = -100,
+                               logit_scale: Optional[float] = None,
+                               softcap: Optional[float] = None,
+                               compute_dtype=jnp.bfloat16):
+    """The loss of a model that reads its head T times a step (a looped
+    stack: ``models/llama.py``, ``total_ut_steps``): ``xs`` [B, T, S, H] the T
+    streams, ``weights`` [B, T, S] (float) what each stream's term of a
+    position counts, an argument that is DIFFERENTIATED as ``xs`` is (an exit
+    distribution made of the model's own gates). Shift-by-one as without
+    weights: position s of every stream predicts ``labels[s + 1]``, the last
+    position and ``ignore_index`` predict nothing. -> ``(sum over streams and
+    counted positions of weights * CE / the counted positions, CE [B, T, S]
+    of every stream and position in fp32 (0 where nothing is counted),
+    counted [B, S])``.
+
+    The T streams go through ONE sweep, a row of the batch each: a chunk is
+    the ``B * T * sc`` rows of every stream at the same positions, so the head
+    is read once a chunk and not once a stream and chunk; ``sc`` is chosen for
+    ``chunk / T``, which leaves the transient logits at ``B * S * chunk``
+    elements."""
+    B, T, S, H = xs.shape
+    sc = seq_chunk(S, max(1, chunk // T), w.shape[1])
+    pad = -S % sc
+    tg, counted, inv_n = _shifted_targets(labels, pad, ignore_index)
+    if pad:
+        xs = jnp.pad(xs, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, 0), (0, 0), (0, pad)))
+    rows = lambda a: a.reshape(B * T, *a.shape[2:])     # noqa: E731
+    each = lambda a: jnp.broadcast_to(a[:, None], (B, T, S + pad))  # noqa: E731
+    loss, nll = _weighted_ce(
+        rows(xs), w, bias, rows(each(tg)),
+        rows(weights.astype(jnp.float32) * counted[:, None]), inv_n, sc,
+        logit_scale, softcap, compute_dtype)
+    nll = jax.lax.stop_gradient(nll).reshape(B, T, S + pad) * counted[:, None]
+    return loss, nll[..., :S], counted[:, :S]

@@ -1718,12 +1718,8 @@ class DeepSpeedTpuEngine:
                     value = gauge.steps([step[gauge.source] for step in steps])
                 else:
                     continue
-                if value is None:
-                    continue
-                if gauge.counter:
-                    reg.counter(gauge.name, gauge.help).inc(float(value))
-                else:
-                    reg.gauge(gauge.name, gauge.help).set(float(value))
+                if value is not None:
+                    gauge.publish(reg, value)
         if not self._kernel_line_logged:
             # once, after the first step that sowed was traced: which grouped
             # matmul this engine's call sites took, by pass (what

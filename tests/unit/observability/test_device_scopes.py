@@ -195,8 +195,12 @@ def test_named_scope_goes_through_one_helper_in_the_engine():
                             '"ds.mla.assemble"', '"ds.mla.gate"', '"ds.kda.gates"',
                             '"ds.gdn.split"', '"ds.gdn.split"', '"ds.gdn.gates"',
                             '"ds.moe.route"', '"ds.moe.shared"',
-                            '"ds.head.loss"', '"ds.head.loss"', '"ds.selscan.dt"',
-                            '"ds.gmu.gate"'],
+                            # a looped stack: a scope a pass, the exit gate
+                            'f"ds.loop.pass{t}"', '"ds.loop.exit"',
+                            '"ds.head.loss"', '"ds.head.loss"',
+                            # the exit loss: p, the head's sweep, the entropy
+                            '"ds.loop.exit"', '"ds.head.loss"', '"ds.loop.exit"',
+                            '"ds.selscan.dt"', '"ds.gmu.gate"'],
         "ops/dsa_attention.py": ['"ds.dsa.select"', '"ds.dsa.select"'],
         # the kernels' small operands and what comes back for them; XLA's
         # norms and mean decay around the recurrence where no kernel runs

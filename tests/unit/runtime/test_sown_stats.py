@@ -282,7 +282,9 @@ def _step(k):
             "gdn_beta_mean": f32(.5), "gdn_fused_rows": f32(1), "gdn_head_block": f32(4),
             "gdn_grid_steps": f32(64),
             "selscan_state_absmax": f32(1.25), "selscan_dt_mean": f32(.05 * k),
-            "diffattn_lambda_mean": np.array([.2, .4, .6], f32), "attn_gate_mean": f32(.5)}
+            "diffattn_lambda_mean": np.array([.2, .4, .6], f32), "attn_gate_mean": f32(.5),
+            "loop_exit_mass": np.array([.5, .25, .125, .125], f32),
+            "loop_ce": np.array([5., 4., 3., 2.], f32) * k, "loop_exit_entropy": f32(1.2)}
 
 
 # after a publish of steps 1 and 2 and a second of step 3: a gauge holds the
@@ -300,7 +302,19 @@ PUBLISHED = {
     "ds_kda_head_block": 4.0, "ds_kda_grid_steps": 2048.0,
     "ds_selscan_state_absmax": 1.25, "ds_selscan_dt_mean": 0.15,
     "ds_diffattn_lambda_mean": 0.4, "ds_gdn_state_absmax": 10.5, "ds_gdn_decay_mean": 0.82,
-    "ds_attn_gate_mean": 0.5}
+    "ds_attn_gate_mean": 0.5,
+    # a series a pass (``Gauge.label``): the second publish's step
+    "ds_loop_exit_mass": [.5, .25, .125, .125], "ds_loop_ce": [15., 12., 9., 6.],
+    "ds_loop_exit_entropy": 1.2}
+
+
+def _series(gauge):
+    """The registry's series of ``gauge``: one, or one a position of its label."""
+    reg = get_registry()
+    if gauge.label is None:
+        return [reg.get(gauge.name)]
+    return [reg.get(gauge.name, {gauge.label: str(i)})
+            for i in range(len(PUBLISHED[gauge.name]))]
 
 
 @pytest.fixture(scope="module")
@@ -318,16 +332,17 @@ def published(request):
         holder._sown_pending = steps
         engine_module.DeepSpeedTpuEngine._publish_sown_stats(holder)
         assert holder._sown_pending == []
-    return {g.name: reg.get(g.name).value - before.get(g.name, 0.0) for g in GAUGES}
+    return {g.name: [m.value - before.get(g.name, 0.0) for m in _series(g)] for g in GAUGES}
 
 
 @pytest.mark.parametrize("gauge", GAUGES, ids=lambda gauge: gauge.name)
 def test_a_publish_sets_every_declared_series_from_hand_built_steps(gauge, published):
     assert set(PUBLISHED) == {g.name for g in GAUGES}
-    assert published[gauge.name] == pytest.approx(PUBLISHED[gauge.name], rel=1e-6)
-    assert type(get_registry().get(gauge.name)).__name__ == (
-        "Counter" if gauge.counter else "Gauge")
-    assert get_registry().get(gauge.name).help == gauge.help
+    assert published[gauge.name] == pytest.approx(
+        np.ravel(PUBLISHED[gauge.name]).tolist(), rel=1e-6)
+    for series in _series(gauge):
+        assert type(series).__name__ == ("Counter" if gauge.counter else "Gauge")
+        assert series.help == gauge.help
 
 
 # ---- (e) a scanned model's statistics reach the host -----------------------
