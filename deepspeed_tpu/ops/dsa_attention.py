@@ -30,9 +30,20 @@ attention kernels read the mask (``dsa_attention``):
     tile's words expand by a sublane broadcast, a shift and an AND. A grid
     step holds all KV heads of a (query, key) tile, so the words are expanded
     once for them; a tile whose words are all zero skips its matmuls.
-``dsa_bwd_dq``, ``dsa_bwd_dkdv``  the backward pair under the same words,
-    rebuilt from the saved log-sum-exp, on transposed tiles as ``flash_dkdv``
-    (the expanded tile is transposed: one orientation is stored).
+``dsa_bwd``  the backward under the same words, rebuilt from the saved
+    log-sum-exp, on transposed tiles as ``flash_dkdv`` (the expanded tile is
+    transposed: one orientation is stored): dQ, dK and dV from ONE walk over
+    a KV head's live (query, key) tiles, query-major, five products a tile.
+    dQ is held a query tile at a time; the head's float32 dK and dV of ALL
+    keys stay in VMEM through its walk (2 * T * D * 4 bytes: 32 MiB at 32,768
+    keys of 128, an eighth of its dQ at a group of 8) and leave in the last
+    query tile's sweep. The walk is a table of the live tiles in SMEM: no
+    step is spent past the causal diagonal.
+``dsa_bwd_dq``, ``dsa_bwd_dkdv``  the same backward as a pair, seven
+    products a tile, which holds no accumulator longer than a tile: where
+    dK and dV pass the VMEM cap (65,536 keys at head 128;
+    ``kernel_dispatch.resolve_dsa_bwd``) and under ``bwd="pair"``. Its
+    gradients are the one walk's bit for bit.
 ``dsa_mask``  the words again from ``tau`` and ``tie``: one pass of scores by
     ``dsa_index``'s code at its tile shape (bit-equal), no selection. Only in
     the backward of a recomputed layer that did not keep its mask.
@@ -481,11 +492,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, dq_ref
 
 
 def _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, scale,
-                bits, dq_acc=None, dk_acc=None, dv_acc=None):
+                bits, dq_acc=None, dk_acc=None, dv_acc=None, key_tile=None):
     """One transposed tile ([BK, G * BQ] = k . q^T a KV head) of the backward
     under the choice's mask (query tile ``i``'s words, expanded and
-    transposed), added to the accumulators given: dQ, or dK and dV. Nothing
-    is multiplied where the tile holds no chosen pair."""
+    transposed), added to the accumulators given: dQ, dK and dV, or all
+    three. dK and dV are the KV head's slot of a [KV, BK, D] accumulator, or
+    slot ``key_tile`` of one head's [key tiles, BK, D] (``_bwd_kernel``).
+    Nothing is multiplied where the tile holds no chosen pair."""
     kv, g, bq, d = q_ref.shape[1:]
     words = _tile_words(mask_ref, i, bq, bits)
 
@@ -494,6 +507,7 @@ def _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, sc
         chosen = _expand(words, bits).T != 0
         cols = jnp.concatenate([chosen] * g, axis=1) if g > 1 else chosen
         for h in range(kv):
+            at = h if key_tile is None else key_tile
             q = q_ref[0, h].reshape(g * bq, d)
             k, v = k_ref[0, h], v_ref[0, h]
             do = do_ref[0, h].reshape(g * bq, d)
@@ -503,15 +517,15 @@ def _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, sc
                                     preferred_element_type=jnp.float32) * scale
             p = jnp.where(cols, jnp.exp(s - lse), 0.0)
             if dv_acc is not None:
-                dv_acc[h] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                                 (((1, ), (0, )), ((), ())),
-                                                 preferred_element_type=jnp.float32)
+                dv_acc[at] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                                  (((1, ), (0, )), ((), ())),
+                                                  preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(v, do, (((1, ), (1, )), ((), ())),
                                      preferred_element_type=jnp.float32)
             ds = (p * (dp - delta) * scale).astype(q.dtype)
             if dk_acc is not None:
-                dk_acc[h] += jax.lax.dot_general(ds, q, (((1, ), (0, )), ((), ())),
-                                                 preferred_element_type=jnp.float32)
+                dk_acc[at] += jax.lax.dot_general(ds, q, (((1, ), (0, )), ((), ())),
+                                                  preferred_element_type=jnp.float32)
             if dq_acc is not None:
                 dq_acc[h] += jax.lax.dot_general(ds, k, (((0, ), (0, )), ((), ())),
                                                  preferred_element_type=jnp.float32)
@@ -537,6 +551,43 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, dk_r
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale, block_q,
+                block_k, num_q, bits):
+    """dQ, dK and dV of one KV head from ONE walk over its live (query, key)
+    tiles, query-major, each visited once (``qt_ref`` / ``kt_ref``: the
+    walk's tiles, a table in SMEM): a query tile's dQ accumulates over its
+    sweep and leaves when it ends; the head's dK and dV of ALL keys
+    accumulate in float32 in VMEM, [key tiles, BK, D] each, and the last
+    query tile's sweep, in which every key tile is live, writes them out.
+    The sums run in the pair's order: a tile's five products once, its words
+    expanded and transposed once."""
+    step = pl.program_id(2)
+    i, j = qt_ref[step], kt_ref[step]
+    g, bq, d = q_ref.shape[2:]
+
+    @pl.when(step == 0)
+    def _init_keys():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    _tile_grads(i, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, scale,
+                bits, dq_acc=dq_acc, dk_acc=dk_acc, dv_acc=dv_acc, key_tile=j)
+
+    @pl.when(j == _last_key_tile(i, block_q, block_k))
+    def _finalize():
+        dq_ref[0, 0] = dq_acc[0].reshape(g, bq, d).astype(dq_ref.dtype)
+
+    @pl.when(i == num_q - 1)
+    def _finalize_keys():
+        dk_ref[0, 0] = dk_acc[j].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[j].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -641,12 +692,12 @@ def dsa_mask(qi, ki, w, tau, tie, blocks, interpret: bool = False):
 
 
 def _mask_spec(T, blocks, q_at, k_at):
-    """A (query, key) tile's words: grid step (b, x, y) -> the block that
-    holds query tile ``q_at(x, y)``'s rows of key tile ``k_at(x, y)``."""
+    """A (query, key) tile's words: grid step (b, *at) -> the block that
+    holds query tile ``q_at(*at)``'s rows of key tile ``k_at(*at)``."""
     block_q, block_k, _, _ = _tiles(T, blocks)
     _, rows, block = mask_layout(T, block_q)
     return pl.BlockSpec((1, 1, block, block_k),
-                        lambda b, x, y: (b, k_at(x, y), (q_at(x, y) * rows) // block, 0))
+                        lambda b, *at: (b, k_at(*at), (q_at(*at) * rows) // block, 0))
 
 
 def _dsa_fwd(q, k, v, mask, scale, blocks, interpret):
@@ -683,30 +734,89 @@ def _dsa_fwd(q, k, v, mask, scale, blocks, interpret):
     return _ungroup(out), lse
 
 
-def _dsa_bwd(q, k, v, mask, o, lse, g_out, scale, blocks, interpret):
-    from .kernel_dispatch import dsa_vmem_bytes
+def _live_tiles(block_q, block_k, num_q):
+    """The one walk's (query tile, key tile) pairs in its order, query-major
+    and a query tile's causal key tiles ascending: two int32 tables."""
+    live = [_last_key_tile(i, block_q, block_k) + 1 for i in range(num_q)]
+    return (np.repeat(np.arange(num_q, dtype=np.int32), live),
+            np.concatenate([np.arange(n, dtype=np.int32) for n in live]))
+
+
+def _dsa_bwd(q, k, v, mask, o, lse, g_out, scale, blocks, bwd, interpret):
+    """``bwd``: (kernel, its query tile) as ``kernel_dispatch.resolve_dsa_bwd``
+    gives them: "fused", the one walk ``dsa_bwd``, or "pair"; the tile whole
+    tiles of the forward's, with words of as many queries."""
+    from .kernel_dispatch import IMPL_FUSED, dsa_vmem_bytes
     B, T, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
+    kernel, tile_q = bwd
+    fwd_q = _tiles(T, blocks)[0]
+    blocks = (tile_q, blocks[1])
     block_q, block_k, num_q, num_k = _tiles(T, blocks)
-    live = _live_key_map(block_q, block_k)
-    static = dict(scale=scale, block_q=block_q, block_k=block_k,
-                  bits=mask_layout(T, block_q)[0])
-    params = _compiler_params(dsa_vmem_bytes(
-        "bwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T))
+    bits = mask_layout(T, block_q)[0]
+    assert bits == mask_layout(T, fwd_q)[0], (blocks, fwd_q)
+    static = dict(scale=scale, block_q=block_q, block_k=block_k, bits=bits)
     qg, kt, vt = _group(q, k, v)
     dog, _, _ = _group(g_out, k, v)
     og, _, _ = _group(o, k, v)
     delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
 
-    # both kernels walk TRANSPOSED tiles: what belongs to a query enters as
+    # the kernels walk TRANSPOSED tiles: what belongs to a query enters as
     # rows, lane-dense in HBM: [B, KV, q tiles, 1, .], g-major inside a tile
     # like the folded q rows
     def rows(x):    # [B, KV, G, T] -> [B, KV, q tiles, 1, G * BQ]
         return (x.reshape(B, KV, G, num_q, block_q).transpose(0, 1, 3, 2, 4)
                 .reshape(B, KV, num_q, 1, G * block_q))
 
+    if block_q != fwd_q:    # the forward laid its log-sum-exp out by ITS tiles
+        lse = rows(lse.reshape(B, KV, T // fwd_q, G, fwd_q).transpose(0, 1, 3, 2, 4)
+                   .reshape(B, KV, G, T))
     operands = (qg, kt, vt, dog, lse, rows(delta), mask)
+
+    if kernel == IMPL_FUSED:
+        # one KV head a row of the grid; its walk is a table of the live
+        # tiles, so that no step is spent past the causal diagonal
+        def at(index):
+            return lambda b, h, s, qt, kt: index(b, h, qt[s], kt[s])
+
+        q_spec = pl.BlockSpec((1, 1, G, block_q, D), at(lambda b, h, i, j: (b, h, 0, i, 0)))
+        kv_spec = pl.BlockSpec((1, 1, block_k, D), at(lambda b, h, i, j: (b, h, j, 0)))
+        r_spec = pl.BlockSpec((1, 1, 1, 1, G * block_q),
+                              at(lambda b, h, i, j: (b, h, i, 0, 0)))
+        m_spec = _mask_spec(T, blocks, lambda h, s, qt, kt: qt[s],
+                            lambda h, s, qt, kt: kt[s])
+        # a key tile's dK/dV are whole, and written, in the last query tile's
+        # sweep; until then the map names tile 0, which that sweep writes first
+        keys_out = pl.BlockSpec(
+            (1, 1, block_k, D),
+            at(lambda b, h, i, j: (b, h, jnp.where(i == num_q - 1, j, 0), 0)))
+        tiles = _live_tiles(block_q, block_k, num_q)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_kernel, num_q=num_q, **static),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B, KV, len(tiles[0])),
+                in_specs=[q_spec, kv_spec, kv_spec, q_spec, r_spec, r_spec, m_spec],
+                out_specs=[q_spec, keys_out, keys_out],
+                scratch_shapes=[pltpu.VMEM((1, G * block_q, D), jnp.float32),
+                                pltpu.VMEM((num_k, block_k, D), jnp.float32),
+                                pltpu.VMEM((num_k, block_k, D), jnp.float32)]),
+            # dQ first: ``benchmark/dsa_cost.py`` reads a call's shape off its
+            # first result
+            out_shape=[jax.ShapeDtypeStruct(qg.shape, q.dtype),
+                       jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                       jax.ShapeDtypeStruct(vt.shape, v.dtype)],
+            compiler_params=_compiler_params(dsa_vmem_bytes(
+                "fused", KV, G, D, q.dtype.itemsize, block_q, block_k, T)),
+            interpret=interpret,
+            name="dsa_bwd",
+        )(*tiles, *operands)
+        return _ungroup(dq), dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3)
+
+    live = _live_key_map(block_q, block_k)
+    params = _compiler_params(dsa_vmem_bytes(
+        "bwd", KV, G, D, q.dtype.itemsize, block_q, block_k, T))
 
     def specs(q_at, k_at):
         """The seven operands' blocks; ``q_at`` / ``k_at`` (x, y) -> the
@@ -754,26 +864,26 @@ def _dsa_bwd(q, k, v, mask, o, lse, g_out, scale, blocks, interpret):
     return _ungroup(dq), dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _dsa_attend(q, k, v, mask, again, scale, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _dsa_attend(q, k, v, mask, again, scale, blocks, bwd, interpret):
     """Attention under ``mask``. ``again``: None where the backward is handed
     the forward's mask (it is a residual), else what ``dsa_mask`` makes it
     again from (qi, ki, w, tau, tie), and the mask is no residual."""
     return _dsa_fwd(q, k, v, mask, scale, blocks, interpret)[0]
 
 
-def _attend_fwd(q, k, v, mask, again, scale, blocks, interpret):
+def _attend_fwd(q, k, v, mask, again, scale, blocks, bwd, interpret):
     o, lse = _dsa_fwd(q, k, v, mask, scale, blocks, interpret)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (q, k, v, mask if again is None else None, again, o, lse)
 
 
-def _attend_bwd(scale, blocks, interpret, res, g):
+def _attend_bwd(scale, blocks, bwd, interpret, res, g):
     q, k, v, mask, again, o, lse = res
     if mask is None:
         mask = dsa_mask(*again, blocks, interpret)
-    dq, dk, dv = _dsa_bwd(q, k, v, mask, o, lse, g, scale, blocks, interpret)
+    dq, dk, dv = _dsa_bwd(q, k, v, mask, o, lse, g, scale, blocks, bwd, interpret)
     no = lambda a: np.zeros(a.shape, jax.dtypes.float0)    # noqa: E731
     if again is not None:
         qi, ki, w, tau, tie = again
@@ -786,10 +896,10 @@ _dsa_attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 # a jit frame of its own, so that the kernels read ``%dsa_fwd.N`` /
-# ``%dsa_bwd_dq.N`` under ``jax.grad`` (ops/attention.py,
+# ``%dsa_bwd.N`` under ``jax.grad`` (ops/attention.py,
 # ``_flash_attention_call``); XLA inlines the call
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
-def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, interpret, keep_mask):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, bwd, interpret, keep_mask):
     qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
     tau, tie, chosen, kth, mask = dsa_index(qi, ki, w, topk, blocks, interpret)
     tau = checkpoint_name(tau, remat.DSA_CHOICE[0])
@@ -797,13 +907,14 @@ def _dsa_call(q, k, v, qi, ki, w, topk, scale, blocks, interpret, keep_mask):
     mask = checkpoint_name(mask, remat.DSA_MASK if keep_mask
                            else remat.DSA_MASK + remat.AGAIN)
     o = _dsa_attend(q, k, v, mask, None if keep_mask else (qi, ki, w, tau, tie),
-                    scale, blocks, interpret)
+                    scale, blocks, bwd, interpret)
     return o, chosen, kth
 
 
 def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
                   blocks: Optional[tuple] = None, force_pallas: Optional[bool] = None,
-                  interpret: bool = False, keep_mask: bool = True):
+                  interpret: bool = False, keep_mask: bool = True,
+                  bwd: Optional[str] = None):
     """Attention of each query over the ``topk`` earlier keys its indexer
     scores highest. q [B, T, H, D], k/v [B, T, KV, D] (GQA native); the
     indexer's qi [B, T, HI, DI], its one key a token ki [B, T, DI] and head
@@ -812,11 +923,15 @@ def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
     smallest chosen score). No gradient reaches qi, ki or w.
 
     On a TPU (or with ``interpret=True`` anywhere) the ``dsa_*`` kernels run
-    at ``kernel_dispatch.choose_dsa_blocks``' tiles unless ``blocks`` pins
-    (query, key) tiles; elsewhere ``dense_dsa``. ``keep_mask``: whether the
-    backward is handed the forward's mask (named ``remat.DSA_MASK``: what a
-    caller inside a recomputation asks ``remat.keeps``) or makes it again
-    with ``dsa_mask``."""
+    at ``kernel_dispatch.choose_dsa_blocks``' tiles, the one-walk backward
+    at a wider query tile where ``resolve_dsa_bwd`` finds room, unless
+    ``blocks`` pins (query, key) tiles, every kernel's; elsewhere
+    ``dense_dsa``. ``keep_mask``: whether the backward is handed the
+    forward's mask (named ``remat.DSA_MASK``: what a caller inside a
+    recomputation asks ``remat.keeps``) or makes it again with ``dsa_mask``.
+    ``bwd`` pins the backward: "fused" (``dsa_bwd``, the one walk: what every
+    shape whose dK and dV fit VMEM resolves to) or "pair" (``dsa_bwd_dq`` +
+    ``dsa_bwd_dkdv``), as ``impl_bwd`` pins flash's."""
     from . import kernel_dispatch as kd
     scale = float(scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]))
     w = w.astype(jnp.float32)
@@ -824,6 +939,8 @@ def dsa_attention(q, k, v, qi, ki, w, topk: int, scale: Optional[float] = None,
         return dense_dsa(q, k, v, qi, ki, w, topk, scale)
     sig = kd.make_sig(q.shape, k.shape[2], k.shape[1], q.dtype, True, None, None,
                       pattern=f"dsa{topk}")
+    widen = blocks is None      # the rule's own tiles: the one walk may take a wider
     blocks = tuple(blocks or kd.choose_dsa_blocks(sig, qi.shape[2], qi.shape[3]))
-    return _dsa_call(q, k, v, qi, ki, w, int(topk), scale, blocks, interpret,
+    return _dsa_call(q, k, v, qi, ki, w, int(topk), scale, blocks,
+                     kd.resolve_dsa_bwd(sig, blocks, bwd, widen), interpret,
                      bool(keep_mask))

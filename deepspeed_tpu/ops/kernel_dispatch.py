@@ -265,13 +265,20 @@ def choose_block_diffusion_blocks(sig: ShapeSig, leg: str,
 
 
 # Learned sparse attention (``ops/dsa_attention.py``, ``dsa_index`` /
-# ``dsa_mask`` / ``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkdv``): a grid step
-# holds ALL KV heads of one (query, key) tile, so that the choice's words are
-# expanded once for them; the query tile folds with its group to MAX_ROWS rows
-# a KV head, the key tile is KEY_BLOCK. ``dsa_index`` keeps a query tile's
-# scores over the whole row in VMEM (seq * block_q * 4 bytes: 16 MiB at 32,768
-# keys and 128 queries), which caps the query tile; every call carries its
-# own limit.
+# ``dsa_mask`` / ``dsa_fwd`` / ``dsa_bwd``, and the pair ``dsa_bwd_dq`` /
+# ``dsa_bwd_dkdv``): a grid step of the forward and of the pair holds ALL KV
+# heads of one (query, key) tile, so that the choice's words are expanded
+# once for them; the query tile folds with its group to MAX_ROWS rows a KV
+# head, the key tile is KEY_BLOCK. ``dsa_index`` keeps a query tile's scores
+# over the whole row in VMEM (seq * block_q * 4 bytes: 16 MiB at 32,768 keys
+# and 128 queries), which caps the query tile; every call carries its own
+# limit. The backward is ``dsa_bwd``, one walk a KV head with that head's
+# float32 dK and dV of every key in VMEM (no group factor: 32 MiB at 32,768
+# keys of 128, where a range of 2,048 queries' dQ of all four heads would be
+# as much and PR 57's partials 2.1 GB), wherever its estimate is within
+# FUSED_VMEM_CAP_BYTES (``resolve_dsa_bwd``: 57 MiB at the Keye-VL cell's
+# call, at a query tile of 256; 65,536 keys are 64 MiB of dK and dV alone),
+# and past it the pair.
 DSA_ROW_SCORES_CAP_BYTES = 32 * 2**20
 
 
@@ -281,8 +288,11 @@ def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
     """Upper estimate of the VMEM one grid step of the ``dsa_*`` kernels
     holds, counted as ``flash_vmem_bytes`` counts: ``leg`` "index" (the
     row's scores over ``seq`` keys and its words), "mask" (the words and one
-    tile's scores), "fwd" or "bwd" (the larger of the pair; a tile's words
-    and their expansion). The indexer's operands are counted at 128 lanes."""
+    tile's scores), "fwd", "bwd" (the larger of the pair; a tile's words
+    and their expansion) or "fused" (the one walk ``dsa_bwd``: ONE KV head's
+    tiles whatever ``kv_heads`` says, ds transposed, and the float32 dK and
+    dV of its ``seq`` keys). The indexer's operands are counted at 128
+    lanes."""
     from .dsa_attention import mask_layout
     word_rows = mask_layout(seq, block_q)[2]
     if leg in ("index", "mask"):
@@ -291,6 +301,8 @@ def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
                   + 6 * block_q * block_k * 4               # the tile
                   + 2 * word_rows * seq * 4)                # a row of key tiles' words
         return scores + (seq * block_q * 4 if leg == "index" else 0)
+    if leg == "fused":
+        kv_heads = 1
     rows = kv_heads * group * block_q
     lanes = max(head_dim, 128)
     words = 2 * word_rows * block_k * 4 + 3 * block_q * block_k * 4
@@ -302,12 +314,54 @@ def dsa_vmem_bytes(leg: str, kv_heads: int, group: int, head_dim: int,
         blocks = 2 * q_blk + 2 * kv_blk + stat          # q, o; k, v; lse
         scratch = rows * lanes * 4 + 2 * stat           # acc; m, l
         temps = tile * (2 * 4 + itemsize)
+    elif leg == "fused":
+        # q, do, dq; k, v, dk, dv; lse and delta as rows
+        blocks = 3 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4
+        scratch = rows * lanes * 4 + 2 * seq * lanes * 4
+        temps = tile * (3 * 4 + 3 * itemsize)
     else:
         blocks = max(3 * q_blk + 2 * kv_blk + 2 * stat,
                      2 * q_blk + 4 * kv_blk + 2 * 8 * rows * 4)
         scratch = max(rows * lanes * 4, 2 * kv_heads * block_k * lanes * 4)
         temps = tile * (3 * 4 + 2 * itemsize)
     return words + 2 * blocks + scratch + temps
+
+
+DSA_BWD_PAIR = "pair"
+
+
+def resolve_dsa_bwd(sig: ShapeSig, blocks: tuple, pinned: Optional[str] = None,
+                    widen: bool = True) -> tuple:
+    """(kernel, its query tile) of the ``dsa_*`` backward at ``sig`` under
+    the call's ``blocks``, from the shape alone: IMPL_FUSED, the one walk
+    ``dsa_bwd``, wherever its estimate (a KV head's float32 dK and dV of
+    every key in it) is within FUSED_VMEM_CAP_BYTES, else DSA_BWD_PAIR
+    (``dsa_bwd_dq`` + ``dsa_bwd_dkdv``), which needs no accumulator longer
+    than a tile. ``pinned`` names either (tests, the sweep). The one walk's
+    step is not held to the forward's MAX_ROWS: where the call's tiles are
+    the rule's own (``widen``) and the estimate allows, its query tile is
+    FUSED_MAX_ROWS folded rows of at most 512 queries, whole tiles of the
+    call's and words of as many queries (half the steps: 130.4 for 141.3 ms
+    at the Keye-VL cell's call, PR 59); the key tile is the call's, which
+    the mask's words are laid out by."""
+    from .dsa_attention import mask_layout
+    if pinned not in (None, IMPL_FUSED, DSA_BWD_PAIR):
+        raise ValueError(f"bwd={pinned!r}: {IMPL_FUSED!r}, {DSA_BWD_PAIR!r} or None")
+    block_q, block_k = (min(b, sig.seq_q) for b in blocks)
+    group = max(1, sig.heads // sig.kv_heads)
+
+    def fits(tile_q):
+        return dsa_vmem_bytes("fused", 1, group, sig.head_dim,
+                              4 if "32" in sig.dtype else 2, tile_q, block_k,
+                              sig.seq_k) <= FUSED_VMEM_CAP_BYTES
+
+    if pinned == DSA_BWD_PAIR or not (pinned or fits(block_q)):
+        return DSA_BWD_PAIR, block_q
+    wide = _largest_block(sig.seq_q, max(block_q, min(512, FUSED_MAX_ROWS // group)))
+    if (widen and wide % block_q == 0 and fits(wide)
+            and mask_layout(sig.seq_q, wide)[0] == mask_layout(sig.seq_q, block_q)[0]):
+        return IMPL_FUSED, wide
+    return IMPL_FUSED, block_q
 
 
 def choose_dsa_blocks(sig: ShapeSig, index_heads: int, index_dim: int) -> tuple:

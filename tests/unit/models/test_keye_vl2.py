@@ -249,14 +249,14 @@ def test_the_kernels_under_the_model_and_its_recomputation_make_the_choice_once(
             assert _rel(g, np.asarray(w)) <= 2e-3
     traced = fn.trace(small["params"])
     text = str(traced.jaxpr)
-    for kernel in ("dsa_index", "dsa_fwd", "dsa_bwd_dq", "dsa_bwd_dkdv"):
+    for kernel in ("dsa_index", "dsa_fwd", "dsa_bwd"):
         assert f"name={kernel}" in text, kernel
     tokens = ROWS * 2 * SEQ
     assert kept_residual_bytes(traced.jaxpr) == 2 * tokens * (8 * (16 * 4 + 4) + 8)
     # no memory report, no mask kept: each layer's backward makes it again from
     # the kept thresholds, and the selection is not in the recomputed forward
     assert _kernel_calls(traced.jaxpr) == {
-        "dsa_index": 2, "dsa_fwd": 2, "dsa_mask": 2, "dsa_bwd_dq": 2, "dsa_bwd_dkdv": 2}
+        "dsa_index": 2, "dsa_fwd": 2, "dsa_mask": 2, "dsa_bwd": 2}
 
 
 @pytest.mark.parametrize("masks", [0, 1, 2])

@@ -182,8 +182,11 @@ def test_the_kernels_carry_their_own_names():
     """The benchmark's flash readers credit ``%flash_*`` calls with causal
     work from their shape: these kernels must not match them."""
     q, k, v, _ = _inputs(0, 1, 32, 2, 2, 16)
-    text = jax.jit(jax.grad(lambda q, k, v: A.block_diffusion_attention(
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: A.block_diffusion_attention(
         q, k, v, 4, blocks_fwd=(16, 16), blocks_bwd=(16, 16), interpret=True).sum(),
-        (0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
-    assert "bdattn_fwd" in text and "bdattn_bwd" in text
-    assert "flash_fwd" not in text and "flash_dkdv" not in text and "flash_dq" not in text
+        (0, 1, 2)))(q, k, v))
+    # the calls' own names: a lowering's debug locations also hold the frames
+    # of whatever traced a shared helper first in this process
+    names = {word[len("name="):] for word in text.split() if word.startswith("name=")}
+    assert {"bdattn_fwd", "bdattn_bwd"} <= names, names
+    assert not any(n.startswith("flash_") for n in names), names

@@ -1,11 +1,12 @@
 """Learned sparse attention's kernels (``ops/dsa_attention.py``: ``dsa_index``,
-``dsa_fwd``, ``dsa_bwd_dq``, ``dsa_bwd_dkdv``), interpreted, against the dense
+``dsa_fwd``, the one-walk backward ``dsa_bwd``), interpreted, against the dense
 form by hand (scores, ``lax.top_k``, a mask): the forward and every gradient,
 the count a row chose and its smallest chosen score, a row with fewer than
 ``topk`` candidates, ties at the threshold, a tile with no chosen pair, a
-group of eight; the choice as ``dsa_index`` packs it (a bit mask, read back in
-both orientations) and as ``dsa_mask`` makes it again; and the blocks
-``kernel_dispatch`` gives the call."""
+group of eight; the one walk against the pinned pair (``dsa_bwd_dq``,
+``dsa_bwd_dkdv``) bit for bit; the choice as ``dsa_index`` packs it (a bit
+mask, read back in both orientations) and as ``dsa_mask`` makes it again; and
+the blocks and the backward ``kernel_dispatch`` gives the call."""
 
 import jax
 import jax.numpy as jnp
@@ -189,15 +190,9 @@ def test_a_backward_that_makes_the_mask_again_is_the_kept_paths_bit_for_bit(case
 
 
 def test_a_tile_with_no_chosen_pair_is_skipped_and_changes_nothing():
-    """An indexer that scores the first 64 keys far above the rest: every
-    query past them chooses among those alone, the second key tile holds no
-    chosen pair (its matmuls are skipped), and output and gradients are the
-    dense form's."""
-    q, k, v, qi, ki, w = _operands(8, 1, seed=2)
-    qi = jnp.abs(qi)
-    ki = jnp.abs(ki) * jnp.where(jnp.arange(T) < 64, 50.0, 1e-3)[None, :, None]
-    w = jnp.abs(w)
-    a = (q, k, v, qi, ki, w)
+    """``_skipping``'s indexer: the second key tile holds no chosen pair (its
+    matmuls are skipped), and output and gradients are the dense form's."""
+    a = q, k, v, qi, ki, w = _skipping(_operands(8, 1, seed=2))
     loss = lambda f: lambda *x: jnp.sum(jnp.square(f(*x)[0]))    # noqa: E731
     scores = np.asarray(dsa.index_scores(qi, ki, w))[0]
     assert (np.argsort(-scores[200, :201])[:TOPK] < 64).all()
@@ -206,6 +201,56 @@ def test_a_tile_with_no_chosen_pair_is_skipped_and_changes_nothing():
                     jax.grad(loss(_dense()), argnums=(0, 1, 2))(*a)):
         np.testing.assert_allclose(x, y, atol=2e-4, rtol=2e-4)
     assert not np.any(jax.grad(loss(_kernels()), argnums=1)(*a)[0, 128:])
+
+
+def _skipping(a):
+    """An indexer that scores the first 64 keys far above the rest: every
+    query past them chooses among those alone, and the second key tile holds
+    no chosen pair."""
+    q, k, v, qi, ki, w = a
+    ki = jnp.abs(ki) * jnp.where(jnp.arange(T) < 64, 50.0, 1e-3)[None, :, None]
+    return q, k, v, jnp.abs(qi), ki.astype(qi.dtype), jnp.abs(w)
+
+
+@pytest.mark.parametrize("heads,kv,dtype,case", [
+    (8, 1, jnp.float32, "seeded"), (4, 2, jnp.bfloat16, "seeded"),
+    (8, 1, jnp.float32, "a_dead_tile")], ids=["group8_float32", "group2_bfloat16",
+                                              "a_dead_tile"])
+def test_the_one_walk_is_the_pinned_pair_bit_for_bit(heads, kv, dtype, case):
+    """``dsa_bwd`` (what the rule gives every shape whose dK and dV fit VMEM)
+    against ``bwd="pair"``: dQ, dK and dV equal to the bit (the sums run in
+    the pair's order), with a tile the walk visits and skips; the traced
+    gradient holds the one call or the two."""
+    a = _operands(heads, kv, dtype, seed=5, rows=2)
+    if case == "a_dead_tile":
+        a = _skipping(a)
+
+    def loss(bwd, blocks=(64, 128)):
+        return lambda *x: jnp.sum(jnp.square(dsa.dsa_attention(
+            *x, TOPK, blocks=blocks, interpret=True, bwd=bwd)[0].astype(jnp.float32)))
+
+    walk = jax.grad(loss(None), argnums=(0, 1, 2))(*a)
+    pair = jax.grad(loss("pair"), argnums=(0, 1, 2))(*a)
+    for name, x, y in zip(("q", "k", "v"), walk, pair):
+        assert x.dtype == dtype and np.any(np.asarray(x, np.float32)), name
+        np.testing.assert_array_equal(np.asarray(x, np.float32), np.asarray(y, np.float32),
+                                      err_msg=f"d{name}")
+    if dtype == jnp.bfloat16:      # one case traces the two programs again
+        for bwd, names in ((None, ["dsa_bwd"]), ("pair", ["dsa_bwd_dq", "dsa_bwd_dkdv"])):
+            text = str(jax.make_jaxpr(jax.grad(loss(bwd), argnums=(0, 1, 2)))(*a))
+            assert [n for n in text.split() if n.startswith("name=dsa_bwd")] == [
+                f"name={n}" for n in names], bwd
+        with pytest.raises(ValueError, match="bwd="):
+            dsa.dsa_attention(*a, TOPK, blocks=(64, 128), interpret=True, bwd="two")
+    if case == "a_dead_tile":
+        assert not np.any(np.asarray(walk[1])[:, 128:])
+        # at the rule's own tiles the walk's query tile (256) is two of the
+        # forward's (128), whose log-sum-exp it lays out again
+        sig = kd.make_sig(a[0].shape, kv, T, a[0].dtype, True, None, None)
+        assert kd.choose_dsa_blocks(sig, HI, DI) == (128, 256)
+        assert kd.resolve_dsa_bwd(sig, (128, 256)) == (kd.IMPL_FUSED, 256)
+        for x, y in zip(jax.grad(loss(None, None), argnums=(0, 1, 2))(*a), walk):
+            np.testing.assert_allclose(x, y, atol=2e-5 * float(jnp.abs(y).max()), rtol=2e-5)
 
 
 def test_a_sequence_shorter_than_topk_is_causal_attention():
@@ -239,9 +284,29 @@ def test_blocks_and_vmem_of_the_cells_call():
     assert dsa.mask_layout(32768, 128) == (32, 4, 8)
     assert np.prod(dsa._mask_shape(1, 32768, (128, 512))) * 4 == 32768**2 // 8
     assert index - kd.dsa_vmem_bytes("mask", 1, 1, 64, 2, 128, 512, 32768, 16) == 16 * 2**20
-    for leg in ("fwd", "bwd"):
+    for leg in ("fwd", "bwd", "fused"):
         need = kd.dsa_vmem_bytes(leg, 4, 8, 128, 2, 128, 512, 32768)
         assert kd.VMEM_SCOPED_DEFAULT_BYTES < need < kd.FUSED_VMEM_CAP_BYTES, (leg, need)
+    # the one walk holds ONE KV head's tiles and its float32 dK and dV of
+    # every key (32 MiB here); where those pass the cap, 65,536 keys at head
+    # 128, the rule hands back the pair, which a pin names at any shape
+    fused = kd.dsa_vmem_bytes("fused", 4, 8, 128, 2, 128, 512, 32768)
+    assert fused == kd.dsa_vmem_bytes("fused", 1, 8, 128, 2, 128, 512, 32768)
+    assert 32 * 2**20 < fused < 48 * 2**20
+    # its query tile: FUSED_MAX_ROWS folded rows (one block of the words'
+    # rows) where the tiles are the rule's own and the estimate allows
+    wide = kd.dsa_vmem_bytes("fused", 1, 8, 128, 2, 256, 512, 32768)
+    assert fused < wide < kd.FUSED_VMEM_CAP_BYTES
+    assert dsa.mask_layout(32768, 256) == (32, 8, 8)
+    assert kd.resolve_dsa_bwd(sig, (128, 512)) == (kd.IMPL_FUSED, 256)
+    assert kd.resolve_dsa_bwd(sig, (128, 512), widen=False) == (kd.IMPL_FUSED, 128)
+    assert kd.resolve_dsa_bwd(sig, (128, 512), kd.DSA_BWD_PAIR) == (kd.DSA_BWD_PAIR, 128)
+    for seq, want in ((49152, (kd.IMPL_FUSED, 128)), (65536, (kd.DSA_BWD_PAIR, 128))):
+        longer = kd.make_sig((1, seq, 32, 128), 4, seq, "bfloat16", True, None, None,
+                             pattern="dsa2048")
+        assert kd.resolve_dsa_bwd(longer, (128, 512)) == want, seq
+        assert kd.resolve_dsa_bwd(longer, (128, 512), kd.IMPL_FUSED) == (
+            kd.IMPL_FUSED, 128)
     mha = kd.make_sig((1, 32768, 8, 128), 8, 32768, "bfloat16", True, None, None)
     assert kd.choose_dsa_blocks(mha, 16, 64) == (256, 512)
     with pytest.raises(ValueError, match="shorten the sequence"):

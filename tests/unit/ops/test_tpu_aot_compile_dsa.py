@@ -1,7 +1,8 @@
-"""Learned sparse attention's kernels (``dsa_index``, ``dsa_fwd``,
-``dsa_bwd_dq``, ``dsa_bwd_dkdv``) compiled for a described (not attached) TPU
-v5e at Keye-VL-2.0-30B-A3B's published widths and the cell's 1 x 32,768
-tokens, in the engine's fused step: no chip time, nothing runs.
+"""Learned sparse attention's kernels (``dsa_index``, ``dsa_fwd`` and the
+one-walk backward ``dsa_bwd``, which the cell's shape resolves to: a KV head's
+float32 dK and dV of all 32,768 keys in VMEM) compiled for a described (not
+attached) TPU v5e at Keye-VL-2.0-30B-A3B's published widths and the cell's
+1 x 32,768 tokens, in the engine's fused step: no chip time, nothing runs.
 
 A file of its own beside ``test_tpu_aot_compile_mla.py`` (a worker's whole
 share under ``--dist loadfile``), whose ``step_of`` spells the step out: two
@@ -82,7 +83,8 @@ def test_every_layer_chooses_and_attends_once_a_step(step):
     """Under whole-layer recomputation each layer's ``dsa_index`` and
     ``dsa_fwd`` run once (the thresholds, the output and the log-sum-exp are
     kept by name for the recomputed layer's backward) and its backward is the
-    one pair; with the described chip's room both layers keep their mask
+    one walk ``dsa_bwd``, no pair; with the described chip's room both layers
+    keep their mask
     (``ds.dsa.mask``, first in the walk: no ``dsa_mask`` call makes it
     again) and the kept bytes hold it; no ``flash_*`` call is in the
     program."""
@@ -91,8 +93,9 @@ def test_every_layer_chooses_and_attends_once_a_step(step):
     names = [line.split(" = ")[0].split("%")[-1].split(".")[0]
              for line in mla.custom_calls(step["compiled"])]
     kernels = {n: names.count(n) for n in set(names)}
-    for name in ("dsa_index", "dsa_fwd", "dsa_bwd_dq", "dsa_bwd_dkdv"):
+    for name in ("dsa_index", "dsa_fwd", "dsa_bwd"):
         assert kernels.pop(name) == 2, (name, names)
+    assert not any(n.startswith("dsa_bwd_") for n in kernels), names
     # two layers' shares, either branch of their cond: the program's own
     assert (kernels.pop("moe_gmm_rows"), kernels.pop("moe_gmm_d_rows"),
             kernels.pop("moe_gmm_weights")) == (4 * (3 + 3), 4 * 3, 4 * 3), names
@@ -130,11 +133,14 @@ def kernel_operands(closed_jaxpr):
 
 def test_the_attention_kernels_take_the_mask_and_none_of_the_indexers_operands(step):
     """The indexer's scores are made in ``dsa_index`` alone: ``dsa_fwd`` takes
-    q, k, v and the words ``i32[rows, key tiles, seq / 32, 512]``, the pair
-    those with dO, the log-sum-exp and delta: no qi ``bf16[rows, 16, seq,
-    64]``, ki, w or threshold; and in the compiled step ``dsa_index`` writes
-    the words after its first results (``tau``, ``tie``: where
-    ``benchmark/dsa_cost.py`` reads the call's shape)."""
+    q, k, v and the words ``i32[rows, key tiles, seq / 32, 512]``, the one
+    walk its table of live tiles (two ``i32[4160]`` a KV head at its query
+    tile of 256), then those with dO, the log-sum-exp and delta laid out by
+    that tile: no qi ``bf16[rows, 16, seq, 64]``, ki, w or threshold; and in
+    the compiled step ``dsa_index`` writes the words after its first results
+    (``tau``, ``tie``) and ``dsa_bwd`` dQ ``bf16[rows, 4, 8, seq, 128]``
+    before dK and dV (the first result is where ``benchmark/dsa_cost.py``
+    reads a call's shape)."""
     rows, seq, group = step["rows"], step["seq"], HEADS // KV
     words = f"i32[{rows},{seq // 512},{seq // 32},512]"
     q = f"bf16[{rows},{KV},{group},{seq},{D}]"
@@ -142,14 +148,20 @@ def test_the_attention_kernels_take_the_mask_and_none_of_the_indexers_operands(s
     stat = f"f32[{rows},{KV},{seq // 128},1,{group * 128}]"
     operands = kernel_operands(step["traced"].jaxpr)
     assert operands["dsa_fwd"] == [q, kv, kv, words]
-    assert operands["dsa_bwd_dq"] == operands["dsa_bwd_dkdv"] == [
-        q, kv, kv, q, stat, stat, words]
+    live = f"i32[{sum(i // 2 + 1 for i in range(seq // 256))}]"
+    wide = f"f32[{rows},{KV},{seq // 256},1,{group * 256}]"
+    assert operands["dsa_bwd"] == [live, live, q, kv, kv, q, wide, wide, words]
+    assert "dsa_bwd_dq" not in operands and "dsa_bwd_dkdv" not in operands
     assert operands["dsa_index"] == [f"bf16[{rows},{HI},{seq},{DI}]",
                                      f"bf16[{rows},{seq},{DI}]", f"f32[{rows},{seq},{HI}]"]
     results = next(line for line in mla.custom_calls(step["compiled"])
                    if "%dsa_index" in line.split(" = ")[0]).split("custom-call(")[0]
     assert (results.index(f"s32[{rows},{seq},1]")
             < results.index(f"s32[{rows},{seq // 512},{seq // 32},512]"))
+    results = next(line for line in mla.custom_calls(step["compiled"])
+                   if "%dsa_bwd" in line.split(" = ")[0]).split("custom-call(")[0]
+    assert results.split(" = ")[1].lstrip("( ").startswith(q), results
+    assert results.count(f"bf16[{rows},{KV},{seq},{D}]") == 2
 
 
 def test_the_calls_have_the_blocks_dispatch_chose_and_their_own_vmem(step):
@@ -165,12 +177,11 @@ def test_the_calls_have_the_blocks_dispatch_chose_and_their_own_vmem(step):
     assert f"bf16[{rows},{HI},{seq},{DI}]" in calls["dsa_index"].split("custom-call(")[1]
     fwd = calls["dsa_fwd"].split("custom-call(")[0]
     assert grouped in fwd and f"f32[{rows},{KV},{seq // 128},1,{HEADS // KV * 128}]" in fwd
-    assert grouped in calls["dsa_bwd_dq"].split("custom-call(")[0]
-    assert f"bf16[{rows},{KV},{seq},{D}]" in calls["dsa_bwd_dkdv"].split("custom-call(")[0]
-    for name, leg in (("dsa_index", "index"), ("dsa_fwd", "fwd"), ("dsa_bwd_dq", "bwd"),
-                      ("dsa_bwd_dkdv", "bwd")):
+    assert kd.resolve_dsa_bwd(sig, (128, 512)) == (kd.IMPL_FUSED, 256)
+    assert grouped in calls["dsa_bwd"].split("custom-call(")[0]
+    for name, leg in (("dsa_index", "index"), ("dsa_fwd", "fwd"), ("dsa_bwd", "fused")):
         need = kd.dsa_vmem_bytes(leg, *((1, 1, DI) if leg == "index" else (KV, HEADS // KV, D)),
-                                 2, 128, 512, seq, HI)
+                                 2, 256 if leg == "fused" else 128, 512, seq, HI)
         asked = re.findall(r'scoped_memory_configs":\[([^\]]*)\]', calls[name])[0]
         assert int(re.search(r'"size":"(\d+)"', asked).group(1)) == kd.vmem_limit_bytes(need)
 
