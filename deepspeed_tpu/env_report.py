@@ -75,6 +75,12 @@ def debug_report():
                      + kernel_dispatch.resolved_note(
                          batch=1, seq=4096, heads=32, kv_heads=8,
                          head_dim=128, window=4096))
+        # a looped stack's 16,384-token call (the Ouro cell's): the walk is
+        # a table of the live tiles, about half of the rectangle's steps
+        lines.append(f"attn dispatch @ [1,16384,16/16,128] {'.' * 13} "
+                     + kernel_dispatch.resolved_note(
+                         batch=1, seq=16384, heads=16, kv_heads=16,
+                         head_dim=128))
         # past the fused backward's VMEM cap whole: walked by query ranges
         lines.append(f"attn dispatch @ [1,32768,16/2,256] {'.' * 14} "
                      + kernel_dispatch.resolved_note(

@@ -25,10 +25,16 @@ RANGE at a time, one range's dQ in VMEM, and a kv block's dk and dv leave as
 a float32 partial a range, summed after the call. The two-pass pair (that
 sweep for dk/dv alone and a dq kernel that sweeps kv blocks per q block) is
 what ``impl_bwd="pallas"`` pins: the tests' second oracle and the sweep's
-other column. All rebuild p from the saved LSE (no second online softmax). A block wholly
-above the causal diagonal or outside the window is skipped and fetches
-nothing: the swept operand's index map is clamped to the live range, so a
-dead step names the block already resident. Blocks come from the shape
+other column. All rebuild p from the saved LSE (no second online softmax).
+A block wholly above the causal diagonal or outside the window is no grid
+step at all: a masked call's forward and fused backward walk a TABLE of
+their live tiles (``kernel_dispatch.flash_walk``, scalar-prefetched; grid
+``(B*KV, live tiles)`` in the rectangle's order, so every sum takes the same
+terms in turn). An unmasked call has no dead tile and keeps the rectangle; so
+do the pair and a call whose table would pass SMEM
+(``kernel_dispatch.walked``), where a dead step is skipped and fetches
+nothing: the swept operand's index map is clamped to the live range and names
+the block already resident. Blocks come from the shape
 (``kernel_dispatch.choose_blocks``: 1024 folded query rows a step at any
 group, and 512 keys or as many as the queries, where the sequences allow).
 
@@ -122,14 +128,23 @@ def _mask_scores(s, q_pos, k_pos, causal, window):
     return s
 
 
-def _when_live(qi, ki, block_q, block_k, causal, window, compute):
+def _when_live(qi, ki, block_q, block_k, causal, window, compute, flags=None):
     """Run ``compute(masked)`` on the [q block qi] x [kv block ki] tile if it
     is live (some (q, k) pair unmasked), with ``masked=False`` if it is
     interior (every pair unmasked), so that it skips the iota + select mask
     chain (splash-style full/edge specialization: at seq >> block most live
-    tiles are interior)."""
+    tiles are interior). ``flags``: the step's entry of a walk's flags table,
+    which says both (``kernel_dispatch.flash_walk``); on the rectangle they
+    are worked out from the tile's place."""
     if not causal and window is None:
         compute(masked=False)
+        return
+    if flags is not None:
+        from .kernel_dispatch import EDGE, LIVE
+        edge = _flag(flags, EDGE)
+        pl.when(_flag(flags, LIVE) & jnp.logical_not(edge))(
+            lambda: compute(masked=False))
+        pl.when(edge)(lambda: compute(masked=True))
         return
     live = interior = True
     if causal:
@@ -148,6 +163,11 @@ def _when_live(qi, ki, block_q, block_k, causal, window, compute):
     @pl.when(live & jnp.logical_not(interior))
     def _():
         compute(masked=True)
+
+
+def _flag(flags, bit):
+    """Whether ``bit`` is set in a step's entry of a walk's flags table."""
+    return (flags & bit) != 0
 
 
 def _live_kv_block(i, j, block_q, block_k, num_kv, causal, window):
@@ -193,11 +213,22 @@ def _compiler_params(vmem_bytes):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
                 *, scale, causal, block_q, block_k, num_kv, window=None,
-                softcap=None):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+                softcap=None, walk=None):
+    """``walk``: None on the rectangle, where the step's tile and the ends
+    of its query block's sweep are read off the grid; else (qi, ki, flags) as
+    ``_fwd_table_kernel`` reads them off its tables."""
+    from .kernel_dispatch import CLOSES, OPENS
+    if walk is None:
+        qi = pl.program_id(1)
+        ki = pl.program_id(2)
+        flags = None
+        opens, closes = (lambda: ki == 0), (lambda: ki == num_kv - 1)
+    else:
+        qi, ki, flags = walk
+        opens, closes = (functools.partial(_flag, flags, bit)
+                         for bit in (OPENS, CLOSES))
 
-    @pl.when(ki == 0)
+    @pl.when(opens())
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, NEG_INF)
@@ -243,9 +274,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
         acc[:] = acc[:] * _lanes(corr, acc.shape[1]) + pv
         m_s[:] = m_cur
 
-    _when_live(qi, ki, block_q, block_k, causal, window, _compute)
+    _when_live(qi, ki, block_q, block_k, causal, window, _compute, flags)
 
-    @pl.when(ki == num_kv - 1)
+    @pl.when(closes())
     def _finalize():
         g, bq, d = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
         l = l_s[:]
@@ -255,6 +286,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
         m_safe = jnp.where(m_s[:] <= NEG_INF, 0.0, m_s[:])
         lse = jnp.where(l == 0.0, LSE_MASKED, m_safe + jnp.log(safe_l))
         lse_ref[0] = lse[:, :1].reshape(g, bq, 1)
+
+
+def _fwd_table_kernel(q_tiles, k_tiles, flags, *refs, **static):
+    """``_fwd_kernel`` on grid ``(B*KV, live tiles)``: the step's tile and
+    what it opens and closes come from the tables
+    (``kernel_dispatch.flash_walk``, query-major: a query block's sums open
+    on its first listed tile and close on its last)."""
+    step = pl.program_id(1)
+    _fwd_kernel(*refs, walk=(q_tiles[step], k_tiles[step], flags[step]), **static)
+
+
+def _grid_spec(tables, **spec):
+    """The call's grid and blocks: with ``tables`` (scalar-prefetched, every
+    index map and the kernel read them after the grid's indices) or plain."""
+    if tables:
+        return pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(tables), **spec)
+    return pl.GridSpec(**spec)
 
 
 def _regroup(q, k, v):
@@ -287,9 +335,12 @@ def _blocked(Sq, Sk, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
-               softcap=None):
-    """Per-head Pallas forward → (o, lse[B*KV, G, Sq, 1])."""
-    from .kernel_dispatch import flash_vmem_bytes, vmem_width
+               softcap=None, table=False):
+    """Per-head Pallas forward → (o, lse[B*KV, G, Sq, 1]). ``table``: the
+    grid is ``(B*KV, live tiles)``, a query block's key blocks ascending as
+    on the rectangle ``(B*KV, q blocks, kv blocks)``, whose dead steps it
+    leaves out."""
+    from . import kernel_dispatch as kd
     B, Sq, H, D = q.shape
     Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[3]
     assert H % KV == 0, (H, KV)
@@ -297,43 +348,67 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None,
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
 
     qg, kt, vt = _regroup(q, k, v)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, num_kv=num_kv,
-                               window=window, softcap=softcap)
+    static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                  num_kv=num_kv, window=window, softcap=softcap)
+    if table:
+        tables = kd.flash_walk("fwd", num_q, num_kv, block_q, block_k, causal,
+                               window)
+        kernel = functools.partial(_fwd_table_kernel, **static)
+        grid = (B * KV, len(tables[0]))
 
-    def kv_map(b, i, j):
-        return (b, _live_kv_block(i, j, block_q, block_k, num_kv, causal,
-                                  window), 0)
+        def q_blk(s, q_tiles, k_tiles, flags):
+            return q_tiles[s]
+
+        def kv_blk(s, q_tiles, k_tiles, flags):
+            return k_tiles[s]
+    else:
+        tables = ()
+        kernel = functools.partial(_fwd_kernel, **static)
+        grid = (B * KV, num_q, num_kv)
+
+        def q_blk(i, j):
+            return i
+
+        def kv_blk(i, j):
+            return _live_kv_block(i, j, block_q, block_k, num_kv, causal, window)
+
+    def q_map(b, *at):
+        return (b, 0, q_blk(*at), 0)
+
+    def kv_map(b, *at):
+        return (b, kv_blk(*at), 0)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * KV, num_q, num_kv),
-        in_specs=[
-            pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, block_k, D), kv_map),
-            pl.BlockSpec((1, block_k, Dv), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, G, block_q, Dv), lambda b, i, j: (b, 0, i, 0)),
-            # trailing unit lane dim: every reshape of the LSE then keeps the
-            # minormost dim intact (a supported Mosaic shape cast), unlike
-            # (1,G,BQ)->(G*BQ,1) which fails to lower for G > 1
-            pl.BlockSpec((1, G, block_q, 1), lambda b, i, j: (b, 0, i, 0)),
-        ],
+        grid_spec=_grid_spec(
+            tables,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, G, block_q, D), q_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, Dv), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, G, block_q, Dv), q_map),
+                # trailing unit lane dim: every reshape of the LSE then keeps
+                # the minormost dim intact (a supported Mosaic shape cast),
+                # unlike (1,G,BQ)->(G*BQ,1) which fails to lower for G > 1
+                pl.BlockSpec((1, G, block_q, 1), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((G * block_q, Dv), jnp.float32),
+                pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
+                pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B * KV, G, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * KV, G, Sq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((G * block_q, Dv), jnp.float32),
-            pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
-            pltpu.VMEM((G * block_q, STAT_LANES), jnp.float32),
-        ],
-        compiler_params=_compiler_params(flash_vmem_bytes(
-            "fwd", G, vmem_width(D, Dv), q.dtype.itemsize, block_q, block_k)),
+        compiler_params=_compiler_params(kd.flash_vmem_bytes(
+            "fwd", G, kd.vmem_width(D, Dv), q.dtype.itemsize, block_q, block_k)),
         interpret=interpret,
         name=_kernel_name("flash_fwd", "mla_fwd", D, Dv),
-    )(qg, kt, vt)
+    )(*tables, qg, kt, vt)
     o = (out.reshape(B, KV, G, Sq, Dv).transpose(0, 3, 1, 2, 4)
          .reshape(B, Sq, H, Dv))
     return o, lse
@@ -401,7 +476,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                  scale, causal, block_q, block_k, num_q, num_kv, fused,
-                 ranges=1, window=None, softcap=None):
+                 ranges=1, window=None, softcap=None, walk=None):
     """dK and dV of one kv block over a sweep of the q blocks (innermost)
     and, ``fused``, dQ from the same score tiles: the dQ of this KV head's
     queries accumulates in float32 in ``dq_acc`` [q blocks, G*BQ, D] over
@@ -414,29 +489,48 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     that ``dq_acc`` holds one range's dQ; a kv block's dK and dV then leave
     as one float32 partial a range, for the caller to sum, and the results
     come dq first (``benchmark/flash_cost.py`` reads a call's heads,
-    sequence and head size off its first result)."""
+    sequence and head size off its first result).
+
+    ``walk``: None on the rectangle, where the step's tile and the ends of
+    its sweeps are read off the grid; else (qi, ki, flags) as
+    ``_dkdv_table_kernel`` reads them off its tables. There a q block's dQ
+    opens on its first live kv block and leaves on its last, the diagonal's,
+    which is where its sum is whole."""
+    from .kernel_dispatch import CLOSES, DQ_CLOSES, DQ_OPENS, OPENS
     if ranges == 1:
         if fused:
             dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
         else:
             dk_ref, dv_ref, dk_acc, dv_acc = refs
-        ki, qr = pl.program_id(1), pl.program_id(2)
-        qi, last_kv = qr, num_kv - 1
     else:
         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc = refs
-        # qr: the q block within the range; qi: within the sequence
-        r, ki, qr = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-        qi = r * (num_q // ranges) + qr
-        last_kv = _live_kv_block(r, num_kv - 1, block_q * (num_q // ranges),
-                                 block_k, num_kv, causal, window)
+    if walk is not None:
+        qi, ki, flags = walk
+        qr = qi if ranges == 1 else qi % (num_q // ranges)
+        opens, closes, dq_opens, dq_closes = (
+            functools.partial(_flag, flags, bit)
+            for bit in (OPENS, CLOSES, DQ_OPENS, DQ_CLOSES))
+    else:
+        flags = None
+        if ranges == 1:
+            ki, qr = pl.program_id(1), pl.program_id(2)
+            qi, last_kv = qr, num_kv - 1
+        else:
+            # qr: the q block within the range; qi: within the sequence
+            r, ki, qr = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+            qi = r * (num_q // ranges) + qr
+            last_kv = _live_kv_block(r, num_kv - 1, block_q * (num_q // ranges),
+                                     block_k, num_kv, causal, window)
+        opens, closes = (lambda: qr == 0), (lambda: qr == num_q // ranges - 1)
+        dq_opens, dq_closes = (lambda: ki == 0), (lambda: ki == last_kv)
 
-    @pl.when(qr == 0)
+    @pl.when(opens())
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     if fused:
-        @pl.when(ki == 0)
+        @pl.when(dq_opens())
         def _init_dq():
             dq_acc[qr] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
@@ -488,9 +582,9 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 ds, k, (((0, ), (0, )), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _when_live(qi, ki, block_q, block_k, causal, window, _compute)
+    _when_live(qi, ki, block_q, block_k, causal, window, _compute, flags)
 
-    @pl.when(qr == num_q // ranges - 1)
+    @pl.when(closes())
     def _finalize():
         if ranges == 1:
             dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
@@ -500,27 +594,40 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             dv_ref[0, 0] = dv_acc[:]
 
     if fused:
-        @pl.when(ki == last_kv)
+        @pl.when(dq_closes())
         def _finalize_dq():
             g, bq = dq_ref.shape[1], dq_ref.shape[2]
             dq_ref[0] = dq_acc[qr].reshape(g, bq, -1).astype(dq_ref.dtype)
 
 
+def _dkdv_table_kernel(q_tiles, k_tiles, dq_tiles, flags, *refs, **static):
+    """The fused ``_dkdv_kernel`` on grid ``(B*KV, live tiles)``: the step's
+    tile and what it opens and closes come from the tables
+    (``kernel_dispatch.flash_walk``: key-major, a query range after another; a
+    kv block's dK and dV open on the first listed tile of its sweep of one
+    range and close on the last). ``dq_tiles`` is the dQ result's index
+    map's alone."""
+    step = pl.program_id(1)
+    _dkdv_kernel(*refs, walk=(q_tiles[step], k_tiles[step], flags[step]), **static)
+
+
 def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=None,
-               softcap=None, fused=False, ranges=1):
+               softcap=None, fused=False, ranges=1, table=False):
     """Per-head Pallas backward; ``res`` carries lse in the per-head
     layout, [B*KV, G, Sq] as the forward rule keeps it (or with the kernels'
     trailing unit dimension). ``fused``: one ``flash_dkdv_dq`` call in place
     of ``flash_dq`` and ``flash_dkdv``, its walk made ``ranges`` query ranges
     at a time (``kernel_dispatch`` decides: the float32 dQ of a KV head's
-    range has to fit in VMEM)."""
-    from .kernel_dispatch import flash_vmem_bytes, vmem_width
+    range has to fit in VMEM). ``table``: the fused call's grid is ``(B*KV,
+    live tiles)``, the rectangle's walk with its dead steps left out."""
+    from .kernel_dispatch import flash_vmem_bytes, flash_walk, vmem_width
     q, k, v, o, lse = res
     B, Sq, H, D = q.shape
     Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // KV
     block_q, block_k, num_q, num_kv = _blocked(Sq, Sk, block_q, block_k)
     assert fused or ranges == 1, "only the fused backward walks by ranges"
+    assert fused or not table, "only the fused backward walks a table"
     assert num_q % ranges == 0, (
         f"{ranges} ranges must each hold whole q blocks: {num_q} of {block_q}")
     range_q = num_q // ranges       # q blocks a range
@@ -565,7 +672,31 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         return (x.reshape(B * KV, G, num_q, block_q).transpose(0, 2, 1, 3)
                 .reshape(B * KV, num_q, 1, G * block_q))
 
-    if ranges == 1:
+    tables = ()
+    if table:
+        # (KV head, live tile): a kv block's q blocks ascending, a range after
+        # another; a (range, kv block) with no live tile keeps one dead step,
+        # which writes its zero partial
+        tables = flash_walk("bwd", num_q, num_kv, block_q, block_k, causal,
+                            window, ranges)
+        grid = (B * KV, len(tables[0]))
+
+        def q_blk(s, q_tiles, k_tiles, dq_tiles, flags):
+            return q_tiles[s]
+
+        def kv_blk(s, q_tiles, k_tiles, dq_tiles, flags):
+            return k_tiles[s]
+
+        def dq_blk(s, q_tiles, k_tiles, dq_tiles, flags):
+            # the q block whose dQ is completed next: held until the step
+            # that completes it, so each is written once, whole
+            return dq_tiles[s]
+
+        def dkv_at(s, q_tiles, k_tiles, dq_tiles, flags):
+            if ranges == 1:
+                return (k_tiles[s], )
+            return q_tiles[s] // range_q, k_tiles[s]
+    elif ranges == 1:
         grid = (B * KV, num_kv, num_q)
 
         def q_blk(j, i):
@@ -581,10 +712,8 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
             # result
             return jnp.where(j == num_kv - 1, i, 0)
 
-        dkv_shape, dkv_dtype = (B * KV, Sk), (k.dtype, v.dtype)
-
-        def dkv_map(width):
-            return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
+        def dkv_at(j, i):
+            return (j, )
     else:
         # (KV head, range, kv block, q block of the range): a dead step,
         # and a dead sweep (a kv block past a causal range, or before its
@@ -611,12 +740,17 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
             return r * range_q + jnp.where(
                 j < last, 0, jnp.where(j == last, i, range_q - 1))
 
-        # a kv block's dK and dV of one range: float32, summed below
+        def dkv_at(r, j, i):
+            return r, j
+
+    if ranges == 1:
+        dkv_shape, dkv_dtype = (B * KV, Sk), (k.dtype, v.dtype)
+    else:   # a kv block's dK and dV of one range: float32, summed below
         dkv_shape, dkv_dtype = (B * KV, ranges, Sk), (jnp.float32, ) * 2
 
-        def dkv_map(width):
-            return pl.BlockSpec((1, 1, block_k, width),
-                                lambda b, r, j, i: (b, r, j, 0))
+    def dkv_map(width):
+        return pl.BlockSpec((1, ) * (len(dkv_shape) - 1) + (block_k, width),
+                            lambda b, *ids: (b, *dkv_at(*ids), 0))
 
     q_spec2 = pl.BlockSpec((1, G, block_q, D),
                            lambda b, *ids: (b, 0, q_blk(*ids), 0))
@@ -641,18 +775,19 @@ def _flash_bwd(res, g_out, scale, causal, block_q, block_k, interpret, window=No
         out_shape.insert(at, dq_shape)
         scratch_shapes.append(pltpu.VMEM((range_q, G * block_q, D), jnp.float32))
     outs = pl.pallas_call(
-        functools.partial(_dkdv_kernel, num_q=num_q, num_kv=num_kv,
-                          fused=fused, ranges=ranges, **static),
-        grid=grid,
-        in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, r_spec2, r_spec2],
-        out_specs=out_specs,
+        functools.partial(_dkdv_table_kernel if table else _dkdv_kernel,
+                          num_q=num_q, num_kv=num_kv, fused=fused,
+                          ranges=ranges, **static),
+        grid_spec=_grid_spec(
+            tables, grid=grid,
+            in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, r_spec2, r_spec2],
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
         out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
         compiler_params=params,
         interpret=interpret,
         name=(_kernel_name("flash_dkdv_dq", "mla_bwd", D, Dv) if fused
               else _kernel_name("flash_dkdv", "mla_bwd_dkdv", D, Dv)),
-    )(qg, kt, vt, dog, rows(lse), rows(delta))
+    )(*tables, qg, kt, vt, dog, rows(lse), rows(delta))
     if not fused:
         dk, dv = outs
     elif ranges == 1:
@@ -704,7 +839,8 @@ def _dispatched_attention(q, k, v, scale, causal, window, softcap, interpret,
     trace time): the fused kernel in its ranges, or the dq + dk/dv pair
     where a caller pinned it."""
     return _flash_fwd(q, k, v, scale, causal, fwd_dec.block_q,
-                      fwd_dec.block_k, interpret, window, softcap)[0]
+                      fwd_dec.block_k, interpret, window, softcap,
+                      table=fwd_dec.table)[0]
 
 
 # What a layer's backward needs of an attention kernel's forward, by the
@@ -734,7 +870,7 @@ def _fwd_rule(q, k, v, scale, causal, window, softcap, interpret, fwd_dec,
               bwd_dec):
     o, lse = _name_residuals(*_flash_fwd(
         q, k, v, scale, causal, fwd_dec.block_q, fwd_dec.block_k, interpret,
-        window, softcap))
+        window, softcap, table=fwd_dec.table))
     return o, (q, k, v, o, lse)
 
 
@@ -742,7 +878,8 @@ def _bwd_rule(scale, causal, window, softcap, interpret, fwd_dec, bwd_dec,
               res, g):
     return _flash_bwd(res, g, scale, causal, bwd_dec.block_q,
                       bwd_dec.block_k, interpret, window, softcap,
-                      fused=bwd_dec.impl == "fused", ranges=bwd_dec.ranges)
+                      fused=bwd_dec.impl == "fused", ranges=bwd_dec.ranges,
+                      table=bwd_dec.table)
 
 
 _dispatched_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -770,7 +907,8 @@ def flash_attention(q,
                     force_pallas: Optional[bool] = None,
                     interpret: bool = False,
                     impl_bwd: Optional[str] = None,
-                    ranges: Optional[int] = None):
+                    ranges: Optional[int] = None,
+                    table: Optional[bool] = None):
     """Blocked attention; q [B, S, H, D], k/v [B, S, KV, D] (GQA native);
     v may be ``[B, S, KV, Dv]`` with ``Dv != D`` (the result is then ``Dv``
     wide and the kernels are the ``mla_*`` calls).
@@ -784,6 +922,12 @@ def flash_attention(q,
     (tests, the sweep tool); blocks otherwise follow from the shape
     (``kernel_dispatch.choose_blocks``). Off a TPU without ``interpret``,
     ``_xla_attention`` runs both ways.
+
+    A causal or windowed call's grid is a table of its live tiles, in the
+    order the rectangle walked them (``kernel_dispatch.walked``: the forward
+    and the fused backward, up to its cap); an unmasked call has no dead
+    tile and keeps the rectangle. ``table`` pins either walk of a masked call
+    (``False``: the clamped rectangle): the results are equal bit for bit.
 
     The kernels count a causal mask or a window from the first query and
     the first key; ``_xla_attention`` aligns the last query with the last
@@ -805,9 +949,10 @@ def flash_attention(q,
                       window, softcap, v_dim=v.shape[-1])
     blocks = ((block_q, block_k)
               if block_q is not None and block_k is not None else None)
-    fwd_dec, bwd_dec = (_fit_blocks(dec, q.shape[1], k.shape[1])
-                        for dec in kd.resolve(sig, impl_bwd=impl_bwd,
-                                              blocks=blocks, ranges=ranges))
+    fwd_dec, bwd_dec = (
+        kd.walked(sig, _fit_blocks(dec, q.shape[1], k.shape[1]), leg, table)
+        for leg, dec in zip(("fwd", "bwd"), kd.resolve(
+            sig, impl_bwd=impl_bwd, blocks=blocks, ranges=ranges)))
     return _flash_attention_call(q, k, v, scale, causal, window,
                                  softcap, interpret, fwd_dec, bwd_dec)
 

@@ -14,6 +14,10 @@ variable and no file is read on the way. The routes this replaced (an XLA forwar
 rule on the head size, head-folded kernels, a measured table) lost to these
 two at every point of a sweep on the chip: PERF.md, PR 44.
 
+A causal or windowed call's grid is a table of its live tiles (``walked``,
+``flash_walk``: listed here at trace time, from the blocks and the mask);
+an unmasked call keeps the rectangle.
+
 Blocks are ``choose_blocks``, a pure function of the shape signature and a
 VMEM estimate of the leg's tiles (1024 folded query rows a step at any GQA
 group, and 512 keys or as many as the queries). The block-diffusion kernels'
@@ -23,6 +27,8 @@ kernel of ``ops/`` sizes its limit by (``vmem_limit_bytes``) live here too.
 
 import math
 from typing import NamedTuple, Optional
+
+import numpy as np
 
 # the backward's two implementations, both per-head Pallas kernels
 # (ops/attention.py): the pair ``flash_dq`` + ``flash_dkdv``, and the
@@ -49,15 +55,23 @@ class ShapeSig(NamedTuple):
     # the values' width where it is not the keys' (latent attention: keys
     # 192, values 128; the ``mla_*`` calls); 0 = ``head_dim``
     v_dim: int = 0
+    # the window's keys where ``windowed`` (the live tiles are counted from it)
+    window: int = 0
 
 
 class Decision(NamedTuple):
     """One leg's kernel and blocks, and the query ranges the fused backward
-    makes its walk in (1: the whole sequence at once; every other leg)."""
+    makes its walk in (1: the whole sequence at once; every other leg).
+    ``walked`` adds the leg's walk over a KV head's score tiles: ``tiles``
+    of them hold an unmasked pair, the rectangle has ``grid``, and ``table``
+    says that the call's grid is a list of the first and not the second."""
     impl: str
     block_q: int
     block_k: int
     ranges: int = 1
+    tiles: int = 0
+    grid: int = 0
+    table: bool = False
 
 
 def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
@@ -68,7 +82,8 @@ def make_sig(q_shape, kv_heads: int, seq_k: int, dtype, causal: bool,
                     dtype=str(dtype), causal=bool(causal),
                     windowed=window is not None,
                     softcapped=softcap is not None, pattern=pattern,
-                    v_dim=0 if int(v_dim) == int(d) else int(v_dim))
+                    v_dim=0 if int(v_dim) == int(d) else int(v_dim),
+                    window=0 if window is None else int(window))
 
 
 # What the block choice aims at (sweeps on a v5e: PR 25 at group 4, PR 32 at
@@ -544,20 +559,157 @@ def resolve(sig: ShapeSig, *, impl_bwd: Optional[str] = None,
             Decision(impl_bwd, *map(int, bwd_blocks), ranges=int(ranges)))
 
 
+# A causal or windowed call's grid is a TABLE of its live tiles (PR 60): a
+# tile past the diagonal or outside the window copied and multiplied nothing
+# on the rectangle, but was a grid step, and a step has a fixed cost (some
+# 0.2 us on a v5e: docs/kernel_dispatch.md). The tables are int32 in SMEM by
+# scalar prefetch, three a forward step and four a fused backward's, and a
+# v5e core's SMEM is 1 MiB. Past this many steps a KV head the call keeps the
+# clamped rectangle (from the shape alone: ``walked``).
+TABLE_CAP_TILES = 49152
+
+# A step's entry in a walk's flags table: what the kernel would otherwise
+# work out from the mask, a step: whether the step OPENS and CLOSES its run
+# (a forward's query block, a backward's key block of one range: the sums
+# that are zeroed and written there), whether its tile is LIVE and on the
+# mask's EDGE (live and not interior: it needs the element mask), and, in a
+# key-major walk, whether it is its query block's first (DQ_OPENS) and last
+# (DQ_CLOSES) live step.
+OPENS, CLOSES, LIVE, EDGE, DQ_OPENS, DQ_CLOSES = 1, 2, 4, 8, 16, 32
+
+
+def live_tiles(num_q: int, num_k: int, block_q: int, block_k: int,
+               causal: bool, window: Optional[int] = None) -> tuple:
+    """(live, interior), each ``[num_q, num_k]`` bool: the (query tile, key
+    tile) pairs in which the mask lets SOME (query, key) through, and those
+    in which it lets EVERY one: ``ops/attention.py::_when_live``'s tests,
+    listed. Any other structured mask is another such pair of matrices (a
+    learned sparse call's causal one is this with no window; block
+    diffusion's has an own-block column)."""
+    q0 = np.arange(num_q)[:, None] * block_q
+    k0 = np.arange(num_k)[None, :] * block_k
+    live = np.ones((num_q, num_k), bool)
+    interior = live.copy()
+    if causal:
+        live &= k0 <= q0 + block_q - 1
+        interior &= k0 + block_k - 1 <= q0
+    if window is not None:
+        live &= k0 + block_k - 1 >= q0 - (window - 1)
+        interior &= q0 + block_q - 1 - k0 <= window - 1
+    return live, live & interior
+
+
+def tile_walk(live: np.ndarray, major: str = "q", ranges: int = 1) -> tuple:
+    """The walk over ``live`` as two int32 tables, (query tile, key tile) a
+    grid step. ``major`` "q": a query tile's key tiles ascending, one query
+    tile after another (a forward: a row's sums open and close once); "k": a
+    key tile's query tiles ascending (a backward's dK and dV), within each of
+    ``ranges`` equal runs of query tiles in turn. A major tile with no live
+    tile keeps ONE step, its first, so that every one opens and closes
+    exactly once and its zero result is written; that step is not LIVE."""
+    per = live.shape[0] // ranges
+    q_tiles, k_tiles = [], []
+    for first in range(0, live.shape[0], per):
+        part = live[first:first + per]
+        part = (part if major == "q" else part.T).copy()
+        part[~part.any(axis=1), 0] = True
+        outer, inner = np.nonzero(part)
+        q, k = (outer, inner) if major == "q" else (inner, outer)
+        q_tiles.append(q + first)
+        k_tiles.append(k)
+    return (np.concatenate(q_tiles).astype(np.int32),
+            np.concatenate(k_tiles).astype(np.int32))
+
+
+def flash_walk(leg: str, num_q: int, num_k: int, block_q: int, block_k: int,
+               causal: bool, window: Optional[int], ranges: int = 1) -> tuple:
+    """The int32 tables of one KV head's walk, an entry a grid step. "fwd":
+    (query tile, key tile, flags), query-major. "bwd" (the fused kernel's):
+    (query tile, key tile, dQ's tile, flags), key-major in ``ranges`` query
+    ranges. dQ's tile is the query tile whose LAST live step comes next, at
+    the step or after it: a result that leaves once a query tile names it, so
+    its block is held from the step after the one before closed until the
+    step that completes it; each is written once, whole."""
+    live, interior = live_tiles(num_q, num_k, block_q, block_k, causal, window)
+    q_tiles, k_tiles = tile_walk(live, "q" if leg == "fwd" else "k", ranges)
+    steps = np.arange(len(q_tiles))
+    real = live[q_tiles, k_tiles]
+    run = (q_tiles if leg == "fwd"
+           else (q_tiles // (num_q // ranges)) * num_k + k_tiles)
+    turns = run[1:] != run[:-1]
+    flags = (OPENS * np.r_[True, turns] + CLOSES * np.r_[turns, True]
+             + LIVE * real + EDGE * (real & ~interior[q_tiles, k_tiles]))
+    if leg == "fwd":
+        return q_tiles, k_tiles, flags.astype(np.int32)
+    first, last = np.full(num_q, len(steps)), np.full(num_q, -1)
+    np.minimum.at(first, q_tiles[real], steps[real])
+    np.maximum.at(last, q_tiles[real], steps[real])
+    assert last.min() >= 0, "a query tile with no live key tile"
+    flags += DQ_OPENS * (first[q_tiles] == steps) + DQ_CLOSES * (last[q_tiles] == steps)
+    closes = np.sort(last)
+    upcoming = closes[np.minimum(np.searchsorted(closes, steps), num_q - 1)]
+    return q_tiles, k_tiles, q_tiles[upcoming], flags.astype(np.int32)
+
+
+def walked(sig: ShapeSig, dec: Decision, leg: str,
+           table: Optional[bool] = None) -> Decision:
+    """``dec`` (the "fwd" or "bwd" ``leg`` of ``resolve``, its blocks fitted
+    to the sequences) with its walk over a KV head's score tiles. The grid is
+    the table where the mask (causal, a window) leaves more than half as
+    many tiles dead as live, the kernel reads one (the forward and the fused
+    backward; the pair keeps its rectangles) and the table has at most
+    TABLE_CAP_TILES steps. On a v5e a dead step of the rectangle costs some
+    0.2 us and a table makes every live step 0.06 (forward) to 0.09 us
+    (backward) dearer, every operand's block index being read from SMEM
+    (docs/kernel_dispatch.md), so a call with few dead tiles (three of four
+    live) and an unmasked call, which has none, keep the rectangle.
+    ``table`` pins either walk of a masked call (tests, the sweep tool:
+    ``False`` the clamped rectangle, ``True`` the table wherever a tile is
+    dead, past the cap too)."""
+    block_q, block_k = min(dec.block_q, sig.seq_q), min(dec.block_k, sig.seq_k)
+    if sig.pattern or sig.seq_q % block_q or sig.seq_k % block_k:
+        return dec
+    num_q, num_k = sig.seq_q // block_q, sig.seq_k // block_k
+    grid = tiles = num_q * num_k
+    if sig.causal or sig.windowed:
+        ranges = dec.ranges if num_q % dec.ranges == 0 else 1
+        live, _ = live_tiles(num_q, num_k, block_q, block_k, sig.causal,
+                             sig.window if sig.windowed else None)
+        tiles = len(tile_walk(live, "q" if leg == "fwd" else "k", ranges)[0])
+    reads_one = leg == "fwd" or dec.impl == IMPL_FUSED
+    if table is None:
+        table = tiles <= TABLE_CAP_TILES and 2 * (grid - tiles) > tiles
+    return dec._replace(tiles=int(tiles), grid=int(grid),
+                        table=bool(table and reads_one and tiles < grid))
+
+
 def describe(fwd: Decision, bwd: Decision) -> str:
     """Compact per-leg note for reports and artifacts, e.g.
     ``attn[fwd=pallas@256x512,bwd=fused@512x512]``; a backward walked in
-    several query ranges says how many (``bwd=fused@256x512/r8``)."""
-    return (f"attn[fwd={fwd.impl}@{fwd.block_q}x{fwd.block_k},"
-            f"bwd={bwd.impl}@{bwd.block_q}x{bwd.block_k}"
-            f"{f'/r{bwd.ranges}' if bwd.ranges > 1 else ''}]")
+    several query ranges says how many (``bwd=fused@256x512/r8``), and legs
+    that ``walked`` counted say their grid steps a KV head, live tiles of the
+    rectangle's (``fwd=pallas@1024x1024 tiles=136/256, bwd=fused@512x512
+    tiles=528/1024``; ``(grid)`` behind them where a masked leg keeps the
+    clamped rectangle)."""
+    def leg(name, dec):
+        note = (f"{name}={dec.impl}@{dec.block_q}x{dec.block_k}"
+                f"{f'/r{dec.ranges}' if dec.ranges > 1 else ''}")
+        if dec.grid:
+            note += (f" tiles={dec.tiles}/{dec.grid}"
+                     f"{'' if dec.table or dec.tiles == dec.grid else '(grid)'}")
+        return note
+
+    return (f"attn[{leg('fwd', fwd)}{', ' if fwd.grid or bwd.grid else ','}"
+            f"{leg('bwd', bwd)}]")
 
 
 def resolved_note(batch=8, seq=1024, heads=16, kv_heads=None, head_dim=64,
                   dtype="bfloat16", causal=True, window=None) -> str:
     """The per-leg note at a given shape (default: the 0.4B preset's), so a
-    saved report records which kernels a TPU runs there."""
+    saved report records which kernels a TPU runs there and how many grid
+    steps each makes."""
     sig = make_sig((batch, seq, heads, head_dim),
                    kv_heads if kv_heads is not None else heads, seq, dtype,
                    causal, window, None)
-    return describe(*resolve(sig))
+    fwd, bwd = resolve(sig)
+    return describe(walked(sig, fwd, "fwd"), walked(sig, bwd, "bwd"))

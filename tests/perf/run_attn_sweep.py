@@ -23,10 +23,16 @@ Per shape the tool times:
 ``--impls`` and ``--blocks`` narrow the candidates (a block sweep of one
 impl: ``--impls pallas --blocks 512x512,1024x512,1024x1024``); ``--ranges
 8,16`` walks the fused backward in 8 and in 16 query ranges at each block
-pair (``fused@256x512/r8``).
+pair (``fused@256x512/r8``). ``--window`` and ``--v-dim`` give a windowed
+call and one whose values are narrower than its keys (``mla_*``).
+``--walks table,grid`` times a masked call on its table of live tiles and on
+the clamped rectangle (``fused@512x512:grid``) and says whether the two
+walks' results are equal bit for bit; ``--out`` appends a JSON line a row
+(the shape, the leg's ``Decision`` with its live tiles and grid steps, ms).
 """
 
 import argparse
+import json
 import os
 import sys
 import time
@@ -65,10 +71,15 @@ def _blocks_for(impl: str, sig, leg: str, quick: bool, grid=None):
 
 
 def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
-                iters, interpret, quick, impls=None, grid=None, ranges=None):
+                iters, interpret, quick, impls=None, grid=None, ranges=None,
+                window=None, v_dim=None, walks=None, out=None):
     """Sweep one shape; returns {leg: [(label, impl, blocks, ms), ...]}.
     ``grid`` replaces the kernels' block grid; ``ranges`` lists the query
-    ranges to walk the fused backward in (default: what the shape gives)."""
+    ranges to walk the fused backward in (default: what the shape gives);
+    ``walks`` the walks of a masked call to time (``True`` its table of live
+    tiles, ``False`` the clamped rectangle; default: what the shape gives),
+    whose results are compared bit for bit; ``out``: a file that takes a JSON
+    line a row."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kernel_dispatch as kd
@@ -79,32 +90,39 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
                                                     head_dim)
     q = jnp.asarray(rng.standard_normal(shp_q), dtype)
     k = jnp.asarray(rng.standard_normal(shp_kv), dtype)
-    v = jnp.asarray(rng.standard_normal(shp_kv), dtype)
+    v = jnp.asarray(rng.standard_normal(shp_kv[:3] + (v_dim or head_dim, )), dtype)
 
-    sig = kd.make_sig(shp_q, kv_heads, seq, q.dtype, causal, None, None)
+    sig = kd.make_sig(shp_q, kv_heads, seq, q.dtype, causal, window, None,
+                      v_dim=v.shape[-1])
+    device = jax.devices()[0]
+    device_kind = getattr(device, "device_kind", device.platform)
     impls = impls or (IMPL_XLA, kd.IMPL_PALLAS, kd.IMPL_FUSED)
 
-    def attend(impl, blocks, walk=None):
+    def attend(impl, blocks, walk=None, table=None):
         if impl == IMPL_XLA:
             scale = 1.0 / np.sqrt(head_dim)
-            return lambda q, k, v: _xla_attention(q, k, v, scale, causal)
+            return lambda q, k, v: _xla_attention(q, k, v, scale, causal, window)
         bq, bk = blocks
         return lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, interpret=interpret, impl_bwd=impl,
-            block_q=bq, block_k=bk, ranges=walk)
+            q, k, v, causal=causal, window=window, interpret=interpret,
+            impl_bwd=impl, block_q=bq, block_k=bk, ranges=walk, table=table)
 
-    def fwd_fn(impl, blocks, walk=None):
-        f = jax.jit(attend(impl, blocks))
+    def fwd_fn(impl, blocks, walk=None, table=None):
+        f = jax.jit(attend(impl, blocks, table=table))
         return lambda: f(q, k, v)
 
-    def bwd_fn(impl, blocks, walk=None):
+    def bwd_fn(impl, blocks, walk=None, table=None):
         # the pullback alone: one untimed forward leaves its residuals, and
         # only the backward's kernels are in the timing (the reference's
         # float32 scores are 4 GiB at 4 x 16 x 4096^2)
-        out, pull = jax.vjp(attend(impl, blocks, walk), q, k, v)
-        g = jnp.ones_like(out)
+        res, pull = jax.vjp(attend(impl, blocks, walk, table), q, k, v)
+        g = jnp.ones_like(res)
         run = jax.jit(lambda pull, g: pull(g))
         return lambda: run(pull, g)
+
+    def same(a, b):
+        return all(bool(jnp.array_equal(x, y)) for x, y in
+                   zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
 
     results = {}
     for leg, make in (("fwd", fwd_fn), ("bwd", bwd_fn)):
@@ -112,31 +130,59 @@ def sweep_shape(batch, seq, heads, kv_heads, head_dim, dtype, causal, *,
         for impl in impls:
             if leg == "fwd" and impl == kd.IMPL_FUSED:
                 continue    # a backward: its forward is the per-head one
-            seen = set()
-            walks = ranges if leg == "bwd" and impl == kd.IMPL_FUSED and ranges else [None]
-            for blocks, walk in ((b, w) for b in _blocks_for(impl, sig, leg, quick, grid)
-                                 for w in walks):
+            in_ranges = ranges if leg == "bwd" and impl == kd.IMPL_FUSED and ranges else [None]
+            tables = walks if impl != IMPL_XLA and walks else [None]
+            # candidates seen, and (blocks, ranges) -> its first walk's results
+            seen, first = set(), {}
+            for blocks, walk, table in (
+                    (b, w, t) for b in _blocks_for(impl, sig, leg, quick, grid)
+                    for w in in_ranges for t in tables):
                 if blocks is not None:
-                    # a tile can't exceed the sequence — clamp, then dedupe
-                    # (several candidates can clamp to the same point)
+                    # a tile can't exceed the sequence: clamp
                     blocks = (min(blocks[0], seq), min(blocks[1], seq))
-                    if (blocks, walk) in seen:
-                        continue
-                    seen.add((blocks, walk))
                 label = impl if blocks is None else (
                     f"{impl}@{blocks[0]}x{blocks[1]}")
+                line = {"shape": [batch, seq, heads, kv_heads, head_dim,
+                                  v.shape[-1]],
+                        "causal": causal, "window": window, "dtype": str(dtype),
+                        "device": device_kind, "leg": leg, "impl": impl}
                 try:
-                    if leg == "bwd" and impl == kd.IMPL_FUSED:
-                        walked = kd.resolve(sig, impl_bwd=impl, blocks=blocks,
-                                            ranges=walk)[1].ranges
-                        label += f"/r{walked}" if walked > 1 else ""
-                    ms = _time(make(impl, blocks, walk), iters)
+                    if impl != IMPL_XLA:
+                        dec = kd.resolve(sig, impl_bwd=impl if leg == "bwd" else None,
+                                         blocks=blocks, ranges=walk)[leg == "bwd"]
+                        dec = kd.walked(sig, dec, leg, table)
+                        # several candidates can clamp to the same point, and
+                        # a pin of the walk can change nothing
+                        if (blocks, walk, dec.table) in seen:
+                            continue
+                        seen.add((blocks, walk, dec.table))
+                        label += f"/r{dec.ranges}" if dec.ranges > 1 else ""
+                        # two walks side by side: the one on the rectangle
+                        label += ":grid" if (
+                            len(tables) > 1 and not dec.table
+                            and dec.tiles < dec.grid
+                            and (leg == "fwd" or impl == kd.IMPL_FUSED)) else ""
+                        line.update(dec._asdict())
+                    run = make(impl, blocks, walk, table)
+                    ms = _time(run, iters)
+                    if (blocks, walk) in first:     # the second walk of a pair
+                        line["equal"] = same(first[blocks, walk], run())
+                    elif len(tables) > 1:
+                        first[blocks, walk] = run()
                 except Exception as e:  # noqa: BLE001 — report, keep sweeping
                     print(f"  {leg} {label: <18} FAILED: "
                           f"{type(e).__name__}: {e}", flush=True)
                     continue
                 rows.append((label, impl, blocks, ms))
-                print(f"  {leg} {label: <18} {ms: >9.3f} ms", flush=True)
+                line.update(label=label, ms=ms)
+                if out is not None:
+                    out.write(json.dumps(line) + "\n")
+                    out.flush()
+                print(f"  {leg} {label: <18} {ms: >9.3f} ms"
+                      + (f"  tiles {line['tiles']}/{line['grid']}"
+                         if line.get("grid") else "")
+                      + (f"  equal={line['equal']}" if "equal" in line else ""),
+                      flush=True)
         if not rows:
             print(f"  {leg}: no candidate ran")
             continue
@@ -171,6 +217,16 @@ def main(argv=None):
     ap.add_argument("--ranges", default=None,
                     help="comma list of query-range counts to walk the fused "
                          "backward in (default: what the shape gives)")
+    ap.add_argument("--window", type=int, default=None,
+                    help="a sliding window of this many keys")
+    ap.add_argument("--v-dim", type=int, default=None,
+                    help="the values' width where it is not --head-dim (mla_*)")
+    ap.add_argument("--walks", default=None,
+                    help="comma list of table,grid: a masked call on its table "
+                         "of live tiles and on the clamped rectangle, compared "
+                         "bit for bit (default: what the shape gives)")
+    ap.add_argument("--out", default=None,
+                    help="append a JSON line a row to this file")
     args = ap.parse_args(argv)
 
     import jax
@@ -184,7 +240,8 @@ def main(argv=None):
     d = jax.devices()[0]
     kv = args.kv_heads if args.kv_heads is not None else args.heads
     print(f"attn sweep: b{args.batch} s{args.seq} h{args.heads} kv{kv} "
-          f"d{args.head_dim} {args.dtype} causal={args.causal} "
+          f"d{args.head_dim}{f'|{args.v_dim}' if args.v_dim else ''} "
+          f"{args.dtype} causal={args.causal} window={args.window} "
           f"device_kind={getattr(d, 'device_kind', d.platform)!r}"
           f"{' (interpreted)' if args.interpret else ''}")
     sweep_shape(args.batch, args.seq, args.heads, kv, args.head_dim,
@@ -193,7 +250,10 @@ def main(argv=None):
                 impls=args.impls and tuple(args.impls.split(",")),
                 grid=args.blocks and [tuple(int(x) for x in b.split("x"))
                                       for b in args.blocks.split(",")],
-                ranges=args.ranges and [int(r) for r in args.ranges.split(",")])
+                ranges=args.ranges and [int(r) for r in args.ranges.split(",")],
+                window=args.window, v_dim=args.v_dim,
+                walks=args.walks and [w == "table" for w in args.walks.split(",")],
+                out=args.out and open(args.out, "a"))
     return 0
 
 

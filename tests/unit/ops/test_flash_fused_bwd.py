@@ -49,6 +49,12 @@ CASES = {
     # one q block and one kv block: init, compute and write in one step
     "g4_d64_one_step": _case(128, 4, 1, 64, (128, 128)),
 }
+# cases of the walks' comparison alone (``_walks``)
+WALK_CASES = {
+    # a q block of two kv blocks: two kv sweeps open on the same q block,
+    # and a q block's dQ leaves in the second kv block of its diagonal
+    "g1_d128_queries_past_keys": _case(512, 1, 1, 128, (256, 128)),
+}
 
 # test_flash_ranged_bwd.py's cases (the same kernel walked a query range at a
 # time) register here, so that ``_grads`` serves both files
@@ -56,11 +62,15 @@ RANGED_CASES = {}
 
 
 @functools.lru_cache(maxsize=None)
-def _grads(name, dtype, impl_bwd, ranges=None):
-    """dq, dk, dv of case ``name``, one compiled program a call (op by op,
+def _results(name, dtype, impl_bwd, ranges=None, table=None):
+    """o, dq, dk, dv of case ``name``, one compiled program a call (op by op,
     every small op of the regrouping and of the reference's backward is a
-    compile of its own); kept, so the float32 reference serves both tests."""
-    case = CASES.get(name) or RANGED_CASES[name]
+    compile of its own); kept, so the float32 reference serves every test
+    that asks. ``table`` pins a masked call's walk: its table of live tiles
+    wherever a tile is dead, or ``False`` the clamped rectangle; unpinned,
+    the rule leaves most of these small calls the rectangle (few of their
+    tiles are dead) and gives the table to four blocks a side."""
+    case = CASES.get(name) or WALK_CASES.get(name) or RANGED_CASES[name]
     rng = np.random.default_rng(11)
     shape_q = (case["b"], case["s"], case["h"], case["d"])
     shape_kv = (case["b"], case["sk"], case["kv"], case["d"])
@@ -84,9 +94,42 @@ def _grads(name, dtype, impl_bwd, ranges=None):
             return flash_attention(
                 q, k, v, causal=case["causal"], window=case["window"],
                 softcap=case["softcap"], interpret=True,
-                impl_bwd=impl_bwd, block_q=bq, block_k=bk, ranges=ranges)
-    pulled = jax.jit(lambda q, k, v, g: jax.vjp(fn, q, k, v)[1](g))(q, k, v, g)
-    return [np.asarray(x, np.float32) for x in pulled]
+                impl_bwd=impl_bwd, block_q=bq, block_k=bk, ranges=ranges,
+                table=table)
+
+    def run(q, k, v, g):
+        o, pull = jax.vjp(fn, q, k, v)
+        return (o, ) + pull(g)
+
+    return [np.asarray(x, np.float32) for x in jax.jit(run)(q, k, v, g)]
+
+
+def _grads(name, dtype, impl_bwd, ranges=None):
+    """dq, dk, dv of case ``name``."""
+    return _results(name, dtype, impl_bwd, ranges)[1:]
+
+
+def assert_the_walks_are_equal(name, dtype, ranges=None):
+    """o, dQ, dK and dV of the fused call on its table of live tiles equal
+    the clamped rectangle's BIT FOR BIT: the table lists the rectangle's live
+    steps in its order, so every sum takes the same terms in turn."""
+    table = _results(name, dtype, "fused", ranges, True)
+    grid = _results(name, dtype, "fused", ranges, False)
+    for leaf, a, b in zip(("o", "dq", "dk", "dv"), table, grid):
+        assert np.abs(b).max() > 0, leaf
+        np.testing.assert_array_equal(a, b, err_msg=leaf)
+
+
+@pytest.mark.parametrize("name,dtype", [
+    ("g1_d64", jnp.float32),                    # BQ = BK, group 1, two rows
+    ("g8_d128", jnp.bfloat16),                  # BQ < BK, group 8
+    ("g1_d128_queries_past_keys", jnp.float32),     # BQ > BK
+], ids=lambda x: x if isinstance(x, str) else x.__name__)
+def test_the_tables_walk_equals_the_rectangles_bit_for_bit(name, dtype):
+    """Causal calls, one range; under a window (narrower than a block, across
+    blocks, without ``causal``), in ranges and at two widths the walks are
+    compared in ``test_flash_ranged_bwd.py`` and ``test_mla_attention.py``."""
+    assert_the_walks_are_equal(name, dtype)
 
 
 @pytest.mark.parametrize("name", CASES)

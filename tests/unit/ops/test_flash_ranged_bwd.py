@@ -14,7 +14,8 @@ import pytest
 
 from aot_v5e import _pallas_calls
 from deepspeed_tpu.ops.attention import flash_attention
-from test_flash_fused_bwd import RANGED_CASES, _case, _grads
+from test_flash_fused_bwd import (RANGED_CASES, _case, _grads,
+                                  assert_the_walks_are_equal)
 
 # The walk by query ranges, (case, ranges): groups 1 and 8, heads 64 / 128 /
 # 256 and the latent 192 | 128, and every mask. Four q blocks in four ranges,
@@ -87,6 +88,21 @@ def test_the_walk_by_ranges_in_bfloat16(name, ranges):
         assert np.abs(a - c).max() <= 4e-2 * np.abs(c).max(), leaf
 
 
+@pytest.mark.parametrize("name,ranges,dtype", [
+    ("g1_latent_192_128", 2, jnp.float32),      # widths 192 | 128, BQ < BK
+    # eight blocks a side in two ranges: (range, kv block) pairs with no live
+    # tile past the first range's diagonal and before the second's window,
+    # each kept as one step that writes its zeros
+    ("g4_d128_window_across_a_boundary", 2, jnp.bfloat16),
+], ids=lambda x: x if isinstance(x, str) else getattr(x, "__name__", f"r{x}"))
+def test_the_ranged_tables_walk_equals_the_rectangles_bit_for_bit(name, ranges,
+                                                                  dtype):
+    """The walk by ranges on its table: dQ, and the float32 partials' sums
+    dK and dV, equal the clamped ``(KV head, range, kv block, q block)``
+    grid's bit for bit."""
+    assert_the_walks_are_equal(f"ranged_{name}", dtype, ranges)
+
+
 def _backward_call(ranges, **pins):
     """The ``pallas_call`` of the fused backward as ``flash_attention``
     traces it at ``[1, 512, 4/1, 64]``, float32, causal."""
@@ -117,8 +133,19 @@ def test_one_range_is_the_call_it_was_before_the_ranges():
     float32 dQ of the whole sequence in scratch, under the name the trace
     readers match. Pinned or resolved from the shape, it is the same call."""
     q_blk, kv_blk, row = (1, 4, 128, 64), (1, 256, 64), (1, 1, 1, 512)
-    for pins in (dict(block_q=128, block_k=256, impl_bwd="fused"),
-                 dict(block_q=128, block_k=256)):
+    # the table (PR 60) changes the grid alone: 6 live tiles of the 2 x 4,
+    # and 6 of the two ranges' 2 x 2 x 2 (the first range's second kv block
+    # has no live tile and keeps one step)
+    one = _backward_call(1, block_q=128, block_k=256, table=True)
+    assert one[0] == (1, 6) and one[1:] == _backward_call(
+        1, block_q=128, block_k=256, table=False)[1:]
+    two = _backward_call(2, block_q=128, block_k=256, table=True)
+    assert two[0] == (1, 7) and two[1:] == _backward_call(
+        2, block_q=128, block_k=256, table=False)[1:]
+    # with two dead tiles to six live the rule leaves this call the rectangle
+    assert _backward_call(1, block_q=128, block_k=256)[0] == (1, 2, 4)
+    for pins in (dict(block_q=128, block_k=256, impl_bwd="fused", table=False),
+                 dict(block_q=128, block_k=256, table=False)):
         grid, blocks, scratch, results = _backward_call(1, **pins)
         assert grid == (1, 2, 4)
         assert blocks == [q_blk, kv_blk, kv_blk, q_blk, row, row,   # q k v do lse delta
@@ -126,11 +153,12 @@ def test_one_range_is_the_call_it_was_before_the_ranges():
         assert scratch == [(256, 64), (256, 64), (4, 512, 64)]
         assert results == [((1, 512, 64), "float32"), ((1, 512, 64), "float32"),
                            ((1, 4, 512, 64), "float32")]
-    assert _backward_call(None, block_q=128, block_k=256) == (
+    assert _backward_call(None, block_q=128, block_k=256, table=False) == (
         grid, blocks, scratch, results)
     # two ranges: the range an axis of the same grid, dq first, the ranges'
     # float32 partials of dk and dv after it, half the sequence's dQ in scratch
-    grid, blocks, scratch, results = _backward_call(2, block_q=128, block_k=256)
+    grid, blocks, scratch, results = _backward_call(2, block_q=128, block_k=256,
+                                                    table=False)
     part = (1, 1, 256, 64)
     assert grid == (1, 2, 2, 2)
     assert blocks == [q_blk, kv_blk, kv_blk, q_blk, row, row, q_blk, part, part]

@@ -498,14 +498,227 @@ def test_resolution_reads_the_shape_and_nothing_else(monkeypatch):
 
 
 def test_describe_and_resolved_note():
+    """Since PR 60 a note says each leg's grid steps a KV head, live tiles of
+    the rectangle's; legs no ``walked`` counted print as they did."""
     note = kd.resolved_note()
-    assert note == "attn[fwd=pallas@1024x1024,bwd=fused@512x512]"
-    assert kd.describe(*kd.resolve(_bench_sig())) == note
+    assert note == "attn[fwd=pallas@1024x1024 tiles=1/1, bwd=fused@512x512 tiles=3/4(grid)]"
+    sig = _bench_sig()
+    fwd, bwd = kd.resolve(sig)
+    assert kd.describe(fwd, bwd) == "attn[fwd=pallas@1024x1024,bwd=fused@512x512]"
+    assert kd.describe(kd.walked(sig, fwd, "fwd"), kd.walked(sig, bwd, "bwd")) == note
     assert kd.resolved_note(batch=1, seq=32768, heads=32, kv_heads=8) == (
-        "attn[fwd=pallas@256x512,bwd=fused@512x512/r2]")
+        "attn[fwd=pallas@256x512 tiles=4160/8192, "
+        "bwd=fused@512x512/r2 tiles=2112/4096]")
     assert kd.resolved_note(batch=1, seq=32768, heads=16, kv_heads=2,
                             head_dim=256) == (
-        "attn[fwd=pallas@128x512,bwd=fused@256x512/r8]")
+        "attn[fwd=pallas@128x512 tiles=8320/16384, "
+        "bwd=fused@256x512/r8 tiles=4384/8192]")
+    # the Ouro cell's call, ISSUE 60's example; no mask, no dead tile
+    assert kd.resolved_note(batch=1, seq=16384, heads=16, head_dim=128) == (
+        "attn[fwd=pallas@1024x1024 tiles=136/256, bwd=fused@512x512 tiles=528/1024]")
+    assert kd.resolved_note(causal=False) == (
+        "attn[fwd=pallas@1024x1024 tiles=1/1, bwd=fused@512x512 tiles=4/4]")
+
+
+# ---------------------------------------------------------------------------
+# the walk of a masked call: a table of its live tiles (PR 60)
+# ---------------------------------------------------------------------------
+
+# (seq, block_q, block_k, causal, window, ranges): BQ <, =, > BK; a window
+# across blocks, inside one, alone; a range of one q block; one tile
+WALKS = {
+    "causal_square": (512, 128, 128, True, None, 1),
+    "causal_keys_wider": (1024, 128, 512, True, None, 2),
+    "causal_queries_wider": (1024, 512, 128, True, None, 1),
+    "window_across_blocks": (1024, 128, 128, True, 300, 4),
+    "window_inside_a_block": (768, 128, 256, True, 50, 3),
+    "window_wider_than_the_sequence": (512, 128, 128, True, 4096, 2),
+    "window_alone": (512, 64, 128, False, 100, 8),
+    "one_tile": (128, 128, 128, True, None, 1),
+}
+
+
+def _pairs(seq, causal, window):
+    """[seq, seq] bool, True where the query sees the key: the kernels'
+    ``_mask_scores``, literally."""
+    q, k = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    keep = np.ones((seq, seq), bool)
+    if causal:
+        keep &= k <= q
+    if window is not None:
+        keep &= q - k < window
+    return keep
+
+
+@pytest.mark.parametrize("name", WALKS)
+def test_the_listed_tiles_against_the_mask_pair_by_pair(name):
+    """Every unmasked (query, key) pair lies in a listed tile, no listed tile
+    is wholly masked but the one step a (range, major block) with no live
+    tile keeps, the order is the rectangle's with its dead steps left out,
+    and every query block (forward) and every key block of a range
+    (backward) opens and closes once; a step's flags say whether its tile is
+    live and whether the mask cuts it; the backward's dQ table names, a step,
+    the query block that completes next, each once, on its last live tile."""
+    seq, bq, bk, causal, window, ranges = WALKS[name]
+    num_q, num_k = seq // bq, seq // bk
+    pairs = _pairs(seq, causal, window).reshape(num_q, bq, num_k, bk)
+    some, every = pairs.any(axis=(1, 3)), pairs.all(axis=(1, 3))
+    live, interior = kd.live_tiles(num_q, num_k, bq, bk, causal, window)
+    np.testing.assert_array_equal(live, some)
+    np.testing.assert_array_equal(interior, every)
+
+    def ends(runs):     # a run's steps are consecutive: one open, one close
+        runs = list(runs)
+        assert 1 + sum(a != b for a, b in zip(runs, runs[1:])) == len(set(runs))
+        return ([i == 0 or runs[i - 1] != r for i, r in enumerate(runs)],
+                [i == len(runs) - 1 or runs[i + 1] != r for i, r in enumerate(runs)])
+
+    def bits(flags, bit):
+        return list((flags & bit) != 0)
+
+    q, k, flags = kd.flash_walk("fwd", num_q, num_k, bq, bk, causal, window)
+    assert q.dtype == k.dtype == flags.dtype == np.int32
+    assert some[q, k].all() and len(q) == some.sum()     # every query block is live
+    assert list(zip(q, k)) == [(i, j) for i in range(num_q) for j in range(num_k)
+                               if some[i, j]]
+    assert set(q) == set(range(num_q))
+    assert (bits(flags, kd.OPENS), bits(flags, kd.CLOSES)) == ends(q)
+    assert bits(flags, kd.LIVE) == list(some[q, k])
+    assert bits(flags, kd.EDGE) == list(some[q, k] & ~every[q, k])
+    assert not (flags & (kd.DQ_OPENS | kd.DQ_CLOSES)).any()
+
+    q, k, dq, flags = kd.flash_walk("bwd", num_q, num_k, bq, bk, causal, window, ranges)
+    per = num_q // ranges
+    rect = [(i, j) for r in range(ranges) for j in range(num_k)
+            for i in range(r * per, (r + 1) * per)]
+    dead_sweeps = [(r, j) for r in range(ranges) for j in range(num_k)
+                   if not some[r * per:(r + 1) * per, j].any()]
+    kept = [(i, j) for i, j in rect if some[i, j]
+            or ((i // per, j) in dead_sweeps and i % per == 0)]
+    assert list(zip(q, k)) == kept and len(q) == some.sum() + len(dead_sweeps)
+    sweeps = list(zip(q // per, k))
+    assert len(set(sweeps)) == ranges * num_k
+    assert (bits(flags, kd.OPENS), bits(flags, kd.CLOSES)) == ends(sweeps)
+    real = some[q, k]
+    assert bits(flags, kd.LIVE) == list(real)
+    assert bits(flags, kd.EDGE) == list(real & ~every[q, k])
+    visits = {i: [s for s in range(len(q)) if q[s] == i and real[s]]
+              for i in range(num_q)}
+    assert [s for s in range(len(q)) if flags[s] & kd.DQ_OPENS] == sorted(
+        v[0] for v in visits.values())
+    closing = sorted(v[-1] for v in visits.values())
+    assert [s for s in range(len(q)) if flags[s] & kd.DQ_CLOSES] == closing
+    # dQ's block: the query tile that closes next, so that it is resident on
+    # the step that completes it and named by no step after that
+    for s in range(len(q)):
+        upcoming = [c for c in closing if c >= s] or closing[-1:]
+        assert dq[s] == q[upcoming[0]]
+    for i, v in visits.items():
+        assert dq[v[-1]] == i and not (dq[v[-1] + 1:] == i).any()
+        # where the rectangle's kernel tests a dQ's ends by the mask's arithmetic
+        first_k = 0 if window is None else max(i * bq - (window - 1), 0) // bk
+        last_k = min((i * bq + bq - 1) // bk, num_k - 1) if causal else num_k - 1
+        assert (k[v[0]], k[v[-1]]) == (first_k, last_k)
+
+
+def test_the_walk_is_a_table_where_a_mask_leaves_tiles_dead_and_under_the_cap():
+    """From the shape alone: a causal or windowed call's forward and fused
+    backward walk the table, the pair and an unmasked call the rectangle; one
+    tile a side has none dead; past ``TABLE_CAP_TILES`` steps a KV head (SMEM
+    holds 1 MiB: three int32 a forward step, four a backward's) the clamped
+    rectangle stays; ``table=`` pins either."""
+    def legs(sig, table=None, **pins):
+        fwd, bwd = kd.resolve(sig, **pins)
+        return kd.walked(sig, fwd, "fwd", table), kd.walked(sig, bwd, "bwd", table)
+
+    ouro = _sig(16384, 16, 16, 128)
+    fwd, bwd = legs(ouro)
+    assert (fwd.tiles, fwd.grid, fwd.table) == (136, 256, True)
+    assert (bwd.tiles, bwd.grid, bwd.table) == (528, 1024, True)
+    assert fwd[:4] == kd.resolve(ouro)[0][:4] and bwd[:4] == kd.resolve(ouro)[1][:4]
+    pair = legs(ouro, impl_bwd="pallas")[1]
+    assert (pair.tiles, pair.grid, pair.table) == (272, 512, False)    # at (1024, 512)
+    assert [d.table for d in legs(ouro, table=False)] == [False, False]
+    # Phi-4-mini-flash's sliding layers: 63 of 1,024 tiles a KV head
+    phi = kd.make_sig((1, 16384, 40, 64), 20, 16384, "bfloat16", True, 512, None,
+                      v_dim=128)
+    assert phi.window == 512
+    assert [(d.tiles, d.grid, d.table) for d in legs(phi)] == [(63, 1024, True)] * 2
+    full = kd.make_sig((1, 16384, 16, 128), 16, 16384, "bfloat16", False, None, None)
+    assert [(d.tiles, d.grid, d.table) for d in legs(full)] == [
+        (256, 256, False), (1024, 1024, False)]
+    assert [d.table for d in legs(full, table=True)] == [False, False]
+    assert [(d.tiles, d.table) for d in legs(_sig(512, 4, 4, 64))] == [(1, False)] * 2
+    # few dead tiles: a dead step costs some 0.2 us, a table's live step
+    # 0.06 to 0.09 more, so three live tiles of four keep the rectangle
+    preset = legs(_bench_sig())[1]
+    assert (preset.tiles, preset.grid, preset.table) == (3, 4, False)
+    assert legs(_bench_sig(), table=True)[1].table
+    olmoe = legs(_sig(4096, 16, 16, 128))[0]
+    assert (olmoe.tiles, olmoe.grid, olmoe.table) == (10, 16, True)
+    # the Qwen3-Next cell's ranged walk: 4,160 live tiles and a step each for
+    # the 224 (range, kv block) pairs past a range's diagonal
+    qwen = _sig(32768, 16, 2, 256)
+    assert [(d.tiles, d.grid, d.table) for d in legs(qwen)] == [
+        (8320, 16384, True), (4384, 8192, True)]
+    long = _sig(131072, 32, 8, 128)
+    fwd, bwd = legs(long)
+    assert fwd.tiles == 65792 > kd.TABLE_CAP_TILES and not fwd.table
+    assert bwd.tiles == 33792 and bwd.table
+    assert legs(long, table=True)[0].table
+    assert kd.describe(fwd, bwd) == (
+        "attn[fwd=pallas@256x512 tiles=65792/131072(grid), "
+        "bwd=fused@512x512/r8 tiles=33792/65536]")
+
+
+# sha256 of the traced text (forward, then the pullback) that the PARENT
+# commit (ef2e5aa) gives these calls: bf16, ``interpret=True``
+# (``str(jax.make_jaxpr(...))``, jax 0.9.0)
+_PARENT_TEXT = {
+    "unmasked": (((2, 512, 4, 128), 2, 128), dict(block_q=128, block_k=256),
+                 "3b040310bf05278e"),
+    "unmasked_two_widths": (((1, 512, 2, 192), 2, 128), dict(block_q=256, block_k=128),
+                            "c7899edd03a83508"),
+    "unmasked_pair": (((1, 512, 2, 64), 2, 64),
+                      dict(block_q=128, block_k=128, impl_bwd="pallas"),
+                      "3e9781431760f0dd"),
+    "unmasked_two_ranges": (((1, 512, 4, 128), 1, 128),
+                            dict(block_q=128, block_k=128, ranges=2), "081a2bf74bc8f73b"),
+    "causal_one_tile": (((1, 128, 2, 128), 2, 128), dict(causal=True),
+                        "1c0dd51e095665b1"),
+    # a masked call pinned to the rectangle is the parent's call too
+    "causal_on_the_rectangle": (((1, 512, 2, 128), 2, 128),
+                                dict(causal=True, block_q=128, block_k=128, table=False),
+                                "d0ae1d925950a9d9"),
+    "causal_pair_on_the_rectangle": (
+        ((1, 512, 2, 128), 2, 128),
+        dict(causal=True, block_q=128, block_k=128, impl_bwd="pallas", table=False),
+        "1cf1829f2fe96771"),
+}
+
+
+@pytest.mark.parametrize("name", _PARENT_TEXT)
+def test_a_call_with_no_dead_tile_traces_to_the_parents_text(name):
+    """An unmasked call (BERT, a cross call) has no dead tile and keeps the
+    rectangle: its traced kernels, index maps and grid are the parent's TEXT
+    FOR TEXT, forward and backward; so is a masked call of one tile, and a
+    masked call pinned to the rectangle (what a call past the cap keeps)."""
+    import hashlib
+    (shape, kv, dv), pins, want = _PARENT_TEXT[name]
+    b, s, h, d = shape
+    q = jnp.zeros(shape, jnp.bfloat16)
+    k = jnp.zeros((b, s, kv, d), jnp.bfloat16)
+    v = jnp.zeros((b, s, kv, dv), jnp.bfloat16)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, interpret=True, **pins)
+
+    text = str(jax.make_jaxpr(f)(q, k, v)) + str(jax.make_jaxpr(
+        lambda q, k, v: jax.vjp(f, q, k, v)[1](jnp.ones((b, s, h, dv), jnp.bfloat16))
+    )(q, k, v))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+    if "unmasked" in name:      # and it is what the rule gives it: no pin
+        assert "PrefetchScalarGridSpec" not in text and "num_scalar_prefetch" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -754,7 +967,10 @@ def test_env_report_includes_dispatch_lines():
     from deepspeed_tpu.env_report import debug_report
     rep = debug_report()
     assert "attn dispatch @ bench shape" in rep
-    assert "attn[fwd=pallas@1024x1024,bwd=fused@512x512]" in rep
-    assert "attn[fwd=pallas@128x512,bwd=fused@256x512/r8]" in rep
+    assert "attn[fwd=pallas@1024x1024 tiles=1/1, bwd=fused@512x512 tiles=3/4(grid)]" in rep
+    assert ("attn[fwd=pallas@1024x1024 tiles=136/256, "
+            "bwd=fused@512x512 tiles=528/1024]") in rep
+    assert ("attn[fwd=pallas@128x512 tiles=8320/16384, "
+            "bwd=fused@256x512/r8 tiles=4384/8192]") in rep
     assert "attn dispatch table" not in rep
     assert "flash-attention variant" not in rep

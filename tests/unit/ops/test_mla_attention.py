@@ -52,6 +52,28 @@ def test_forward_and_every_gradient_match_xla_at_192_and_128(bwd, dtype, tol):
                                    atol=tol * scale, rtol=tol, err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("d_qk,d_v,window", [(64, 128, 100)], ids=["64_128_window"])
+def test_the_table_of_live_tiles_equals_the_rectangle_at_two_widths(d_qk, d_v, window):
+    """Values WIDER than keys (a differential layer's call: 64 | 128, group
+    2, causal under a window that ends inside a tile): ``mla_fwd`` and
+    ``mla_bwd`` on their tables of live tiles (6 of the 3 x 3 a KV head) give
+    the clamped rectangle's o, dQ, dK and dV bit for bit. 192 | 128 walks its
+    table in ``test_flash_ranged_bwd.py``."""
+    rng = np.random.default_rng(60)
+    q, k, v, g = (jnp.asarray(rng.normal(size=(1, 384, h, d)), jnp.bfloat16)
+                  for h, d in ((4, d_qk), (2, d_qk), (2, d_v), (4, d_v)))
+
+    def walk(table):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, interpret=True, block_q=128,
+            block_k=128, table=table), q, k, v)
+        return [np.asarray(a, np.float32) for a in (out, *vjp(g))]
+
+    for name, a, b in zip(("o", "dq", "dk", "dv"), walk(True), walk(False)):
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
 def test_the_shared_rope_keys_gradient_is_the_sum_over_the_heads():
     """The operator's form: ``k = [k_nope | k_r]`` with ONE 64-wide ``k_r`` a
     token broadcast over the heads. Through the kernels its gradient is the

@@ -198,6 +198,13 @@ def test_the_fused_backward_compiles_at_the_cells_shapes(
     assert f"f32[{bkv},{group},{seq},1]" not in bwd
     assert sorted(n.split(".")[0] for n in _custom_call_names(compiled)) == [
         "flash_dkdv_dq", "flash_fwd"]
+    # the walks are tables of the live tiles (PR 60), lowered through Mosaic
+    # with their scalar prefetch: three int32 a forward step, four a backward's
+    fwd, = [c for c in _custom_calls(compiled) if "%flash_fwd" in c.split(" = ")[0]]
+    for leg, call, tables in (("fwd", fwd, 3), ("bwd", bwd, 4)):
+        walk = kd.walked(sig, kd.resolve(sig)[leg == "bwd"], leg)
+        assert walk.table and walk.tiles < walk.grid, walk
+        assert call.count(f"s32[{walk.tiles}]{{0}}") == tables, (leg, walk)
 
 
 def test_rms_norm_compiles(one_chip, no_compile_cache):

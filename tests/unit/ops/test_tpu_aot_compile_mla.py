@@ -217,6 +217,12 @@ def test_the_kernels_take_both_widths_with_the_blocks_dispatch_chose(step):
     assert operands.count(f"bf16[{bh},1,{seq},{D_QK}]") == 1     # q
     assert operands.count(f"bf16[{bh},{seq},{D_QK}]") == 1       # k
     assert operands.count(f"bf16[{bh},{seq},{D_V}]") == 1        # v
+    # both walk their live tiles (PR 60): the tables are the first operands
+    walks = [kd.walked(sig, dec, leg) for dec, leg in ((fwd, "fwd"), (bwd, "bwd"))]
+    assert [(w.tiles, w.grid, w.table) for w in walks] == [(72, 128, True),
+                                                           (136, 256, True)]
+    assert calls["mla_fwd"].count("s32[72]{0}") == 3
+    assert calls["mla_bwd"].count("s32[136]{0}") == 4
     results = calls["mla_bwd"].split("custom-call(")[0]
     for shape in (f"bf16[{bh},{seq},{D_QK}]", f"bf16[{bh},{seq},{D_V}]",
                   f"bf16[{bh},1,{seq},{D_QK}]"):                 # dK, dV, dQ
